@@ -2,34 +2,25 @@
 
 The GCS's node-selection path calls into the native hybrid policy
 (reference: ``hybrid_scheduling_policy.cc:99-186`` + FixedPoint resource
-math) when the library is built; callers fall back to the Python policy
-otherwise, so a source checkout without `make -C src` still works.
+math) for every resource-driven pick.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
 
 import numpy as np
 
+from ray_tpu._private import native
+
 _lib = None
-_checked = False
-
-
-def available() -> bool:
-    return _load() is not None
 
 
 def _load():
-    global _lib, _checked
-    if _checked:
+    global _lib
+    if _lib is not None:
         return _lib
-    _checked = True
-    path = os.path.join(os.path.dirname(__file__), "libtpusched.so")
-    if not os.path.exists(path):
-        return None
-    lib = ctypes.CDLL(path)
+    lib = native.load("libtpusched.so")
     lib.sched_pick_node.restype = ctypes.c_int
     lib.sched_pick_node.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
@@ -56,8 +47,6 @@ def pick_node(node_ids: list, totals: list[dict], avails: list[dict],
     """Returns the chosen node id or None. Resource kinds are the union
     of demand keys (kinds a node lacks count as total=0 → infeasible)."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("libtpusched.so not built")
     # zero-valued demand keys still participate (they contribute node
     # utilization, matching the Python policy); EMPTY demand means every
     # alive node ties at score 0 -> first node, like the Python loop
@@ -83,8 +72,6 @@ def pick_node(node_ids: list, totals: list[dict], avails: list[dict],
 def score_nodes(totals: list[dict], avails: list[dict], alive: list[bool],
                 demand: dict) -> list[float]:
     lib = _load()
-    if lib is None:
-        raise RuntimeError("libtpusched.so not built")
     kinds = sorted(demand)
     n, k = len(totals), len(kinds)
     t = np.zeros((n, k), np.float64)

@@ -14,12 +14,9 @@ mapped segment; ``create`` returns a writable one. Buffers must be released
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "libtpustore.so")
-_SRC = os.path.join(os.path.dirname(__file__), "..", "..", "src")
+from ray_tpu._private import native
 
 TS_OK = 0
 TS_ERR = -1
@@ -29,8 +26,6 @@ TS_OOM = -4
 TS_TABLE_FULL = -5
 TS_NOT_SEALED = -6
 TS_TIMEOUT = -7
-
-_build_lock = threading.Lock()
 
 ID_LEN = 20
 
@@ -42,30 +37,8 @@ def _key(object_id: bytes) -> bytes:
     return object_id.ljust(ID_LEN, b"\x00")
 
 
-def _ensure_built() -> str:
-    src = os.path.join(_SRC, "store", "shm_store.cc")
-
-    def stale() -> bool:
-        if not os.path.exists(_LIB_PATH):
-            return True
-        # ABI/layout changes in the source must force a rebuild — a stale
-        # library would miss symbols or silently corrupt the segment
-        return (os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(_LIB_PATH))
-
-    if stale():
-        with _build_lock:
-            if stale():
-                subprocess.run(
-                    ["make", "-C", os.path.abspath(_SRC)],
-                    check=True,
-                    capture_output=True,
-                )
-    return _LIB_PATH
-
-
 def _load():
-    lib = ctypes.CDLL(_ensure_built())
+    lib = native.load("libtpustore.so")
     u64 = ctypes.c_uint64
     p = ctypes.c_void_p
     lib.store_create.restype = p

@@ -17,6 +17,8 @@ import os
 
 import numpy as np
 
+from ray_tpu._private import native
+
 _DTYPES = {
     np.dtype(np.float32): 0,
     np.dtype(np.float64): 1,
@@ -32,12 +34,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    path = os.path.join(os.path.dirname(__file__), "libtpucollective.so")
-    if not os.path.exists(path):
-        raise RuntimeError(
-            "libtpucollective.so not built; run `make -C src` at the repo "
-            "root")
-    lib = ctypes.CDLL(path)
+    lib = native.load("libtpucollective.so")
     lib.tc_init.restype = ctypes.c_int
     lib.tc_init.argtypes = [ctypes.c_int, ctypes.c_int, ctypes.c_char_p,
                             ctypes.c_int]

@@ -48,7 +48,7 @@ def init(
 ):
     """Start the runtime (reference: ``ray.init``, ``worker.py:1139``).
 
-    In-process local cluster by default; TPU devices visible to JAX are
+    In-process local cluster by default; the host's TPU chips are
     registered as a ``TPU`` resource. Pass ``address=(host, port)`` (a GCS
     address, e.g. ``cluster_utils.Cluster().gcs_address``) or
     ``"host:port"`` to connect to a running cluster instead.
@@ -95,18 +95,12 @@ def init(
 
 
 def _autodetect_tpu_count() -> int:
-    """TPU autodetect (reference: ``_private/accelerator.py:20,35`` probes GCE
-    metadata; here we ask JAX directly, without forcing a backend init)."""
-    import os
+    """TPU autodetect (reference: ``_private/accelerators/tpu.py`` counts
+    the chips' device files). Never through JAX: initialising a backend
+    here would take the chips for the driver, and no worker could."""
+    from ray_tpu._private import accelerator
 
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        return 0
-    try:
-        import jax
-
-        return sum(1 for d in jax.devices() if d.platform == "tpu")
-    except Exception:  # noqa: BLE001 - no TPU runtime present
-        return 0
+    return accelerator.tpu_chip_count()
 
 
 def shutdown():

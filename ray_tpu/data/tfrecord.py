@@ -17,85 +17,31 @@ No tensorflow import anywhere.
 
 from __future__ import annotations
 
+import functools
 import struct
 
 
 # ---------------------------------------------------------------------------
-# CRC32C (software table; small and dependency-free)
+# CRC32C
 # ---------------------------------------------------------------------------
 
-def _make_tables():
-    """Slicing-by-8 tables: 8 bytes per loop iteration instead of 1 —
-    the per-byte table loop is ~5-20 MB/s in pure Python, which would
-    make checksum (run over every record on both read and write) the
-    TFRecord throughput ceiling."""
-    poly = 0x82F63B78
-    t0 = []
-    for n in range(256):
-        c = n
-        for _ in range(8):
-            c = (c >> 1) ^ poly if c & 1 else c >> 1
-        t0.append(c)
-    tables = [t0]
-    for k in range(1, 8):
-        prev = tables[k - 1]
-        tables.append([t0[prev[n] & 0xFF] ^ (prev[n] >> 8)
-                       for n in range(256)])
-    return tables
-
-
-_T = _make_tables()
-
-
-def _load_native_crc():
-    """The C/SSE4.2 implementation (src/util/crc32c.cc) when built —
-    ~GB/s vs single-digit MB/s for any pure-Python loop; checksums run
-    over every record's full payload on both read and write."""
+@functools.cache
+def _native_crc32c():
+    """The C/SSE4.2 implementation (src/util/crc32c.cc) — ~GB/s vs
+    single-digit MB/s for any pure-Python loop; checksums run over every
+    record's full payload on both read and write."""
     import ctypes
-    import os
 
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "_private", "libtpucrc.so")
-    try:
-        lib = ctypes.CDLL(path)
-        lib.crc32c.restype = ctypes.c_uint32
-        lib.crc32c.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
+    from ray_tpu._private import native
 
-        def crc(data: bytes) -> int:
-            return lib.crc32c(bytes(data), len(data))
-
-        assert crc(b"123456789") == 0xE3069283  # Castagnoli check vector
-        return crc
-    except Exception:  # noqa: BLE001 - lib absent/mismatched: Python path
-        return None
+    lib = native.load("libtpucrc.so")
+    lib.crc32c.restype = ctypes.c_uint32
+    lib.crc32c.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
+    return lib.crc32c
 
 
-_U64S = struct.Struct("<Q")
-
-
-def _crc32c_py(data: bytes) -> int:
-    crc = 0xFFFFFFFF
-    n = len(data)
-    i = 0
-    t0, t1, t2, t3, t4, t5, t6, t7 = _T
-    end8 = n - (n % 8)
-    unpack = _U64S.unpack_from
-    while i < end8:
-        (word,) = unpack(data, i)
-        word ^= crc
-        hi = word >> 32
-        crc = (t7[word & 0xFF] ^ t6[(word >> 8) & 0xFF]
-               ^ t5[(word >> 16) & 0xFF] ^ t4[(word >> 24) & 0xFF]
-               ^ t3[hi & 0xFF] ^ t2[(hi >> 8) & 0xFF]
-               ^ t1[(hi >> 16) & 0xFF] ^ t0[hi >> 24])
-        i += 8
-    while i < n:
-        crc = t0[(crc ^ data[i]) & 0xFF] ^ (crc >> 8)
-        i += 1
-    return crc ^ 0xFFFFFFFF
-
-
-_crc32c = _load_native_crc() or _crc32c_py
+def _crc32c(data: bytes) -> int:
+    return _native_crc32c()(bytes(data), len(data))
 
 
 def _masked_crc(data: bytes) -> int:

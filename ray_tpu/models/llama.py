@@ -182,7 +182,7 @@ def attention_sublayer(cfg, x, p, sin, cos, segment_ids, attn_impl,
                                   causal=True)
     else:
         attn_out = attention(q, k, v, causal=True, segment_ids=segment_ids,
-                             impl=attn_impl)
+                             impl=attn_impl, mesh=mesh)
     # checkpoint naming for the "attn"/"dots_attn" remat policies lives
     # INSIDE the attention impls (flash names its kernel residuals in
     # _flash_vjp_fwd; the reference impl names its output in

@@ -198,10 +198,6 @@ def slice_topology() -> dict:
         "num_hosts": max((d.process_index for d in devices), default=0) + 1,
     }
     if devices and devices[0].platform == "tpu":
-        try:
-            coords = [getattr(d, "coords", None) for d in devices]
-            info["coords"] = coords
-            info["device_kind"] = devices[0].device_kind
-        except Exception:  # noqa: BLE001
-            pass
+        info["coords"] = [d.coords for d in devices]
+        info["device_kind"] = devices[0].device_kind
     return info

@@ -19,6 +19,7 @@ on source device ``src`` relative to my index ``idx``:
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -257,6 +258,30 @@ def _ring_flash_body(axis_name, n, scale, causal, blocks, interpret,
     return out.transpose(0, 2, 1, 3)
 
 
+def batch_head_spec(mesh: Mesh, b: int, h: int, hk: int, *,
+                    batch_axes=("dp", "fsdp"), head_axis: str = "tp",
+                    seq_axis: str | None = None) -> P:
+    """PartitionSpec of a [batch, seq, heads, head_dim] attention operand
+    inside a ``shard_map`` over ``mesh``: batch over ``batch_axes`` and
+    heads over ``head_axis``, each only where the axis is on the mesh
+    with size > 1 and divides the dim (``hk`` kv heads too), so the
+    region does not replicate compute across mesh axes that partition
+    independent work. ``seq_axis`` shards the sequence (ring attention)."""
+    b_ax = tuple(
+        a for a in batch_axes
+        if a in mesh.axis_names and mesh.shape[a] > 1
+    )
+    if b_ax and b % math.prod(mesh.shape[a] for a in b_ax):
+        b_ax = ()
+    h_ax = (
+        head_axis
+        if head_axis in mesh.axis_names and mesh.shape[head_axis] > 1
+        and h % mesh.shape[head_axis] == 0 and hk % mesh.shape[head_axis] == 0
+        else None
+    )
+    return P(b_ax or None, seq_axis, h_ax, None)
+
+
 def ring_attention(
     q, k, v, *, mesh: Mesh, axis: str = "sp", causal: bool = True,
     scale: float | None = None, batch_axes=("dp", "fsdp"),
@@ -283,20 +308,8 @@ def ring_attention(
         raise ValueError(f"seq {s} not divisible by {axis} size {n}")
     scale = scale if scale is not None else d ** -0.5
 
-    import math
-
-    b_ax = tuple(
-        a for a in batch_axes
-        if a in mesh.axis_names and mesh.shape[a] > 1
-    )
-    if b_ax and b % math.prod(mesh.shape[a] for a in b_ax):
-        b_ax = ()
-    h_ax = (
-        head_axis
-        if head_axis in mesh.axis_names and mesh.shape[head_axis] > 1
-        and h % mesh.shape[head_axis] == 0 and hk % mesh.shape[head_axis] == 0
-        else None
-    )
+    spec = batch_head_spec(mesh, b, h, hk, batch_axes=batch_axes,
+                           head_axis=head_axis, seq_axis=axis)
 
     if impl not in ("auto", "flash", "reference"):
         raise ValueError(
@@ -311,7 +324,6 @@ def ring_attention(
                        (block_q, block_k), interpret)
     else:
         body = partial(_ring_body, axis, n, scale, causal)
-    spec = P(b_ax or None, axis, h_ax, None)
     fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -767,19 +767,32 @@ class GcsServer(RpcServer):
         return {"ok": True}
 
     def _health_loop(self):
+        interval = self._hb_timeout / 4
         while not self._stopping:
-            time.sleep(self._hb_timeout / 4)
-            now = time.monotonic()
-            with self._lock:
-                dead = [n.node_id for n in self._nodes.values()
-                        if n.alive and now - n.last_heartbeat > self._hb_timeout]
-            for node_id in dead:
-                self._mark_node_dead(node_id, reason="heartbeat timeout")
-            try:
-                self._process_deferred_contains()
-                self._reap_stale_clients()
-            except Exception:  # noqa: BLE001 - next tick retries
-                pass
+            t0 = time.monotonic()
+            time.sleep(interval)
+            self._health_tick(time.monotonic() - t0 - interval)
+
+    def _health_tick(self, overslept: float):
+        """Declare nodes dead whose beat is overdue. A monitor that did
+        not run itself cannot judge: while this process (or its whole
+        machine — a TPU backend initialising in ANY process freezes a
+        v5e host for seconds) was stalled, no beat could land either,
+        so a round that overslept credits every node with the stall."""
+        now = time.monotonic()
+        with self._lock:
+            if overslept > self._hb_timeout / 4:
+                for n in self._nodes.values():
+                    n.last_heartbeat += overslept
+            dead = [n.node_id for n in self._nodes.values()
+                    if n.alive and now - n.last_heartbeat > self._hb_timeout]
+        for node_id in dead:
+            self._mark_node_dead(node_id, reason="heartbeat timeout")
+        try:
+            self._process_deferred_contains()
+            self._reap_stale_clients()
+        except Exception:  # noqa: BLE001 - next tick retries
+            pass
 
     def _mark_node_dead(self, node_id: str, reason: str):
         with self._lock:
@@ -1270,13 +1283,11 @@ class GcsServer(RpcServer):
                 if n and n.alive and _fits(demand, n.resources):
                     return nid
             return None
-        # native hybrid policy (C++ fixed-point scoring —
-        # src/scheduler/scheduling.cc) when built; Python fallback below
-        # keeps source checkouts working without `make -C src`
         from ray_tpu._private import scheduling as _sched
 
-        if demand and _sched.available():
-            # resource-driven picks: the native hybrid policy. Empty
+        if demand:
+            # resource-driven picks: the native hybrid policy (C++
+            # fixed-point scoring — src/scheduler/scheduling.cc). Empty
             # demands fall through to the Python score — they tie on
             # utilization, and only the Python path knows queue depth
             # and actor occupancy (the actual spread signals).
@@ -1289,43 +1300,34 @@ class GcsServer(RpcServer):
                 exclude or set(), demand,
                 spread_threshold=0.0, top_k=1)
         if occupancy is None:
+            # zero-resource demands tie on utilization everywhere, so
+            # live-actor occupancy is the spread signal (reference:
+            # GcsActorScheduler spreads; without it an envelope flood
+            # stacks all 2,000 actors on node[0]). Recomputed per pick
+            # — drift-free vs incremental counts across the many death
+            # paths, and only empty-demand picks pay the O(actors)
+            # scan. Batch scheduling passes a precomputed dict it
+            # maintains incrementally (one scan per BATCH, not per
+            # actor — per-pick rescans are O(n^2) at the 40k tier).
             occupancy = {}
-            if not demand:
-                # zero-resource demands tie on utilization everywhere, so
-                # live-actor occupancy is the spread signal (reference:
-                # GcsActorScheduler spreads; without it an envelope flood
-                # stacks all 2,000 actors on node[0]). Recomputed per pick
-                # — drift-free vs incremental counts across the many death
-                # paths, and only empty-demand picks pay the O(actors)
-                # scan. Batch scheduling passes a precomputed dict it
-                # maintains incrementally (one scan per BATCH, not per
-                # actor — per-pick rescans are O(n^2) at the 40k tier).
-                for a in self._actors.values():
-                    if a.node_id and a.state in ("PENDING", "ALIVE",
-                                                 "RESTARTING"):
-                        occupancy[a.node_id] = \
-                            occupancy.get(a.node_id, 0) + 1
+            for a in self._actors.values():
+                if a.node_id and a.state in ("PENDING", "ALIVE",
+                                             "RESTARTING"):
+                    occupancy[a.node_id] = \
+                        occupancy.get(a.node_id, 0) + 1
         best, best_score = None, None
-        feasible_busy, busy_load = None, None
         for n in self._nodes.values():
             if not n.alive or (exclude and n.node_id in exclude):
                 continue
-            if not _fits(demand, n.resources):
-                continue
-            if _fits(demand, n.available):
-                # queue depth folds into the score: a node whose
-                # `available` looks healthy because per-task
-                # acquire/release averages out may still hold a deep
-                # ready queue — placement must prefer shallow queues
-                score = (_critical_utilization(demand, n)
-                         + min(n.load, 1000) * 0.001
-                         + min(occupancy.get(n.node_id, 0), 100_000)
-                         * 1e-6)
-                if best_score is None or score < best_score:
-                    best, best_score = n.node_id, score
-            elif busy_load is None or n.load < busy_load:
-                feasible_busy, busy_load = n.node_id, n.load
-        return best if best is not None else feasible_busy
+            # queue depth is the score: a node whose `available` looks
+            # healthy because per-task acquire/release averages out may
+            # still hold a deep ready queue — placement must prefer
+            # shallow queues
+            score = (min(n.load, 1000) * 0.001
+                     + min(occupancy.get(n.node_id, 0), 100_000) * 1e-6)
+            if best_score is None or score < best_score:
+                best, best_score = n.node_id, score
+        return best
 
     def rpc_pick_node(self, conn, send_lock, *, demand, exclude=None,
                       pg_id=None):
@@ -2297,19 +2299,6 @@ def _ns_key(namespace: str, name: str) -> str:
 
 def _fits(demand: dict, supply: dict) -> bool:
     return all(supply.get(k, 0.0) >= v for k, v in demand.items() if v > 0)
-
-
-def _critical_utilization(demand: dict, node: NodeInfo) -> float:
-    """Score = max over demanded resources of (used+demand)/total; lower is
-    better (reference: hybrid_scheduling_policy.cc:99-186)."""
-    score = 0.0
-    for k, v in demand.items():
-        total = node.resources.get(k, 0.0)
-        if total <= 0:
-            continue
-        used = total - node.available.get(k, 0.0)
-        score = max(score, (used + v) / total)
-    return score
 
 
 def _place_bundles(bundles: list, strategy: str, nodes: list):

@@ -67,16 +67,8 @@ def jax_backends_initialized() -> bool:
     """True iff this process holds a LIVE XLA backend (not merely an
     imported jax module — importing is fork-safe, initialized device
     runtimes are not)."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        xb = sys.modules.get("jax._src.xla_bridge")
-        if xb is not None and getattr(xb, "_backends", None):
-            return True
-    except Exception:  # noqa: BLE001 - jax internals moved; assume unsafe
-        return True
-    return False
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return xb is not None and xb.backends_are_initialized()
 
 
 class ForkedProc:
@@ -294,26 +286,9 @@ class PrestartManager:
     # -- template registry ---------------------------------------------
 
     def _base_env(self) -> dict:
-        from ray_tpu.runtime.worker_pool import (_worker_pythonpath,
-                                                 env_get_default)
+        from ray_tpu.runtime.worker_pool import worker_env
 
-        node = self._pool._node
-        env = dict(os.environ)
-        env["PYTHONPATH"] = _worker_pythonpath(env.get("PYTHONPATH", ""))
-        env.update({
-            "RAY_TPU_RAYLET_HOST": node.address[0],
-            "RAY_TPU_RAYLET_PORT": str(node.address[1]),
-            "RAY_TPU_GCS_HOST": node.gcs_address[0],
-            "RAY_TPU_GCS_PORT": str(node.gcs_address[1]),
-            "RAY_TPU_STORE_NAME": node.store_name,
-            "RAY_TPU_NODE_ID": node.node_id,
-            "JAX_PLATFORMS": env_get_default("JAX_PLATFORMS", "cpu"),
-            "PYTHONUNBUFFERED": "1",
-        })
-        if getattr(node, "log_dir", None):
-            # forked children re-enter Worker() directly; the in-process
-            # log capture reads this to find its stamped-file home
-            env["RAY_TPU_LOG_DIR"] = node.log_dir
+        env = worker_env(self._pool._node)
         env.pop("RAY_TPU_WORKER_ID", None)
         env.pop("RAY_TPU_RUNTIME_ENV", None)
         return env

@@ -22,6 +22,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 
+from ray_tpu._private import accelerator
 from ray_tpu.runtime import fault_injection as _fi
 from ray_tpu.runtime.gcs import _fits
 from ray_tpu.runtime.rpc import send_msg
@@ -217,7 +218,9 @@ class TaskScheduler:
                     f"runtime env setup failed: {env_err}"))
                 continue
             gen = self._dispatch_gen
-            worker = pool.idle_worker(task.get("runtime_env"))
+            worker = pool.idle_worker(
+                task.get("runtime_env"),
+                accelerator.chips_for(task.get("resources", {})))
             if worker is None:
                 self.enqueue(task)
                 # wait for a completion/registration kick instead of a
@@ -370,7 +373,9 @@ class TaskScheduler:
                                     "env_error": env_err}
                 waiter["event"].set()
                 continue
-            worker = pool.idle_worker(waiter["runtime_env"])
+            worker = pool.idle_worker(
+                waiter["runtime_env"],
+                accelerator.chips_for(waiter["demand"]))
             if worker is None:
                 return  # spawn in progress / pool exhausted; kick revisits
             if worker.push_addr is None:

@@ -56,10 +56,13 @@ _STAGE_TO_BUCKET = {"data_wait": "stall", "collective_sync": "stall",
 
 ANNEX_PREFIX = "train/progress/"
 
-# peak dense-matmul TFLOPs per chip (bf16) — same table the bench uses;
-# MFU needs a peak, declared or detected
-_PEAK_TFLOPS = {"v4": 275.0, "v5e": 197.0, "v5litepod": 197.0,
-                "v5p": 459.0, "v6e": 918.0}
+# Peak dense bf16 TFLOP/s of one chip, keyed by jax's ``device_kind``
+# (source: Google Cloud TPU documentation, the page of each generation).
+# The one table: MFU here and in bench.py divides by it, and a TPU that
+# is not in it is an error, never a default.
+PEAK_TFLOPS = {"TPU v4": 275.0, "TPU v5 lite": 197.0, "TPU v5e": 197.0,
+               "TPU v5": 459.0, "TPU v5p": 459.0, "TPU v6 lite": 918.0,
+               "TPU v6e": 918.0}
 
 
 def _enabled() -> bool:
@@ -78,19 +81,19 @@ def run_trace_id(run: str) -> str:
 
 
 def detect_peak_flops() -> float | None:
-    """Per-chip peak FLOP/s from the local jax device kind, if it is a
-    TPU generation the table knows. None on CPU/GPU — callers must
-    declare a peak for MFU there."""
-    try:
-        import jax
+    """Per-chip peak FLOP/s of the local jax device. None off TPU —
+    callers must declare a peak for MFU there."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 - no jax / no devices
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
         return None
-    for key, tflops in _PEAK_TFLOPS.items():
-        if key in kind:
-            return tflops * 1e12
-    return None
+    if dev.device_kind not in PEAK_TFLOPS:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {dev.device_kind!r}: "
+            "add it, with its source, to PEAK_TFLOPS in "
+            "ray_tpu/train/telemetry.py")
+    return PEAK_TFLOPS[dev.device_kind] * 1e12
 
 
 class StepTelemetry:

@@ -16,6 +16,7 @@ import os
 from typing import Any, Callable
 
 import ray_tpu
+from ray_tpu._private import accelerator
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.train.session import TrainContext, _init_session
 
@@ -34,8 +35,7 @@ class _RankWorker:
             os.environ[k] = str(v)
         # multi-host TPU bootstrap (jax.distributed): only when a
         # coordinator is published AND this process owns TPU chips
-        if coordinator and os.environ.get("JAX_PLATFORMS", "") not in (
-                "cpu", "cpu,"):
+        if coordinator and not accelerator.cpu_only():
             import jax
 
             jax.distributed.initialize(

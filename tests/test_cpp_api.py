@@ -111,9 +111,9 @@ def test_msgpack_xlang_put_get_and_task(cluster):
     assert got["value"] == 42
 
 
-@pytest.mark.skipif(not os.path.exists(EXAMPLE),
-                    reason="C++ example not built (run make -C src)")
 def test_cpp_example_binary(cluster):
+    # the cluster has opened the shm store by now, and with it the one
+    # native build has run: the example binary is part of it
     host, port = cluster.gcs_address
     proc = subprocess.run([EXAMPLE, host, str(port)], capture_output=True,
                           text=True, timeout=120)

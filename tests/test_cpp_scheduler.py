@@ -2,12 +2,8 @@
 hybrid_scheduling_policy.cc semantics)."""
 
 import numpy as np
-import pytest
 
 from ray_tpu._private import scheduling as sched
-
-pytestmark = pytest.mark.skipif(not sched.available(),
-                                reason="libtpusched.so not built")
 
 
 def _nodes(specs):
@@ -87,10 +83,24 @@ def test_spread_threshold_ties_low_utilization():
     assert always == {"n0"}
 
 
+def _critical_utilization(demand: dict, node) -> float:
+    """The policy's score in plain Python: max over demanded resources of
+    (used+demand)/total; lower is better (reference:
+    hybrid_scheduling_policy.cc:99-186)."""
+    score = 0.0
+    for k, v in demand.items():
+        total = node.resources.get(k, 0.0)
+        if total <= 0:
+            continue
+        used = total - node.available.get(k, 0.0)
+        score = max(score, (used + v) / total)
+    return score
+
+
 def test_matches_python_policy_randomized():
-    """C++ policy must agree with the Python fallback on the
+    """C++ policy must agree with a plain Python statement of it on the
     deterministic (top_k=1, threshold=0) configuration."""
-    from ray_tpu.runtime.gcs import _critical_utilization, _fits
+    from ray_tpu.runtime.gcs import _fits
 
     rng = np.random.default_rng(0)
     for _ in range(200):

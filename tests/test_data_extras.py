@@ -409,6 +409,21 @@ def test_tfrecord_crc_detects_corruption(tmp_path):
         list(tfr.iter_records(bytes(framed)))
 
 
+@pytest.mark.parametrize("data,want", [
+    (b"", 0x00000000),
+    (b"123456789", 0xE3069283),            # the Castagnoli check value
+    (bytes(32), 0x8A9136AA),               # RFC 3720 B.4: 32 zero bytes
+    (bytes([0xFF] * 32), 0x62A8AB43),      # RFC 3720 B.4: 32 bytes of 0xFF
+    (bytes(range(32)), 0x46DD794E),        # RFC 3720 B.4: ascending bytes
+])
+def test_crc32c_known_vectors(data, want):
+    """The native CRC32C (the only implementation: there is no Python
+    fallback to agree with) against published vectors."""
+    from ray_tpu.data import tfrecord as tfr
+
+    assert tfr._crc32c(data) == want
+
+
 def test_webdataset_reader(ray_tpu_start, tmp_path):
     import io
     import tarfile

@@ -1,0 +1,109 @@
+"""Compile the main path's device programs for a TPU that is described,
+not attached: the chip's own compiler refuses here what it would refuse
+on the chip (a kernel it cannot tile or partition, a step that does not
+fit HBM), at no chip time. Nothing runs, so nothing here is a result or
+a time.
+
+Code that asks ``jax.default_backend()`` sees the CPU under test, so the
+trainers are steered to the flash kernel from here (``attn_impl``).
+"""
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import llama
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.parallel.mesh import create_mesh
+from ray_tpu.train.trainer import JaxTrainer, TrainConfig
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: the next run would warn
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("heads,kv_heads,seq", [
+    (32, 8, 2048), (32, 8, 16384), (12, 4, 2048)])
+def test_flash_kernels_compile(v5e_2x2, heads, kv_heads, seq, grad):
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def operand(h):
+        return jax.ShapeDtypeStruct((1, seq, h, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    if grad:
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(
+        operand(heads), operand(kv_heads), operand(kv_heads)).compile()
+    # forward alone is one kernel; its gradient adds dq and dk/dv
+    assert compiled.as_text().count("tpu_custom_call") >= (3 if grad else 1)
+
+
+def _compile_step(trainer, batch, seq):
+    trainer.attn_impl = "flash"
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        trainer.abstract_state(), trainer.state_shardings())
+    tokens = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32)
+    tokens = jax.ShapeDtypeStruct(
+        tokens.shape, tokens.dtype,
+        sharding=trainer._batch_shardings(tokens))
+    return jax.jit(trainer._step, donate_argnums=(0,)).lower(
+        state, tokens).compile()
+
+
+def test_one_chip_1b_step_compiles(v5e_2x2):
+    """bench.py's 1.0B config at its batch: fits one chip's HBM (the
+    compiler raises when a program does not) and keeps its three kernel
+    calls (the remat policy saves the flash residuals, so the backward
+    does not run the forward kernel again)."""
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, d_model=2048, n_layers=16, n_heads=16,
+        n_kv_heads=4, head_dim=128, d_ff=7168, remat="dots_attn")
+    trainer = JaxTrainer(
+        cfg, TrainConfig(mesh_axes={"dp": 1}, strategy="dp"),
+        mesh=create_mesh({"dp": 1}, devices=v5e_2x2[:1]))
+    text = _compile_step(trainer, 4, 2048).as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_fsdp4_step_compiles_with_flash(v5e_2x2):
+    """Llama-3-8B widths sharded over the four chips. The compiler cannot
+    partition a Pallas kernel by itself ("Mosaic kernels cannot be
+    automatically partitioned"), so this fails unless the attention
+    dispatch wraps the kernel in shard_map under a multi-device mesh."""
+    cfg = dataclasses.replace(llama.llama3_8b(), n_layers=2,
+                              remat="dots_attn")
+    trainer = JaxTrainer(
+        cfg, TrainConfig(mesh_axes={"fsdp": 4}, strategy="fsdp",
+                         fused_loss=True),
+        mesh=create_mesh({"fsdp": 4}, devices=v5e_2x2))
+    text = _compile_step(trainer, 4, 2048).as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "all-gather" in text       # the sharded parameters, gathered
