@@ -514,8 +514,8 @@ def bench_serve() -> dict:
     if steady_bds:
         ttft_breakdown = {
             k: round(float(np.mean([bd[k] for bd in steady_bds])), 4)
-            for k in ("queue_wait_s", "prefill_s", "pipeline_stall_s",
-                      "ship_s")}
+            for k in ("queue_wait_s", "device_wait_s", "prefill_s",
+                      "pipeline_stall_s", "ship_s")}
         ttft_breakdown["sum_s"] = round(
             sum(ttft_breakdown.values()), 4)
         ttft_breakdown["mean_observed_ttft_s"] = round(
@@ -754,8 +754,8 @@ def bench_serve_scaleout() -> dict:
             bd = None
             if d["bds"]:
                 bd = {k: round(float(np.mean([x[k] for x in d["bds"]])), 4)
-                      for k in ("queue_wait_s", "prefill_s",
-                                "pipeline_stall_s", "ship_s")}
+                      for k in ("queue_wait_s", "device_wait_s",
+                                "prefill_s", "pipeline_stall_s", "ship_s")}
             per_replica[tag] = {
                 "requests": d["requests"],
                 "p50_ttft_s": (round(float(np.median(d["ttfts"])), 4)

@@ -163,6 +163,7 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return (res[0], res[1]) if with_lse else (res[0], None)
 
@@ -303,6 +304,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, g, lse, delta)
 
     # kv-head-major grid; inner axis fuses (gqa rep, q block) so dk/dv
@@ -330,6 +332,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, g, lse, delta)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 

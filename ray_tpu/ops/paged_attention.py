@@ -234,12 +234,14 @@ class PrefixCache:
         for hsh in hashes:
             page = self._by_hash.get(hsh)
             if page is None:
-                self.miss_pages += 1
                 break
             pages.append(page)
             self.hit_pages += 1
             self._refs[page] = self._refs.get(page, 0) + 1
             self._idle.pop(page, None)
+        # every page from the first miss on has to be computed: then
+        # hit / (hit + miss) is the share of looked-up pages reused
+        self.miss_pages += len(hashes) - len(pages)
         return pages
 
     def release(self, pages: list[int]):

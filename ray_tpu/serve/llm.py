@@ -25,6 +25,7 @@ import itertools
 import queue
 import threading
 import time
+import uuid
 from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
@@ -42,17 +43,37 @@ from ray_tpu.util import tracing as _tracing
 
 # Per-request TTFT decomposition (metrics plane): every request's time to
 # first token splits into queue_wait (submit -> prefill dispatch),
-# prefill (dispatch -> device completion, stamped by the ready watcher),
-# pipeline_stall (device completion -> the loop draining the firsts) and
-# ship (the host copy of the first-token batch). The four stages sum to
-# the observed TTFT exactly (see Request.breakdown). Series carry the
+# device_wait (dispatch -> the device starts the prefill: the wait on its
+# queue behind the decode chunk in flight), prefill (the program's own
+# run; start and end stamped by the watcher thread), pipeline_stall
+# (device completion -> the loop coming for the firsts) and ship (the
+# host copy of the first-token batch). The five stages sum to the
+# observed TTFT exactly (see Request.breakdown). Series carry the
 # hosting deployment + replica tags (from the serve replica context) so
 # the controller's autoscaler and the dashboard can split per
 # deployment/replica; engines outside serve tag deployment="-".
-_STAGES = ("queue_wait", "prefill", "pipeline_stall", "ship")
+_STAGES = ("queue_wait", "device_wait", "prefill", "pipeline_stall", "ship")
 _serve_hist = _metrics.histogram(
     "ray_tpu_serve_stage_s", "per-request serve TTFT stage latency",
     tag_keys=("stage", "deployment", "replica"))
+
+
+def _named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` as the program ``jit_<name>``: a jitted
+    ``functools.partial`` or lambda is ``jit__unknown`` / ``jit__lambda_``
+    in a device trace, a compile log and the backend's list of live
+    executables. The name carries the program's static facts (chunk,
+    pages), so a reader tells the programs apart without looking inside
+    them; it is also part of the compile-cache key."""
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kwargs)
+
+
+def _wall(mono: float) -> float:
+    """A ``time.monotonic()`` stamp on the wall clock spans are kept on."""
+    return time.time() - (time.monotonic() - mono)
 
 
 def _bucket(n: int, minimum: int = 16) -> int:
@@ -74,9 +95,10 @@ class Request:
     submit_t: float = field(default_factory=time.monotonic)
     first_token_t: float | None = None
     # TTFT decomposition stamps (see Request.breakdown): prefill batch
-    # dispatched / device results ready (watcher thread) / loop drained
-    # the first-token batch to the host
+    # dispatched / the device started it / its results ready (both from
+    # the watcher thread) / the loop came to read the first-token batch
     dispatch_t: float | None = None
+    start_t: float | None = None
     ready_t: float | None = None
     drain_t: float | None = None
     generated: int = 0
@@ -100,20 +122,29 @@ class Request:
 
     @property
     def breakdown(self) -> dict | None:
-        """Measured TTFT decomposition. ``ready_t`` (stamped by the
-        watcher thread off the device stream) is clamped into
-        [dispatch_t, drain_t] so the four stages ALWAYS sum to the
-        observed TTFT exactly."""
+        """Measured TTFT decomposition. ``start_t`` and ``ready_t``
+        (stamped by the watcher thread off the device stream) are
+        clamped into dispatch_t <= start <= ready <= first_token_t, so
+        the five stages ALWAYS sum to the observed TTFT exactly. The
+        loop usually comes for the firsts (``drain_t``) before they are
+        ready and blocks: that wait is the device's (device_wait,
+        prefill), not a stall of the pipeline, and ship starts when both
+        the results and the loop are there."""
         if (self.first_token_t is None or self.dispatch_t is None
                 or self.drain_t is None):
             return None
         ready = self.ready_t if self.ready_t is not None else self.drain_t
-        ready = min(max(ready, self.dispatch_t), self.drain_t)
+        ready = min(max(ready, self.dispatch_t), self.first_token_t)
+        start = self.start_t if self.start_t is not None \
+            else self.dispatch_t
+        start = min(max(start, self.dispatch_t), ready)
+        taken = min(max(self.drain_t, ready), self.first_token_t)
         return {
             "queue_wait_s": self.dispatch_t - self.submit_t,
-            "prefill_s": ready - self.dispatch_t,
-            "pipeline_stall_s": self.drain_t - ready,
-            "ship_s": self.first_token_t - self.drain_t,
+            "device_wait_s": start - self.dispatch_t,
+            "prefill_s": ready - start,
+            "pipeline_stall_s": taken - ready,
+            "ship_s": self.first_token_t - taken,
         }
 
     engine: "LLMEngine | None" = None
@@ -198,28 +229,36 @@ class LLMEngine:
         self.total_generated = 0
         self.total_finished = 0
         self.ttfts: "deque[float]" = deque(maxlen=1024)
-        # per-request TTFT stage breakdowns (same bounded window)
-        self.breakdowns: "deque[dict]" = deque(maxlen=1024)
         # pre-resolved per-(deployment, replica) stage-histogram handles
         self._h_stage = {s: _serve_hist.handle(
             {"stage": s, "deployment": self.deployment_name,
              "replica": self.replica_tag}) for s in _STAGES}
-        # ready watcher: stamps Request.ready_t when a prefill batch's
-        # device results complete — block_until_ready OFF the loop
-        # thread, so the measurement never stalls the decode pipeline
-        self._ready_q: "queue.Queue | None" = None
-        if _metrics.enabled():
-            self._ready_q = queue.Queue()
-            threading.Thread(target=self._ready_watcher, daemon=True,
-                             name="llm-ready-watcher").start()
+        # ready watcher: handed EVERY dispatch (prefill and decode chunk)
+        # in stream order, it stamps when the device started and finished
+        # each — block_until_ready OFF the loop thread, so the
+        # measurement never stalls the decode pipeline (see
+        # _ready_watcher; started with the loop, joined by stop())
+        self._ready_q: "queue.Queue" = queue.Queue()
+        self._watcher: threading.Thread | None = None
+        # every dispatch's place in the device stream (prefills and
+        # chunks together; _dispatch_seq below counts chunks alone)
+        self._stream_seq = itertools.count()
+        # the engine loop's spans are one trace (util/tracing.phase)
+        self._trace_id = uuid.uuid4().hex[:16]
+        # requests the loop has taken off the queue whose prefill is not
+        # dispatched yet (a failing dispatch must still end their
+        # streams: see _loop), and requests whose first token went out
+        # before the watcher had stamped their prefill (_publish_stamped)
+        self._admitting: list[Request] = []
+        self._unpublished: list[Request] = []
         # device-resident loop inputs (see _device_inputs)
         self._dev_inputs: dict | None = None
         self._dev_dirty = True
         # device-resident last-token vector (chained through decode
         # programs and prefill scatters; see _dispatch_decode)
         self._last_dev = None
-        self._scatter_fn = jax.jit(
-            lambda last, slots, firsts:
+        self._scatter_fn = _named_jit(
+            "scatter_firsts", lambda last, slots, firsts:
             last.at[slots].set(firsts.astype(last.dtype)))
         # prefill batches whose first tokens haven't reached the host
         # yet: (dispatch_seq_at, items, firsts_device)
@@ -242,17 +281,19 @@ class LLMEngine:
         cfg = self.cfg
         self._cache = decoding.init_cache(cfg, self.max_batch,
                                           self.max_len)
-        self._decode_fn = jax.jit(
+        self._decode_fn = _named_jit(
+            f"dense_decode_c{self.decode_chunk}",
             partial(self._decode_impl, cfg, chunk=self.decode_chunk),
             donate_argnums=(1,)
         )
         self._decode_fn_drain = (
             self._decode_fn if self._drain_chunk == self.decode_chunk
-            else jax.jit(
+            else _named_jit(
+                f"dense_decode_c{self._drain_chunk}",
                 partial(self._decode_impl, cfg, chunk=self._drain_chunk),
                 donate_argnums=(1,)))
-        self._prefill_fn = jax.jit(
-            partial(self._prefill_impl, cfg),
+        self._prefill_fn = _named_jit(
+            "dense_prefill", partial(self._prefill_impl, cfg),
             static_argnames=("bucket",), donate_argnums=(1,),
         )
         # batched prefill: N prompts of one bucket in ONE dispatch —
@@ -261,8 +302,9 @@ class LLMEngine:
         # any compute. Specializes per (n, bucket) shape;
         # admission splits bursts into power-of-two groups so the
         # variant count stays logarithmic.
-        self._prefill_batch_fn = jax.jit(
-            partial(self._prefill_batch_impl, cfg), donate_argnums=(1,))
+        self._prefill_batch_fn = _named_jit(
+            "dense_prefill_batch", partial(self._prefill_batch_impl, cfg),
+            donate_argnums=(1,))
 
     # -- jitted programs ---------------------------------------------------
 
@@ -384,6 +426,10 @@ class LLMEngine:
     # -- engine loop -------------------------------------------------------
 
     def start(self):
+        self._watcher = threading.Thread(
+            target=self._ready_watcher, daemon=True,
+            name="llm-ready-watcher")
+        self._watcher.start()
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -391,25 +437,45 @@ class LLMEngine:
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=30)
-        if self._ready_q is not None:
-            self._ready_q.put(None)
+        # the watcher goes after the loop (no dispatch follows its
+        # sentinel) and is waited for: a daemon thread still blocked on
+        # the device when the interpreter exits aborts the process
+        self._ready_q.put(None)
+        if self._watcher is not None:
+            self._watcher.join(timeout=30)
 
     def _ready_watcher(self):
-        """Stamp ready_t per prefill batch in dispatch order (device
-        stream order, so sequential blocking gives correct stamps)."""
+        """The device's timeline as the host sees it. Every dispatch
+        (kind, stream seq, an output of the program, dispatch_t, the
+        requests it prefills, its dispatch span or None) arrives in
+        stream order, and the device runs them in that order, so
+        blocking on each in turn gives when it finished (done) and when
+        it started: at its dispatch, or when the one before it finished,
+        whichever is later. Prefilled requests get ``start_t`` /
+        ``ready_t``; a dispatch made while spans are recorded gets a
+        ``device.run`` child."""
+        prev_done = float("-inf")
         while True:
             item = self._ready_q.get()
             if item is None:
                 return
-            firsts, reqs = item
+            kind, seq, result, dispatch_t, reqs, span = item
             try:
-                firsts.block_until_ready()
-            except Exception:  # noqa: BLE001 - backend quirk: skip stamp
-                continue
-            now = time.monotonic()
+                result.block_until_ready()
+            except Exception:  # noqa: BLE001 - a failed run has ended too
+                pass
+            done = time.monotonic()
+            start = max(dispatch_t, prev_done)
+            prev_done = done
             for r in reqs:
-                if r.ready_t is None:
-                    r.ready_t = now
+                r.start_t = start
+                r.ready_t = done       # last: _publish_stamped waits on it
+            if span is not None:
+                _tracing.emit(
+                    "device.run", start=_wall(start), duration=done - start,
+                    parent=span, kind="serve",
+                    attrs={"kind": kind, "seq": seq,
+                           "wait_s": start - dispatch_t})
 
     def submit(self, prompt, *, max_new_tokens: int = 128,
                temperature: float = 0.0, eos_id: int | None = None) -> Request:
@@ -421,8 +487,11 @@ class LLMEngine:
             eos_id=eos_id,
         )
         req.engine = self
-        if _tracing.is_enabled():
-            req.trace_ctx = _tracing.current_context()
+        if _tracing.recording():
+            # with no ambient span (a caller outside serve: the
+            # benchmark's client) the request is a trace of its own
+            req.trace_ctx = _tracing.current_context() or \
+                _tracing.SpanContext(uuid.uuid4().hex[:16], "")
             req.submit_wall = time.time()
         # Lock pairs with the drain in _loop's finally: a request either
         # lands in _waiting before the drain (and gets its sentinel
@@ -463,14 +532,18 @@ class LLMEngine:
         padded[:plen] = req.prompt
         return (req, slot, plen, padded)
 
-    def _dispatch_prefill(self, part: list, bucket: int):
+    def _dispatch_prefill(self, part: list, bucket: int, ph):
         """Hook: dispatch one prefill sub-batch (``part`` is a list of
-        (req, slot, plen, padded)); returns the device first-tokens."""
+        (req, slot, plen, padded)); returns the device first-tokens.
+        ``ph`` is the dispatch's span (``tracing.phase``): the paged
+        engine adds its window and prefix-cache counts."""
         tokens = jnp.asarray(np.stack([it[3] for it in part]))
         plens = jnp.asarray(np.array([it[2] for it in part], np.int32))
         slots = jnp.asarray(np.array([it[1] for it in part], np.int32))
         temps = jnp.asarray(np.array(
             [it[0].temperature for it in part], np.float32))
+        if ph:
+            ph.set(new_tokens=sum(it[2] for it in part), cached_tokens=0)
         self._cache, firsts = self._prefill_batch_fn(
             self.params, self._cache, tokens, plens, slots, temps,
             self._next_key(),
@@ -478,13 +551,20 @@ class LLMEngine:
         return firsts
 
     def _admit(self, first: "Request | None" = None):
+        with _tracing.phase("engine.admit", kind="serve") as ph:
+            admitted, dispatches = self._admit_round(first)
+            if ph:
+                ph.set(admitted=admitted, dispatches=dispatches,
+                       blocked=self._admission_blocked)
+
+    def _admit_round(self, first: "Request | None") -> tuple:
         """Prefill waiting requests into free slots. All prefills of the
         round are DISPATCHED first and their first tokens extracted in
         one host pass — each sync has a fixed cost, so a burst of
         admissions pays ~one, not one per request. ``first``: a request
         already pulled off the queue (the admission window's timed get)
         — admitted ahead of the queue, requeued on backpressure like any
-        other."""
+        other. Returns (requests admitted, prefill dispatches)."""
         admits = []   # (req, slot, plen, padded)
         self._admission_blocked = False
         pulled = first
@@ -517,7 +597,8 @@ class LLMEngine:
         if pulled is not None:
             self._waiting.put(pulled)   # no free slot took it
         if not admits:
-            return
+            return 0, 0
+        self._admitting = [item[0] for item in admits]
         # Group by bucket, then split each group into POWER-OF-TWO
         # sub-batches: one batched-prefill dispatch per sub-batch (a
         # 16-burst = 1 dispatch; 15 = 8+4+2+1 = 4) with one stacked
@@ -535,12 +616,18 @@ class LLMEngine:
                     m *= 2
                 part = items[i:i + m]
                 i += m
-                firsts = self._dispatch_prefill(part, bucket)
-                now = time.monotonic()
-                for it in part:
-                    it[0].dispatch_t = now
-                if self._ready_q is not None:
-                    self._ready_q.put((firsts, [it[0] for it in part]))
+                with _tracing.phase("engine.dispatch_prefill",
+                                    kind="serve") as ph:
+                    firsts = self._dispatch_prefill(part, bucket, ph)
+                    now = time.monotonic()
+                    seq = next(self._stream_seq)
+                    if ph:
+                        ph.set(seq=seq, group=len(part), bucket=bucket)
+                reqs = [it[0] for it in part]
+                for req in reqs:
+                    req.dispatch_t = now
+                self._ready_q.put(
+                    ("prefill", seq, firsts, now, reqs, ph or None))
                 batches.append((part, firsts))
         # ASYNC first tokens: scatter each batch's firsts into the
         # device last-token vector (so the very next decode chunk
@@ -572,7 +659,9 @@ class LLMEngine:
             # executes after this prefill on the device stream
             self._pending_firsts.append(
                 (self._dispatch_seq, part, firsts))
+        self._admitting = []
         self._dev_dirty = True   # active set / lengths changed
+        return len(admits), len(batches)
 
     def _drain_firsts(self, completed_seq: int | None = None):
         """Emit first tokens whose prefill results reached the host.
@@ -592,37 +681,57 @@ class LLMEngine:
                 keep.append((seq_at, part, firsts))
                 continue
             t_drain = time.monotonic()
-            vals = np.asarray(firsts)
+            with _tracing.phase("engine.wait_device", kind="serve",
+                                attrs={"what": "firsts"}):
+                vals = np.asarray(firsts)
             now = time.monotonic()
-            for (req, slot, plen, _), first in zip(part, vals):
-                req.drain_t = t_drain
-                req.first_token_t = now
-                self.ttfts.append(req.ttft)
-                bd = req.breakdown
-                if bd is not None:
-                    self.breakdowns.append(bd)
-                    if _metrics.enabled():
-                        for stage in _STAGES:
-                            self._h_stage[stage].observe(bd[f"{stage}_s"])
-                    if req.trace_ctx is not None \
-                            and req.submit_wall is not None:
-                        self._emit_trace_spans(req, bd)
-                self._emit(req, int(first))
+            with _tracing.phase("engine.emit", kind="serve") as ph:
+                finished = self.total_finished
+                for (req, slot, plen, _), first in zip(part, vals):
+                    req.drain_t = t_drain
+                    req.first_token_t = now
+                    self.ttfts.append(req.ttft)
+                    self._unpublished.append(req)
+                    self._emit(req, int(first))
+                if ph:
+                    ph.set(what="firsts", tokens=len(part),
+                           finished=self.total_finished - finished)
         self._pending_firsts = keep
+        self._publish_stamped()
+
+    def _publish_stamped(self):
+        """Publish the TTFT breakdown (stage histograms, trace spans) of
+        every request whose first token has gone out and whose
+        prefill the watcher has stamped. The loop thread and the watcher
+        wake on the same device event, so the stamp may be a moment
+        behind the token: such a request waits here for the loop's next
+        pass, and its stages are never made up."""
+        if not self._unpublished:
+            return
+        keep = []
+        for req in self._unpublished:
+            if req.ready_t is None:
+                keep.append(req)
+                continue
+            bd = req.breakdown
+            if _metrics.enabled():
+                for stage in _STAGES:
+                    self._h_stage[stage].observe(bd[f"{stage}_s"])
+            if req.trace_ctx is not None:
+                self._emit_trace_spans(req, bd)
+        self._unpublished = keep
 
     def _emit_trace_spans(self, req: Request, bd: dict):
         """The engine's span subtree for one traced request: an
         ``engine.request`` parent spanning submit -> first token
         (wall-anchored at the submit stamp, parented to the replica's
-        run span), with the four TTFT stages as SEQUENTIAL children.
-        ``breakdown`` clamps the stamps, so the children tile the parent
-        exactly — the waterfall shows queue_wait/prefill/pipeline_stall/
-        ship summing to the traced TTFT."""
-        ttft = req.ttft
-        if ttft is None:
-            return
+        run span, or the root of the request's own trace), with the five
+        TTFT stages as SEQUENTIAL children. ``breakdown`` clamps the
+        stamps, so the children tile the parent exactly — the waterfall
+        shows queue_wait/device_wait/prefill/pipeline_stall/ship summing
+        to the traced TTFT."""
         parent = _tracing.emit(
-            "engine.request", start=req.submit_wall, duration=ttft,
+            "engine.request", start=req.submit_wall, duration=req.ttft,
             parent=req.trace_ctx, kind="serve",
             attrs={"request_id": req.request_id,
                    "deployment": self.deployment_name,
@@ -659,9 +768,15 @@ class LLMEngine:
             timeout = deadline - time.monotonic()
             if timeout <= 0:
                 break
-            try:
-                req = self._waiting.get(timeout=timeout)
-            except queue.Empty:
+            with _tracing.phase("engine.wait_arrivals", kind="serve",
+                                attrs={"what": "window"}) as ph:
+                try:
+                    req = self._waiting.get(timeout=timeout)
+                except queue.Empty:
+                    req = None
+                if ph:
+                    ph.set(arrivals=int(req is not None))
+            if req is None:
                 break
             self._admit(first=req)
             admitted = True
@@ -697,15 +812,18 @@ class LLMEngine:
         except BaseException as e:  # noqa: BLE001 — propagate to callers
             self.error = e
         finally:
-            # Runs on BOTH error and clean stop(): every live stream and
-            # every waiter gets its sentinel, so no tokens() consumer can
-            # hang. Under _submit_lock so no request slips in after the
-            # drain (see submit()).
+            # Runs on BOTH error and clean stop(): every live stream,
+            # every waiter and every request the loop had taken off the
+            # queue when a prefill dispatch failed gets its sentinel, so
+            # no tokens() consumer can hang. Under _submit_lock so no
+            # request slips in after the drain (see submit()).
+            self._publish_stamped()
             with self._submit_lock:
                 self._stop.set()
-                for req in self._active:
-                    if req is not None:
-                        req.out.put(None)
+                live = {id(r): r for r in self._active if r is not None}
+                live.update((id(r), r) for r in self._admitting)
+                for req in live.values():
+                    req.out.put(None)
                 while True:
                     try:
                         self._waiting.get_nowait().out.put(None)
@@ -761,11 +879,12 @@ class LLMEngine:
             self._dev_dirty = False
         return self._dev_inputs
 
-    def _decode_call(self, chunk: int, last_tok, dev):
+    def _decode_call(self, chunk: int, last_tok, dev, ph):
         """Hook: run the compiled decode program for one chunk and
         return (token_matrix, advanced_lens, merged_last_tok) — the
         ONLY piece the paged engine overrides; the pipeline tail below
-        stays shared."""
+        stays shared. ``ph`` is the dispatch's span (the paged engine
+        adds its pages bucket)."""
         decode = (self._decode_fn_drain if chunk == self._drain_chunk
                   and self._decode_fn_drain is not self._decode_fn
                   else self._decode_fn)
@@ -782,26 +901,35 @@ class LLMEngine:
         program) both update it on device, so consecutive dispatches
         never need a host round trip no matter how the active set
         changed in between."""
-        drain = self._use_drain_chunk()
-        chunk = self._drain_chunk if drain else self.decode_chunk
-        dev = self._device_inputs(active_idx)
-        toks, lens, new_last = self._decode_call(chunk, self._last_dev,
-                                                 dev)
-        self._last_dev = new_last
-        dev["lens"] = lens   # stays on device for the chained chunk
-        # start the token matrix's device->host copy NOW: it overlaps
-        # the next chunk's compute instead of adding a serial RTT to
-        # every chunk sync
-        try:
-            toks.copy_to_host_async()
-        except Exception:  # noqa: BLE001 - backend without async copy
-            pass
-        # host mirror advances deterministically (+chunk per active
-        # slot) — retired slots are reconciled at admission
-        self._lengths[active_idx] += chunk
-        gens = [int(self._slot_gen[i]) for i in active_idx]
-        seq = self._dispatch_seq
-        self._dispatch_seq += 1
+        with _tracing.phase("engine.dispatch_decode", kind="serve") as ph:
+            drain = self._use_drain_chunk()
+            chunk = self._drain_chunk if drain else self.decode_chunk
+            reupload = self._dev_inputs is None or self._dev_dirty
+            dev = self._device_inputs(active_idx)
+            toks, lens, new_last = self._decode_call(
+                chunk, self._last_dev, dev, ph)
+            now = time.monotonic()
+            stream_seq = next(self._stream_seq)
+            if ph:
+                ph.set(seq=stream_seq, chunk=chunk, live=len(active_idx),
+                       slots=self.max_batch, drain=drain,
+                       reupload=reupload)
+            self._last_dev = new_last
+            dev["lens"] = lens   # stays on device for the chained chunk
+            # start the token matrix's device->host copy NOW: it overlaps
+            # the next chunk's compute instead of adding a serial RTT to
+            # every chunk sync
+            try:
+                toks.copy_to_host_async()
+            except Exception:  # noqa: BLE001 - backend without async copy
+                pass
+            # host mirror advances deterministically (+chunk per active
+            # slot) — retired slots are reconciled at admission
+            self._lengths[active_idx] += chunk
+            gens = [int(self._slot_gen[i]) for i in active_idx]
+            seq = self._dispatch_seq
+            self._dispatch_seq += 1
+        self._ready_q.put(("decode", stream_seq, toks, now, (), ph or None))
         return toks, active_idx, gens, chunk, seq
 
     def _emit_chunk(self, toks_np, active_idx, gens):
@@ -815,6 +943,41 @@ class LLMEngine:
                     break   # finished mid-chunk; drop surplus tokens
                 self._emit(req, int(toks_np[t, i]))
 
+    def _sync_chunk(self, toks, active_idx, gens, seq: int | None):
+        """Chunk N's host sync, then its tokens to their streams. Firsts
+        of prefills dispatched before the chunk (``seq``: before chunk
+        ``seq``; None: drained by the caller already) go out ahead of
+        it, so emission order per request is preserved."""
+        with _tracing.phase("engine.wait_device", kind="serve",
+                            attrs={"what": "chunk"}):
+            toks_np = np.asarray(toks)
+        now = time.monotonic()
+        if seq is not None:
+            self._drain_firsts(completed_seq=seq)
+        with _tracing.phase("engine.emit", kind="serve") as ph:
+            generated, finished = self.total_generated, self.total_finished
+            self._emit_chunk(toks_np, active_idx, gens)
+            if ph:
+                ph.set(what="chunk",
+                       tokens=self.total_generated - generated,
+                       finished=self.total_finished - finished)
+        return now
+
+    def _wait_idle(self):
+        """No live slot and nothing in flight: poll for arrivals every
+        millisecond, as ONE span however long the wait (an idle engine
+        must not fill the span ring)."""
+        with _tracing.phase("engine.wait_arrivals", kind="serve",
+                            attrs={"what": "idle"}) as ph:
+            while True:
+                self._on_idle()
+                self._publish_stamped()
+                time.sleep(0.001)
+                if self._stop.is_set() or not self._waiting.empty():
+                    break
+            if ph:
+                ph.set(arrivals=self._waiting.qsize())
+
     def _run_loop(self):
         """Double-buffered decode over a device-resident last-token
         vector: while chunk N's tokens copy back to the host and get
@@ -824,59 +987,67 @@ class LLMEngine:
         emitted asynchronously when their copy lands (_drain_firsts).
         Emission order per request is preserved: firsts dispatched
         before chunk N are force-drained right after chunk N's sync,
-        before the chunk's tokens are emitted."""
+        before the chunk's tokens are emitted.
+
+        Each pass is one ``engine.iteration`` span while spans are
+        recorded (``tracing.phase``), its phases its children: what the
+        children leave uncovered is host work no phase names."""
         pending = None   # (device_toks, active_idx, gens, chunk, seq)
         self._last_dev = jnp.asarray(self._last_tok)
-        while not self._stop.is_set():
-            self._admit()
+        for n in itertools.count():
+            if self._stop.is_set():
+                break
+            with _tracing.phase("engine.iteration", kind="serve",
+                                trace_id=self._trace_id) as ph:
+                if ph:
+                    ph.set(seq=n, waiting=self._waiting.qsize(),
+                           live=sum(r is not None for r in self._active))
+                pending = self._iteration(pending)
+
+    def _iteration(self, pending):
+        """One pass of the loop; returns the chunk left in flight."""
+        self._admit()
+        active_idx = [i for i, r in enumerate(self._active)
+                      if r is not None]
+        if not active_idx:
+            self._sync_t = None   # pipeline drains: period resets
+            if pending is not None:
+                toks, idxs, gens, _, seq = pending
+                self._sync_chunk(toks, idxs, gens, seq)
+            elif self._pending_firsts:
+                # every active request is brand-new and nothing is
+                # in flight (e.g. max_new_tokens=1 bursts): block
+                # for the outstanding firsts
+                self._drain_firsts(completed_seq=self._dispatch_seq)
+            else:
+                self._wait_idle()
+            return None
+        if pending is None:
+            return self._dispatch_decode(active_idx)
+        # continuous admission: requests arriving while `pending`
+        # computes are prefilled NOW, before the next chunk is
+        # dispatched behind them
+        if self._admission_window():
             active_idx = [i for i, r in enumerate(self._active)
                           if r is not None]
-            if not active_idx:
-                self._sync_t = None   # pipeline drains: period resets
-                if pending is not None:
-                    toks, idxs, gens, _, seq = pending
-                    pending = None
-                    toks_np = np.asarray(toks)
-                    self._drain_firsts(completed_seq=seq)
-                    self._emit_chunk(toks_np, idxs, gens)
-                    continue
-                if self._pending_firsts:
-                    # every active request is brand-new and nothing is
-                    # in flight (e.g. max_new_tokens=1 bursts): block
-                    # for the outstanding firsts
-                    self._drain_firsts(completed_seq=self._dispatch_seq)
-                    continue
-                self._on_idle()
-                time.sleep(0.001)
-                continue
-            if pending is None:
-                pending = self._dispatch_decode(active_idx)
-                continue
-            # continuous admission: requests arriving while `pending`
-            # computes are prefilled NOW, before the next chunk is
-            # dispatched behind them
-            if self._admission_window():
-                active_idx = [i for i, r in enumerate(self._active)
-                              if r is not None]
-            nxt = self._dispatch_decode(active_idx)
-            toks_prev, idx_prev, gens_prev, _, seq_prev = pending
-            # EVERY pending prefill was dispatched before nxt: block for
-            # their firsts now (bounded by chunk N + prefill compute —
-            # chunk N+1 is already queued behind them, so this wait
-            # steals no device time) and emit them FIRST. Waiting for
-            # the next chunk's sync instead cost a whole extra chunk of
-            # first-token latency.
-            self._drain_firsts(completed_seq=self._dispatch_seq)
-            toks_np = np.asarray(toks_prev)     # chunk N host sync
-            now = time.monotonic()
-            if self._sync_t is not None:
-                period = now - self._sync_t
-                self._chunk_period = (
-                    period if self._chunk_period is None
-                    else 0.5 * self._chunk_period + 0.5 * period)
-            self._sync_t = now
-            self._emit_chunk(toks_np, idx_prev, gens_prev)
-            pending = nxt
+        nxt = self._dispatch_decode(active_idx)
+        toks_prev, idx_prev, gens_prev, _, _ = pending
+        # EVERY pending prefill was dispatched before nxt: block for
+        # their firsts now (bounded by chunk N + prefill compute —
+        # chunk N+1 is already queued behind them, so this wait
+        # steals no device time) and emit them FIRST. Waiting for
+        # the next chunk's sync instead cost a whole extra chunk of
+        # first-token latency.
+        self._drain_firsts(completed_seq=self._dispatch_seq)
+        sync_t = self._sync_t
+        now = self._sync_chunk(toks_prev, idx_prev, gens_prev, None)
+        if sync_t is not None:
+            period = now - sync_t
+            self._chunk_period = (
+                period if self._chunk_period is None
+                else 0.5 * self._chunk_period + 0.5 * period)
+        self._sync_t = now
+        return nxt
 
     # -- metrics -----------------------------------------------------------
 
@@ -889,16 +1060,6 @@ class LLMEngine:
             "total_finished": self.total_finished,
             "mean_ttft_s": float(np.mean(self.ttfts)) if self.ttfts else None,
         }
-        if self.breakdowns:
-            bs = list(self.breakdowns)
-            out["ttft_breakdown_s"] = {
-                k: float(np.mean([b[k] for b in bs]))
-                for k in ("queue_wait_s", "prefill_s",
-                          "pipeline_stall_s", "ship_s")}
-            total = sum(out["ttft_breakdown_s"].values())
-            if total > 0:
-                out["queue_wait_share"] = (
-                    out["ttft_breakdown_s"]["queue_wait_s"] / total)
         return out
 
 

@@ -47,7 +47,7 @@ from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
                                          dequantize_kv, page_hashes,
                                          quantize_kv)
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
-from ray_tpu.serve.llm import LLMEngine, _bucket
+from ray_tpu.serve.llm import LLMEngine, _bucket, _named_jit
 
 
 def _write_gather_kv(kp, vp, ks, vs, k_new, v_new, pidx, ip, table_c,
@@ -166,7 +166,8 @@ class PagedLLMEngine(LLMEngine):
         key = (chunk, pages_bucket)
         fn = self._decode_cache.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = _named_jit(
+                f"paged_decode_c{chunk}_w{pages_bucket}",
                 partial(self._paged_decode_impl, self.cfg, chunk=chunk,
                         page_size=self.page_size,
                         quantized=self.kv_dtype == "int8"),
@@ -181,7 +182,8 @@ class PagedLLMEngine(LLMEngine):
         start + suffix)."""
         fn = self._prefill_cache.get(window_pages)
         if fn is None:
-            fn = jax.jit(
+            fn = _named_jit(
+                f"paged_prefill_w{window_pages}",
                 partial(self._paged_prefill_impl, self.cfg,
                         page_size=self.page_size,
                         quantized=self.kv_dtype == "int8"),
@@ -352,8 +354,9 @@ class PagedLLMEngine(LLMEngine):
             pb *= 2
         return min(pb, self.max_pages_per_seq)
 
-    def _decode_call(self, chunk: int, last_tok, dev):
+    def _decode_call(self, chunk: int, last_tok, dev, ph):
         pb = self._pages_bucket()
+        ph.set(pages=pb)
         fn = self._decode_paged(chunk, pb)
         key = ("table", pb)
         if key not in dev:
@@ -444,12 +447,22 @@ class PagedLLMEngine(LLMEngine):
         padded[:len(suffix)] = suffix
         return (req, slot, plen, padded)
 
-    def _dispatch_prefill(self, part: list, bucket: int):
+    def _dispatch_prefill(self, part: list, bucket: int, ph):
         tokens = jnp.asarray(np.stack([it[3] for it in part]))
         starts_np = np.array([self._prefix_len[it[1]] for it in part],
                              np.int32)
         slens_np = np.array([it[2] for it in part], np.int32) - starts_np
         wp = self._window_pages(int((starts_np + slens_np).max()))
+        if ph:
+            # what the prefix cache gave this dispatch, counted as its
+            # lookups were (PrefixCache.acquire): the full pages before
+            # a prompt's last token, those reused and those missed
+            page, cached = self.page_size, int(starts_np.sum())
+            lookups = (sum((it[2] - 1) // page for it in part)
+                       if self._prefix_enabled else 0)
+            ph.set(window_pages=wp, new_tokens=int(slens_np.sum()),
+                   cached_tokens=cached,
+                   missed_pages=lookups - cached // page)
         prefill = self._prefill_paged(wp)
         slens = jnp.asarray(slens_np)
         rows = jnp.asarray(np.stack(

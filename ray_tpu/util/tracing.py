@@ -11,7 +11,7 @@ land in a per-process bounded ring drained by the MetricsPusher into the
 GCS :class:`TraceStore` (same drop-not-block contract as metric frames),
 with an optional JSONL file exporter kept for local runs.
 
-Four cooperating pieces:
+Five cooperating pieces:
 
 - **Propagation** — ``submission_context``/``execution_span`` thread
   context through task specs (tasks + actor calls); ``wire_context``/
@@ -28,6 +28,12 @@ Four cooperating pieces:
 - **Stuck-call watchdog** — ``call_started``/``call_finished`` maintain
   an in-flight registry (RPCs, pulls, leases) surfaced through
   ``local_stuck_calls`` / ``util.state.stuck_calls``.
+- **Device plane** — ``phase`` is ``span`` for a loop that feeds a
+  device (the serving engine's): on while ``recording()``, which adds
+  "a ``jax.profiler`` session is live in this process" to
+  ``is_enabled()``, and entered as a ``TraceAnnotation`` too, so the
+  loop's phases lie in the profiler's trace on the device's clock
+  (docs/tracing_plane.md §1a). ``recorded_spans`` reads them back.
 
 Usage:
     ray_tpu.util.tracing.enable_tracing()          # collected plane
@@ -50,6 +56,7 @@ import json
 import logging
 import os
 import signal
+import sys
 import tempfile
 import threading
 import time
@@ -223,67 +230,15 @@ def _emit(record: dict) -> None:
     _file_sink(record)
 
 
-@contextlib.contextmanager
-def span(name: str, *, kind: str = "local",
-         parent: SpanContext | None = None,
-         attrs: dict | None = None):
-    """Record one span; inside the block, the ambient context points at
-    it (children created here parent to it). An escaping exception marks
-    the span ``error`` (tail-based retention keeps such traces)."""
-    if not is_enabled():
-        yield None
-        return
-    if parent is None:
-        parent = _current.get()
-    ctx = SpanContext(
-        trace_id=parent.trace_id if parent else uuid.uuid4().hex[:16],
-        span_id=uuid.uuid4().hex[:16],
-    )
-    token = _current.set(ctx)
-    start = time.time()
-    error = False
-    try:
-        yield ctx
-    except BaseException:
-        error = True
-        raise
-    finally:
-        _current.reset(token)
-        rec = {
-            "name": name,
-            "trace_id": ctx.trace_id,
-            "span_id": ctx.span_id,
-            "parent_id": parent.span_id if parent else None,
-            "start": start,
-            "duration": time.time() - start,
-            "pid": os.getpid(),
-            "kind": kind,
-        }
-        if attrs:
-            rec["attrs"] = attrs
-        if error:
-            rec["error"] = True
-        _emit(rec)
-
-
-def emit(name: str, *, start: float, duration: float,
-         parent: SpanContext | None = None, kind: str = "local",
-         attrs: dict | None = None,
-         ctx: SpanContext | None = None) -> SpanContext:
-    """Emit one already-timed span (the serve engine stamps queue_wait /
-    prefill / pipeline_stall from its own monotonic breakdown and emits
-    them after the fact). Returns the span's context so stage children
-    can parent to it."""
-    if ctx is None:
-        ctx = SpanContext(
-            trace_id=parent.trace_id if parent else uuid.uuid4().hex[:16],
-            span_id=uuid.uuid4().hex[:16],
-        )
+def _record(name: str, ctx: SpanContext, parent_id: str | None,
+            start: float, duration: float, kind: str, attrs: dict | None,
+            error: bool = False) -> None:
+    """One finished span into the rings (and the file sink)."""
     rec = {
         "name": name,
         "trace_id": ctx.trace_id,
         "span_id": ctx.span_id,
-        "parent_id": parent.span_id if parent else None,
+        "parent_id": parent_id,
         "start": start,
         "duration": duration,
         "pid": os.getpid(),
@@ -291,7 +246,143 @@ def emit(name: str, *, start: float, duration: float,
     }
     if attrs:
         rec["attrs"] = attrs
+    if error:
+        rec["error"] = True
     _emit(rec)
+
+
+# ``jax.profiler.TraceAnnotation``, once JAX is there to ask (this module
+# never imports it: most processes that trace never touch a device)
+_annotation = None
+
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` session is live in this process: one
+    atomic read. A process that has not imported JAX cannot be
+    profiling."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+        if _annotation is None:
+            return False
+    return _annotation.is_enabled()
+
+
+def recording() -> bool:
+    """The rule for "on" where the host feeds a device (``phase``):
+    ``is_enabled()``, or a ``jax.profiler`` session live in this process.
+    Whoever profiles a process (an operator tracing a replica,
+    ``benchmark/run.py --trace 1``) gets its device-feeding loop's spans
+    for exactly the profiled slice, with no switch to set."""
+    return is_enabled() or _profiling()
+
+
+class LiveSpan(SpanContext):
+    """A span being timed, and its own context: inside its ``with`` block
+    the ambient context points at it, so spans opened there parent to it.
+    ``set`` adds counts to its ``attrs`` until the block ends. While a
+    ``jax.profiler`` session is live it also enters a ``TraceAnnotation``
+    of the same name, so the span lies in the profiler's trace on the
+    clock of the device events. An escaping exception marks the record
+    ``error`` (tail-based retention keeps such traces)."""
+
+    def __init__(self, name: str, kind: str,
+                 parent: SpanContext | None, attrs: dict | None,
+                 trace_id: str | None = None):
+        if parent is None and trace_id is None:
+            parent = _current.get()
+        super().__init__(
+            trace_id=(parent.trace_id if parent
+                      else trace_id or uuid.uuid4().hex[:16]),
+            span_id=uuid.uuid4().hex[:16])
+        self.name, self.kind = name, kind
+        self.parent_id = parent.span_id if parent else None
+        self.attrs = attrs
+
+    def set(self, **counts) -> None:
+        if self.attrs is None:
+            self.attrs = counts
+        else:
+            self.attrs.update(counts)
+
+    def __enter__(self) -> "LiveSpan":
+        self._annotated = None
+        if _profiling():
+            self._annotated = _annotation(self.name)
+            self._annotated.__enter__()
+        self._token = _current.set(self)
+        self._start = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        duration = time.time() - self._start
+        _current.reset(self._token)
+        if self._annotated is not None:
+            self._annotated.__exit__(exc_type, exc, tb)
+        _record(self.name, self, self.parent_id, self._start, duration,
+                self.kind, self.attrs, error=exc_type is not None)
+        return False
+
+
+class _NoPhase:
+    """What ``phase`` hands back while nothing records: falsy, so a
+    caller skips working out counts nobody will read."""
+
+    def __bool__(self) -> bool:
+        return False
+
+    def __enter__(self) -> "_NoPhase":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **counts) -> None:
+        pass
+
+
+_NO_SPAN = contextlib.nullcontext()
+_NO_PHASE = _NoPhase()
+
+
+def span(name: str, *, kind: str = "local",
+         parent: SpanContext | None = None,
+         attrs: dict | None = None):
+    """Record one span around a ``with`` block (see ``LiveSpan``); the
+    block gets the span's context, or None with tracing off."""
+    if not is_enabled():
+        return _NO_SPAN
+    return LiveSpan(name, kind, parent, attrs)
+
+
+def phase(name: str, *, kind: str = "local",
+          parent: SpanContext | None = None, trace_id: str | None = None,
+          attrs: dict | None = None):
+    """One phase of a loop that feeds a device, as a ``LiveSpan`` while
+    ``recording()`` and at the cost of that one check otherwise. The
+    block always gets an object with ``set`` (counts known only at the
+    phase's end), falsy when nothing records. ``trace_id`` makes a root
+    span of a given trace (one trace id an engine)."""
+    if not recording():
+        return _NO_PHASE
+    return LiveSpan(name, kind, parent, attrs, trace_id)
+
+
+def emit(name: str, *, start: float, duration: float,
+         parent: SpanContext | None = None, kind: str = "local",
+         attrs: dict | None = None) -> SpanContext:
+    """Emit one already-timed span (the serve engine stamps a request's
+    TTFT stages and, from its watcher thread, the device's runs, and
+    emits them after the fact). Returns the span's context so children
+    can parent to it."""
+    ctx = SpanContext(
+        trace_id=parent.trace_id if parent else uuid.uuid4().hex[:16],
+        span_id=uuid.uuid4().hex[:16],
+    )
+    # a parent with no span id is the root of a trace of its own
+    _record(name, ctx, (parent.span_id or None) if parent else None,
+            start, duration, kind, attrs)
     return ctx
 
 
@@ -480,12 +571,21 @@ def flight_snapshot(last_s: float | None = None) -> dict:
 def local_trace(trace_id: str) -> list[dict]:
     """Spans of one trace still in the local flight ring (local-mode
     ``util.state.get_trace`` backend)."""
+    return sorted((r for r in recorded_spans()
+                   if r.get("trace_id") == trace_id),
+                  key=lambda r: r["start"])
+
+
+def recorded_spans(prefix: str = "") -> list[dict]:
+    """Finished spans still in this process's flight ring whose name
+    starts with ``prefix``, oldest first. Reading takes nothing away
+    (the pusher drains the other ring), so a run can be read once it has
+    ended: the benchmark's per-layer readers do."""
     _, flight = _rings()
     with _ring_lock:
         records = list(flight)
-    return sorted((r for r in records
-                   if "event" not in r and r.get("trace_id") == trace_id),
-                  key=lambda r: r["start"])
+    return [r for r in records
+            if "event" not in r and r["name"].startswith(prefix)]
 
 
 def dump_flight(path: str | None = None, last_s: float | None = None) -> str:
@@ -547,16 +647,9 @@ def submission_context(function_name: str) -> dict | None:
         trace_id=parent.trace_id if parent else uuid.uuid4().hex[:16],
         span_id=uuid.uuid4().hex[:16],
     )
-    _emit({
-        "name": f"submit:{function_name}",
-        "trace_id": ctx.trace_id,
-        "span_id": ctx.span_id,
-        "parent_id": parent.span_id if parent else None,
-        "start": time.time(),
-        "duration": 0.0,
-        "pid": os.getpid(),
-        "kind": "submit",
-    })
+    _record(f"submit:{function_name}", ctx,
+            parent.span_id if parent else None, time.time(), 0.0, "submit",
+            None)
     wire = ctx.to_dict()
     # Cluster-mode workers are spawned by the RAYLET, whose environ never
     # saw the driver's enable_tracing() — so the trace dir must ride the

@@ -553,11 +553,20 @@ def test_serve_request_is_one_trace(ray_tpu_start, tmp_path):
             by_name.setdefault(s["name"], s)
         for required in ("serve.request:llm_traced", "serve.route",
                          "engine.request", "engine.queue_wait",
-                         "engine.prefill"):
+                         "engine.device_wait", "engine.prefill"):
             assert required in by_name, sorted(by_name)
-        # ONE trace: every serve/engine span shares the request root
+        # ONE trace: every serve span and the request's engine spans
+        # share the request root (the engine loop's own phases,
+        # engine.iteration and its children, are the engine's trace)
         tid = by_name["serve.request:llm_traced"]["trace_id"]
-        assert {s["trace_id"] for s in spans} == {tid}
+        loop = {s["trace_id"] for s in spans
+                if s["name"] == "engine.iteration"}
+        assert len(loop) == 1 and tid not in loop
+        spans = [s for s in spans if s["trace_id"] == tid]
+        assert {s["name"] for s in spans} >= set(by_name) - {
+            "engine.iteration", "engine.admit", "engine.dispatch_prefill",
+            "engine.dispatch_decode", "engine.wait_arrivals",
+            "engine.wait_device", "engine.emit"}
         # the replica-side run span is in the same trace too
         run = [s for s in tracing.read_spans(str(tmp_path))
                if s["trace_id"] == tid and s["name"].startswith("run:")]
