@@ -108,9 +108,8 @@ def fsdp4_config():
 
 KERNEL_SHAPE = dict(batch=2, seq=2048, heads=32, kv_heads=8, head_dim=128)
 TRAIN_STEPS = 6
-# the engine's prefill program holds the page pool twice (its layer scan
-# writes a new pool beside the donated one): 5.6 GB of weights + 2 x 3.8
-# GB + ~1.6 GB of temporaries is what the chip's compiler counts
+# 5.6 GB of weights + 3.8 GB of pages, and the prefill program's
+# temporaries (the pool itself stays in place: it is not among them)
 SERVE = dict(num_pages=896, max_batch=8, max_len=2048, new_tokens=32,
              prompts=(64, 64, 300, 640, 640, 1024), shared_prefix=512)
 

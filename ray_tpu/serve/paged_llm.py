@@ -27,6 +27,16 @@ continuous-batching loop of ``serve/llm.py``:
   (long contexts / many concurrent slots); at small windows where
   decode is weight-read-bound it measures ~35% slower (v5e, 0.5B).
 
+- The device programs keep the pools IN PLACE: the layer loop carries
+  the stacked pools (and scale pools) whole, beside the activations,
+  and scans over (layer weights, layer index); a layer scatters its new
+  rows at [layer, page, offset] and gathers its page window at
+  [layer, table] (``_write_gather_kv``). Scanning OVER the pools
+  instead hands each layer a slice: XLA then copies every layer's K
+  and V pool out and back, every layer of every step, and the prefill
+  program holds a second pool (measured on a v5e at 12 layers x 544
+  pages: 43% of the device's time, 5.9 GB of HBM).
+
 Engine mechanics (queues, continuous batching, chunked + pipelined
 decode, metrics) are inherited from ``LLMEngine``.
 """
@@ -50,26 +60,35 @@ from ray_tpu.ops.rope import apply_rope, rope_sin_cos
 from ray_tpu.serve.llm import LLMEngine, _bucket, _named_jit
 
 
-def _write_gather_kv(kp, vp, ks, vs, k_new, v_new, pidx, ip, table_c,
-                     quantized):
+def _write_gather_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip,
+                     table_c, quantized):
     """THE write-then-gather KV protocol, shared by decode and prefill
     (shape-generic: decode writes one token per slot with [B] indices,
-    prefill a padded suffix with [n, T] indices). Writes k/v (+ scales
-    in int8 mode) at (pidx, ip) with out-of-bounds indices dropping,
-    then gathers the table_c page window, dequantizing if quantized."""
+    prefill a padded suffix with [n, T] indices), on the STACKED pools
+    [L, P, page, nkv, hd] (+ scale pools in int8 mode) at layer
+    ``layer``. Writes k/v at (layer, pidx, ip) with out-of-bounds
+    indices dropping, then gathers layer ``layer``'s table_c page
+    window, dequantizing if quantized.
+
+    The pools come in whole and go out whole: all that is written is
+    the new rows (a scatter, in place on the buffer the layer loop
+    carries), all that is read is the page window. Write before gather,
+    so the window holds the rows just written."""
     if quantized:
         kq, ksc = quantize_kv(k_new)
         vq, vsc = quantize_kv(v_new)
-        kp = kp.at[pidx, ip].set(kq, mode="drop")
-        vp = vp.at[pidx, ip].set(vq, mode="drop")
-        ks = ks.at[pidx, ip].set(ksc, mode="drop")
-        vs = vs.at[pidx, ip].set(vsc, mode="drop")
-        kg = dequantize_kv(kp[table_c], ks[table_c])
-        vg = dequantize_kv(vp[table_c], vs[table_c])
+        kp = kp.at[layer, pidx, ip].set(kq, mode="drop")
+        vp = vp.at[layer, pidx, ip].set(vq, mode="drop")
+        ks = ks.at[layer, pidx, ip].set(ksc, mode="drop")
+        vs = vs.at[layer, pidx, ip].set(vsc, mode="drop")
+        kg = dequantize_kv(kp[layer, table_c], ks[layer, table_c])
+        vg = dequantize_kv(vp[layer, table_c], vs[layer, table_c])
     else:
-        kp = kp.at[pidx, ip].set(k_new.astype(kp.dtype), mode="drop")
-        vp = vp.at[pidx, ip].set(v_new.astype(vp.dtype), mode="drop")
-        kg, vg = kp[table_c], vp[table_c]
+        kp = kp.at[layer, pidx, ip].set(k_new.astype(kp.dtype),
+                                        mode="drop")
+        vp = vp.at[layer, pidx, ip].set(v_new.astype(vp.dtype),
+                                        mode="drop")
+        kg, vg = kp[layer, table_c], vp[layer, table_c]
     return kp, vp, ks, vs, kg, vg
 
 
@@ -207,12 +226,16 @@ class PagedLLMEngine(LLMEngine):
         gathered through the (bucketed) page table [B, PB]. In int8
         mode (``quantized``) writes quantize per token+head and gathers
         dequantize against the scale pages — half the KV bytes per
-        step."""
+        step. Two nested scans: over steps, carrying the pools, last
+        tokens, lengths and key; inside it over layers, carrying the
+        activations and the same stacked pools (module docstring: in
+        place), scanning over the layers' weights and indices."""
         num_pages = k_pages.shape[1]
         b, pb = table.shape
         s = pb * page_size
         scale = cfg.head_dim ** -0.5
         table_c = jnp.maximum(table, 0)
+        layers = jnp.arange(k_pages.shape[0])
 
         def one_step(carry, _):
             k_pages, v_pages, k_scale, v_scale, toks, lens, key = carry
@@ -228,8 +251,9 @@ class PagedLLMEngine(LLMEngine):
             pidx = jnp.where((pidx >= 0) & active, pidx, num_pages)
             ip = pos % page_size
 
-            def block(x, xs):
-                p, kp, vp, ks, vs = xs
+            def block(carry, xs):
+                x, kp, vp, ks, vs = carry
+                p, layer = xs
                 h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
                 q = (h @ p["wq"]).reshape(b, 1, cfg.n_heads, cfg.head_dim)
                 k = (h @ p["wk"]).reshape(b, 1, cfg.n_kv_heads,
@@ -239,7 +263,7 @@ class PagedLLMEngine(LLMEngine):
                 q = apply_rope(q, sin, cos)
                 k = apply_rope(k, sin, cos)
                 kp, vp, ks, vs, kg, vg = _write_gather_kv(
-                    kp, vp, ks, vs, k[:, 0], v[:, 0], pidx, ip,
+                    kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
                     table_c, quantized)
                 # this slot's window [B, PB, page, nkv, hd]
                 kg = kg.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
@@ -249,11 +273,11 @@ class PagedLLMEngine(LLMEngine):
                 h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
                 gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
                 x = x + gated @ p["w_down"]
-                return x, (kp, vp, ks, vs)
+                return (x, kp, vp, ks, vs), None
 
-            x, (k_pages, v_pages, k_scale, v_scale) = jax.lax.scan(
-                block, x,
-                (params["blocks"], k_pages, v_pages, k_scale, v_scale))
+            (x, k_pages, v_pages, k_scale, v_scale), _ = jax.lax.scan(
+                block, (x, k_pages, v_pages, k_scale, v_scale),
+                (params["blocks"], layers))
             x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)[:, 0]
             head = llama.lm_head_weights(cfg, params)
             logits = jnp.einsum("bd,dv->bv", x, head,
@@ -283,7 +307,9 @@ class PagedLLMEngine(LLMEngine):
         written into the pages first, then attention runs over the
         row's whole gathered page window, so suffix queries see the
         reused prefix KV exactly as the original prompt computed it.
-        table_rows: [n, max_pages_per_seq]."""
+        table_rows: [n, max_pages_per_seq]. The layer scan carries the
+        activations and the stacked pools, as decode's does: the
+        program holds one pool, the donated one."""
         num_pages = k_pages.shape[1]
         n, t = tokens.shape
         mp = table_rows.shape[1]
@@ -302,8 +328,9 @@ class PagedLLMEngine(LLMEngine):
         ip_all = positions % page_size
         table_c = jnp.maximum(table_rows, 0)
 
-        def block(x, xs):
-            p, kp, vp, ks, vs = xs
+        def block(carry, xs):
+            x, kp, vp, ks, vs = carry
+            p, layer = xs
             h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
             q = (h @ p["wq"]).reshape(n, t, cfg.n_heads, cfg.head_dim)
             k = (h @ p["wk"]).reshape(n, t, cfg.n_kv_heads, cfg.head_dim)
@@ -311,7 +338,7 @@ class PagedLLMEngine(LLMEngine):
             q = apply_rope(q, sin, cos)
             k = apply_rope(k, sin, cos)
             kp, vp, ks, vs, kg, vg = _write_gather_kv(
-                kp, vp, ks, vs, k, v, pidx_all, ip_all, table_c,
+                kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, table_c,
                 quantized)
             # gather the whole window AFTER the suffix writes: queries
             # attend over cached prefix + their own fresh KV; positions
@@ -324,11 +351,11 @@ class PagedLLMEngine(LLMEngine):
             h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
             gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
             x = x + gated @ p["w_down"]
-            return x, (kp, vp, ks, vs)
+            return (x, kp, vp, ks, vs), None
 
-        x, (k_pages, v_pages, k_scale, v_scale) = jax.lax.scan(
-            block, x, (params["blocks"], k_pages, v_pages, k_scale,
-                       v_scale))
+        (x, k_pages, v_pages, k_scale, v_scale), _ = jax.lax.scan(
+            block, (x, k_pages, v_pages, k_scale, v_scale),
+            (params["blocks"], jnp.arange(k_pages.shape[0])))
         x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
         x = jnp.take_along_axis(
             x, (slens - 1)[:, None, None], axis=1).squeeze(1)

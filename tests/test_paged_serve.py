@@ -31,8 +31,12 @@ def tiny():
 def _run(engine, prompts, max_new=16):
     # submit BEFORE start: admission happens in ONE deterministic wave
     # (thread timing otherwise splits waves, changing which prefill
-    # program — and therefore which rounding — each request sees)
-    reqs = [engine.submit(p, max_new_tokens=max_new) for p in prompts]
+    # program — and therefore which rounding — each request sees).
+    # max_new: one budget for all, or one a prompt
+    budgets = ([max_new] * len(prompts) if isinstance(max_new, int)
+               else max_new)
+    reqs = [engine.submit(p, max_new_tokens=n)
+            for p, n in zip(prompts, budgets)]
     engine.start()
     outs = [list(r.tokens()) for r in reqs]
     return reqs, outs
@@ -316,3 +320,49 @@ def test_int8_kv_with_prefix_cache(tiny):
     eng.stop()
     assert a == want and b == want
     assert st["prefix_cache"]["hit_pages"] >= 2
+
+
+@pytest.mark.parametrize("prefix_cache", [True, False],
+                         ids=["reuse", "no_reuse"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_matches_dense_through_slot_refill(tiny, monkeypatch,
+                                                 kv_dtype, prefix_cache):
+    """Five requests over two slots with unequal budgets: slots retire
+    at different chunks and are refilled from the queue while the other
+    still decodes, three prompts share a two-page prefix (reused or
+    prefilled again), and the pool is small enough that freed pages are
+    handed out again. The stacked pools are written and gathered at
+    [layer, page] inside the layer loop: every layer's rows must land in
+    that layer's pages, in bf16 and through the int8 scale pools, or the
+    greedy tokens leave the dense engine's. For int8 the dense engine
+    rounds each new K and V row as the int8 pages do (plain int8 against
+    bf16 flips near-tie tokens in most draws of the prompts)."""
+    from ray_tpu.models import decoding
+    from ray_tpu.ops.paged_attention import dequantize_kv, quantize_kv
+
+    cfg, params = tiny
+    if kv_dtype == "int8":
+        write = decoding._write_cache
+        monkeypatch.setattr(
+            decoding, "_write_cache", lambda cache, new, start: write(
+                cache, dequantize_kv(*quantize_kv(new), cache.dtype), start))
+    rng = np.random.default_rng(11)
+    base = rng.integers(1, cfg.vocab_size, 64)     # 2 full pages @ ps=32
+    prompts = [np.concatenate([base, rng.integers(1, cfg.vocab_size, n)])
+               for n in (9, 30)]
+    prompts += [rng.integers(1, cfg.vocab_size, n) for n in (21, 50)]
+    prompts.append(np.concatenate([base, rng.integers(1, cfg.vocab_size, 3)]))
+    budgets = [5, 20, 9, 14, 7]
+
+    dense = LLMEngine(cfg=cfg, params=params, max_batch=2, max_len=128)
+    _, want = _run(dense, prompts, budgets)
+    dense.stop()
+    paged = PagedLLMEngine(
+        cfg=cfg, params=params, max_batch=2, max_len=128, page_size=32,
+        num_pages=10, kv_dtype=kv_dtype, prefix_cache=prefix_cache)
+    _, got = _run(paged, prompts, budgets)
+    st = paged.stats()
+    paged.stop()
+    assert [len(o) for o in got] == budgets
+    assert got == want
+    assert (st["prefix_cache"]["hit_pages"] >= 2) == prefix_cache

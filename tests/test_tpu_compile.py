@@ -10,6 +10,8 @@ trainers are steered to the flash kernel from here (``attn_impl``).
 
 import dataclasses
 import os
+import re
+from functools import partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs to /tmp
 
@@ -21,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from ray_tpu.models import llama
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel.mesh import create_mesh
+from ray_tpu.serve.paged_llm import PagedLLMEngine
 from ray_tpu.train.trainer import JaxTrainer, TrainConfig
 
 
@@ -107,3 +110,71 @@ def test_fsdp4_step_compiles_with_flash(v5e_2x2):
     text = _compile_step(trainer, 4, 2048).as_text()
     assert text.count("tpu_custom_call") == 3
     assert "all-gather" in text       # the sharded parameters, gathered
+
+
+# the serving cells' engine: Mistral-7B-v0.3 widths cut to 12 layers, 32
+# slots x 2048 tokens, 544 KV pages of 128 tokens (142.6 MB a layer's K)
+_D12 = dict(vocab_size=32768, d_model=4096, n_layers=12, n_heads=32,
+            n_kv_heads=8, head_dim=128, d_ff=14336, rope_theta=1e6,
+            tie_embeddings=False)
+_D12_PAGES, _D12_PAGE, _D12_SLOTS = 544, 128, 32
+# an operation that moves one layer's pool, or the stacked pool, whole
+_POOL_COPY = re.compile(
+    r"= bf16\[(?:12,|1,)?544,128,8,128\]\S* "
+    r"(?:copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+# program, its dimensions (decode: chunk, window pages; prefill: prompts,
+# tokens, window pages), GB of temporaries it may need
+_D12_PROGRAMS = [
+    ("decode", (16, 16), 0.8), ("decode", (8, 16), 0.8),
+    ("prefill", (2, 2048, 16), 3.4), ("prefill", (4, 1024, 16), 3.4),
+    ("prefill", (4, 1024, 8), 3.4), ("prefill", (1, 2048, 16), 3.4),
+    ("prefill", (4, 2048, 16), 4.5)]
+
+
+@pytest.mark.parametrize(
+    "program,dims,temp_gb", _D12_PROGRAMS,
+    ids=[f"{p}-{'x'.join(map(str, d))}" for p, d, _ in _D12_PROGRAMS])
+def test_d12_engine_programs_keep_the_pool_in_place(v5e_2x2, program, dims,
+                                                    temp_gb):
+    """The paged engine's layer loop carries the stacked page pools and
+    writes and gathers at [layer, page]: the compiled programs hold no
+    copy, slice or update-slice the size of a layer's pool (a scan OVER
+    the pools sliced each layer's K and V pool out and wrote it back,
+    every layer of every step), and the prefill programs no second pool:
+    their temporaries stay under one pool's 3.4 GB (with a second pool
+    they are 5.1-8.1 GB), and four 2048-token prompts, which the
+    compiler then refuses for HBM, fit at 4.4 GB."""
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    cfg = llama.LlamaConfig(**_D12)
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(partial(llama.init_params, cfg), jax.random.key(0)))
+    pool = shape((cfg.n_layers, _D12_PAGES, _D12_PAGE, cfg.n_kv_heads,
+                  cfg.head_dim), jnp.bfloat16)
+    scale = shape((cfg.n_layers, 1, 1, 1), jnp.float32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    if program == "decode":
+        chunk, pages = dims
+        b = _D12_SLOTS
+        fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
+                     page_size=_D12_PAGE, quantized=False)
+        args = (shape((b, pages), jnp.int32), shape((b,), jnp.int32),
+                shape((b,), jnp.int32), shape((b,), jnp.bool_),
+                shape((b,), jnp.float32), key)
+    else:
+        n, tokens, pages = dims
+        fn = partial(PagedLLMEngine._paged_prefill_impl, cfg,
+                     page_size=_D12_PAGE, quantized=False)
+        args = (shape((n, pages), jnp.int32), shape((n, tokens), jnp.int32),
+                shape((n,), jnp.int32), shape((n,), jnp.int32),
+                shape((n,), jnp.float32), key)
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        params, pool, pool, scale, scale, *args).compile()
+    assert not _POOL_COPY.findall(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
