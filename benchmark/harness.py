@@ -3,13 +3,33 @@
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 ``BENCHMARK.json`` names the cell; the harness finds by name alone
-``benchmark/configs/<config>.json`` (the sizes, and the program's settings
-under ``system``), ``benchmark/traffic/<traffic>.json`` (which names a
-generator under ``benchmark/generators/`` and a runner under
-``benchmark/runners/``) and, for a traced run, one reader
-``benchmark/layer_metrics/<metric>.py`` per per-layer metric of the cell.
-A new configuration, mix, cell or per-layer metric is new files and new
-entries; no file that is there changes.
+``benchmark/configs/<config>.json`` (the sizes, the model family under
+``family``, and the program's settings under ``system``),
+``benchmark/traffic/<traffic>.json`` (which names a generator under
+``benchmark/generators/`` and a runner under ``benchmark/runners/``) and,
+for a traced run, one reader ``benchmark/layer_metrics/<metric>.py`` per
+per-layer metric of the cell. Whatever knows a model's block is found by
+the configuration's ``family`` and by nothing else:
+``benchmark/families/<family>.py`` holds the adapter to the program, the
+plain reference that decides ``correct`` and the operation and byte
+counts the readers divide by (``benchmark/families/__init__.py``); no
+other file names a family, a weight or a width of a block, and programs
+are told apart in a trace by their names alone.
+
+So what a later PR adds is new files and new entries, and no file that is
+there changes:
+
+- a model family: ``benchmark/families/<family>.py``;
+- a configuration: ``benchmark/configs/<config>.json`` and its entry
+  under ``configs``;
+- a traffic mix: ``benchmark/traffic/<traffic>.json``;
+- a per-layer metric: ``benchmark/layer_metrics/<metric>.py`` and its
+  entry under ``per_layer``, with the cells that report it under
+  ``workloads``;
+- a cell: its entry under ``workloads``, and the one thing that is not a
+  new entry: the cell's name appended to the ``workloads`` list of each
+  end-to-end metric it reports, and of each per-layer metric there
+  already that it reports. Nothing else of those entries moves.
 
 The last line of standard output is the result object and nothing else;
 what else is worth reading is printed on earlier lines. Off a TPU, or with
@@ -79,7 +99,7 @@ def cell_metrics(bench: dict, cell: str, group: str) -> list:
 def load_reader(name: str, root: str = ROOT):
     """A per-layer metric's reader, found by the metric's name: a file
     of that name, else of the name before its last suffix (one reader
-    serves ``decode_step_ms.chat`` and ``decode_step_ms.doc``)."""
+    serves ``decode_roofline.chat`` and ``decode_roofline.doc``)."""
     folder = os.path.join(root, "benchmark", "layer_metrics")
     path = os.path.join(folder, name + ".py")
     if not os.path.exists(path) and "." in name:

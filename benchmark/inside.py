@@ -1,27 +1,31 @@
 """Arithmetic of the per-layer metrics that measure the serving layers and
 the kernels from inside (PR 24): device programs and kernels found BY NAME
 in the reduced trace (``jit_paged_decode_c<chunk>_w<pages>``,
-``jit_paged_prefill_w<pages>``, ``flash_fwd`` / ``flash_dq`` /
-``flash_dkv``), and the engine loop's own spans (``program_spans``).
+``jit_paged_prefill_w<pages>``, ``jit__step``, ``flash_fwd`` /
+``flash_dq`` / ``flash_dkv``), and the engine loop's own spans
+(``program_spans``). The name is the only way a program is told from
+another: nothing here counts layers or loop passes, so a model with more
+than one layer loop reads like any other.
 Pure functions of a ``Trace`` or a list of span records, so the CPU tests
 run them on synthetic ones. Each returns None where there is nothing to
 read, or fewer than ``MIN_SAMPLES`` samples: a median of three is not one.
 
-The metrics of ``readers.py`` that time the same layers from outside
-(loop depth, the client's counters) stay; while both can be read they
-have to agree (PERF.md)."""
+``readers.py`` holds the readers of the client's counters and of the chip
+as a whole, and ``decode_roofline``, which divides a family's bytes by
+the step time read here."""
 
 from __future__ import annotations
 
 import re
 from collections import Counter
 
-from benchmark import readers, stats
+from benchmark import stats
 from benchmark.trace import KERNEL_TARGET
 
 MIN_SAMPLES = 5
 DECODE = re.compile(r"^jit_paged_decode_c(\d+)_w\d+\(")
 PREFILL = re.compile(r"^jit_paged_prefill_w\d+\(")
+TRAIN_STEP = re.compile(r"^jit__step\(")
 WAITS = ("engine.wait_device", "engine.wait_arrivals")
 STAGES = ("queue_wait", "device_wait", "prefill", "pipeline_stall", "ship")
 
@@ -67,7 +71,7 @@ def kernel_share(trace, kernels: tuple):
     ``name`` becomes its instruction's: ``%flash_fwd.6 = ...``)."""
     if trace is None or not trace.devices:
         return None
-    step, _ = trace.module_time(readers.TRAIN_STEP.match)
+    step, _ = trace.module_time(TRAIN_STEP.match)
 
     def mine(op: str) -> bool:
         return KERNEL_TARGET in op and op.lstrip("%").startswith(kernels)

@@ -5,9 +5,11 @@ span ring by ``ray_tpu.util.tracing``). With ``benchmark/systems.py`` the
 only place the benchmark touches the program; the arithmetic on what it
 returns is ``benchmark/inside.py``.
 
-The trace cannot give them: ``trace.Trace.from_file`` keeps only the
-benchmark's own host spans. A program from before PR 24 records none and
-has no accessor: then this returns None and every by-span reader with it."""
+The trace cannot give them whole: ``trace.Trace.from_file`` keeps the
+phases' names and times (they name the idle gaps of the breakdown), not
+their counts or which span caused which. A program from before PR 24
+records none and has no accessor: then this returns None and every
+by-span reader with it."""
 
 from __future__ import annotations
 

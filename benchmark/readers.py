@@ -1,20 +1,21 @@
-"""What the per-layer readers share. A reader is
+"""What the per-layer readers share, and the arithmetic of those that
+read the client's counters, the train step and the chip as a whole (the
+serving programs, the kernels by name and the engine's spans:
+``inside.py``). A reader is
 ``benchmark/layer_metrics/<metric name>.py`` with ``read(run)``; ``run`` is
 the harness's RunRecord: ``counters`` (what the runner counted and what it
 read from the program's counters), ``trace`` (the reduced device trace of
 a traced run on a chip, else None), ``config``, ``traffic``, ``device``.
 A reader that finds nothing to read returns None, and the harness leaves
 the metric out of the line. Every device number needs the trace: without
-one (a CPU rehearsal) the reader returns None."""
+one (a CPU rehearsal) the reader returns None. What a number needs of the
+model (operations a token, bytes a step, a kernel's cost) is asked of the
+configuration's family (``systems.family``), never spelt out here."""
 
 from __future__ import annotations
 
-import re
-
-from benchmark import flops, stats
+from benchmark import flops, inside, stats, systems
 from benchmark.trace import KERNEL_TARGET
-
-TRAIN_STEP = re.compile(r"^jit__step\(")
 
 
 def percentile_ms(run, counter: str, q: float):
@@ -46,52 +47,27 @@ def batch_occupancy(run):
     return 100.0 * sum(samples) / len(samples) / run.counters["max_batch"]
 
 
-def _engine_programs(run) -> dict:
-    return run.trace.loop_depth(run.config["num_hidden_layers"])
-
-
-def decode_step_ms(run):
-    """Device time of the decode programs (those that loop over steps)
-    over the steps they ran, whole runs only."""
-    if run.trace is None:
-        return None
-    depth = _engine_programs(run)
-    seconds = steps = 0.0
-    for name, passes in depth.items():
-        if passes >= 2:
-            t, runs = run.trace.module_time(lambda n: n == name, whole=True)
-            seconds, steps = seconds + t, steps + runs * passes
-    return seconds / steps * 1e3 if steps else None
-
-
-def prefill_share(run):
-    """The prefill programs' (one pass over the layers) share of the
-    device's busy time."""
-    if run.trace is None or run.trace.busy_s() <= 0:
-        return None
-    depth = _engine_programs(run)
-    seconds, _ = run.trace.module_time(lambda n: depth.get(n) == 1)
-    return 100.0 * seconds / run.trace.busy_s()
-
-
 def decode_roofline(run):
-    """Bytes one step must move (weights once, live keys and values once)
-    over the chip's bandwidth, over the step's time. Memory-bound: at 32
-    slots a step has 2 x 32 operations a weight byte pair, far under the
-    chip's 240 operations a byte."""
-    step_ms = decode_step_ms(run)
+    """Bytes one step must move (the family's count: for a dense model
+    the weights once and the live keys and values once) over the chip's
+    bandwidth, over the step's time, which is that of the decode programs
+    found by name. Memory-bound: at 32 slots a step has 2 x 32 operations
+    a weight byte pair, far under the chip's 240 operations a byte."""
+    step_ms = inside.decode_program_step_ms(run.trace)
     if not step_ms:
         return None
+    nbytes = systems.family(run.config).decode_step_bytes(run.config,
+                                                          run.counters)
+    if nbytes is None:
+        return None
     peak = flops.peaks(run.device["kind"])
-    nbytes = flops.decode_step_bytes(
-        run.config, run.counters.get("live_kv_tokens_mean", 0.0))
     return 100.0 * nbytes / peak["hbm_bytes_per_s"] / (step_ms * 1e-3)
 
 
 def train_step_ms(run):
     if run.trace is None:
         return None
-    seconds, runs = run.trace.module_time(TRAIN_STEP.match, whole=True)
+    seconds, runs = run.trace.module_time(inside.TRAIN_STEP.match, whole=True)
     return seconds / runs * 1e3 if runs else None
 
 
@@ -101,9 +77,11 @@ def train_mfu(run):
     rate, so only a run on a chip reports it."""
     if run.device["platform"] != "tpu":
         return None
+    per_token = systems.family(run.config).train_flops_per_token(
+        run.config, run.counters["seq_len"])
+    if per_token is None:
+        return None
     peak = flops.peaks(run.device["kind"])
-    per_token = flops.train_flops_per_token(run.config,
-                                            run.counters["seq_len"])
     return (100.0 * per_token * run.counters["tokens_per_s_per_chip"]
             / peak["bf16_flops_per_s"])
 
@@ -111,33 +89,29 @@ def train_mfu(run):
 def _flash_seconds_per_step(run):
     """(kernel seconds, step seconds) of one train step: the kernels'
     share of the step programs' time in the trace, times a whole step."""
-    traced, _ = run.trace.module_time(TRAIN_STEP.match)
-    whole, runs = run.trace.module_time(TRAIN_STEP.match, whole=True)
+    traced, _ = run.trace.module_time(inside.TRAIN_STEP.match)
+    whole, runs = run.trace.module_time(inside.TRAIN_STEP.match, whole=True)
     if not runs or not traced:
         return None, None
     kernel = run.trace.op_time(lambda n: KERNEL_TARGET in n)
     return kernel / traced * whole / runs, whole / runs
 
 
-def flash_share(run):
-    if run.trace is None:
-        return None
-    kernel, step = _flash_seconds_per_step(run)
-    return 100.0 * kernel / step if step else None
-
-
 def flash_roofline(run):
     """The flash kernels (forward, dq, dk/dv) of one step against the
-    larger of operations over peak and bytes over bandwidth. Compute-bound
-    at these shapes (head size 128, 2048 keys)."""
+    larger of operations over peak and bytes over bandwidth (the family's
+    count of both). Compute-bound at these shapes (head size 128, 2048
+    keys)."""
     if run.trace is None:
         return None
     kernel, _ = _flash_seconds_per_step(run)
     if not kernel:
         return None
     c = run.counters
-    cost = flops.flash_train_cost(run.config, c["batch"] // c["chips"],
-                                  c["seq_len"])
+    cost = systems.family(run.config).flash_train_cost(
+        run.config, c["batch"] // c["chips"], c["seq_len"])
+    if cost is None:
+        return None
     share, _ = flops.roofline_share(cost["flops"], cost["bytes"], kernel,
                                     flops.peaks(run.device["kind"]))
     return share

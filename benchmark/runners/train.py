@@ -8,7 +8,7 @@ import importlib
 import math
 import time
 
-from benchmark import flops, reference, systems
+from benchmark import reference, systems
 from benchmark.harness import RunRecord, say, span
 
 # The trainer's bf16 loss against the float32 reference's on the same
@@ -47,7 +47,8 @@ def run(ctx) -> RunRecord:
     batch0 = jax.block_until_ready(make_batch(data_key, 0))
     ctx.phases.mark("first batch")
 
-    ref_loss = reference.loss(config, state.params, batch0,
+    family = systems.family(config)     # its reference and its counts
+    ref_loss = reference.loss(family.logits, config, state.params, batch0,
                               rows_per_call=n_chips)
     ctx.phases.mark("reference loss")
 
@@ -62,7 +63,7 @@ def run(ctx) -> RunRecord:
     state, metrics = trainer.train_step(state, batch0)
     loss0 = float(metrics["loss"])
     batch1 = make_batch(data_key, 1)
-    ref_loss1 = reference.loss(config, state.params, batch1,
+    ref_loss1 = reference.loss(family.logits, config, state.params, batch1,
                                rows_per_call=n_chips)
     state, metrics = trainer.train_step(state, batch1)
     loss1 = float(metrics["loss"])              # the steady-state path once
@@ -115,7 +116,7 @@ def run(ctx) -> RunRecord:
                      reference_loss1=ref_loss1, loss_gap=gap, tol=LOSS_TOL,
                      last_loss=losses[-1], live_gb=live / 1e9,
                      temp_gb=temp_bytes / 1e9,
-                     flops_per_token=flops.train_flops_per_token(
+                     flops_per_token=family.train_flops_per_token(
                          config, job["seq_len"]))
     say("train", **rec.notes)
     return rec

@@ -225,13 +225,14 @@ def prepare_engine(ctx, prompts_and_budgets, limits: dict):
         say("engine discarded", gone=wait_gone(gone),
             live_bytes=systems.live_bytes())
 
+    logits = systems.family(config).logits      # the family's reference
     gaps, flips = [], 0
     for prompt, new in samples:     # the second reuses the first's pages
         req = eng.submit(prompt, max_new_tokens=new)
         tokens = collect(eng, req)
         ok = len(tokens) == new and all(0 <= t < vocab for t in tokens)
-        gap, missed = (reference.token_gap(config, eng.params, prompt,
-                                           tokens)
+        gap, missed = (reference.token_gap(logits, config, eng.params,
+                                           prompt, tokens)
                        if ok else (float("inf"), new))
         gaps.append(gap)
         flips += missed
@@ -239,8 +240,9 @@ def prepare_engine(ctx, prompts_and_budgets, limits: dict):
     ctx.phases.mark("reference check")
 
     # what the compiler says the largest program compiled so far needs
-    # beside nothing else (the prefill program holds the page pool
-    # twice), against the live arrays
+    # beside nothing else (the largest prefill program holds the page
+    # pool once: the donated argument, which its result aliases), against
+    # the live arrays
     temp, top = systems.largest_program()
     live = systems.live_bytes()
     facts = {"token_gap": max(gaps), "tol": TOKEN_GAP_TOL,

@@ -1,26 +1,43 @@
-"""The only place the benchmark touches the program: it builds the system
-under test (the model config class, ``JaxTrainer``, ``PagedLLMEngine``)
-from a configuration file, through the adapter its ``family`` names, and
+"""Where the benchmark touches the program: it builds the system under
+test (the model config object, ``JaxTrainer``, ``PagedLLMEngine``) from a
+configuration file, through the adapter of the family the file names, and
 reads the engine's counters and what the compiler says the compiled
 programs need. It calls the program through its public surface only: no
 jitted partial by its signature, no bucketing rule, no private array.
-Everything else under ``benchmark/`` is yardstick."""
+Beside it only a family's two adapter functions build anything of the
+program's, and ``program_spans.py`` reads the engine's recorded spans;
+everything else under ``benchmark/`` is yardstick.
+
+``family(config)`` is also the one way to a model's block: its plain
+reference and its operation and byte counts live in the family's module
+(``benchmark/families/__init__.py``), and the runners and readers reach
+them through here, so that none of them names a family."""
 
 from __future__ import annotations
 
 import functools
 import importlib
 
+from benchmark import families
+
 
 def family(config: dict):
-    """The configuration's model family, found by name:
-    ``benchmark/families/<family>.py``."""
+    """The configuration's model family, found by the name under its
+    ``family`` key and by nothing else: ``benchmark/families/<family>.py``,
+    with the adapter, the plain reference and the counts (every name of
+    ``families.API``; a module that lacks one is refused here, in a CPU
+    rehearsal too, and not at the first reader that asks on the chip)."""
     try:
-        return importlib.import_module(
+        module = importlib.import_module(
             "benchmark.families." + config["family"])
     except ModuleNotFoundError as e:
-        raise SystemExit(f"benchmark: no adapter for model family "
+        raise SystemExit(f"benchmark: no model family "
                          f"{config['family']!r}: {e}") from e
+    missing = [name for name in families.API if not hasattr(module, name)]
+    if missing:
+        raise SystemExit(f"benchmark: model family {config['family']!r} "
+                         f"lacks {missing} (benchmark/families/__init__.py)")
+    return module
 
 
 def model_config(config: dict):

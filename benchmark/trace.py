@@ -8,17 +8,18 @@ run of a jitted program, named ``jit_<function>(<hash>)``), ``XLA Ops``
 (one event per HLO operation run, named by its whole HLO text) and
 ``Async XLA Ops`` (copies and collectives in flight, start to done); and
 ``/host:CPU`` with one line per thread, where ``jax.profiler
-.TraceAnnotation`` spans appear under their own names. Device and host
-events are on one clock to within a millisecond or two.
+.TraceAnnotation`` spans appear under their own names: the benchmark's
+own (``bench.*``, ``harness.span``) and, since PR 24, the phases of the
+program's engine loop (``engine.*``). Device and host events are on one
+clock to within a millisecond or two.
 """
 
 from __future__ import annotations
 
-import bisect
 import glob
 import os
 import re
-from collections import Counter, defaultdict
+from collections import defaultdict
 
 COLLECTIVE_OPCODES = ("all-gather", "all-reduce", "all-to-all",
                       "collective-permute", "reduce-scatter",
@@ -27,7 +28,10 @@ CONTAINERS = ("while", "conditional", "call")
 KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'   # a Pallas kernel
 _OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
 _SHAPE = re.compile(r"([a-z]+[0-9]*)\[([0-9,]*)\]")
-SPAN_PREFIX = "bench."
+# The host spans kept, by whose they are, in the order in which they name
+# an idle gap: the program's own phases first (its loop is the thread that
+# feeds the device), then the benchmark's (the client's thread).
+SPAN_PREFIXES = ("engine.", "bench.")
 
 
 def find_xplane(trace_dir: str) -> str | None:
@@ -120,7 +124,8 @@ def _busy_intervals(dev: dict) -> list:
 class Trace:
     """Events of one trace, in seconds. ``devices`` is a list (one entry a
     chip) of dicts ``modules``, ``ops``, ``async_ops``, each a list of
-    (name, start, end); ``spans`` is the benchmark's own host spans."""
+    (name, start, end); ``spans`` is the host spans kept (``SPAN_PREFIXES``),
+    of every thread, in the order they started."""
 
     def __init__(self, devices: list, spans: list, extent_s=None):
         self.devices = devices
@@ -151,7 +156,7 @@ class Trace:
                     first, last = min(first, start), max(last, end)
                     if key:
                         dev[key].append((e.name, start * 1e-9, end * 1e-9))
-                    elif host and e.name.startswith(SPAN_PREFIX):
+                    elif host and e.name.startswith(SPAN_PREFIXES):
                         spans.append((e.name, start * 1e-9, end * 1e-9))
             if device:
                 devices.append(dev)
@@ -182,33 +187,6 @@ class Trace:
         window, for all."""
         ev = [(s, e) for n, s, e in self._modules(whole) if match(n)]
         return sum(e - s for s, e in ev), len(ev)
-
-    def loop_depth(self, inner: int) -> dict:
-        """{program name: passes}: how many times one run of each program
-        runs its layer stack of ``inner`` layers. A program that scans the
-        layers inside a scan over steps runs an operation of the layer
-        body steps x inner times a run, and none more often; one pass over
-        the layers gives 1; a program with no layer loop 0. Taken from the
-        first whole run of each program. This is how the engine's programs
-        are told apart: its jitted partials carry no name
-        (``jit__unknown``), but the decode program alone loops over steps
-        (a chunk of them, and chunks differ by program) and the prefill
-        program alone passes the layers once."""
-        if not self.devices:
-            return {}
-        ops = sorted(self.devices[0]["ops"], key=lambda x: x[1])
-        starts = [s for _, s, _ in ops]
-        out = {}
-        for name, s, e in self._modules(whole=True):
-            if name in out:
-                continue
-            lo, hi = bisect.bisect_left(starts, s), \
-                bisect.bisect_right(starts, e)
-            counts = Counter(n for n, _, _ in ops[lo:hi]
-                             if opcode(n) not in CONTAINERS)
-            most = max(counts.values()) if counts else 0
-            out[name] = round(most / max(1, inner))
-        return out
 
     def op_time(self, match) -> float:
         """Seconds of the operations ``match`` accepts, a chip's mean."""
@@ -262,8 +240,13 @@ class Trace:
 
     def idle_gaps(self, k: int = 10) -> list:
         """The longest gaps between operations on the first chip, each
-        named by the benchmark span that covers most of it (what the host
-        was doing), or ``no-benchmark-span``."""
+        named by what the host was doing in most of it, or
+        ``no-benchmark-span``. A phase of the program goes before a span
+        of the benchmark (the order of ``SPAN_PREFIXES``:
+        ``engine.wait_device``, not the client's ``bench.sleep``). Among
+        the spans of one kind the innermost counts: a span is given only
+        the part of the gap that no shorter span covers, so an iteration
+        names what none of its phases does."""
         if not self.devices:
             return []
         d = self.devices[0]
@@ -272,12 +255,19 @@ class Trace:
                        for a, b in zip(busy, busy[1:])), reverse=True)[:k]
         out = []
         for length, s, e in gaps:
-            best, cover = "no-benchmark-span", 0.0
+            over = []
             for name, ss, se in self.spans:
                 if ss >= e:
                     break
-                c = min(e, se) - max(s, ss)
-                if c > cover:
-                    best, cover = name, c
+                if se > s:
+                    over.append((se - ss, name, (max(s, ss), min(e, se))))
+            best = "no-benchmark-span"
+            for prefix in SPAN_PREFIXES:
+                mine = sorted(x for x in over if x[1].startswith(prefix))
+                if mine:
+                    own = [subtract_length([clip], [c for _, _, c in mine[:i]])
+                           for i, (_, _, clip) in enumerate(mine)]
+                    best = mine[own.index(max(own))][1]
+                    break
             out.append([best, length])
         return out

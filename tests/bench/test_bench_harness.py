@@ -1,6 +1,7 @@
 """The harness end to end on the CPU at toy size, in a temporary copy to
-which a configuration, traffic mixes, cells and a per-layer metric are
-added as new files and entries; and the contract of BENCHMARK.json."""
+which configurations, model families (one of them not Llama-shaped),
+traffic mixes, cells and a per-layer metric are added as new files and
+entries; and the contract of BENCHMARK.json."""
 
 import json
 import os
@@ -40,7 +41,10 @@ def test_added_files_are_found_by_name_alone(toy):
     bench, cell, config, traffic = harness.load_cell("toy-chat", root=toy)
     assert cell["config"] == "toy-serve" and config["hidden_size"] == 64
     assert traffic["generator"] == "chat_sessions"
-    assert config["family"] == "toy_family"     # an adapter added as a file
+    assert config["family"] == "toy_family"     # a family added as a file
+    for family in ("toy_family", "toy_norope", "toy_gpt"):
+        assert os.path.exists(os.path.join(toy, "benchmark", "families",
+                                           family + ".py"))
     names = [m["name"] for m in harness.cell_metrics(
         bench, "toy-chat", "per_layer")]
     assert "toy_bursts" in names and "prefix_hit_share.chat" in names
@@ -77,6 +81,36 @@ def test_rehearsal_runs_the_cell_and_prints_counts_only(toy, cell, counted):
     assert got["metrics"]["compiles_in_window"]["value"] <= (
         3.0 if cell == "toy-chat" else 0.0)
     assert "memory_peak_bytes" not in got["device"]
+
+
+def test_a_family_that_is_not_llama_shaped_is_files_only(toy):
+    """A GPT-2 block through the trainer's normal path: the adapter, the
+    reference and the counts are the family's one file, written into the
+    copy beside what is there (``make_toy`` checks that nothing that was
+    there changed). The first two steps' losses are held to the family's
+    own reference, and the operations a token are the family's count."""
+    r = bench_toy.run_cell(toy, "toy-gpt-train")
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    _, got = last_json(r.stdout)
+    assert got["correct"] is True and got["failed"] == 0
+    # 6 x (2 x (4 x 64 x 64 + 2 x 64 x 128) + 64 x 512) + 3 x 2 x 2 x 64 x 64
+    assert "flops_per_token=638976.0000" in r.stdout
+    assert "loss_gap=0.00" in r.stdout
+
+
+def test_the_familys_reference_decides_correct(toy):
+    """The same engine, weights and traffic as ``toy-doc`` (which reads
+    ``correct`` true against the toy family's own copy of the reference,
+    above) under a family whose reference leaves out the rotary embedding:
+    every request finishes, and the run is not correct."""
+    r = bench_toy.run_cell(toy, "toy-doc-norope", seconds=1.0)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    _, got = last_json(r.stdout)
+    assert got["correct"] is False and got["failed"] == 0
+    assert got["attempted"] > 0
+    gap = float(re.search(r"bench reference: token_gap=([0-9.]+)",
+                          r.stdout).group(1))
+    assert gap > 10 * 0.1           # far over TOKEN_GAP_TOL, not near it
 
 
 def test_rehearsal_of_the_sharded_train_cell_on_four_devices(toy):

@@ -15,68 +15,82 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import bench_toy  # noqa: E402
 from test_bench_trace import engine_like_trace  # noqa: E402
 
-from benchmark import harness, inside, readers  # noqa: E402
+from benchmark import harness, inside, readers, systems  # noqa: E402
 from benchmark.trace import Trace  # noqa: E402
 
-NAMES = {"jit__unknown(1)": "jit_paged_decode_c4_w2(1)",
-         "jit__unknown(2)": "jit_paged_decode_c2_w2(2)",
-         "jit__unknown(3)": "jit_paged_prefill_w2(3)",
-         "jit__unknown(4)": "jit_scatter_firsts(4)"}
 SPAN_METRICS = ("engine_host_share", "prefill_device_wait_p50_ms",
                 "prefill_run_p50_ms", "prefill_group_mean",
                 "decode_active_share")
 
 
-def named(trace, times: int = 1):
-    """The loop-depth test's trace with the engine's programs named as
-    PR 24 names them, repeated ``times`` times end to end."""
+def repeated(trace, times: int):
+    """The engine-like trace ``times`` times end to end."""
     dev, period = trace.devices[0], trace.extent_s
     out = {"modules": [], "ops": [], "async_ops": []}
     for k in range(times):
-        out["modules"] += [(NAMES[n], s + k * period, e + k * period)
+        out["modules"] += [(n, s + k * period, e + k * period)
                            for n, s, e in dev["modules"]]
         out["ops"] += [(n, s + k * period, e + k * period)
                        for n, s, e in dev["ops"]]
     return Trace([out], [], extent_s=period * times)
 
 
-def run_with(trace, layers=3):
-    return type("Run", (), {"trace": trace,
-                            "config": {"num_hidden_layers": layers}})
-
-
-def test_by_name_agrees_with_loop_depth_on_the_loop_depth_trace():
-    unnamed = engine_like_trace()
-    run = run_with(named(unnamed))
-    # the same whole runs, found by name and by counting loop passes
-    assert inside.decode_program_step_ms(run.trace) == pytest.approx(
-        readers.decode_step_ms(run_with(unnamed))) == pytest.approx(4300.0)
-    assert readers.decode_step_ms(run) == pytest.approx(4300.0)
-    # one prefill run is no sample of five
-    assert inside.prefill_program_share(run.trace) is None
-    five = run_with(named(unnamed, times=5))
-    assert inside.prefill_program_share(five.trace) == pytest.approx(
-        readers.prefill_share(five))
-    assert inside.prefill_program_runs_ms(five.trace) == pytest.approx(
-        [5000.0] * 5)
-    assert inside.decode_program_step_ms(five.trace) == pytest.approx(
-        readers.decode_step_ms(five))
-
-
-def test_regrouped_loops_break_loop_depth_and_not_the_names():
-    """What a perf_opt PR on the pool copies will do: the decode program
-    no longer repeats one layer-body operation steps x layers times."""
-    t = engine_like_trace()
-    dev = t.devices[0]
+def regrouped(trace):
+    """What a perf_opt PR on the layer loop may do, and a model with more
+    than one layer loop does by itself: no operation of the decode
+    program repeats steps x layers times any more."""
+    dev = trace.devices[0]
     ops = [(f"%body.{i} = f32[1]{{0}} fusion(f32[1]{{0}} %x)"
             if n.startswith("%body") else n, s, e)
            for i, (n, s, e) in enumerate(dev["ops"])]
-    regrouped = Trace([{"modules": dev["modules"], "ops": ops,
-                        "async_ops": []}], [], extent_s=t.extent_s)
-    assert readers.decode_step_ms(run_with(regrouped)) != pytest.approx(
-        4300.0)
-    assert inside.decode_program_step_ms(named(regrouped)) == pytest.approx(
-        4300.0)
+    return Trace([{"modules": dev["modules"], "ops": ops, "async_ops": []}],
+                 [], extent_s=trace.extent_s)
+
+
+@pytest.mark.parametrize("reshape", [lambda t: t, regrouped],
+                         ids=["scanned", "regrouped"])
+def test_engine_programs_are_told_apart_by_their_names(reshape):
+    """Whole decode runs: two of chunk 4 (17 s each) and one of chunk 2
+    (9 s), 43 s over 10 steps, however the programs loop inside."""
+    trace = reshape(engine_like_trace())
+    assert inside.decode_program_step_ms(trace) == pytest.approx(4300.0)
+    # one prefill run is no sample of five
+    assert inside.prefill_program_share(trace) is None
+    five = reshape(repeated(engine_like_trace(), 5))
+    assert inside.prefill_program_share(five) == pytest.approx(
+        100.0 * 5 * 5.0 / five.busy_s())
+    assert inside.prefill_program_runs_ms(five) == pytest.approx([5000.0] * 5)
+    # the runs that an edge cut in one copy are whole inside five: all
+    # but the first and the last, 255 s over 74 steps
+    assert inside.decode_program_step_ms(five) == pytest.approx(255e3 / 74)
+    run = type("Run", (), {"trace": five})
+    assert readers.device_idle_share(run) == pytest.approx(
+        100.0 * (1 - five.busy_s() / five.extent_s))
+
+
+def test_decode_roofline_is_the_familys_bytes_over_the_named_step(monkeypatch):
+    """``decode_roofline.*``: the step time of the programs found by name
+    under the bytes the configuration's family counts from the run's
+    counters; a family with nothing to count gives no metric."""
+    with open(os.path.join(bench_toy.REPO, "benchmark", "configs",
+                           "mistral-7b-v0.3-d12.json")) as f:
+        config = json.load(f)
+    counters = {"live_kv_tokens_mean": 20000.0}
+    run = type("Run", (), {"trace": engine_like_trace(), "config": config,
+                           "counters": counters,
+                           "device": {"platform": "tpu",
+                                      "kind": "TPU v5 lite"}})
+    family = systems.family(config)
+    nbytes = family.decode_step_bytes(config, counters)
+    assert nbytes > family.decode_step_bytes(config, {}) > 5e9
+    assert readers.decode_roofline(run) == pytest.approx(
+        100.0 * nbytes / 819e9 / 4.3)
+    assert harness.load_reader("decode_roofline.doc")(run) == pytest.approx(
+        readers.decode_roofline(run))
+    monkeypatch.setattr(family, "decode_step_bytes", lambda c, k: None)
+    assert readers.decode_roofline(run) is None
+    run.trace = None
+    assert readers.decode_roofline(run) is None
 
 
 def kernel(name, out="bf16[6,32,2048,128]{3,2,1,0}"):
@@ -102,7 +116,6 @@ def test_flash_kernels_apart_by_their_names():
     fwd = inside.kernel_share(run.trace, ("flash_fwd",))
     bwd = inside.kernel_share(run.trace, ("flash_dq", "flash_dkv"))
     assert fwd == pytest.approx(5.0) and bwd == pytest.approx(20.0)
-    assert fwd + bwd == pytest.approx(readers.flash_share(run))
     assert harness.load_reader("flash_fwd_share")(run) == pytest.approx(5.0)
     assert harness.load_reader("flash_bwd_share")(run) == pytest.approx(20.0)
     # a kernel with no name (the parent's) is in neither
@@ -204,8 +217,7 @@ def test_new_readers_return_none_without_a_trace_or_spans(monkeypatch):
     from benchmark import program_spans
     from ray_tpu.util import tracing
 
-    run = type("Run", (), {"trace": None, "counters": {},
-                           "config": {"num_hidden_layers": 2}})
+    run = type("Run", (), {"trace": None, "counters": {}, "config": {}})
     for name in ("decode_program_step_ms.chat", "prefill_program_share.doc",
                  "flash_fwd_share", "flash_bwd_share"):
         assert harness.load_reader(name)(run) is None

@@ -1,5 +1,6 @@
 """The benchmark's metric arithmetic: percentiles, the serving window's
-accounting, and operations and bytes from shapes."""
+accounting, the chip's rooflines, and the operations and bytes that the
+configurations' family counts from shapes."""
 
 import json
 import math
@@ -7,7 +8,7 @@ import os
 
 import pytest
 
-from benchmark import flops, stats
+from benchmark import families, flops, stats, systems
 from benchmark.generators.grid import Req
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -93,15 +94,16 @@ def test_config_files_keep_published_widths(name, layers, billions):
             c["num_attention_heads"], c["num_key_value_heads"],
             c["head_dim"]) == (4096, 14336, 32768, 32, 8, 128)
     assert c["rope_theta"] == 1e6 and c["sliding_window"] is None
-    assert flops.total_params(c) / 1e9 == pytest.approx(billions, abs=0.01)
+    total_params = systems.family(c).total_params
+    assert total_params(c) / 1e9 == pytest.approx(billions, abs=0.01)
     full = dict(c, num_hidden_layers=32)
-    assert flops.total_params(full) / 1e9 == pytest.approx(7.25, abs=0.01)
+    assert total_params(full) / 1e9 == pytest.approx(7.25, abs=0.01)
 
 
 def test_train_flops_per_token():
     c = config("mistral-7b-v0.3-d4")
-    assert flops.matmul_params(c) == 4 * 218103808 + 4096 * 32768
-    per_token = flops.train_flops_per_token(c, 2048)
+    assert systems.family(c).matmul_params(c) == 4 * 218103808 + 4096 * 32768
+    per_token = systems.family(c).train_flops_per_token(c, 2048)
     # 6 x matmul parameters, plus causal attention: 3 x 4 layers x
     # (4 x 2048 x 4096 / 2) operations a token
     assert per_token == pytest.approx(6 * 1006632960 + 12 * 16777216)
@@ -109,7 +111,7 @@ def test_train_flops_per_token():
 
 def test_flash_cost_and_roofline_bound():
     c = config("mistral-7b-v0.3-d4")
-    cost = flops.flash_train_cost(c, 6, 2048)
+    cost = systems.family(c).flash_train_cost(c, 6, 2048)
     assert cost["flops"] == pytest.approx(
         3.5 * 4 * 6 * 0.5 * 4 * 2048 * 2048 * 4096)
     peak = flops.peaks("TPU v5 lite")
@@ -122,9 +124,29 @@ def test_flash_cost_and_roofline_bound():
 
 def test_decode_step_bytes_and_unknown_device():
     c = config("mistral-7b-v0.3-d12")
-    weights = 2 * flops.matmul_params(c)
-    assert flops.decode_step_bytes(c, 0) == weights
+    family = systems.family(c)
+    weights = 2 * family.matmul_params(c)
+    assert family.decode_step_bytes(c, {}) == weights
     per_token = 2 * 2 * 12 * 8 * 128          # k and v, bf16, every layer
-    assert flops.decode_step_bytes(c, 1000) == weights + 1000 * per_token
+    assert family.decode_step_bytes(
+        c, {"live_kv_tokens_mean": 1000, "max_batch": 32}) == (
+            weights + 1000 * per_token)
     with pytest.raises(SystemExit):
         flops.peaks("TPU v9 imaginary")
+
+
+def test_a_family_is_found_by_name_and_held_to_the_whole_contract(tmp_path,
+                                                                 monkeypatch):
+    c = config("mistral-7b-v0.3-d4")
+    assert all(callable(getattr(systems.family(c), n)) for n in families.API)
+    with pytest.raises(SystemExit, match="no model family 'no_such"):
+        systems.family({"family": "no_such"})
+    # a family that leaves a count out is refused when it is loaded, not
+    # at the first reader that asks on the chip
+    (tmp_path / "half.py").write_text(
+        "def model_config(c): ...\ndef init_params(m, k): ...\n"
+        "def logits(c, p, t): ...\n")
+    monkeypatch.setattr(families, "__path__",
+                        list(families.__path__) + [str(tmp_path)])
+    with pytest.raises(SystemExit, match="train_flops_per_token"):
+        systems.family({"family": "half"})

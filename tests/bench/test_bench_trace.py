@@ -1,10 +1,13 @@
 """The reduction from a profiler trace to the per-layer metrics: on a small
 trace recorded on a v5e in PR 23 (a one-off recorder, not kept: four rounds of a
 three-matmul program and the flash kernel, with benchmark spans and a 5 ms
-sleep between rounds), and on synthetic events for what that trace lacks
-(collectives, a program that scans steps)."""
+sleep between rounds), on synthetic events for what that trace lacks
+(collectives, the engine's programs and phases), and on a trace of host
+spans recorded here."""
 
 import os
+import threading
+import time
 
 import pytest
 
@@ -108,18 +111,19 @@ def test_collectives_total_and_exposed():
 
 
 def engine_like_trace(layers=3):
-    """Programs named as the engine's are (``jit__unknown``): decode
-    programs of chunk steps x L layers (two chunk sizes), a prefill
-    program (one pass over the layers) and a program with no loop; the
-    first and last runs are cut short by the trace's edges."""
+    """Programs named as the engine names its own: decode programs of
+    chunk steps x L layers (two chunk sizes), a prefill program (one pass
+    over the layers) and a program with no loop; the first and last runs
+    are cut short by the trace's edges."""
     ops, modules, t = [], [], 0.0
-    for program, passes in (("jit__unknown(1)", 1),     # decode, cut short
-                            ("jit__unknown(1)", 4),     # decode, chunk 4
-                            ("jit__unknown(2)", 2),     # decode, chunk 2
-                            ("jit__unknown(3)", 1),     # prefill
-                            ("jit__unknown(4)", 0),     # a scatter
-                            ("jit__unknown(1)", 4),
-                            ("jit__unknown(2)", 1)):    # cut short
+    for program, passes in (
+            ("jit_paged_decode_c4_w2(1)", 1),       # cut short
+            ("jit_paged_decode_c4_w2(1)", 4),
+            ("jit_paged_decode_c2_w2(2)", 2),
+            ("jit_paged_prefill_w2(3)", 1),
+            ("jit_scatter_firsts(4)", 0),
+            ("jit_paged_decode_c4_w2(1)", 4),
+            ("jit_paged_decode_c2_w2(2)", 1)):      # cut short
         start = t
         for _ in range(passes):
             for _ in range(layers):
@@ -137,32 +141,67 @@ def engine_like_trace(layers=3):
                  extent_s=t)
 
 
-def test_engine_programs_are_told_apart_by_their_loops():
-    trace = engine_like_trace()
-    assert trace.loop_depth(3) == {
-        "jit__unknown(1)": 4, "jit__unknown(2)": 2, "jit__unknown(3)": 1,
-        "jit__unknown(4)": 0}
+def gap_trace(spans):
+    """Two operations with an idle gap from 1.0 to 3.0 between them."""
+    return Trace([{"modules": [], "ops": [("%a = f32[1]{0} add()", 0.0, 1.0),
+                                          ("%a = f32[1]{0} add()", 3.0, 4.0)],
+                   "async_ops": []}], spans)
 
 
-def test_decode_step_time_and_prefill_share_from_loop_depth():
-    from benchmark import readers
+@pytest.mark.parametrize("spans,want", [
+    # the engine's phase inside its iteration, not the client's sleep,
+    # though the sleep is the shortest span that covers the gap
+    ([("engine.iteration", 0.0, 4.0), ("engine.wait_device", 0.5, 3.5),
+      ("bench.sleep", 0.9, 3.1)], "engine.wait_device"),
+    # of two phases, the one that covers most of the gap
+    ([("engine.iteration", 0.0, 1.5), ("engine.admit", 0.2, 1.4),
+      ("engine.iteration", 1.5, 4.0), ("engine.wait_arrivals", 1.6, 3.9),
+      ("bench.read", 0.0, 4.0)], "engine.wait_arrivals"),
+    # the iteration itself where no phase of it covers the gap
+    ([("engine.iteration", 0.0, 4.0), ("engine.emit", 0.0, 0.9)],
+     "engine.iteration"),
+    # a cell whose program records no phase: the benchmark's own spans,
+    # innermost first
+    ([("bench.wait", 0.0, 4.0), ("bench.step_call", 0.9, 3.1)],
+     "bench.step_call"),
+    ([("bench.wait", 3.5, 4.0)], "no-benchmark-span")])
+def test_idle_gap_is_named_by_the_innermost_phase_that_covers_it(spans, want):
+    assert gap_trace(spans).idle_gaps() == [[want, 2.0]]
 
-    trace = engine_like_trace()
-    run = type("Run", (), {"trace": trace,
-                           "config": {"num_hidden_layers": 3}})
-    # whole decode runs: two of chunk 4 (17 s each) and one of chunk 2
-    # (9 s): 43 s over 10 steps
-    assert readers.decode_step_ms(run) == pytest.approx(4300.0)
-    prefill_s = 5.0
-    assert readers.prefill_share(run) == pytest.approx(
-        100.0 * prefill_s / trace.busy_s())
-    assert readers.device_idle_share(run) == pytest.approx(
-        100.0 * (1 - trace.busy_s() / trace.extent_s))
+
+def test_engine_phases_are_kept_from_a_trace_file(tmp_path):
+    """A profiler session on the CPU: no device plane, but the host spans
+    the reduction keeps are the engine's phases beside the benchmark's,
+    and nothing else's."""
+    import jax
+
+    def client():
+        with jax.profiler.TraceAnnotation("bench.sleep"):
+            time.sleep(0.002)
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        thread = threading.Thread(target=client)
+        thread.start()
+        with jax.profiler.TraceAnnotation("engine.iteration"):
+            with jax.profiler.TraceAnnotation("engine.wait_device"):
+                time.sleep(0.004)
+            with jax.profiler.TraceAnnotation("other.thing"):
+                time.sleep(0.001)
+        thread.join(timeout=10)
+    finally:
+        jax.profiler.stop_trace()
+    trace = Trace.from_file(tr.find_xplane(str(tmp_path)))
+    assert trace.devices == [] and trace.idle_gaps() == []
+    assert sorted(n for n, _, _ in trace.spans) == [
+        "bench.sleep", "engine.iteration", "engine.wait_device"]
+    inner = {n: (s, e) for n, s, e in trace.spans}
+    assert (inner["engine.iteration"][0] <= inner["engine.wait_device"][0]
+            < inner["engine.wait_device"][1] <= inner["engine.iteration"][1])
 
 
 def test_gap_with_no_span_is_named_so():
-    t = Trace([{"modules": [], "ops": [("%a = f32[1]{0} add()", 0.0, 1.0),
-                                        ("%a = f32[1]{0} add()", 3.0, 4.0)],
-                "async_ops": []}], [])
-    assert t.idle_gaps() == [["no-benchmark-span", 2.0]]
+    assert gap_trace([]).idle_gaps() == [["no-benchmark-span", 2.0]]
     assert Trace([], []).busy_s() == 0.0 and Trace([], []).top_ops() == []
