@@ -1,0 +1,9 @@
+"""Per-layer metric ``expert_load_max_over_mean.*`` (see
+benchmark/experts.py)."""
+
+from benchmark import experts, program_spans
+
+
+def read(run):
+    return experts.chunk_stat_mean(program_spans.engine_spans(),
+                                   "expert_load_max_over_mean")

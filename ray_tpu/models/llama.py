@@ -158,17 +158,38 @@ def num_params(params) -> int:
 # Forward
 # ---------------------------------------------------------------------------
 
+def attention_projections(cfg, p, x, sin, cos):
+    """What attention takes in, from the residual stream ``x`` [b, s, d]:
+    pre-norm, the three projections split into heads, rotary on ``q`` and
+    ``k``. Returns (q [b, s, heads, hd], k, v [b, s, kv heads, hd]). With
+    ``feed_forward`` the block as two pieces that take no view on where
+    keys and values live: ``attention_sublayer`` puts causal attention
+    over the sequence between them, the paged serving engine its page
+    pool (``serve/paged_llm.py``)."""
+    b, s, _ = x.shape
+    h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
+    q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+    k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+
+
+def feed_forward(cfg, p, x, valid=None):
+    """Pre-norm SwiGLU feed-forward over ``x`` [b, s, d]; returns (the
+    residual-added stream, its statistics: a dense block has none).
+    ``valid`` [b, s] marks the rows that are tokens, for a block that
+    routes; a dense one computes every row."""
+    h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
+    gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
+    return x + gated @ p["w_down"], {}
+
+
 def attention_sublayer(cfg, x, p, sin, cos, segment_ids, attn_impl,
                        mesh=None, sp_axis="sp"):
     """Pre-norm attention sublayer (shared by Llama and Mixtral blocks).
     Returns the residual-added stream."""
     b, s, d = x.shape
-    h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    q, k, v = attention_projections(cfg, p, x, sin, cos)
     if attn_impl == "ring":
         from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -198,9 +219,7 @@ def _block(cfg: LlamaConfig, x, layer_params, sin, cos, segment_ids,
     p = layer_params
     x = attention_sublayer(cfg, x, p, sin, cos, segment_ids, attn_impl,
                            mesh=mesh, sp_axis=sp_axis)
-    h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
-    gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
-    x = x + gated @ p["w_down"]
+    x, _ = feed_forward(cfg, p, x)
     return x
 
 

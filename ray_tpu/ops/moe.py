@@ -1,11 +1,20 @@
-"""Mixture-of-Experts ops: top-k router + capacity-based expert dispatch.
+"""Mixture-of-Experts ops: two routed SwiGLU feed-forwards.
 
-GShard/Switch-style MoE, the TPU-idiomatic formulation: dispatch and combine
-are einsums against one-hot capacity tensors (static shapes, MXU-friendly,
-no gathers), and expert parallelism is pure sharding — with the expert dim of
-``wi``/``wo`` sharded on the ``ep`` mesh axis, XLA's SPMD partitioner emits
-the token all-to-all automatically. (Reference has NO MoE implementation —
-SURVEY.md §2c row EP; Mixtral is a BASELINE.json target config.)
+``moe_ffn`` is the GShard/Switch formulation for TRAINING under expert
+parallelism: dispatch and combine are einsums against one-hot capacity
+tensors (static shapes, MXU-friendly, no gathers), and with the expert dim
+of ``wi``/``wo`` sharded on the ``ep`` mesh axis XLA's SPMD partitioner
+emits the token all-to-all. It is NOT exact: an expert takes at most
+``capacity`` tokens and DROPS the overflow (the residual stream carries
+those tokens unchanged), it renormalises the chosen gates, and its
+``[T, E, C]`` tensors grow with the square of T.
+
+``moe_ffn_dropless`` is EXACT, for serving and wherever a dropped token is
+a wrong answer: float32 softmax over all experts, top-k, every chosen
+expert applied to its token, nothing dropped, no ``[T, E, C]`` tensor. It
+picks its formulation from the token count it is traced with
+(``DENSE_MAX_TOKENS``). (Reference has NO MoE implementation — SURVEY.md
+§2c row EP.)
 """
 
 from __future__ import annotations
@@ -109,3 +118,108 @@ def moe_ffn(
     out = jnp.einsum("ecd,tec->td", expert_out, combine.astype(dtype),
                      preferred_element_type=jnp.float32)
     return out.astype(dtype), aux
+
+
+# Up to this many tokens ``moe_ffn_dropless`` runs every expert over every
+# token and weights the result (zero for an expert not chosen); past it, it
+# sorts the (token, choice) pairs by expert and runs grouped matmuls over
+# the chosen experts alone. On a v5e at OLMoE's widths (64 experts of 2048 x
+# 1024, 8 a token; PERF.md, PR 28) the first reads the experts' weights at
+# nine tenths of the chip's bandwidth up to 128 tokens (1.1 ms a layer) and
+# is bound by its 64 / 8 times the operations from about 256 (4.4 ms at
+# 1024, at the chip's peak); the second, whose grouped matmul XLA pads to
+# its tile group by group, takes 4-6 ms up to 1024 tokens and wins from
+# about 2048 (8.4 ms), and its temporaries do not grow with E x F a token.
+DENSE_MAX_TOKENS = 1024
+
+
+def moe_ffn_dropless(
+    x,                  # [T, D] tokens (flattened batch*seq)
+    router_w,           # [D, E]
+    wi_gate,            # [E, D, F]
+    wi_up,              # [E, D, F]
+    wo,                 # [E, F, D]
+    *,
+    top_k: int,
+    norm_topk_prob: bool = False,
+    valid=None,         # [T] bool: rows that are tokens (None: all)
+):
+    """Exact routed SwiGLU feed-forward. Returns (out [T, D], load [E]).
+
+    ``p = softmax(float32(x) @ router_w)`` over all experts; the ``top_k``
+    largest and their experts; the weights are those probabilities as they
+    are, or divided by their sum with ``norm_topk_prob``;
+    ``out = sum_k p_k * (silu(x @ gate_k) * (x @ up_k)) @ down_k``. Rows
+    that ``valid`` marks as padding go to no expert and come out zero.
+    ``load`` counts the (token, choice) pairs each expert got (int32).
+    """
+    t, d = x.shape
+    e = router_w.shape[1]
+    dtype = x.dtype
+    with jax.named_scope("moe_router"):
+        # true float32: the chip's default would round the products to
+        # bf16 and now and then pick another k-th expert than float32 does
+        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, top_k)     # [T, K]
+        if norm_topk_prob:
+            gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+        chosen = gate_idx[:, :, None] == jnp.arange(e)        # [T, K, E]
+        if valid is not None:
+            chosen = chosen & valid[:, None, None]
+        load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)  # [E]
+    with jax.named_scope("moe_experts"):
+        if t <= DENSE_MAX_TOKENS:
+            weights = jnp.sum(jnp.where(chosen, gate_vals[:, :, None], 0.0),
+                              axis=1)                          # [T, E]
+            out = _experts_all(x, weights, wi_gate, wi_up, wo)
+        else:
+            out = _experts_grouped(x, gate_vals, gate_idx, valid, load,
+                                   wi_gate, wi_up, wo)
+    return out.astype(dtype), load
+
+
+def _experts_all(x, weights, wi_gate, wi_up, wo):
+    """Every expert over every token; ``weights`` [T, E] is zero where an
+    expert was not chosen. The tokens are broadcast along the expert axis
+    so that the up projections are plain batched matmuls, and the down
+    projection contracts expert and width together, so nothing of shape
+    [E, T, D] comes out of it."""
+    xe = jnp.broadcast_to(x, (wi_gate.shape[0],) + x.shape)     # [E, T, D]
+    h = jax.nn.silu(
+        jnp.einsum("etd,edf->etf", xe, wi_gate,
+                   preferred_element_type=jnp.float32)
+    ) * jnp.einsum("etd,edf->etf", xe, wi_up,
+                   preferred_element_type=jnp.float32)
+    h = (h * weights.T[:, :, None]).astype(x.dtype)
+    return jnp.einsum("etf,efd->td", h, wo,
+                      preferred_element_type=jnp.float32)
+
+
+def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
+                     wo):
+    """The chosen experts alone: the T x K (token, choice) pairs sorted by
+    expert (padding rows last, in no group), three grouped matmuls
+    (``jax.lax.ragged_dot``) over the sorted rows, and each token's K
+    weighted results summed where they came from."""
+    t, k = gate_idx.shape
+    e = wi_gate.shape[0]
+    expert = gate_idx.reshape(t * k)
+    if valid is not None:
+        expert = jnp.where(jnp.repeat(valid, k), expert, e)
+    order = jnp.argsort(expert)                    # stable: pair -> row
+    xs = x[order // k]                             # [T*K, D]
+    h = jax.nn.silu(
+        jax.lax.ragged_dot(xs, wi_gate, load,
+                           preferred_element_type=jnp.float32)
+    ) * jax.lax.ragged_dot(xs, wi_up, load,
+                           preferred_element_type=jnp.float32)
+    ys = jax.lax.ragged_dot(h.astype(x.dtype), wo, load,
+                            preferred_element_type=jnp.float32)
+    # rows past the last group belong to no expert: whatever they hold
+    # must not reach a sum
+    weight = gate_vals.reshape(t * k)[order]
+    ys = jnp.where((expert[order] < e)[:, None], ys * weight[:, None], 0.0)
+    back = jnp.argsort(order)                      # row -> pair
+    return jnp.sum(ys[back].reshape(t, k, -1), axis=1)

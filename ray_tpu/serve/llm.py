@@ -279,6 +279,14 @@ class LLMEngine:
         """Build the KV cache + compiled programs (dense layout; the
         paged engine overrides this — serve/paged_llm.py)."""
         cfg = self.cfg
+        from ray_tpu.models import olmoe
+
+        if isinstance(cfg, olmoe.OlmoeConfig):
+            raise TypeError(
+                "the dense-KV engine (serve/llm.py, models/decoding.py) is "
+                "not taught the OLMoE block (QK-norm, routed experts): "
+                "serve an OlmoeConfig through PagedLLMEngine / "
+                "kv_layout='paged'")
         self._cache = decoding.init_cache(cfg, self.max_batch,
                                           self.max_len)
         self._decode_fn = _named_jit(
@@ -894,6 +902,12 @@ class LLMEngine:
         )
         return toks, lens, new_last
 
+    def _chunk_facts(self, recording: bool) -> dict:
+        """Hook: counts of the chunk being emitted that the decode
+        program itself took, for its ``engine.emit`` span (the paged
+        engine: the feed-forward's statistics of a routed block)."""
+        return {}
+
     def _dispatch_decode(self, active_idx):
         """Dispatch one decode chunk (no host sync), chained off the
         DEVICE-resident last-token vector — admissions (prefill firsts
@@ -957,10 +971,11 @@ class LLMEngine:
         with _tracing.phase("engine.emit", kind="serve") as ph:
             generated, finished = self.total_generated, self.total_finished
             self._emit_chunk(toks_np, active_idx, gens)
+            facts = self._chunk_facts(bool(ph))
             if ph:
                 ph.set(what="chunk",
                        tokens=self.total_generated - generated,
-                       finished=self.total_finished - finished)
+                       finished=self.total_finished - finished, **facts)
         return now
 
     def _wait_idle(self):

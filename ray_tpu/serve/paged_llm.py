@@ -11,9 +11,11 @@ continuous-batching loop of ``serve/llm.py``:
   ``max_batch * max_len`` — a 256-token chat on a 2048-token engine
   stops reserving 8x its need.
 - Decode attends over a BUCKETED page window: the gather width is the
-  power-of-two page count covering the longest live sequence, so short
-  workloads read a fraction of the dense cache's KV bytes per step
-  (the dominant decode-step HBM traffic at small models).
+  power-of-two page count covering the longest RESERVED page list among
+  the live slots (prompt + budget + one overshoot page: ``_pages_bucket``;
+  not the longest live sequence), so short workloads read a fraction of
+  the dense cache's KV bytes per step (the dominant decode-step HBM
+  traffic at small models).
 - Allocation is reserve-on-admit (pages for prompt + budget + one
   chained-overshoot page, released at retirement): admission applies
   backpressure when the pool is exhausted, and a mid-flight sequence
@@ -37,12 +39,24 @@ continuous-batching loop of ``serve/llm.py``:
   program holds a second pool (measured on a v5e at 12 layers x 544
   pages: 43% of the device's time, 5.9 GB of HBM).
 
+- What is the MODEL's comes from the model's module, resolved from the
+  config's class (``_model_module``): the attention projections
+  (``attention_projections``: norm, q/k/v, whatever the block does to
+  them, rotary), the feed-forward (``feed_forward``: a dense SwiGLU, or
+  routed experts) and the output head (``lm_head_weights``). What is the
+  ENGINE's stays here, once for every model: the page write and gather,
+  ``_cached_attention``, the layer scan, sampling, the chunk loop. A
+  feed-forward may hand back statistics of its call (scalars; a dense
+  one has none): the decode program averages them over the chunk's
+  layer-steps, and they go on the chunk's ``engine.emit`` span.
+
 Engine mechanics (queues, continuous batching, chunked + pipelined
 decode, metrics) are inherited from ``LLMEngine``.
 """
 
 from __future__ import annotations
 
+from collections import deque
 from functools import partial
 
 import jax
@@ -56,8 +70,22 @@ from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
                                          dequantize_kv, page_hashes,
                                          quantize_kv)
-from ray_tpu.ops.rope import apply_rope, rope_sin_cos
+from ray_tpu.ops.rope import rope_sin_cos
 from ray_tpu.serve.llm import LLMEngine, _bucket, _named_jit
+
+
+def _model_module(cfg):
+    """The module that states ``cfg``'s block, by the config's class (as
+    ``JaxTrainer._resolve_family`` finds a trainer's)."""
+    if isinstance(cfg, llama.LlamaConfig):
+        return llama
+    from ray_tpu.models import olmoe
+
+    if isinstance(cfg, olmoe.OlmoeConfig):
+        return olmoe
+    raise TypeError(
+        f"unsupported model config {type(cfg).__name__}; the paged engine "
+        "serves LlamaConfig and OlmoeConfig")
 
 
 def _write_gather_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip,
@@ -173,6 +201,9 @@ class PagedLLMEngine(LLMEngine):
         self._deferred_free: list[list[int]] = []
         self._decode_cache: dict[tuple[int, int], object] = {}
         self._prefill_cache: dict[int, object] = {}
+        # per dispatched decode chunk, the feed-forward's statistics on
+        # the device until the chunk is emitted (_chunk_facts)
+        self._chunk_stats: deque = deque()
         # prefix cache state: shared (read-only, refcounted) pages per
         # slot, the slot's cached-prefix token count, and the full-page
         # hash chain awaiting registration after its prefill dispatch
@@ -230,6 +261,7 @@ class PagedLLMEngine(LLMEngine):
         tokens, lengths and key; inside it over layers, carrying the
         activations and the same stacked pools (module docstring: in
         place), scanning over the layers' weights and indices."""
+        model = _model_module(cfg)
         num_pages = k_pages.shape[1]
         b, pb = table.shape
         s = pb * page_size
@@ -254,14 +286,7 @@ class PagedLLMEngine(LLMEngine):
             def block(carry, xs):
                 x, kp, vp, ks, vs = carry
                 p, layer = xs
-                h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-                q = (h @ p["wq"]).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-                k = (h @ p["wk"]).reshape(b, 1, cfg.n_kv_heads,
-                                          cfg.head_dim)
-                v = (h @ p["wv"]).reshape(b, 1, cfg.n_kv_heads,
-                                          cfg.head_dim)
-                q = apply_rope(q, sin, cos)
-                k = apply_rope(k, sin, cos)
+                q, k, v = model.attention_projections(cfg, p, x, sin, cos)
                 kp, vp, ks, vs, kg, vg = _write_gather_kv(
                     kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
                     table_c, quantized)
@@ -270,31 +295,34 @@ class PagedLLMEngine(LLMEngine):
                 vg = vg.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
                 attn = _cached_attention(q, kg, vg, pos, scale=scale)
                 x = x + attn.reshape(b, 1, -1) @ p["wo"]
-                h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
-                gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
-                x = x + gated @ p["w_down"]
-                return (x, kp, vp, ks, vs), None
+                x, stats = model.feed_forward(cfg, p, x,
+                                              valid=active[:, None])
+                return (x, kp, vp, ks, vs), stats
 
-            (x, k_pages, v_pages, k_scale, v_scale), _ = jax.lax.scan(
+            (x, k_pages, v_pages, k_scale, v_scale), stats = jax.lax.scan(
                 block, (x, k_pages, v_pages, k_scale, v_scale),
                 (params["blocks"], layers))
             x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)[:, 0]
-            head = llama.lm_head_weights(cfg, params)
+            head = model.lm_head_weights(cfg, params)
             logits = jnp.einsum("bd,dv->bv", x, head,
                                 preferred_element_type=jnp.float32)
             nxt = select_tokens(logits, temps, sub)
             lens = jnp.where(active, lens + 1, lens)
             return (k_pages, v_pages, k_scale, v_scale, nxt, lens,
-                    key), nxt
+                    key), (nxt, stats)
 
-        (k_pages, v_pages, k_scale, v_scale, _, lens, _), toks = \
+        (k_pages, v_pages, k_scale, v_scale, _, lens, _), (toks, stats) = \
             jax.lax.scan(
                 one_step,
                 (k_pages, v_pages, k_scale, v_scale, tokens, lengths,
                  key), None, length=chunk)
         # merged device-resident last-token vector (see llm._decode_impl)
         new_last = jnp.where(active, toks[-1], tokens)
-        return k_pages, v_pages, k_scale, v_scale, toks, lens, new_last
+        # the feed-forward's statistics [chunk, layers], as the chunk's
+        # means (nothing, for a block that hands back none)
+        stats = jax.tree.map(jnp.mean, stats)
+        return (k_pages, v_pages, k_scale, v_scale, toks, lens, new_last,
+                stats)
 
     @staticmethod
     def _paged_prefill_impl(cfg, params, k_pages, v_pages, k_scale,
@@ -310,6 +338,7 @@ class PagedLLMEngine(LLMEngine):
         table_rows: [n, max_pages_per_seq]. The layer scan carries the
         activations and the stacked pools, as decode's does: the
         program holds one pool, the donated one."""
+        model = _model_module(cfg)
         num_pages = k_pages.shape[1]
         n, t = tokens.shape
         mp = table_rows.shape[1]
@@ -331,12 +360,7 @@ class PagedLLMEngine(LLMEngine):
         def block(carry, xs):
             x, kp, vp, ks, vs = carry
             p, layer = xs
-            h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-            q = (h @ p["wq"]).reshape(n, t, cfg.n_heads, cfg.head_dim)
-            k = (h @ p["wk"]).reshape(n, t, cfg.n_kv_heads, cfg.head_dim)
-            v = (h @ p["wv"]).reshape(n, t, cfg.n_kv_heads, cfg.head_dim)
-            q = apply_rope(q, sin, cos)
-            k = apply_rope(k, sin, cos)
+            q, k, v = model.attention_projections(cfg, p, x, sin, cos)
             kp, vp, ks, vs, kg, vg = _write_gather_kv(
                 kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, table_c,
                 quantized)
@@ -348,9 +372,7 @@ class PagedLLMEngine(LLMEngine):
             vg = vg.reshape(n, s, cfg.n_kv_heads, cfg.head_dim)
             attn = _cached_attention(q, kg, vg, starts, scale=scale)
             x = x + attn.reshape(n, t, -1) @ p["wo"]
-            h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
-            gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
-            x = x + gated @ p["w_down"]
+            x, _ = model.feed_forward(cfg, p, x, valid=valid)
             return (x, kp, vp, ks, vs), None
 
         (x, k_pages, v_pages, k_scale, v_scale), _ = jax.lax.scan(
@@ -359,7 +381,7 @@ class PagedLLMEngine(LLMEngine):
         x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
         x = jnp.take_along_axis(
             x, (slens - 1)[:, None, None], axis=1).squeeze(1)
-        head = llama.lm_head_weights(cfg, params)
+        head = model.lm_head_weights(cfg, params)
         logits = jnp.einsum("bd,dv->bv", x, head,
                             preferred_element_type=jnp.float32)
         first = select_tokens(logits, temps, key)
@@ -395,12 +417,22 @@ class PagedLLMEngine(LLMEngine):
             # in-flight chunk a torn table
             dev[key] = jnp.asarray(self._table[:, :pb].copy())
         (self._k_pages, self._v_pages, self._k_scale, self._v_scale,
-         toks, lens, new_last) = fn(
+         toks, lens, new_last, stats) = fn(
             self.params, self._k_pages, self._v_pages, self._k_scale,
             self._v_scale, dev[key], last_tok, dev["lens"],
             dev["active"], dev["temps"], self._next_key(),
         )
+        self._chunk_stats.append(stats)
         return toks, lens, new_last
+
+    def _chunk_facts(self, recording: bool) -> dict:
+        """The feed-forward's statistics of the chunk being emitted
+        (chunks are emitted in the order they were dispatched). They came
+        out of the program whose tokens the loop has just read, so reading
+        them waits for nothing."""
+        stats = self._chunk_stats.popleft() if self._chunk_stats else {}
+        return ({name: float(v) for name, v in stats.items()}
+                if recording else {})
 
     def _reserve_slot_resources(self, req, slot: int) -> bool:
         """Reserve-on-admit: pages for prompt + token budget + one page
@@ -665,7 +697,7 @@ class PagedLLMEngine(LLMEngine):
             for chunk in {self.decode_chunk, self._drain_chunk}:
                 fn = self._decode_paged(chunk, pb)
                 (self._k_pages, self._v_pages, self._k_scale,
-                 self._v_scale, toks, _, _) = fn(
+                 self._v_scale, toks, _, _, _) = fn(
                     self.params, self._k_pages, self._v_pages,
                     self._k_scale, self._v_scale,
                     jnp.full((self.max_batch, pb), -1, jnp.int32),

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, olmoe
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel.mesh import create_mesh
 from ray_tpu.serve.paged_llm import PagedLLMEngine
@@ -117,11 +117,47 @@ def test_fsdp4_step_compiles_with_flash(v5e_2x2):
 _D12 = dict(vocab_size=32768, d_model=4096, n_layers=12, n_heads=32,
             n_kv_heads=8, head_dim=128, d_ff=14336, rope_theta=1e6,
             tie_embeddings=False)
-_D12_PAGES, _D12_PAGE, _D12_SLOTS = 544, 128, 32
+_D12_PAGES = 544
 # an operation that moves one layer's pool, or the stacked pool, whole
 _POOL_COPY = re.compile(
     r"= bf16\[(?:12,|1,)?544,128,8,128\]\S* "
     r"(?:copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _compile_engine_program(device, model, cfg, num_pages, program, dims,
+                            slots=32, page=128):
+    """One of the paged engine's two programs for ``cfg`` (whose block
+    ``model`` states), compiled for ``device`` from shapes alone.
+    ``dims``: decode (chunk, window pages); prefill (prompts, tokens,
+    window pages)."""
+    one = SingleDeviceSharding(device)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(partial(model.init_params, cfg), jax.random.key(0)))
+    pool = shape((cfg.n_layers, num_pages, page, cfg.n_kv_heads,
+                  cfg.head_dim), jnp.bfloat16)
+    scale = shape((cfg.n_layers, 1, 1, 1), jnp.float32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    if program == "decode":
+        chunk, pages = dims
+        fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
+                     page_size=page, quantized=False)
+        args = (shape((slots, pages), jnp.int32), shape((slots,), jnp.int32),
+                shape((slots,), jnp.int32), shape((slots,), jnp.bool_),
+                shape((slots,), jnp.float32), key)
+    else:
+        n, tokens, pages = dims
+        fn = partial(PagedLLMEngine._paged_prefill_impl, cfg,
+                     page_size=page, quantized=False)
+        args = (shape((n, pages), jnp.int32), shape((n, tokens), jnp.int32),
+                shape((n,), jnp.int32), shape((n,), jnp.int32),
+                shape((n,), jnp.float32), key)
+    return jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        params, pool, pool, scale, scale, *args).compile()
 
 
 # program, its dimensions (decode: chunk, window pages; prefill: prompts,
@@ -146,35 +182,43 @@ def test_d12_engine_programs_keep_the_pool_in_place(v5e_2x2, program, dims,
     their temporaries stay under one pool's 3.4 GB (with a second pool
     they are 5.1-8.1 GB), and four 2048-token prompts, which the
     compiler then refuses for HBM, fit at 4.4 GB."""
-    one = SingleDeviceSharding(v5e_2x2[0])
-
-    def shape(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
-
-    cfg = llama.LlamaConfig(**_D12)
-    params = jax.tree.map(
-        lambda a: shape(a.shape, a.dtype),
-        jax.eval_shape(partial(llama.init_params, cfg), jax.random.key(0)))
-    pool = shape((cfg.n_layers, _D12_PAGES, _D12_PAGE, cfg.n_kv_heads,
-                  cfg.head_dim), jnp.bfloat16)
-    scale = shape((cfg.n_layers, 1, 1, 1), jnp.float32)
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    if program == "decode":
-        chunk, pages = dims
-        b = _D12_SLOTS
-        fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
-                     page_size=_D12_PAGE, quantized=False)
-        args = (shape((b, pages), jnp.int32), shape((b,), jnp.int32),
-                shape((b,), jnp.int32), shape((b,), jnp.bool_),
-                shape((b,), jnp.float32), key)
-    else:
-        n, tokens, pages = dims
-        fn = partial(PagedLLMEngine._paged_prefill_impl, cfg,
-                     page_size=_D12_PAGE, quantized=False)
-        args = (shape((n, pages), jnp.int32), shape((n, tokens), jnp.int32),
-                shape((n,), jnp.int32), shape((n,), jnp.int32),
-                shape((n,), jnp.float32), key)
-    compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
-        params, pool, pool, scale, scale, *args).compile()
+    compiled = _compile_engine_program(
+        v5e_2x2[0], llama, llama.LlamaConfig(**_D12), _D12_PAGES, program,
+        dims)
     assert not _POOL_COPY.findall(compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+
+
+# the expert cell's engine (PR 28): OLMoE-1B-7B widths cut to 10 layers,
+# 32 slots x 1024 tokens, 352 KV pages of 128 tokens (16 KV heads)
+_MOE_PAGES, _MOE_LAYERS = 352, 10
+_MOE_PROGRAMS = [
+    ("decode", (16, 8), False), ("decode", (8, 8), False),
+    ("prefill", (2, 512, 4), False), ("prefill", (1, 1024, 8), False),
+    ("prefill", (2, 1024, 8), True)]
+
+
+@pytest.mark.parametrize(
+    "program,dims,grouped", _MOE_PROGRAMS,
+    ids=[f"{p}-{'x'.join(map(str, d))}" for p, d, _ in _MOE_PROGRAMS])
+def test_olmoe_d10_engine_programs_compile_and_fit(v5e_2x2, program, dims,
+                                                   grouped):
+    """The same two engine programs around OLMoE's block: they compile
+    for the chip beside 8.8 GB of weights and a 3.7 GB pool, keep the
+    pool in place, and take the dropless op's formulation from their
+    token count: every expert over every token up to 1,024 tokens (no
+    grouped matmul in the program), the chosen experts alone past it
+    (XLA's ragged-dot kernel, three times a layer)."""
+    cfg = dataclasses.replace(olmoe.olmoe_1b_7b(), n_layers=_MOE_LAYERS)
+    compiled = _compile_engine_program(v5e_2x2[0], olmoe, cfg, _MOE_PAGES,
+                                       program, dims)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert ("ragged-dot" in text) == grouped
+    assert not re.findall(
+        r"= bf16\[(?:10,|1,)?352,128,16,128\]\S* "
+        r"(?:copy|dynamic-slice|dynamic-update-slice)\(", text)
+    pool_bytes = _MOE_LAYERS * _MOE_PAGES * 128 * 16 * 128 * 2
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes        # pools in place
+    assert mem.temp_size_in_bytes < 0.8e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            - mem.alias_size_in_bytes) < 13.5e9              # of 15.75 GB
