@@ -3,9 +3,11 @@
 Reference: ABSENT from the reference repo (it serves models via user
 code in replicas — SURVEY P15); this is the vLLM-style PagedAttention
 scheme rebuilt TPU-first: the KV cache is a pool of fixed-size pages,
-each sequence owns a page table, and the decode step gathers its pages
-with static shapes (gather + mask — XLA-friendly; a Pallas kernel can
-swap in later without changing the interface).
+each sequence owns a page table, and ``paged_attention`` here gathers a
+sequence's pages with static shapes (gather + mask — XLA-friendly; the
+unjitted helper of the tests and of small callers). The serving
+engine's decode step does not gather: its Pallas kernel reads the pages
+where they lie (``ops/paged_decode_attention.py``, same layout below).
 
 Why paging: the slot-based cache (ray_tpu/models/decoding.py KVCache)
 reserves max_len per slot — a 2048-token cache for an 80-token chat

@@ -1,0 +1,240 @@
+"""Decode attention over the KV pages where they lie: one Pallas kernel.
+
+Reference: ABSENT from the reference repo (SURVEY P15). The paged
+engine's decode step used to gather every slot's page window out of the
+pool ([B x PB, page, nkv, hd], a copy as large as the window, per layer
+per step) and attend over the copy. This kernel reads each slot's pages
+from the STACKED pool [L, P, page, nkv, hd] in place, as the engine
+holds it, and stops at each slot's length:
+
+- the pools stay in HBM (``pl.ANY``); the layer index, the flattened page
+  table, the per-slot key counts and the next-live-slot chain come
+  through scalar prefetch (SMEM);
+- one invocation walks the live slots in order and, per slot, the pages
+  up to ``ceil(keys / page)``. A page [page, nkv, hd] is contiguous in
+  the pool: ONE async copy brings every KV head of it into VMEM, double
+  buffered, and the copy of the next page (of this slot or of the next
+  live slot) is in flight while this one is computed. A slot with no
+  keys (inactive) costs nothing; pages past a slot's length are neither
+  fetched nor computed;
+- the page is read as the rows [page * nkv, hd]: ONE matmul scores every
+  query head against every (token, kv head) row, and a mask keeps, for
+  query head r, the rows of ITS kv head (r // group) at key positions
+  under the slot's count. The online softmax then runs over exactly the
+  keys ``_cached_attention`` sees, and the masked probabilities are
+  zero, so the second matmul (probabilities x the same rows of V) is the
+  grouped weighted sum. Grouped (GQA, 4 query heads a KV head) and plain
+  (MHA) attention are the same code at different shapes, and no head is
+  ever sliced out of a page (a strided sublane read of packed bf16);
+- scores and softmax state in float32, probabilities cast to the pages'
+  dtype before the weighted sum, as ``_cached_attention`` does;
+- int8 pages: the per-(token, head) scales multiply the score COLUMNS
+  (K) and the probability columns (V), which is the dequantisation done
+  in VMEM after the matmul instead of on a window copy before it. The
+  window's scales (1/32 of its bytes) are gathered by XLA into rows.
+
+``paged_decode_attention`` is the entry: on a program LOWERED for a TPU
+it is the kernel, on any other platform the plain gather formulation
+(``paged_decode_attention_reference``), chosen by
+``jax.lax.platform_dependent`` and by nothing else.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.models.decoding import _cached_attention
+from ray_tpu.ops.paged_attention import dequantize_kv
+
+KERNEL_NAME = "paged_decode_attn"
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+_BUFFERS = 2
+
+
+def gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer, table):
+    """Layer ``layer``'s ``table`` page window of every row [B, PB, page,
+    nkv, hd], copied out of the stacked pools (holes read page 0; the
+    caller's causal limit masks them) and dequantised to bf16 if the pages
+    are int8: what the gather formulation and the engine's PREFILL attend
+    over."""
+    table_c = jnp.maximum(table, 0)
+    kg, vg = k_pages[layer, table_c], v_pages[layer, table_c]
+    if k_pages.dtype == jnp.int8:
+        kg = dequantize_kv(kg, k_scale[layer, table_c])
+        vg = dequantize_kv(vg, v_scale[layer, table_c])
+    return kg, vg
+
+
+def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
+                                     layer, table, pos, active):
+    """The gather formulation: every slot's window copied out
+    (``gather_kv_window``), then ``_cached_attention`` over the copy with
+    the causal limit ``key position <= pos``. What the kernel is held to,
+    and what every platform but the TPU runs."""
+    del active      # a dead slot attends over page 0; its row is discarded
+    b, _, hd = q.shape
+    kg, vg = gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer,
+                              table)
+    nkv = kg.shape[-2]
+    out = _cached_attention(q[:, None], kg.reshape(b, -1, nkv, hd),
+                            vg.reshape(b, -1, nkv, hd), pos,
+                            scale=hd ** -0.5)
+    return out[:, 0]
+
+
+def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
+            q_ref, k_hbm, v_hbm, *rest, pages_per_slot, quantized):
+    """See the module docstring. ``rest``: the window's scale rows (int8
+    only), the output, the page buffers and their DMA semaphores."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sem = rest
+    else:
+        o_ref, k_buf, v_buf, sem = rest
+    slots, nh, hd = q_ref.shape
+    page, nkv = k_hbm.shape[2], k_hbm.shape[3]
+    rows = page * nkv
+    scale = hd ** -0.5
+    layer = layer_ref[0]
+    # what the gather formulation attends over: the pages' own type, or
+    # the dequantised bf16 window
+    kv_dtype = jnp.bfloat16 if quantized else k_buf.dtype
+
+    def copies(slot, block, buf):
+        p = table_ref[slot * pages_per_slot + block]
+        return (pltpu.make_async_copy(k_hbm.at[layer, p], k_buf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[layer, p], v_buf.at[buf],
+                                      sem.at[1, buf]))
+
+    def start(slot, block, buf):
+        for c in copies(slot, block, buf):
+            c.start()
+
+    # which query head may see which row of a page: row = token * nkv + kv
+    # head; head r reads kv head r // group
+    col = lax.broadcasted_iota(jnp.int32, (nh, rows), 1)
+    row = lax.broadcasted_iota(jnp.int32, (nh, rows), 0)
+    own_head = (col % nkv) == (row // (nh // nkv))
+    token = col // nkv
+
+    first = next_ref[0]
+
+    @pl.when(first < slots)
+    def _():
+        start(first, 0, 0)
+
+    def slot_body(slot, step):
+        count = count_ref[slot]
+        # never past the table's row (the gather formulation's window
+        # ends there too; the engine's reservations keep counts inside)
+        n_blocks = jnp.minimum((count + page - 1) // page, pages_per_slot)
+
+        def block_body(i, carry):
+            m, l, acc, step = carry
+            buf = step % _BUFFERS
+            more = i + 1 < n_blocks
+            nslot = jnp.where(more, slot, next_ref[slot + 1])
+            nblock = jnp.where(more, i + 1, 0)
+
+            @pl.when(nslot < slots)
+            def _():
+                start(nslot, nblock, (step + 1) % _BUFFERS)
+
+            k_copy, v_copy = copies(slot, i, buf)
+            k_copy.wait()
+            q = q_ref[slot]
+            k = k_buf[buf].reshape(rows, hd).astype(kv_dtype)
+            s = lax.dot_general(q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if quantized:
+                s = s * ks_ref[slot, pl.ds(i, 1), :]
+            s = jnp.where(own_head & (token < count - i * page), s, _MASKED)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + p.sum(axis=-1, keepdims=True)
+            v_copy.wait()
+            v = v_buf[buf].reshape(rows, hd).astype(kv_dtype)
+            if quantized:
+                p = p * vs_ref[slot, pl.ds(i, 1), :]
+            acc = alpha * acc + lax.dot_general(
+                p.astype(kv_dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc, step + 1
+
+        m, l, acc, step = lax.fori_loop(
+            0, n_blocks, block_body,
+            (jnp.full((nh, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((nh, 1), jnp.float32),
+             jnp.zeros((nh, hd), jnp.float32), step))
+        # a slot with no keys: zeros (l == 0), never a NaN
+        o_ref[slot] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+        return step
+
+    lax.fori_loop(0, slots, slot_body, 0)
+
+
+def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
+                                  layer, table, pos, active, *,
+                                  interpret=False):
+    """The kernel's launch; arguments as ``paged_decode_attention``."""
+    slots, nh, hd = q.shape
+    _, _, page, nkv, _ = k_pages.shape
+    pb = table.shape[1]
+    quantized = k_pages.dtype == jnp.int8
+    count = jnp.where(active, pos + 1, 0).astype(jnp.int32)
+    # next_live[0]: the first slot with keys; next_live[s + 1]: the first
+    # after s (``slots`` when there is none)
+    idx = jnp.arange(slots, dtype=jnp.int32)
+    live_at = jnp.where(count > 0, idx, slots)
+    after = lax.cummin(live_at, reverse=True)
+    next_live = jnp.concatenate([after, jnp.full((1,), slots, jnp.int32)])
+    table_c = jnp.maximum(table, 0).astype(jnp.int32)
+    operands = [q, k_pages, v_pages]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    if quantized:
+        # the window's scales as rows [B, PB, page * nkv]: 1/32 of the
+        # window's bytes, laid out as the score columns are
+        operands += [k_scale[layer, table_c].reshape(slots, pb, page * nkv),
+                     v_scale[layer, table_c].reshape(slots, pb, page * nkv)]
+        in_specs += [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2
+    return pl.pallas_call(
+        functools.partial(_kernel, pages_per_slot=pb, quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(1,), in_specs=in_specs,
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((_BUFFERS, page, nkv, hd), k_pages.dtype),
+                pltpu.VMEM((_BUFFERS, page, nkv, hd), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, _BUFFERS))]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret, name=KERNEL_NAME,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), table_c.reshape(-1), count,
+      next_live, *operands)
+
+
+def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
+                           table, pos, active):
+    """One decode step's attention for every slot, scores scaled by
+    head_dim ** -0.5. q [B, nh, hd]; stacked pools [L, P, page, nkv, hd]
+    (bf16, or int8 with their scale pools [L, P, page, nkv]); ``layer`` a
+    scalar; ``table`` [B, PB] page ids (-1 = hole); slot b attends key
+    positions <= pos[b] of its pages if ``active[b]`` (a dead slot's row
+    is unspecified, and discarded). Returns [B, nh, hd] in q's dtype.
+
+    The two branches are the module's own functions, not closures made a
+    call: JAX then keeps their traces, and an engine's decode programs
+    that differ in their chunk alone trace them once (a trace of both is
+    0.2-0.3 s of a program's set-up on the chip's host)."""
+    return lax.platform_dependent(
+        q, k_pages, v_pages, k_scale, v_scale, layer, table, pos, active,
+        tpu=paged_decode_attention_kernel,
+        default=paged_decode_attention_reference)

@@ -10,12 +10,17 @@ continuous-batching loop of ``serve/llm.py``:
   (reserved per request = prompt + max_new_tokens), not with
   ``max_batch * max_len`` — a 256-token chat on a 2048-token engine
   stops reserving 8x its need.
-- Decode attends over a BUCKETED page window: the gather width is the
-  power-of-two page count covering the longest RESERVED page list among
-  the live slots (prompt + budget + one overshoot page: ``_pages_bucket``;
-  not the longest live sequence), so short workloads read a fraction of
-  the dense cache's KV bytes per step (the dominant decode-step HBM
-  traffic at small models).
+- Decode attends over the pages WHERE THEY LIE: after a layer's new row
+  is written, one Pallas kernel (``ops/paged_decode_attention.py``)
+  reads each live slot's pages of that layer from the stacked pool, up
+  to the slot's length and no further; a dead slot costs nothing. No
+  window is gathered and no copy of one exists. The page table a decode
+  program takes is still BUCKETED (the power-of-two page count covering
+  the longest RESERVED page list among the live slots: ``_pages_bucket``),
+  which now only sets the table's width, not the bytes a step reads. On
+  a platform other than the TPU the same call is the plain formulation
+  (gather the window, ``_cached_attention``), chosen where the program
+  is lowered; nothing sets it.
 - Allocation is reserve-on-admit (pages for prompt + budget + one
   chained-overshoot page, released at retirement): admission applies
   backpressure when the pool is exhausted, and a mid-flight sequence
@@ -23,17 +28,22 @@ continuous-batching loop of ``serve/llm.py``:
   allocation + preemption is a future extension).
 - ``kv_dtype="int8"`` stores pages quantized (per-token-per-head
   symmetric scales in a parallel scale pool): half the KV HBM, so the
-  same pool holds 2x the tokens in flight. Dequantization happens on
-  gather — a VPU cost per decode step — so it's a CAPACITY trade, the
-  right default only when KV footprint is the binding constraint
-  (long contexts / many concurrent slots); at small windows where
-  decode is weight-read-bound it measures ~35% slower (v5e, 0.5B).
+  same pool holds 2x the tokens in flight. Decode dequantizes in VMEM,
+  inside the kernel (the scales multiply the scores and the
+  probabilities; only the window's scales, 1/32 of its bytes, are
+  gathered); prefill dequantizes the window it gathers. The kernel is
+  compute-bound over int8 pages (conversion on the VPU), so a step's
+  attention takes about as long as over bf16 pages (v5e, kernel alone:
+  326 against 283 us a layer at 32 slots of 1-1.9k tokens): int8 is a
+  CAPACITY trade, the right default only when KV footprint is the
+  binding constraint (long contexts / many concurrent slots).
 
 - The device programs keep the pools IN PLACE: the layer loop carries
   the stacked pools (and scale pools) whole, beside the activations,
   and scans over (layer weights, layer index); a layer scatters its new
-  rows at [layer, page, offset] and gathers its page window at
-  [layer, table] (``_write_gather_kv``). Scanning OVER the pools
+  rows at [layer, page, offset] (``_write_kv``) and reads its pages at
+  [layer, table]: decode in the kernel, prefill by gathering its window
+  (``gather_kv_window``). Scanning OVER the pools
   instead hands each layer a slice: XLA then copies every layer's K
   and V pool out and back, every layer of every step, and the prefill
   program holds a second pool (measured on a v5e at 12 layers x 544
@@ -44,7 +54,8 @@ continuous-batching loop of ``serve/llm.py``:
   (``attention_projections``: norm, q/k/v, whatever the block does to
   them, rotary), the feed-forward (``feed_forward``: a dense SwiGLU, or
   routed experts) and the output head (``lm_head_weights``). What is the
-  ENGINE's stays here, once for every model: the page write and gather,
+  ENGINE's stays here, once for every model: the page write, decode's
+  attention over the pages (the kernel), prefill's gather and
   ``_cached_attention``, the layer scan, sampling, the chunk loop. A
   feed-forward may hand back statistics of its call (scalars; a dense
   one has none): the decode program averages them over the chunk's
@@ -68,8 +79,9 @@ from ray_tpu.models.decoding import (_cached_attention,
                                      select_tokens)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
-                                         dequantize_kv, page_hashes,
-                                         quantize_kv)
+                                         page_hashes, quantize_kv)
+from ray_tpu.ops.paged_decode_attention import (gather_kv_window,
+                                                paged_decode_attention)
 from ray_tpu.ops.rope import rope_sin_cos
 from ray_tpu.serve.llm import LLMEngine, _bucket, _named_jit
 
@@ -88,20 +100,17 @@ def _model_module(cfg):
         "serves LlamaConfig and OlmoeConfig")
 
 
-def _write_gather_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip,
-                     table_c, quantized):
-    """THE write-then-gather KV protocol, shared by decode and prefill
-    (shape-generic: decode writes one token per slot with [B] indices,
-    prefill a padded suffix with [n, T] indices), on the STACKED pools
-    [L, P, page, nkv, hd] (+ scale pools in int8 mode) at layer
-    ``layer``. Writes k/v at (layer, pidx, ip) with out-of-bounds
-    indices dropping, then gathers layer ``layer``'s table_c page
-    window, dequantizing if quantized.
+def _write_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip, quantized):
+    """THE KV write, shared by decode and prefill (shape-generic: decode
+    writes one token per slot with [B] indices, prefill a padded suffix
+    with [n, T] indices), on the STACKED pools [L, P, page, nkv, hd]
+    (+ scale pools in int8 mode) at layer ``layer``: k/v land at
+    (layer, pidx, ip), out-of-bounds indices dropping.
 
     The pools come in whole and go out whole: all that is written is
     the new rows (a scatter, in place on the buffer the layer loop
-    carries), all that is read is the page window. Write before gather,
-    so the window holds the rows just written."""
+    carries). Write before any read of the layer's pages, so the reader
+    sees the rows just written."""
     if quantized:
         kq, ksc = quantize_kv(k_new)
         vq, vsc = quantize_kv(v_new)
@@ -109,15 +118,12 @@ def _write_gather_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip,
         vp = vp.at[layer, pidx, ip].set(vq, mode="drop")
         ks = ks.at[layer, pidx, ip].set(ksc, mode="drop")
         vs = vs.at[layer, pidx, ip].set(vsc, mode="drop")
-        kg = dequantize_kv(kp[layer, table_c], ks[layer, table_c])
-        vg = dequantize_kv(vp[layer, table_c], vs[layer, table_c])
     else:
         kp = kp.at[layer, pidx, ip].set(k_new.astype(kp.dtype),
                                         mode="drop")
         vp = vp.at[layer, pidx, ip].set(v_new.astype(vp.dtype),
                                         mode="drop")
-        kg, vg = kp[layer, table_c], vp[layer, table_c]
-    return kp, vp, ks, vs, kg, vg
+    return kp, vp, ks, vs
 
 
 class PagedLLMEngine(LLMEngine):
@@ -253,20 +259,18 @@ class PagedLLMEngine(LLMEngine):
     def _paged_decode_impl(cfg, params, k_pages, v_pages, k_scale,
                            v_scale, table, tokens, lengths, active,
                            temps, key, *, chunk, page_size, quantized):
-        """``chunk`` decode steps over every slot; KV pages written and
-        gathered through the (bucketed) page table [B, PB]. In int8
-        mode (``quantized``) writes quantize per token+head and gathers
-        dequantize against the scale pages — half the KV bytes per
-        step. Two nested scans: over steps, carrying the pools, last
-        tokens, lengths and key; inside it over layers, carrying the
-        activations and the same stacked pools (module docstring: in
-        place), scanning over the layers' weights and indices."""
+        """``chunk`` decode steps over every slot; KV rows written, then
+        attended over where they lie, through the (bucketed) page table
+        [B, PB]. In int8 mode (``quantized``) writes quantize per
+        token+head and the kernel dequantizes against the scale pages —
+        half the KV bytes per step. Two nested scans: over steps,
+        carrying the pools, last tokens, lengths and key; inside it
+        over layers, carrying the activations and the same stacked
+        pools (module docstring: in place), scanning over the layers'
+        weights and indices."""
         model = _model_module(cfg)
         num_pages = k_pages.shape[1]
-        b, pb = table.shape
-        s = pb * page_size
-        scale = cfg.head_dim ** -0.5
-        table_c = jnp.maximum(table, 0)
+        b = table.shape[0]
         layers = jnp.arange(k_pages.shape[0])
 
         def one_step(carry, _):
@@ -287,13 +291,13 @@ class PagedLLMEngine(LLMEngine):
                 x, kp, vp, ks, vs = carry
                 p, layer = xs
                 q, k, v = model.attention_projections(cfg, p, x, sin, cos)
-                kp, vp, ks, vs, kg, vg = _write_gather_kv(
+                kp, vp, ks, vs = _write_kv(
                     kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
-                    table_c, quantized)
-                # this slot's window [B, PB, page, nkv, hd]
-                kg = kg.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-                vg = vg.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-                attn = _cached_attention(q, kg, vg, pos, scale=scale)
+                    quantized)
+                # each live slot's pages up to its length, read where
+                # they lie; the row just written is among them
+                attn = paged_decode_attention(
+                    q[:, 0], kp, vp, ks, vs, layer, table, pos, active)
                 x = x + attn.reshape(b, 1, -1) @ p["wo"]
                 x, stats = model.feed_forward(cfg, p, x,
                                               valid=active[:, None])
@@ -355,15 +359,14 @@ class PagedLLMEngine(LLMEngine):
         pidx_all = jnp.where((pidx_all >= 0) & valid, pidx_all,
                              num_pages)
         ip_all = positions % page_size
-        table_c = jnp.maximum(table_rows, 0)
 
         def block(carry, xs):
             x, kp, vp, ks, vs = carry
             p, layer = xs
             q, k, v = model.attention_projections(cfg, p, x, sin, cos)
-            kp, vp, ks, vs, kg, vg = _write_gather_kv(
-                kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, table_c,
-                quantized)
+            kp, vp, ks, vs = _write_kv(
+                kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, quantized)
+            kg, vg = gather_kv_window(kp, vp, ks, vs, layer, table_rows)
             # gather the whole window AFTER the suffix writes: queries
             # attend over cached prefix + their own fresh KV; positions
             # beyond start+i are masked causally, stale page contents

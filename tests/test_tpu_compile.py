@@ -118,18 +118,35 @@ _D12 = dict(vocab_size=32768, d_model=4096, n_layers=12, n_heads=32,
             n_kv_heads=8, head_dim=128, d_ff=14336, rope_theta=1e6,
             tie_embeddings=False)
 _D12_PAGES = 544
-# an operation that moves one layer's pool, or the stacked pool, whole
-_POOL_COPY = re.compile(
-    r"= bf16\[(?:12,|1,)?544,128,8,128\]\S* "
-    r"(?:copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _pool_copy(layers, pages, nkv):
+    """An operation that moves one layer's pool, or the stacked pool,
+    whole (bf16 or int8 pages)."""
+    return re.compile(
+        rf"= (?:bf16|s8)\[(?:{layers},|1,)?{pages},128,{nkv},128\]\S* "
+        r"(?:copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+# the decode kernel's instruction, under its name (PR 30)
+_DECODE_KERNEL = re.compile(
+    r"%paged_decode_attn[.\d]* = \S+ custom-call\(.*tpu_custom_call")
+
+
+def _window(slots, pages, nkv):
+    """An operation whose result is a page window of every slot (the
+    gather decode attended over before PR 30, or a float32 copy of it)."""
+    return re.compile(rf"= (?:bf16|f32|s8)\[(?:{slots * pages}|"
+                      rf"{slots},{pages}),128,{nkv},128\]")
 
 
 def _compile_engine_program(device, model, cfg, num_pages, program, dims,
-                            slots=32, page=128):
+                            slots=32, page=128, kv_dtype="bf16"):
     """One of the paged engine's two programs for ``cfg`` (whose block
     ``model`` states), compiled for ``device`` from shapes alone.
     ``dims``: decode (chunk, window pages); prefill (prompts, tokens,
-    window pages)."""
+    window pages). ``kv_dtype``: the engine's, bf16 or int8 pages (these
+    with their scale pools)."""
     one = SingleDeviceSharding(device)
 
     def shape(dims, dtype):
@@ -138,21 +155,23 @@ def _compile_engine_program(device, model, cfg, num_pages, program, dims,
     params = jax.tree.map(
         lambda a: shape(a.shape, a.dtype),
         jax.eval_shape(partial(model.init_params, cfg), jax.random.key(0)))
-    pool = shape((cfg.n_layers, num_pages, page, cfg.n_kv_heads,
-                  cfg.head_dim), jnp.bfloat16)
-    scale = shape((cfg.n_layers, 1, 1, 1), jnp.float32)
+    quantized = kv_dtype == "int8"
+    pool_dims = (cfg.n_layers, num_pages, page, cfg.n_kv_heads, cfg.head_dim)
+    pool = shape(pool_dims, jnp.int8 if quantized else jnp.bfloat16)
+    scale = shape(pool_dims[:-1] if quantized else (cfg.n_layers, 1, 1, 1),
+                  jnp.float32)
     key = jax.eval_shape(lambda: jax.random.key(0))
     if program == "decode":
         chunk, pages = dims
         fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
-                     page_size=page, quantized=False)
+                     page_size=page, quantized=quantized)
         args = (shape((slots, pages), jnp.int32), shape((slots,), jnp.int32),
                 shape((slots,), jnp.int32), shape((slots,), jnp.bool_),
                 shape((slots,), jnp.float32), key)
     else:
         n, tokens, pages = dims
         fn = partial(PagedLLMEngine._paged_prefill_impl, cfg,
-                     page_size=page, quantized=False)
+                     page_size=page, quantized=quantized)
         args = (shape((n, pages), jnp.int32), shape((n, tokens), jnp.int32),
                 shape((n,), jnp.int32), shape((n,), jnp.int32),
                 shape((n,), jnp.float32), key)
@@ -185,8 +204,37 @@ def test_d12_engine_programs_keep_the_pool_in_place(v5e_2x2, program, dims,
     compiled = _compile_engine_program(
         v5e_2x2[0], llama, llama.LlamaConfig(**_D12), _D12_PAGES, program,
         dims)
-    assert not _POOL_COPY.findall(compiled.as_text())
+    text = compiled.as_text()
+    assert not _pool_copy(12, _D12_PAGES, 8).findall(text)
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    # decode reads the pages where they lie (PR 30): the kernel under its
+    # name, four query heads a KV head, and no window of the slots' pages
+    assert bool(_DECODE_KERNEL.search(text)) == (program == "decode")
+    if program == "decode":
+        assert not _window(32, dims[1], 8).findall(text)
+
+
+@pytest.mark.parametrize("model,pages,window", [
+    ("d12", _D12_PAGES, 16), ("olmoe-d10", 352, 8)])
+def test_decode_programs_compile_over_int8_pages(v5e_2x2, model, pages,
+                                                 window):
+    """The same decode program over int8 pages and their scale pools:
+    the same kernel (the pool's dtype is all that differs), dequantising
+    in VMEM; no window of the pages in any type, no pool moved whole. The
+    window's SCALES are gathered (1/32 of its bytes)."""
+    if model == "d12":
+        module, cfg = llama, llama.LlamaConfig(**_D12)
+    else:
+        module = olmoe
+        cfg = dataclasses.replace(olmoe.olmoe_1b_7b(), n_layers=10)
+    compiled = _compile_engine_program(
+        v5e_2x2[0], module, cfg, pages, "decode", (16, window),
+        kv_dtype="int8")
+    text = compiled.as_text()
+    assert _DECODE_KERNEL.search(text)
+    assert not _window(32, window, cfg.n_kv_heads).findall(text)
+    assert not _pool_copy(cfg.n_layers, pages, cfg.n_kv_heads).findall(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
 
 
 # the expert cell's engine (PR 28): OLMoE-1B-7B widths cut to 10 layers,
@@ -214,9 +262,12 @@ def test_olmoe_d10_engine_programs_compile_and_fit(v5e_2x2, program, dims,
                                        program, dims)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert ("ragged-dot" in text) == grouped
-    assert not re.findall(
-        r"= bf16\[(?:10,|1,)?352,128,16,128\]\S* "
-        r"(?:copy|dynamic-slice|dynamic-update-slice)\(", text)
+    assert not _pool_copy(_MOE_LAYERS, _MOE_PAGES, 16).findall(text)
+    # decode: the same kernel at one query head a KV head (MHA), and
+    # neither the window nor a float32 copy of it
+    assert bool(_DECODE_KERNEL.search(text)) == (program == "decode")
+    if program == "decode":
+        assert not _window(32, dims[1], 16).findall(text)
     pool_bytes = _MOE_LAYERS * _MOE_PAGES * 128 * 16 * 128 * 2
     assert mem.alias_size_in_bytes >= 2 * pool_bytes        # pools in place
     assert mem.temp_size_in_bytes < 0.8e9
