@@ -1,16 +1,19 @@
-"""Autoregressive decoding: KV cache, prefill/decode, sampling, generation.
+"""The library's plain decode: KV cache, prefill/decode, sampling, generation.
 
-TPU-first design (net-new capability vs the reference, which serves models
-only through user code inside Serve replicas — `python/ray/serve/`, P15):
+Net-new capability vs the reference, which serves models only through user
+code inside Serve replicas (`python/ray/serve/`, P15). This module is the
+straightforward formulation: offline/eval generation (`generate`), the
+substrate of `ray_tpu.models.speculative`, and the ORACLE the tests hold
+the serving engine (`ray_tpu.serve.paged_llm`) to. The engine does not
+run it: its programs keep a paged KV pool (`ray_tpu.ops.paged_attention`)
+and share with this module only `select_tokens` and
+`ops.attention.cached_attention`.
 
 - One **unified cached forward** handles prefill (T=prompt) and decode (T=1):
-  static shapes, per-sequence write offsets via vmapped dynamic slicing, so
-  a single compiled program serves every step of continuous batching.
-- The KV cache is slot-based: `[layers, max_batch, max_len, kv_heads, hd]`.
-  A "slot" is one row of the batch; the serving engine (ray_tpu.serve.llm)
-  assigns/frees slots as requests arrive/finish. All control flow that
-  depends on which slots are live is expressed as masks, never Python
-  branches — the decode program never recompiles.
+  static shapes, per-sequence write offsets via vmapped dynamic slicing.
+- The KV cache is contiguous rows: `[layers, batch, max_len, kv_heads, hd]`,
+  one row a sequence. Which rows are live is the caller's bookkeeping,
+  expressed as masks, never Python branches.
 - Layers run under `lax.scan` with the cache as scanned xs/ys, matching the
   stacked-block layout of `ray_tpu.models.llama`.
 - Sampling (greedy/temperature/top-k/top-p) is jitted alongside the model
@@ -20,13 +23,13 @@ only through user code inside Serve replicas — `python/ray/serve/`, P15):
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import llama
+from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
 
@@ -70,20 +73,6 @@ def init_cache(cfg, max_batch: int, max_len: int, dtype=None) -> KVCache:
     )
 
 
-def lax_slice_row(arr, slot):
-    """arr [L, B, ...] -> [L, 1, ...] at dynamic row `slot` (one cache
-    slot's KV across all layers)."""
-    start = (0, slot) + (0,) * (arr.ndim - 2)
-    sizes = (arr.shape[0], 1) + arr.shape[2:]
-    return lax.dynamic_slice(arr, start, sizes)
-
-
-def lax_update_row(arr, row, slot):
-    """Inverse of lax_slice_row: write row [L, 1, ...] back at `slot`."""
-    start = (0, slot) + (0,) * (arr.ndim - 2)
-    return lax.dynamic_update_slice(arr, row.astype(arr.dtype), start)
-
-
 def _write_cache(cache_kv, new_kv, start):
     """Write new_kv [B, T, ...] into cache_kv [B, S, ...] at per-row offsets
     start [B]. vmapped dynamic_update_slice keeps shapes static."""
@@ -94,32 +83,6 @@ def _write_cache(cache_kv, new_kv, start):
         )
 
     return jax.vmap(write_one)(cache_kv, new_kv, start)
-
-
-def _cached_attention(q, k_cache, v_cache, start, *, scale):
-    """q: [B, T, nh, hd]; caches [B, S, nkv, hd]; start [B] = offset of the
-    first query token. Causal over the whole cache: query i attends to
-    key positions <= start + i."""
-    b, t, nh, hd = q.shape
-    s = k_cache.shape[1]
-    nkv = k_cache.shape[2]
-    n_rep = nh // nkv
-    # Grouped attention without materializing repeated KV: fold the
-    # query heads as [B, T, nkv, n_rep, hd] and contract against the
-    # cache directly — repeating K/V would multiply HBM traffic on the
-    # hottest decode-step tensor by n_rep.
-    qg = q.reshape(b, t, nkv, n_rep, hd)
-    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    qpos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
-    kpos = jnp.arange(s, dtype=jnp.int32)                            # [S]
-    mask = kpos[None, None, :] <= qpos[:, :, None]                   # [B,T,S]
-    logits = jnp.where(mask[:, None, None, :, :], logits,
-                       jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v_cache.dtype),
-                     v_cache, preferred_element_type=jnp.float32)
-    return out.reshape(b, t, nh, hd).astype(q.dtype)
 
 
 def cached_forward(cfg, params, tokens, cache: KVCache, *,
@@ -160,7 +123,7 @@ def cached_forward(cfg, params, tokens, cache: KVCache, *,
         k = apply_rope(k, sin, cos)
         k_cache = _write_cache(k_cache, k, start)
         v_cache = _write_cache(v_cache, v, start)
-        attn = _cached_attention(q, k_cache, v_cache, start, scale=scale)
+        attn = cached_attention(q, k_cache, v_cache, start, scale=scale)
         x = x + attn.reshape(b, t, cfg.n_heads * cfg.head_dim) @ p["wo"]
         h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
         gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
@@ -191,10 +154,9 @@ def cached_forward(cfg, params, tokens, cache: KVCache, *,
 # ---------------------------------------------------------------------------
 
 def select_tokens(logits, temps, key):
-    """The serving engines' per-slot token choice: greedy at temp 0,
-    temperature-scaled categorical otherwise. ONE implementation — the
-    dense and paged engines' decode/prefill programs all call this, and
-    their exact-token-equality contract depends on it staying shared."""
+    """The serving engine's per-slot token choice: greedy at temp 0,
+    temperature-scaled categorical otherwise. ONE implementation: the
+    engine's decode and prefill programs both call it."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(

@@ -77,6 +77,35 @@ def reference_attention(
     return out.astype(q.dtype)
 
 
+def cached_attention(q, k_cache, v_cache, start, *, scale):
+    """Plain attention of new queries over a cache that already holds
+    their keys. q: [B, T, nh, hd]; caches [B, S, nkv, hd]; start [B] =
+    offset of the first query token. Causal over the whole cache: query i
+    attends to key positions <= start + i. The library's plain decode
+    (``models/decoding.py``), the serving engine's prefill and the
+    non-TPU lowering of its decode attention all call this."""
+    b, t, nh, hd = q.shape
+    s = k_cache.shape[1]
+    nkv = k_cache.shape[2]
+    n_rep = nh // nkv
+    # Grouped attention without materializing repeated KV: fold the
+    # query heads as [B, T, nkv, n_rep, hd] and contract against the
+    # cache directly — repeating K/V would multiply HBM traffic on the
+    # hottest decode-step tensor by n_rep.
+    qg = q.reshape(b, t, nkv, n_rep, hd)
+    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache,
+                        preferred_element_type=jnp.float32) * scale
+    qpos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
+    kpos = jnp.arange(s, dtype=jnp.int32)                            # [S]
+    mask = kpos[None, None, :] <= qpos[:, :, None]                   # [B,T,S]
+    logits = jnp.where(mask[:, None, None, :, :], logits,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v_cache.dtype),
+                     v_cache, preferred_element_type=jnp.float32)
+    return out.reshape(b, t, nh, hd).astype(q.dtype)
+
+
 def attention(q, k, v, *, causal=True, segment_ids=None,
               logits_soft_cap=None, impl: str = "auto", mesh=None):
     """Dispatching entry point. ``impl``: auto | reference | flash.

@@ -1,62 +1,40 @@
-"""Paged KV-cache attention for serving.
+"""The paged-KV format: where a token's K and V rows live, how they are
+written and how they are read back.
 
 Reference: ABSENT from the reference repo (it serves models via user
 code in replicas — SURVEY P15); this is the vLLM-style PagedAttention
-scheme rebuilt TPU-first: the KV cache is a pool of fixed-size pages,
-each sequence owns a page table, and ``paged_attention`` here gathers a
-sequence's pages with static shapes (gather + mask — XLA-friendly; the
-unjitted helper of the tests and of small callers). The serving
-engine's decode step does not gather: its Pallas kernel reads the pages
-where they lie (``ops/paged_decode_attention.py``, same layout below).
+scheme rebuilt TPU-first. The serving engine (``serve/paged_llm.py``)
+holds the pools and calls this module from its two device programs; the
+decode kernel (``ops/paged_decode_attention.py``) reads the same layout
+in place. Nothing else states the format.
 
-Why paging: the slot-based cache (ray_tpu/models/decoding.py KVCache)
-reserves max_len per slot — a 2048-token cache for an 80-token chat
-wastes 96% of its HBM. Pages allocate on demand, so max_batch scales
-with TOKENS in flight, not worst-case sequence length.
+Why paging: a cache of contiguous rows reserves max_len per sequence — a
+2048-token row for an 80-token chat wastes 96% of its HBM. Pages are
+reserved per request, so max_batch scales with TOKENS in flight, not
+worst-case sequence length.
 
 Layout:
-    k_pages, v_pages: [L, n_pages, page_size, n_kv, head_dim]
-    page_table:       [B, max_pages_per_seq] int32 (−1 = unused)
-    lengths:          [B] int32 tokens currently cached per sequence
+    k_pages, v_pages: [L, n_pages, page_size, n_kv, head_dim], bf16, or
+                      int8 with
+    k_scale, v_scale: [L, n_pages, page_size, n_kv] float32 (one scale a
+                      token and head: ``quantize_kv``)
+    page_table:       [B, max_pages_per_seq] int32 (-1 = unused)
+
+Token ``pos`` of a sequence lives at ``[layer, table[pos // page_size],
+pos % page_size]``. ``write_kv`` scatters new rows there (an index
+outside the pool, which is how a caller names a dead slot or a hole,
+writes nothing); ``gather_kv_window`` copies a table's pages back out,
+dequantised. On the host, ``PageAllocator`` hands out page ids,
+``page_hashes`` and ``PrefixCache`` make full prompt pages shareable.
 """
 
 from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from dataclasses import dataclass
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-@dataclass
-class PagedKVCache:
-    """K/V pages live on device; the page table and lengths are HOST
-    numpy — they're scheduler bookkeeping mutated per request per step,
-    and keeping them host-side avoids a device round-trip + sync on
-    every allocation (they ship to the device per attention call, a few
-    hundred bytes)."""
-
-    k_pages: jax.Array       # [L, P, page, nkv, hd]
-    v_pages: jax.Array
-    page_table: np.ndarray   # [B, max_pages] int32, -1 = hole
-    lengths: np.ndarray      # [B] int32
-
-
-def init_paged_cache(cfg, *, num_pages: int, page_size: int,
-                     max_batch: int, max_pages_per_seq: int,
-                     dtype=jnp.bfloat16) -> PagedKVCache:
-    nkv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
-    hd = cfg.head_dim
-    shape = (cfg.n_layers, num_pages, page_size, nkv, hd)
-    return PagedKVCache(
-        k_pages=jnp.zeros(shape, dtype),
-        v_pages=jnp.zeros(shape, dtype),
-        page_table=np.full((max_batch, max_pages_per_seq), -1, np.int32),
-        lengths=np.zeros((max_batch,), np.int32),
-    )
 
 
 class PageAllocator:
@@ -80,101 +58,6 @@ class PageAllocator:
         for p in self.owned.pop(slot, []):
             self.free.append(p)
 
-    def pages_needed(self, cur_len: int, new_tokens: int,
-                     page_size: int) -> int:
-        have = (cur_len + page_size - 1) // page_size
-        need = (cur_len + new_tokens + page_size - 1) // page_size
-        return need - have
-
-
-def paged_write(cache: PagedKVCache, layer: int, slot, k_new, v_new,
-                start) -> PagedKVCache:
-    """Append k_new/v_new [T, nkv, hd] for one sequence at position
-    `start` (its current length). Positions map to
-    (page_table[slot][pos // page], pos % page). A position landing on
-    an unassigned table hole (-1) is DROPPED, never written: -1 would
-    wrap to the last page and silently corrupt another sequence's KV.
-
-    PERF: each functional .at[].set copies the whole multi-layer page
-    pool when run eagerly — call this inside jit with the cache arrays
-    donated (XLA then updates in place), or write every layer at once
-    with paged_write_all."""
-    page_size = cache.k_pages.shape[2]
-    num_pages = cache.k_pages.shape[1]
-    t = k_new.shape[0]
-    pos = start + np.arange(t)
-    page_idx = cache.page_table[slot][pos // page_size]  # [T] host
-    # holes -> out-of-bounds index + mode="drop" (loud alternative:
-    # callers should assign_pages first; see assign_pages' guard)
-    page_idx = np.where(page_idx >= 0, page_idx, num_pages)
-    in_page = pos % page_size
-
-    k_pages = cache.k_pages.at[layer, jnp.asarray(page_idx),
-                               jnp.asarray(in_page)].set(
-        k_new.astype(cache.k_pages.dtype), mode="drop")
-    v_pages = cache.v_pages.at[layer, jnp.asarray(page_idx),
-                               jnp.asarray(in_page)].set(
-        v_new.astype(cache.v_pages.dtype), mode="drop")
-    return PagedKVCache(k_pages, v_pages, cache.page_table, cache.lengths)
-
-
-def paged_write_all(cache: PagedKVCache, slot, k_new, v_new,
-                    start) -> PagedKVCache:
-    """Append k_new/v_new [L, T, nkv, hd] for ALL layers in one indexed
-    update per tensor (one pool copy eagerly, in-place under jit) —
-    the per-decode-step entry point."""
-    page_size = cache.k_pages.shape[2]
-    num_pages = cache.k_pages.shape[1]
-    t = k_new.shape[1]
-    pos = start + np.arange(t)
-    page_idx = cache.page_table[slot][pos // page_size]
-    page_idx = np.where(page_idx >= 0, page_idx, num_pages)
-    in_page = pos % page_size
-    k_pages = cache.k_pages.at[:, jnp.asarray(page_idx),
-                               jnp.asarray(in_page)].set(
-        k_new.astype(cache.k_pages.dtype), mode="drop")
-    v_pages = cache.v_pages.at[:, jnp.asarray(page_idx),
-                               jnp.asarray(in_page)].set(
-        v_new.astype(cache.v_pages.dtype), mode="drop")
-    return PagedKVCache(k_pages, v_pages, cache.page_table, cache.lengths)
-
-
-def paged_attention(q, cache: PagedKVCache, layer: int, *,
-                    scale: float | None = None):
-    """Decode-step attention: q [B, n_heads, hd] against each sequence's
-    paged KV. Gathers each sequence's pages into a contiguous
-    [max_pages*page, nkv, hd] view (static shape) and masks beyond
-    `lengths`. Supports GQA (n_heads a multiple of n_kv)."""
-    b, nh, hd = q.shape
-    page_size = cache.k_pages.shape[2]
-    nkv = cache.k_pages.shape[3]
-    max_pages = cache.page_table.shape[1]
-    if scale is None:
-        scale = hd ** -0.5
-    n_rep = nh // nkv
-
-    # gather pages: [B, max_pages, page, nkv, hd]; holes (-1) clamp to
-    # page 0 and are masked out by `lengths`
-    table = jnp.maximum(jnp.asarray(cache.page_table), 0)
-    k = cache.k_pages[layer][table]
-    v = cache.v_pages[layer][table]
-    s = max_pages * page_size
-    k = k.reshape(b, s, nkv, hd)
-    v = v.reshape(b, s, nkv, hd)
-
-    qg = q.reshape(b, nkv, n_rep, hd)
-    logits = jnp.einsum("bgrd,bkgd->bgrk", qg, k,
-                        preferred_element_type=jnp.float32) * scale
-    kpos = jnp.arange(s)
-    lengths = jnp.asarray(cache.lengths)
-    mask = kpos[None, :] < lengths[:, None]                # [B, S]
-    logits = jnp.where(mask[:, None, None, :], logits,
-                       jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bgrk,bkgd->bgrd", probs.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, nh, hd).astype(q.dtype)
-
 
 def quantize_kv(x):
     """Per-token-per-head symmetric int8 quantization of a K or V tensor
@@ -192,6 +75,46 @@ def quantize_kv(x):
 def dequantize_kv(q, scale, dtype=jnp.bfloat16):
     """Inverse of quantize_kv (scale broadcast over head_dim)."""
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
+
+
+def write_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip, quantized):
+    """THE KV write, shared by decode and prefill (shape-generic: decode
+    writes one token per slot with [B] indices, prefill a padded suffix
+    with [n, T] indices), on the STACKED pools [L, P, page, nkv, hd]
+    (+ scale pools in int8 mode) at layer ``layer``: k/v land at
+    (layer, pidx, ip), out-of-bounds indices dropping.
+
+    The pools come in whole and go out whole: all that is written is
+    the new rows (a scatter, in place on the buffer the layer loop
+    carries). Write before any read of the layer's pages, so the reader
+    sees the rows just written."""
+    if quantized:
+        kq, ksc = quantize_kv(k_new)
+        vq, vsc = quantize_kv(v_new)
+        kp = kp.at[layer, pidx, ip].set(kq, mode="drop")
+        vp = vp.at[layer, pidx, ip].set(vq, mode="drop")
+        ks = ks.at[layer, pidx, ip].set(ksc, mode="drop")
+        vs = vs.at[layer, pidx, ip].set(vsc, mode="drop")
+    else:
+        kp = kp.at[layer, pidx, ip].set(k_new.astype(kp.dtype),
+                                        mode="drop")
+        vp = vp.at[layer, pidx, ip].set(v_new.astype(vp.dtype),
+                                        mode="drop")
+    return kp, vp, ks, vs
+
+
+def gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer, table):
+    """Layer ``layer``'s ``table`` page window of every row [B, PB, page,
+    nkv, hd], copied out of the stacked pools (holes read page 0; the
+    caller's causal limit masks them) and dequantised to bf16 if the pages
+    are int8: what the gather formulation and the engine's PREFILL attend
+    over."""
+    table_c = jnp.maximum(table, 0)
+    kg, vg = k_pages[layer, table_c], v_pages[layer, table_c]
+    if k_pages.dtype == jnp.int8:
+        kg = dequantize_kv(kg, k_scale[layer, table_c])
+        vg = dequantize_kv(vg, v_scale[layer, table_c])
+    return kg, vg
 
 
 def page_hashes(tokens: np.ndarray, page_size: int) -> list[bytes]:
@@ -287,37 +210,3 @@ class PrefixCache:
             self._by_hash.pop(hsh, None)
             out.append(page)
         return out
-
-
-# ---------------------------------------------------------------------------
-# host-side helpers for the serving engine
-# ---------------------------------------------------------------------------
-
-def assign_pages(cache: PagedKVCache, allocator: PageAllocator, slot: int,
-                 new_tokens: int) -> PagedKVCache:
-    """Grow `slot`'s page table to cover new_tokens more positions.
-    Raises MemoryError (the allocator's exhaustion contract) when the
-    sequence would outgrow max_pages_per_seq — not an opaque numpy
-    broadcast error."""
-    page_size = cache.k_pages.shape[2]
-    max_pages = cache.page_table.shape[1]
-    cur = int(cache.lengths[slot])
-    n_new = allocator.pages_needed(cur, new_tokens, page_size)
-    if n_new == 0:
-        return cache
-    have = (cur + page_size - 1) // page_size
-    if have + n_new > max_pages:
-        raise MemoryError(
-            f"sequence in slot {slot} needs {have + n_new} pages, over "
-            f"max_pages_per_seq={max_pages}")
-    pages = allocator.alloc(slot, n_new)
-    cache.page_table[slot, have:have + n_new] = pages  # host, in place
-    return cache
-
-
-def release_slot(cache: PagedKVCache, allocator: PageAllocator,
-                 slot: int) -> PagedKVCache:
-    allocator.free_slot(slot)
-    cache.page_table[slot, :] = -1
-    cache.lengths[slot] = 0
-    return cache

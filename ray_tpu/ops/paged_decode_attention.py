@@ -21,13 +21,13 @@ holds it, and stops at each slot's length:
   query head against every (token, kv head) row, and a mask keeps, for
   query head r, the rows of ITS kv head (r // group) at key positions
   under the slot's count. The online softmax then runs over exactly the
-  keys ``_cached_attention`` sees, and the masked probabilities are
+  keys ``cached_attention`` sees, and the masked probabilities are
   zero, so the second matmul (probabilities x the same rows of V) is the
   grouped weighted sum. Grouped (GQA, 4 query heads a KV head) and plain
   (MHA) attention are the same code at different shapes, and no head is
   ever sliced out of a page (a strided sublane read of packed bf16);
 - scores and softmax state in float32, probabilities cast to the pages'
-  dtype before the weighted sum, as ``_cached_attention`` does;
+  dtype before the weighted sum, as ``cached_attention`` does;
 - int8 pages: the per-(token, head) scales multiply the score COLUMNS
   (K) and the probability columns (V), which is the dequantisation done
   in VMEM after the matmul instead of on a window copy before it. The
@@ -49,32 +49,18 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.models.decoding import _cached_attention
-from ray_tpu.ops.paged_attention import dequantize_kv
+from ray_tpu.ops.attention import cached_attention
+from ray_tpu.ops.paged_attention import gather_kv_window
 
 KERNEL_NAME = "paged_decode_attn"
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 _BUFFERS = 2
 
 
-def gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer, table):
-    """Layer ``layer``'s ``table`` page window of every row [B, PB, page,
-    nkv, hd], copied out of the stacked pools (holes read page 0; the
-    caller's causal limit masks them) and dequantised to bf16 if the pages
-    are int8: what the gather formulation and the engine's PREFILL attend
-    over."""
-    table_c = jnp.maximum(table, 0)
-    kg, vg = k_pages[layer, table_c], v_pages[layer, table_c]
-    if k_pages.dtype == jnp.int8:
-        kg = dequantize_kv(kg, k_scale[layer, table_c])
-        vg = dequantize_kv(vg, v_scale[layer, table_c])
-    return kg, vg
-
-
 def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
                                      layer, table, pos, active):
     """The gather formulation: every slot's window copied out
-    (``gather_kv_window``), then ``_cached_attention`` over the copy with
+    (``gather_kv_window``), then ``cached_attention`` over the copy with
     the causal limit ``key position <= pos``. What the kernel is held to,
     and what every platform but the TPU runs."""
     del active      # a dead slot attends over page 0; its row is discarded
@@ -82,7 +68,7 @@ def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
     kg, vg = gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer,
                               table)
     nkv = kg.shape[-2]
-    out = _cached_attention(q[:, None], kg.reshape(b, -1, nkv, hd),
+    out = cached_attention(q[:, None], kg.reshape(b, -1, nkv, hd),
                             vg.reshape(b, -1, nkv, hd), pos,
                             scale=hd ** -0.5)
     return out[:, 0]

@@ -4,7 +4,7 @@ Reference analog: ``serve.get_replica_context()``
 (``serve/context.py`` — ReplicaContext dataclass). The hosting
 ``_Replica`` actor sets the context on its own thread before
 constructing the user deployment object, so engine code (e.g.
-``serve/llm.py``) can tag its metrics series and prefix-cache digests
+``serve/paged_llm.py``) can tag its metrics series and prefix-cache digests
 with the deployment name and a stable replica tag. Thread-local: in
 local mode several replicas share one process, and each actor
 constructs its body on its own thread."""

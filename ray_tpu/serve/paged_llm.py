@@ -1,9 +1,17 @@
 """Paged-KV continuous-batching LLM engine.
 
 Reference: ABSENT from the reference repo (it serves models via user
-code in replicas — SURVEY.md P15). This engine wires the vLLM-style
-paged KV allocator (``ray_tpu/ops/paged_attention.py``) into the
-continuous-batching loop of ``serve/llm.py``:
+code in replicas — SURVEY.md P15). This is the one serving engine: a
+continuous-batching host loop over device programs that keep their KV in
+the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
+
+- **Continuous batching**: a fixed-shape decode program runs every chunk
+  over all ``max_batch`` slots; which slots are live is a mask, so
+  admitting or retiring a request never recompiles. New requests are
+  prefilled into a free slot (prompt padded to a power-of-two bucket, a
+  handful of compiled prefill variants in all) while decode keeps
+  streaming for everyone else. Tokens stream back through per-request
+  queues (``serve/llm.py``: ``Request``).
 
 - The KV cache is a POOL of fixed-size pages [L, P, page, nkv, hd];
   each slot owns a page list. HBM scales with TOKENS IN FLIGHT
@@ -19,7 +27,7 @@ continuous-batching loop of ``serve/llm.py``:
   the longest RESERVED page list among the live slots: ``_pages_bucket``),
   which now only sets the table's width, not the bytes a step reads. On
   a platform other than the TPU the same call is the plain formulation
-  (gather the window, ``_cached_attention``), chosen where the program
+  (gather the window, ``cached_attention``), chosen where the program
   is lowered; nothing sets it.
 - Allocation is reserve-on-admit (pages for prompt + budget + one
   chained-overshoot page, released at retirement): admission applies
@@ -41,9 +49,10 @@ continuous-batching loop of ``serve/llm.py``:
 - The device programs keep the pools IN PLACE: the layer loop carries
   the stacked pools (and scale pools) whole, beside the activations,
   and scans over (layer weights, layer index); a layer scatters its new
-  rows at [layer, page, offset] (``_write_kv``) and reads its pages at
+  rows at [layer, page, offset] (``write_kv``) and reads its pages at
   [layer, table]: decode in the kernel, prefill by gathering its window
-  (``gather_kv_window``). Scanning OVER the pools
+  (``gather_kv_window``; both state the format, in
+  ``ops/paged_attention.py``). Scanning OVER the pools
   instead hands each layer a slice: XLA then copies every layer's K
   and V pool out and back, every layer of every step, and the prefill
   program holds a second pool (measured on a v5e at 12 layers x 544
@@ -56,17 +65,24 @@ continuous-batching loop of ``serve/llm.py``:
   routed experts) and the output head (``lm_head_weights``). What is the
   ENGINE's stays here, once for every model: the page write, decode's
   attention over the pages (the kernel), prefill's gather and
-  ``_cached_attention``, the layer scan, sampling, the chunk loop. A
+  ``cached_attention``, the layer scan, sampling, the chunk loop. A
   feed-forward may hand back statistics of its call (scalars; a dense
   one has none): the decode program averages them over the chunk's
   layer-steps, and they go on the chunk's ``engine.emit`` span.
 
-Engine mechanics (queues, continuous batching, chunked + pipelined
-decode, metrics) are inherited from ``LLMEngine``.
+Threading: one engine thread owns the device loop (admission, prefill
+and decode dispatches, emission); a watcher thread blocks on each
+dispatch in stream order and stamps when the device ran it; callers
+enqueue requests and read token queues — no JAX calls on caller threads.
 """
 
 from __future__ import annotations
 
+import itertools
+import queue
+import threading
+import time
+import uuid
 from collections import deque
 from functools import partial
 
@@ -75,15 +91,29 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import llama
-from ray_tpu.models.decoding import (_cached_attention,
-                                     select_tokens)
+from ray_tpu.models.decoding import select_tokens
+from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
-                                         page_hashes, quantize_kv)
-from ray_tpu.ops.paged_decode_attention import (gather_kv_window,
-                                                paged_decode_attention)
+                                         gather_kv_window, page_hashes,
+                                         write_kv)
+from ray_tpu.ops.paged_decode_attention import paged_decode_attention
 from ray_tpu.ops.rope import rope_sin_cos
-from ray_tpu.serve.llm import LLMEngine, _bucket, _named_jit
+from ray_tpu.serve.llm import _STAGES, Request, _named_jit, _serve_hist
+from ray_tpu.util import metrics as _metrics
+from ray_tpu.util import tracing as _tracing
+
+
+def _wall(mono: float) -> float:
+    """A ``time.monotonic()`` stamp on the wall clock spans are kept on."""
+    return time.time() - (time.monotonic() - mono)
+
+
+def _bucket(n: int, minimum: int = 16) -> int:
+    b = minimum
+    while b < n:
+        b *= 2
+    return b
 
 
 def _model_module(cfg):
@@ -100,34 +130,8 @@ def _model_module(cfg):
         "serves LlamaConfig and OlmoeConfig")
 
 
-def _write_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip, quantized):
-    """THE KV write, shared by decode and prefill (shape-generic: decode
-    writes one token per slot with [B] indices, prefill a padded suffix
-    with [n, T] indices), on the STACKED pools [L, P, page, nkv, hd]
-    (+ scale pools in int8 mode) at layer ``layer``: k/v land at
-    (layer, pidx, ip), out-of-bounds indices dropping.
-
-    The pools come in whole and go out whole: all that is written is
-    the new rows (a scatter, in place on the buffer the layer loop
-    carries). Write before any read of the layer's pages, so the reader
-    sees the rows just written."""
-    if quantized:
-        kq, ksc = quantize_kv(k_new)
-        vq, vsc = quantize_kv(v_new)
-        kp = kp.at[layer, pidx, ip].set(kq, mode="drop")
-        vp = vp.at[layer, pidx, ip].set(vq, mode="drop")
-        ks = ks.at[layer, pidx, ip].set(ksc, mode="drop")
-        vs = vs.at[layer, pidx, ip].set(vsc, mode="drop")
-    else:
-        kp = kp.at[layer, pidx, ip].set(k_new.astype(kp.dtype),
-                                        mode="drop")
-        vp = vp.at[layer, pidx, ip].set(v_new.astype(vp.dtype),
-                                        mode="drop")
-    return kp, vp, ks, vs
-
-
-class PagedLLMEngine(LLMEngine):
-    """LLMEngine with a paged KV cache (see module docstring).
+class PagedLLMEngine:
+    """Continuous batching over a paged KV pool (see module docstring).
 
     With ``prefix_cache=True`` (default), full prompt pages are also a
     content-addressed PREFIX CACHE (vLLM-style automatic prefix caching,
@@ -153,38 +157,120 @@ class PagedLLMEngine(LLMEngine):
         if kv_dtype not in ("bf16", "int8"):
             raise ValueError(f"kv_dtype must be 'bf16' or 'int8', "
                              f"got {kv_dtype!r}")
+        self.cfg = cfg
+        self.params = params
+        self.max_batch = max_batch
+        self.max_len = max_len
         self.kv_dtype = kv_dtype
         self.page_size = page_size
         self.max_pages_per_seq = -(-max_len // page_size)
-        # default pool: half the dense equivalent — the paged layout's
-        # raison d'être is NOT reserving worst-case length per slot —
-        # floored so every slot can hold a minimal reservation (prompt
-        # page + 1 overshoot page); without the floor, short-sequence
-        # configs (max_pages_per_seq == 2) starve half of max_batch and
-        # admission waits a full generation for pages, not slots
+        # default pool: half of max_batch full-length sequences — the
+        # paged layout's raison d'être is NOT reserving worst-case
+        # length per slot — floored so every slot can hold a minimal
+        # reservation (prompt page + 1 overshoot page); without the
+        # floor, short-sequence configs (max_pages_per_seq == 2) starve
+        # half of max_batch and admission waits a full generation for
+        # pages, not slots
         if num_pages is not None:
             self.num_pages = num_pages
         else:
             half_dense = max_batch * self.max_pages_per_seq // 2
             floor = max_batch * min(2, self.max_pages_per_seq)
             self.num_pages = max(half_dense, floor)
-        self._prefix_enabled = prefix_cache
-        super().__init__(cfg, params, max_batch=max_batch,
-                         max_len=max_len, decode_chunk=decode_chunk)
-        # prefix-cache digest publishing (serve/prefix_router.py): the
-        # engine periodically drops a compact digest — chained full-page
-        # hashes + pool occupancy — into the process annex registry;
-        # the metrics pusher piggybacks it to the GCS and handles route
-        # repeat-prefix traffic to the replica already holding the pages
-        self._digest_enabled = (self._prefix_enabled
-                                and _cfg.serve_prefix_routing_enabled)
-        self._digest_interval = float(_cfg.serve_digest_publish_interval_s)
-        self._digest_t = 0.0
+        # tokens generated per device round trip: one host sync per CHUNK
+        # of decode steps (lax.scan), not per token — every sync has a
+        # fixed host cost, so fewer dispatches per token. Admission of
+        # waiting requests happens between chunks (adds <= chunk *
+        # step_time to queueing latency). Default: flag serve_decode_chunk.
+        if decode_chunk is None:
+            decode_chunk = _cfg.serve_decode_chunk
+        self.decode_chunk = max(1, decode_chunk)
+        # drain-mode decode: a SHORT chunk used when a slot is about to
+        # retire while requests wait, so admission happens within a few
+        # steps instead of a full chunk (TTFT <- admission latency);
+        # flag serve_drain_chunk
+        self._drain_chunk = max(1, min(_cfg.serve_drain_chunk,
+                                       self.decode_chunk))
+        # serve replica identity: set by the hosting _Replica before it
+        # constructs the deployment body; engines built outside serve
+        # get a private tag (bench / direct use)
+        from ray_tpu.serve.context import get_replica_context
+        ctx = get_replica_context()
+        self.deployment_name = ctx.deployment if ctx else "-"
+        self.replica_tag = (ctx.replica_tag if ctx
+                            else f"engine-{id(self) & 0xffffff:06x}")
+        # continuous admission (flag serve_continuous_admission): the
+        # loop opens a timed window between chunk dispatches so a
+        # request arriving mid-chunk prefills behind ONE in-flight
+        # chunk instead of waiting out the full double-buffered
+        # pipeline (the dominant queue_wait term in BENCH_r07)
+        self._continuous_admission = bool(_cfg.serve_continuous_admission)
+        self._window_frac = min(0.95, max(
+            0.0, float(_cfg.serve_admission_window_frac)))
+        self._sync_t: float | None = None       # last chunk-sync finish
+        self._chunk_period: float | None = None  # EMA between syncs
+        # host-side slot state (the trusted copy of the device lengths)
+        self._lengths = np.zeros((max_batch,), np.int32)
+        self._last_tok = np.zeros((max_batch,), np.int32)
+        # bumped per admission into a slot: lets the pipelined loop tell
+        # "same slot, same request" from "same slot, NEW request" when
+        # deciding whether an in-flight chunk's tokens are still valid
+        self._slot_gen = np.zeros((max_batch,), np.int64)
+        self._active: list[Request | None] = [None] * max_batch
+        self._waiting: "queue.Queue[Request]" = queue.Queue()
+        self._req_ids = itertools.count()
+        self._stop = threading.Event()
+        self._thread: threading.Thread | None = None
+        self._key = jax.random.key(0)
+        self.error: BaseException | None = None
+        self._submit_lock = threading.Lock()
+        # metrics (TTFT window is bounded: a long-lived replica must not
+        # grow memory per request, and a recent window tracks current
+        # latency better than an all-time mean)
+        self.total_generated = 0
+        self.total_finished = 0
+        self.ttfts: "deque[float]" = deque(maxlen=1024)
+        # pre-resolved per-(deployment, replica) stage-histogram handles
+        self._h_stage = {s: _serve_hist.handle(
+            {"stage": s, "deployment": self.deployment_name,
+             "replica": self.replica_tag}) for s in _STAGES}
+        # ready watcher: handed EVERY dispatch (prefill and decode chunk)
+        # in stream order, it stamps when the device started and finished
+        # each — block_until_ready OFF the loop thread, so the
+        # measurement never stalls the decode pipeline (see
+        # _ready_watcher; started with the loop, joined by stop())
+        self._ready_q: "queue.Queue" = queue.Queue()
+        self._watcher: threading.Thread | None = None
+        # every dispatch's place in the device stream (prefills and
+        # chunks together; _dispatch_seq below counts chunks alone)
+        self._stream_seq = itertools.count()
+        # the engine loop's spans are one trace (util/tracing.phase)
+        self._trace_id = uuid.uuid4().hex[:16]
+        # requests the loop has taken off the queue whose prefill is not
+        # dispatched yet (a failing dispatch must still end their
+        # streams: see _loop), and requests whose first token went out
+        # before the watcher had stamped their prefill (_publish_stamped)
+        self._admitting: list[Request] = []
+        self._unpublished: list[Request] = []
+        # device-resident loop inputs (see _device_inputs)
+        self._dev_inputs: dict | None = None
+        self._dev_dirty = True
+        # device-resident last-token vector (chained through decode
+        # programs and prefill scatters; see _dispatch_decode)
+        self._last_dev = None
+        self._scatter_fn = _named_jit(
+            "scatter_firsts", lambda last, slots, firsts:
+            last.at[slots].set(firsts.astype(last.dtype)))
+        # prefill batches whose first tokens haven't reached the host
+        # yet: (dispatch_seq_at, items, firsts_device)
+        self._pending_firsts: list = []
+        self._dispatch_seq = 0
+        # set when an admission failed on pages (not slots) this round —
+        # gates the free-slot drain clause
+        self._admission_blocked = False
 
-    # -- device state ------------------------------------------------------
-
-    def _setup_device_state(self):
-        cfg = self.cfg
+        # -- device state: the pools, their host-side bookkeeping and the
+        # programs compiled so far
         nkv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
         shape = (cfg.n_layers, self.num_pages, self.page_size, nkv,
                  cfg.head_dim)
@@ -200,23 +286,35 @@ class PagedLLMEngine(LLMEngine):
         self._table = np.full((self.max_batch, self.max_pages_per_seq),
                               -1, np.int32)
         self._alloc = PageAllocator(self.num_pages)
-        # deferred page frees: (slot_pages, syncs_remaining) — a chunk
+        # deferred page frees: [syncs_remaining, slot_pages] — a chunk
         # dispatched before the retirement was observed may still write
         # into the retired slot's own pages; they return to the free
         # list only after two chunk syncs have drained the pipeline
-        self._deferred_free: list[list[int]] = []
+        self._deferred_free: list[list] = []
         self._decode_cache: dict[tuple[int, int], object] = {}
         self._prefill_cache: dict[int, object] = {}
         # per dispatched decode chunk, the feed-forward's statistics on
-        # the device until the chunk is emitted (_chunk_facts)
+        # the device until the chunk is emitted (_sync_chunk)
         self._chunk_stats: deque = deque()
         # prefix cache state: shared (read-only, refcounted) pages per
         # slot, the slot's cached-prefix token count, and the full-page
         # hash chain awaiting registration after its prefill dispatch
+        self._prefix_enabled = prefix_cache
         self._prefix = PrefixCache()
         self._shared: dict[int, list[int]] = {}
         self._prefix_len = np.zeros((self.max_batch,), np.int32)
         self._pending_hashes: dict[int, list[bytes]] = {}
+        # prefix-cache digest publishing (serve/prefix_router.py): the
+        # engine periodically drops a compact digest — chained full-page
+        # hashes + pool occupancy — into the process annex registry;
+        # the metrics pusher piggybacks it to the GCS and handles route
+        # repeat-prefix traffic to the replica already holding the pages
+        self._digest_enabled = (self._prefix_enabled
+                                and _cfg.serve_prefix_routing_enabled)
+        self._digest_interval = float(_cfg.serve_digest_publish_interval_s)
+        self._digest_t = 0.0
+
+    # -- compiled programs -------------------------------------------------
 
     def _decode_paged(self, chunk: int, pages_bucket: int):
         key = (chunk, pages_bucket)
@@ -235,7 +333,9 @@ class PagedLLMEngine(LLMEngine):
         """Prefill program gathering a ``window_pages``-page KV window —
         bucketed like decode so a short-prompt batch reads a fraction of
         the full window's KV bytes (the window must cover every row's
-        start + suffix)."""
+        start + suffix). It specializes per (n, bucket) shape besides;
+        admission splits bursts into power-of-two groups so the variant
+        count stays logarithmic."""
         fn = self._prefill_cache.get(window_pages)
         if fn is None:
             fn = _named_jit(
@@ -253,21 +353,24 @@ class PagedLLMEngine(LLMEngine):
         need = max(1, -(-max_covered // self.page_size))
         return min(_bucket(need, minimum=1), self.max_pages_per_seq)
 
-    # -- jitted programs ---------------------------------------------------
-
     @staticmethod
     def _paged_decode_impl(cfg, params, k_pages, v_pages, k_scale,
                            v_scale, table, tokens, lengths, active,
                            temps, key, *, chunk, page_size, quantized):
-        """``chunk`` decode steps over every slot; KV rows written, then
-        attended over where they lie, through the (bucketed) page table
-        [B, PB]. In int8 mode (``quantized``) writes quantize per
-        token+head and the kernel dequantizes against the scale pages —
-        half the KV bytes per step. Two nested scans: over steps,
-        carrying the pools, last tokens, lengths and key; inside it
-        over layers, carrying the activations and the same stacked
-        pools (module docstring: in place), scanning over the layers'
-        weights and indices."""
+        """``chunk`` decode steps over every slot in one compiled program;
+        KV rows written, then attended over where they lie, through the
+        (bucketed) page table [B, PB]. Returns the pools, the [chunk,
+        max_batch] token matrix and the advanced lengths (kept ON DEVICE
+        so chained chunks never need a host upload). Inactive slots are
+        computed but masked (their writes drop). Slots finishing
+        mid-chunk keep decoding; the host drops their surplus tokens.
+        In int8 mode (``quantized``) writes quantize per token+head and
+        the kernel dequantizes against the scale pages — half the KV
+        bytes per step. Two nested scans: over steps, carrying the
+        pools, last tokens, lengths and key; inside it over layers,
+        carrying the activations and the same stacked pools (module
+        docstring: in place), scanning over the layers' weights and
+        indices."""
         model = _model_module(cfg)
         num_pages = k_pages.shape[1]
         b = table.shape[0]
@@ -291,7 +394,7 @@ class PagedLLMEngine(LLMEngine):
                 x, kp, vp, ks, vs = carry
                 p, layer = xs
                 q, k, v = model.attention_projections(cfg, p, x, sin, cos)
-                kp, vp, ks, vs = _write_kv(
+                kp, vp, ks, vs = write_kv(
                     kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
                     quantized)
                 # each live slot's pages up to its length, read where
@@ -320,7 +423,10 @@ class PagedLLMEngine(LLMEngine):
                 one_step,
                 (k_pages, v_pages, k_scale, v_scale, tokens, lengths,
                  key), None, length=chunk)
-        # merged device-resident last-token vector (see llm._decode_impl)
+        # merged last-token vector: chunk-active slots advance to their
+        # newest token, others keep their prior value — the loop chains
+        # every next dispatch off this DEVICE array, so admissions /
+        # retirements never force a host round trip to rebuild last_tok
         new_last = jnp.where(active, toks[-1], tokens)
         # the feed-forward's statistics [chunk, layers], as the chunk's
         # means (nothing, for a block that hands back none)
@@ -333,7 +439,10 @@ class PagedLLMEngine(LLMEngine):
                             v_scale, table_rows, tokens, slens, starts,
                             temps, key, *, page_size, quantized):
         """Prefill ``n`` prompt SUFFIXES (one padded bucket) into pages
-        and sample each row's first token. ``tokens`` holds only the
+        and sample each row's first token, in a single program: each
+        dispatch has a fixed sync cost, so a 16-request burst admitted
+        one-by-one would pay 16 of them serially in TTFT before any
+        compute. ``tokens`` holds only the
         tokens past each row's cached prefix (``starts`` absolute
         offsets; 0 = no prefix reuse, the plain prefill). Suffix KV is
         written into the pages first, then attention runs over the
@@ -364,7 +473,7 @@ class PagedLLMEngine(LLMEngine):
             x, kp, vp, ks, vs = carry
             p, layer = xs
             q, k, v = model.attention_projections(cfg, p, x, sin, cos)
-            kp, vp, ks, vs = _write_kv(
+            kp, vp, ks, vs = write_kv(
                 kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, quantized)
             kg, vg = gather_kv_window(kp, vp, ks, vs, layer, table_rows)
             # gather the whole window AFTER the suffix writes: queries
@@ -373,7 +482,7 @@ class PagedLLMEngine(LLMEngine):
             # beyond the prompt never influence the result
             kg = kg.reshape(n, s, cfg.n_kv_heads, cfg.head_dim)
             vg = vg.reshape(n, s, cfg.n_kv_heads, cfg.head_dim)
-            attn = _cached_attention(q, kg, vg, starts, scale=scale)
+            attn = cached_attention(q, kg, vg, starts, scale=scale)
             x = x + attn.reshape(n, t, -1) @ p["wo"]
             x, _ = model.feed_forward(cfg, p, x, valid=valid)
             return (x, kp, vp, ks, vs), None
@@ -390,57 +499,178 @@ class PagedLLMEngine(LLMEngine):
         first = select_tokens(logits, temps, key)
         return k_pages, v_pages, k_scale, v_scale, first
 
-    # -- engine integration ------------------------------------------------
+    # -- warm-up -----------------------------------------------------------
 
-    def _pages_bucket(self) -> int:
-        """Power-of-two page count covering every live slot's RESERVED
-        pages — exclusive AND shared-prefix (chained chunks may run
-        ahead of the host's view of lengths, but never past the
-        reservation)."""
-        owned = [len(self._alloc.owned.get(i, ()))
-                 + len(self._shared.get(i, ()))
-                 for i, r in enumerate(self._active) if r is not None]
-        need = max(owned) if owned else 1
+    def _warm_prefill(self, start: int, bucket: int, top: int):
+        """Run the prefill program for ``bucket`` new tokens behind
+        ``start`` cached ones at each power-of-two group size up to
+        ``top``, against an empty page table (every write drops); yields
+        each group's size and its first tokens (on the device)."""
+        wp = self._window_pages(start + bucket)
+        prefill = self._prefill_paged(wp)
+        n = 1
+        while n <= top:
+            rows = jnp.full((n, wp), -1, jnp.int32)
+            (self._k_pages, self._v_pages, self._k_scale,
+             self._v_scale, firsts) = prefill(
+                self.params, self._k_pages, self._v_pages,
+                self._k_scale, self._v_scale, rows,
+                jnp.zeros((n, bucket), jnp.int32),
+                jnp.ones((n,), jnp.int32),
+                jnp.full((n,), start, jnp.int32),
+                jnp.zeros((n,), jnp.float32), self._next_key())
+            yield n, firsts
+            n *= 2
+
+    def warmup_prefix(self, prefix_len: int, tail_len: int,
+                      max_n: int | None = None):
+        """Compile the SUFFIX prefill variants that prefix-cache hits
+        dispatch (tail bucket + the window covering prefix+tail), so a
+        deployment with a known system-prompt shape doesn't pay XLA
+        compilation inside the first shared-prefix request's TTFT.
+        ``warmup`` alone only covers the cold (starts=0) path."""
+        bucket = min(_bucket(tail_len), self.max_len)
+        top = max_n if max_n is not None else self.max_batch
+        for _, firsts in self._warm_prefill(prefix_len, bucket, top):
+            np.asarray(firsts)
+
+    def warmup(self, prompt_len: int):
+        """Deterministically compile every program a burst at this
+        prompt bucket can hit: the prefill at each power-of-two group
+        size up to max_batch, and the decode programs at every
+        pages-bucket a run can touch. Call BEFORE start()
+        (request-driven warmup races the admit loop, so which
+        (n, bucket) prefill variants compile is scheduling-dependent —
+        a missed one lands seconds of JIT inside a measured or
+        user-facing TTFT). For shared-prefix workloads also call
+        ``warmup_prefix`` with the expected (prefix, tail) shape."""
+        bucket = min(_bucket(prompt_len), self.max_len)
+        if self._last_dev is None:
+            self._last_dev = jnp.asarray(self._last_tok)
+        for n, firsts in self._warm_prefill(0, bucket, self.max_batch):
+            # warm the firsts scatter at this group size too: it
+            # specializes per slots-shape, and a compile inside _admit
+            # stalls the loop ~0.5s per NEW burst size (measured)
+            self._last_dev = self._scatter_fn(
+                self._last_dev, jnp.arange(n, dtype=jnp.int32), firsts)
+            np.asarray(firsts)
+        self._last_dev = jnp.asarray(self._last_tok)
+        active = jnp.zeros((self.max_batch,), bool)
+        # every pages-bucket a run can touch: powers of two PLUS the
+        # non-power-of-two cap (_pages_bucket clamps to it — e.g.
+        # max_pages_per_seq=6 serves buckets {1,2,4,6})
+        buckets = []
         pb = 1
-        while pb < need:
+        while pb < self.max_pages_per_seq:
+            buckets.append(pb)
             pb *= 2
-        return min(pb, self.max_pages_per_seq)
+        buckets.append(self.max_pages_per_seq)
+        for pb in buckets:
+            for chunk in {self.decode_chunk, self._drain_chunk}:
+                fn = self._decode_paged(chunk, pb)
+                (self._k_pages, self._v_pages, self._k_scale,
+                 self._v_scale, toks, _, _, _) = fn(
+                    self.params, self._k_pages, self._v_pages,
+                    self._k_scale, self._v_scale,
+                    jnp.full((self.max_batch, pb), -1, jnp.int32),
+                    jnp.zeros((self.max_batch,), jnp.int32),
+                    jnp.zeros((self.max_batch,), jnp.int32), active,
+                    jnp.zeros((self.max_batch,), jnp.float32),
+                    self._next_key())
+                np.asarray(toks)
+        self._lengths[:] = 0
+        self._last_tok[:] = 0
 
-    def _decode_call(self, chunk: int, last_tok, dev, ph):
-        pb = self._pages_bucket()
-        ph.set(pages=pb)
-        fn = self._decode_paged(chunk, pb)
-        key = ("table", pb)
-        if key not in dev:
-            # sliced page table uploads only on admission/retirement
-            # (the _device_inputs rebuild drops stale entries). The
-            # explicit host COPY matters: jnp.asarray may transfer
-            # asynchronously from the numpy buffer, and a retirement
-            # writing table[slot] = -1 mid-transfer would hand the
-            # in-flight chunk a torn table
-            dev[key] = jnp.asarray(self._table[:, :pb].copy())
-        (self._k_pages, self._v_pages, self._k_scale, self._v_scale,
-         toks, lens, new_last, stats) = fn(
-            self.params, self._k_pages, self._v_pages, self._k_scale,
-            self._v_scale, dev[key], last_tok, dev["lens"],
-            dev["active"], dev["temps"], self._next_key(),
+    # -- threads and submission --------------------------------------------
+
+    def start(self):
+        self._watcher = threading.Thread(
+            target=self._ready_watcher, daemon=True,
+            name="llm-ready-watcher")
+        self._watcher.start()
+        self._thread = threading.Thread(target=self._loop, daemon=True)
+        self._thread.start()
+
+    def stop(self):
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join(timeout=30)
+        # the watcher goes after the loop (no dispatch follows its
+        # sentinel) and is waited for: a daemon thread still blocked on
+        # the device when the interpreter exits aborts the process
+        self._ready_q.put(None)
+        if self._watcher is not None:
+            self._watcher.join(timeout=30)
+
+    def _ready_watcher(self):
+        """The device's timeline as the host sees it. Every dispatch
+        (kind, stream seq, an output of the program, dispatch_t, the
+        requests it prefills, its dispatch span or None) arrives in
+        stream order, and the device runs them in that order, so
+        blocking on each in turn gives when it finished (done) and when
+        it started: at its dispatch, or when the one before it finished,
+        whichever is later. Prefilled requests get ``start_t`` /
+        ``ready_t``; a dispatch made while spans are recorded gets a
+        ``device.run`` child."""
+        prev_done = float("-inf")
+        while True:
+            item = self._ready_q.get()
+            if item is None:
+                return
+            kind, seq, result, dispatch_t, reqs, span = item
+            try:
+                result.block_until_ready()
+            except Exception:  # noqa: BLE001 - a failed run has ended too
+                pass
+            done = time.monotonic()
+            start = max(dispatch_t, prev_done)
+            prev_done = done
+            for r in reqs:
+                r.start_t = start
+                r.ready_t = done       # last: _publish_stamped waits on it
+            if span is not None:
+                _tracing.emit(
+                    "device.run", start=_wall(start), duration=done - start,
+                    parent=span, kind="serve",
+                    attrs={"kind": kind, "seq": seq,
+                           "wait_s": start - dispatch_t})
+
+    def submit(self, prompt, *, max_new_tokens: int = 128,
+               temperature: float = 0.0, eos_id: int | None = None) -> Request:
+        req = Request(
+            request_id=next(self._req_ids),
+            prompt=np.asarray(prompt, np.int32),
+            max_new_tokens=max_new_tokens,
+            temperature=temperature,
+            eos_id=eos_id,
         )
-        self._chunk_stats.append(stats)
-        return toks, lens, new_last
+        req.engine = self
+        if _tracing.recording():
+            # with no ambient span (a caller outside serve: the
+            # benchmark's client) the request is a trace of its own
+            req.trace_ctx = _tracing.current_context() or \
+                _tracing.SpanContext(uuid.uuid4().hex[:16], "")
+            req.submit_wall = time.time()
+        # Lock pairs with the drain in _loop's finally: a request either
+        # lands in _waiting before the drain (and gets its sentinel
+        # there) or observes the dead/stopped engine here — never neither.
+        with self._submit_lock:
+            if self.error is not None or self._stop.is_set():
+                req.out.put(None)  # engine is dead: fail fast at tokens()
+            else:
+                self._waiting.put(req)
+        return req
 
-    def _chunk_facts(self, recording: bool) -> dict:
-        """The feed-forward's statistics of the chunk being emitted
-        (chunks are emitted in the order they were dispatched). They came
-        out of the program whose tokens the loop has just read, so reading
-        them waits for nothing."""
-        stats = self._chunk_stats.popleft() if self._chunk_stats else {}
-        return ({name: float(v) for name, v in stats.items()}
-                if recording else {})
+    # -- admission and prefill ---------------------------------------------
 
-    def _reserve_slot_resources(self, req, slot: int) -> bool:
+    def _free_slots(self) -> list[int]:
+        return [i for i, r in enumerate(self._active) if r is None]
+
+    def _reserve_pages(self, req: Request, slot: int) -> bool:
         """Reserve-on-admit: pages for prompt + token budget + one page
-        of chained-dispatch overshoot; exhaustion = backpressure (the
-        base _admit requeues the request until pages free up).
+        of chained-dispatch overshoot. False = backpressure: the caller
+        requeues the request until pages free up and stops admitting
+        this round (or, with ``req.error`` set, rejects it).
 
         With the prefix cache, cached full-prefix pages are mapped
         read-only into the slot's table (refcounted, never re-written:
@@ -453,8 +683,8 @@ class PagedLLMEngine(LLMEngine):
         pages = min(-(-budget // self.page_size) + 1,
                     self.max_pages_per_seq)
         if pages > self.num_pages:
-            # can NEVER fit, even with the pool empty: reject now (the
-            # base _admit turns req.error into a terminated stream)
+            # can NEVER fit, even with the pool empty: reject now
+            # (_admit_round turns req.error into a terminated stream)
             req.error = MemoryError(
                 f"request needs {pages} KV pages "
                 f"(prompt {plen} + budget {req.max_new_tokens}) but the "
@@ -499,8 +729,10 @@ class PagedLLMEngine(LLMEngine):
             self._pending_hashes[slot] = hashes
         return True
 
-    def _pack_admit(self, req, slot: int, plen: int) -> tuple:
-        """Pack only the SUFFIX past the slot's cached prefix — a
+    def _pack_admit(self, req: Request, slot: int, plen: int) -> tuple:
+        """One admit item (req, slot, plen, padded): the tokens the
+        prefill program must actually process, padded to a power-of-two
+        bucket. Only the SUFFIX past the slot's cached prefix — a
         shared-prefix request prefills (and buckets) just its tail."""
         start = int(self._prefix_len[slot])
         suffix = np.asarray(req.prompt, np.int32)[start:]
@@ -510,6 +742,10 @@ class PagedLLMEngine(LLMEngine):
         return (req, slot, plen, padded)
 
     def _dispatch_prefill(self, part: list, bucket: int, ph):
+        """Dispatch one prefill sub-batch (``part`` is a list of
+        (req, slot, plen, padded)); returns the device first-tokens.
+        ``ph`` is the dispatch's span (``tracing.phase``), which gets
+        the window and the prefix-cache counts."""
         tokens = jnp.asarray(np.stack([it[3] for it in part]))
         starts_np = np.array([self._prefix_len[it[1]] for it in part],
                              np.int32)
@@ -566,31 +802,265 @@ class PagedLLMEngine(LLMEngine):
             shared.append(page)
             self._prefix.ref(page)
 
-    def _publish_digest(self, force: bool = False):
-        """Drop this replica's prefix-cache digest into the process
-        annex registry (throttled; the pusher ships it). Engine-thread
-        only — ``_by_hash`` has a single mutator."""
-        if not self._digest_enabled:
-            return
-        import time as _time
-        now = _time.monotonic()
-        if not force and now - self._digest_t < self._digest_interval:
-            return
-        self._digest_t = now
-        from ray_tpu.runtime import metrics_plane as _mp
-        hashes = [int.from_bytes(h[:8], "little")
-                  for h in list(self._prefix._by_hash)]
-        _mp.set_annex(f"serve/prefix_digest/{self.replica_tag}", {
-            "tag": self.replica_tag,
-            "deployment": self.deployment_name,
-            "page_size": self.page_size,
-            "hashes": hashes,
-            "kv_free": len(self._alloc.free),
-            "kv_total": self.num_pages,
-        })
+    def _admit(self, first: "Request | None" = None):
+        with _tracing.phase("engine.admit", kind="serve") as ph:
+            admitted, dispatches = self._admit_round(first)
+            if ph:
+                ph.set(admitted=admitted, dispatches=dispatches,
+                       blocked=self._admission_blocked)
 
-    def _on_slot_retired(self, slot: int):
-        super()._on_slot_retired(slot)   # marks device inputs dirty
+    def _admit_round(self, first: "Request | None") -> tuple:
+        """Prefill waiting requests into free slots. All prefills of the
+        round are DISPATCHED first and their first tokens extracted in
+        one host pass — each sync has a fixed cost, so a burst of
+        admissions pays ~one, not one per request. ``first``: a request
+        already pulled off the queue (the admission window's timed get)
+        — admitted ahead of the queue, requeued on backpressure like any
+        other. Returns (requests admitted, prefill dispatches)."""
+        admits = []   # (req, slot, plen, padded)
+        self._admission_blocked = False
+        pulled = first
+        for slot in self._free_slots():
+            if pulled is not None:
+                req, pulled = pulled, None
+            else:
+                try:
+                    req = self._waiting.get_nowait()
+                except queue.Empty:
+                    break
+            plen = len(req.prompt)
+            if plen >= self.max_len:
+                req.error = ValueError(
+                    f"prompt length {plen} >= engine max_len "
+                    f"{self.max_len}")
+                req.out.put(None)
+                continue
+            if not self._reserve_pages(req, slot):
+                if req.error is not None:
+                    # permanently infeasible (e.g. a reservation larger
+                    # than the whole page pool): reject — requeueing
+                    # would hang it and head-of-line-block the queue
+                    req.out.put(None)
+                    continue
+                self._waiting.put(req)   # backpressure: retry later
+                self._admission_blocked = True
+                break
+            admits.append(self._pack_admit(req, slot, plen))
+        if pulled is not None:
+            self._waiting.put(pulled)   # no free slot took it
+        if not admits:
+            return 0, 0
+        self._admitting = [item[0] for item in admits]
+        # Group by bucket, then split each group into POWER-OF-TWO
+        # sub-batches: one batched-prefill dispatch per sub-batch (a
+        # 16-burst = 1 dispatch; 15 = 8+4+2+1 = 4) with one stacked
+        # prompt upload each. Per-dispatch sync costs would otherwise
+        # dominate burst TTFT.
+        groups: dict[int, list] = {}
+        for item in admits:
+            groups.setdefault(len(item[3]), []).append(item)
+        batches = []   # (items, first_tokens_device)
+        for bucket, items in groups.items():
+            i = 0
+            while i < len(items):
+                m = 1
+                while m * 2 <= len(items) - i:
+                    m *= 2
+                part = items[i:i + m]
+                i += m
+                with _tracing.phase("engine.dispatch_prefill",
+                                    kind="serve") as ph:
+                    firsts = self._dispatch_prefill(part, bucket, ph)
+                    now = time.monotonic()
+                    seq = next(self._stream_seq)
+                    if ph:
+                        ph.set(seq=seq, group=len(part), bucket=bucket)
+                reqs = [it[0] for it in part]
+                for req in reqs:
+                    req.dispatch_t = now
+                self._ready_q.put(
+                    ("prefill", seq, firsts, now, reqs, ph or None))
+                batches.append((part, firsts))
+        # ASYNC first tokens: scatter each batch's firsts into the
+        # device last-token vector (so the very next decode chunk
+        # covers the new slots with no host round trip) and activate
+        # the slots NOW; the host-side emission of the first tokens
+        # happens in _drain_firsts when the async copy lands. Blocking
+        # here for the sync RTT stalled the whole decode pipeline once
+        # per admission round — with small chunks that stall WAS the
+        # sustained-TTFT/throughput ceiling.
+        for part, firsts in batches:
+            slots = jnp.asarray(np.array([it[1] for it in part],
+                                         np.int32))
+            self._last_dev = self._scatter_fn(self._last_dev, slots,
+                                              firsts)
+            try:
+                firsts.copy_to_host_async()
+            except Exception:  # noqa: BLE001 - backend without async copy
+                pass
+            for (req, slot, plen, _) in part:
+                req.slot = slot
+                self._active[slot] = req
+                # admission GENERATION: an in-flight decode chunk
+                # dispatched for this slot's PREVIOUS occupant must
+                # neither have its tokens emitted to the new request
+                # nor be chained from
+                self._slot_gen[slot] += 1
+                self._lengths[slot] = plen
+            # any chunk dispatched from here on (seq >= _dispatch_seq)
+            # executes after this prefill on the device stream
+            self._pending_firsts.append(
+                (self._dispatch_seq, part, firsts))
+        self._admitting = []
+        self._dev_dirty = True   # active set / lengths changed
+        return len(admits), len(batches)
+
+    def _drain_firsts(self, completed_seq: int | None = None):
+        """Emit first tokens whose prefill results reached the host.
+        ``completed_seq``: a decode chunk with this dispatch seq has
+        been READ on the host — every prefill dispatched before it is
+        device-complete, so blocking on those firsts costs only the
+        (already overlapped) copy."""
+        if not self._pending_firsts:
+            return
+        keep = []
+        for seq_at, part, firsts in self._pending_firsts:
+            # NOTE: no is_ready() polling — a readiness query can
+            # itself block on the device, which (measured in round 5)
+            # serialized the whole loop. Readiness is derived purely
+            # from device-stream ordering via completed_seq.
+            if completed_seq is None or seq_at > completed_seq:
+                keep.append((seq_at, part, firsts))
+                continue
+            t_drain = time.monotonic()
+            with _tracing.phase("engine.wait_device", kind="serve",
+                                attrs={"what": "firsts"}):
+                vals = np.asarray(firsts)
+            now = time.monotonic()
+            with _tracing.phase("engine.emit", kind="serve") as ph:
+                finished = self.total_finished
+                for (req, slot, plen, _), first in zip(part, vals):
+                    req.drain_t = t_drain
+                    req.first_token_t = now
+                    self.ttfts.append(req.ttft)
+                    self._unpublished.append(req)
+                    self._emit(req, int(first))
+                if ph:
+                    ph.set(what="firsts", tokens=len(part),
+                           finished=self.total_finished - finished)
+        self._pending_firsts = keep
+        self._publish_stamped()
+
+    def _publish_stamped(self):
+        """Publish the TTFT breakdown (stage histograms, trace spans) of
+        every request whose first token has gone out and whose
+        prefill the watcher has stamped. The loop thread and the watcher
+        wake on the same device event, so the stamp may be a moment
+        behind the token: such a request waits here for the loop's next
+        pass, and its stages are never made up."""
+        if not self._unpublished:
+            return
+        keep = []
+        for req in self._unpublished:
+            if req.ready_t is None:
+                keep.append(req)
+                continue
+            bd = req.breakdown
+            if _metrics.enabled():
+                for stage in _STAGES:
+                    self._h_stage[stage].observe(bd[f"{stage}_s"])
+            if req.trace_ctx is not None:
+                self._emit_trace_spans(req, bd)
+        self._unpublished = keep
+
+    def _emit_trace_spans(self, req: Request, bd: dict):
+        """The engine's span subtree for one traced request: an
+        ``engine.request`` parent spanning submit -> first token
+        (wall-anchored at the submit stamp, parented to the replica's
+        run span, or the root of the request's own trace), with the five
+        TTFT stages as SEQUENTIAL children. ``breakdown`` clamps the
+        stamps, so the children tile the parent exactly — the waterfall
+        shows queue_wait/device_wait/prefill/pipeline_stall/ship summing
+        to the traced TTFT."""
+        parent = _tracing.emit(
+            "engine.request", start=req.submit_wall, duration=req.ttft,
+            parent=req.trace_ctx, kind="serve",
+            attrs={"request_id": req.request_id,
+                   "deployment": self.deployment_name,
+                   "replica": self.replica_tag})
+        t = req.submit_wall
+        for stage in _STAGES:
+            d = bd[f"{stage}_s"]
+            _tracing.emit(f"engine.{stage}", start=t, duration=d,
+                          parent=parent, kind="serve")
+            t += d
+
+    def _admission_window(self) -> bool:
+        """Continuous admission: between the previous chunk's sync and
+        the NEXT chunk's dispatch, block on the waiting queue for up to
+        a fraction of the EMA chunk period and prefill arrivals
+        immediately. A prefill dispatched here queues behind only the
+        ONE in-flight chunk — without the window, a request arriving
+        just after an emit waits out the whole double-buffered pipeline
+        (~2.5 chunks of queue_wait, the dominant TTFT term in
+        BENCH_r07). The wait costs no device time: the in-flight chunk
+        computes while this thread sleeps, and the remaining period
+        fraction covers the next dispatch. Skipped until the loop has a
+        period estimate, when no slot is free, or under page
+        backpressure (a request the pool can't place would spin)."""
+        if (not self._continuous_admission or self._chunk_period is None
+                or self._sync_t is None):
+            return False
+        deadline = self._sync_t + self._window_frac * self._chunk_period
+        admitted = False
+        while not self._stop.is_set():
+            if self._admission_blocked or \
+                    not any(r is None for r in self._active):
+                break
+            timeout = deadline - time.monotonic()
+            if timeout <= 0:
+                break
+            with _tracing.phase("engine.wait_arrivals", kind="serve",
+                                attrs={"what": "window"}) as ph:
+                try:
+                    req = self._waiting.get(timeout=timeout)
+                except queue.Empty:
+                    req = None
+                if ph:
+                    ph.set(arrivals=int(req is not None))
+            if req is None:
+                break
+            self._admit(first=req)
+            admitted = True
+        return admitted
+
+    # -- emission and retirement -------------------------------------------
+
+    def _next_key(self):
+        self._key, sub = jax.random.split(self._key)
+        return sub
+
+    def _emit(self, req: Request, tok: int):
+        req.generated += 1
+        self.total_generated += 1
+        self._last_tok[req.slot] = tok
+        # the cache-capacity cutoff counts prompt + emitted tokens — the
+        # _lengths mirror is chunk-granular (pre-advanced at dispatch)
+        # and would trip this up to two chunks early
+        done = (req.eos_id is not None and tok == req.eos_id) or \
+            req.generated >= req.max_new_tokens or \
+            len(req.prompt) + req.generated >= self.max_len
+        req.out.put(tok)
+        if done:
+            req.out.put(None)
+            self._active[req.slot] = None
+            self.total_finished += 1
+            self._retire_slot(req.slot)
+
+    def _retire_slot(self, slot: int):
+        """A request finished and its slot was released: its pages go
+        back, the shared ones now and its own after two chunk syncs."""
+        self._dev_dirty = True
         # a chunk dispatched before this retirement was observed may
         # still write into the slot's own (reserved) pages: defer the
         # free by two chunk syncs. Shared prefix pages are released
@@ -618,111 +1088,316 @@ class PagedLLMEngine(LLMEngine):
                 still.append(entry)
         self._deferred_free = still
 
+    def _publish_digest(self, force: bool = False):
+        """Drop this replica's prefix-cache digest into the process
+        annex registry (throttled; the pusher ships it). Engine-thread
+        only — ``_by_hash`` has a single mutator."""
+        if not self._digest_enabled:
+            return
+        now = time.monotonic()
+        if not force and now - self._digest_t < self._digest_interval:
+            return
+        self._digest_t = now
+        from ray_tpu.runtime import metrics_plane as _mp
+        hashes = [int.from_bytes(h[:8], "little")
+                  for h in list(self._prefix._by_hash)]
+        _mp.set_annex(f"serve/prefix_digest/{self.replica_tag}", {
+            "tag": self.replica_tag,
+            "deployment": self.deployment_name,
+            "page_size": self.page_size,
+            "hashes": hashes,
+            "kv_free": len(self._alloc.free),
+            "kv_total": self.num_pages,
+        })
+
+    # -- the loop: decode chunks, double buffered --------------------------
+
+    def _loop(self):
+        try:
+            self._run_loop()
+        except BaseException as e:  # noqa: BLE001 — propagate to callers
+            self.error = e
+        finally:
+            # Runs on BOTH error and clean stop(): every live stream,
+            # every waiter and every request the loop had taken off the
+            # queue when a prefill dispatch failed gets its sentinel, so
+            # no tokens() consumer can hang. Under _submit_lock so no
+            # request slips in after the drain (see submit()).
+            self._publish_stamped()
+            with self._submit_lock:
+                self._stop.set()
+                live = {id(r): r for r in self._active if r is not None}
+                live.update((id(r), r) for r in self._admitting)
+                for req in live.values():
+                    req.out.put(None)
+                while True:
+                    try:
+                        self._waiting.get_nowait().out.put(None)
+                    except queue.Empty:
+                        break
+
+    def _use_drain_chunk(self) -> bool:
+        """Short decode chunks ONLY when a waiting request could
+        actually be admitted soon — i.e. a slot is about to retire (an
+        active request near its token budget). Draining whenever the
+        queue was non-empty ran 4-step chunks for entire saturated runs
+        (4x the sync overhead) while no slot could possibly free.
+
+        Two admission opportunities count: a FREE SLOT already exists
+        (run the engine with max_batch above the offered concurrency and
+        this is the common case — admission then never waits for a
+        retirement), or a retirement is imminent. The horizon is 3
+        chunks because the double-buffered loop's ``generated`` counts
+        lag the device by up to two in-flight chunks."""
+        if self._waiting.empty():
+            return False
+        if any(r is None for r in self._active) \
+                and not self._admission_blocked:
+            # a free slot AND admission actually possible (a page-starved
+            # engine must not drain forever against a free slot it
+            # cannot fill)
+            return True
+        horizon = 3 * self.decode_chunk
+        return any(
+            r is not None
+            and (r.max_new_tokens - r.generated) <= horizon
+            for r in self._active)
+
+    def _device_inputs(self, active_idx):
+        """Device-resident loop inputs (active mask, temps, lengths).
+        Uploaded only when admission/retirement changed them — a
+        per-dispatch host upload would otherwise serialize with the
+        decode chunks."""
+        if self._dev_inputs is None or self._dev_dirty:
+            active = np.zeros((self.max_batch,), bool)
+            active[active_idx] = True
+            temps = np.array(
+                [r.temperature if r is not None else 0.0
+                 for r in self._active], np.float32)
+            self._dev_inputs = {
+                "active": jnp.asarray(active),
+                "temps": jnp.asarray(temps),
+                # .copy(): the host mirror is mutated right after each
+                # dispatch; an asynchronous transfer reading the live
+                # buffer would upload a torn lengths vector
+                "lens": jnp.asarray(self._lengths.copy()),
+            }
+            self._dev_dirty = False
+        return self._dev_inputs
+
+    def _pages_bucket(self) -> int:
+        """Power-of-two page count covering every live slot's RESERVED
+        pages — exclusive AND shared-prefix (chained chunks may run
+        ahead of the host's view of lengths, but never past the
+        reservation)."""
+        owned = [len(self._alloc.owned.get(i, ()))
+                 + len(self._shared.get(i, ()))
+                 for i, r in enumerate(self._active) if r is not None]
+        need = max(owned) if owned else 1
+        return min(_bucket(need, minimum=1), self.max_pages_per_seq)
+
+    def _dispatch_decode(self, active_idx):
+        """Dispatch one decode chunk (no host sync), chained off the
+        DEVICE-resident last-token vector — admissions (prefill firsts
+        scattered into it) and chunk outputs (merged in the decode
+        program) both update it on device, so consecutive dispatches
+        never need a host round trip no matter how the active set
+        changed in between."""
+        with _tracing.phase("engine.dispatch_decode", kind="serve") as ph:
+            drain = self._use_drain_chunk()
+            chunk = self._drain_chunk if drain else self.decode_chunk
+            reupload = self._dev_inputs is None or self._dev_dirty
+            dev = self._device_inputs(active_idx)
+            pb = self._pages_bucket()
+            table = ("table", pb)
+            if table not in dev:
+                # sliced page table uploads only on admission/retirement
+                # (the _device_inputs rebuild drops stale entries). The
+                # explicit host COPY matters: jnp.asarray may transfer
+                # asynchronously from the numpy buffer, and a retirement
+                # writing table[slot] = -1 mid-transfer would hand the
+                # in-flight chunk a torn table
+                dev[table] = jnp.asarray(self._table[:, :pb].copy())
+            (self._k_pages, self._v_pages, self._k_scale, self._v_scale,
+             toks, lens, new_last, stats) = self._decode_paged(chunk, pb)(
+                self.params, self._k_pages, self._v_pages, self._k_scale,
+                self._v_scale, dev[table], self._last_dev, dev["lens"],
+                dev["active"], dev["temps"], self._next_key(),
+            )
+            self._chunk_stats.append(stats)
+            now = time.monotonic()
+            stream_seq = next(self._stream_seq)
+            if ph:
+                ph.set(pages=pb, seq=stream_seq, chunk=chunk,
+                       live=len(active_idx), slots=self.max_batch,
+                       drain=drain, reupload=reupload)
+            self._last_dev = new_last
+            dev["lens"] = lens   # stays on device for the chained chunk
+            # start the token matrix's device->host copy NOW: it overlaps
+            # the next chunk's compute instead of adding a serial RTT to
+            # every chunk sync
+            try:
+                toks.copy_to_host_async()
+            except Exception:  # noqa: BLE001 - backend without async copy
+                pass
+            # host mirror advances deterministically (+chunk per active
+            # slot) — retired slots are reconciled at admission
+            self._lengths[active_idx] += chunk
+            gens = [int(self._slot_gen[i]) for i in active_idx]
+            seq = self._dispatch_seq
+            self._dispatch_seq += 1
+        self._ready_q.put(("decode", stream_seq, toks, now, (), ph or None))
+        return toks, active_idx, gens, chunk, seq
+
     def _emit_chunk(self, toks_np, active_idx, gens):
-        super()._emit_chunk(toks_np, active_idx, gens)
+        for i, gen in zip(active_idx, gens):
+            if self._slot_gen[i] != gen:
+                continue   # slot re-admitted since dispatch: the chunk's
+                # tokens belong to the RETIRED occupant, not this request
+            for t in range(toks_np.shape[0]):
+                req = self._active[i]
+                if req is None:
+                    break   # finished mid-chunk; drop surplus tokens
+                self._emit(req, int(toks_np[t, i]))
         # one chunk sync elapsed: age the deferred frees
         self._age_deferred_frees()
         self._publish_digest()
 
-    def _on_idle(self):
-        # no active slots and nothing in flight: every dispatched chunk
-        # has synced, so deferred frees cannot race anything — release
-        # them all (otherwise pages retired on the last emit before an
-        # idle period would strand and deadlock page backpressure)
-        if self._deferred_free:
-            self._age_deferred_frees(drain_all=True)
+    def _sync_chunk(self, toks, active_idx, gens, seq: int | None):
+        """Chunk N's host sync, then its tokens to their streams. Firsts
+        of prefills dispatched before the chunk (``seq``: before chunk
+        ``seq``; None: drained by the caller already) go out ahead of
+        it, so emission order per request is preserved."""
+        with _tracing.phase("engine.wait_device", kind="serve",
+                            attrs={"what": "chunk"}):
+            toks_np = np.asarray(toks)
+        now = time.monotonic()
+        if seq is not None:
+            self._drain_firsts(completed_seq=seq)
+        with _tracing.phase("engine.emit", kind="serve") as ph:
+            generated, finished = self.total_generated, self.total_finished
+            self._emit_chunk(toks_np, active_idx, gens)
+            # the feed-forward's statistics of this chunk (chunks are
+            # emitted in the order they were dispatched). They came out
+            # of the program whose tokens the loop has just read, so
+            # reading them waits for nothing
+            stats = self._chunk_stats.popleft() if self._chunk_stats else {}
+            if ph:
+                ph.set(what="chunk",
+                       tokens=self.total_generated - generated,
+                       finished=self.total_finished - finished,
+                       **{name: float(v) for name, v in stats.items()})
+        return now
 
-    def warmup_prefix(self, prefix_len: int, tail_len: int,
-                      max_n: int | None = None):
-        """Compile the SUFFIX prefill variants that prefix-cache hits
-        dispatch (tail bucket + the window covering prefix+tail), so a
-        deployment with a known system-prompt shape doesn't pay XLA
-        compilation inside the first shared-prefix request's TTFT.
-        ``warmup`` alone only covers the cold (starts=0) path."""
-        bucket = min(_bucket(tail_len), self.max_len)
-        wp = self._window_pages(prefix_len + bucket)
-        prefill = self._prefill_paged(wp)
-        n = 1
-        top = max_n if max_n is not None else self.max_batch
-        while n <= top:
-            rows = jnp.full((n, wp), -1, jnp.int32)
-            (self._k_pages, self._v_pages, self._k_scale,
-             self._v_scale, firsts) = prefill(
-                self.params, self._k_pages, self._v_pages,
-                self._k_scale, self._v_scale, rows,
-                jnp.zeros((n, bucket), jnp.int32),
-                jnp.ones((n,), jnp.int32),
-                jnp.full((n,), prefix_len, jnp.int32),
-                jnp.zeros((n,), jnp.float32), self._next_key())
-            np.asarray(firsts)
-            n *= 2
+    def _wait_idle(self):
+        """No live slot and nothing in flight: poll for arrivals every
+        millisecond, as ONE span however long the wait (an idle engine
+        must not fill the span ring)."""
+        with _tracing.phase("engine.wait_arrivals", kind="serve",
+                            attrs={"what": "idle"}) as ph:
+            while True:
+                # every dispatched chunk has synced, so deferred frees
+                # cannot race anything — release them all (otherwise
+                # pages retired on the last emit before an idle period
+                # would strand and deadlock page backpressure)
+                if self._deferred_free:
+                    self._age_deferred_frees(drain_all=True)
+                self._publish_stamped()
+                time.sleep(0.001)
+                if self._stop.is_set() or not self._waiting.empty():
+                    break
+            if ph:
+                ph.set(arrivals=self._waiting.qsize())
 
-    def warmup(self, prompt_len: int):
-        """Compile the prefill program (each power-of-two group size at
-        this bucket) and the decode programs at every pages-bucket a
-        run can touch. For shared-prefix workloads also call
-        ``warmup_prefix`` with the expected (prefix, tail) shape."""
-        bucket = min(_bucket(prompt_len), self.max_len)
-        wp = self._window_pages(bucket)
-        prefill = self._prefill_paged(wp)
-        if self._last_dev is None:
-            self._last_dev = jnp.asarray(self._last_tok)
-        n = 1
-        while n <= self.max_batch:
-            rows = jnp.full((n, wp), -1, jnp.int32)
-            (self._k_pages, self._v_pages, self._k_scale,
-             self._v_scale, firsts) = prefill(
-                self.params, self._k_pages, self._v_pages,
-                self._k_scale, self._v_scale, rows,
-                jnp.zeros((n, bucket), jnp.int32),
-                jnp.ones((n,), jnp.int32),
-                jnp.zeros((n,), jnp.int32),
-                jnp.zeros((n,), jnp.float32), self._next_key())
-            # warm the firsts scatter at this group size (it
-            # specializes per slots-shape; compiling inside _admit
-            # stalls the loop ~0.5s — measured)
-            self._last_dev = self._scatter_fn(
-                self._last_dev, jnp.arange(n, dtype=jnp.int32), firsts)
-            np.asarray(firsts)
-            n *= 2
+    def _run_loop(self):
+        """Double-buffered decode over a device-resident last-token
+        vector: while chunk N's tokens copy back to the host and get
+        emitted, chunk N+1 already runs on device. Admissions scatter
+        their (still on-device) first tokens into the vector, so the
+        pipeline NEVER stalls for a prefill sync — first tokens are
+        emitted asynchronously when their copy lands (_drain_firsts).
+        Emission order per request is preserved: firsts dispatched
+        before chunk N are force-drained right after chunk N's sync,
+        before the chunk's tokens are emitted.
+
+        Each pass is one ``engine.iteration`` span while spans are
+        recorded (``tracing.phase``), its phases its children: what the
+        children leave uncovered is host work no phase names."""
+        pending = None   # (device_toks, active_idx, gens, chunk, seq)
         self._last_dev = jnp.asarray(self._last_tok)
-        active = jnp.zeros((self.max_batch,), bool)
-        # every pages-bucket a run can touch: powers of two PLUS the
-        # non-power-of-two cap (_pages_bucket clamps to it — e.g.
-        # max_pages_per_seq=6 serves buckets {1,2,4,6})
-        buckets = []
-        pb = 1
-        while pb < self.max_pages_per_seq:
-            buckets.append(pb)
-            pb *= 2
-        buckets.append(self.max_pages_per_seq)
-        for pb in buckets:
-            for chunk in {self.decode_chunk, self._drain_chunk}:
-                fn = self._decode_paged(chunk, pb)
-                (self._k_pages, self._v_pages, self._k_scale,
-                 self._v_scale, toks, _, _, _) = fn(
-                    self.params, self._k_pages, self._v_pages,
-                    self._k_scale, self._v_scale,
-                    jnp.full((self.max_batch, pb), -1, jnp.int32),
-                    jnp.zeros((self.max_batch,), jnp.int32),
-                    jnp.zeros((self.max_batch,), jnp.int32), active,
-                    jnp.zeros((self.max_batch,), jnp.float32),
-                    self._next_key())
-                np.asarray(toks)
-        self._lengths[:] = 0
-        self._last_tok[:] = 0
+        for n in itertools.count():
+            if self._stop.is_set():
+                break
+            with _tracing.phase("engine.iteration", kind="serve",
+                                trace_id=self._trace_id) as ph:
+                if ph:
+                    ph.set(seq=n, waiting=self._waiting.qsize(),
+                           live=sum(r is not None for r in self._active))
+                pending = self._iteration(pending)
+
+    def _iteration(self, pending):
+        """One pass of the loop; returns the chunk left in flight."""
+        self._admit()
+        active_idx = [i for i, r in enumerate(self._active)
+                      if r is not None]
+        if not active_idx:
+            self._sync_t = None   # pipeline drains: period resets
+            if pending is not None:
+                toks, idxs, gens, _, seq = pending
+                self._sync_chunk(toks, idxs, gens, seq)
+            elif self._pending_firsts:
+                # every active request is brand-new and nothing is
+                # in flight (e.g. max_new_tokens=1 bursts): block
+                # for the outstanding firsts
+                self._drain_firsts(completed_seq=self._dispatch_seq)
+            else:
+                self._wait_idle()
+            return None
+        if pending is None:
+            return self._dispatch_decode(active_idx)
+        # continuous admission: requests arriving while `pending`
+        # computes are prefilled NOW, before the next chunk is
+        # dispatched behind them
+        if self._admission_window():
+            active_idx = [i for i, r in enumerate(self._active)
+                          if r is not None]
+        nxt = self._dispatch_decode(active_idx)
+        toks_prev, idx_prev, gens_prev, _, _ = pending
+        # EVERY pending prefill was dispatched before nxt: block for
+        # their firsts now (bounded by chunk N + prefill compute —
+        # chunk N+1 is already queued behind them, so this wait
+        # steals no device time) and emit them FIRST. Waiting for
+        # the next chunk's sync instead cost a whole extra chunk of
+        # first-token latency.
+        self._drain_firsts(completed_seq=self._dispatch_seq)
+        sync_t = self._sync_t
+        now = self._sync_chunk(toks_prev, idx_prev, gens_prev, None)
+        if sync_t is not None:
+            period = now - sync_t
+            self._chunk_period = (
+                period if self._chunk_period is None
+                else 0.5 * self._chunk_period + 0.5 * period)
+        self._sync_t = now
+        return nxt
+
+    # -- metrics -----------------------------------------------------------
 
     def stats(self) -> dict:
-        out = super().stats()
-        out["kv_pages_total"] = self.num_pages
-        out["kv_pages_free"] = len(self._alloc.free)
+        out = {
+            "active_slots": sum(r is not None for r in self._active),
+            "waiting": self._waiting.qsize(),
+            "total_generated": self.total_generated,
+            "total_finished": self.total_finished,
+            "mean_ttft_s": float(np.mean(self.ttfts)) if self.ttfts else None,
+            "kv_pages_total": self.num_pages,
+            "kv_pages_free": len(self._alloc.free),
+        }
         # feed the metrics plane: pool occupancy + prefix-cache hit
         # counters ride the process's next pushed delta frame
-        from ray_tpu.util import metrics as _m
-        if _m.enabled():
-            g = _m.gauge("ray_tpu_serve_kv_pages",
-                         "paged-KV pool size by state",
-                         tag_keys=("state", "deployment", "replica"))
+        if _metrics.enabled():
+            g = _metrics.gauge("ray_tpu_serve_kv_pages",
+                               "paged-KV pool size by state",
+                               tag_keys=("state", "deployment", "replica"))
             base = {"deployment": self.deployment_name,
                     "replica": self.replica_tag}
             g.set(out["kv_pages_free"], tags={"state": "free", **base})
@@ -740,6 +1415,7 @@ class PagedLLMEngine(LLMEngine):
         out["kv_pages_bytes"] = int(
             self._k_pages.size * self._k_pages.dtype.itemsize * 2
             + scale_bytes)   # K+V pages (+ dequant scales in int8 mode)
+        # what max_batch contiguous bf16 rows of max_len would take
         dense = (self.cfg.n_layers * self.max_batch * self.max_len
                  * self._k_pages.shape[3] * self._k_pages.shape[4]
                  * 2 * 2)

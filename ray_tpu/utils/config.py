@@ -303,7 +303,7 @@ class Config:
     # Checkpointing: async by default.
     async_checkpointing: bool = True
 
-    # --- serve LLM engine (ray_tpu.serve.llm / paged_llm) ---
+    # --- serve LLM engine (ray_tpu.serve.paged_llm) ---
     # Steady-state decode steps per device dispatch: large chunks
     # amortize per-dispatch overhead (throughput), small chunks
     # bound how long a new request waits behind in-flight work (TTFT).

@@ -1,7 +1,8 @@
 """KV-cache decoding + continuous-batching engine tests.
 
-Correctness anchor: prefill+decode through the cache must reproduce the
-full (uncached) forward pass exactly under greedy sampling.
+Correctness anchor: prefill+decode through the cache, the plain one of
+``models/decoding.py`` and the serving engine's paged one, must reproduce
+the full (uncached) forward pass exactly under greedy sampling.
 """
 
 import jax
@@ -131,10 +132,10 @@ def test_sample_top_k_top_p():
 # ---------------------------------------------------------------------------
 
 def test_llm_engine_streams_and_matches_offline(tiny):
-    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.paged_llm import PagedLLMEngine
 
     cfg, params = tiny
-    eng = LLMEngine(cfg, params, max_batch=4, max_len=128)
+    eng = PagedLLMEngine(cfg, params, max_batch=4, max_len=128)
     eng.start()
     try:
         prompts = [[3, 17, 99, 254, 7], [5, 9, 13], [21, 34, 55, 89]]
@@ -159,7 +160,7 @@ def _tiny_builder():
 
 
 def test_llm_deployment_via_serve(ray_tpu_start):
-    """End-to-end: LLMEngine hosted in a Serve replica actor."""
+    """End-to-end: the engine hosted in a Serve replica actor."""
     from ray_tpu import serve
     from ray_tpu.serve.llm import LLMDeployment
 
@@ -177,10 +178,10 @@ def test_llm_deployment_via_serve(ray_tpu_start):
 
 def test_llm_engine_more_requests_than_slots(tiny):
     """Requests beyond max_batch queue up and still complete correctly."""
-    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.paged_llm import PagedLLMEngine
 
     cfg, params = tiny
-    eng = LLMEngine(cfg, params, max_batch=2, max_len=64)
+    eng = PagedLLMEngine(cfg, params, max_batch=2, max_len=64)
     eng.start()
     try:
         prompts = [[i + 1, i + 2, i + 3] for i in range(5)]

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama
-from ray_tpu.serve.llm import _STAGES, LLMEngine
+from ray_tpu.serve.llm import _STAGES
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 from ray_tpu.util import tracing
 
@@ -289,15 +289,6 @@ def test_engine_programs_carry_their_static_facts_in_their_names(tiny):
     assert "@jit_paged_prefill_w2" in text
     assert "@jit_scatter_firsts" in eng._scatter_fn.lower(
         i32((4,)), i32((2,)), i32((2,))).as_text()
-    dense = LLMEngine(cfg, params, max_batch=2, max_len=64, decode_chunk=4)
-    assert f"@jit_dense_decode_c{dense.decode_chunk}" in \
-        dense._decode_fn.lower(
-            params, dense._cache, i32((2,)), i32((2,)),
-            jnp.zeros((2,), bool), jnp.zeros((2,), jnp.float32),
-            jax.random.key(0)).as_text()
-    assert "@jit_dense_prefill_batch" in dense._prefill_batch_fn.lower(
-        params, dense._cache, i32((1, 16)), i32((1,)), i32((1,)),
-        jnp.zeros((1,), jnp.float32), jax.random.key(0)).as_text()
 
 
 def test_flash_kernels_are_named_in_the_lowered_program():

@@ -101,6 +101,8 @@ def test_cli_status_and_list(capsys):
         rows = json.loads(capsys.readouterr().out)
         assert len(rows) == 1
         main(["memory", "--address", addr])
-        assert "workers=" in capsys.readouterr().out
+        out = capsys.readouterr().out
+        assert "Cluster memory summary (mode=" in out
+        assert "\nOwned: " in out and " owners | store allocated " in out
     finally:
         c.shutdown()

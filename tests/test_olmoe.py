@@ -16,7 +16,6 @@ from benchmark import reference
 from benchmark.families import olmoe as family
 from ray_tpu.models import olmoe
 from ray_tpu.ops import moe
-from ray_tpu.serve.llm import LLMDeployment, LLMEngine
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 from ray_tpu.util import tracing
 
@@ -317,15 +316,6 @@ def test_decode_chunks_carry_the_routing_counts(tiny, tmp_path):
     # three live slots x 3 choices reach more than 3 of 8 experts
     assert max(a["experts_touched"] for a in chunks) > 3.0
     assert not eng._chunk_stats          # every dispatched chunk was read
-
-
-def test_the_dense_engine_refuses_the_block(tiny):
-    cfg, params = tiny
-    with pytest.raises(TypeError, match="dense-KV engine"):
-        LLMEngine(cfg, params, max_batch=1, max_len=64)
-    with pytest.raises(TypeError, match="dense-KV engine"):
-        LLMDeployment(lambda: (cfg, params), max_batch=1, max_len=64,
-                      kv_layout="dense")
 
 
 def test_the_engine_resolves_the_block_from_the_configs_class(tiny):

@@ -1,4 +1,4 @@
-"""Paged-KV serving engine (serve/paged_llm.py).
+"""The serving engine (serve/paged_llm.py) and its paged KV pool.
 
 Reference: ABSENT from the reference (it serves via user code in
 replicas, SURVEY.md P15); this is the vLLM-style paged KV design
@@ -11,8 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
-from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.models import decoding, llama
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 
 
@@ -21,9 +20,10 @@ def tiny():
     cfg = llama.llama_tiny()
     params = llama.init_params(cfg, jax.random.key(0))
     # sharpen the head: random-weight logits sit near ties, and the
-    # dense/paged engines compile DIFFERENT programs whose float
-    # rounding can flip a near-tie greedy argmax — a 4x margin makes
-    # exact token equality robust to program-level rounding
+    # engine and the plain decode it is held to compile DIFFERENT
+    # programs whose float rounding can flip a near-tie greedy argmax —
+    # a 4x margin makes exact token equality robust to program-level
+    # rounding
     params["lm_head"] = params["lm_head"] * 4.0
     return cfg, params
 
@@ -42,50 +42,54 @@ def _run(engine, prompts, max_new=16):
     return reqs, outs
 
 
+def _plain_greedy(cfg, params, prompts, max_new=16):
+    """The tokens the engine must produce: the library's plain decode
+    over contiguous KV rows (``decoding.generate``), one prompt a call."""
+    budgets = ([max_new] * len(prompts) if isinstance(max_new, int)
+               else max_new)
+    return [np.asarray(decoding.generate(
+        cfg, params, jnp.asarray(p, jnp.int32)[None],
+        sampling=decoding.SamplingParams(temperature=0.0,
+                                         max_new_tokens=n)))[0].tolist()
+            for p, n in zip(prompts, budgets)]
+
+
 def test_paged_matches_dense_greedy(tiny):
     """Greedy decode through the paged engine must produce EXACTLY the
-    dense engine's tokens — paging changes layout, not math."""
+    plain decode's tokens — paging changes layout, not math."""
     cfg, params = tiny
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, cfg.vocab_size, int(n))
                for n in (24, 48, 13, 70)]
-    dense = LLMEngine(cfg=cfg, params=params, max_batch=4, max_len=256)
-    _, out_d = _run(dense, prompts)
-    dense.stop()
+    out_d = _plain_greedy(cfg, params, prompts)
     paged = PagedLLMEngine(cfg=cfg, params=params, max_batch=4,
                            max_len=256, page_size=32)
     _, out_p = _run(paged, prompts)
     st = paged.stats()
     paged.stop()
     assert out_p == out_d
-    # the pool is half the dense equivalent by default
+    # the pool is half of max_batch full-length rows by default
     assert st["kv_pages_bytes"] * 2 == st["kv_dense_equiv_bytes"]
 
 
 def test_paged_matches_dense_across_admission_waves(tiny):
     """Requests admitted SEQUENTIALLY (multiple admission waves) must
-    still match the dense engine — regression for the stale device
+    still match the plain decode — regression for the stale device
     active-mask/table after the first wave."""
     cfg, params = tiny
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, cfg.vocab_size, int(n))
                for n in (20, 33, 27)]
 
-    def run_sequential(engine):
-        engine.start()
-        outs = []
-        for p in prompts:   # one at a time: each is its own wave
-            req = engine.submit(p, max_new_tokens=12)
-            outs.append(list(req.tokens()))
-        engine.stop()
-        return outs
-
-    dense = LLMEngine(cfg=cfg, params=params, max_batch=2, max_len=128)
-    out_d = run_sequential(dense)
     paged = PagedLLMEngine(cfg=cfg, params=params, max_batch=2,
                            max_len=128, page_size=32)
-    out_p = run_sequential(paged)
-    assert out_p == out_d
+    paged.start()
+    out_p = []
+    for p in prompts:   # one at a time: each is its own wave
+        req = paged.submit(p, max_new_tokens=12)
+        out_p.append(list(req.tokens()))
+    paged.stop()
+    assert out_p == _plain_greedy(cfg, params, prompts, 12)
 
 
 def test_pages_released_on_completion(tiny):
@@ -334,10 +338,9 @@ def test_paged_matches_dense_through_slot_refill(tiny, monkeypatch,
     handed out again. The stacked pools are written and gathered at
     [layer, page] inside the layer loop: every layer's rows must land in
     that layer's pages, in bf16 and through the int8 scale pools, or the
-    greedy tokens leave the dense engine's. For int8 the dense engine
+    greedy tokens leave the plain decode's. For int8 the plain decode
     rounds each new K and V row as the int8 pages do (plain int8 against
     bf16 flips near-tie tokens in most draws of the prompts)."""
-    from ray_tpu.models import decoding
     from ray_tpu.ops.paged_attention import dequantize_kv, quantize_kv
 
     cfg, params = tiny
@@ -354,9 +357,7 @@ def test_paged_matches_dense_through_slot_refill(tiny, monkeypatch,
     prompts.append(np.concatenate([base, rng.integers(1, cfg.vocab_size, 3)]))
     budgets = [5, 20, 9, 14, 7]
 
-    dense = LLMEngine(cfg=cfg, params=params, max_batch=2, max_len=128)
-    _, want = _run(dense, prompts, budgets)
-    dense.stop()
+    want = _plain_greedy(cfg, params, prompts, budgets)
     paged = PagedLLMEngine(
         cfg=cfg, params=params, max_batch=2, max_len=128, page_size=32,
         num_pages=10, kv_dtype=kv_dtype, prefix_cache=prefix_cache)

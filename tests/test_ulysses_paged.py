@@ -58,59 +58,122 @@ def test_ulysses_head_divisibility_error(sp_mesh):
 
 
 # ---------------------------------------------------------------------------
-# paged attention
+# the paged-KV format (ops/paged_attention.py): write, read back, allocate
 # ---------------------------------------------------------------------------
 
-class _Cfg:
-    n_layers = 2
-    n_heads = 4
-    n_kv_heads = 2
-    head_dim = 8
+LAYERS, POOL, PAGE, NKV, GROUP, HEAD_DIM = 3, 12, 4, 2, 2, 8
+LAYER = 1                   # written and read; the other layers stay zero
+LENGTHS = [7, 10, 0]        # ragged; slot 2 is dead
 
 
-def test_paged_matches_dense_decode():
-    from ray_tpu.ops.paged_attention import (PageAllocator, assign_pages,
-                                             init_paged_cache,
-                                             paged_attention, paged_write)
+def _ragged_slots(rng, dtype):
+    """Pools, a page table of scattered pages (holes past each slot's own)
+    and each slot's contiguous K and V rows [n, nkv, hd]."""
+    shape = (LAYERS, POOL, PAGE, NKV, HEAD_DIM)
+    pools = [jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)]
+    scale_shape = shape[:-1] if dtype == jnp.int8 else (LAYERS, 1, 1, 1)
+    pools += [jnp.ones(scale_shape, jnp.float32)] * 2
+    table = np.full((len(LENGTHS), 4), -1, np.int32)
+    free = list(rng.permutation(POOL))
+    for slot, n in enumerate(LENGTHS):
+        for i in range(-(-n // PAGE)):
+            table[slot, i] = free.pop()
+    rows = [(rng.normal(size=(n, NKV, HEAD_DIM)).astype(np.float32),
+             rng.normal(size=(n, NKV, HEAD_DIM)).astype(np.float32))
+            for n in LENGTHS]
+    return pools, table, rows
 
-    cfg = _Cfg()
-    page = 4
-    cache = init_paged_cache(cfg, num_pages=16, page_size=page,
-                             max_batch=2, max_pages_per_seq=4,
-                             dtype=jnp.float32)
-    alloc = PageAllocator(16)
+
+def _write_rows(pools, table, rows, quantized):
+    """Every slot's rows through ``write_kv`` at layer LAYER, as the
+    engine's prefill does: [n, T] indices, padding and dead slots named
+    by the index one past the pool."""
+    from ray_tpu.ops.paged_attention import write_kv
+
+    t = max(LENGTHS)
+    k_new = np.zeros((len(LENGTHS), t, NKV, HEAD_DIM), np.float32)
+    v_new = np.zeros_like(k_new)
+    pidx = np.full((len(LENGTHS), t), POOL, np.int32)
+    for slot, (k, v) in enumerate(rows):
+        n = len(k)
+        k_new[slot, :n], v_new[slot, :n] = k, v
+        pidx[slot, :n] = table[slot, np.arange(n) // PAGE]
+    ip = np.broadcast_to(np.arange(t) % PAGE, pidx.shape)
+    return write_kv(*pools, jnp.int32(LAYER), jnp.asarray(k_new),
+                    jnp.asarray(v_new), jnp.asarray(pidx), jnp.asarray(ip),
+                    quantized)
+
+
+def test_rows_written_to_scattered_pages_read_back_as_plain_attention():
+    """Ragged slots over scattered pages, grouped heads: rows written at
+    [layer, page, offset] and read back through the page table, by
+    ``gather_kv_window`` + ``cached_attention`` (the engine's prefill)
+    and by the decode attention's gather formulation, give plain
+    attention over each slot's contiguous rows."""
+    from ray_tpu.ops.attention import cached_attention
+    from ray_tpu.ops.paged_attention import gather_kv_window
+    from ray_tpu.ops.paged_decode_attention import \
+        paged_decode_attention_reference
 
     rng = np.random.default_rng(0)
-    lens = [7, 10]
-    kv = {}
-    for slot, n in enumerate(lens):
-        cache = assign_pages(cache, alloc, slot, n)
-        k_new = rng.normal(size=(n, cfg.n_kv_heads, cfg.head_dim)) \
-            .astype(np.float32)
-        v_new = rng.normal(size=(n, cfg.n_kv_heads, cfg.head_dim)) \
-            .astype(np.float32)
-        kv[slot] = (k_new, v_new)
-        for layer in range(cfg.n_layers):
-            cache = paged_write(cache, layer, slot, jnp.asarray(k_new),
-                                jnp.asarray(v_new), 0)
-        cache.lengths[slot] = n
-
-    q = rng.normal(size=(2, cfg.n_heads, cfg.head_dim)).astype(np.float32)
-    out = paged_attention(jnp.asarray(q), cache, layer=1)
-
-    # dense reference per sequence (GQA: repeat kv heads)
-    scale = cfg.head_dim ** -0.5
-    for slot, n in enumerate(lens):
-        k_new, v_new = kv[slot]
-        n_rep = cfg.n_heads // cfg.n_kv_heads
-        k_r = np.repeat(k_new, n_rep, axis=1)   # [n, nh, hd]
-        v_r = np.repeat(v_new, n_rep, axis=1)
-        logits = np.einsum("hd,khd->hk", q[slot], k_r) * scale
+    pools, table, rows = _ragged_slots(rng, jnp.float32)
+    pools = _write_rows(pools, table, rows, quantized=False)
+    for other in (0, 2):        # that layer's pages only
+        assert not np.asarray(pools[0][other]).any()
+    b, nh = len(LENGTHS), NKV * GROUP
+    q = rng.normal(size=(b, nh, HEAD_DIM)).astype(np.float32)
+    pos = np.maximum(np.array(LENGTHS) - 1, 0).astype(np.int32)
+    live = np.array(LENGTHS) > 0
+    args = (jnp.int32(LAYER), jnp.asarray(table))
+    kg, vg = gather_kv_window(*pools, *args)
+    got = cached_attention(
+        jnp.asarray(q)[:, None], kg.reshape(b, -1, NKV, HEAD_DIM),
+        vg.reshape(b, -1, NKV, HEAD_DIM), jnp.asarray(pos),
+        scale=HEAD_DIM ** -0.5)[:, 0]
+    ref = paged_decode_attention_reference(
+        jnp.asarray(q), *pools, *args, jnp.asarray(pos), jnp.asarray(live))
+    for slot, (k, v) in enumerate(rows):
+        if not live[slot]:
+            continue
+        k_r = np.repeat(k, GROUP, axis=1)           # [n, nh, hd]
+        v_r = np.repeat(v, GROUP, axis=1)
+        logits = np.einsum("hd,khd->hk", q[slot], k_r) * HEAD_DIM ** -0.5
         probs = np.exp(logits - logits.max(-1, keepdims=True))
         probs /= probs.sum(-1, keepdims=True)
-        expect = np.einsum("hk,khd->hd", probs, v_r)
-        np.testing.assert_allclose(np.asarray(out[slot]), expect,
-                                   rtol=2e-4, atol=2e-4)
+        want = np.einsum("hk,khd->hd", probs, v_r)
+        for out in (got, ref):
+            np.testing.assert_allclose(np.asarray(out[slot]), want,
+                                       rtol=2e-4, atol=2e-4)
+
+
+def test_int8_write_keeps_the_quantisers_error_and_drops_outside_the_pool():
+    """The same write through int8 pages and their scale pools: what
+    comes back is the row to within the quantiser's step, and an index
+    outside the pool (a dead slot, padding, a hole) writes nothing."""
+    from ray_tpu.ops.paged_attention import dequantize_kv
+
+    rng = np.random.default_rng(1)
+    pools, table, rows = _ragged_slots(rng, jnp.int8)
+    kp, vp, ks, vs = _write_rows(pools, table, rows, quantized=True)
+    assert kp.dtype == jnp.int8 and ks.shape == kp.shape[:-1]
+    k_back = np.asarray(dequantize_kv(kp, ks, jnp.float32))[LAYER]
+    v_back = np.asarray(dequantize_kv(vp, vs, jnp.float32))[LAYER]
+    written = np.zeros((POOL, PAGE), bool)
+    for slot, (k, v) in enumerate(rows):
+        for i in range(len(k)):
+            at = (table[slot, i // PAGE], i % PAGE)
+            written[at] = True
+            for back, row in ((k_back, k), (v_back, v)):
+                # half a step of a symmetric 127-level grid a (token, head)
+                step = np.abs(row[i]).max(axis=-1, keepdims=True) / 127.0
+                assert (np.abs(back[at] - row[i]) <= 0.51 * step).all()
+    assert written.sum() == sum(LENGTHS)
+    # everything else is as it was: zero pages, unit scales
+    assert not np.asarray(kp)[LAYER][~written].any()
+    assert (np.asarray(ks)[LAYER][~written] == 1.0).all()
+    for other in (0, 2):
+        assert not np.asarray(kp)[other].any()
+        assert (np.asarray(ks)[other] == 1.0).all()
 
 
 def test_page_allocator_reuse_and_exhaustion():
@@ -124,54 +187,47 @@ def test_page_allocator_reuse_and_exhaustion():
     alloc.free_slot(0)
     b = alloc.alloc(1, 4)
     assert len(set(b)) == 4
-    assert alloc.pages_needed(7, 1, 4) == 0   # 7+1 = 8 fits in 2 pages
-    assert alloc.pages_needed(8, 1, 4) == 1
 
 
-def test_release_slot_frees_pages():
-    from ray_tpu.ops.paged_attention import (PageAllocator, assign_pages,
-                                             init_paged_cache,
-                                             release_slot)
+def test_free_slot_returns_that_slots_pages_only():
+    """Two slots hold the whole pool: a request past it raises and takes
+    nothing; freeing one slot returns exactly its pages, and they are
+    what the next request gets."""
+    from ray_tpu.ops.paged_attention import PageAllocator
 
-    cfg = _Cfg()
-    cache = init_paged_cache(cfg, num_pages=8, page_size=4, max_batch=2,
-                             max_pages_per_seq=4, dtype=jnp.float32)
     alloc = PageAllocator(8)
-    cache = assign_pages(cache, alloc, 0, 16)  # 4 pages
-    assert len(alloc.free) == 4
-    # overflow raises the allocator's documented exhaustion error
-    cache.lengths[0] = 16
+    mine, theirs = alloc.alloc(0, 5), alloc.alloc(1, 3)
+    assert not alloc.free and not set(mine) & set(theirs)
     with pytest.raises(MemoryError):
-        assign_pages(cache, alloc, 0, 1)
-    cache = release_slot(cache, alloc, 0)
-    assert len(alloc.free) == 8
-    assert int(cache.lengths[0]) == 0
-    assert np.all(np.asarray(cache.page_table)[0] == -1)
+        alloc.alloc(2, 1)
+    assert 2 not in alloc.owned and alloc.owned[1] == theirs
+    alloc.free_slot(0)
+    assert sorted(alloc.free) == sorted(mine) and 0 not in alloc.owned
+    assert alloc.owned[1] == theirs
+    alloc.free_slot(0)                       # nothing left to return
+    assert len(alloc.free) == 5
+    assert sorted(alloc.alloc(2, 5)) == sorted(mine)
 
 
-def test_paged_write_all_matches_per_layer():
-    from ray_tpu.ops.paged_attention import (PageAllocator, assign_pages,
-                                             init_paged_cache, paged_write,
-                                             paged_write_all)
+def test_ops_import_nothing_from_the_layers_above():
+    """``ray_tpu/ops`` is the bottom layer: no module of it imports from
+    ``ray_tpu.models``, ``ray_tpu.serve`` or ``ray_tpu.train``, at the
+    top of the file or inside a function."""
+    import ast
+    import pathlib
 
-    cfg = _Cfg()
-    rng = np.random.default_rng(3)
-    kv = rng.normal(size=(cfg.n_layers, 6, cfg.n_kv_heads,
-                          cfg.head_dim)).astype(np.float32)
+    import ray_tpu.ops
 
-    def fresh():
-        c = init_paged_cache(cfg, num_pages=8, page_size=4, max_batch=1,
-                             max_pages_per_seq=4, dtype=jnp.float32)
-        a = PageAllocator(8)
-        return assign_pages(c, a, 0, 6)
-
-    c1 = fresh()
-    for layer in range(cfg.n_layers):
-        c1 = paged_write(c1, layer, 0, jnp.asarray(kv[layer]),
-                         jnp.asarray(kv[layer]), 0)
-    c2 = fresh()
-    c2 = paged_write_all(c2, 0, jnp.asarray(kv), jnp.asarray(kv), 0)
-    np.testing.assert_allclose(np.asarray(c1.k_pages),
-                               np.asarray(c2.k_pages))
-    np.testing.assert_allclose(np.asarray(c1.v_pages),
-                               np.asarray(c2.v_pages))
+    above = ("ray_tpu.models", "ray_tpu.serve", "ray_tpu.train")
+    found = []
+    for path in sorted(pathlib.Path(ray_tpu.ops.__file__).parent.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and not node.level:
+                names = [f"{node.module}.{a.name}" for a in node.names]
+            else:
+                continue
+            found += [(path.name, n) for n in names
+                      if any(n == a or n.startswith(a + ".") for a in above)]
+    assert not found
