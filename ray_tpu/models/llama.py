@@ -165,13 +165,39 @@ def attention_projections(cfg, p, x, sin, cos):
     ``feed_forward`` the block as two pieces that take no view on where
     keys and values live: ``attention_sublayer`` puts causal attention
     over the sequence between them, the paged serving engine its page
-    pool (``serve/paged_llm.py``)."""
+    pool (``serve/paged_llm.py``). Where the layer's weights hold the
+    fused ``wqkv`` (``fuse_attention_projections``) the three are one
+    matmul, split afterwards: each output column is the dot product it
+    was."""
     b, s, _ = x.shape
     h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    if "wqkv" in p:
+        qdim = cfg.n_heads * cfg.head_dim
+        kvdim = cfg.n_kv_heads * cfg.head_dim
+        q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
+            h @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
+    else:
+        q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+        k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+
+
+def fuse_attention_projections(blocks):
+    """The stacked blocks with ``wq``, ``wk`` and ``wv`` as ONE stack
+    ``wqkv`` [layers, d, (heads + 2 x kv heads) x hd], columns q | k | v,
+    which ``attention_projections`` takes in their place. For a program
+    that scans the layers many times over the same weights (the serving
+    engine's decode program), called once at the program's entry: a
+    stack of one projection can be small enough for the compiler to park
+    on the core (``wk`` at 12 layers of Mistral-7B's widths is 100.7 MB
+    of a v5e's 128 MiB) and then to write back and refetch WHOLE round
+    every layer's attention kernel; the fused stack (604 MB there) cannot
+    be, and a layer reads its own slice of it where it lies."""
+    blocks = dict(blocks)
+    blocks["wqkv"] = jnp.concatenate(
+        [blocks.pop("wq"), blocks.pop("wk"), blocks.pop("wv")], axis=-1)
+    return blocks
 
 
 def feed_forward(cfg, p, x, valid=None):

@@ -57,6 +57,25 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   and V pool out and back, every layer of every step, and the prefill
   program holds a second pool (measured on a v5e at 12 layers x 544
   pages: 43% of the device's time, 5.9 GB of HBM).
+- The WEIGHTS stay in place too: a decode step reads each layer's
+  weights once, where the stacks lie in HBM. The decode program
+  projects q, k and v from ONE stack where the block's module states
+  how (``fuse_attention_projections``: ``wqkv``, columns q | k | v),
+  built once at the program's entry, outside the step and layer loops;
+  ``self.params`` stay the caller's, in the published layout. Why one
+  stack: it must NOT fit the core's memory. A stack the compiler can
+  park there (Mistral-7B's ``wk`` over 12 layers: 100.7 MB of a v5e's
+  128 MiB) becomes a value of the layer loop that is written back to
+  HBM whole before the attention kernel, which needs the room, and
+  fetched whole again after it, in every layer of every step, to read
+  one layer of it: 201 MB a layer-step, a quarter of a step. The fused
+  stack (604 MB) cannot be parked, so its matmul takes the stack and
+  the layer index and reads its layer. Such a move has no name of its
+  own in a trace (``copy-done``, ``slice-done`` of stacked-weight
+  shape among the costliest operations is all that shows); to see one,
+  compile the program for a described chip and look for ``S(1)`` in
+  the layout of a weight stack inside a loop
+  (``tests/test_tpu_compile.py:_stack_moves_in_loops``).
 
 - What is the MODEL's comes from the model's module, resolved from the
   config's class (``_model_module``): the attention projections
@@ -375,6 +394,10 @@ class PagedLLMEngine:
         num_pages = k_pages.shape[1]
         b = table.shape[0]
         layers = jnp.arange(k_pages.shape[0])
+        # q, k and v from ONE weight stack where the block's module states
+        # how (module docstring), built here once, outside both scans
+        blocks = getattr(model, "fuse_attention_projections",
+                         lambda blocks: blocks)(params["blocks"])
 
         def one_step(carry, _):
             k_pages, v_pages, k_scale, v_scale, toks, lens, key = carry
@@ -408,7 +431,7 @@ class PagedLLMEngine:
 
             (x, k_pages, v_pages, k_scale, v_scale), stats = jax.lax.scan(
                 block, (x, k_pages, v_pages, k_scale, v_scale),
-                (params["blocks"], layers))
+                (blocks, layers))
             x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)[:, 0]
             head = model.lm_head_weights(cfg, params)
             logits = jnp.einsum("bd,dv->bv", x, head,

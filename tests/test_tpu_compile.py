@@ -11,7 +11,7 @@ trainers are steered to the flash kernel from here (``attn_impl``).
 import dataclasses
 import os
 import re
-from functools import partial
+from functools import cache, partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs to /tmp
 
@@ -140,10 +140,17 @@ def _window(slots, pages, nkv):
                       rf"{slots},{pages}),128,{nkv},128\]")
 
 
+@cache        # several tests read the same program's text
 def _compile_engine_program(device, model, cfg, num_pages, program, dims,
-                            slots=32, page=128, kv_dtype="bf16"):
+                            **engine):
+    return _lower_engine_program(device, model, cfg, num_pages, program,
+                                 dims, **engine).compile()
+
+
+def _lower_engine_program(device, model, cfg, num_pages, program, dims,
+                          slots=32, page=128, kv_dtype="bf16"):
     """One of the paged engine's two programs for ``cfg`` (whose block
-    ``model`` states), compiled for ``device`` from shapes alone.
+    ``model`` states), lowered for ``device`` from shapes alone.
     ``dims``: decode (chunk, window pages); prefill (prompts, tokens,
     window pages). ``kv_dtype``: the engine's, bf16 or int8 pages (these
     with their scale pools)."""
@@ -176,7 +183,60 @@ def _compile_engine_program(device, model, cfg, num_pages, program, dims,
                 shape((n,), jnp.int32), shape((n,), jnp.int32),
                 shape((n,), jnp.float32), key)
     return jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
-        params, pool, pool, scale, scale, *args).compile()
+        params, pool, pool, scale, scale, *args)
+
+
+_CALLED = re.compile(r"(?:calls|body|condition|to_apply)=(%[\w.\-]+)|"
+                     r"(?:branch|called)_computations=\{([^}]*)\}")
+_MOVE = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) (copy-start|copy-done|"
+                   r"slice-start|slice-done|copy|custom-call)\(")
+
+
+def _stack_moves_in_loops(text, layers, d_in, widths):
+    """The instructions of a compiled program's loops (the computations
+    its ``while``s run, and whatever those call) that MOVE a stack of
+    projection weights, whole or in part: a copy, an asynchronous copy
+    or slice, or the ``ConcatBitcast`` that joins such slices, whose
+    result is ``bf16[k, d_in, width]`` for 1 <= k <= ``layers``. What the
+    compiler parks on the core (``S(1)`` in a layout) it may write back
+    and fetch again round a kernel that needs the room, every trip of
+    the loop: such traffic has no name of its own in a trace and shows
+    only here. A fusion that READS a layer of a stack in place is no
+    move."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+
+    def called(lines):
+        for hit in _CALLED.finditer("\n".join(lines)):
+            yield from re.findall(r"%[\w.\-]+", hit.group(1) or hit.group(2))
+
+    todo = list(called(line for lines in bodies.values() for line in lines
+                       if " while(" in line))
+    in_loops = set()
+    while todo:
+        comp = todo.pop()
+        if comp in in_loops or comp not in bodies:
+            continue
+        in_loops.add(comp)
+        todo.extend(called(bodies[comp]))
+    assert in_loops, "the program has no loop"
+    stack = re.compile(
+        rf"bf16\[(\d+),{d_in},(?:{'|'.join(map(str, widths))})\]")
+    moves = []
+    for comp in in_loops:
+        for line in bodies[comp]:
+            m = _MOVE.match(line)
+            if not m or (m.group(2) == "custom-call"
+                         and "ConcatBitcast" not in line):
+                continue
+            if any(int(k) <= layers for k in stack.findall(m.group(1))):
+                moves.append(line.strip()[:160])
+    return moves
 
 
 # program, its dimensions (decode: chunk, window pages; prefill: prompts,
@@ -214,6 +274,13 @@ def test_d12_engine_programs_keep_the_pool_in_place(v5e_2x2, program, dims,
         assert not _window(32, dims[1], 8).findall(text)
 
 
+def _serving_model(name):
+    """A serve cell's block module and configuration, by its name."""
+    if name == "d12":
+        return llama, llama.LlamaConfig(**_D12)
+    return olmoe, dataclasses.replace(olmoe.olmoe_1b_7b(), n_layers=10)
+
+
 @pytest.mark.parametrize("model,pages,window", [
     ("d12", _D12_PAGES, 16), ("olmoe-d10", 352, 8)])
 def test_decode_programs_compile_over_int8_pages(v5e_2x2, model, pages,
@@ -222,11 +289,7 @@ def test_decode_programs_compile_over_int8_pages(v5e_2x2, model, pages,
     the same kernel (the pool's dtype is all that differs), dequantising
     in VMEM; no window of the pages in any type, no pool moved whole. The
     window's SCALES are gathered (1/32 of its bytes)."""
-    if model == "d12":
-        module, cfg = llama, llama.LlamaConfig(**_D12)
-    else:
-        module = olmoe
-        cfg = dataclasses.replace(olmoe.olmoe_1b_7b(), n_layers=10)
+    module, cfg = _serving_model(model)
     compiled = _compile_engine_program(
         v5e_2x2[0], module, cfg, pages, "decode", (16, window),
         kv_dtype="int8")
@@ -235,6 +298,12 @@ def test_decode_programs_compile_over_int8_pages(v5e_2x2, model, pages,
     assert not _window(32, window, cfg.n_kv_heads).findall(text)
     assert not _pool_copy(cfg.n_layers, pages, cfg.n_kv_heads).findall(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
+    # (OLMoE's block states no fused stack, and over int8 pages its decode
+    # program does evict and refetch the parked ``wv`` stack every layer:
+    # no cell runs it; ROADMAP Queue 1 item 3)
+    if model == "d12":
+        assert not _stack_moves_in_loops(text, cfg.n_layers, cfg.d_model,
+                                         _projection_widths(cfg))
 
 
 # the expert cell's engine (PR 28): OLMoE-1B-7B widths cut to 10 layers,
@@ -273,3 +342,38 @@ def test_olmoe_d10_engine_programs_compile_and_fit(v5e_2x2, program, dims,
     assert mem.temp_size_in_bytes < 0.8e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             - mem.alias_size_in_bytes) < 13.5e9              # of 15.75 GB
+
+
+def _projection_widths(cfg):
+    """The output widths a stack of attention projection weights can
+    have: q (and ``wo``'s input), k or v, and the three fused."""
+    q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    return sorted({q, kv, q + 2 * kv})
+
+
+@pytest.mark.parametrize("model,pages,window,chunk", [
+    ("d12", _D12_PAGES, 16, 16), ("d12", _D12_PAGES, 16, 8),
+    ("olmoe-d10", _MOE_PAGES, 8, 16), ("olmoe-d10", _MOE_PAGES, 8, 8)])
+def test_decode_loops_move_no_projection_weight_stack(v5e_2x2, model, pages,
+                                                      window, chunk):
+    """A decode step reads each layer's projection weights where they lie
+    and once. With q, k and v projected from three stacks the compiler
+    parked the d12 ``wk`` stack (100.7 MB of the core's 128 MiB) on the
+    core as a value of the layer loop, and in every layer of every step
+    wrote all twelve layers back to HBM before the attention kernel
+    (``copy-done bf16[12,4096,1024]``) and fetched them again in four
+    ``slice-done bf16[3,4096,1024]``: 201 MB a layer-step that no
+    arithmetic needs, 2.9 ms of an 11.4-14.7 ms step on a v5e (ledger,
+    PR 31). The fused stack (604 MB) cannot be parked: its matmul's
+    fusion takes the whole stack and the layer index. OLMoE's block
+    states no fused stack; its decode programs park ``wv`` once at the
+    entry and move nothing inside a loop: a fence."""
+    module, cfg = _serving_model(model)
+    compiled = _compile_engine_program(v5e_2x2[0], module, cfg, pages,
+                                       "decode", (chunk, window))
+    moves = _stack_moves_in_loops(compiled.as_text(), cfg.n_layers,
+                                  cfg.d_model, _projection_widths(cfg))
+    assert not moves, "\n".join(moves)
+    # the fused stack is built once a run, at the entry, in place of the
+    # three transposed entry copies: the same bytes of temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
