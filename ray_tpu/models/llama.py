@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -181,6 +182,39 @@ def attention_projections(cfg, p, x, sin, cos):
         k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
         v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+
+
+def attention_output(cfg, p, x, attn):
+    """The attention sublayer's end: the heads' outputs ``attn`` ([b, s,
+    heads, hd], or [b, heads, hd] of a one-token step) through ``wo``,
+    added to the residual stream ``x`` [b, s, d]. The third of the block's
+    pieces that the serving engine calls (a block that gates its heads
+    does it here)."""
+    b, s, _ = x.shape
+    return x + attn.reshape(b, s, -1) @ p["wo"]
+
+
+class LayerStack(NamedTuple):
+    """A run of identical layers, as the serving engine's layer loop
+    takes it (``layer_plan``)."""
+    key: str | None      # ``params["blocks"][key]`` holds the run's weights
+    #                      stacked on a leading axis; None: the blocks do
+    kind: str            # which of ``rotary_tables`` its attention takes
+    window: int | None   # keys a query sees (a sliding layer); None: all
+    layers: int
+
+
+def layer_plan(cfg) -> tuple:
+    """The model's layers as runs of identical layers, in order. Here one
+    run: every layer is the same block, stacked in ``params["blocks"]``,
+    and attends over its whole context."""
+    return (LayerStack(None, "full", None, cfg.n_layers),)
+
+
+def rotary_tables(cfg, positions) -> dict:
+    """(sin, cos) of ``positions`` for each kind of layer the plan names."""
+    return {"full": rope_sin_cos(positions, cfg.head_dim,
+                                 theta=cfg.rope_theta)}
 
 
 def fuse_attention_projections(blocks):

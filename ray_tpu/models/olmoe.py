@@ -12,9 +12,9 @@ logical-axis annotations), with two differences in the block:
   the routing weights are the top-k softmax probabilities as they are
   unless ``norm_topk_prob``.
 
-The block is stated as two pieces that take no view on where keys and
-values live, ``attention_projections`` and ``feed_forward`` (as
-``models/llama.py`` states its own): ``forward`` puts causal attention
+The block is stated as pieces that take no view on where keys and
+values live, ``attention_projections``, ``attention_output`` and
+``feed_forward`` (as ``models/llama.py`` states its own): ``forward`` puts causal attention
 between them, the paged serving engine its page pool
 (``serve/paged_llm.py``).
 """
@@ -27,9 +27,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.llama import (
+from ray_tpu.models.llama import (  # noqa: F401 - parts of the block's module
+    attention_output,   # the attention sublayer's end, the one run of
+    layer_plan,         # identical layers, the one rotary table and the
+    rotary_tables,      # head are Llama's
     fanin_init,
-    lm_head_weights,  # noqa: F401 - the head is Llama's: part of the block's module
+    lm_head_weights,
 )
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.moe import moe_ffn_dropless

@@ -77,13 +77,18 @@ def reference_attention(
     return out.astype(q.dtype)
 
 
-def cached_attention(q, k_cache, v_cache, start, *, scale):
+def cached_attention(q, k_cache, v_cache, start, *, scale, window=None,
+                     key_start=None):
     """Plain attention of new queries over a cache that already holds
     their keys. q: [B, T, nh, hd]; caches [B, S, nkv, hd]; start [B] =
     offset of the first query token. Causal over the whole cache: query i
-    attends to key positions <= start + i. The library's plain decode
-    (``models/decoding.py``), the serving engine's prefill and the
-    non-TPU lowering of its decode attention all call this."""
+    attends to key positions <= start + i, and with ``window`` to those
+    > start + i - window alone (a sliding layer). ``key_start`` [B] is the
+    position of the cache's first row where that is not 0 (a caller that
+    hands a windowed layer only the rows its queries can see). The
+    library's plain decode (``models/decoding.py``), the serving engine's
+    prefill and the non-TPU lowering of its decode attention all call
+    this."""
     b, t, nh, hd = q.shape
     s = k_cache.shape[1]
     nkv = k_cache.shape[2]
@@ -97,7 +102,13 @@ def cached_attention(q, k_cache, v_cache, start, *, scale):
                         preferred_element_type=jnp.float32) * scale
     qpos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
     kpos = jnp.arange(s, dtype=jnp.int32)                            # [S]
-    mask = kpos[None, None, :] <= qpos[:, :, None]                   # [B,T,S]
+    if key_start is None:
+        mask = kpos[None, None, :] <= qpos[:, :, None]               # [B,T,S]
+    else:
+        kpos = key_start[:, None] + kpos[None, :]                    # [B,S]
+        mask = kpos[:, None, :] <= qpos[:, :, None]
+    if window is not None:
+        mask = mask & (kpos[..., None, :] > qpos[:, :, None] - window)
     logits = jnp.where(mask[:, None, None, :, :], logits,
                        jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)

@@ -13,8 +13,12 @@ those tokens unchanged), it renormalises the chosen gates, and its
 a wrong answer: float32 softmax over all experts, top-k, every chosen
 expert applied to its token, nothing dropped, no ``[T, E, C]`` tensor. It
 picks its formulation from the token count it is traced with
-(``DENSE_MAX_TOKENS``). (Reference has NO MoE implementation — SURVEY.md
-§2c row EP.)
+(``DENSE_MAX_TOKENS``). It is also the expert layer of ONE CHIP under
+expert parallelism: told which experts it holds (expert stacks narrower
+than the router, and the first held expert's index), it routes over all
+of them and computes the held experts' part of the result, without the
+exchange that would add the other chips' parts. (Reference has NO MoE
+implementation — SURVEY.md §2c row EP.)
 """
 
 from __future__ import annotations
@@ -136,25 +140,33 @@ DENSE_MAX_TOKENS = 1024
 def moe_ffn_dropless(
     x,                  # [T, D] tokens (flattened batch*seq)
     router_w,           # [D, E]
-    wi_gate,            # [E, D, F]
-    wi_up,              # [E, D, F]
-    wo,                 # [E, F, D]
+    wi_gate,            # [H, D, F]: the H <= E experts held here
+    wi_up,              # [H, D, F]
+    wo,                 # [H, F, D]
     *,
     top_k: int,
     norm_topk_prob: bool = False,
+    routed_scale: float = 1.0,
+    first_expert: int = 0,
     valid=None,         # [T] bool: rows that are tokens (None: all)
 ):
-    """Exact routed SwiGLU feed-forward. Returns (out [T, D], load [E]).
+    """Exact routed SwiGLU feed-forward. Returns (out [T, D], load [H]).
 
-    ``p = softmax(float32(x) @ router_w)`` over all experts; the ``top_k``
-    largest and their experts; the weights are those probabilities as they
-    are, or divided by their sum with ``norm_topk_prob``;
-    ``out = sum_k p_k * (silu(x @ gate_k) * (x @ up_k)) @ down_k``. Rows
-    that ``valid`` marks as padding go to no expert and come out zero.
-    ``load`` counts the (token, choice) pairs each expert got (int32).
+    ``p = softmax(float32(x) @ router_w)`` over all E experts; the
+    ``top_k`` largest and their experts; the weights are those
+    probabilities as they are, or divided by their sum with
+    ``norm_topk_prob``, times ``routed_scale``;
+    ``out = sum_k p_k * (silu(x @ gate_k) * (x @ up_k)) @ down_k`` over
+    those of a token's chosen experts that are HELD here: experts
+    ``first_expert`` to ``first_expert + H`` of the router's E, H the
+    length of the expert stacks (all of them where H = E). A choice that
+    falls on an absent expert adds nothing: its part is another chip's.
+    Rows that ``valid`` marks as padding go to no expert and come out
+    zero. ``load`` counts the (token, choice) pairs each held expert got
+    (int32).
     """
     t, d = x.shape
-    e = router_w.shape[1]
+    e = wi_gate.shape[0]
     dtype = x.dtype
     with jax.named_scope("moe_router"):
         # true float32: the chip's default would round the products to
@@ -165,7 +177,14 @@ def moe_ffn_dropless(
         gate_vals, gate_idx = jax.lax.top_k(probs, top_k)     # [T, K]
         if norm_topk_prob:
             gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-        chosen = gate_idx[:, :, None] == jnp.arange(e)        # [T, K, E]
+        if routed_scale != 1.0:
+            gate_vals = gate_vals * routed_scale
+        if e < router_w.shape[1]:
+            # a share: experts by their place in the stacks held here, and
+            # every absent one as ``e``, which is no expert's place
+            gate_idx = gate_idx - first_expert
+            gate_idx = jnp.where((gate_idx >= 0) & (gate_idx < e), gate_idx, e)
+        chosen = gate_idx[:, :, None] == jnp.arange(e)        # [T, K, H]
         if valid is not None:
             chosen = chosen & valid[:, None, None]
         load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)  # [E]

@@ -24,7 +24,8 @@ Token ``pos`` of a sequence lives at ``[layer, table[pos // page_size],
 pos % page_size]``. ``write_kv`` scatters new rows there (an index
 outside the pool, which is how a caller names a dead slot or a hole,
 writes nothing); ``gather_kv_window`` copies a table's pages back out,
-dequantised. On the host, ``PageAllocator`` hands out page ids,
+dequantised (``visible_pages`` first cuts the table to what a windowed
+layer can see). On the host, ``PageAllocator`` hands out page ids,
 ``page_hashes`` and ``PrefixCache`` make full prompt pages shareable.
 """
 
@@ -115,6 +116,24 @@ def gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer, table):
         kg = dequantize_kv(kg, k_scale[layer, table_c])
         vg = dequantize_kv(vg, v_scale[layer, table_c])
     return kg, vg
+
+
+def visible_pages(table, first_key, n_pages: int, page_size: int):
+    """The part of each row's page table that a WINDOWED layer's queries
+    can see: ``n_pages`` consecutive entries of ``table`` [B, PB] from
+    the page that holds key position ``first_key`` [B] (the oldest key of
+    the row's first query's window), and the position of their first row
+    [B], which ``cached_attention`` takes as ``key_start``. Entries past
+    the table's end repeat its last: their positions lie past every query
+    and the causal limit masks them. The whole table, from position 0,
+    where it is no wider than ``n_pages``."""
+    b, pb = table.shape
+    if n_pages >= pb:
+        return table, jnp.zeros((b,), jnp.int32)
+    first_page = jnp.maximum(first_key, 0) // page_size
+    cols = first_page[:, None] + jnp.arange(n_pages, dtype=jnp.int32)
+    rows = jnp.take_along_axis(table, jnp.minimum(cols, pb - 1), axis=1)
+    return rows, (first_page * page_size).astype(jnp.int32)
 
 
 def page_hashes(tokens: np.ndarray, page_size: int) -> list[bytes]:

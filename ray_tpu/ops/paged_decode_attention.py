@@ -31,7 +31,14 @@ holds it, and stops at each slot's length:
 - int8 pages: the per-(token, head) scales multiply the score COLUMNS
   (K) and the probability columns (V), which is the dequantisation done
   in VMEM after the matmul instead of on a window copy before it. The
-  window's scales (1/32 of its bytes) are gathered by XLA into rows.
+  window's scales (1/32 of its bytes) are gathered by XLA into rows;
+- a SLIDING layer (``window``: a query sees the ``window`` newest keys,
+  itself among them) starts each slot's walk at the page that holds key
+  ``count - window`` and masks the rows before it: pages that lie wholly
+  before the window are neither fetched nor computed, so a slot costs
+  ``window / page`` pages, one more where the window straddles a page's
+  edge, however long its context. Without a window the kernel is the
+  one it was, instruction for instruction.
 
 ``paged_decode_attention`` is the entry: on a program LOWERED for a TPU
 it is the kernel, on any other platform the plain gather formulation
@@ -50,7 +57,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import cached_attention
-from ray_tpu.ops.paged_attention import gather_kv_window
+from ray_tpu.ops.paged_attention import gather_kv_window, visible_pages
 
 KERNEL_NAME = "paged_decode_attn"
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -58,24 +65,33 @@ _BUFFERS = 2
 
 
 def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
-                                     layer, table, pos, active):
+                                     layer, table, pos, active, *,
+                                     window=None):
     """The gather formulation: every slot's window copied out
     (``gather_kv_window``), then ``cached_attention`` over the copy with
-    the causal limit ``key position <= pos``. What the kernel is held to,
-    and what every platform but the TPU runs."""
+    the causal limit ``key position <= pos``; for a sliding layer only
+    the pages that hold keys ``> pos - window`` are copied. What the
+    kernel is held to, and what every platform but the TPU runs."""
     del active      # a dead slot attends over page 0; its row is discarded
     b, _, hd = q.shape
+    page = k_pages.shape[2]
+    key_start = None
+    if window is not None:
+        # the window's keys lie in window / page pages, and one more
+        table, key_start = visible_pages(
+            table, pos - window + 1, -(-(window - 1) // page) + 1, page)
     kg, vg = gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer,
                               table)
     nkv = kg.shape[-2]
     out = cached_attention(q[:, None], kg.reshape(b, -1, nkv, hd),
                             vg.reshape(b, -1, nkv, hd), pos,
-                            scale=hd ** -0.5)
+                            scale=hd ** -0.5, window=window,
+                            key_start=key_start)
     return out[:, 0]
 
 
 def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
-            q_ref, k_hbm, v_hbm, *rest, pages_per_slot, quantized):
+            q_ref, k_hbm, v_hbm, *rest, pages_per_slot, quantized, window):
     """See the module docstring. ``rest``: the window's scale rows (int8
     only), the output, the page buffers and their DMA semaphores."""
     if quantized:
@@ -109,11 +125,20 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
     own_head = (col % nkv) == (row // (nh // nkv))
     token = col // nkv
 
+    def first_block(slot):
+        """The first page of a slot's walk: 0, or the page of the oldest
+        key its window holds."""
+        if window is None:
+            return 0
+        # (the chain ends at ``slots``, which is no slot: read the last)
+        count = count_ref[jnp.minimum(slot, slots - 1)]
+        return jnp.maximum(count - window, 0) // page
+
     first = next_ref[0]
 
     @pl.when(first < slots)
     def _():
-        start(first, 0, 0)
+        start(first, first_block(first), 0)
 
     def slot_body(slot, step):
         count = count_ref[slot]
@@ -126,7 +151,7 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
             buf = step % _BUFFERS
             more = i + 1 < n_blocks
             nslot = jnp.where(more, slot, next_ref[slot + 1])
-            nblock = jnp.where(more, i + 1, 0)
+            nblock = jnp.where(more, i + 1, first_block(nslot))
 
             @pl.when(nslot < slots)
             def _():
@@ -140,7 +165,10 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
                                 preferred_element_type=jnp.float32) * scale
             if quantized:
                 s = s * ks_ref[slot, pl.ds(i, 1), :]
-            s = jnp.where(own_head & (token < count - i * page), s, _MASKED)
+            seen = own_head & (token < count - i * page)
+            if window is not None:
+                seen = seen & (token >= count - window - i * page)
+            s = jnp.where(seen, s, _MASKED)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -155,7 +183,7 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
             return m_new, l, acc, step + 1
 
         m, l, acc, step = lax.fori_loop(
-            0, n_blocks, block_body,
+            first_block(slot), n_blocks, block_body,
             (jnp.full((nh, 1), -jnp.inf, jnp.float32),
              jnp.zeros((nh, 1), jnp.float32),
              jnp.zeros((nh, hd), jnp.float32), step))
@@ -168,7 +196,7 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
                                   layer, table, pos, active, *,
-                                  interpret=False):
+                                  window=None, interpret=False):
     """The kernel's launch; arguments as ``paged_decode_attention``."""
     slots, nh, hd = q.shape
     _, _, page, nkv, _ = k_pages.shape
@@ -193,7 +221,8 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
                      v_scale[layer, table_c].reshape(slots, pb, page * nkv)]
         in_specs += [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2
     return pl.pallas_call(
-        functools.partial(_kernel, pages_per_slot=pb, quantized=quantized),
+        functools.partial(_kernel, pages_per_slot=pb, quantized=quantized,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(1,), in_specs=in_specs,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -207,20 +236,33 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
       next_live, *operands)
 
 
+@functools.cache
+def _lowerings(window):
+    """The two lowerings for a layer of this window, made once a window:
+    see ``paged_decode_attention`` on why they are not made a call."""
+    if window is None:
+        return paged_decode_attention_kernel, paged_decode_attention_reference
+    return (functools.partial(paged_decode_attention_kernel, window=window),
+            functools.partial(paged_decode_attention_reference,
+                              window=window))
+
+
 def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
-                           table, pos, active):
+                           table, pos, active, *, window=None):
     """One decode step's attention for every slot, scores scaled by
     head_dim ** -0.5. q [B, nh, hd]; stacked pools [L, P, page, nkv, hd]
     (bf16, or int8 with their scale pools [L, P, page, nkv]); ``layer`` a
     scalar; ``table`` [B, PB] page ids (-1 = hole); slot b attends key
-    positions <= pos[b] of its pages if ``active[b]`` (a dead slot's row
-    is unspecified, and discarded). Returns [B, nh, hd] in q's dtype.
+    positions <= pos[b] of its pages, with ``window`` (static: a sliding
+    layer's) those > pos[b] - window alone, if ``active[b]`` (a dead
+    slot's row is unspecified, and discarded). Returns [B, nh, hd] in q's
+    dtype.
 
     The two branches are the module's own functions, not closures made a
     call: JAX then keeps their traces, and an engine's decode programs
     that differ in their chunk alone trace them once (a trace of both is
     0.2-0.3 s of a program's set-up on the chip's host)."""
+    kernel, reference = _lowerings(window)
     return lax.platform_dependent(
         q, k_pages, v_pages, k_scale, v_scale, layer, table, pos, active,
-        tpu=paged_decode_attention_kernel,
-        default=paged_decode_attention_reference)
+        tpu=kernel, default=reference)

@@ -13,7 +13,11 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   streaming for everyone else. Tokens stream back through per-request
   queues (``serve/llm.py``: ``Request``).
 
-- The KV cache is a POOL of fixed-size pages [L, P, page, nkv, hd];
+- The KV cache is a POOL of fixed-size pages [L, P, page, nkv, hd],
+  uniform over the layers whatever their kind (a model's full and
+  sliding layers share its KV heads and head size; a sliding layer keeps
+  every page too, and reads only its window's: releasing what has fallen
+  out of every window needs a pool per kind, ROADMAP Queue 2 B.1);
   each slot owns a page list. HBM scales with TOKENS IN FLIGHT
   (reserved per request = prompt + max_new_tokens), not with
   ``max_batch * max_len`` — a 256-token chat on a 2048-token engine
@@ -48,7 +52,11 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
 
 - The device programs keep the pools IN PLACE: the layer loop carries
   the stacked pools (and scale pools) whole, beside the activations,
-  and scans over (layer weights, layer index); a layer scatters its new
+  and scans over (layer weights, layer index), one scan for each run of
+  identical layers in the model's LAYER PLAN (``layer_plan`` of its
+  module: one run for a model that repeats one block; a leading layer,
+  then sliding x 3, full x 1, ... for Laguna), the runs in order over
+  the pools' layers; a layer scatters its new
   rows at [layer, page, offset] (``write_kv``) and reads its pages at
   [layer, table]: decode in the kernel, prefill by gathering its window
   (``gather_kv_window``; both state the format, in
@@ -78,13 +86,20 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   (``tests/test_tpu_compile.py:_stack_moves_in_loops``).
 
 - What is the MODEL's comes from the model's module, resolved from the
-  config's class (``_model_module``): the attention projections
-  (``attention_projections``: norm, q/k/v, whatever the block does to
-  them, rotary), the feed-forward (``feed_forward``: a dense SwiGLU, or
-  routed experts) and the output head (``lm_head_weights``). What is the
+  config's class (``_model_module``): the layer plan (``layer_plan``:
+  the runs of identical layers, each with its kind and its window or
+  none), the rotary tables of each kind (``rotary_tables``, once a
+  step), the attention projections (``attention_projections``: norm,
+  q/k/v, whatever the block does to them, rotary), the sublayer's end
+  (``attention_output``: ``wo`` and the residual, a per-head gate where
+  the block has one), the feed-forward (``feed_forward``: a dense
+  SwiGLU, or routed experts, held whole or as this chip's share) and the
+  output head (``lm_head_weights``). What is the
   ENGINE's stays here, once for every model: the page write, decode's
   attention over the pages (the kernel), prefill's gather and
-  ``cached_attention``, the layer scan, sampling, the chunk loop. A
+  ``cached_attention`` (whole, or over blocks of queries where the
+  scores would not fit: ``_prefill_attention``), the scans over the
+  plan's runs, sampling, the chunk loop. A
   feed-forward may hand back statistics of its call (scalars; a dense
   one has none): the decode program averages them over the chunk's
   layer-steps, and they go on the chunk's ``engine.emit`` span.
@@ -115,9 +130,8 @@ from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
                                          gather_kv_window, page_hashes,
-                                         write_kv)
+                                         visible_pages, write_kv)
 from ray_tpu.ops.paged_decode_attention import paged_decode_attention
-from ray_tpu.ops.rope import rope_sin_cos
 from ray_tpu.serve.llm import _STAGES, Request, _named_jit, _serve_hist
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import tracing as _tracing
@@ -144,9 +158,113 @@ def _model_module(cfg):
 
     if isinstance(cfg, olmoe.OlmoeConfig):
         return olmoe
+    from ray_tpu.models import laguna
+
+    if isinstance(cfg, laguna.LagunaConfig):
+        return laguna
     raise TypeError(
         f"unsupported model config {type(cfg).__name__}; the paged engine "
-        "serves LlamaConfig and OlmoeConfig")
+        "serves LlamaConfig, OlmoeConfig and LagunaConfig")
+
+
+# Float32 scores of one ``cached_attention`` call that a prefill program
+# may hold, [n, heads, T, S] (the call keeps about twice that beside
+# them): past it the program goes over its queries in blocks whose scores
+# are a quarter of it. From the traced shapes, as ``ops.moe`` picks its
+# formulation; 1 GiB is two cold prompts of 2048 tokens at 32 heads, the
+# largest prefill of the cells that serve Mistral-7B's widths, so every
+# program that ran whole before runs whole.
+SCORES_MAX_BYTES = 1 << 30
+
+
+def _query_block(n: int, t: int, heads: int, keys: int, window) -> int:
+    """How many of a prefill's ``t`` query positions a row attends with at
+    once (``t``: all of them). A sliding layer goes window by window: a
+    block of ``window`` queries sees two windows of keys, whatever ``t``.
+    A full layer goes whole while its scores fit ``SCORES_MAX_BYTES``, and
+    past that in blocks whose scores are a quarter of it."""
+    def scores(block):
+        return 4 * n * heads * block * keys
+
+    if window is not None:
+        block = _bucket(window, minimum=1)
+    elif scores(t) <= SCORES_MAX_BYTES:
+        return t
+    else:
+        block = t
+        while block > 16 and scores(block) > SCORES_MAX_BYTES // 4:
+            block //= 2
+    return block if block < t and t % block == 0 else t
+
+
+def _prefill_attention(q, kp, vp, ks, vs, layer, table_rows, starts, *,
+                       window, page_size):
+    """A prefill's attention for one layer: queries ``q`` [n, T, heads,
+    hd] at positions ``starts + i`` over their rows' pages of ``layer``,
+    whose new rows are written. One gather of the rows' whole tables and
+    one ``cached_attention`` where that fits; past ``SCORES_MAX_BYTES``
+    the same call on blocks of queries, one after another (one program,
+    one dispatch: the host sees nothing of it). A sliding layer goes in
+    blocks of its window, and for each gathers only the pages that the
+    block's queries can see."""
+    n, t, heads, hd = q.shape
+    mp = table_rows.shape[1]
+    nkv = kp.shape[3]
+    block = _query_block(n, t, heads, mp * page_size, window)
+    # the pages that hold the keys of ``block`` queries' windows
+    seen = (mp if window is None
+            else -(-(block + window - 2) // page_size) + 1)
+
+    def attend(q, first):
+        """``q`` [n, block, heads, hd], the first of them at ``first``."""
+        rows, where = table_rows, {}
+        if window is not None:
+            rows, key_start = visible_pages(table_rows, first - window + 1,
+                                            seen, page_size)
+            where = {"window": window, "key_start": key_start}
+        # gathered AFTER the suffix writes: queries attend over cached
+        # prefix + their own fresh KV; positions beyond start+i are
+        # masked causally, stale page contents beyond the prompt never
+        # influence the result
+        kg, vg = gather_kv_window(kp, vp, ks, vs, layer, rows)
+        return cached_attention(q, kg.reshape(n, -1, nkv, hd),
+                                vg.reshape(n, -1, nkv, hd), first,
+                                scale=hd ** -0.5, **where)
+
+    if block == t:
+        return attend(q, starts)
+    firsts = starts[None, :] + block * jnp.arange(
+        t // block, dtype=jnp.int32)[:, None]                  # [blocks, n]
+    qb = jnp.moveaxis(q.reshape(n, t // block, block, heads, hd), 1, 0)
+    out = jax.lax.map(lambda xs: attend(*xs), (qb, firsts))
+    return jnp.moveaxis(out, 0, 1).reshape(n, t, heads, hd)
+
+
+def _plan_runs(plan, blocks, fuse=None) -> list:
+    """What each run of a layer plan scans over: (its stacked weights, its
+    layers' indices in the pools). Layers take the pools' layers in the
+    plan's order. ``fuse``: what a module does to its blocks once at a
+    program's entry (``fuse_attention_projections``)."""
+    layers, first = [], 0
+    for run in plan:
+        layers.append(jnp.arange(first, first + run.layers))
+        first += run.layers
+    if fuse is not None:
+        blocks = fuse(blocks)
+    return [(blocks if run.key is None else blocks[run.key], idx)
+            for run, idx in zip(plan, layers)]
+
+
+def _over_layers(stats: list) -> dict:
+    """The feed-forward statistics of one step's runs, each {name:
+    [layers of the run]}, as {name: [the layers that report it]}: a run
+    of dense layers reports none."""
+    names = {name for run_stats in stats for name in run_stats}
+    out = {}
+    for name in sorted(names):
+        parts = [run_stats[name] for run_stats in stats if name in run_stats]
+        out[name] = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    return out
 
 
 class PagedLLMEngine:
@@ -312,6 +430,11 @@ class PagedLLMEngine:
         self._deferred_free: list[list] = []
         self._decode_cache: dict[tuple[int, int], object] = {}
         self._prefill_cache: dict[int, object] = {}
+        # a sliding layer's window, if the model's plan has such layers
+        # (for the decode dispatch's count of the KV rows a step reads)
+        self._window = next(
+            (run.window for run in _model_module(cfg).layer_plan(cfg)
+             if run.window is not None), None)
         # per dispatched decode chunk, the feed-forward's statistics on
         # the device until the chunk is emitted (_sync_chunk)
         self._chunk_stats: deque = deque()
@@ -385,27 +508,29 @@ class PagedLLMEngine:
         mid-chunk keep decoding; the host drops their surplus tokens.
         In int8 mode (``quantized``) writes quantize per token+head and
         the kernel dequantizes against the scale pages — half the KV
-        bytes per step. Two nested scans: over steps, carrying the
-        pools, last tokens, lengths and key; inside it over layers,
-        carrying the activations and the same stacked pools (module
-        docstring: in place), scanning over the layers' weights and
+        bytes per step. Nested scans: over steps, carrying the
+        pools, last tokens, lengths and key; inside it over the layers
+        of each run of the model's layer plan in turn, carrying the
+        activations and the same stacked pools (module docstring: in
+        place), scanning over the run's weights and its layers'
         indices."""
         model = _model_module(cfg)
         num_pages = k_pages.shape[1]
-        b = table.shape[0]
-        layers = jnp.arange(k_pages.shape[0])
+        # the model's layers as runs of identical layers (one run, for a
+        # model that repeats one block); each run's weights and its
+        # layers' places in the pools, built here once, outside every scan
+        plan = model.layer_plan(cfg)
         # q, k and v from ONE weight stack where the block's module states
-        # how (module docstring), built here once, outside both scans
-        blocks = getattr(model, "fuse_attention_projections",
-                         lambda blocks: blocks)(params["blocks"])
+        # how (module docstring)
+        runs = _plan_runs(plan, params["blocks"], getattr(
+            model, "fuse_attention_projections", None))
 
         def one_step(carry, _):
             k_pages, v_pages, k_scale, v_scale, toks, lens, key = carry
             key, sub = jax.random.split(key)
             pos = jnp.where(active, lens, 0)                    # [B]
             x = params["embedding"][toks[:, None]]              # [B,1,d]
-            sin, cos = rope_sin_cos(pos[:, None], cfg.head_dim,
-                                    theta=cfg.rope_theta)
+            rotary = model.rotary_tables(cfg, pos[:, None])
             # per-slot write target for this token
             pidx = jnp.take_along_axis(
                 table, (pos // page_size)[:, None], axis=1)[:, 0]
@@ -413,25 +538,32 @@ class PagedLLMEngine:
             pidx = jnp.where((pidx >= 0) & active, pidx, num_pages)
             ip = pos % page_size
 
-            def block(carry, xs):
+            def block(run, carry, xs):
                 x, kp, vp, ks, vs = carry
                 p, layer = xs
-                q, k, v = model.attention_projections(cfg, p, x, sin, cos)
+                q, k, v = model.attention_projections(
+                    cfg, p, x, *rotary[run.kind])
                 kp, vp, ks, vs = write_kv(
                     kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
                     quantized)
-                # each live slot's pages up to its length, read where
-                # they lie; the row just written is among them
+                # each live slot's pages up to its length (a sliding
+                # layer: the pages of its window), read where they lie;
+                # the row just written is among them
                 attn = paged_decode_attention(
-                    q[:, 0], kp, vp, ks, vs, layer, table, pos, active)
-                x = x + attn.reshape(b, 1, -1) @ p["wo"]
+                    q[:, 0], kp, vp, ks, vs, layer, table, pos, active,
+                    window=run.window)
+                x = model.attention_output(cfg, p, x, attn)
                 x, stats = model.feed_forward(cfg, p, x,
                                               valid=active[:, None])
                 return (x, kp, vp, ks, vs), stats
 
-            (x, k_pages, v_pages, k_scale, v_scale), stats = jax.lax.scan(
-                block, (x, k_pages, v_pages, k_scale, v_scale),
-                (blocks, layers))
+            carry = (x, k_pages, v_pages, k_scale, v_scale)
+            stats = []
+            for run, xs in zip(plan, runs):
+                carry, run_stats = jax.lax.scan(partial(block, run), carry,
+                                                xs)
+                stats.append(run_stats)
+            x, k_pages, v_pages, k_scale, v_scale = carry
             x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)[:, 0]
             head = model.lm_head_weights(cfg, params)
             logits = jnp.einsum("bd,dv->bv", x, head,
@@ -439,7 +571,7 @@ class PagedLLMEngine:
             nxt = select_tokens(logits, temps, sub)
             lens = jnp.where(active, lens + 1, lens)
             return (k_pages, v_pages, k_scale, v_scale, nxt, lens,
-                    key), (nxt, stats)
+                    key), (nxt, _over_layers(stats))
 
         (k_pages, v_pages, k_scale, v_scale, _, lens, _), (toks, stats) = \
             jax.lax.scan(
@@ -471,20 +603,18 @@ class PagedLLMEngine:
         written into the pages first, then attention runs over the
         row's whole gathered page window, so suffix queries see the
         reused prefix KV exactly as the original prompt computed it.
-        table_rows: [n, max_pages_per_seq]. The layer scan carries the
-        activations and the stacked pools, as decode's does: the
-        program holds one pool, the donated one."""
+        table_rows: [n, max_pages_per_seq]. The layer scans (one a run
+        of the model's layer plan) carry the activations and the stacked
+        pools, as decode's do: the program holds one pool, the donated
+        one."""
         model = _model_module(cfg)
         num_pages = k_pages.shape[1]
         n, t = tokens.shape
-        mp = table_rows.shape[1]
-        s = mp * page_size
-        scale = cfg.head_dim ** -0.5
+        plan = model.layer_plan(cfg)
         x = params["embedding"][tokens]
         rel = jnp.arange(t, dtype=jnp.int32)
         positions = starts[:, None] + rel[None, :]            # [n, T]
-        sin, cos = rope_sin_cos(positions, cfg.head_dim,
-                                theta=cfg.rope_theta)
+        rotary = model.rotary_tables(cfg, positions)
         pidx_all = jnp.take_along_axis(
             table_rows, positions // page_size, axis=1)       # [n, T]
         valid = rel[None, :] < slens[:, None]                 # [n, T]
@@ -492,27 +622,24 @@ class PagedLLMEngine:
                              num_pages)
         ip_all = positions % page_size
 
-        def block(carry, xs):
+        def block(run, carry, xs):
             x, kp, vp, ks, vs = carry
             p, layer = xs
-            q, k, v = model.attention_projections(cfg, p, x, sin, cos)
+            q, k, v = model.attention_projections(cfg, p, x,
+                                                  *rotary[run.kind])
             kp, vp, ks, vs = write_kv(
                 kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, quantized)
-            kg, vg = gather_kv_window(kp, vp, ks, vs, layer, table_rows)
-            # gather the whole window AFTER the suffix writes: queries
-            # attend over cached prefix + their own fresh KV; positions
-            # beyond start+i are masked causally, stale page contents
-            # beyond the prompt never influence the result
-            kg = kg.reshape(n, s, cfg.n_kv_heads, cfg.head_dim)
-            vg = vg.reshape(n, s, cfg.n_kv_heads, cfg.head_dim)
-            attn = cached_attention(q, kg, vg, starts, scale=scale)
-            x = x + attn.reshape(n, t, -1) @ p["wo"]
+            attn = _prefill_attention(
+                q, kp, vp, ks, vs, layer, table_rows, starts,
+                window=run.window, page_size=page_size)
+            x = model.attention_output(cfg, p, x, attn)
             x, _ = model.feed_forward(cfg, p, x, valid=valid)
             return (x, kp, vp, ks, vs), None
 
-        (x, k_pages, v_pages, k_scale, v_scale), _ = jax.lax.scan(
-            block, (x, k_pages, v_pages, k_scale, v_scale),
-            (params["blocks"], jnp.arange(k_pages.shape[0])))
+        carry = (x, k_pages, v_pages, k_scale, v_scale)
+        for run, xs in zip(plan, _plan_runs(plan, params["blocks"])):
+            carry, _ = jax.lax.scan(partial(block, run), carry, xs)
+        x, k_pages, v_pages, k_scale, v_scale = carry
         x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
         x = jnp.take_along_axis(
             x, (slens - 1)[:, None, None], axis=1).squeeze(1)
@@ -1251,9 +1378,17 @@ class PagedLLMEngine:
             now = time.monotonic()
             stream_seq = next(self._stream_seq)
             if ph:
+                # KV rows the chunk's first step reads in a layer, over
+                # the live slots, from the host's own lengths: all of a
+                # slot's rows in a full layer, its window's in a sliding
+                rows = self._lengths[active_idx].astype(np.int64) + 1
                 ph.set(pages=pb, seq=stream_seq, chunk=chunk,
                        live=len(active_idx), slots=self.max_batch,
-                       drain=drain, reupload=reupload)
+                       drain=drain, reupload=reupload,
+                       kv_rows_full=int(rows.sum()))
+                if self._window is not None:
+                    ph.set(kv_rows_window=int(
+                        np.minimum(rows, self._window).sum()))
             self._last_dev = new_last
             dev["lens"] = lens   # stays on device for the chained chunk
             # start the token matrix's device->host copy NOW: it overlaps

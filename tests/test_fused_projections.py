@@ -102,8 +102,8 @@ def _train_step_text(strategy, fused_loss):
         state, tokens).as_text()
 
 
-def _engine_text(model, cfg, program, kv_dtype):
-    dims = (4, 4) if program == "decode" else (2, 16, 4)
+def _engine_text(model, cfg, program, kv_dtype, dims=None):
+    dims = dims or ((4, 4) if program == "decode" else (2, 16, 4))
     return _lower_engine_program(
         jax.devices("cpu")[0], model, cfg, 16, program, dims, slots=4,
         page=8, kv_dtype=kv_dtype).as_text()
@@ -166,3 +166,38 @@ def test_the_llama_decode_program_is_the_one_that_changed():
     fused stack does lower to another text than the parent's."""
     text = _engine_text(llama, llama.llama_tiny(), "decode", "bf16")
     assert _digest(text) != _PARENT_TEXT["llama-decode-bf16"]
+
+
+# The same digests of the engine's programs on the commit before the layer
+# plan (9544057: PR 32), where the two programs scanned ONE stack: the
+# Llama decode program with its fused stack, and for both models the
+# widest prefill the serving cells warm (two cold prompts of 2048 tokens
+# over 16 pages), which is where ``paged_llm.SCORES_MAX_BYTES`` draws its
+# line: at 32 heads it still goes whole.
+_BEFORE_THE_LAYER_PLAN = {
+    "llama-decode-bf16": "fdd46263ed8b0999",
+    "llama-decode-int8": "1cf7fdecece96f9a",
+    "llama-prefill-wide": "7e741f0e9ff873fa",
+    "olmoe-prefill-wide": "afa0c5c62c6296a9",
+}
+_ONE_RUN = {
+    "llama-decode-bf16": lambda: _engine_text(
+        llama, llama.llama_tiny(), "decode", "bf16"),
+    "llama-decode-int8": lambda: _engine_text(
+        llama, llama.llama_tiny(), "decode", "int8"),
+    "llama-prefill-wide": lambda: _engine_text(
+        llama, llama.llama_tiny(), "prefill", "bf16", (2, 2048, 16)),
+    "olmoe-prefill-wide": lambda: _engine_text(
+        olmoe, olmoe.olmoe_tiny(), "prefill", "bf16", (2, 2048, 16)),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_ONE_RUN))
+def test_a_one_run_layer_plan_lowers_to_the_one_scan_it_was(program):
+    """A model that repeats one block states a plan of one run
+    (``llama.layer_plan``), and the engine's programs for it, which now
+    follow the plan, ask the module for its rotary tables and hand the
+    sublayer's end to ``attention_output``, are the programs they were:
+    with the fence above, every engine program of ``LlamaConfig`` and
+    ``OlmoeConfig``, byte for byte."""
+    assert _digest(_ONE_RUN[program]()) == _BEFORE_THE_LAYER_PLAN[program]

@@ -278,6 +278,14 @@ def _serving_model(name):
     """A serve cell's block module and configuration, by its name."""
     if name == "d12":
         return llama, llama.LlamaConfig(**_D12)
+    if name == "laguna-ep4-d5":
+        from ray_tpu.models import laguna
+
+        # serve-code-gen's: a leading layer and one period, 64 of each
+        # sparse layer's 256 experts, a quarter of the vocabulary
+        return laguna, dataclasses.replace(
+            laguna.laguna_s_2_1(), layer_types=(laguna._PERIOD * 2)[:5],
+            n_experts_held=64, vocab_size=25088)
     return olmoe, dataclasses.replace(olmoe.olmoe_1b_7b(), n_layers=10)
 
 
@@ -346,14 +354,18 @@ def test_olmoe_d10_engine_programs_compile_and_fit(v5e_2x2, program, dims,
 
 def _projection_widths(cfg):
     """The output widths a stack of attention projection weights can
-    have: q (and ``wo``'s input), k or v, and the three fused."""
-    q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    return sorted({q, kv, q + 2 * kv})
+    have: q (and ``wo``'s input), k or v, and the three fused; at each
+    number of query heads the model's layers have."""
+    kv = cfg.n_kv_heads * cfg.head_dim
+    heads = {cfg.n_heads, getattr(cfg, "n_heads_sliding", cfg.n_heads)}
+    return sorted({w for h in heads for w in (
+        h * cfg.head_dim, kv, (h * cfg.head_dim) + 2 * kv)})
 
 
 @pytest.mark.parametrize("model,pages,window,chunk", [
     ("d12", _D12_PAGES, 16, 16), ("d12", _D12_PAGES, 16, 8),
-    ("olmoe-d10", _MOE_PAGES, 8, 16), ("olmoe-d10", _MOE_PAGES, 8, 8)])
+    ("olmoe-d10", _MOE_PAGES, 8, 16), ("olmoe-d10", _MOE_PAGES, 8, 8),
+    ("laguna-ep4-d5", 2304, 32, 16), ("laguna-ep4-d5", 2304, 32, 8)])
 def test_decode_loops_move_no_projection_weight_stack(v5e_2x2, model, pages,
                                                       window, chunk):
     """A decode step reads each layer's projection weights where they lie
@@ -367,12 +379,24 @@ def test_decode_loops_move_no_projection_weight_stack(v5e_2x2, model, pages,
     PR 31). The fused stack (604 MB) cannot be parked: its matmul's
     fusion takes the whole stack and the layer index. OLMoE's block
     states no fused stack; its decode programs park ``wv`` once at the
-    entry and move nothing inside a loop: a fence."""
+    entry and move nothing inside a loop: a fence. Laguna's runs of one
+    and of three layers hold q | k | v as one stack from the start (three
+    layers' ``wk`` alone would be 18.9 MB), and its decode programs (64
+    slots, the 32-page table) move none of them; the per-head gate's
+    ``wg`` (0.3-0.4 MB a layer) is fetched a layer ahead, which is no
+    projection stack and costs nothing to see."""
     module, cfg = _serving_model(model)
+    slots, widths = {}, _projection_widths(cfg)
+    if model.startswith("laguna"):
+        # its blocks hold no k or v stack of their own: what is that wide
+        # (bf16[1, 3072, 1024]) is the last layer's shared expert, 6.3 MB
+        # fetched a layer ahead and read once
+        slots = {"slots": 64}
+        widths.remove(cfg.n_kv_heads * cfg.head_dim)
     compiled = _compile_engine_program(v5e_2x2[0], module, cfg, pages,
-                                       "decode", (chunk, window))
+                                       "decode", (chunk, window), **slots)
     moves = _stack_moves_in_loops(compiled.as_text(), cfg.n_layers,
-                                  cfg.d_model, _projection_widths(cfg))
+                                  cfg.d_model, widths)
     assert not moves, "\n".join(moves)
     # the fused stack is built once a run, at the entry, in place of the
     # three transposed entry copies: the same bytes of temporaries
