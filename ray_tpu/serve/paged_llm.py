@@ -33,6 +33,13 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   a platform other than the TPU the same call is the plain formulation
   (gather the window, ``cached_attention``), chosen where the program
   is lowered; nothing sets it.
+- Prefill attends over the pages where they lie too, where that pays:
+  in a full-attention layer over bf16 pages whose float32 scores would
+  pass 256 MiB, the suffix queries go through a second Pallas kernel
+  (``ops/paged_prefill_attention.py``), scores and softmax state in
+  VMEM. The rule reads the traced shapes (``kernel_engages``); under it,
+  in a sliding layer, over int8 pages and off the TPU a prefill gathers
+  its rows' page windows and calls ``cached_attention`` as it did.
 - Allocation is reserve-on-admit (pages for prompt + budget + one
   chained-overshoot page, released at retirement): admission applies
   backpressure when the pool is exhausted, and a mid-flight sequence
@@ -58,8 +65,8 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   then sliding x 3, full x 1, ... for Laguna), the runs in order over
   the pools' layers; a layer scatters its new
   rows at [layer, page, offset] (``write_kv``) and reads its pages at
-  [layer, table]: decode in the kernel, prefill by gathering its window
-  (``gather_kv_window``; both state the format, in
+  [layer, table]: decode in its kernel, prefill in its own or by
+  gathering its window (``gather_kv_window``; all state the format, in
   ``ops/paged_attention.py``). Scanning OVER the pools
   instead hands each layer a slice: XLA then copies every layer's K
   and V pool out and back, every layer of every step, and the prefill
@@ -96,10 +103,10 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   SwiGLU, or routed experts, held whole or as this chip's share) and the
   output head (``lm_head_weights``). What is the
   ENGINE's stays here, once for every model: the page write, decode's
-  attention over the pages (the kernel), prefill's gather and
-  ``cached_attention`` (whole, or over blocks of queries where the
-  scores would not fit: ``_prefill_attention``), the scans over the
-  plan's runs, sampling, the chunk loop. A
+  attention over the pages (the kernel), prefill's
+  (``paged_prefill_attention``: its kernel, or the gather and
+  ``cached_attention``, whole or over blocks of queries), the scans over
+  the plan's runs, sampling, the chunk loop. A
   feed-forward may hand back statistics of its call (scalars; a dense
   one has none): the decode program averages them over the chunk's
   layer-steps, and they go on the chunk's ``engine.emit`` span.
@@ -126,12 +133,12 @@ import numpy as np
 
 from ray_tpu.models import llama
 from ray_tpu.models.decoding import select_tokens
-from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
-                                         gather_kv_window, page_hashes,
-                                         visible_pages, write_kv)
+                                         page_hashes, write_kv)
 from ray_tpu.ops.paged_decode_attention import paged_decode_attention
+from ray_tpu.ops.paged_prefill_attention import (kernel_engages,
+                                                 paged_prefill_attention)
 from ray_tpu.serve.llm import _STAGES, Request, _named_jit, _serve_hist
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import tracing as _tracing
@@ -165,79 +172,6 @@ def _model_module(cfg):
     raise TypeError(
         f"unsupported model config {type(cfg).__name__}; the paged engine "
         "serves LlamaConfig, OlmoeConfig and LagunaConfig")
-
-
-# Float32 scores of one ``cached_attention`` call that a prefill program
-# may hold, [n, heads, T, S] (the call keeps about twice that beside
-# them): past it the program goes over its queries in blocks whose scores
-# are a quarter of it. From the traced shapes, as ``ops.moe`` picks its
-# formulation; 1 GiB is two cold prompts of 2048 tokens at 32 heads, the
-# largest prefill of the cells that serve Mistral-7B's widths, so every
-# program that ran whole before runs whole.
-SCORES_MAX_BYTES = 1 << 30
-
-
-def _query_block(n: int, t: int, heads: int, keys: int, window) -> int:
-    """How many of a prefill's ``t`` query positions a row attends with at
-    once (``t``: all of them). A sliding layer goes window by window: a
-    block of ``window`` queries sees two windows of keys, whatever ``t``.
-    A full layer goes whole while its scores fit ``SCORES_MAX_BYTES``, and
-    past that in blocks whose scores are a quarter of it."""
-    def scores(block):
-        return 4 * n * heads * block * keys
-
-    if window is not None:
-        block = _bucket(window, minimum=1)
-    elif scores(t) <= SCORES_MAX_BYTES:
-        return t
-    else:
-        block = t
-        while block > 16 and scores(block) > SCORES_MAX_BYTES // 4:
-            block //= 2
-    return block if block < t and t % block == 0 else t
-
-
-def _prefill_attention(q, kp, vp, ks, vs, layer, table_rows, starts, *,
-                       window, page_size):
-    """A prefill's attention for one layer: queries ``q`` [n, T, heads,
-    hd] at positions ``starts + i`` over their rows' pages of ``layer``,
-    whose new rows are written. One gather of the rows' whole tables and
-    one ``cached_attention`` where that fits; past ``SCORES_MAX_BYTES``
-    the same call on blocks of queries, one after another (one program,
-    one dispatch: the host sees nothing of it). A sliding layer goes in
-    blocks of its window, and for each gathers only the pages that the
-    block's queries can see."""
-    n, t, heads, hd = q.shape
-    mp = table_rows.shape[1]
-    nkv = kp.shape[3]
-    block = _query_block(n, t, heads, mp * page_size, window)
-    # the pages that hold the keys of ``block`` queries' windows
-    seen = (mp if window is None
-            else -(-(block + window - 2) // page_size) + 1)
-
-    def attend(q, first):
-        """``q`` [n, block, heads, hd], the first of them at ``first``."""
-        rows, where = table_rows, {}
-        if window is not None:
-            rows, key_start = visible_pages(table_rows, first - window + 1,
-                                            seen, page_size)
-            where = {"window": window, "key_start": key_start}
-        # gathered AFTER the suffix writes: queries attend over cached
-        # prefix + their own fresh KV; positions beyond start+i are
-        # masked causally, stale page contents beyond the prompt never
-        # influence the result
-        kg, vg = gather_kv_window(kp, vp, ks, vs, layer, rows)
-        return cached_attention(q, kg.reshape(n, -1, nkv, hd),
-                                vg.reshape(n, -1, nkv, hd), first,
-                                scale=hd ** -0.5, **where)
-
-    if block == t:
-        return attend(q, starts)
-    firsts = starts[None, :] + block * jnp.arange(
-        t // block, dtype=jnp.int32)[:, None]                  # [blocks, n]
-    qb = jnp.moveaxis(q.reshape(n, t // block, block, heads, hd), 1, 0)
-    out = jax.lax.map(lambda xs: attend(*xs), (qb, firsts))
-    return jnp.moveaxis(out, 0, 1).reshape(n, t, heads, hd)
 
 
 def _plan_runs(plan, blocks, fuse=None) -> list:
@@ -435,6 +369,13 @@ class PagedLLMEngine:
         self._window = next(
             (run.window for run in _model_module(cfg).layer_plan(cfg)
              if run.window is not None), None)
+        # prefill dispatches, and those whose program holds the prefill
+        # attention kernel: a model with full-attention layers, lowered
+        # for a TPU (``_dispatch_prefill``)
+        self._kernel_backend = jax.default_backend() == "tpu" and any(
+            run.window is None for run in _model_module(cfg).layer_plan(cfg))
+        self.prefill_dispatches = 0
+        self.prefill_kernel_dispatches = 0
         # per dispatched decode chunk, the feed-forward's statistics on
         # the device until the chunk is emitted (_sync_chunk)
         self._chunk_stats: deque = deque()
@@ -601,8 +542,8 @@ class PagedLLMEngine:
         tokens past each row's cached prefix (``starts`` absolute
         offsets; 0 = no prefix reuse, the plain prefill). Suffix KV is
         written into the pages first, then attention runs over the
-        row's whole gathered page window, so suffix queries see the
-        reused prefix KV exactly as the original prompt computed it.
+        row's pages (``paged_prefill_attention``), so suffix queries see
+        the reused prefix KV exactly as the original prompt computed it.
         table_rows: [n, max_pages_per_seq]. The layer scans (one a run
         of the model's layer plan) carry the activations and the stacked
         pools, as decode's do: the program holds one pool, the donated
@@ -629,9 +570,9 @@ class PagedLLMEngine:
                                                   *rotary[run.kind])
             kp, vp, ks, vs = write_kv(
                 kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, quantized)
-            attn = _prefill_attention(
-                q, kp, vp, ks, vs, layer, table_rows, starts,
-                window=run.window, page_size=page_size)
+            attn = paged_prefill_attention(
+                q, kp, vp, ks, vs, layer, table_rows, starts, slens,
+                window=run.window)
             x = model.attention_output(cfg, p, x, attn)
             x, _ = model.feed_forward(cfg, p, x, valid=valid)
             return (x, kp, vp, ks, vs), None
@@ -901,6 +842,13 @@ class PagedLLMEngine:
                              np.int32)
         slens_np = np.array([it[2] for it in part], np.int32) - starts_np
         wp = self._window_pages(int((starts_np + slens_np).max()))
+        # whether this program's full layers attend in the prefill kernel
+        # (the rule the program itself was traced by, on a TPU alone)
+        kernel = self._kernel_backend and kernel_engages(
+            (len(part), bucket, self.cfg.n_heads, self.cfg.head_dim),
+            self._k_pages, wp, None)
+        self.prefill_dispatches += 1
+        self.prefill_kernel_dispatches += int(kernel)
         if ph:
             # what the prefix cache gave this dispatch, counted as its
             # lookups were (PrefixCache.acquire): the full pages before
@@ -910,7 +858,8 @@ class PagedLLMEngine:
                        if self._prefix_enabled else 0)
             ph.set(window_pages=wp, new_tokens=int(slens_np.sum()),
                    cached_tokens=cached,
-                   missed_pages=lookups - cached // page)
+                   missed_pages=lookups - cached // page,
+                   attn_kernel=int(kernel))
         prefill = self._prefill_paged(wp)
         slens = jnp.asarray(slens_np)
         rows = jnp.asarray(np.stack(
@@ -1145,44 +1094,40 @@ class PagedLLMEngine:
                           parent=parent, kind="serve")
             t += d
 
-    def _admission_window(self) -> bool:
+    def _admission_window(self) -> "Request | None":
         """Continuous admission: between the previous chunk's sync and
         the NEXT chunk's dispatch, block on the waiting queue for up to
-        a fraction of the EMA chunk period and prefill arrivals
-        immediately. A prefill dispatched here queues behind only the
+        a fraction of the EMA chunk period; the loop prefills an arrival
+        immediately (``_iteration``) and asks again. A prefill
+        dispatched then queues behind only the
         ONE in-flight chunk — without the window, a request arriving
         just after an emit waits out the whole double-buffered pipeline
         (~2.5 chunks of queue_wait, the dominant TTFT term in
         BENCH_r07). The wait costs no device time: the in-flight chunk
         computes while this thread sleeps, and the remaining period
-        fraction covers the next dispatch. Skipped until the loop has a
+        fraction covers the next dispatch. Closed until the loop has a
         period estimate, when no slot is free, or under page
-        backpressure (a request the pool can't place would spin)."""
+        backpressure (a request the pool can't place would spin).
+        Returns the request that arrived, or None once the window is
+        closed or has run out."""
         if (not self._continuous_admission or self._chunk_period is None
-                or self._sync_t is None):
-            return False
-        deadline = self._sync_t + self._window_frac * self._chunk_period
-        admitted = False
-        while not self._stop.is_set():
-            if self._admission_blocked or \
-                    not any(r is None for r in self._active):
-                break
-            timeout = deadline - time.monotonic()
-            if timeout <= 0:
-                break
-            with _tracing.phase("engine.wait_arrivals", kind="serve",
-                                attrs={"what": "window"}) as ph:
-                try:
-                    req = self._waiting.get(timeout=timeout)
-                except queue.Empty:
-                    req = None
-                if ph:
-                    ph.set(arrivals=int(req is not None))
-            if req is None:
-                break
-            self._admit(first=req)
-            admitted = True
-        return admitted
+                or self._sync_t is None or self._stop.is_set()
+                or self._admission_blocked
+                or not any(r is None for r in self._active)):
+            return None
+        timeout = (self._sync_t + self._window_frac * self._chunk_period
+                   - time.monotonic())
+        if timeout <= 0:
+            return None
+        with _tracing.phase("engine.wait_arrivals", kind="serve",
+                            attrs={"what": "window"}) as ph:
+            try:
+                req = self._waiting.get(timeout=timeout)
+            except queue.Empty:
+                req = None
+            if ph:
+                ph.set(arrivals=int(req is not None))
+        return req
 
     # -- emission and retirement -------------------------------------------
 
@@ -1494,31 +1439,42 @@ class PagedLLMEngine:
                 pending = self._iteration(pending)
 
     def _iteration(self, pending):
-        """One pass of the loop; returns the chunk left in flight."""
-        self._admit()
-        active_idx = [i for i, r in enumerate(self._active)
-                      if r is not None]
-        if not active_idx:
-            self._sync_t = None   # pipeline drains: period resets
-            if pending is not None:
-                toks, idxs, gens, _, seq = pending
-                self._sync_chunk(toks, idxs, gens, seq)
-            elif self._pending_firsts:
-                # every active request is brand-new and nothing is
-                # in flight (e.g. max_new_tokens=1 bursts): block
-                # for the outstanding firsts
-                self._drain_firsts(completed_seq=self._dispatch_seq)
-            else:
-                self._wait_idle()
-            return None
-        if pending is None:
-            return self._dispatch_decode(active_idx)
-        # continuous admission: requests arriving while `pending`
-        # computes are prefilled NOW, before the next chunk is
-        # dispatched behind them
-        if self._admission_window():
+        """One pass of the loop; returns the chunk left in flight.
+
+        Requests are admitted from ONE place: at the top of the pass
+        (``first`` None: whatever waits), then, while a chunk is in
+        flight, each arrival inside the admission window, prefilled NOW,
+        before the next chunk is dispatched behind it. One place,
+        because a prefill program that holds a Pallas kernel carries its
+        operations' source locations, ten frames of the stack that
+        traced it, in its compile-cache key: admitted from two places,
+        a program took its key from whichever met it first, and a later
+        process that met it the other way compiled it again (v5e: one
+        program of ten in every ``serve-chat`` run)."""
+        first = None
+        while True:
+            self._admit(first)
             active_idx = [i for i, r in enumerate(self._active)
                           if r is not None]
+            if first is None:
+                if not active_idx:
+                    self._sync_t = None   # pipeline drains: period resets
+                    if pending is not None:
+                        toks, idxs, gens, _, seq = pending
+                        self._sync_chunk(toks, idxs, gens, seq)
+                    elif self._pending_firsts:
+                        # every active request is brand-new and nothing
+                        # is in flight (e.g. max_new_tokens=1 bursts):
+                        # block for the outstanding firsts
+                        self._drain_firsts(completed_seq=self._dispatch_seq)
+                    else:
+                        self._wait_idle()
+                    return None
+                if pending is None:
+                    return self._dispatch_decode(active_idx)
+            first = self._admission_window()
+            if first is None:
+                break
         nxt = self._dispatch_decode(active_idx)
         toks_prev, idx_prev, gens_prev, _, _ = pending
         # EVERY pending prefill was dispatched before nxt: block for
@@ -1546,6 +1502,8 @@ class PagedLLMEngine:
             "waiting": self._waiting.qsize(),
             "total_generated": self.total_generated,
             "total_finished": self.total_finished,
+            "prefill_dispatches": self.prefill_dispatches,
+            "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
             "mean_ttft_s": float(np.mean(self.ttfts)) if self.ttfts else None,
             "kv_pages_total": self.num_pages,
             "kv_pages_free": len(self._alloc.free),

@@ -129,7 +129,9 @@ def test_every_dispatch_has_counts_and_one_device_run(profiled):
     assert sum(s["attrs"]["group"] for s in prefills) == len(reqs)
     for s in prefills:
         assert {"seq", "group", "bucket", "window_pages", "new_tokens",
-                "cached_tokens", "missed_pages"} <= set(s["attrs"])
+                "cached_tokens", "missed_pages",
+                "attn_kernel"} <= set(s["attrs"])
+        assert s["attrs"]["attn_kernel"] == 0       # lowered for the CPU
     for s in decodes:
         a = s["attrs"]
         assert {"seq", "chunk", "live", "slots", "pages", "drain",
@@ -186,6 +188,52 @@ def test_prefix_miss_pages_are_the_pages_past_the_first_miss(profiled):
     lookups = sum((len(r.prompt) - 1) // PAGE for r in reqs)
     assert cached + missed == lookups and cached >= 3 * len(reqs)
     assert missed > 0
+
+
+def test_prefill_dispatches_say_whether_their_program_holds_the_kernel(
+        tiny, monkeypatch):
+    """``attn_kernel`` on ``engine.dispatch_prefill`` is the rule the
+    program was traced by (``ops/paged_prefill_attention.py``:
+    ``kernel_engages``) applied to the host's own shapes, on a TPU
+    backend alone; ``stats()`` counts the dispatches and those with it.
+    Off a TPU every dispatch reads 0 (above). Here the engine is told it
+    is on one, and a rule that the tiny shapes reach stands in for the
+    256 MiB of scores: buckets of 64 tokens engage, shorter ones do not."""
+    from ray_tpu.serve import paged_llm
+
+    eng = make_engine(tiny)
+    assert not eng._kernel_backend                  # the CPU's
+    eng._kernel_backend = True
+    seen = []
+
+    def rule(q_shape, pools, table_width, window):
+        seen.append((q_shape, pools.shape, table_width, window))
+        return q_shape[1] >= 64
+
+    monkeypatch.setattr(paged_llm, "kernel_engages", rule)
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        eng.start()
+        rng = np.random.default_rng(2)
+        for n in (50, 9, 40, 70):       # distinct prompts: no page reused
+            assert len(list(eng.submit(rng.integers(1, 500, n),
+                                       max_new_tokens=4).tokens())) == 4
+        eng.stop()
+        spans = tracing.recorded_spans("engine.dispatch_prefill")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    got = [(s["attrs"]["bucket"], s["attrs"]["attn_kernel"]) for s in spans]
+    assert got == [(64, 1), (16, 0), (64, 1), (128, 1)]
+    stats = eng.stats()
+    assert stats["prefill_dispatches"] == 4
+    assert stats["prefill_kernel_dispatches"] == 3
+    # the rule saw the dispatch's own shapes: rows, bucket, the model's
+    # full-layer heads and head size; the pool; the window's pages
+    cfg = tiny[0]
+    assert seen[0] == ((1, 64, cfg.n_heads, cfg.head_dim),
+                       eng._k_pages.shape, 4, None)
 
 
 def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):
@@ -289,6 +337,36 @@ def test_engine_programs_carry_their_static_facts_in_their_names(tiny):
     assert "@jit_paged_prefill_w2" in text
     assert "@jit_scatter_firsts" in eng._scatter_fn.lower(
         i32((4,)), i32((2,)), i32((2,))).as_text()
+
+
+def test_the_prefill_kernel_is_named_in_a_program_lowered_for_the_tpu():
+    """A prefill program over the rule (two rows of 2048 tokens over 16
+    pages at 16 heads of 128: 512 MiB of scores) holds the kernel under its
+    name where it is lowered for the TPU and nothing of it where it is
+    lowered for the CPU; under the rule (64 tokens) neither does."""
+    cfg = llama.LlamaConfig(vocab_size=64, d_model=128, n_layers=2,
+                            n_heads=16, n_kv_heads=2, head_dim=128,
+                            d_ff=256, remat="none")
+    params = jax.eval_shape(partial(llama.init_params, cfg),
+                            jax.random.key(0))
+    pool = jax.ShapeDtypeStruct((2, 40, 128, 2, 128), jnp.bfloat16)
+    scale = jax.ShapeDtypeStruct((2, 1, 1, 1), jnp.float32)
+    fn = jax.jit(partial(PagedLLMEngine._paged_prefill_impl, cfg,
+                         page_size=128, quantized=False))
+
+    def kernels(tokens, platform):
+        i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+        text = fn.trace(
+            params, pool, pool, scale, scale, i32((2, 16)),
+            i32((2, tokens)), i32((2,)), i32((2,)),
+            jax.ShapeDtypeStruct((2,), jnp.float32),
+            jax.eval_shape(lambda: jax.random.key(0))).lower(
+                lowering_platforms=(platform,)).as_text()
+        return re.findall(r'kernel_name = "(\w+)"', text)
+
+    assert kernels(2048, "tpu") == ["paged_prefill_attn"]
+    assert kernels(2048, "cpu") == []
+    assert kernels(64, "tpu") == []
 
 
 def test_flash_kernels_are_named_in_the_lowered_program():

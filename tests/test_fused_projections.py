@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import llama, olmoe
+from ray_tpu.ops.paged_prefill_attention import kernel_engages
 from ray_tpu.ops.rope import rope_sin_cos
 from ray_tpu.parallel.mesh import create_mesh
 from ray_tpu.serve.paged_llm import PagedLLMEngine
@@ -172,7 +173,8 @@ def test_the_llama_decode_program_is_the_one_that_changed():
 # plan (9544057: PR 32), where the two programs scanned ONE stack: the
 # Llama decode program with its fused stack, and for both models the
 # widest prefill the serving cells warm (two cold prompts of 2048 tokens
-# over 16 pages), which is where ``paged_llm.SCORES_MAX_BYTES`` draws its
+# over 16 pages), which is where ``SCORES_MAX_BYTES`` (the plain prefill
+# attention's, ``ops/paged_prefill_attention.py`` since PR 34) draws its
 # line: at 32 heads it still goes whole.
 _BEFORE_THE_LAYER_PLAN = {
     "llama-decode-bf16": "fdd46263ed8b0999",
@@ -201,3 +203,33 @@ def test_a_one_run_layer_plan_lowers_to_the_one_scan_it_was(program):
     with the fence above, every engine program of ``LlamaConfig`` and
     ``OlmoeConfig``, byte for byte."""
     assert _digest(_ONE_RUN[program]()) == _BEFORE_THE_LAYER_PLAN[program]
+
+
+# PR 34 put a Pallas kernel into the prefill program's full-attention
+# layers, by a rule on the traced shapes, and re-pinned NO digest above:
+# every prefill program pinned here is under the rule (the tiny configs'
+# head size of 32 is no whole lane, their scores are 8 MiB at most, and
+# the int8 programs' pools are not bf16), so each calls the plain path
+# directly, before any ``platform_dependent``, and lowers to the parent's
+# text although the attention moved to ``ops/paged_prefill_attention.py``
+# and takes the rows' valid lengths. The decode programs and the train
+# step import none of it. What a program OVER the rule lowers to is held by
+# ``tests/test_engine_tracing.py`` (the kernel under its name where the
+# program is lowered for the TPU, nothing of it for the CPU) and
+# ``tests/test_tpu_compile.py`` (compiled at the cells' widths).
+@pytest.mark.parametrize("model,kv_dtype,dims", [
+    (llama, "bf16", (2, 16, 4)), (llama, "int8", (2, 16, 4)),
+    (olmoe, "bf16", (2, 16, 4)), (olmoe, "int8", (2, 16, 4)),
+    (llama, "bf16", (2, 2048, 16)), (olmoe, "bf16", (2, 2048, 16))],
+    ids=["llama-prefill-bf16", "llama-prefill-int8", "olmoe-prefill-bf16",
+         "olmoe-prefill-int8", "llama-prefill-wide", "olmoe-prefill-wide"])
+def test_every_pinned_prefill_program_is_under_the_kernels_rule(model,
+                                                                kv_dtype,
+                                                                dims):
+    cfg = llama.llama_tiny() if model is llama else olmoe.olmoe_tiny()
+    n, tokens, pages = dims
+    pool = jax.ShapeDtypeStruct(
+        (cfg.n_layers, 16, 8, cfg.n_kv_heads, cfg.head_dim),
+        jnp.int8 if kv_dtype == "int8" else jnp.bfloat16)
+    assert not kernel_engages((n, tokens, cfg.n_heads, cfg.head_dim), pool,
+                              pages, None)

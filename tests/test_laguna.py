@@ -22,6 +22,7 @@ from benchmark.families import laguna as family
 from ray_tpu.models import laguna
 from ray_tpu.ops import moe
 from ray_tpu.ops import paged_decode_attention as pda
+from ray_tpu.ops import paged_prefill_attention as ppa
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.paged_attention import quantize_kv
 from ray_tpu.serve import paged_llm
@@ -305,7 +306,7 @@ def test_cached_attention_takes_a_window_and_a_key_start():
 
 
 def test_query_blocks_are_chosen_from_the_shapes():
-    block = paged_llm._query_block
+    block = ppa.query_block
     # every prefill the cells at Mistral-7B's widths warm goes whole
     assert block(2, 2048, 32, 2048, None) == 2048
     assert block(1, 2048, 32, 2048, None) == 2048
@@ -314,7 +315,7 @@ def test_query_blocks_are_chosen_from_the_shapes():
     assert block(1, 2048, 48, 2048, None) == 2048
     assert block(2, 2048, 48, 2048, None) == 256
     assert block(1, 4096, 48, 4096, None) == 256
-    assert 4 * 1 * 48 * 256 * 4096 <= paged_llm.SCORES_MAX_BYTES // 4
+    assert 4 * 1 * 48 * 256 * 4096 <= ppa.SCORES_MAX_BYTES // 4
     # a sliding layer goes window by window, whatever the size
     assert block(1, 4096, 72, 4096, 512) == 512
     assert block(2, 64, 18, 64, 16) == 16
@@ -341,12 +342,12 @@ def test_prefill_attention_in_blocks_is_the_one_call(monkeypatch, window):
         2, -1, nkv, hd)
     want = cached_attention(q, kg, vg, starts, scale=hd ** -0.5,
                             window=window)
-    monkeypatch.setattr(paged_llm, "SCORES_MAX_BYTES", 4 * 2 * heads * 16
+    monkeypatch.setattr(ppa, "SCORES_MAX_BYTES", 4 * 2 * heads * 16
                         * mp * PAGE * 4 - 4)
-    assert paged_llm._query_block(2, t, heads, mp * PAGE, window) == 16
-    got = paged_llm._prefill_attention(
+    assert ppa.query_block(2, t, heads, mp * PAGE, window) == 16
+    got = ppa.paged_prefill_attention_reference(
         q, kp, vp, scale1, scale1, jnp.int32(1), table, starts,
-        window=window, page_size=PAGE)
+        window=window)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
 

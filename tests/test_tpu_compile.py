@@ -128,9 +128,12 @@ def _pool_copy(layers, pages, nkv):
         r"(?:copy|dynamic-slice|dynamic-update-slice)\(")
 
 
-# the decode kernel's instruction, under its name (PR 30)
+# the decode kernel's instruction, under its name (PR 30), and the prefill
+# kernel's (PR 34)
 _DECODE_KERNEL = re.compile(
     r"%paged_decode_attn[.\d]* = \S+ custom-call\(.*tpu_custom_call")
+_PREFILL_KERNEL = re.compile(
+    r"%paged_prefill_attn[.\d]* = \S+ custom-call\(.*tpu_custom_call")
 
 
 def _window(slots, pages, nkv):
@@ -401,3 +404,119 @@ def test_decode_loops_move_no_projection_weight_stack(v5e_2x2, model, pages,
     # the fused stack is built once a run, at the entry, in place of the
     # three transposed entry copies: the same bytes of temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
+
+
+def _score_arrays(text, keys):
+    """The float32 arrays of attention-score shape in a program's text:
+    [rows, KV heads, heads a KV head, queries, ``keys``] of 4M elements
+    or more (the plain prefill attention's scores, whole or a block of
+    queries of them; an expert layer's [tokens x 8, 1024] is none)."""
+    found = set()
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        sizes = [int(d) for d in dims.split(",")]
+        elements = 1
+        for size in sizes:
+            elements *= size
+        if len(sizes) >= 4 and sizes[-1] == keys and elements >= 1 << 22:
+            found.add(dims)
+    return sorted(found)
+
+
+def _moved_shapes(text, cfg):
+    return {re.search(r"bf16\[[\d,]+\]", line).group()
+            for line in _stack_moves_in_loops(
+                text, cfg.n_layers, cfg.d_model, _projection_widths(cfg))}
+
+
+# model, KV pages, prefill (prompts, tokens, window pages), the kernel's
+# instructions in the program, GB of temporaries it may need
+_PREFILL_RULE = [
+    # serve-doc's two widest programs
+    ("d12", _D12_PAGES, (2, 2048, 16), 1, 0.5),
+    ("d12", _D12_PAGES, (1, 2048, 16), 1, 0.5),
+    # the reference check's 600 tokens (128 MiB of scores) and a prefix
+    # hit's suffix (32 MiB): under the rule
+    ("d12", _D12_PAGES, (1, 1024, 8), 0, 0.5),
+    ("d12", _D12_PAGES, (2, 64, 16), 0, 0.5),
+    # everything serve-moe-gen warms stays plain (128 MiB at most)
+    ("olmoe-d10", _MOE_PAGES, (2, 512, 8), 0, 0.8),
+    ("olmoe-d10", _MOE_PAGES, (2, 1024, 8), 0, 0.8),
+    # Laguna's two runs of full layers (48 heads; an instruction each):
+    # a cold file, the warm-up's 4,095 tokens, and a short suffix
+    ("laguna-ep4-d5", 2304, (1, 2048, 16), 2, 1.4),
+    ("laguna-ep4-d5", 2304, (1, 4096, 32), 2, 1.4),
+    ("laguna-ep4-d5", 2304, (1, 64, 32), 0, 1.4),
+]
+
+
+@pytest.mark.parametrize(
+    "model,pages,dims,kernels,temp_gb", _PREFILL_RULE,
+    ids=[f"{m}-{'x'.join(map(str, d))}" for m, _, d, _, _ in _PREFILL_RULE])
+def test_prefill_programs_hold_the_kernel_by_the_rule(v5e_2x2, model, pages,
+                                                      dims, kernels,
+                                                      temp_gb):
+    """Prefill attends over the pages where they lie (PR 34): a program
+    whose full layers' float32 scores would pass 256 MiB
+    (``ops/paged_prefill_attention.py:kernel_engages``) holds the kernel
+    under its name, one instruction a run of full layers, and no float32
+    array of score shape: the d12 program of two cold 2048-token prompts
+    held ``f32[2,8,4,2048,2048]``, 1 GiB, and about 2 GiB of temporaries
+    with it; Laguna's went over its queries in blocks of a quarter of a
+    GiB. A program under the rule holds no such instruction and is the
+    text it was (digests: tests/test_fused_projections.py). Laguna's
+    sliding layers keep their window-by-window path either way (their
+    blocks' scores end in 1,152 keys, not the window's pages). All fit,
+    and the kernel's need of the core's memory moves no weight stack: the
+    loops of a program with the kernel copy what they copied without it,
+    ONE layer of ``wq`` / ``wk`` / ``wv`` each (the per-layer transposes,
+    ROADMAP Queue 1 item 2), never a stack."""
+    module, cfg = _serving_model(model)
+    compiled = _compile_engine_program(v5e_2x2[0], module, cfg, pages,
+                                       "prefill", dims)
+    text = compiled.as_text()
+    assert len(_PREFILL_KERNEL.findall(text)) == kernels
+    assert not _DECODE_KERNEL.search(text)
+    if kernels:
+        assert not _score_arrays(text, dims[2] * 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    assert not _pool_copy(cfg.n_layers, pages, cfg.n_kv_heads).findall(text)
+    moved = _moved_shapes(text, cfg)
+    assert all(shape.startswith("bf16[1,") for shape in moved), moved
+    if model == "d12" and kernels:
+        plain = _compile_engine_program(v5e_2x2[0], module, cfg, pages,
+                                        "prefill", (2, 64, 16)).as_text()
+        assert moved == _moved_shapes(plain, cfg)
+
+
+@pytest.mark.parametrize("heads,kv_heads,rows,tokens,pages", [
+    (16, 16, 2, 2048, 16), (48, 8, 1, 4096, 32), (32, 8, 1, 2048, 16)],
+    ids=["mha-16x16", "gqa-48x8", "gqa-32x8"])
+def test_prefill_kernel_compiles_alone(v5e_2x2, heads, kv_heads, rows,
+                                       tokens, pages):
+    """The kernel by itself at the three head layouts the engine serves
+    (no whole program holds it at OLMoE's 16/16: that cell's contexts stop
+    at 1,024 tokens, under the rule)."""
+    from ray_tpu.ops.paged_prefill_attention import (
+        paged_prefill_attention_kernel)
+
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = shape((5, 600, 128, kv_heads, 128), jnp.bfloat16)
+    scale = shape((5, 1, 1, 1), jnp.float32)
+    compiled = jax.jit(paged_prefill_attention_kernel).lower(
+        shape((rows, tokens, heads, 128), jnp.bfloat16), pool, pool, scale,
+        scale, shape((), jnp.int32), shape((rows, pages), jnp.int32),
+        shape((rows,), jnp.int32), shape((rows,), jnp.int32)).compile()
+    assert len(_PREFILL_KERNEL.findall(compiled.as_text())) == 1
+
+
+def test_the_plain_prefill_path_does_hold_score_arrays(v5e_2x2):
+    """The fence above is not blind: the d12 program under the rule
+    (two 64-token suffixes over 2048 keys) holds its float32 scores."""
+    text = _compile_engine_program(
+        v5e_2x2[0], llama, llama.LlamaConfig(**_D12), _D12_PAGES, "prefill",
+        (2, 64, 16)).as_text()
+    assert _score_arrays(text, 2048) == ["2,8,4,64,2048"]
