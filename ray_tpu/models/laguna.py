@@ -48,7 +48,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.llama import LayerStack, fanin_init, lm_head_weights
+from ray_tpu.models.llama import (  # noqa: F401 - embed, head_logits:
+    LayerStack, embed, fanin_init,  # pieces of the block's module that
+    head_logits, lm_head_weights)   # are Llama's
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.moe import moe_ffn_dropless
 from ray_tpu.ops.norms import rms_norm

@@ -202,6 +202,11 @@ class LayerStack(NamedTuple):
     kind: str            # which of ``rotary_tables`` its attention takes
     window: int | None   # keys a query sees (a sliding layer); None: all
     layers: int
+    # what a sequence keeps per layer of the run BESIDE its KV pages, where
+    # the block holds a recurrent mixer (``models/falcon_h1.py:
+    # RecurrentState``: the arrays' shapes and dtypes, the scan's chunk);
+    # None: pages and nothing else
+    state: tuple | None = None
 
 
 def layer_plan(cfg) -> tuple:
@@ -347,6 +352,18 @@ def forward_hidden(cfg, params, tokens, *, positions=None,
 
     x, _ = lax.scan(scan_fn, x, params["blocks"])
     return rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
+
+
+def embed(cfg, params, tokens):
+    """Token ids -> the residual stream's start (a model that scales its
+    embedding states its own)."""
+    return params["embedding"][tokens]
+
+
+def head_logits(cfg, params, x):
+    """Normed last hidden states [b, d] -> float32 logits [b, vocab]."""
+    return jnp.einsum("bd,dv->bv", x, lm_head_weights(cfg, params),
+                      preferred_element_type=jnp.float32)
 
 
 def lm_head_weights(cfg, params):

@@ -31,7 +31,9 @@ from ray_tpu.models.llama import (  # noqa: F401 - parts of the block's module
     attention_output,   # the attention sublayer's end, the one run of
     layer_plan,         # identical layers, the one rotary table and the
     rotary_tables,      # head are Llama's
+    embed,
     fanin_init,
+    head_logits,
     lm_head_weights,
 )
 from ray_tpu.ops.attention import attention

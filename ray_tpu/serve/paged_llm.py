@@ -92,16 +92,40 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   the layout of a weight stack inside a loop
   (``tests/test_tpu_compile.py:_stack_moves_in_loops``).
 
-- What is the MODEL's comes from the model's module, resolved from the
-  config's class (``_model_module``): the layer plan (``layer_plan``:
-  the runs of identical layers, each with its kind and its window or
-  none), the rotary tables of each kind (``rotary_tables``, once a
-  step), the attention projections (``attention_projections``: norm,
-  q/k/v, whatever the block does to them, rotary), the sublayer's end
+- A sequence's state is its pages and, where the model's layer plan
+  has a RECURRENT run (a state-space mixer beside the attention:
+  ``LayerStack.state``), one more thing: per layer, the arrays the run
+  states (Falcon-H1: a float32 state [heads, width, state size] and the
+  last rows its convolution saw), which do not grow with the context.
+  They live in the SLOT: one array a kind [L, max_batch, ...], allocated
+  once, donated to both programs and got back in place, as the pools
+  are. A prefill runs the mixer over the padded prompt from the zero
+  state (``recurrent_mixer``: padding moves nothing) and INSTALLS each
+  row's state after its last token at [layer, slot], whole; a decode
+  step advances the active slots' states (``recurrent_step``) and leaves
+  the others' as they are. A slot that finishes mid-chunk decodes on, as
+  today, and its state is garbage afterwards: nothing reads it, for the
+  next tenant's prefill overwrites it before any decode step of that
+  tenant runs (the device runs dispatches in order). A prefix hit would
+  hand a request its prefix's pages WITHOUT the state at their end, so
+  over such a plan the prefix cache is off by rule (``prefix_cache=
+  True`` raises; reuse by state snapshot is ROADMAP Queue 2 B.5). For a
+  plan of pages alone nothing is allocated and the programs take no such
+  argument: they lower to the text they lowered to before.
+
+- What is the MODEL's comes from the model's module, the one its
+  config's class is defined in (``_model_module``, which checks it for
+  the pieces): the layer plan (``layer_plan``: the runs of identical
+  layers, each with its kind, its window or none, and what it keeps per
+  sequence beside pages, or nothing), the stream's start (``embed``),
+  the rotary tables of each kind (``rotary_tables``, once a step), the
+  attention projections (``attention_projections``: norm, q/k/v,
+  whatever the block does to them, rotary), the sublayer's end
   (``attention_output``: ``wo`` and the residual, a per-head gate where
-  the block has one), the feed-forward (``feed_forward``: a dense
-  SwiGLU, or routed experts, held whole or as this chip's share) and the
-  output head (``lm_head_weights``). What is the
+  the block has one), the recurrent mixer's two forms where the plan
+  has such a run, the feed-forward (``feed_forward``: a dense SwiGLU, or
+  routed experts, held whole or as this chip's share) and the output
+  head (``head_logits``). What is the
   ENGINE's stays here, once for every model: the page write, decode's
   attention over the pages (the kernel), prefill's
   (``paged_prefill_attention``: its kernel, or the gather and
@@ -121,6 +145,7 @@ from __future__ import annotations
 
 import itertools
 import queue
+import sys
 import threading
 import time
 import uuid
@@ -131,7 +156,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import llama
 from ray_tpu.models.decoding import select_tokens
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
@@ -156,22 +180,38 @@ def _bucket(n: int, minimum: int = 16) -> int:
     return b
 
 
+_PIECES = ("layer_plan", "rotary_tables", "embed", "attention_projections",
+           "attention_output", "feed_forward", "head_logits")
+_RECURRENT_PIECES = ("recurrent_mixer", "recurrent_step")
+
+
 def _model_module(cfg):
-    """The module that states ``cfg``'s block, by the config's class (as
-    ``JaxTrainer._resolve_family`` finds a trainer's)."""
-    if isinstance(cfg, llama.LlamaConfig):
-        return llama
-    from ray_tpu.models import olmoe
+    """The module that states ``cfg``'s block: the one its config class
+    is defined in, which must hold the block's pieces (module
+    docstring), and the mixer's two forms where its plan has a
+    recurrent run."""
+    model = sys.modules.get(type(cfg).__module__)
+    missing = [name for name in _PIECES if not hasattr(model, name)]
+    if not missing and _recurrent(model.layer_plan(cfg)) is not None:
+        missing = [name for name in _RECURRENT_PIECES
+                   if not hasattr(model, name)]
+    if missing:
+        raise TypeError(
+            f"the paged engine cannot serve {type(cfg).__name__}: its "
+            f"module {type(cfg).__module__} states no {', '.join(missing)}")
+    return model
 
-    if isinstance(cfg, olmoe.OlmoeConfig):
-        return olmoe
-    from ray_tpu.models import laguna
 
-    if isinstance(cfg, laguna.LagunaConfig):
-        return laguna
-    raise TypeError(
-        f"unsupported model config {type(cfg).__name__}; the paged engine "
-        "serves LlamaConfig, OlmoeConfig and LagunaConfig")
+def _recurrent(plan):
+    """What the plan's recurrent runs keep per sequence and layer beside
+    the KV pages (``LayerStack.state``), or None where no run holds a
+    recurrent mixer. One statement a plan: the slots' arrays span every
+    layer, as the pools do."""
+    states = {run.state for run in plan if run.state is not None}
+    if len(states) > 1:
+        raise ValueError("a layer plan's recurrent runs must keep the "
+                         f"same state, not {sorted(states)}")
+    return next(iter(states), None)
 
 
 def _plan_runs(plan, blocks, fuse=None) -> list:
@@ -201,6 +241,18 @@ def _over_layers(stats: list) -> dict:
     return out
 
 
+def _advance_state(step, state, layer, active):
+    """One decode step of a layer's recurrent mixer over the slots:
+    ``step(its state)`` -> (the term for the stream, the new state),
+    the state read from the stacked arrays at [layer] and written back
+    there, an inactive slot's left as it is."""
+    old = tuple(a[layer] for a in state)
+    mixed, new = step(old)
+    keep = [active.reshape(-1, *[1] * (o.ndim - 1)) for o in old]
+    return mixed, [a.at[layer].set(jnp.where(k, n.astype(o.dtype), o))
+                   for a, k, n, o in zip(state, keep, new, old)]
+
+
 class PagedLLMEngine:
     """Continuous batching over a paged KV pool (see module docstring).
 
@@ -223,8 +275,20 @@ class PagedLLMEngine:
         _cfg = get_config()
         if page_size is None:
             page_size = _cfg.serve_kv_page_size    # flag
+        plan = _model_module(cfg).layer_plan(cfg)
+        # what each slot keeps per layer beside its pages (None: nothing)
+        self._recurrent = _recurrent(plan)
+        if self._recurrent is not None and prefix_cache:
+            raise ValueError(
+                "prefix_cache=True over a layer plan with a recurrent run: "
+                "a prefix hit would hand a request its prefix's KV pages "
+                "without the recurrent state at their end, and its tokens "
+                "would be silently wrong (reuse by state snapshot: "
+                "ROADMAP Queue 2 B.5)")
         if prefix_cache is None:
-            prefix_cache = _cfg.serve_prefix_cache_enabled   # flag
+            # the flag, for a plan whose prefix is its pages alone
+            prefix_cache = (_cfg.serve_prefix_cache_enabled   # flag
+                            and self._recurrent is None)
         if kv_dtype not in ("bf16", "int8"):
             raise ValueError(f"kv_dtype must be 'bf16' or 'int8', "
                              f"got {kv_dtype!r}")
@@ -354,6 +418,19 @@ class PagedLLMEngine:
                        else (cfg.n_layers, 1, 1, 1))
         self._k_scale = jnp.ones(scale_shape, jnp.float32)
         self._v_scale = jnp.ones(scale_shape, jnp.float32)
+        # the slots' recurrent state, one array a kind [L, max_batch,
+        # ...], where the plan has a recurrent run (else none, and the
+        # programs take no such argument): donated to both programs and
+        # got back, in place as the pools. A prefill INSTALLS each row's
+        # final state in its slot whole, so a slot's new tenant never
+        # reads its last one's; decode advances the live slots' states
+        self._state = tuple(
+            jnp.zeros((cfg.n_layers, max_batch, *shape), dtype)
+            for _, shape, dtype in (self._recurrent.arrays
+                                    if self._recurrent else ()))
+        self._state_slot_bytes = sum(
+            a.size * a.dtype.itemsize for a in self._state) // max_batch
+        self.state_installs = 0
         self._table = np.full((self.max_batch, self.max_pages_per_seq),
                               -1, np.int32)
         self._alloc = PageAllocator(self.num_pages)
@@ -367,13 +444,12 @@ class PagedLLMEngine:
         # a sliding layer's window, if the model's plan has such layers
         # (for the decode dispatch's count of the KV rows a step reads)
         self._window = next(
-            (run.window for run in _model_module(cfg).layer_plan(cfg)
-             if run.window is not None), None)
+            (run.window for run in plan if run.window is not None), None)
         # prefill dispatches, and those whose program holds the prefill
         # attention kernel: a model with full-attention layers, lowered
         # for a TPU (``_dispatch_prefill``)
         self._kernel_backend = jax.default_backend() == "tpu" and any(
-            run.window is None for run in _model_module(cfg).layer_plan(cfg))
+            run.window is None for run in plan)
         self.prefill_dispatches = 0
         self.prefill_kernel_dispatches = 0
         # per dispatched decode chunk, the feed-forward's statistics on
@@ -408,7 +484,7 @@ class PagedLLMEngine:
                 partial(self._paged_decode_impl, self.cfg, chunk=chunk,
                         page_size=self.page_size,
                         quantized=self.kv_dtype == "int8"),
-                donate_argnums=(1, 2, 3, 4))
+                donate_argnums=self._donated())
             self._decode_cache[key] = fn
         return fn
 
@@ -426,9 +502,14 @@ class PagedLLMEngine:
                 partial(self._paged_prefill_impl, self.cfg,
                         page_size=self.page_size,
                         quantized=self.kv_dtype == "int8"),
-                donate_argnums=(1, 2, 3, 4))
+                donate_argnums=self._donated())
             self._prefill_cache[window_pages] = fn
         return fn
+
+    def _donated(self) -> tuple:
+        """The programs' donated arguments: the four pools, and the
+        slots' recurrent state, which follows the key."""
+        return (1, 2, 3, 4) + tuple(range(11, 11 + len(self._state)))
 
     def _window_pages(self, max_covered: int) -> int:
         """Power-of-two page count covering ``max_covered`` tokens,
@@ -439,7 +520,8 @@ class PagedLLMEngine:
     @staticmethod
     def _paged_decode_impl(cfg, params, k_pages, v_pages, k_scale,
                            v_scale, table, tokens, lengths, active,
-                           temps, key, *, chunk, page_size, quantized):
+                           temps, key, *state, chunk, page_size,
+                           quantized):
         """``chunk`` decode steps over every slot in one compiled program;
         KV rows written, then attended over where they lie, through the
         (bucketed) page table [B, PB]. Returns the pools, the [chunk,
@@ -454,7 +536,11 @@ class PagedLLMEngine:
         of each run of the model's layer plan in turn, carrying the
         activations and the same stacked pools (module docstring: in
         place), scanning over the run's weights and its layers'
-        indices."""
+        indices. ``state``: the slots' recurrent state, one array a kind
+        [L, max_batch, ...], for a plan with a recurrent run (else
+        none): carried as the pools are, a layer's mixer advancing the
+        ACTIVE slots' states at [layer] and leaving the others' as they
+        are; returned after the rest."""
         model = _model_module(cfg)
         num_pages = k_pages.shape[1]
         # the model's layers as runs of identical layers (one run, for a
@@ -467,10 +553,11 @@ class PagedLLMEngine:
             model, "fuse_attention_projections", None))
 
         def one_step(carry, _):
-            k_pages, v_pages, k_scale, v_scale, toks, lens, key = carry
+            k_pages, v_pages, k_scale, v_scale, toks, lens, key, *state = \
+                carry
             key, sub = jax.random.split(key)
             pos = jnp.where(active, lens, 0)                    # [B]
-            x = params["embedding"][toks[:, None]]              # [B,1,d]
+            x = model.embed(cfg, params, toks[:, None])         # [B,1,d]
             rotary = model.rotary_tables(cfg, pos[:, None])
             # per-slot write target for this token
             pidx = jnp.take_along_axis(
@@ -480,10 +567,15 @@ class PagedLLMEngine:
             ip = pos % page_size
 
             def block(run, carry, xs):
-                x, kp, vp, ks, vs = carry
+                x, kp, vp, ks, vs, *state = carry
                 p, layer = xs
                 q, k, v = model.attention_projections(
                     cfg, p, x, *rotary[run.kind])
+                if run.state is not None:
+                    # the mixer beside the attention, on the same input
+                    mixed, state = _advance_state(
+                        partial(model.recurrent_step, cfg, p, x), state,
+                        layer, active)
                 kp, vp, ks, vs = write_kv(
                     kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
                     quantized)
@@ -494,31 +586,31 @@ class PagedLLMEngine:
                     q[:, 0], kp, vp, ks, vs, layer, table, pos, active,
                     window=run.window)
                 x = model.attention_output(cfg, p, x, attn)
+                if run.state is not None:
+                    x = x + mixed
                 x, stats = model.feed_forward(cfg, p, x,
                                               valid=active[:, None])
-                return (x, kp, vp, ks, vs), stats
+                return (x, kp, vp, ks, vs, *state), stats
 
-            carry = (x, k_pages, v_pages, k_scale, v_scale)
+            carry = (x, k_pages, v_pages, k_scale, v_scale, *state)
             stats = []
             for run, xs in zip(plan, runs):
                 carry, run_stats = jax.lax.scan(partial(block, run), carry,
                                                 xs)
                 stats.append(run_stats)
-            x, k_pages, v_pages, k_scale, v_scale = carry
+            x, k_pages, v_pages, k_scale, v_scale, *state = carry
             x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)[:, 0]
-            head = model.lm_head_weights(cfg, params)
-            logits = jnp.einsum("bd,dv->bv", x, head,
-                                preferred_element_type=jnp.float32)
+            logits = model.head_logits(cfg, params, x)
             nxt = select_tokens(logits, temps, sub)
             lens = jnp.where(active, lens + 1, lens)
             return (k_pages, v_pages, k_scale, v_scale, nxt, lens,
-                    key), (nxt, _over_layers(stats))
+                    key, *state), (nxt, _over_layers(stats))
 
-        (k_pages, v_pages, k_scale, v_scale, _, lens, _), (toks, stats) = \
-            jax.lax.scan(
+        (k_pages, v_pages, k_scale, v_scale, _, lens, _, *state), \
+            (toks, stats) = jax.lax.scan(
                 one_step,
                 (k_pages, v_pages, k_scale, v_scale, tokens, lengths,
-                 key), None, length=chunk)
+                 key, *state), None, length=chunk)
         # merged last-token vector: chunk-active slots advance to their
         # newest token, others keep their prior value — the loop chains
         # every next dispatch off this DEVICE array, so admissions /
@@ -528,12 +620,12 @@ class PagedLLMEngine:
         # means (nothing, for a block that hands back none)
         stats = jax.tree.map(jnp.mean, stats)
         return (k_pages, v_pages, k_scale, v_scale, toks, lens, new_last,
-                stats)
+                stats, *state)
 
     @staticmethod
     def _paged_prefill_impl(cfg, params, k_pages, v_pages, k_scale,
                             v_scale, table_rows, tokens, slens, starts,
-                            temps, key, *, page_size, quantized):
+                            temps, key, *state, page_size, quantized):
         """Prefill ``n`` prompt SUFFIXES (one padded bucket) into pages
         and sample each row's first token, in a single program: each
         dispatch has a fixed sync cost, so a 16-request burst admitted
@@ -547,12 +639,19 @@ class PagedLLMEngine:
         table_rows: [n, max_pages_per_seq]. The layer scans (one a run
         of the model's layer plan) carry the activations and the stacked
         pools, as decode's do: the program holds one pool, the donated
-        one."""
+        one. ``state``, for a plan with a recurrent run (else none): the
+        slots' recurrent state arrays and, last, each row's slot [n]. A
+        row's mixer starts from the zero state (no prefix is reused
+        over such a plan: every prompt starts at 0) and its state after
+        the row's last token is INSTALLED at [layer, slot], whole: what
+        the slot's last tenant left there is never read. A slot past
+        the last one (a warm-up's row) drops."""
         model = _model_module(cfg)
         num_pages = k_pages.shape[1]
         n, t = tokens.shape
         plan = model.layer_plan(cfg)
-        x = params["embedding"][tokens]
+        *state, slots = state or (None,)
+        x = model.embed(cfg, params, tokens)
         rel = jnp.arange(t, dtype=jnp.int32)
         positions = starts[:, None] + rel[None, :]            # [n, T]
         rotary = model.rotary_tables(cfg, positions)
@@ -564,31 +663,37 @@ class PagedLLMEngine:
         ip_all = positions % page_size
 
         def block(run, carry, xs):
-            x, kp, vp, ks, vs = carry
+            x, kp, vp, ks, vs, *state = carry
             p, layer = xs
             q, k, v = model.attention_projections(cfg, p, x,
                                                   *rotary[run.kind])
+            if run.state is not None:
+                fresh = tuple(jnp.zeros((n, *a.shape[2:]), a.dtype)
+                              for a in state)
+                mixed, final = model.recurrent_mixer(cfg, p, x, fresh,
+                                                     valid)
+                state = [a.at[layer, slots].set(new, mode="drop")
+                         for a, new in zip(state, final)]
             kp, vp, ks, vs = write_kv(
                 kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, quantized)
             attn = paged_prefill_attention(
                 q, kp, vp, ks, vs, layer, table_rows, starts, slens,
                 window=run.window)
             x = model.attention_output(cfg, p, x, attn)
+            if run.state is not None:
+                x = x + mixed
             x, _ = model.feed_forward(cfg, p, x, valid=valid)
-            return (x, kp, vp, ks, vs), None
+            return (x, kp, vp, ks, vs, *state), None
 
-        carry = (x, k_pages, v_pages, k_scale, v_scale)
+        carry = (x, k_pages, v_pages, k_scale, v_scale, *state)
         for run, xs in zip(plan, _plan_runs(plan, params["blocks"])):
             carry, _ = jax.lax.scan(partial(block, run), carry, xs)
-        x, k_pages, v_pages, k_scale, v_scale = carry
+        x, k_pages, v_pages, k_scale, v_scale, *state = carry
         x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
         x = jnp.take_along_axis(
             x, (slens - 1)[:, None, None], axis=1).squeeze(1)
-        head = model.lm_head_weights(cfg, params)
-        logits = jnp.einsum("bd,dv->bv", x, head,
-                            preferred_element_type=jnp.float32)
-        first = select_tokens(logits, temps, key)
-        return k_pages, v_pages, k_scale, v_scale, first
+        first = select_tokens(model.head_logits(cfg, params, x), temps, key)
+        return (k_pages, v_pages, k_scale, v_scale, first, *state)
 
     # -- warm-up -----------------------------------------------------------
 
@@ -602,16 +707,24 @@ class PagedLLMEngine:
         n = 1
         while n <= top:
             rows = jnp.full((n, wp), -1, jnp.int32)
+            # no slot: every row's state drops, as its KV rows do
+            nowhere = jnp.full((n,), self.max_batch, jnp.int32)
             (self._k_pages, self._v_pages, self._k_scale,
-             self._v_scale, firsts) = prefill(
+             self._v_scale, firsts, *self._state) = prefill(
                 self.params, self._k_pages, self._v_pages,
                 self._k_scale, self._v_scale, rows,
                 jnp.zeros((n, bucket), jnp.int32),
                 jnp.ones((n,), jnp.int32),
                 jnp.full((n,), start, jnp.int32),
-                jnp.zeros((n,), jnp.float32), self._next_key())
+                jnp.zeros((n,), jnp.float32), self._next_key(),
+                *self._state_args(nowhere))
             yield n, firsts
             n *= 2
+
+    def _state_args(self, slots) -> tuple:
+        """What a prefill program takes after its key: the slots'
+        recurrent state and each row's slot, or nothing."""
+        return (*self._state, slots) if self._state else ()
 
     def warmup_prefix(self, prefix_len: int, tail_len: int,
                       max_n: int | None = None):
@@ -660,14 +773,14 @@ class PagedLLMEngine:
             for chunk in {self.decode_chunk, self._drain_chunk}:
                 fn = self._decode_paged(chunk, pb)
                 (self._k_pages, self._v_pages, self._k_scale,
-                 self._v_scale, toks, _, _, _) = fn(
+                 self._v_scale, toks, _, _, _, *self._state) = fn(
                     self.params, self._k_pages, self._v_pages,
                     self._k_scale, self._v_scale,
                     jnp.full((self.max_batch, pb), -1, jnp.int32),
                     jnp.zeros((self.max_batch,), jnp.int32),
                     jnp.zeros((self.max_batch,), jnp.int32), active,
                     jnp.zeros((self.max_batch,), jnp.float32),
-                    self._next_key())
+                    self._next_key(), *self._state)
                 np.asarray(toks)
         self._lengths[:] = 0
         self._last_tok[:] = 0
@@ -860,6 +973,16 @@ class PagedLLMEngine:
                    cached_tokens=cached,
                    missed_pages=lookups - cached // page,
                    attn_kernel=int(kernel))
+        slots = None
+        if self._state:
+            # every row's final state goes into its slot; the scan cuts
+            # the padded bucket into chunks, padding included
+            slots = jnp.asarray(np.array([it[1] for it in part], np.int32))
+            self.state_installs += len(part)
+            if ph:
+                ph.set(state_installs=len(part),
+                       scan_chunks=len(part) * -(-bucket
+                                                 // self._recurrent.chunk))
         prefill = self._prefill_paged(wp)
         slens = jnp.asarray(slens_np)
         rows = jnp.asarray(np.stack(
@@ -867,10 +990,10 @@ class PagedLLMEngine:
         temps = jnp.asarray(np.array(
             [it[0].temperature for it in part], np.float32))
         (self._k_pages, self._v_pages, self._k_scale, self._v_scale,
-         firsts) = prefill(
+         firsts, *self._state) = prefill(
             self.params, self._k_pages, self._v_pages, self._k_scale,
             self._v_scale, rows, tokens, slens, jnp.asarray(starts_np),
-            temps, self._next_key())
+            temps, self._next_key(), *self._state_args(slots))
         # the dispatch above is what makes each slot's full prompt pages
         # valid on device: REGISTER them in the prefix cache now — any
         # future admission's prefill program runs after this one on the
@@ -1314,11 +1437,12 @@ class PagedLLMEngine:
                 # in-flight chunk a torn table
                 dev[table] = jnp.asarray(self._table[:, :pb].copy())
             (self._k_pages, self._v_pages, self._k_scale, self._v_scale,
-             toks, lens, new_last, stats) = self._decode_paged(chunk, pb)(
+             toks, lens, new_last, stats,
+             *self._state) = self._decode_paged(chunk, pb)(
                 self.params, self._k_pages, self._v_pages, self._k_scale,
                 self._v_scale, dev[table], self._last_dev, dev["lens"],
                 dev["active"], dev["temps"], self._next_key(),
-            )
+                *self._state)
             self._chunk_stats.append(stats)
             now = time.monotonic()
             stream_seq = next(self._stream_seq)
@@ -1334,6 +1458,12 @@ class PagedLLMEngine:
                 if self._window is not None:
                     ph.set(kv_rows_window=int(
                         np.minimum(rows, self._window).sum()))
+                if self._state:
+                    # the live slots' recurrent state, which one step
+                    # reads once and writes once in every layer
+                    ph.set(state_slots=len(active_idx),
+                           state_bytes=2 * len(active_idx)
+                           * self._state_slot_bytes)
             self._last_dev = new_last
             dev["lens"] = lens   # stays on device for the chained chunk
             # start the token matrix's device->host copy NOW: it overlaps
@@ -1504,6 +1634,11 @@ class PagedLLMEngine:
             "total_finished": self.total_finished,
             "prefill_dispatches": self.prefill_dispatches,
             "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
+            # recurrent state beside the pages (0 where the plan has no
+            # recurrent run): rows whose state a prefill wrote into a
+            # slot, and the bytes the slots' state arrays hold
+            "state_installs": self.state_installs,
+            "state_bytes_held": self._state_slot_bytes * self.max_batch,
             "mean_ttft_s": float(np.mean(self.ttfts)) if self.ttfts else None,
             "kv_pages_total": self.num_pages,
             "kv_pages_free": len(self._alloc.free),

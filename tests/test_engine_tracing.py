@@ -236,6 +236,65 @@ def test_prefill_dispatches_say_whether_their_program_holds_the_kernel(
                        eng._k_pages.shape, 4, None)
 
 
+def test_recurrent_state_counts_on_the_dispatch_spans_equal_stats(tiny):
+    """A plan with a recurrent run (the tiny Falcon-H1): each prefill
+    dispatch says how many rows' state it installs in a slot
+    (``state_installs``: its group) and how many chunks its scan cuts
+    the padded bucket into (``scan_chunks``); each decode dispatch how
+    many live slots' state the chunk advances (``state_slots``) and the
+    bytes one step reads and writes of them (``state_bytes``), all from
+    the host's own counts. Their sums are ``stats()``'s. A plan of pages
+    alone carries none of the four."""
+    from ray_tpu.models import falcon_h1
+
+    cfg = falcon_h1.falcon_h1_tiny()
+    eng = PagedLLMEngine(cfg, falcon_h1.init_params(cfg, jax.random.key(0)),
+                         max_batch=3, max_len=128, page_size=PAGE,
+                         num_pages=30)
+    state_keys = {"state_installs", "scan_chunks", "state_slots",
+                  "state_bytes"}
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        eng.start()
+        rng = np.random.default_rng(5)
+        reqs = [eng.submit(rng.integers(1, 100, n), max_new_tokens=6)
+                for n in (50, 9, 40, 70, 12)]
+        for r in reqs:
+            assert len(list(r.tokens())) == 6
+        eng.stop()
+        prefills = tracing.recorded_spans("engine.dispatch_prefill")
+        decodes = tracing.recorded_spans("engine.dispatch_decode")
+        plain = make_engine(tiny)
+        clear_ring()
+        plain.start()
+        assert len(list(plain.submit(rng.integers(1, 500, 20),
+                                     max_new_tokens=4).tokens())) == 4
+        plain.stop()
+        others = tracing.recorded_spans("engine.dispatch_")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    stats = eng.stats()
+    slot_bytes = cfg.n_layers * 4 * (6 * 8 * 16 + 3 * cfg.conv_dim)
+    assert stats["state_bytes_held"] == 3 * slot_bytes
+    assert prefills and decodes
+    for s in prefills:
+        a = s["attrs"]
+        assert a["state_installs"] == a["group"]
+        assert a["scan_chunks"] == a["group"] * -(-a["bucket"]
+                                                  // cfg.ssm_chunk)
+    assert sum(s["attrs"]["state_installs"] for s in prefills) == \
+        stats["state_installs"] == len(reqs)
+    for s in decodes:
+        a = s["attrs"]
+        assert a["state_slots"] == a["live"]
+        assert a["state_bytes"] == 2 * a["live"] * slot_bytes
+    assert others and not any(state_keys & set(s["attrs"]) for s in others)
+    assert plain.stats()["state_installs"] == 0
+    assert plain.stats()["state_bytes_held"] == 0
+
+
 def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):
     clear_ring()
     assert not tracing.recording()

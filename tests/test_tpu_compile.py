@@ -171,21 +171,30 @@ def _lower_engine_program(device, model, cfg, num_pages, program, dims,
     scale = shape(pool_dims[:-1] if quantized else (cfg.n_layers, 1, 1, 1),
                   jnp.float32)
     key = jax.eval_shape(lambda: jax.random.key(0))
+    # the slots' recurrent state, where the plan has a recurrent run: the
+    # engine's own arrays [layers, slots, ...], after the key, donated
+    recurrent = next((run.state for run in model.layer_plan(cfg)
+                      if run.state is not None), None)
+    state = tuple(shape((cfg.n_layers, slots, *dims_), dtype)
+                  for _, dims_, dtype in (recurrent.arrays if recurrent
+                                          else ()))
     if program == "decode":
         chunk, pages = dims
         fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
                      page_size=page, quantized=quantized)
         args = (shape((slots, pages), jnp.int32), shape((slots,), jnp.int32),
                 shape((slots,), jnp.int32), shape((slots,), jnp.bool_),
-                shape((slots,), jnp.float32), key)
+                shape((slots,), jnp.float32), key, *state)
     else:
         n, tokens, pages = dims
         fn = partial(PagedLLMEngine._paged_prefill_impl, cfg,
                      page_size=page, quantized=quantized)
         args = (shape((n, pages), jnp.int32), shape((n, tokens), jnp.int32),
                 shape((n,), jnp.int32), shape((n,), jnp.int32),
-                shape((n,), jnp.float32), key)
-    return jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+                shape((n,), jnp.float32), key, *state,
+                *([shape((n,), jnp.int32)] if state else []))
+    donated = (1, 2, 3, 4) + tuple(range(11, 11 + len(state)))
+    return jax.jit(fn, donate_argnums=donated).lower(
         params, pool, pool, scale, scale, *args)
 
 
@@ -520,3 +529,59 @@ def test_the_plain_prefill_path_does_hold_score_arrays(v5e_2x2):
         v5e_2x2[0], llama, llama.LlamaConfig(**_D12), _D12_PAGES, "prefill",
         (2, 64, 16)).as_text()
     assert _score_arrays(text, 2048) == ["2,8,4,64,2048"]
+
+
+# Falcon-H1-34B-Instruct cut to 4 blocks (``serve-instruct-gen``): 128
+# slots, 1280 KV pages, and each slot's recurrent state beside them
+_H1_LAYERS, _H1_SLOTS, _H1_PAGES = 4, 128, 1280
+_H1_PROGRAMS = [("decode", (8, 8)), ("decode", (16, 8)),
+                ("prefill", (2, 1024, 8)), ("prefill", (2, 512, 4))]
+_STATE_COPY = re.compile(
+    r"= f32\[(?:4,|1,)?128,32,128,256\]\S* (?:copy|copy-start)\(")
+
+
+@pytest.mark.parametrize(
+    "program,dims", _H1_PROGRAMS,
+    ids=[f"{p}-{'x'.join(map(str, d))}" for p, d in _H1_PROGRAMS])
+def test_falcon_h1_d4_engine_programs_fit_beside_their_state(v5e_2x2,
+                                                             program, dims):
+    """The engine's programs for the recurrent plan at the published
+    widths: the decode program at the cell's one table (8 pages) in both
+    chunks, and the widest prefill programs (two cold 1024-token prompts,
+    which the reference check's 600 tokens reach; two of 512, the
+    traffic's). Arguments of 12.3 GB (8.79 GB of weights, 1.34 GB of
+    pools, 2.16 GB of state) fit a v5e with the program's temporaries
+    beside them; the pools AND the slots' state are donated and come back
+    in place; no instruction copies a layer's state, or the stack of
+    them, whole (the update reads a layer's through a slice fused into
+    its consumer and writes it through a fused update, in place; the
+    program's temporaries, under 1 GB, hold no second state of 2.1 GB)."""
+    from ray_tpu.models import falcon_h1
+
+    cfg = dataclasses.replace(falcon_h1.falcon_h1_34b_instruct(),
+                              n_layers=_H1_LAYERS)
+    compiled = _compile_engine_program(
+        v5e_2x2[0], falcon_h1, cfg, _H1_PAGES, program, dims,
+        slots=_H1_SLOTS)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pool_bytes = _H1_LAYERS * _H1_PAGES * 128 * 4 * 128 * 2
+    state_bytes = _H1_LAYERS * _H1_SLOTS * (4 * 32 * 128 * 256
+                                            + 2 * 3 * 5120)
+    assert 12.2e9 < mem.argument_size_in_bytes < 12.4e9
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes + state_bytes
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            - mem.alias_size_in_bytes) < 13.5e9              # of 15.75 GB
+    assert not _pool_copy(_H1_LAYERS, _H1_PAGES, 4).findall(text)
+    assert bool(_DECODE_KERNEL.search(text)) == (program == "decode")
+    if program == "decode":
+        assert not _STATE_COPY.findall(text)
+        # five query heads a KV head through the decode kernel, and no
+        # stack of projection weights moved in the loops: q | k | v are
+        # one stack (147 MB), wo's (105 MB) is read where it lies
+        assert not _stack_moves_in_loops(
+            text, _H1_LAYERS, cfg.d_model, (3584, 9248, cfg.d_ff))
+        assert not _stack_moves_in_loops(text, _H1_LAYERS, 2560,
+                                         (cfg.d_model,))
+        assert not _stack_moves_in_loops(text, _H1_LAYERS, cfg.d_ssm,
+                                         (cfg.d_model,))
