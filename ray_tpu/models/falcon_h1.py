@@ -26,8 +26,9 @@ So a sequence's state is KV pages AND, per layer, the recurrent state
 the block has a fourth piece beside ``attention_projections`` /
 ``attention_output`` / ``feed_forward``: the mixer, over a padded block
 of tokens from a given state (``recurrent_mixer``, a prefill) and for
-one token a row (``recurrent_step``, a decode step). The pieces take no
-view on where keys, values or states live: ``forward`` puts plain causal
+one token a slot (``recurrent_step``, a decode step, which advances the
+states in the stacked arrays they are handed in). The pieces take no
+view on where keys or values live: ``forward`` puts plain causal
 attention and a zero starting state between them, the paged serving
 engine its page pool and its slots' states (``serve/paged_llm.py``).
 
@@ -54,7 +55,8 @@ from ray_tpu.models.llama import LayerStack, fanin_init, lm_head_weights
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
-from ray_tpu.ops.ssm import causal_conv, last_rows, ssm_scan, ssm_step
+from ray_tpu.ops.ssm import (causal_conv, last_rows, ssm_scan,
+                             ssm_state_step)
 
 
 @dataclass(frozen=True)
@@ -378,16 +380,27 @@ def recurrent_mixer(cfg: FalconH1Config, p, x, state, valid):
     return _mixer_out(cfg, p, y, xs, z), (s1, last_rows(xbc, tail, lengths))
 
 
-def recurrent_step(cfg: FalconH1Config, p, x, state):
-    """The mixer for one token a row: ``x`` [n, 1, d], ``state`` as
-    above. Returns (the term to add [n, 1, d], the new state)."""
-    s0, tail = state
+def recurrent_step(cfg: FalconH1Config, p, x, state, layer, active):
+    """The mixer for one token a slot, over the slots' states where they
+    lie: ``x`` [n, 1, d]; ``state`` the STACKED arrays (S [L, n, H, P,
+    N] float32, tail [L, n, K-1, C]) of which this block's are at
+    [layer]; ``active`` [n] bool. Returns (the term to add [n, 1, d],
+    the stacked arrays): an active slot's state at [layer] advanced one
+    token, an inactive slot's and every other layer's left bit for bit
+    as they were. The float32 state goes through ``ssm_state_step`` (on
+    a TPU one kernel that reads it once and writes it once, in place);
+    the tail, 7 MB a layer at the published widths, is sliced and
+    written back here."""
+    states, tails = state
+    tail = tails[layer]
     z, xbc, dt = _mixer_in(cfg, p, x)
     conv = causal_conv(xbc, tail, p["conv_w"], p["conv_b"])
     xs, b, c, step, a = _mixer_split(cfg, p, conv[:, 0], dt[:, 0])
-    y, s1 = ssm_step(xs, step, a, b, c, s0)
+    y, states = ssm_state_step(xs, step, a, b, c, states, layer, active)
     new_tail = jnp.concatenate([tail[:, 1:], xbc.astype(tail.dtype)], axis=1)
-    return _mixer_out(cfg, p, y, xs, z[:, 0])[:, None], (s1, new_tail)
+    tails = tails.at[layer].set(
+        jnp.where(active[:, None, None], new_tail, tail))
+    return _mixer_out(cfg, p, y, xs, z[:, 0])[:, None], (states, tails)
 
 
 def zero_state(cfg: FalconH1Config, rows: int) -> tuple:

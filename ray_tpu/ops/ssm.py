@@ -11,7 +11,9 @@ section 6): inside a chunk the outputs are a masked, decayed ``c b^T``
 against the tokens' ``dt x`` (matmuls), across chunks the state is
 carried, one step a chunk. It starts from a given state and hands back
 the state after the block. ``ssm_step`` is the recurrence itself for one
-token, which a decode step runs over every slot.
+token. ``ssm_state_step`` is what a decode step runs: the same one token
+over the slots' states where they lie, in the STACKED array a serving
+engine holds them in ([L, slots, H, P, N]), at one layer.
 
 Padding is exact, not approximate: where ``dt`` is 0 the decay is
 ``exp(0) = 1`` and the added term is 0, so the state passes through
@@ -21,13 +23,50 @@ the state after the block is the state after each row's last token.
 ``dt``, the decays, the state and every accumulation are float32; the
 matmuls take operands in ``x``'s dtype (bf16 in a served model, float32
 in a float32 one). ``b`` and ``c`` come in ``G`` groups, each shared by
-``H / G`` consecutive heads. Plain ``jax.numpy`` and ``lax``: no kernel.
+``H / G`` consecutive heads. The scan, the one-token update and the
+convolution are plain ``jax.numpy`` and ``lax``.
+
+``ssm_state_step`` on a program LOWERED for a TPU is one Pallas kernel
+(``ssm_state_step_kernel``), where the state's shape meets its rule
+(``state_kernel_engages``: a float32 state, ``N`` whole lanes, ``P`` whole
+sublanes): XLA lowers the plain formulation to two fusions that read a
+layer's state twice and write it once; the kernel moves it once each way.
+
+- The stacked array stays where it is: the kernel's blocks are cut from
+  it at [layer, slot, a block of heads] (the layer index comes through
+  scalar prefetch into the block's index map), and its output IS its
+  input (``input_output_aliases``). Handing the kernel a layer's slice
+  would be a copy of the layer in and an update-slice out: the third
+  pass by another name.
+- The grid walks (slot, block of heads); Pallas' own pipeline fetches the
+  next block and writes the last one back while this one is computed.
+  A block is at most ``_BLOCK_BYTES`` of state; in and out, double
+  buffered, four of them lie in VMEM.
+- Per head: ``S' = d S + dtx (x) b`` and ``y = sum_n S' c``, elementwise
+  and one reduction over lanes, all float32: no matmul, nothing of the
+  state is rounded. ``d = exp(dt a)`` is a scalar a head (SMEM, scalar
+  prefetch); a block's ``dtx`` [heads of the block, P] is transposed in
+  VMEM so that a head's is a column, broadcast over lanes, and ``y``, a
+  column a head, goes out the same way back; ``b`` and ``c`` come in
+  their groups, a head reads its group's row. All of these are computed
+  outside (kilobytes a slot).
+- An inactive slot's block is copied through as it is, bit for bit (the
+  pipeline writes every block back), and its ``y`` is zero.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+STATE_KERNEL_NAME = "ssm_state_step"
+_BLOCK_BYTES = 1 << 20      # of state a grid step moves each way
+_BLOCK_MAX_BYTES = 3 << 20  # the most it may: four blocks lie in VMEM
 
 
 def _per_head(grouped, heads: int, axis: int = -2):
@@ -99,7 +138,8 @@ def ssm_step(x, dt, a, b, c, state):
     the new state). Elementwise and a reduction, all float32: ``y`` is a
     sum over the new state's last axis (no matmul, so nothing of the
     state is rounded). The state need be read once and written once;
-    XLA's lowering on a v5e reads it twice (PERF.md, section 5)."""
+    XLA's lowering on a v5e reads it twice, which is why a decode step
+    goes through ``ssm_state_step`` and its kernel."""
     heads = x.shape[1]
     b_h = _per_head(b, heads).astype(jnp.float32)               # [n, H, N]
     c_h = _per_head(c, heads).astype(jnp.float32)
@@ -107,6 +147,131 @@ def ssm_step(x, dt, a, b, c, state):
     state = (jnp.exp(dt * a)[..., None, None] * state
              + dtx[..., None] * b_h[:, :, None, :])
     return jnp.sum(state * c_h[:, :, None, :], axis=-1), state
+
+
+def _block_heads(heads: int, head_bytes: int) -> int:
+    """Heads a grid step moves: whole groups of 8 (a tile of ``dtx`` and
+    of ``y`` is 8 heads by ``P``), the most that divide ``heads`` within
+    ``_BLOCK_BYTES`` of state, and 8 at the least; where ``heads`` is no
+    multiple of 8, all of them."""
+    fits = [k for k in range(8, heads + 1, 8)
+            if heads % k == 0 and k * head_bytes <= _BLOCK_BYTES]
+    return max(fits) if fits else (8 if heads % 8 == 0 else heads)
+
+
+def state_kernel_engages(states) -> bool:
+    """The rule, from the stacked state's shape and dtype (the array, or
+    anything with its ``shape`` and ``dtype``): whether
+    ``ssm_state_step`` over it is the kernel on a TPU. A float32 state
+    [L, slots, H, P, N] whose ``N`` is whole lanes and ``P`` whole
+    sublanes (a head's state is then whole float32 tiles), and whose
+    heads go in blocks that four of fit the kernel's VMEM."""
+    if len(states.shape) != 5 or states.dtype != jnp.float32:
+        return False
+    heads, width, size = states.shape[2:]
+    head_bytes = 4 * width * size
+    return (size % 128 == 0 and width % 8 == 0
+            and _block_heads(heads, head_bytes) * head_bytes
+            <= _BLOCK_MAX_BYTES)
+
+
+def ssm_state_step_reference(x, dt, a, b, c, states, layer, active):
+    """The plain formulation: the layer's states sliced out of the stack,
+    ``ssm_step``, an inactive slot's kept by a ``where``, the layer
+    written back. What the kernel is held to, and what every platform
+    but the TPU (and every shape outside the rule) runs."""
+    old = states[layer]
+    y, new = ssm_step(x, dt, a, b, c, old)
+    keep = active[:, None, None, None]
+    return y, states.at[layer].set(
+        jnp.where(keep, new.astype(states.dtype), old))
+
+
+def _state_kernel(layer_ref, active_ref, decay_ref,            # SMEM
+                  dtx_ref, b_ref, c_ref, s_ref, y_ref, o_ref, y_cols, *,
+                  heads, per_group):
+    """One (slot, block of heads); see the module docstring. dtx_ref,
+    y_ref [hb, P]; b_ref, c_ref [G, 1, N]; s_ref, o_ref [hb, P, N];
+    y_cols [P, hb], the block's ``y`` as columns."""
+    del layer_ref                     # the index maps read it
+    hb = s_ref.shape[0]
+    slot = pl.program_id(0)
+    first = pl.program_id(1) * hb
+    live = active_ref[slot] != 0
+
+    @pl.when(live)
+    def _():
+        dtx = dtx_ref[...].T                                    # [P, hb]
+        for j in range(hb):
+            group = (first + j) // per_group
+            new = (decay_ref[slot * heads + first + j] * s_ref[j]
+                   + dtx[:, j:j + 1] * b_ref[group])
+            o_ref[j] = new
+            y_cols[:, j:j + 1] = jnp.sum(new * c_ref[group], axis=-1,
+                                         keepdims=True)
+        y_ref[...] = y_cols[...].T
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = s_ref[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+def ssm_state_step_kernel(x, dt, a, b, c, states, layer, active, *,
+                          interpret=False):
+    """The kernel's launch; arguments as ``ssm_state_step``."""
+    n, heads, width = x.shape
+    groups, size = b.shape[1:]
+    hb = _block_heads(heads, 4 * width * size)
+    decay = jnp.exp(dt * a).reshape(-1)                         # [n * H]
+    dtx = dt[..., None] * x.astype(jnp.float32)                 # [n, H, P]
+    b = b.astype(jnp.float32)[:, :, None, :]                    # [n,G,1,N]
+    c = c.astype(jnp.float32)[:, :, None, :]
+
+    def at_layer(s, h, layer_ref, *_):
+        return layer_ref[0], s, h, 0, 0
+
+    state_block = pl.BlockSpec((None, None, hb, width, size), at_layer)
+    head_block = pl.BlockSpec((None, hb, width), lambda s, h, *_: (s, h, 0))
+    group_block = pl.BlockSpec((None, groups, 1, size),
+                               lambda s, h, *_: (s, 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_state_kernel, heads=heads,
+                          per_group=heads // groups),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(n, heads // hb),
+            in_specs=[head_block, group_block, group_block, state_block],
+            out_specs=[head_block, state_block],
+            scratch_shapes=[pltpu.VMEM((width, hb), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((n, heads, width), jnp.float32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        # operand 6 (after the three prefetched scalars and dtx, b, c) is
+        # the stacked state, and it is output 1: updated in place
+        input_output_aliases={6: 1},
+        interpret=interpret, name=STATE_KERNEL_NAME,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
+      decay, dtx, b, c, states)
+
+
+def ssm_state_step(x, dt, a, b, c, states, layer, active):
+    """One token a slot at one layer of the slots' stacked states. x [n,
+    H, P]; dt [n, H] float32; a [H]; b, c [n, G, N]; ``states`` [L, n,
+    H, P, N]; ``layer`` a scalar; ``active`` [n] bool. Slot i's state at
+    [layer, i] advances one token if ``active[i]`` and is otherwise left
+    bit for bit as it was; every other layer's is untouched. Returns (y
+    [n, H, P] float32, an inactive slot's row unspecified; the stacked
+    array). A caller that donates ``states`` gets it back in place.
+
+    Outside the rule (``state_kernel_engages``) this IS the plain
+    formulation, called directly. Within it the two lowerings are the
+    module's own functions, not closures made a call, so the programs of
+    an engine trace them once."""
+    if not state_kernel_engages(states):
+        return ssm_state_step_reference(x, dt, a, b, c, states, layer,
+                                        active)
+    return lax.platform_dependent(
+        x, dt, a, b, c, states, layer, active,
+        tpu=ssm_state_step_kernel, default=ssm_state_step_reference)
 
 
 def causal_conv(x, tail, weight, bias):

@@ -102,8 +102,16 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   are. A prefill runs the mixer over the padded prompt from the zero
   state (``recurrent_mixer``: padding moves nothing) and INSTALLS each
   row's state after its last token at [layer, slot], whole; a decode
-  step advances the active slots' states (``recurrent_step``) and leaves
-  the others' as they are. A slot that finishes mid-chunk decodes on, as
+  step hands the model's ``recurrent_step`` the STACKED arrays, the
+  layer and the slots that are active, and gets the arrays back with
+  the active slots' states at [layer] advanced one token and the others'
+  as they were, bit for bit. The engine slices nothing out: the float32
+  state is updated where it lies (``ops/ssm.py:ssm_state_step``: on a
+  program lowered for a TPU one Pallas kernel that reads a slot's state
+  once and writes it once, aliased from input to output, where the plain
+  formulation's lowering made three passes; the dispatch's span says so,
+  ``state_kernel``, and ``stats()`` counts ``state_kernel_dispatches``
+  of ``decode_dispatches``). A slot that finishes mid-chunk decodes on, as
   today, and its state is garbage afterwards: nothing reads it, for the
   next tenant's prefill overwrites it before any decode step of that
   tenant runs (the device runs dispatches in order). A prefix hit would
@@ -163,6 +171,7 @@ from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
 from ray_tpu.ops.paged_decode_attention import paged_decode_attention
 from ray_tpu.ops.paged_prefill_attention import (kernel_engages,
                                                  paged_prefill_attention)
+from ray_tpu.ops.ssm import state_kernel_engages
 from ray_tpu.serve.llm import _STAGES, Request, _named_jit, _serve_hist
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import tracing as _tracing
@@ -239,18 +248,6 @@ def _over_layers(stats: list) -> dict:
         parts = [run_stats[name] for run_stats in stats if name in run_stats]
         out[name] = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
     return out
-
-
-def _advance_state(step, state, layer, active):
-    """One decode step of a layer's recurrent mixer over the slots:
-    ``step(its state)`` -> (the term for the stream, the new state),
-    the state read from the stacked arrays at [layer] and written back
-    there, an inactive slot's left as it is."""
-    old = tuple(a[layer] for a in state)
-    mixed, new = step(old)
-    keep = [active.reshape(-1, *[1] * (o.ndim - 1)) for o in old]
-    return mixed, [a.at[layer].set(jnp.where(k, n.astype(o.dtype), o))
-                   for a, k, n, o in zip(state, keep, new, old)]
 
 
 class PagedLLMEngine:
@@ -431,6 +428,13 @@ class PagedLLMEngine:
         self._state_slot_bytes = sum(
             a.size * a.dtype.itemsize for a in self._state) // max_batch
         self.state_installs = 0
+        # decode dispatches, and those whose program advances the state
+        # in the state kernel: the rule on the arrays' own shapes, on a
+        # program lowered for a TPU (``ops/ssm.py:ssm_state_step``)
+        self._state_kernel = jax.default_backend() == "tpu" and any(
+            state_kernel_engages(a) for a in self._state)
+        self.decode_dispatches = 0
+        self.state_kernel_dispatches = 0
         self._table = np.full((self.max_batch, self.max_pages_per_seq),
                               -1, np.int32)
         self._alloc = PageAllocator(self.num_pages)
@@ -573,9 +577,8 @@ class PagedLLMEngine:
                     cfg, p, x, *rotary[run.kind])
                 if run.state is not None:
                     # the mixer beside the attention, on the same input
-                    mixed, state = _advance_state(
-                        partial(model.recurrent_step, cfg, p, x), state,
-                        layer, active)
+                    mixed, state = model.recurrent_step(
+                        cfg, p, x, state, layer, active)
                 kp, vp, ks, vs = write_kv(
                     kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
                     quantized)
@@ -1444,6 +1447,8 @@ class PagedLLMEngine:
                 dev["active"], dev["temps"], self._next_key(),
                 *self._state)
             self._chunk_stats.append(stats)
+            self.decode_dispatches += 1
+            self.state_kernel_dispatches += int(self._state_kernel)
             now = time.monotonic()
             stream_seq = next(self._stream_seq)
             if ph:
@@ -1460,10 +1465,12 @@ class PagedLLMEngine:
                         np.minimum(rows, self._window).sum()))
                 if self._state:
                     # the live slots' recurrent state, which one step
-                    # reads once and writes once in every layer
+                    # reads once and writes once in every layer, and
+                    # whether this program does so in the state kernel
                     ph.set(state_slots=len(active_idx),
                            state_bytes=2 * len(active_idx)
-                           * self._state_slot_bytes)
+                           * self._state_slot_bytes,
+                           state_kernel=int(self._state_kernel))
             self._last_dev = new_last
             dev["lens"] = lens   # stays on device for the chained chunk
             # start the token matrix's device->host copy NOW: it overlaps
@@ -1634,6 +1641,8 @@ class PagedLLMEngine:
             "total_finished": self.total_finished,
             "prefill_dispatches": self.prefill_dispatches,
             "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
+            "decode_dispatches": self.decode_dispatches,
+            "state_kernel_dispatches": self.state_kernel_dispatches,
             # recurrent state beside the pages (0 where the plan has no
             # recurrent run): rows whose state a prefill wrote into a
             # slot, and the bytes the slots' state arrays hold

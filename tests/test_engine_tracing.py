@@ -236,6 +236,56 @@ def test_prefill_dispatches_say_whether_their_program_holds_the_kernel(
                        eng._k_pages.shape, 4, None)
 
 
+_STATE_KERNEL_CASES = [
+    # the backend the engine finds, the mixer's state size, state_kernel
+    ("cpu", 16, 0), ("cpu", 128, 0), ("tpu", 16, 0), ("tpu", 128, 1)]
+
+
+@pytest.mark.parametrize(
+    "backend,state_size,engaged", _STATE_KERNEL_CASES,
+    ids=[f"{b}-state{n}" for b, n, _ in _STATE_KERNEL_CASES])
+def test_decode_dispatches_say_whether_their_program_holds_the_state_kernel(
+        monkeypatch, backend, state_size, engaged):
+    """``state_kernel`` on ``engine.dispatch_decode`` is the rule the
+    program's state update was traced by (``ops/ssm.py``:
+    ``state_kernel_engages``) applied to the engine's own state arrays,
+    on a TPU backend alone; ``stats()`` counts the decode dispatches and
+    those with it. On the CPU it is 0 of n whatever the shapes; an engine
+    that FINDS a TPU backend (it is told so here, as it is built; its
+    programs still lower for the CPU) says 1 where the state is whole
+    lanes of float32 and 0 where it is not. A plan of pages alone
+    carries no such count (the test below)."""
+    from ray_tpu.models import falcon_h1
+    from ray_tpu.serve import paged_llm
+
+    cfg = falcon_h1.falcon_h1_tiny(ssm_state=state_size)
+    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: backend)
+    eng = PagedLLMEngine(cfg, falcon_h1.init_params(cfg, jax.random.key(0)),
+                         max_batch=2, max_len=64, page_size=PAGE,
+                         num_pages=12)
+    monkeypatch.undo()
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        eng.start()
+        rng = np.random.default_rng(3)
+        for n in (20, 7, 33):
+            assert len(list(eng.submit(rng.integers(1, 100, n),
+                                       max_new_tokens=9).tokens())) == 9
+        eng.stop()
+        decodes = tracing.recorded_spans("engine.dispatch_decode")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    stats = eng.stats()
+    assert decodes and stats["decode_dispatches"] == len(decodes)
+    assert [s["attrs"]["state_kernel"] for s in decodes] == \
+        [engaged] * len(decodes)
+    assert stats["state_kernel_dispatches"] == engaged * len(decodes)
+    assert sum(s["attrs"]["state_kernel"] for s in decodes) == \
+        stats["state_kernel_dispatches"]
+
+
 def test_recurrent_state_counts_on_the_dispatch_spans_equal_stats(tiny):
     """A plan with a recurrent run (the tiny Falcon-H1): each prefill
     dispatch says how many rows' state it installs in a slot
@@ -244,7 +294,9 @@ def test_recurrent_state_counts_on_the_dispatch_spans_equal_stats(tiny):
     many live slots' state the chunk advances (``state_slots``) and the
     bytes one step reads and writes of them (``state_bytes``), all from
     the host's own counts. Their sums are ``stats()``'s. A plan of pages
-    alone carries none of the four."""
+    alone carries none of the four, nor ``state_kernel``, and its
+    ``stats()`` count the decode dispatches with no state kernel among
+    them."""
     from ray_tpu.models import falcon_h1
 
     cfg = falcon_h1.falcon_h1_tiny()
@@ -252,7 +304,7 @@ def test_recurrent_state_counts_on_the_dispatch_spans_equal_stats(tiny):
                          max_batch=3, max_len=128, page_size=PAGE,
                          num_pages=30)
     state_keys = {"state_installs", "scan_chunks", "state_slots",
-                  "state_bytes"}
+                  "state_bytes", "state_kernel"}
     clear_ring()
     tracing.enable_tracing()
     try:
@@ -293,6 +345,8 @@ def test_recurrent_state_counts_on_the_dispatch_spans_equal_stats(tiny):
     assert others and not any(state_keys & set(s["attrs"]) for s in others)
     assert plain.stats()["state_installs"] == 0
     assert plain.stats()["state_bytes_held"] == 0
+    assert plain.stats()["decode_dispatches"] >= 1
+    assert plain.stats()["state_kernel_dispatches"] == 0
 
 
 def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):

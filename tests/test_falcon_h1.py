@@ -19,6 +19,7 @@ from benchmark import reference, serving
 from benchmark.families import falcon_h1 as family
 from ray_tpu.models import falcon_h1, laguna
 from ray_tpu.models.llama import LayerStack
+from ray_tpu.ops import ssm
 from ray_tpu.serve import paged_llm
 from ray_tpu.serve.paged_llm import PagedLLMEngine, _model_module
 from test_tpu_compile import _lower_engine_program
@@ -134,9 +135,28 @@ def test_each_departure_of_the_reference_is_another_model(tiny, name):
     assert float(jnp.max(jnp.abs(other - want))) > 0.3
 
 
+def _steps(cfg, p, x, tokens, rows=2):
+    """``tokens`` tokens one at a time through ``recurrent_step`` from
+    the zero state, the rows' states at layer 1 of a stack of two:
+    (each step's term, the rows' state and tail at the end)."""
+    state = tuple(jnp.stack([jnp.full_like(a, 3.0), a])
+                  for a in falcon_h1.zero_state(cfg, rows))
+    active = jnp.ones((rows,), bool)
+    terms = []
+    for t in range(tokens):
+        term, state = falcon_h1.recurrent_step(cfg, p, x[:, t:t + 1], state,
+                                               jnp.int32(1), active)
+        terms.append(term[:, 0])
+    # the other layer's arrays are as they were
+    for a in state:
+        assert float(jnp.min(a[0])) == float(jnp.max(a[0])) == 3.0
+    return terms, tuple(a[1] for a in state)
+
+
 def test_the_two_forms_of_the_mixer_agree(tiny):
     """A block of tokens through ``recurrent_mixer`` and the same tokens
-    one at a time through ``recurrent_step``: the same terms for the
+    one at a time through ``recurrent_step`` (at one layer of a stack of
+    states, as the engine hands them over): the same terms for the
     stream, and the same state and tail at the end."""
     cfg, params = tiny
     p = jax.tree.map(lambda a: a[0], params["blocks"])
@@ -144,23 +164,41 @@ def test_the_two_forms_of_the_mixer_agree(tiny):
     valid = jnp.ones((2, 16), bool)
     out, (s_end, tail_end) = falcon_h1.recurrent_mixer(
         cfg, p, x, falcon_h1.zero_state(cfg, 2), valid)
-    state = falcon_h1.zero_state(cfg, 2)
-    for t in range(16):
-        step, state = falcon_h1.recurrent_step(cfg, p, x[:, t:t + 1], state)
-        np.testing.assert_allclose(step[:, 0], out[:, t], rtol=1e-4,
-                                   atol=1e-5)
+    terms, state = _steps(cfg, p, x, 16)
+    for t, step in enumerate(terms):
+        np.testing.assert_allclose(step, out[:, t], rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(state[0], s_end, rtol=1e-4, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(state[1]), np.asarray(tail_end))
     # padding behind 5 valid tokens: the state and tail after the fifth
     out5, (s5, tail5) = falcon_h1.recurrent_mixer(
         cfg, p, x, falcon_h1.zero_state(cfg, 2), jnp.arange(16)[None] < 5)
-    state = falcon_h1.zero_state(cfg, 2)
-    for t in range(5):
-        _, state = falcon_h1.recurrent_step(cfg, p, x[:, t:t + 1], state)
+    _, state = _steps(cfg, p, x, 5)
     np.testing.assert_allclose(state[0], s5, rtol=1e-4, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(state[1]), np.asarray(tail5))
     np.testing.assert_array_equal(np.asarray(out5[:, :5]),
                                   np.asarray(out[:, :5]))
+
+
+def test_a_step_leaves_an_inactive_slots_state_and_tail(tiny):
+    """``recurrent_step`` over three slots of which the middle one is
+    inactive: its state and its tail at the layer are the bits they
+    were, the others' moved."""
+    cfg, params = tiny
+    p = jax.tree.map(lambda a: a[1], params["blocks"])
+    ks = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(ks[0], (3, 1, cfg.d_model))
+    (_, s_shape, _), (_, t_shape, t_dt) = \
+        falcon_h1.layer_plan(cfg)[0].state.arrays
+    state = (jax.random.normal(ks[1], (2, 3, *s_shape)),
+             jax.random.normal(ks[2], (2, 3, *t_shape)).astype(t_dt))
+    _, new = falcon_h1.recurrent_step(cfg, p, x, state, jnp.int32(0),
+                                      jnp.array([True, False, True]))
+    for before, after in zip(state, new):
+        before, after = np.asarray(before), np.asarray(after)
+        np.testing.assert_array_equal(after[1], before[1])
+        np.testing.assert_array_equal(after[0, 1], before[0, 1])
+        assert not np.array_equal(after[0, 0], before[0, 0])
+        assert not np.array_equal(after[0, 2], before[0, 2])
 
 
 # -- the engine's two programs against the reference's one forward pass ------
@@ -245,6 +283,43 @@ def test_prefill_then_decode_is_the_references_forward_pass(
     seq = np.concatenate([prompt, tokens[:-1]])[None]
     want = np.asarray(family.logits(config, params, seq))[0, plen - 1:]
     assert got.shape == want.shape == (new, cfg.vocab_size)
+    np.testing.assert_allclose(got, want, rtol=PAGED_TOL, atol=PAGED_TOL)
+    gap, _ = reference.token_gap(family.logits, config, params, prompt,
+                                 tokens)
+    assert gap <= PAGED_TOL
+
+
+@pytest.mark.parametrize("plen,chunk", [(21, 4), (8, 3)],
+                         ids=["padded-bucket", "one-chunk-of-prompt"])
+def test_prefill_then_decode_through_the_state_kernel(monkeypatch, plen,
+                                                      chunk):
+    """The same check with the decode program's state update in the
+    KERNEL (interpret mode; a state of 128, whole lanes, so that the rule
+    holds and the engine's own arrays are what the kernel takes): the
+    state a prefill installed, advanced in place at [layer, slot] beside
+    two inactive slots, gives the reference's forward pass, and the
+    inactive slots' arrays are the bits they were (``_programs_logits``
+    checks)."""
+    config = dict(CONFIG, mamba_n_heads=8, mamba_d_ssm=64,
+                  mamba_d_state=128)
+    cfg = family.model_config(config)
+    params = make_params(cfg)
+    calls = []
+
+    def kernel(x, dt, a, b, c, states, layer, active):
+        assert ssm.state_kernel_engages(states)
+        calls.append(states.shape)
+        return ssm.ssm_state_step_kernel(x, dt, a, b, c, states, layer,
+                                         active, interpret=True)
+
+    monkeypatch.setattr(falcon_h1, "ssm_state_step", kernel)
+    prompt = np.random.default_rng(plen).integers(1, cfg.vocab_size, plen)
+    new = 9
+    got, tokens = _programs_logits(monkeypatch, cfg, params, prompt, new,
+                                   page=8, chunk=chunk)
+    assert calls and set(calls) == {(2, 3, 8, 8, 128)}
+    seq = np.concatenate([prompt, tokens[:-1]])[None]
+    want = np.asarray(family.logits(config, params, seq))[0, plen - 1:]
     np.testing.assert_allclose(got, want, rtol=PAGED_TOL, atol=PAGED_TOL)
     gap, _ = reference.token_gap(family.logits, config, params, prompt,
                                  tokens)

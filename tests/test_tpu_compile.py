@@ -204,17 +204,9 @@ _MOVE = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) (copy-start|copy-done|"
                    r"slice-start|slice-done|copy|custom-call)\(")
 
 
-def _stack_moves_in_loops(text, layers, d_in, widths):
-    """The instructions of a compiled program's loops (the computations
-    its ``while``s run, and whatever those call) that MOVE a stack of
-    projection weights, whole or in part: a copy, an asynchronous copy
-    or slice, or the ``ConcatBitcast`` that joins such slices, whose
-    result is ``bf16[k, d_in, width]`` for 1 <= k <= ``layers``. What the
-    compiler parks on the core (``S(1)`` in a layout) it may write back
-    and fetch again round a kernel that needs the room, every trip of
-    the loop: such traffic has no name of its own in a trace and shows
-    only here. A fusion that READS a layer of a stack in place is no
-    move."""
+def _loop_bodies(text):
+    """The instructions of a compiled program's loops: the lines of the
+    computations its ``while``s run, and of whatever those call."""
     bodies, name = {}, None
     for line in text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
@@ -237,17 +229,61 @@ def _stack_moves_in_loops(text, layers, d_in, widths):
         in_loops.add(comp)
         todo.extend(called(bodies[comp]))
     assert in_loops, "the program has no loop"
+    return [line for comp in in_loops for line in bodies[comp]]
+
+
+def _in_loops(text, instruction) -> int:
+    """How many instructions of the program's loops match."""
+    return sum(bool(instruction.search(line)) for line in _loop_bodies(text))
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$", re.M)
+# what hands an array on without touching it
+_HANDS_ON = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+             "call", "conditional", "opt-barrier"}
+
+
+def _state_passes(text, axes="32,128,256"):
+    """The instructions that pass over the recurrent state outside the
+    state kernel: whatever has a float32 array of the state's axes (a
+    layer's, or the stack) as its result or among its operands and does
+    more than hand it on. (The text names an instruction's operands, not
+    their types: an operand is one if the instruction that made it says
+    so.)"""
+    state = re.compile(rf"f32\[(?:\d+,)*{axes}\]")
+    found = _INSTRUCTION.findall(text)
+    holds = {name for name, result, _, _ in found
+             if not result.startswith("(") and state.match(result)}
+    return [f"{name} = {result[:60]} {op}" for name, result, op, rest in found
+            if op not in _HANDS_ON and not _STATE_KERNEL.search(
+                f"{name} = {result} {op}({rest}")
+            and (state.search(result)
+                 or holds & set(re.findall(r"%[\w.\-]+",
+                                           rest.split("), ")[0])))]
+
+
+def _stack_moves_in_loops(text, layers, d_in, widths):
+    """The instructions of a compiled program's loops (the computations
+    its ``while``s run, and whatever those call) that MOVE a stack of
+    projection weights, whole or in part: a copy, an asynchronous copy
+    or slice, or the ``ConcatBitcast`` that joins such slices, whose
+    result is ``bf16[k, d_in, width]`` for 1 <= k <= ``layers``. What the
+    compiler parks on the core (``S(1)`` in a layout) it may write back
+    and fetch again round a kernel that needs the room, every trip of
+    the loop: such traffic has no name of its own in a trace and shows
+    only here. A fusion that READS a layer of a stack in place is no
+    move."""
     stack = re.compile(
         rf"bf16\[(\d+),{d_in},(?:{'|'.join(map(str, widths))})\]")
     moves = []
-    for comp in in_loops:
-        for line in bodies[comp]:
-            m = _MOVE.match(line)
-            if not m or (m.group(2) == "custom-call"
-                         and "ConcatBitcast" not in line):
-                continue
-            if any(int(k) <= layers for k in stack.findall(m.group(1))):
-                moves.append(line.strip()[:160])
+    for line in _loop_bodies(text):
+        m = _MOVE.match(line)
+        if not m or (m.group(2) == "custom-call"
+                     and "ConcatBitcast" not in line):
+            continue
+        if any(int(k) <= layers for k in stack.findall(m.group(1))):
+            moves.append(line.strip()[:160])
     return moves
 
 
@@ -538,6 +574,11 @@ _H1_PROGRAMS = [("decode", (8, 8)), ("decode", (16, 8)),
                 ("prefill", (2, 1024, 8)), ("prefill", (2, 512, 4))]
 _STATE_COPY = re.compile(
     r"= f32\[(?:4,|1,)?128,32,128,256\]\S* (?:copy|copy-start)\(")
+# the state kernel's instruction, under its name: the stacked state among
+# its operands and, in place, among its results
+_STATE_KERNEL = re.compile(
+    r"%ssm_state_step[.\d]* = \(.*f32\[4,128,32,128,256\]\S*\) "
+    r"custom-call\(.*tpu_custom_call")
 
 
 @pytest.mark.parametrize(
@@ -553,9 +594,15 @@ def test_falcon_h1_d4_engine_programs_fit_beside_their_state(v5e_2x2,
     pools, 2.16 GB of state) fit a v5e with the program's temporaries
     beside them; the pools AND the slots' state are donated and come back
     in place; no instruction copies a layer's state, or the stack of
-    them, whole (the update reads a layer's through a slice fused into
-    its consumer and writes it through a fused update, in place; the
-    program's temporaries, under 1 GB, hold no second state of 2.1 GB)."""
+    them, whole. A decode program advances the state in ONE instruction a
+    layer-step, the state kernel under its name (PR 37), which takes the
+    stacked array and hands it back aliased: the layer loop holds no
+    other instruction that reads or writes an array of the state's axes
+    (XLA lowered the plain formulation to two fusions there, one that
+    read a layer's state through a fused slice and reduced it against
+    ``c``, one that read it again and wrote it through a fused update:
+    both are gone from the text), and the program's temporaries, under
+    1 GB, hold no second state of 2.1 GB."""
     from ray_tpu.models import falcon_h1
 
     cfg = dataclasses.replace(falcon_h1.falcon_h1_34b_instruct(),
@@ -574,8 +621,13 @@ def test_falcon_h1_d4_engine_programs_fit_beside_their_state(v5e_2x2,
             - mem.alias_size_in_bytes) < 13.5e9              # of 15.75 GB
     assert not _pool_copy(_H1_LAYERS, _H1_PAGES, 4).findall(text)
     assert bool(_DECODE_KERNEL.search(text)) == (program == "decode")
+    assert len(_STATE_KERNEL.findall(text)) == (program == "decode")
     if program == "decode":
         assert not _STATE_COPY.findall(text)
+        # the kernel's call is in the layer loop, once, and nothing else
+        # there (or anywhere) passes over the state
+        assert _in_loops(text, _STATE_KERNEL) == 1
+        assert not _state_passes(text)
         # five query heads a KV head through the decode kernel, and no
         # stack of projection weights moved in the loops: q | k | v are
         # one stack (147 MB), wo's (105 MB) is read where it lies
@@ -585,3 +637,59 @@ def test_falcon_h1_d4_engine_programs_fit_beside_their_state(v5e_2x2,
                                          (cfg.d_model,))
         assert not _stack_moves_in_loops(text, _H1_LAYERS, cfg.d_ssm,
                                          (cfg.d_model,))
+
+
+def test_the_plain_state_update_does_pass_over_the_state(v5e_2x2,
+                                                         monkeypatch):
+    """The fence above is not blind: the same decode program with the
+    plain formulation in the kernel's place (what every platform but the
+    TPU runs) holds no kernel call, and fusions in its layer loop whose
+    result is a layer's states reduced against ``c`` out of the stack,
+    and the stack itself written through a fused update: XLA's passes
+    over the state, which the kernel's program has none of."""
+    from ray_tpu.models import falcon_h1
+    from ray_tpu.ops import ssm
+
+    monkeypatch.setattr(falcon_h1, "ssm_state_step",
+                        ssm.ssm_state_step_reference)
+    cfg = dataclasses.replace(falcon_h1.falcon_h1_34b_instruct(),
+                              n_layers=_H1_LAYERS)
+    text = _lower_engine_program(
+        v5e_2x2[0], falcon_h1, cfg, _H1_PAGES, "decode", (8, 8),
+        slots=_H1_SLOTS).compile().as_text()
+    assert not _STATE_KERNEL.search(text)
+    passes = _state_passes(text)
+    assert any(" fusion" in p for p in passes), passes
+    stack = [p for p in passes if "= f32[4,128,32,128,256]" in p]
+    assert stack, passes
+
+
+@pytest.mark.parametrize("heads,width,size,groups", [
+    (32, 128, 256, 2), (24, 64, 128, 1), (80, 64, 128, 8), (6, 8, 128, 2)],
+    ids=["falcon-h1-34b", "24x64x128", "80x64x128", "six-tiny-heads"])
+def test_state_kernel_compiles_alone(v5e_2x2, heads, width, size, groups):
+    """The kernel by itself at the published head shapes and at others
+    its rule admits (heads in one block of 24, in five of 16, six heads
+    that are no whole block of 8): the chip's compiler takes the tiles,
+    and the stacked state is aliased from operand to result."""
+    from ray_tpu.ops import ssm
+
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    slots = 8
+    states = shape((2, slots, heads, width, size), jnp.float32)
+    assert ssm.state_kernel_engages(states)
+    compiled = jax.jit(ssm.ssm_state_step_kernel, donate_argnums=(5,)).lower(
+        shape((slots, heads, width), jnp.bfloat16),
+        shape((slots, heads), jnp.float32), shape((heads,), jnp.float32),
+        shape((slots, groups, size), jnp.bfloat16),
+        shape((slots, groups, size), jnp.bfloat16), states,
+        shape((), jnp.int32), shape((slots,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * slots * heads * width * size * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
