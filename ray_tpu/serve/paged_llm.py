@@ -143,6 +143,19 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   one has none): the decode program averages them over the chunk's
   layer-steps, and they go on the chunk's ``engine.emit`` span.
 
+- What the two programs compute and what reaches a client differ, and
+  the loop keeps the account (always on, integers in ``stats()``; on the
+  spans while spans are recorded). A decode chunk is ``chunk x
+  max_batch`` slot-steps whatever the slots hold; where it is read back
+  each is one of: DELIVERED (a token on a request's stream), OVERRUN
+  TAIL (a live slot's steps after its answer ended inside the chunk),
+  OVERRUN AHEAD (all of a live slot's steps where its answer had ended
+  in the chunk read before: the double buffer dispatches chunk N+1
+  before it reads chunk N), VACANT (slots not live at dispatch); the
+  four sum to the chunk's slot-steps, exactly. A prefill dispatch is
+  ``group x bucket`` token-rows, of which the rows' suffixes are prompt
+  tokens and the rest padding to the bucket.
+
 Threading: one engine thread owns the device loop (admission, prefill
 and decode dispatches, emission); a watcher thread blocks on each
 dispatch in stream order and stamps when the device ran it; callers
@@ -159,6 +172,7 @@ import time
 import uuid
 from collections import deque
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -175,6 +189,16 @@ from ray_tpu.ops.ssm import state_kernel_engages
 from ray_tpu.serve.llm import _STAGES, Request, _named_jit, _serve_hist
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import tracing as _tracing
+
+
+class _Chunk(NamedTuple):
+    """A dispatched decode chunk until its tokens are read back."""
+    toks: object          # [chunk, max_batch] tokens, on the device
+    active_idx: list      # the slots live at dispatch
+    gens: list            # their admission generations then
+    seq: int              # its place among the chunks (``_dispatch_seq``)
+    stream_seq: int       # among all dispatches (the spans' ``seq``)
+    drain: bool           # the short chunk (``_use_drain_chunk``)
 
 
 def _wall(mono: float) -> float:
@@ -435,6 +459,15 @@ class PagedLLMEngine:
             state_kernel_engages(a) for a in self._state)
         self.decode_dispatches = 0
         self.state_kernel_dispatches = 0
+        # what became of every slot-step the decode programs computed
+        # (chunk x max_batch a dispatch), counted where a chunk is read
+        # back (_sync_chunk): delivered + overrun_tail + overrun_ahead +
+        # vacant == slot_steps after every chunk
+        self.decode_slot_steps = 0
+        self.decode_delivered = 0       # a token on a request's stream
+        self.decode_overrun_tail = 0    # after the answer's end, same chunk
+        self.decode_overrun_ahead = 0   # a chunk in flight at the answer's end
+        self.decode_vacant = 0          # slots not live at dispatch
         self._table = np.full((self.max_batch, self.max_pages_per_seq),
                               -1, np.int32)
         self._alloc = PageAllocator(self.num_pages)
@@ -456,6 +489,11 @@ class PagedLLMEngine:
             run.window is None for run in plan)
         self.prefill_dispatches = 0
         self.prefill_kernel_dispatches = 0
+        # the token-rows the prefill programs computed (group x bucket a
+        # dispatch) and the prompt tokens among them (the suffixes past
+        # the cached prefixes); the rest is padding
+        self.prefill_token_rows = 0
+        self.prefill_new_tokens = 0
         # per dispatched decode chunk, the feed-forward's statistics on
         # the device until the chunk is emitted (_sync_chunk)
         self._chunk_stats: deque = deque()
@@ -963,8 +1001,11 @@ class PagedLLMEngine:
         kernel = self._kernel_backend and kernel_engages(
             (len(part), bucket, self.cfg.n_heads, self.cfg.head_dim),
             self._k_pages, wp, None)
+        token_rows, new_tokens = len(part) * bucket, int(slens_np.sum())
         self.prefill_dispatches += 1
         self.prefill_kernel_dispatches += int(kernel)
+        self.prefill_token_rows += token_rows
+        self.prefill_new_tokens += new_tokens
         if ph:
             # what the prefix cache gave this dispatch, counted as its
             # lookups were (PrefixCache.acquire): the full pages before
@@ -972,7 +1013,7 @@ class PagedLLMEngine:
             page, cached = self.page_size, int(starts_np.sum())
             lookups = (sum((it[2] - 1) // page for it in part)
                        if self._prefix_enabled else 0)
-            ph.set(window_pages=wp, new_tokens=int(slens_np.sum()),
+            ph.set(token_rows=token_rows, new_tokens=new_tokens,
                    cached_tokens=cached,
                    missed_pages=lookups - cached // page,
                    attn_kernel=int(kernel))
@@ -1029,19 +1070,18 @@ class PagedLLMEngine:
 
     def _admit(self, first: "Request | None" = None):
         with _tracing.phase("engine.admit", kind="serve") as ph:
-            admitted, dispatches = self._admit_round(first)
+            admitted = self._admit_round(first)
             if ph:
-                ph.set(admitted=admitted, dispatches=dispatches,
-                       blocked=self._admission_blocked)
+                ph.set(admitted=admitted)
 
-    def _admit_round(self, first: "Request | None") -> tuple:
+    def _admit_round(self, first: "Request | None") -> int:
         """Prefill waiting requests into free slots. All prefills of the
         round are DISPATCHED first and their first tokens extracted in
         one host pass — each sync has a fixed cost, so a burst of
         admissions pays ~one, not one per request. ``first``: a request
         already pulled off the queue (the admission window's timed get)
         — admitted ahead of the queue, requeued on backpressure like any
-        other. Returns (requests admitted, prefill dispatches)."""
+        other. Returns the number of requests admitted."""
         admits = []   # (req, slot, plen, padded)
         self._admission_blocked = False
         pulled = first
@@ -1074,7 +1114,7 @@ class PagedLLMEngine:
         if pulled is not None:
             self._waiting.put(pulled)   # no free slot took it
         if not admits:
-            return 0, 0
+            return 0
         self._admitting = [item[0] for item in admits]
         # Group by bucket, then split each group into POWER-OF-TWO
         # sub-batches: one batched-prefill dispatch per sub-batch (a
@@ -1138,7 +1178,7 @@ class PagedLLMEngine:
                 (self._dispatch_seq, part, firsts))
         self._admitting = []
         self._dev_dirty = True   # active set / lengths changed
-        return len(admits), len(batches)
+        return len(admits)
 
     def _drain_firsts(self, completed_seq: int | None = None):
         """Emit first tokens whose prefill results reached the host.
@@ -1427,7 +1467,6 @@ class PagedLLMEngine:
         with _tracing.phase("engine.dispatch_decode", kind="serve") as ph:
             drain = self._use_drain_chunk()
             chunk = self._drain_chunk if drain else self.decode_chunk
-            reupload = self._dev_inputs is None or self._dev_dirty
             dev = self._device_inputs(active_idx)
             pb = self._pages_bucket()
             table = ("table", pb)
@@ -1456,9 +1495,8 @@ class PagedLLMEngine:
                 # the live slots, from the host's own lengths: all of a
                 # slot's rows in a full layer, its window's in a sliding
                 rows = self._lengths[active_idx].astype(np.int64) + 1
-                ph.set(pages=pb, seq=stream_seq, chunk=chunk,
+                ph.set(seq=stream_seq, chunk=chunk, drain=drain,
                        live=len(active_idx), slots=self.max_batch,
-                       drain=drain, reupload=reupload,
                        kv_rows_full=int(rows.sum()))
                 if self._window is not None:
                     ph.set(kv_rows_window=int(
@@ -1487,45 +1525,75 @@ class PagedLLMEngine:
             seq = self._dispatch_seq
             self._dispatch_seq += 1
         self._ready_q.put(("decode", stream_seq, toks, now, (), ph or None))
-        return toks, active_idx, gens, chunk, seq
+        return _Chunk(toks, active_idx, gens, seq, stream_seq, drain)
 
-    def _emit_chunk(self, toks_np, active_idx, gens):
+    def _emit_chunk(self, toks_np, active_idx, gens) -> tuple:
+        """A chunk's tokens to the streams of the requests that still
+        wait for them. Returns the steps it computed for nobody, in the
+        slots that were live at its dispatch: (those after an answer's
+        end inside this chunk, those of slots whose answer had ended
+        before this chunk was read: the double buffer's price)."""
+        steps = toks_np.shape[0]
+        tail = ahead = 0
         for i, gen in zip(active_idx, gens):
             if self._slot_gen[i] != gen:
-                continue   # slot re-admitted since dispatch: the chunk's
-                # tokens belong to the RETIRED occupant, not this request
-            for t in range(toks_np.shape[0]):
+                # slot re-admitted since dispatch: the chunk's tokens
+                # belong to the RETIRED occupant, not this request
+                ahead += steps
+                continue
+            for t in range(steps):
                 req = self._active[i]
                 if req is None:
-                    break   # finished mid-chunk; drop surplus tokens
+                    # finished mid-chunk (drop the surplus tokens), or
+                    # in a chunk read before this one (drop them all)
+                    if t:
+                        tail += steps - t
+                    else:
+                        ahead += steps
+                    break
                 self._emit(req, int(toks_np[t, i]))
         # one chunk sync elapsed: age the deferred frees
         self._age_deferred_frees()
         self._publish_digest()
+        return tail, ahead
 
-    def _sync_chunk(self, toks, active_idx, gens, seq: int | None):
-        """Chunk N's host sync, then its tokens to their streams. Firsts
-        of prefills dispatched before the chunk (``seq``: before chunk
-        ``seq``; None: drained by the caller already) go out ahead of
-        it, so emission order per request is preserved."""
+    def _sync_chunk(self, chunk: _Chunk, firsts: bool):
+        """Chunk N's host sync, then its tokens to their streams, and
+        the account of its ``chunk x max_batch`` slot-steps. ``firsts``:
+        the first tokens of prefills dispatched before the chunk are
+        still to go out ahead of it (else the caller has drained them),
+        so emission order per request is preserved."""
         with _tracing.phase("engine.wait_device", kind="serve",
                             attrs={"what": "chunk"}):
-            toks_np = np.asarray(toks)
+            toks_np = np.asarray(chunk.toks)
         now = time.monotonic()
-        if seq is not None:
-            self._drain_firsts(completed_seq=seq)
+        if firsts:
+            self._drain_firsts(completed_seq=chunk.seq)
         with _tracing.phase("engine.emit", kind="serve") as ph:
             generated, finished = self.total_generated, self.total_finished
-            self._emit_chunk(toks_np, active_idx, gens)
+            tail, ahead = self._emit_chunk(toks_np, chunk.active_idx,
+                                           chunk.gens)
+            steps = toks_np.shape[0]
+            slot_steps = steps * self.max_batch
+            delivered = self.total_generated - generated
+            vacant = slot_steps - len(chunk.active_idx) * steps
+            self.decode_slot_steps += slot_steps
+            self.decode_delivered += delivered
+            self.decode_overrun_tail += tail
+            self.decode_overrun_ahead += ahead
+            self.decode_vacant += vacant
             # the feed-forward's statistics of this chunk (chunks are
             # emitted in the order they were dispatched). They came out
             # of the program whose tokens the loop has just read, so
             # reading them waits for nothing
             stats = self._chunk_stats.popleft() if self._chunk_stats else {}
             if ph:
-                ph.set(what="chunk",
-                       tokens=self.total_generated - generated,
+                ph.set(what="chunk", tokens=delivered,
                        finished=self.total_finished - finished,
+                       slot_steps=slot_steps,
+                       overrun_tail=tail, overrun_ahead=ahead,
+                       vacant=vacant, seq=chunk.stream_seq, chunk=steps,
+                       drain=chunk.drain,
                        **{name: float(v) for name, v in stats.items()})
         return now
 
@@ -1563,7 +1631,7 @@ class PagedLLMEngine:
         Each pass is one ``engine.iteration`` span while spans are
         recorded (``tracing.phase``), its phases its children: what the
         children leave uncovered is host work no phase names."""
-        pending = None   # (device_toks, active_idx, gens, chunk, seq)
+        pending = None   # the _Chunk in flight
         self._last_dev = jnp.asarray(self._last_tok)
         for n in itertools.count():
             if self._stop.is_set():
@@ -1597,8 +1665,7 @@ class PagedLLMEngine:
                 if not active_idx:
                     self._sync_t = None   # pipeline drains: period resets
                     if pending is not None:
-                        toks, idxs, gens, _, seq = pending
-                        self._sync_chunk(toks, idxs, gens, seq)
+                        self._sync_chunk(pending, firsts=True)
                     elif self._pending_firsts:
                         # every active request is brand-new and nothing
                         # is in flight (e.g. max_new_tokens=1 bursts):
@@ -1613,7 +1680,6 @@ class PagedLLMEngine:
             if first is None:
                 break
         nxt = self._dispatch_decode(active_idx)
-        toks_prev, idx_prev, gens_prev, _, _ = pending
         # EVERY pending prefill was dispatched before nxt: block for
         # their firsts now (bounded by chunk N + prefill compute —
         # chunk N+1 is already queued behind them, so this wait
@@ -1622,7 +1688,7 @@ class PagedLLMEngine:
         # first-token latency.
         self._drain_firsts(completed_seq=self._dispatch_seq)
         sync_t = self._sync_t
-        now = self._sync_chunk(toks_prev, idx_prev, gens_prev, None)
+        now = self._sync_chunk(pending, firsts=False)
         if sync_t is not None:
             period = now - sync_t
             self._chunk_period = (
@@ -1643,6 +1709,16 @@ class PagedLLMEngine:
             "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
             "decode_dispatches": self.decode_dispatches,
             "state_kernel_dispatches": self.state_kernel_dispatches,
+            # every slot-step the decode programs computed, by what
+            # became of it, and the prefill programs' token-rows with
+            # the prompt tokens among them (see __init__)
+            "decode_slot_steps": self.decode_slot_steps,
+            "decode_delivered": self.decode_delivered,
+            "decode_overrun_tail": self.decode_overrun_tail,
+            "decode_overrun_ahead": self.decode_overrun_ahead,
+            "decode_vacant": self.decode_vacant,
+            "prefill_token_rows": self.prefill_token_rows,
+            "prefill_new_tokens": self.prefill_new_tokens,
             # recurrent state beside the pages (0 where the plan has no
             # recurrent run): rows whose state a prefill wrote into a
             # slot, and the bytes the slots' state arrays hold

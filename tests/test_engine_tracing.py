@@ -5,6 +5,7 @@ timeline from the watcher thread, and the five TTFT stages. All on the
 CPU with the tiny llama config."""
 
 import re
+import sys
 import threading
 import time
 from functools import partial
@@ -23,6 +24,11 @@ from ray_tpu.util import tracing
 PHASES = {"engine.admit", "engine.dispatch_prefill", "engine.wait_arrivals",
           "engine.dispatch_decode", "engine.wait_device", "engine.emit"}
 PAGE = 16
+# stats()' account of what the two programs computed (always on)
+DECODE_ACCOUNT = ("decode_slot_steps", "decode_delivered",
+                  "decode_overrun_tail", "decode_overrun_ahead",
+                  "decode_vacant")
+PREFILL_ACCOUNT = ("prefill_token_rows", "prefill_new_tokens")
 
 
 @pytest.fixture(scope="module")
@@ -33,8 +39,9 @@ def tiny():
 
 def make_engine(tiny, **kwargs):
     cfg, params = tiny
-    return PagedLLMEngine(cfg, params, max_batch=4, max_len=128,
-                          page_size=PAGE, num_pages=40, **kwargs)
+    kwargs.setdefault("max_batch", 4)
+    return PagedLLMEngine(cfg, params, max_len=128, page_size=PAGE,
+                          num_pages=40, **kwargs)
 
 
 def clear_ring():
@@ -43,10 +50,35 @@ def clear_ring():
     flight.clear()
 
 
+def wait_idle(eng, timeout=60.0):
+    """Until the loop thread stands in its idle poll: then every chunk it
+    dispatched has been read back, and the counters and the ring rest."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        frame = sys._current_frames().get(eng._thread.ident)
+        while frame is not None:
+            if frame.f_code.co_name == "_wait_idle":
+                return
+            frame = frame.f_back
+        time.sleep(0.005)
+    raise AssertionError("the engine loop did not go idle")
+
+
+def account(eng) -> dict:
+    stats = eng.stats()
+    return {k: stats[k] for k in DECODE_ACCOUNT + PREFILL_ACCOUNT}
+
+
+def chunk_emits(spans) -> list:
+    return [s["attrs"] for s in spans if s["name"] == "engine.emit"
+            and s["attrs"]["what"] == "chunk"]
+
+
 @pytest.fixture(scope="module")
 def profiled(tiny, tmp_path_factory):
     """A toy paged engine that served two waves of requests while a
-    profiler session was live: (spans, requests, prefix-cache stats)."""
+    profiler session was live: (spans, requests, what ``stats()``'
+    prefix-cache counts and dispatch accounts rose by meanwhile)."""
     eng = make_engine(tiny)
     eng.start()
     rng = np.random.default_rng(0)
@@ -56,8 +88,9 @@ def profiled(tiny, tmp_path_factory):
         return np.concatenate([shared, rng.integers(1, 500, n)])
 
     list(eng.submit(prompt(9), max_new_tokens=20).tokens())    # compiles
+    wait_idle(eng)      # the chunk in flight at its end has been read
     clear_ring()
-    stats0 = dict(eng.stats()["prefix_cache"])
+    stats0 = dict(eng.stats()["prefix_cache"], **account(eng))
     jax.profiler.start_trace(str(tmp_path_factory.mktemp("profile")))
     try:
         reqs = []
@@ -68,26 +101,28 @@ def profiled(tiny, tmp_path_factory):
                 assert len(list(r.tokens())) == 20
             reqs += batch
         # let the engine go idle, then end the idle wait with one more
-        # request (however loaded the machine, the idle span is there)
+        # request (however loaded the machine, an idle span of half the
+        # pause is there in the end: each further request ends one more)
         for _ in range(50):
             time.sleep(0.1)
             reqs.append(eng.submit(prompt(7), max_new_tokens=4))
             assert len(list(reqs[-1].tokens())) == 4
-            if any(s["attrs"].get("what") == "idle"
+            if any(s["attrs"].get("what") == "idle" and s["duration"] > 0.05
                    for s in tracing.recorded_spans("engine.wait_arrivals")):
                 break
         eng.stop()
     finally:
         jax.profiler.stop_trace()
-    stats1 = eng.stats()["prefix_cache"]
+    stats1 = dict(eng.stats()["prefix_cache"], **account(eng))
     spans = tracing.recorded_spans()
     clear_ring()
-    hits = {k: stats1[k] - stats0[k] for k in ("hit_pages", "miss_pages")}
-    return spans, reqs, hits
+    rose = {k: stats1[k] - stats0[k] for k in (
+        "hit_pages", "miss_pages") + DECODE_ACCOUNT + PREFILL_ACCOUNT}
+    return spans, reqs, rose
 
 
 def test_loop_phases_are_children_of_their_iteration(profiled):
-    spans, _, _ = profiled
+    spans, reqs, _ = profiled
     by_id = {s["span_id"]: s for s in spans}
     iterations = [s for s in spans if s["name"] == "engine.iteration"]
     assert len(iterations) >= 5
@@ -115,7 +150,9 @@ def test_loop_phases_are_children_of_their_iteration(profiled):
     # an idle engine is ONE wait span, however long it idles
     idle = [s for s in spans if s["name"] == "engine.wait_arrivals"
             and s["attrs"]["what"] == "idle"]
-    assert 1 <= len(idle) <= 4 and max(s["duration"] for s in idle) > 0.05
+    pauses = len(reqs) - 6          # the requests after the two waves
+    assert 1 <= len(idle) <= max(4, pauses)
+    assert max(s["duration"] for s in idle) > 0.05
     assert [s["attrs"]["seq"] for s in iterations] == sorted(
         s["attrs"]["seq"] for s in iterations)
 
@@ -128,14 +165,13 @@ def test_every_dispatch_has_counts_and_one_device_run(profiled):
     assert prefills and decodes
     assert sum(s["attrs"]["group"] for s in prefills) == len(reqs)
     for s in prefills:
-        assert {"seq", "group", "bucket", "window_pages", "new_tokens",
+        assert {"seq", "group", "bucket", "token_rows", "new_tokens",
                 "cached_tokens", "missed_pages",
                 "attn_kernel"} <= set(s["attrs"])
         assert s["attrs"]["attn_kernel"] == 0       # lowered for the CPU
     for s in decodes:
         a = s["attrs"]
-        assert {"seq", "chunk", "live", "slots", "pages", "drain",
-                "reupload"} <= set(a)
+        assert {"seq", "chunk", "live", "slots", "drain"} <= set(a)
         assert 1 <= a["live"] <= a["slots"] == 4
     # stream order: sequence numbers rise with the dispatch time
     dispatches = sorted(prefills + decodes, key=lambda s: s["start"])
@@ -188,6 +224,156 @@ def test_prefix_miss_pages_are_the_pages_past_the_first_miss(profiled):
     lookups = sum((len(r.prompt) - 1) // PAGE for r in reqs)
     assert cached + missed == lookups and cached >= 3 * len(reqs)
     assert missed > 0
+
+
+def test_every_chunks_slot_steps_are_accounted_for(profiled):
+    """Each ``engine.emit`` span of a chunk classifies all ``chunk x
+    max_batch`` slot-steps of its dispatch, exactly, and says which
+    dispatch that was: ``seq``, ``chunk`` and ``drain`` are its
+    ``engine.dispatch_decode`` span's, and ``vacant`` the slots that span
+    did not count live."""
+    spans, _, rose = profiled
+    emits = chunk_emits(spans)
+    assert len(emits) >= 5
+    decodes = {s["attrs"]["seq"]: s["attrs"] for s in spans
+               if s["name"] == "engine.dispatch_decode"}
+    for a in emits:
+        assert a["slot_steps"] == a["chunk"] * 4
+        assert (a["tokens"] + a["overrun_tail"] + a["overrun_ahead"]
+                + a["vacant"]) == a["slot_steps"]
+        assert min(a["tokens"], a["overrun_tail"], a["overrun_ahead"],
+                   a["vacant"]) >= 0
+        d = decodes[a["seq"]]
+        assert (a["chunk"], a["drain"]) == (d["chunk"], d["drain"])
+        assert a["vacant"] == (d["slots"] - d["live"]) * d["chunk"]
+    # both kinds of overrun occur in two waves of 20-token answers
+    assert sum(a["overrun_tail"] for a in emits) > 0
+    assert sum(a["overrun_ahead"] for a in emits) > 0
+    assert (rose["decode_delivered"] + rose["decode_overrun_tail"]
+            + rose["decode_overrun_ahead"] + rose["decode_vacant"]
+            ) == rose["decode_slot_steps"] > 0
+
+
+def test_dispatch_accounts_on_the_spans_equal_stats(profiled):
+    """Over the profiled run the spans' sums are what ``stats()``' seven
+    integers rose by (they count where the spans are set, spans or no)."""
+    spans, reqs, rose = profiled
+    emits = chunk_emits(spans)
+    for key, attr in zip(DECODE_ACCOUNT, ("slot_steps", "tokens",
+                                          "overrun_tail", "overrun_ahead",
+                                          "vacant")):
+        assert sum(a[attr] for a in emits) == rose[key], key
+    prefills = [s["attrs"] for s in spans
+                if s["name"] == "engine.dispatch_prefill"]
+    for a in prefills:
+        assert a["token_rows"] == a["group"] * a["bucket"] >= a["new_tokens"]
+    assert sum(a["token_rows"] for a in prefills) == \
+        rose["prefill_token_rows"]
+    assert sum(a["new_tokens"] for a in prefills) == \
+        rose["prefill_new_tokens"]
+    # every token but a request's first comes out of a decode chunk
+    assert rose["decode_delivered"] == sum(r.generated - 1 for r in reqs)
+
+
+def _served(tiny, submit, **engine):
+    """Requests handed over BEFORE the loop starts (so what it admits
+    when does not hang on the clock), served to their ends with tracing
+    on: (the chunks' emit spans in order, the engine's account)."""
+    eng = make_engine(tiny, **engine)
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        reqs = submit(eng)
+        eng.start()
+        for r, n in reqs:
+            assert len(list(r.tokens())) == n
+        wait_idle(eng)
+        eng.stop()
+        emits = chunk_emits(tracing.recorded_spans("engine.emit"))
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    return emits, account(eng)
+
+
+@pytest.mark.parametrize("chunk,tokens,tail", [
+    (4, 7, 2),      # first token, a chunk of 4, then 2 of the next 4
+    (4, 9, 0),      # ends with the last step of its second chunk
+    (16, 20, 13),   # the default chunk: 16, then 3 of 16
+    (1, 3, 0)], ids=["mid-chunk", "chunk-end", "default-chunk", "chunk-1"])
+def test_an_answers_end_costs_its_chunks_tail_and_the_chunk_in_flight(
+        tiny, chunk, tokens, tail):
+    """One request alone in an engine of two slots: its answer's tokens
+    after the first come out of whole chunks; the steps after its end in
+    its last chunk are ``overrun_tail``; the chunk dispatched before
+    that one was read is ``overrun_ahead``, all of it; the other slot is
+    ``vacant`` throughout."""
+    def submit(eng):
+        return [(eng.submit(np.arange(1, 20), max_new_tokens=tokens),
+                 tokens)]
+
+    emits, total = _served(tiny, submit, decode_chunk=chunk, max_batch=2)
+    live = -(-(tokens - 1) // chunk)         # chunks that deliver
+    want = [(chunk, 0, 0)] * (live - 1) + [(chunk - tail, tail, 0),
+                                           (0, 0, chunk)]
+    assert [(a["tokens"], a["overrun_tail"], a["overrun_ahead"])
+            for a in emits] == want
+    assert all(a["vacant"] == chunk and a["slot_steps"] == 2 * chunk
+               and a["chunk"] == chunk for a in emits)
+    assert [total[k] for k in DECODE_ACCOUNT] == [
+        2 * chunk * (live + 1), tokens - 1, tail, chunk,
+        chunk * (live + 1)]
+
+
+def test_a_refilled_slots_chunk_in_flight_is_overrun_ahead(tiny):
+    """One slot, two requests: A (7 tokens) ends two steps into its
+    second chunk; the chunk in flight then belongs to A, is read after B
+    took the slot (its generation changed) and is ``overrun_ahead``
+    whole; B (5 tokens) ends with its chunk's last step, and the chunk
+    in flight behind it finds the slot empty at step 0."""
+    def submit(eng):
+        return [(eng.submit(np.arange(1, 20), max_new_tokens=7), 7),
+                (eng.submit(np.arange(30, 45), max_new_tokens=5), 5)]
+
+    emits, total = _served(tiny, submit, decode_chunk=4, max_batch=1)
+    assert [(a["tokens"], a["overrun_tail"], a["overrun_ahead"], a["vacant"])
+            for a in emits] == [(4, 0, 0, 0), (2, 2, 0, 0), (0, 0, 4, 0),
+                                (4, 0, 0, 0), (0, 0, 4, 0)]
+    assert [total[k] for k in DECODE_ACCOUNT] == [20, 10, 2, 8, 0]
+    # the seqs are the stream's: two prefills lie among the five chunks
+    seqs = [a["seq"] for a in emits]
+    assert seqs == sorted(seqs) and seqs[-1] == 6
+
+
+def test_prefill_rows_are_group_times_bucket_and_new_tokens_the_suffixes(
+        tiny):
+    """``prefill_token_rows`` / ``prefill_new_tokens`` against the prompts
+    submitted: a prompt is padded to its power-of-two bucket, a prefix
+    hit leaves only the suffix past the cached pages to compute, and
+    prompts of one bucket admitted together are one dispatch of ``group
+    x bucket`` rows."""
+    rng = np.random.default_rng(4)
+    shared = rng.integers(1, 500, 3 * PAGE)
+    eng = make_engine(tiny)
+    eng.start()
+    for own, rows, new in ((9, 64, 57), (5, 16, 5)):
+        before = account(eng)
+        prompt = np.concatenate([shared, rng.integers(1, 500, own)])
+        assert len(list(eng.submit(prompt, max_new_tokens=2).tokens())) == 2
+        after = account(eng)
+        assert after["prefill_token_rows"] - before["prefill_token_rows"] \
+            == rows
+        assert after["prefill_new_tokens"] - before["prefill_new_tokens"] \
+            == new
+    eng.stop()
+
+    def submit(eng):        # 20 and 30 tokens: one dispatch of 2 x 32
+        return [(eng.submit(rng.integers(1, 500, n), max_new_tokens=2), 2)
+                for n in (20, 30)]
+
+    _, total = _served(tiny, submit)
+    assert (total["prefill_token_rows"], total["prefill_new_tokens"]) == (
+        64, 50)
 
 
 def test_prefill_dispatches_say_whether_their_program_holds_the_kernel(
@@ -362,6 +548,24 @@ def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):
     # the stamps behind the breakdown are always on
     assert req.start_t is not None and req.ready_t >= req.start_t
     assert req.breakdown.keys() == {f"{s}_s" for s in _STAGES}
+
+
+def test_the_accounts_count_with_no_session_and_tracing_off(tiny):
+    """The seven integers are the operator's: they count whether or not
+    a span is recorded, and the ring stays empty."""
+    clear_ring()
+    assert not tracing.recording()
+    eng = make_engine(tiny, decode_chunk=4)
+    eng.start()
+    assert len(list(eng.submit(np.arange(1, 30),
+                               max_new_tokens=7).tokens())) == 7
+    wait_idle(eng)
+    eng.stop()
+    assert tracing.recorded_spans() == []
+    assert account(eng) == dict(
+        decode_slot_steps=48, decode_delivered=6, decode_overrun_tail=2,
+        decode_overrun_ahead=4, decode_vacant=36,
+        prefill_token_rows=32, prefill_new_tokens=29)
 
 
 def test_phase_follows_enable_tracing_and_costs_nothing_off():
