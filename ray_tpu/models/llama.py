@@ -19,6 +19,7 @@ Design (deliberately NOT a port of any torch module tree):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from functools import partial
 from typing import NamedTuple
@@ -27,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.norms import rms_norm
@@ -374,7 +376,8 @@ def lm_head_weights(cfg, params):
 
 
 def fused_cross_entropy(cfg, params, hidden, targets, *, mask=None,
-                        chunk: int = 1024, z_loss: float = 0.0):
+                        chunk: int = 1024, z_loss: float = 0.0,
+                        mesh=None, rows=(), vocab_axes=()):
     """CE loss WITHOUT materializing the full [b, s, vocab] fp32 logits
     (2+ GB at 8x2048x32k): the LM-head matmul + logsumexp run per
     sequence chunk inside a checkpointed scan, so peak memory is one
@@ -384,8 +387,25 @@ def fused_cross_entropy(cfg, params, hidden, targets, *, mask=None,
 
     hidden: [b, s, d] from forward_hidden; targets [b, s] int; mask
     [b, s] in {0,1}.
+
+    ``vocab_axes`` (with ``mesh`` and ``rows``, the mesh axes that split b
+    and those that split s: what ``parallel.sharding.loss_layout`` reads
+    from the mesh and the rules) splits the vocabulary over those mesh
+    axes inside the loss: see ``_vocab_split_cross_entropy``. Left to the
+    partitioner under fsdp (the head split on d, the axis the logits
+    contract over), every chunk's fp32 logits are all-reduced, forward
+    and backward: 12.6% of the four-chip step's device time, and the
+    step 15% slower (PERF.md, PR 39). On one chip at a 32k vocabulary the
+    recompute costs 3.4% against the dense loss (same place).
     """
     head = lm_head_weights(cfg, params)
+    if vocab_axes:
+        if mask is None:
+            mask = targets >= 0
+        return _vocab_split_cross_entropy(
+            head, hidden, jnp.maximum(targets, 0), mask.astype(jnp.float32),
+            chunk=chunk, z_loss=z_loss, mesh=mesh, rows=rows,
+            vocab_axes=vocab_axes)
     b, s, d = hidden.shape
     n = b * s
     xm = hidden.reshape(n, d)
@@ -422,6 +442,95 @@ def fused_cross_entropy(cfg, params, hidden, targets, *, mask=None,
         jax.checkpoint(body), (jnp.float32(0.0), jnp.float32(0.0)),
         (xc, tc, mc))
     return total / jnp.maximum(count, 1.0)
+
+
+def _vocab_split_cross_entropy(head, hidden, targets, mask, *, chunk, z_loss,
+                               mesh, rows, vocab_axes):
+    """``fused_cross_entropy`` with the vocabulary split over ``vocab_axes``
+    and the rows ([b, s], split over ``rows``' axes) gathered chunk by chunk:
+    every device takes each chunk whole against its own [d, vocab / n]
+    slice of the head. The same fp32 logits for every row and id as on one
+    device, the same masked mean; only the order of the sums differs.
+
+    What crosses the mesh: the head, resharded once a step (and its
+    gradient back); a chunk's rows, gathered in their own type; three fp32
+    vectors a chunk (the rows' maxima, their sums of exponentials with the
+    targets' logits, the backward's cotangent of the rows' losses); and the
+    chunk's hidden cotangent, summed over the devices in fp32, each device
+    keeping its own rows, and rounded once."""
+    row_axes = tuple(a for axes in rows for a in axes)
+    rows = P(*(axes or None for axes in rows))
+    vocab_only = tuple(a for a in vocab_axes if a not in row_axes)
+    # a device's rows of a chunk
+    c_loc = max(1, chunk // math.prod(mesh.shape[a] for a in row_axes))
+
+    @jax.custom_vjp
+    def chunk_logits(x, w):
+        return chunk_logits_fwd(x, w)[0]
+
+    def chunk_logits_fwd(x, w):
+        x = lax.all_gather(x, row_axes, axis=0, tiled=True)
+        return jnp.einsum("cd,dv->cv", x, w,
+                          preferred_element_type=jnp.float32), (x, w)
+
+    def chunk_logits_bwd(res, g):
+        # autodiff would round each device's partial cotangent to the
+        # rows' type BEFORE the sum over the devices
+        x, w = res
+        dx = lax.dot_general(g, w, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        dx = lax.psum_scatter(dx, row_axes, scatter_dimension=0, tiled=True)
+        if vocab_only:
+            dx = lax.psum(dx, vocab_only)
+        dw = lax.dot_general(x, g, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        return dx.astype(x.dtype), dw.astype(w.dtype)
+
+    chunk_logits.defvjp(chunk_logits_fwd, chunk_logits_bwd)
+
+    def local_loss(head, hidden, targets, mask):
+        d = hidden.shape[-1]
+        pad = (-hidden.shape[0] * hidden.shape[1]) % c_loc
+
+        def chunks(a, *tail):      # the device's rows, masked padding last
+            a = a.reshape(-1, *tail)
+            a = jnp.concatenate([a, jnp.zeros((pad, *tail), a.dtype)])
+            return a.reshape(-1, c_loc, *tail)
+
+        xc, mc = chunks(hidden, d), chunks(mask)
+        tc = lax.all_gather(chunks(targets), row_axes, axis=1, tiled=True)
+        first_id = lax.axis_index(vocab_axes) * head.shape[1]
+        first_row = lax.axis_index(row_axes) * c_loc
+
+        def body(carry, inp):
+            x_i, t_i, m_i = inp
+            logits = chunk_logits(x_i, head)
+            top = lax.pmax(lax.stop_gradient(logits.max(axis=-1)),
+                           vocab_axes)
+            ids = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            sums, tl = lax.psum(
+                (jnp.exp(logits - top[:, None]).sum(axis=-1),
+                 jnp.where(ids == (t_i - first_id)[:, None], logits,
+                           0.0).sum(axis=-1)), vocab_axes)
+            lse = top + jnp.log(sums)
+            nll = lse - tl
+            if z_loss > 0.0:
+                nll = nll + z_loss * jnp.square(lse)
+            # every device holds the chunk's losses: each sums its own rows
+            nll = lax.dynamic_slice_in_dim(nll, first_row, c_loc)
+            total, count = carry
+            return (total + jnp.sum(nll * m_i), count + jnp.sum(m_i)), None
+
+        zero = lax.pcast(jnp.float32(0.0), row_axes, to="varying")
+        (total, count), _ = lax.scan(jax.checkpoint(body), (zero, zero),
+                                     (xc, tc, mc))
+        total, count = lax.psum((total, count), row_axes)
+        return total / jnp.maximum(count, 1.0)
+
+    return jax.shard_map(
+        local_loss, mesh=mesh,
+        in_specs=(P(None, vocab_axes), P(*rows, None), rows, rows),
+        out_specs=P())(head, hidden, targets, mask)
 
 
 def cross_entropy_loss(logits, targets, *, mask=None, z_loss: float = 0.0):

@@ -23,6 +23,7 @@ Logical axes used by the model library:
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from typing import Any
 
@@ -154,6 +155,29 @@ def logical_sharding(logical: tuple, mesh: Mesh, rules: ShardingRules) -> NamedS
     """NamedSharding for one array annotated with logical axis names."""
     spec = _filter_spec_for_mesh(rules.mesh_axes(logical), mesh)
     return NamedSharding(mesh, spec)
+
+
+def loss_layout(mesh: Mesh, rules: ShardingRules,
+                vocab_size: int) -> tuple[tuple, tuple]:
+    """How the fused loss lies on the mesh: ``(rows, vocab_axes)``, the
+    mesh axes that split the hidden state's batch and its seq (a tuple
+    each) and those that split the vocabulary inside the loss: those the
+    rules split it over already, then those that split the rows. Every
+    device then takes each chunk of rows whole against its own slice of
+    the head, contracting over all of the model dimension, and what
+    crosses the mesh has the rows' shape, never the logits'.
+    ``vocab_axes`` is ``()`` where the rows lie on one device or the axes'
+    size does not divide the vocabulary: the loss is then left to the
+    partitioner as it stands. Axes of one device are left out of both."""
+    spec = logical_sharding(("vocab", "batch", "seq"), mesh, rules).spec
+    by_dim = [tuple(a for a in ((e,) if isinstance(e, str) else e or ())
+                    if mesh.shape[a] > 1) for e in spec]
+    rows = tuple(by_dim[1:])
+    vocab_axes = tuple(a for axes in by_dim for a in axes)
+    if (not any(rows)
+            or vocab_size % math.prod(mesh.shape[a] for a in vocab_axes)):
+        vocab_axes = ()
+    return rows, vocab_axes
 
 
 def tree_shardings(logical_tree, mesh: Mesh, rules: ShardingRules):

@@ -14,6 +14,7 @@ scaling options, call ``fit()``/``train_step()``, receive metrics.
 
 from __future__ import annotations
 
+import math
 import time
 from dataclasses import dataclass, field
 from functools import partial
@@ -30,9 +31,11 @@ from ray_tpu.parallel.sharding import (
     PRESETS,
     ShardingRules,
     batch_sharding,
+    loss_layout,
     tree_shardings,
 )
 from ray_tpu.train.state import TrainState, state_logical_axes
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -53,8 +56,12 @@ class TrainConfig:
     # Fused chunked cross-entropy: never materializes the [B, S, vocab]
     # fp32 logits — chunked LM-head matmul + logsumexp in a checkpointed
     # scan. Essential at Llama-3 vocab scale (128k vocab = 8 GB of fp32
-    # logits at 8x2048); at 32k vocab the recompute overhead measured
-    # ~4% SLOWER on v5e, so it's opt-in.
+    # logits at 8x2048). At a 32k vocab on one v5e the recompute costs
+    # 3.4% (19,016 against the dense loss's 19,683 tokens/s/chip at 6 x
+    # 2048, d4 widths; PERF.md, PR 39), so it's opt-in. Over a mesh whose
+    # batch axes hold several devices it splits the vocabulary over them
+    # (loss_vocab_axes): 7,948 tokens/s/chip over {"fsdp": 4} at 16 x
+    # 2048, d10 widths, against 6,884 with the loss left to the partitioner.
     fused_loss: bool = False
     loss_chunk: int = 1024
     # Pipeline parallelism (strategy="pp_fsdp"): microbatch count (default
@@ -110,6 +117,12 @@ class JaxTrainer:
             and self.mesh.shape[sp] > 1 else "auto"
         )
         self.sp_axis = sp if self.attn_impl == "ring" else "sp"
+        # The mesh axes the fused loss splits the vocabulary over (with
+        # the rows' spec beside them), read from the mesh, the rules and
+        # the head's shape; () is the plain path, left to the partitioner.
+        self.loss_rows, self.loss_vocab_axes = (
+            loss_layout(self.mesh, self.rules, model_cfg.vocab_size)
+            if cfg.fused_loss else ((), ()))
         # Pipeline parallelism: active when the rules map the stacked-layer
         # dim onto a mesh axis that exists with size > 1.
         ppax = self.rules.layers
@@ -240,7 +253,8 @@ class JaxTrainer:
                 sp_axis=self.sp_axis)
             return llama.fused_cross_entropy(
                 self.model_cfg, params, hidden, targets, mask=mask,
-                chunk=self.cfg.loss_chunk)
+                chunk=self.cfg.loss_chunk, mesh=self.mesh,
+                rows=self.loss_rows, vocab_axes=self.loss_vocab_axes)
         logits = llama.forward(self.model_cfg, params, inputs,
                                segment_ids=segment_ids,
                                attn_impl=self.attn_impl,
@@ -345,12 +359,18 @@ class JaxTrainer:
         step = self._jit_step.get(key)
         if step is None:
             donate = (0,) if self.cfg.donate_state else ()
-            step = jax.jit(
-                self._step,
-                # state keeps its shardings
-                in_shardings=(None, self._batch_shardings(batch)),
-                donate_argnums=donate,
-            )
+            # the layout is chosen as the step is traced, once a compiled
+            # step: the span states it where a counter could only say 1
+            with tracing.phase("train.compile_step", kind="train", attrs={
+                    "loss_vocab_axes": list(self.loss_vocab_axes),
+                    "loss_vocab_shards": math.prod(
+                        self.mesh.shape[a] for a in self.loss_vocab_axes)}):
+                step = jax.jit(
+                    self._step,
+                    # state keeps its shardings
+                    in_shardings=(None, self._batch_shardings(batch)),
+                    donate_argnums=donate,
+                )
             self._jit_step[key] = step
         return step
 
