@@ -209,6 +209,16 @@ class LayerStack(NamedTuple):
     # RecurrentState``: the arrays' shapes and dtypes, the scan's chunk);
     # None: pages and nothing else
     state: tuple | None = None
+    # what a token keeps in a page of a layer of the run, where that is
+    # not a K row and a V row a KV head: the rows, one pool each
+    # (``ops/paged_attention.py:PageRow``: name, width, dtype), as a
+    # latent-attention layer's compressed row and its indexer's key;
+    # None: the K/V twins
+    rows: tuple | None = None
+    # keys a query attends over at most, where the layers pick them (a
+    # learned selection: the rows then hold an index key); None: all it
+    # may see
+    selects: int | None = None
 
 
 def layer_plan(cfg) -> tuple:

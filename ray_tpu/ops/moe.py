@@ -149,11 +149,17 @@ def moe_ffn_dropless(
     routed_scale: float = 1.0,
     first_expert: int = 0,
     valid=None,         # [T] bool: rows that are tokens (None: all)
+    scoring: str = "softmax",
+    choice_bias=None,   # [E] float32, added to the scores for the choice
 ):
     """Exact routed SwiGLU feed-forward. Returns (out [T, D], load [H]).
 
-    ``p = softmax(float32(x) @ router_w)`` over all E experts; the
-    ``top_k`` largest and their experts; the weights are those
+    ``p = softmax(float32(x) @ router_w)`` over all E experts (with
+    ``scoring="sigmoid"`` each expert's sigmoid of its own logit); the
+    ``top_k`` largest and their experts (with ``choice_bias`` the
+    ``top_k`` of largest ``p + choice_bias``, weighed by ``p`` alone: a
+    correction that balances the load moves the choice and no weight);
+    the weights are those
     probabilities as they are, or divided by their sum with
     ``norm_topk_prob``, times ``routed_scale``;
     ``out = sum_k p_k * (silu(x @ gate_k) * (x @ up_k)) @ down_k`` over
@@ -165,6 +171,9 @@ def moe_ffn_dropless(
     zero. ``load`` counts the (token, choice) pairs each held expert got
     (int32).
     """
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
+                         f"got {scoring!r}")
     t, d = x.shape
     e = wi_gate.shape[0]
     dtype = x.dtype
@@ -173,10 +182,19 @@ def moe_ffn_dropless(
         # bf16 and now and then pick another k-th expert than float32 does
         logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, top_k)     # [T, K]
+        probs = (jax.nn.softmax(logits, axis=-1) if scoring == "softmax"
+                 else jax.nn.sigmoid(logits))
+        if choice_bias is None:
+            gate_vals, gate_idx = jax.lax.top_k(probs, top_k)  # [T, K]
+        else:
+            _, gate_idx = jax.lax.top_k(
+                probs + choice_bias.astype(jnp.float32), top_k)
+            gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
         if norm_topk_prob:
-            gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+            total = jnp.sum(gate_vals, axis=-1, keepdims=True)
+            if scoring == "sigmoid":
+                total = total + 1e-20    # sigmoids can all be 0; a
+            gate_vals = gate_vals / total   # softmax's top-k cannot
         if routed_scale != 1.0:
             gate_vals = gate_vals * routed_scale
         if e < router_w.shape[1]:

@@ -20,6 +20,19 @@ Layout:
                       token and head: ``quantize_kv``)
     page_table:       [B, max_pages_per_seq] int32 (-1 = unused)
 
+A layer that keeps ONE ROW a token and no K/V twins (latent attention:
+the compressed KV with its rotary key; an indexer's key) has pools of
+    rows:             [L, n_pages, page_size, lanes], bf16
+one a kind of row, over the layers that keep that kind, addressed by the
+same page table: a page id names the same ``page_size`` tokens in every
+pool, so one allocator and one prefix cache serve them all
+(``row_pool``, ``write_rows``, ``gather_rows``). ``lanes`` is the row's
+width rounded up to whole lanes of ``ROW_LANES``, the rest zero: the
+chip tiles an array's last axis in lanes of 128 anyway, and a pool whose
+last axis is not whole lanes is copied whole at the entry of every
+program that scatters into it (a v5e compile of the decode program over
+576- and 1,088-wide pools: 3.2 GB of temporaries, none at 640 and 1,152).
+
 Token ``pos`` of a sequence lives at ``[layer, table[pos // page_size],
 pos % page_size]``. ``write_kv`` scatters new rows there (an index
 outside the pool, which is how a caller names a dead slot or a hole,
@@ -33,9 +46,18 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
+
+
+class PageRow(NamedTuple):
+    """One row a token keeps in a page of a layer that keeps no K/V twins
+    (a layer plan's run states its rows: ``LayerStack.rows``)."""
+    name: str
+    width: int
+    dtype: str
 
 
 class PageAllocator:
@@ -116,6 +138,37 @@ def gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer, table):
         kg = dequantize_kv(kg, k_scale[layer, table_c])
         vg = dequantize_kv(vg, v_scale[layer, table_c])
     return kg, vg
+
+
+ROW_LANES = 128
+
+
+def row_pool(layers: int, num_pages: int, page_size: int, row: PageRow):
+    """An empty pool of ``row`` for ``layers`` layers: [L, P, page,
+    lanes], the row's width rounded up to whole lanes."""
+    lanes = -(-row.width // ROW_LANES) * ROW_LANES
+    return jnp.zeros((layers, num_pages, page_size, lanes), row.dtype)
+
+
+def write_rows(pool, layer, rows, pidx, ip):
+    """``write_kv`` for a pool of one row a token [L, P, page, lanes]:
+    ``rows`` ([B, width] with [B] indices, or [n, T, width] with [n, T])
+    land at (layer, pidx, ip), zeros in the lanes past their width,
+    out-of-bounds indices dropping."""
+    spare = pool.shape[-1] - rows.shape[-1]
+    if spare:
+        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, spare)])
+    return pool.at[layer, pidx, ip].set(rows.astype(pool.dtype),
+                                        mode="drop")
+
+
+def gather_rows(pool, layer, table):
+    """Layer ``layer``'s ``table`` page window of every sequence, as rows
+    in key order [B, PB x page, lanes], copied out of a pool of one row a
+    token (holes read page 0; the caller's causal limit masks them).
+    The lanes past the row's width hold zeros."""
+    rows = pool[layer, jnp.maximum(table, 0)]
+    return rows.reshape(table.shape[0], -1, pool.shape[-1])
 
 
 def visible_pages(table, first_key, n_pages: int, page_size: int):

@@ -17,8 +17,20 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   uniform over the layers whatever their kind (a model's full and
   sliding layers share its KV heads and head size; a sliding layer keeps
   every page too, and reads only its window's: releasing what has fallen
-  out of every window needs a pool per kind, ROADMAP Queue 2 B.1);
-  each slot owns a page list. HBM scales with TOKENS IN FLIGHT
+  out of every window is ROADMAP Queue 2 B.1). Where a run of the
+  model's layer plan states that its layers keep ROWS and no K/V twins
+  (``LayerStack.rows``: a latent-attention layer's one compressed row a
+  token, and its indexer's key), the pools are what the plan states: one
+  [L', P, page, lanes] a kind of row, over the L' layers that keep it,
+  in place of the twins, carried and donated as they are. ONE page
+  table, one allocator and one prefix cache serve every pool: a page id
+  names the same ``page_size`` tokens in all of them, so a reused
+  prefix brings its rows of every layer. Decode attends over such rows
+  in the absorbed form where they lie, prefill in the expanded one
+  (``ops/latent_attention.py``), both plain ``jax.numpy``; for a plan of
+  K/V twins alone the arrays, the programs' arguments and their lowered
+  text are what they were.
+  Each slot owns a page list. HBM scales with TOKENS IN FLIGHT
   (reserved per request = prompt + max_new_tokens), not with
   ``max_batch * max_len`` — a 256-token chat on a 2048-token engine
   stops reserving 8x its need.
@@ -128,7 +140,9 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   sequence beside pages, or nothing), the stream's start (``embed``),
   the rotary tables of each kind (``rotary_tables``, once a step), the
   attention projections (``attention_projections``: norm, q/k/v,
-  whatever the block does to them, rotary), the sublayer's end
+  whatever the block does to them, rotary; for a run that keeps rows
+  ``latent_projections``: the queries, the row a token keeps, the
+  expansion, the indexer's inputs), the sublayer's end
   (``attention_output``: ``wo`` and the residual, a per-head gate where
   the block has one), the recurrent mixer's two forms where the plan
   has such a run, the feed-forward (``feed_forward``: a dense SwiGLU, or
@@ -165,6 +179,7 @@ enqueue requests and read token queues — no JAX calls on caller threads.
 from __future__ import annotations
 
 import itertools
+import math
 import queue
 import sys
 import threading
@@ -179,9 +194,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.decoding import select_tokens
+from ray_tpu.ops.latent_attention import (latent_decode_attention,
+                                          latent_prefill_attention,
+                                          write_latent)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
-                                         page_hashes, write_kv)
+                                         page_hashes, row_pool, write_kv)
 from ray_tpu.ops.paged_decode_attention import paged_decode_attention
 from ray_tpu.ops.paged_prefill_attention import (kernel_engages,
                                                  paged_prefill_attention)
@@ -213,21 +231,29 @@ def _bucket(n: int, minimum: int = 16) -> int:
     return b
 
 
-_PIECES = ("layer_plan", "rotary_tables", "embed", "attention_projections",
-           "attention_output", "feed_forward", "head_logits")
+_PIECES = ("layer_plan", "rotary_tables", "embed", "attention_output",
+           "feed_forward", "head_logits")
+_KV_PIECES = ("attention_projections",)
+_LATENT_PIECES = ("latent_projections",)
 _RECURRENT_PIECES = ("recurrent_mixer", "recurrent_step")
 
 
 def _model_module(cfg):
     """The module that states ``cfg``'s block: the one its config class
     is defined in, which must hold the block's pieces (module
-    docstring), and the mixer's two forms where its plan has a
-    recurrent run."""
+    docstring): what attention takes in, as q, k and v where a run of
+    its plan keeps K/V twins and as a latent's inputs where it keeps
+    rows, and the mixer's two forms where its plan has a recurrent
+    run."""
     model = sys.modules.get(type(cfg).__module__)
     missing = [name for name in _PIECES if not hasattr(model, name)]
-    if not missing and _recurrent(model.layer_plan(cfg)) is not None:
-        missing = [name for name in _RECURRENT_PIECES
-                   if not hasattr(model, name)]
+    if not missing:
+        plan = model.layer_plan(cfg)
+        asked = (
+            _KV_PIECES * any(run.rows is None for run in plan)
+            + _LATENT_PIECES * any(run.rows is not None for run in plan)
+            + _RECURRENT_PIECES * (_recurrent(plan) is not None))
+        missing = [name for name in asked if not hasattr(model, name)]
     if missing:
         raise TypeError(
             f"the paged engine cannot serve {type(cfg).__name__}: its "
@@ -247,15 +273,33 @@ def _recurrent(plan):
     return next(iter(states), None)
 
 
+def _pool_slices(plan) -> tuple:
+    """Where each page format of a layer plan lies among the pools the
+    two programs carry: ({format: slice}, how many pools). A format is
+    what a run's layers keep a token (``LayerStack.rows``): None, the
+    K/V twins, which are four pools (K, V and their scale pools); else
+    the rows it names, a pool each. Runs of one format share its pools,
+    which span their layers in the plan's order."""
+    slices, at = {}, 0
+    for run in plan:
+        if run.rows not in slices:
+            n = 4 if run.rows is None else len(run.rows)
+            slices[run.rows] = slice(at, at + n)
+            at += n
+    return slices, at
+
+
 def _plan_runs(plan, blocks, fuse=None) -> list:
     """What each run of a layer plan scans over: (its stacked weights, its
-    layers' indices in the pools). Layers take the pools' layers in the
-    plan's order. ``fuse``: what a module does to its blocks once at a
-    program's entry (``fuse_attention_projections``)."""
-    layers, first = [], 0
+    layers' indices in its pools). Layers take the layers of their
+    format's pools in the plan's order. ``fuse``: what a module does to
+    its blocks once at a program's entry
+    (``fuse_attention_projections``)."""
+    layers, first = [], {}
     for run in plan:
-        layers.append(jnp.arange(first, first + run.layers))
-        first += run.layers
+        at = first.get(run.rows, 0)
+        layers.append(jnp.arange(at, at + run.layers))
+        first[run.rows] = at + run.layers
     if fuse is not None:
         blocks = fuse(blocks)
     return [(blocks if run.key is None else blocks[run.key], idx)
@@ -427,18 +471,26 @@ class PagedLLMEngine:
 
         # -- device state: the pools, their host-side bookkeeping and the
         # programs compiled so far
-        nkv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
-        shape = (cfg.n_layers, self.num_pages, self.page_size, nkv,
-                 cfg.head_dim)
-        page_dtype = jnp.int8 if self.kv_dtype == "int8" else jnp.bfloat16
-        self._k_pages = jnp.zeros(shape, page_dtype)
-        self._v_pages = jnp.zeros(shape, page_dtype)
-        # per-token-per-head dequant scales (int8 mode; tiny dummies in
-        # bf16 mode so every program shares one signature/donation set)
-        scale_shape = (shape[:-1] if self.kv_dtype == "int8"
-                       else (cfg.n_layers, 1, 1, 1))
-        self._k_scale = jnp.ones(scale_shape, jnp.float32)
-        self._v_scale = jnp.ones(scale_shape, jnp.float32)
+        # the pools, as the plan's runs state them (``_pool_slices``):
+        # one list, in the order both programs take and return them
+        self._pools = []
+        self._bf16_row_bytes = 0    # a token's rows over the layers, bf16
+        for rows in _pool_slices(plan)[0]:
+            layers = sum(run.layers for run in plan if run.rows == rows)
+            if rows is None:
+                self._pools += self._kv_twins(layers)
+                self._bf16_row_bytes += (
+                    layers * 2 * 2 * math.prod(self._pools[-4].shape[3:]))
+                continue
+            if self.kv_dtype == "int8":
+                raise ValueError(
+                    "kv_dtype='int8' over a layer plan that keeps rows "
+                    f"({', '.join(row.name for row in rows)}): only K/V "
+                    "twins are stored quantised")
+            self._pools += [row_pool(layers, self.num_pages,
+                                     self.page_size, row) for row in rows]
+            self._bf16_row_bytes += layers * 2 * sum(
+                pool.shape[-1] for pool in self._pools[-len(rows):])
         # the slots' recurrent state, one array a kind [L, max_batch,
         # ...], where the plan has a recurrent run (else none, and the
         # programs take no such argument): donated to both programs and
@@ -486,7 +538,19 @@ class PagedLLMEngine:
         # attention kernel: a model with full-attention layers, lowered
         # for a TPU (``_dispatch_prefill``)
         self._kernel_backend = jax.default_backend() == "tpu" and any(
-            run.window is None for run in plan)
+            run.window is None and run.rows is None for run in plan)
+        # the keys a layer that picks them attends over at most, if the
+        # plan has such layers (for the decode dispatch's count of the
+        # rows a step reads after its selection)
+        self._selects = next(
+            (run.selects for run in plan if run.selects is not None), None)
+        # the rows a token keeps in a page, by format, for the prefill
+        # dispatch's span: "k+v" for K/V twins, else the rows' names and
+        # widths
+        self._page_rows = ";".join(
+            "k+v" if rows is None else
+            ",".join(f"{row.name}:{row.width}" for row in rows)
+            for rows in _pool_slices(plan)[0])
         self.prefill_dispatches = 0
         self.prefill_kernel_dispatches = 0
         # the token-rows the prefill programs computed (group x bucket a
@@ -514,6 +578,27 @@ class PagedLLMEngine:
                                 and _cfg.serve_prefix_routing_enabled)
         self._digest_interval = float(_cfg.serve_digest_publish_interval_s)
         self._digest_t = 0.0
+
+    def _kv_twins(self, layers: int) -> list:
+        """The four pools of ``layers`` layers that keep K/V twins: K
+        and V pages [L, P, page, nkv, hd] and their scale pools."""
+        cfg = self.cfg
+        nkv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
+        shape = (layers, self.num_pages, self.page_size, nkv, cfg.head_dim)
+        page_dtype = jnp.int8 if self.kv_dtype == "int8" else jnp.bfloat16
+        # per-token-per-head dequant scales (int8 mode; tiny dummies in
+        # bf16 mode so every program shares one signature/donation set)
+        scale_shape = (shape[:-1] if self.kv_dtype == "int8"
+                       else (layers, 1, 1, 1))
+        return [jnp.zeros(shape, page_dtype), jnp.zeros(shape, page_dtype),
+                jnp.ones(scale_shape, jnp.float32),
+                jnp.ones(scale_shape, jnp.float32)]
+
+    # the K/V twins' four pools by name (a plan of K/V twins alone)
+    _k_pages = property(lambda self: self._pools[0])
+    _v_pages = property(lambda self: self._pools[1])
+    _k_scale = property(lambda self: self._pools[2])
+    _v_scale = property(lambda self: self._pools[3])
 
     # -- compiled programs -------------------------------------------------
 
@@ -549,9 +634,12 @@ class PagedLLMEngine:
         return fn
 
     def _donated(self) -> tuple:
-        """The programs' donated arguments: the four pools, and the
-        slots' recurrent state, which follows the key."""
-        return (1, 2, 3, 4) + tuple(range(11, 11 + len(self._state)))
+        """The programs' donated arguments: the pools, which follow the
+        weights, and the slots' recurrent state, which follows the key
+        (six arguments lie between)."""
+        pools = len(self._pools)
+        return tuple(range(1, 1 + pools)) + tuple(
+            range(7 + pools, 7 + pools + len(self._state)))
 
     def _window_pages(self, max_covered: int) -> int:
         """Power-of-two page count covering ``max_covered`` tokens,
@@ -560,13 +648,15 @@ class PagedLLMEngine:
         return min(_bucket(need, minimum=1), self.max_pages_per_seq)
 
     @staticmethod
-    def _paged_decode_impl(cfg, params, k_pages, v_pages, k_scale,
-                           v_scale, table, tokens, lengths, active,
-                           temps, key, *state, chunk, page_size,
+    def _paged_decode_impl(cfg, params, *args, chunk, page_size,
                            quantized):
         """``chunk`` decode steps over every slot in one compiled program;
         KV rows written, then attended over where they lie, through the
-        (bucketed) page table [B, PB]. Returns the pools, the [chunk,
+        (bucketed) page table [B, PB]. ``args``: the pools the plan
+        states (``_pool_slices``: for K/V twins ``k_pages, v_pages,
+        k_scale, v_scale``), then ``table, tokens, lengths, active,
+        temps, key`` and the slots' recurrent ``state``. Returns the
+        pools, the [chunk,
         max_batch] token matrix and the advanced lengths (kept ON DEVICE
         so chained chunks never need a host upload). Inactive slots are
         computed but masked (their writes drop). Slots finishing
@@ -584,19 +674,22 @@ class PagedLLMEngine:
         ACTIVE slots' states at [layer] and leaving the others' as they
         are; returned after the rest."""
         model = _model_module(cfg)
-        num_pages = k_pages.shape[1]
         # the model's layers as runs of identical layers (one run, for a
         # model that repeats one block); each run's weights and its
         # layers' places in the pools, built here once, outside every scan
         plan = model.layer_plan(cfg)
+        where, n_pools = _pool_slices(plan)
+        pools = args[:n_pools]
+        table, tokens, lengths, active, temps, key, *state = args[n_pools:]
+        num_pages = pools[0].shape[1]
         # q, k and v from ONE weight stack where the block's module states
         # how (module docstring)
         runs = _plan_runs(plan, params["blocks"], getattr(
             model, "fuse_attention_projections", None))
 
         def one_step(carry, _):
-            k_pages, v_pages, k_scale, v_scale, toks, lens, key, *state = \
-                carry
+            *pools, toks, lens, key = carry[:n_pools + 3]
+            state = carry[n_pools + 3:]
             key, sub = jax.random.split(key)
             pos = jnp.where(active, lens, 0)                    # [B]
             x = model.embed(cfg, params, toks[:, None])         # [B,1,d]
@@ -609,49 +702,62 @@ class PagedLLMEngine:
             ip = pos % page_size
 
             def block(run, carry, xs):
-                x, kp, vp, ks, vs, *state = carry
+                x, *rest = carry
+                mine = where[run.rows]
+                held, state = rest[mine], rest[n_pools:]
                 p, layer = xs
-                q, k, v = model.attention_projections(
-                    cfg, p, x, *rotary[run.kind])
-                if run.state is not None:
-                    # the mixer beside the attention, on the same input
-                    mixed, state = model.recurrent_step(
-                        cfg, p, x, state, layer, active)
-                kp, vp, ks, vs = write_kv(
-                    kp, vp, ks, vs, layer, k[:, 0], v[:, 0], pidx, ip,
-                    quantized)
-                # each live slot's pages up to its length (a sliding
-                # layer: the pages of its window), read where they lie;
-                # the row just written is among them
-                attn = paged_decode_attention(
-                    q[:, 0], kp, vp, ks, vs, layer, table, pos, active,
-                    window=run.window)
+                if run.rows is not None:
+                    # a layer that keeps rows: the step's own written,
+                    # then the slot's rows read where they lie (a
+                    # sliding layer: its window's; a layer with an
+                    # indexer: the ones it picks)
+                    inputs = model.latent_projections(
+                        cfg, p, x, *rotary[run.kind])
+                    held = write_latent(inputs, held, layer, pidx, ip)
+                    attn = latent_decode_attention(
+                        inputs, held, layer, table, pos, window=run.window)
+                else:
+                    q, k, v = model.attention_projections(
+                        cfg, p, x, *rotary[run.kind])
+                    if run.state is not None:
+                        # the mixer beside the attention, on the same input
+                        mixed, state = model.recurrent_step(
+                            cfg, p, x, state, layer, active)
+                    held = write_kv(*held, layer, k[:, 0], v[:, 0], pidx,
+                                    ip, quantized)
+                    # each live slot's pages up to its length (a sliding
+                    # layer: the pages of its window), read where they
+                    # lie; the row just written is among them
+                    attn = paged_decode_attention(
+                        q[:, 0], *held, layer, table, pos, active,
+                        window=run.window)
                 x = model.attention_output(cfg, p, x, attn)
                 if run.state is not None:
                     x = x + mixed
                 x, stats = model.feed_forward(cfg, p, x,
                                               valid=active[:, None])
-                return (x, kp, vp, ks, vs, *state), stats
+                return (x, *rest[:mine.start], *held,
+                        *rest[mine.stop:n_pools], *state), stats
 
-            carry = (x, k_pages, v_pages, k_scale, v_scale, *state)
+            carry = (x, *pools, *state)
             stats = []
             for run, xs in zip(plan, runs):
                 carry, run_stats = jax.lax.scan(partial(block, run), carry,
                                                 xs)
                 stats.append(run_stats)
-            x, k_pages, v_pages, k_scale, v_scale, *state = carry
+            x, *rest = carry
             x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)[:, 0]
             logits = model.head_logits(cfg, params, x)
             nxt = select_tokens(logits, temps, sub)
             lens = jnp.where(active, lens + 1, lens)
-            return (k_pages, v_pages, k_scale, v_scale, nxt, lens,
-                    key, *state), (nxt, _over_layers(stats))
+            return (*rest[:n_pools], nxt, lens, key,
+                    *rest[n_pools:]), (nxt, _over_layers(stats))
 
-        (k_pages, v_pages, k_scale, v_scale, _, lens, _, *state), \
-            (toks, stats) = jax.lax.scan(
-                one_step,
-                (k_pages, v_pages, k_scale, v_scale, tokens, lengths,
-                 key, *state), None, length=chunk)
+        carry, (toks, stats) = jax.lax.scan(
+            one_step, (*pools, tokens, lengths, key, *state), None,
+            length=chunk)
+        pools, lens, state = (carry[:n_pools], carry[n_pools + 1],
+                              carry[n_pools + 3:])
         # merged last-token vector: chunk-active slots advance to their
         # newest token, others keep their prior value — the loop chains
         # every next dispatch off this DEVICE array, so admissions /
@@ -660,15 +766,14 @@ class PagedLLMEngine:
         # the feed-forward's statistics [chunk, layers], as the chunk's
         # means (nothing, for a block that hands back none)
         stats = jax.tree.map(jnp.mean, stats)
-        return (k_pages, v_pages, k_scale, v_scale, toks, lens, new_last,
-                stats, *state)
+        return (*pools, toks, lens, new_last, stats, *state)
 
     @staticmethod
-    def _paged_prefill_impl(cfg, params, k_pages, v_pages, k_scale,
-                            v_scale, table_rows, tokens, slens, starts,
-                            temps, key, *state, page_size, quantized):
+    def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
         """Prefill ``n`` prompt SUFFIXES (one padded bucket) into pages
-        and sample each row's first token, in a single program: each
+        and sample each row's first token, in a single program
+        (``args``: the pools the plan states, then ``table_rows,
+        tokens, slens, starts, temps, key`` and ``state``): each
         dispatch has a fixed sync cost, so a 16-request burst admitted
         one-by-one would pay 16 of them serially in TTFT before any
         compute. ``tokens`` holds only the
@@ -688,9 +793,13 @@ class PagedLLMEngine:
         the slot's last tenant left there is never read. A slot past
         the last one (a warm-up's row) drops."""
         model = _model_module(cfg)
-        num_pages = k_pages.shape[1]
-        n, t = tokens.shape
         plan = model.layer_plan(cfg)
+        where, n_pools = _pool_slices(plan)
+        pools = args[:n_pools]
+        table_rows, tokens, slens, starts, temps, key, *state = \
+            args[n_pools:]
+        num_pages = pools[0].shape[1]
+        n, t = tokens.shape
         *state, slots = state or (None,)
         x = model.embed(cfg, params, tokens)
         rel = jnp.arange(t, dtype=jnp.int32)
@@ -704,37 +813,48 @@ class PagedLLMEngine:
         ip_all = positions % page_size
 
         def block(run, carry, xs):
-            x, kp, vp, ks, vs, *state = carry
+            x, *rest = carry
+            mine = where[run.rows]
+            held, state = rest[mine], rest[n_pools:]
             p, layer = xs
-            q, k, v = model.attention_projections(cfg, p, x,
+            if run.rows is not None:
+                inputs = model.latent_projections(cfg, p, x,
                                                   *rotary[run.kind])
-            if run.state is not None:
-                fresh = tuple(jnp.zeros((n, *a.shape[2:]), a.dtype)
-                              for a in state)
-                mixed, final = model.recurrent_mixer(cfg, p, x, fresh,
-                                                     valid)
-                state = [a.at[layer, slots].set(new, mode="drop")
-                         for a, new in zip(state, final)]
-            kp, vp, ks, vs = write_kv(
-                kp, vp, ks, vs, layer, k, v, pidx_all, ip_all, quantized)
-            attn = paged_prefill_attention(
-                q, kp, vp, ks, vs, layer, table_rows, starts, slens,
-                window=run.window)
+                held = write_latent(inputs, held, layer, pidx_all, ip_all)
+                attn = latent_prefill_attention(
+                    inputs, held, layer, table_rows, starts,
+                    window=run.window)
+            else:
+                q, k, v = model.attention_projections(cfg, p, x,
+                                                      *rotary[run.kind])
+                if run.state is not None:
+                    fresh = tuple(jnp.zeros((n, *a.shape[2:]), a.dtype)
+                                  for a in state)
+                    mixed, final = model.recurrent_mixer(cfg, p, x, fresh,
+                                                         valid)
+                    state = [a.at[layer, slots].set(new, mode="drop")
+                             for a, new in zip(state, final)]
+                held = write_kv(*held, layer, k, v, pidx_all, ip_all,
+                                quantized)
+                attn = paged_prefill_attention(
+                    q, *held, layer, table_rows, starts, slens,
+                    window=run.window)
             x = model.attention_output(cfg, p, x, attn)
             if run.state is not None:
                 x = x + mixed
             x, _ = model.feed_forward(cfg, p, x, valid=valid)
-            return (x, kp, vp, ks, vs, *state), None
+            return (x, *rest[:mine.start], *held,
+                    *rest[mine.stop:n_pools], *state), None
 
-        carry = (x, k_pages, v_pages, k_scale, v_scale, *state)
+        carry = (x, *pools, *state)
         for run, xs in zip(plan, _plan_runs(plan, params["blocks"])):
             carry, _ = jax.lax.scan(partial(block, run), carry, xs)
-        x, k_pages, v_pages, k_scale, v_scale, *state = carry
+        x, *rest = carry
         x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
         x = jnp.take_along_axis(
             x, (slens - 1)[:, None, None], axis=1).squeeze(1)
         first = select_tokens(model.head_logits(cfg, params, x), temps, key)
-        return (k_pages, v_pages, k_scale, v_scale, first, *state)
+        return (*rest[:n_pools], first, *rest[n_pools:])
 
     # -- warm-up -----------------------------------------------------------
 
@@ -750,17 +870,24 @@ class PagedLLMEngine:
             rows = jnp.full((n, wp), -1, jnp.int32)
             # no slot: every row's state drops, as its KV rows do
             nowhere = jnp.full((n,), self.max_batch, jnp.int32)
-            (self._k_pages, self._v_pages, self._k_scale,
-             self._v_scale, firsts, *self._state) = prefill(
-                self.params, self._k_pages, self._v_pages,
-                self._k_scale, self._v_scale, rows,
+            firsts = self._took(prefill(
+                self.params, *self._pools, rows,
                 jnp.zeros((n, bucket), jnp.int32),
                 jnp.ones((n,), jnp.int32),
                 jnp.full((n,), start, jnp.int32),
                 jnp.zeros((n,), jnp.float32), self._next_key(),
-                *self._state_args(nowhere))
+                *self._state_args(nowhere)), 1)[0]
             yield n, firsts
             n *= 2
+
+    def _took(self, out: tuple, results: int) -> tuple:
+        """A program's outputs: the pools come first and the slots'
+        recurrent state last, both kept here in place of the donated
+        ones; between them its ``results``, which are returned."""
+        pools = len(self._pools)
+        self._pools = list(out[:pools])
+        self._state = tuple(out[pools + results:])
+        return out[pools:pools + results]
 
     def _state_args(self, slots) -> tuple:
         """What a prefill program takes after its key: the slots'
@@ -813,15 +940,13 @@ class PagedLLMEngine:
         for pb in buckets:
             for chunk in {self.decode_chunk, self._drain_chunk}:
                 fn = self._decode_paged(chunk, pb)
-                (self._k_pages, self._v_pages, self._k_scale,
-                 self._v_scale, toks, _, _, _, *self._state) = fn(
-                    self.params, self._k_pages, self._v_pages,
-                    self._k_scale, self._v_scale,
+                toks = self._took(fn(
+                    self.params, *self._pools,
                     jnp.full((self.max_batch, pb), -1, jnp.int32),
                     jnp.zeros((self.max_batch,), jnp.int32),
                     jnp.zeros((self.max_batch,), jnp.int32), active,
                     jnp.zeros((self.max_batch,), jnp.float32),
-                    self._next_key(), *self._state)
+                    self._next_key(), *self._state), 4)[0]
                 np.asarray(toks)
         self._lengths[:] = 0
         self._last_tok[:] = 0
@@ -1016,7 +1141,7 @@ class PagedLLMEngine:
             ph.set(token_rows=token_rows, new_tokens=new_tokens,
                    cached_tokens=cached,
                    missed_pages=lookups - cached // page,
-                   attn_kernel=int(kernel))
+                   attn_kernel=int(kernel), page_rows=self._page_rows)
         slots = None
         if self._state:
             # every row's final state goes into its slot; the scan cuts
@@ -1033,11 +1158,10 @@ class PagedLLMEngine:
             [self._table[it[1]][:wp] for it in part]))
         temps = jnp.asarray(np.array(
             [it[0].temperature for it in part], np.float32))
-        (self._k_pages, self._v_pages, self._k_scale, self._v_scale,
-         firsts, *self._state) = prefill(
-            self.params, self._k_pages, self._v_pages, self._k_scale,
-            self._v_scale, rows, tokens, slens, jnp.asarray(starts_np),
-            temps, self._next_key(), *self._state_args(slots))
+        firsts, = self._took(prefill(
+            self.params, *self._pools, rows, tokens, slens,
+            jnp.asarray(starts_np), temps, self._next_key(),
+            *self._state_args(slots)), 1)
         # the dispatch above is what makes each slot's full prompt pages
         # valid on device: REGISTER them in the prefix cache now — any
         # future admission's prefill program runs after this one on the
@@ -1478,13 +1602,11 @@ class PagedLLMEngine:
                 # writing table[slot] = -1 mid-transfer would hand the
                 # in-flight chunk a torn table
                 dev[table] = jnp.asarray(self._table[:, :pb].copy())
-            (self._k_pages, self._v_pages, self._k_scale, self._v_scale,
-             toks, lens, new_last, stats,
-             *self._state) = self._decode_paged(chunk, pb)(
-                self.params, self._k_pages, self._v_pages, self._k_scale,
-                self._v_scale, dev[table], self._last_dev, dev["lens"],
-                dev["active"], dev["temps"], self._next_key(),
-                *self._state)
+            toks, lens, new_last, stats = self._took(
+                self._decode_paged(chunk, pb)(
+                    self.params, *self._pools, dev[table], self._last_dev,
+                    dev["lens"], dev["active"], dev["temps"],
+                    self._next_key(), *self._state), 4)
             self._chunk_stats.append(stats)
             self.decode_dispatches += 1
             self.state_kernel_dispatches += int(self._state_kernel)
@@ -1501,6 +1623,12 @@ class PagedLLMEngine:
                 if self._window is not None:
                     ph.set(kv_rows_window=int(
                         np.minimum(rows, self._window).sum()))
+                if self._selects is not None:
+                    # a layer with an indexer scores every row's index
+                    # key and reads the rows it picks
+                    ph.set(index_rows=int(rows.sum()),
+                           kv_rows_selected=int(
+                               np.minimum(rows, self._selects).sum()))
                 if self._state:
                     # the live slots' recurrent state, which one step
                     # reads once and writes once in every layer, and
@@ -1746,14 +1874,15 @@ class PagedLLMEngine:
             "cached_idle_pages": self._prefix.evictable(),
         }
         out["kv_dtype"] = self.kv_dtype
-        scale_bytes = (self._k_scale.size * 4 * 2
-                       if self.kv_dtype == "int8" else 0)
-        out["kv_pages_bytes"] = int(
-            self._k_pages.size * self._k_pages.dtype.itemsize * 2
-            + scale_bytes)   # K+V pages (+ dequant scales in int8 mode)
+        # the pools' own bytes: every pool that holds a row a token (K
+        # and V pages, with their dequant scales in int8 mode; a latent
+        # plan's rows), not the bf16 mode's one-element scale dummies
+        out["kv_pages_bytes"] = sum(
+            a.size * a.dtype.itemsize for a in self._pools
+            if a.shape[1] == self.num_pages)
+        out["cache_bytes_per_token"] = (
+            out["kv_pages_bytes"] // (self.num_pages * self.page_size))
         # what max_batch contiguous bf16 rows of max_len would take
-        dense = (self.cfg.n_layers * self.max_batch * self.max_len
-                 * self._k_pages.shape[3] * self._k_pages.shape[4]
-                 * 2 * 2)
-        out["kv_dense_equiv_bytes"] = int(dense)
+        out["kv_dense_equiv_bytes"] = (
+            self.max_batch * self.max_len * self._bf16_row_bytes)
         return out

@@ -535,6 +535,77 @@ def test_recurrent_state_counts_on_the_dispatch_spans_equal_stats(tiny):
     assert plain.stats()["state_kernel_dispatches"] == 0
 
 
+def _pool_stats(model, cfg, **engine):
+    eng = PagedLLMEngine(cfg, model.init_params(cfg, jax.random.key(0)),
+                         max_batch=3, max_len=128, page_size=PAGE,
+                         num_pages=30, **engine)
+    return eng, eng.stats()
+
+
+@pytest.mark.parametrize("family", ["llama-bf16", "llama-int8", "laguna",
+                                    "dots3_note"])
+def test_stats_count_the_pools_own_bytes_and_what_a_token_keeps(family):
+    """``stats()`` counts the cache from the pools the plan states, not
+    from an assumed K/V twin: ``kv_pages_bytes`` is every pool that holds
+    a row a token (K and V pages, with their scales under int8; a latent
+    plan's rows, in whole lanes), ``cache_bytes_per_token`` that over the
+    pool's tokens, ``kv_dense_equiv_bytes`` what ``max_batch`` contiguous
+    bf16 rows of ``max_len`` would take. A prefill dispatch's span names
+    the rows' formats (``page_rows``)."""
+    from ray_tpu.models import dots3_note, laguna
+
+    tokens, slots_len = 30 * PAGE, 3 * 128
+    if family.startswith("llama"):
+        cfg = llama.llama_tiny()
+        int8 = family.endswith("int8")
+        eng, stats = _pool_stats(llama, cfg,
+                                 kv_dtype="int8" if int8 else "bf16")
+        row = cfg.n_layers * 2 * cfg.n_kv_heads * cfg.head_dim
+        want = row + cfg.n_layers * 2 * cfg.n_kv_heads * 4 if int8 \
+            else 2 * row
+        rows, dense = "k+v", 2 * row
+    elif family == "laguna":
+        cfg = laguna.laguna_tiny()
+        eng, stats = _pool_stats(laguna, cfg)
+        want = dense = cfg.n_layers * 2 * cfg.n_kv_heads * cfg.head_dim * 2
+        rows = "k+v"
+    else:
+        cfg = dots3_note.dots3_note_tiny()
+        eng, stats = _pool_stats(dots3_note, cfg)
+        # float32 rows of 24 | 16 (two full layers) and 40 (three sliding
+        # ones), each in one lane group of 128
+        want, dense = 7 * 128 * 4, 7 * 128 * 2
+        rows = "latent:24,index_key:16;latent:40"
+    assert stats["cache_bytes_per_token"] == want
+    assert stats["kv_pages_bytes"] == want * tokens == sum(
+        a.size * a.dtype.itemsize for a in eng._pools
+        if a.shape[1] == eng.num_pages)
+    assert stats["kv_dense_equiv_bytes"] == dense * slots_len
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        eng.start()
+        assert len(list(eng.submit(np.arange(1, 40) % cfg.vocab_size,
+                                   max_new_tokens=3).tokens())) == 3
+        eng.stop()
+        prefills = tracing.recorded_spans("engine.dispatch_prefill")
+        decodes = tracing.recorded_spans("engine.dispatch_decode")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    assert prefills and all(s["attrs"]["page_rows"] == rows
+                            for s in prefills)
+    selected = [s["attrs"].get("kv_rows_selected") for s in decodes]
+    if family == "dots3_note":
+        assert decodes and all(
+            s["attrs"]["kv_rows_selected"] == min(
+                s["attrs"]["kv_rows_full"], cfg.index_topk * s["attrs"]["live"])
+            and s["attrs"]["index_rows"] == s["attrs"]["kv_rows_full"]
+            for s in decodes)
+    else:
+        assert decodes and selected == [None] * len(decodes)
+
+
 def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):
     clear_ring()
     assert not tracing.recording()
