@@ -27,28 +27,64 @@ kI(s))`` over the indexer's heads, in float32 (the rows are bf16, so
 their products are exact and the sums float32: a choice must not turn on
 a rounding), and its softmax runs over the ``topk`` keys of largest
 ``I`` alone, ties to the lower position; a query that sees no more than
-``topk`` keys attends over them all. The decode form gathers just the
-chosen rows (``select_keys``); the prefill form masks.
+``topk`` keys attends over them all (``kept``: one rule, both forms).
 
 A WINDOWED layer's queries see the ``window`` newest keys, their own
-among them; both forms gather only the pages that hold them
-(``visible_pages``).
+among them.
 
-Plain ``jax.numpy`` and ``lax``: no Pallas kernel. A prefill's float32
-scores, the layer's and its indexer's, go over blocks of queries under
-``SCORES_MAX_BYTES``. The serving engine (``serve/paged_llm.py``) calls
-the three functions at the bottom from its two programs; what they take
-of a block is ``LatentInputs``, which the model's module builds."""
+WHAT RUNS WHERE. The prefill form is plain ``jax.numpy`` and ``lax``: it
+gathers the pages a block of queries can see (``visible_pages`` for a
+window), the selection is a mask, and its float32 scores, the layer's
+and its indexer's, go over blocks of queries under ``SCORES_MAX_BYTES``.
+The decode form has two formulations of the same softmax over the same
+keys, and ``latent_decode_attention`` chooses by the platform a program
+is lowered for (``jax.lax.platform_dependent``) and by static shapes
+(``latent_kernel_engages``), nothing else:
+
+- IN PLACE, one Pallas kernel (``latent_decode_attn``, a sibling of
+  ``ops/paged_decode_attention.py``'s), for a layer with an indexer: the
+  rows' pool [L, P, page, lanes] stays in HBM; the layer, the page table,
+  each slot's key count and the next-live-slot chain come through scalar
+  prefetch; the grid walks the slots and, per live slot, the pages up to
+  ``ceil(keys / page)``, ``_GROUP`` at a step, ONE async copy a page,
+  double buffered, the next pages' copies (of this slot or of the next
+  live one) in flight while these are computed. The pages are contracted
+  as they lie: ``q_row [H, lanes] x rows^T -> [H, keys]`` float32, online
+  softmax in float32, the probabilities cast to the rows' type, ``p x
+  rows[:, :r] -> [H, r]`` accumulated in float32. The SELECTION reaches
+  it as flags a key ([slots, keys], a page's 128 riding with the page):
+  ``kept``'s set among the keys the slot sees, so every page a slot holds
+  is read whole and the keys outside the set are masked. A dead slot and
+  the pages past a slot's count cost nothing;
+- GATHERED, plain ``jax.numpy`` (``_gathered``: what the kernel is held
+  to, and what every other platform, every shape outside the rule and
+  every windowed layer runs): the chosen rows, or a window's pages
+  (``visible_pages``), copied out of the pool and attended over as one
+  array. (A windowed layer's gather is whole pages already: through the
+  kernel its five pages a slot took 0.287 ms a layer against 0.281
+  gathered, on a v5e at the serving cell's shapes, PR 41, so the kernel
+  has no window.)
+
+The rule between them for a layer with an indexer is a ratio the program
+sees as static shapes, the table's keys over ``topk``: see
+``GATHER_PAST``. The serving engine (``serve/paged_llm.py``) calls the
+three functions at the bottom from its two programs; what they take of a
+block is ``LatentInputs``, which the model's module builds."""
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.paged_attention import (gather_rows, visible_pages,
-                                         write_rows)
+from ray_tpu.ops.paged_attention import (ROW_LANES, gather_rows,
+                                         visible_pages, write_rows)
 
 # the most a prefill's float32 scores of one block of queries may take,
 # the layer's [n, H, block, keys] and its indexer's [n, HI, block, keys]
@@ -58,6 +94,8 @@ SCORES_MAX_BYTES = 1 << 30
 # over the keys its last block can see and no further
 KEY_GROUPS = 4
 _MASKED = float(jnp.finfo(jnp.float32).min)
+# inside the kernel a masked score must stay finite under ``s - m``
+_KERNEL_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 class IndexInputs(NamedTuple):
@@ -100,69 +138,245 @@ def index_scores(q, weights, keys):
     return jnp.sum(w * jax.nn.relu(dots), axis=1)
 
 
-def select_keys(index: IndexInputs, index_pool, layer, table, count):
-    """The positions of the ``topk`` keys a decode step's query attends
-    over, for every slot: (positions [B, topk] int32, chosen [B, topk]
-    bool: false where the slot has fewer keys than ``topk``), from the
-    index keys of the slot's pages. ``count`` [B]: the keys the query
-    sees (its own, written already, among them). None where the table
-    holds no more than ``topk`` keys: then nothing is dropped."""
-    keys = gather_rows(index_pool, layer, table)               # [B, S, dI]
-    if keys.shape[1] <= index.topk:
-        return None
-    scores = index_scores(index.q, index.weights, keys)[:, 0]    # [B, S]
-    seen = jnp.arange(keys.shape[1], dtype=jnp.int32) < count[:, None]
-    values, positions = jax.lax.top_k(
-        jnp.where(seen, scores, _MASKED), index.topk)
-    # a key past the slot's count comes out with the mask's own value (a
-    # gather of ``seen`` at the positions says the same, a scalar at a
-    # time: 1.3 ms a layer on a v5e at 64 x 2,048)
-    return positions, values > _MASKED
+def kept(chosen, topk: int):
+    """Which keys a query's softmax runs over, from its index scores
+    ``chosen`` [..., S] float32 (``_MASKED`` where it may not see the
+    key): every key above the ``topk``-th largest score and, of those AT
+    it, the lowest positions that fill the count: the set ``lax.top_k``
+    returns, as a mask. (``top_k`` takes ties by the lower position, so
+    of the keys at the ``topk``-th score it took all up to the highest
+    position it returns among them.) The caller ANDs it with what the
+    query may see: where that is no more than ``topk`` keys the
+    ``topk``-th score is the mask's own and none is dropped."""
+    values, positions = lax.top_k(chosen, topk)
+    kth = values[..., -1:]
+    last = jnp.max(jnp.where(values == kth, positions, -1), axis=-1,
+                   keepdims=True)
+    at = jnp.arange(chosen.shape[-1], dtype=positions.dtype)
+    return (chosen > kth) | ((chosen == kth) & (at <= last))
 
 
-def _absorbed(inputs: LatentInputs, rows, mask):
-    """One query a slot over ``rows`` [B, S, lanes] (``r + dr`` numbers,
-    then zeros) where ``mask`` [B, S] lets it: the absorbed form.
-    Returns [B, H, dv]."""
+# ---------------------------------------------------------------------------
+# Decode: the absorbed form, in place or gathered
+# ---------------------------------------------------------------------------
+
+KERNEL_NAME = "latent_decode_attn"
+_BUFFERS = 2
+# pages a step of the walk: one max, one rescale of the accumulator and one
+# weighted sum for all of them (a full layer at 64 slots of 3.0-5.1k keys
+# on a v5e, the kernel alone, PR 41: 1.36 ms a page at a time, 0.89 by
+# twos, 0.72 by fours, 0.65 by eights and by sixteens)
+_GROUP = 8
+# A layer with an indexer reads its rows IN PLACE (every page the slot
+# holds, the selection a mask) while its table holds no more than this
+# many times ``topk`` keys, and GATHERED (the chosen rows alone, by
+# position) past it. The measurement (a v5e, rows of 1,280 B, traces of
+# PR 40 and PR 41): XLA's gather takes 17 ns a chosen row whatever its
+# width (2.28 ms for 64 x 2,048 rows, 74 GB/s; 2.78 with the scores and
+# values that read the copy back), and a row read in place costs 2.2-2.5
+# ns (0.58-0.65 ms the kernel for the 260 thousand rows 64 slots of
+# 3.0-5.1k keys hold; its bytes over the bandwidth would be 1.6); so the
+# gather wins only where a slot holds more than about 8 x ``topk`` keys.
+# The serving cell's programs (64-page tables of 8,192 keys over a
+# ``topk`` of 2,048) stand at 4 x.
+GATHER_PAST = 8
+
+
+def latent_kernel_engages(page: int, table_pages: int, topk) -> bool:
+    """The rule, from static shapes alone: whether a decode step's
+    attention of a layer that keeps ``topk`` keys (None: it has no
+    indexer), over a table of ``table_pages`` pages of ``page`` rows, is
+    the kernel on a program lowered for a TPU: where the table holds more
+    than ``topk`` keys (else nothing is selected) and no more than
+    ``GATHER_PAST`` times as many, in pages of whole lanes (a page's
+    flags are then whole lanes too)."""
+    return (topk is not None and page % ROW_LANES == 0
+            and topk < page * table_pages <= GATHER_PAST * topk)
+
+
+def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
+            q_ref, pool_hbm, flags_ref, o_ref, buf, sem, step_ref, *,
+            pages_per_slot, group, width, scale):
+    """One grid step a slot (module docstring): its queries ``q_ref`` [1,
+    H, lanes], its flags [1, PB / group, group x page], its output [1, H,
+    width]. ``buf`` [2, group x page, lanes], ``sem`` [2, group]: the
+    page buffers, a GROUP of pages each, and their DMA semaphores;
+    ``step_ref``: the groups walked so far (which buffer is next), kept
+    across the grid's steps as the buffers are."""
+    slot, slots = pl.program_id(0), pl.num_programs(0)
+    heads = q_ref.shape[1]
+    page = pool_hbm.shape[2]
+    layer = layer_ref[0]
+
+    def pages_of(slot):
+        # never past the table's row (the gathered formulation ends there
+        # too; the engine's reservations keep counts inside; the chain
+        # ends at ``slots``, which is no slot: read the last)
+        count = count_ref[jnp.minimum(slot, slots - 1)]
+        return jnp.minimum((count + page - 1) // page, pages_per_slot)
+
+    def copies(slot, g, b, do):
+        """``do`` to the copy of each page of the slot's group ``g`` that
+        the slot holds, into (or in) buffer ``b``."""
+        first = g * group
+
+        def one(j, _):
+            p = table_ref[slot * pages_per_slot + first + j]
+            do(pltpu.make_async_copy(
+                pool_hbm.at[layer, p],
+                buf.at[b, pl.ds(pl.multiple_of(j * page, page), page)],
+                sem.at[b, j]))
+
+        lax.fori_loop(0, jnp.minimum(group, pages_of(slot) - first), one,
+                      None)
+
+    @pl.when(slot == 0)
+    def _():
+        step_ref[0] = 0
+        # a group's pages past the slot's last are not fetched: what the
+        # buffer holds there weighs nothing, and must be a number
+        buf[...] = jnp.zeros_like(buf)
+        first = next_ref[0]
+
+        @pl.when(first < slots)
+        def _():
+            copies(first, 0, 0, lambda c: c.start())
+
+    n_groups = (pages_of(slot) + group - 1) // group
+    q = q_ref[0]                                            # [H, lanes]
+
+    def group_body(g, carry):
+        m, l, acc, step = carry
+        b = step % _BUFFERS
+        more = g + 1 < n_groups
+        nslot = jnp.where(more, slot, next_ref[slot + 1])
+
+        @pl.when(nslot < slots)
+        def _():
+            copies(nslot, jnp.where(more, g + 1, 0), (step + 1) % _BUFFERS,
+                   lambda c: c.start())
+
+        copies(slot, g, b, lambda c: c.wait())
+        rows = buf[b]                               # [group x page, lanes]
+        s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        # the flags hold the slot's count and its selection both
+        seen = flags_ref[0, pl.ds(g, 1), :] > 0      # [1, group x page]
+        s = jnp.where(seen, s, _KERNEL_MASKED)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        # (a group with no key of the set, met before any that has one,
+        # must leave nothing behind: its rows weigh nothing, not one)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + lax.dot_general(
+            p.astype(rows.dtype), rows[:, :width], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc, step + 1
+
+    m, l, acc, step = lax.fori_loop(
+        0, n_groups, group_body,
+        (jnp.full((heads, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, width), jnp.float32), step_ref[0]))
+    step_ref[0] = step
+    # a slot with no keys: zeros (l == 0), never a NaN
+    o_ref[0] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def latent_decode_attention_kernel(q_row, pool, layer, table, count, flags,
+                                   *, rank, scale, interpret=False):
+    """The kernel's launch. ``q_row`` [B, H, lanes]: the queries in the
+    rows' own layout (``_query_rows``); ``pool`` [L, P, page, lanes];
+    ``table`` [B, PB] page ids (-1 = hole); ``count`` [B]: the keys a
+    slot's query sees, 0 for a dead slot; ``flags`` [B, PB x page] bool:
+    the keys its softmax runs over (none past ``count``). Returns the
+    probability-weighted rows' first ``rank`` numbers [B, H, rank] in
+    ``q_row``'s type."""
+    slots, heads, lanes = q_row.shape
+    page, pb = pool.shape[2], table.shape[1]
+    group = math.gcd(pb, _GROUP)
+    # what a page's rows are sliced to: whole lanes (``rank``'s, or all)
+    width = min(lanes, -(-rank // ROW_LANES) * ROW_LANES)
+    # next_live[0]: the first slot with keys; next_live[s + 1]: the first
+    # after s (``slots`` when there is none)
+    live_at = jnp.where(count > 0, jnp.arange(slots, dtype=jnp.int32),
+                        slots)
+    next_live = jnp.concatenate([lax.cummin(live_at, reverse=True),
+                                 jnp.full((1,), slots, jnp.int32)])
+
+    def of_slot(*block):
+        return pl.BlockSpec((1, *block), lambda s, *_: (s, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, pages_per_slot=pb, group=group,
+                          width=width, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(slots,),
+            in_specs=[of_slot(heads, lanes),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      of_slot(pb // group, group * page)],
+            out_specs=of_slot(heads, width),
+            scratch_shapes=[
+                pltpu.VMEM((_BUFFERS, group * page, lanes), pool.dtype),
+                pltpu.SemaphoreType.DMA((_BUFFERS, group)),
+                pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, heads, width), q_row.dtype),
+        # the slots in order on one core: a slot's last group fetches the
+        # next live slot's first
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name=KERNEL_NAME,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      jnp.maximum(table, 0).astype(jnp.int32).reshape(-1), count, next_live,
+      q_row, pool,
+      flags.astype(jnp.int32).reshape(slots, pb // group, group * page))
+    return out[..., :rank]
+
+
+def _query_rows(inputs: LatentInputs, lanes: int):
+    """A step's queries in the rows' own layout [B, H, lanes]: the
+    no-position part through the keys' half of the expansion (``q~``),
+    the rotary part beside it, zeros against the rows' spare lanes: the
+    rows are contracted as they lie, never sliced."""
     r = inputs.wkv_b.shape[0]
     dn = inputs.q.shape[-1] - (inputs.row.shape[-1] - r)
     q = inputs.q[:, 0]                                   # [B, H, dn + dr]
-    wk, wv = inputs.wkv_b[..., :dn], inputs.wkv_b[..., dn:]
-    q_latent = jnp.einsum("bhn,rhn->bhr", q[..., :dn], wk,
+    q_latent = jnp.einsum("bhn,rhn->bhr", q[..., :dn],
+                          inputs.wkv_b[..., :dn],
                           preferred_element_type=jnp.float32)
-    # the query in the row's own layout, zeros against its spare lanes:
-    # the rows are contracted as they lie, never sliced
-    q_row = jnp.concatenate(
+    return jnp.concatenate(
         [q_latent.astype(q.dtype), q[..., dn:],
-         jnp.zeros((*q.shape[:2], rows.shape[-1] - inputs.row.shape[-1]),
-                   q.dtype)], -1)
-    scores = jnp.einsum("bhw,bsw->bhs", q_row, rows,
-                        preferred_element_type=jnp.float32) * inputs.scale
-    scores = jnp.where(mask[:, None, :], scores, _MASKED)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_latent = jnp.einsum("bhs,bsr->bhr", probs.astype(rows.dtype),
-                          rows[..., :r], preferred_element_type=jnp.float32)
-    return jnp.einsum("bhr,rhv->bhv", o_latent.astype(q.dtype), wv,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+         jnp.zeros((*q.shape[:2], lanes - inputs.row.shape[-1]), q.dtype)],
+        -1)
 
 
-def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
-                            table, pos, *, window=None):
-    """A decode step's attention, one query a slot at position ``pos``
-    [B], over the slot's rows where they lie in ``pools`` (the run's:
-    latent rows, then index keys where the layer has an indexer) through
-    the page table [B, PB], the step's own row written already. A layer
-    with an indexer reads its slots' index keys, then the chosen latent
-    rows alone; a windowed layer the pages of its window. Returns [B, H,
-    dv]. A dead slot (the caller gives it position 0) reads one row of
-    whatever page its table names and its result is dropped."""
-    pool = pools[0]
+def _in_place(q_row, pool, layer, table, count, chosen, *, rank, scale,
+              topk):
+    """The kernel's formulation of a layer that selects; arguments as
+    ``_gathered``'s."""
+    return latent_decode_attention_kernel(
+        q_row, pool, layer, table, count,
+        (chosen > _MASKED) & kept(chosen, topk), rank=rank, scale=scale)
+
+
+def _gathered(q_row, pool, layer, table, count, chosen=None, *, rank, scale,
+              topk, window):
+    """The plain formulation: ``q_row`` [B, H, lanes] over the rows that
+    slot b's query attends over, copied out of ``pool`` through ``table``
+    [B, PB]: of the ``count`` [B] keys it sees, the ``topk`` of largest
+    ``chosen`` ([B, PB x page] float32 index scores, ``_MASKED`` past the
+    count) by position, else its ``window``'s pages, else all. Returns
+    the probability-weighted rows' first ``rank`` numbers [B, H, rank] in
+    ``q_row``'s type."""
     page = pool.shape[2]
-    chosen = None
-    if inputs.index is not None:
-        chosen = select_keys(inputs.index, pools[1], layer, table, pos + 1)
     if chosen is not None:
-        positions, mask = chosen
+        values, positions = lax.top_k(chosen, topk)
+        # a key past the slot's count comes out with the mask's own value
+        # (a gather of the seen keys at the positions says the same, a
+        # scalar at a time: 1.3 ms a layer on a v5e at 64 x 2,048)
+        mask = values > _MASKED
         # each position's page id: the table's entry at its page, picked
         # by comparison (a gather of 2,048 scalars a slot out of the
         # table takes 1.0 ms a layer on a v5e; this a few microseconds)
@@ -172,18 +386,76 @@ def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
                         axis=-1)
         rows = pool[layer, pages, positions % page]       # [B, topk, w]
     else:
-        key_start = jnp.zeros_like(pos)
+        key_start = jnp.zeros_like(count)
         if window is not None:
             table, key_start = visible_pages(
-                table, pos - window + 1, -(-(page + window - 1) // page),
-                page)
+                table, count - window, -(-(page + window - 1) // page), page)
         rows = gather_rows(pool, layer, table)               # [B, S, w]
         kpos = key_start[:, None] + jnp.arange(rows.shape[1],
                                                dtype=jnp.int32)
-        mask = kpos <= pos[:, None]
+        mask = kpos < count[:, None]
         if window is not None:
-            mask = mask & (kpos > pos[:, None] - window)
-    return _absorbed(inputs, rows, mask)
+            mask = mask & (kpos >= count[:, None] - window)
+    scores = jnp.einsum("bhw,bsw->bhs", q_row, rows,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask[:, None, :], scores, _MASKED)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhs,bsr->bhr", probs.astype(rows.dtype),
+                      rows[..., :rank], preferred_element_type=jnp.float32
+                      ).astype(q_row.dtype)
+
+
+@functools.cache
+def _formulations(rank, scale, topk, window):
+    """(in place, gathered) for a layer of these statics, made once: the
+    branches ``lax.platform_dependent`` takes are then the same functions
+    from call to call, and an engine's decode programs that differ in
+    their chunk alone trace them once
+    (``ops/paged_decode_attention.py:paged_decode_attention``)."""
+    statics = dict(rank=rank, scale=scale, topk=topk)
+    return (functools.partial(_in_place, **statics),
+            functools.partial(_gathered, window=window, **statics))
+
+
+def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
+                            table, pos, *, window=None, active=None):
+    """A decode step's attention, one query a slot at position ``pos``
+    [B], over the slot's rows where they lie in ``pools`` (the run's:
+    latent rows, then index keys where the layer has an indexer) through
+    the page table [B, PB], the step's own row written already; a slot
+    that is not ``active`` ([B] bool; none: all are) sees no key and its
+    result, unspecified, is dropped. A layer with an indexer scores its
+    slots' index keys and attends over the ``topk`` best, a windowed
+    layer over its window. Returns [B, H, dv].
+
+    On a program lowered for a TPU, where ``latent_kernel_engages``, the
+    rows are read in place by the kernel; everywhere else they are
+    gathered (module docstring). A windowed layer's are gathered on every
+    platform: its gather is whole pages already, and through the kernel
+    it took as long (module docstring)."""
+    pool = pools[0]
+    page, r = pool.shape[2], inputs.wkv_b.shape[0]
+    index = inputs.index
+    count = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
+    topk = None
+    args = (_query_rows(inputs, pool.shape[-1]), pool, layer, table, count)
+    if index is not None and table.shape[1] * page > index.topk:
+        # (a table of no more than ``topk`` keys: nothing is dropped)
+        topk = index.topk
+        keys = gather_rows(pools[1], layer, table)            # [B, S, dI]
+        scores = index_scores(index.q, index.weights, keys)[:, 0]
+        seen = jnp.arange(keys.shape[1], dtype=jnp.int32) < count[:, None]
+        args += (jnp.where(seen, scores, _MASKED),)
+    in_place, gathered = _formulations(r, inputs.scale, topk, window)
+    if latent_kernel_engages(page, table.shape[1], topk):
+        o_latent = lax.platform_dependent(*args, tpu=in_place,
+                                          default=gathered)
+    else:
+        o_latent = gathered(*args)
+    dn = inputs.q.shape[-1] - (inputs.row.shape[-1] - r)
+    return jnp.einsum("bhr,rhv->bhv", o_latent, inputs.wkv_b[..., dn:],
+                      preferred_element_type=jnp.float32
+                      ).astype(inputs.q.dtype)
 
 
 def query_block(n: int, t: int, heads: int, keys: int, window) -> int:
@@ -272,13 +544,7 @@ def latent_prefill_attention(inputs: LatentInputs, pools: tuple, layer,
         if iq is not None and kr.shape[1] > index.topk:
             chosen = jnp.where(mask, index_scores(iq, iw, index_keys),
                                _MASKED)
-            # the topk-th largest score; every key above it, and of
-            # those AT it the lowest positions that fill the count
-            kth = jax.lax.top_k(chosen, index.topk)[0][..., -1:]
-            above, ties = chosen > kth, chosen == kth
-            room = index.topk - jnp.sum(above, axis=-1, keepdims=True)
-            mask = mask & (above | (ties & (
-                jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room)))
+            mask = mask & kept(chosen, index.topk)
         scores = jnp.where(mask[:, None], scores, _MASKED)
         probs = jax.nn.softmax(scores, axis=-1)
         return jnp.einsum("nhts,nhsv->nthv", probs.astype(q.dtype), v,

@@ -27,9 +27,14 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   names the same ``page_size`` tokens in all of them, so a reused
   prefix brings its rows of every layer. Decode attends over such rows
   in the absorbed form where they lie, prefill in the expanded one
-  (``ops/latent_attention.py``), both plain ``jax.numpy``; for a plan of
-  K/V twins alone the arrays, the programs' arguments and their lowered
-  text are what they were.
+  (``ops/latent_attention.py``: plain ``jax.numpy``, but for a decode
+  step's layers that pick their keys, which on a program lowered for a
+  TPU read the slot's rows in place in a Pallas kernel, the selection a
+  mask, while the table holds no more than eight times the keys they
+  pick; the dispatch's span says so, ``latent_kernel``, and ``stats()``
+  counts ``latent_kernel_dispatches`` of ``decode_dispatches``); for a
+  plan of K/V twins alone the arrays, the programs' arguments and their
+  lowered text are what they were.
   Each slot owns a page list. HBM scales with TOKENS IN FLIGHT
   (reserved per request = prompt + max_new_tokens), not with
   ``max_batch * max_len`` — a 256-token chat on a 2048-token engine
@@ -195,6 +200,7 @@ import numpy as np
 
 from ray_tpu.models.decoding import select_tokens
 from ray_tpu.ops.latent_attention import (latent_decode_attention,
+                                          latent_kernel_engages,
                                           latent_prefill_attention,
                                           write_latent)
 from ray_tpu.ops.norms import rms_norm
@@ -544,6 +550,12 @@ class PagedLLMEngine:
         # rows a step reads after its selection)
         self._selects = next(
             (run.selects for run in plan if run.selects is not None), None)
+        # decode dispatches whose program reads such a layer's rows in
+        # place, in the latent kernel: lowered for a TPU, by the rule on
+        # the program's own table (``_dispatch_decode``)
+        self._latent_backend = (jax.default_backend() == "tpu"
+                                and self._selects is not None)
+        self.latent_kernel_dispatches = 0
         # the rows a token keeps in a page, by format, for the prefill
         # dispatch's span: "k+v" for K/V twins, else the rows' names and
         # widths
@@ -715,7 +727,8 @@ class PagedLLMEngine:
                         cfg, p, x, *rotary[run.kind])
                     held = write_latent(inputs, held, layer, pidx, ip)
                     attn = latent_decode_attention(
-                        inputs, held, layer, table, pos, window=run.window)
+                        inputs, held, layer, table, pos, window=run.window,
+                        active=active)
                 else:
                     q, k, v = model.attention_projections(
                         cfg, p, x, *rotary[run.kind])
@@ -1610,6 +1623,9 @@ class PagedLLMEngine:
             self._chunk_stats.append(stats)
             self.decode_dispatches += 1
             self.state_kernel_dispatches += int(self._state_kernel)
+            latent_kernel = self._latent_backend and latent_kernel_engages(
+                self.page_size, pb, self._selects)
+            self.latent_kernel_dispatches += int(latent_kernel)
             now = time.monotonic()
             stream_seq = next(self._stream_seq)
             if ph:
@@ -1625,10 +1641,12 @@ class PagedLLMEngine:
                         np.minimum(rows, self._window).sum()))
                 if self._selects is not None:
                     # a layer with an indexer scores every row's index
-                    # key and reads the rows it picks
+                    # key and attends over the rows it picks: gathered,
+                    # or read in place among the slot's (the kernel)
                     ph.set(index_rows=int(rows.sum()),
                            kv_rows_selected=int(
-                               np.minimum(rows, self._selects).sum()))
+                               np.minimum(rows, self._selects).sum()),
+                           latent_kernel=int(latent_kernel))
                 if self._state:
                     # the live slots' recurrent state, which one step
                     # reads once and writes once in every layer, and
@@ -1837,6 +1855,7 @@ class PagedLLMEngine:
             "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
             "decode_dispatches": self.decode_dispatches,
             "state_kernel_dispatches": self.state_kernel_dispatches,
+            "latent_kernel_dispatches": self.latent_kernel_dispatches,
             # every slot-step the decode programs computed, by what
             # became of it, and the prefill programs' token-rows with
             # the prompt tokens among them (see __init__)

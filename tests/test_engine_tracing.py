@@ -606,6 +606,85 @@ def test_stats_count_the_pools_own_bytes_and_what_a_token_keeps(family):
         assert decodes and selected == [None] * len(decodes)
 
 
+def _decode_spans_of(eng, prompts, new_tokens=9):
+    """The ``engine.dispatch_decode`` spans of ``prompts`` served one
+    after another, and the engine's ``stats()`` after them."""
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        eng.start()
+        for prompt in prompts:
+            assert len(list(eng.submit(
+                prompt, max_new_tokens=new_tokens).tokens())) == new_tokens
+        eng.stop()
+        decodes = tracing.recorded_spans("engine.dispatch_decode")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    assert eng.error is None
+    return decodes, eng.stats()
+
+
+_LATENT_KERNEL_CASES = [
+    # the backend the engine finds, the keys its full layers keep (of a
+    # table of 256), latent_kernel
+    ("cpu", 64, 0), ("tpu", 64, 1), ("tpu", 12, 0), ("tpu", 256, 0)]
+
+
+@pytest.mark.parametrize(
+    "backend,topk,engaged", _LATENT_KERNEL_CASES,
+    ids=[f"{b}-top{n}" for b, n, _ in _LATENT_KERNEL_CASES])
+def test_decode_dispatches_say_whether_their_program_holds_the_latent_kernel(
+        monkeypatch, backend, topk, engaged):
+    """``latent_kernel`` on ``engine.dispatch_decode`` is the rule the
+    program's full layers were traced by (``ops/latent_attention.py``:
+    ``latent_kernel_engages``) applied to the dispatched program's own
+    table (two pages of 128 here), on a TPU backend alone; ``stats()``
+    counts the decode dispatches that took it. On the CPU it is 0 of n
+    whatever the shapes; an engine that FINDS a TPU backend (it is told
+    so here, as it is built; its programs still lower for the CPU) says 1
+    where the table holds more than ``topk`` keys and no more than eight
+    times as many, 0 where it holds twenty times as many and 0 where
+    nothing is selected."""
+    from ray_tpu.models import dots3_note
+    from ray_tpu.serve import paged_llm
+
+    cfg = dots3_note.dots3_note_tiny(index_topk=topk)
+    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: backend)
+    eng = PagedLLMEngine(cfg, dots3_note.init_params(cfg, jax.random.key(0)),
+                         max_batch=2, max_len=256, page_size=128,
+                         num_pages=8)
+    monkeypatch.undo()
+    rng = np.random.default_rng(3)
+    decodes, stats = _decode_spans_of(
+        eng, [rng.integers(1, 100, n) for n in (70, 7, 90)])
+    assert decodes and stats["decode_dispatches"] == len(decodes)
+    assert [s["attrs"]["latent_kernel"] for s in decodes] == \
+        [engaged] * len(decodes)
+    assert stats["latent_kernel_dispatches"] == engaged * len(decodes)
+
+
+@pytest.mark.parametrize("family", ["llama", "olmoe", "laguna", "falcon_h1"])
+def test_the_older_plans_carry_no_latent_kernel_count(monkeypatch, family):
+    """A plan with no layer that picks its keys says nothing of the
+    latent kernel on its decode dispatches and counts none, on an engine
+    that finds a TPU backend too."""
+    from ray_tpu.models import falcon_h1, laguna, olmoe
+    from ray_tpu.serve import paged_llm
+
+    model, cfg = {"llama": (llama, llama.llama_tiny),
+                  "olmoe": (olmoe, olmoe.olmoe_tiny),
+                  "laguna": (laguna, laguna.laguna_tiny),
+                  "falcon_h1": (falcon_h1, falcon_h1.falcon_h1_tiny)}[family]
+    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: "tpu")
+    eng, _ = _pool_stats(model, cfg(), prefix_cache=False)
+    monkeypatch.undo()
+    decodes, stats = _decode_spans_of(eng, [np.arange(1, 40)], new_tokens=5)
+    assert decodes and stats["decode_dispatches"] == len(decodes)
+    assert not any("latent_kernel" in s["attrs"] for s in decodes)
+    assert stats["latent_kernel_dispatches"] == 0
+
+
 def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):
     clear_ring()
     assert not tracing.recording()

@@ -1,0 +1,223 @@
+"""The latent decode-attention kernel (ops/latent_attention.py), in
+interpret mode on the CPU: against a plain float32 attention over the
+slots' chosen rows and against the gathered formulation, at both row
+shapes of the serving cell (640 and 1,152 lanes; few heads, small pages)
+and at tables that walk in groups of one, two, four and eight pages; the
+selection's set against ``lax.top_k``'s, key for key; the shape rule and
+what the lowered text holds on each side of it; and the tiny model
+through the kernel. (Its Mosaic compile at the real shapes:
+tests/test_tpu_compile.py.)"""
+
+import math
+from functools import partial
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import dots3_note
+from ray_tpu.ops import latent_attention as la
+
+PAGE, TABLE, POOL, LAYERS, TOPK = 16, 6, 64, 3, 24
+# heads, latent rank, rotary numbers, lanes: a full layer's row and a
+# sliding layer's, as the cell's pools hold them
+SHAPES = {"full-640": (4, 512, 64, 640), "sliding-1152": (2, 1024, 64, 1152)}
+# a dead slot; fewer keys than the selection keeps; a count that ends
+# mid-page; one that ends with a page; the last live slot, whose index
+# scores tie at the selection's edge
+COUNTS = np.array([0, 19, 53, 64, 77], np.int32)
+SCALE = 0.11
+
+
+def _case(shape, table_pages=TABLE, seed=0):
+    heads, rank, rope, lanes = SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    pool = rng.standard_normal((LAYERS, POOL, PAGE, lanes)) * 0.4
+    pool[..., rank + rope:] = 0
+    q = rng.standard_normal((len(COUNTS), heads, lanes)) * 0.4
+    q[..., rank + rope:] = 0
+    # scattered pages, holes past each slot's reserved pages
+    table = rng.permutation(POOL)[:len(COUNTS) * table_pages].reshape(
+        -1, table_pages)
+    for slot, n in enumerate(COUNTS):
+        table[slot, -(-n // PAGE) + 1:] = -1
+    scores = rng.standard_normal((len(COUNTS), table_pages * PAGE))
+    scores = scores.astype(np.float32)
+    # slot 4: forty keys share the score at the selection's 24th place
+    scores[4, 5:45] = np.sort(scores[4, :77])[-20]
+    seen = np.arange(table_pages * PAGE)[None] < COUNTS[:, None]
+    return dict(
+        q=jnp.asarray(q, jnp.bfloat16), pool=jnp.asarray(pool, jnp.bfloat16),
+        layer=jnp.int32(2), table=jnp.asarray(table, jnp.int32),
+        count=jnp.asarray(COUNTS),
+        chosen=jnp.where(seen, scores, la._MASKED), rank=rank)
+
+
+def _chosen_keys(chosen, topk):
+    """What ``lax.top_k`` picks of each slot's seen keys, as sets."""
+    values, positions = jax.lax.top_k(chosen, topk)
+    return [set(np.asarray(p)[np.asarray(v) > la._MASKED].tolist())
+            for v, p in zip(values, positions)]
+
+
+def plain_attention(case, keys_of_slot):
+    """Float32, one slot at a time: softmax over the slot's ``keys`` of
+    the rows its pages hold in table order, the probabilities over the
+    rows' first ``rank`` numbers."""
+    q = np.asarray(case["q"], np.float32)
+    pool = np.asarray(case["pool"], np.float32)[int(case["layer"])]
+    table = np.asarray(case["table"])
+    out = np.zeros((*q.shape[:2], case["rank"]), np.float32)
+    for b, keys in enumerate(keys_of_slot):
+        if not keys:
+            continue
+        rows = np.concatenate([pool[max(p, 0)] for p in table[b]])
+        rows = rows[sorted(keys)]
+        s = q[b] @ rows.T * SCALE
+        w = np.exp(s - s.max(-1, keepdims=True))
+        out[b] = (w / w.sum(-1, keepdims=True)) @ rows[:, :case["rank"]]
+    return out
+
+
+@pytest.mark.parametrize("table_pages", [5, 6, 8, 12])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_kernel_is_plain_latent_attention(shape, table_pages):
+    """Slot 0 is dead and the others are live after it (the next live
+    slot's first pages are fetched while the last one's compute); layer 2
+    of a stacked pool whose other layers hold other numbers; tables of 5,
+    6, 8 and 12 pages, walked a page, two, eight and four at a time, so that a
+    slot's last group holds pages it has and pages it has not."""
+    case = _case(shape, table_pages)
+    assert math.gcd(table_pages, la._GROUP) == {5: 1, 6: 2, 8: 8, 12: 4}[table_pages]
+    args = (case["q"], case["pool"], case["layer"], case["table"],
+            case["count"])
+    flags = (case["chosen"] > la._MASKED) & la.kept(case["chosen"], TOPK)
+    got = la.latent_decode_attention_kernel(
+        *args, flags, rank=case["rank"], scale=SCALE, interpret=True)
+    assert got.shape == (*case["q"].shape[:2], case["rank"])
+    assert got.dtype == case["q"].dtype
+    got = np.asarray(got, np.float32)
+    want = plain_attention(case, _chosen_keys(case["chosen"], TOPK))
+    live = COUNTS > 0
+    # bf16 probabilities and a bf16 result: 2**-8 of values of order 1
+    assert np.abs(got - want)[live].max() < 2e-2
+    assert np.isfinite(got).all() and not got[~live].any()
+    # and the formulation every other platform runs is the same function
+    plain = np.asarray(la._gathered(
+        *args, case["chosen"], rank=case["rank"], scale=SCALE, topk=TOPK,
+        window=None), np.float32)
+    assert np.abs(plain - want)[live].max() < 2e-2
+    assert np.abs(plain - got)[live].max() < 2e-2
+
+
+TIES = {
+    "none": lambda s: s,
+    "at-the-edge": lambda s: np.where(
+        (np.arange(s.size) % 3 == 0), np.sort(s)[-TOPK], s),
+    "all-equal": lambda s: np.zeros_like(s),
+    "two-values": lambda s: np.where(s > 0, 1.0, 0.0).astype(np.float32),
+}
+
+
+@pytest.mark.parametrize("count", [7, TOPK, TOPK + 1, 90, TABLE * PAGE])
+@pytest.mark.parametrize("ties", TIES)
+def test_the_mask_is_top_ks_set_key_for_key(ties, count):
+    """``kept`` over a slot's seen keys is the set ``select_keys``'s
+    ``lax.top_k`` returns by position (ties to the lower position), with
+    scores that tie across the ``topk``-th place; a slot that sees no
+    more than ``topk`` keys keeps all of them and no unseen one."""
+    rng = np.random.default_rng(count)
+    scores = TIES[ties](rng.standard_normal(TABLE * PAGE).astype(np.float32))
+    seen = np.arange(TABLE * PAGE) < count
+    chosen = jnp.where(seen, scores, la._MASKED)[None]
+    flags = np.asarray((chosen > la._MASKED) & la.kept(chosen, TOPK))[0]
+    assert set(np.nonzero(flags)[0].tolist()) == _chosen_keys(chosen, TOPK)[0]
+    assert flags.sum() == min(count, TOPK) and not flags[count:].any()
+
+
+def _lowered(table_pages, platform, page=128, topk=256):
+    """The text of one decode step's attention of a layer with an
+    indexer over a table of ``table_pages`` pages, lowered for
+    ``platform``."""
+    heads, rank, rope, lanes = 4, 128, 64, 256
+    slots, pages = 2, 8
+    inputs = la.LatentInputs(
+        jnp.zeros((slots, 1, heads, 32 + rope), jnp.bfloat16),
+        jnp.zeros((slots, 1, rank + rope), jnp.bfloat16),
+        jnp.zeros((rank, heads, 32 + 16), jnp.bfloat16), 0.1,
+        la.IndexInputs(jnp.zeros((slots, 1, 2, 128), jnp.bfloat16),
+                       jnp.zeros((slots, 1, 2), jnp.float32),
+                       jnp.zeros((slots, 1, 128), jnp.bfloat16), topk))
+    pools = (jnp.zeros((1, pages, page, lanes), jnp.bfloat16),
+             jnp.zeros((1, pages, page, 128), jnp.bfloat16))
+    fn = jax.jit(partial(la.latent_decode_attention, inputs))
+    return fn.trace(pools, jnp.int32(0),
+                    jnp.zeros((slots, table_pages), jnp.int32),
+                    jnp.zeros((slots,), jnp.int32)).lower(
+        lowering_platforms=(platform,)).as_text()
+
+
+@pytest.mark.parametrize("table_pages,engages", [
+    (2, False),      # 256 keys: no more than topk, nothing is selected
+    (4, True),       # 2 x topk
+    (16, True),      # 8 x topk: the last table that reads in place
+    (32, False),     # 16 x topk: the gather by position
+], ids=["1x", "2x", "8x", "16x"])
+def test_the_shape_rule_says_which_formulation_a_tpu_program_holds(
+        table_pages, engages):
+    """Lowered for a TPU, a layer with an indexer holds the kernel where
+    its table holds more than ``topk`` keys and no more than
+    ``GATHER_PAST`` times as many, and the gather of the chosen rows (and
+    no kernel) on the other side; lowered for the CPU it holds the gather
+    on both."""
+    assert la.latent_kernel_engages(128, table_pages, 256) is engages
+    assert la.GATHER_PAST == 8
+    text = _lowered(table_pages, "tpu")
+    assert ("tpu_custom_call" in text) is engages
+    assert (la.KERNEL_NAME in text) is engages
+    # the rows copied out of the pool: the 256 chosen ones (or, at 1x,
+    # the table's 256)
+    gathers = "stablehlo.gather" in text and "tensor<2x256x256xbf16>" in text
+    assert gathers is (not engages)
+    assert "tpu_custom_call" not in _lowered(table_pages, "cpu")
+
+
+def test_small_pages_and_layers_without_an_indexer_stay_gathered():
+    assert not la.latent_kernel_engages(16, 64, 256)     # flags of 16 lanes
+    assert not la.latent_kernel_engages(128, 8, None)    # no selection
+
+
+@pytest.fixture
+def through_the_kernel(monkeypatch):
+    """Every decode-form attention of a layer that selects through the
+    kernel in interpret mode, whatever the rule says of its page size:
+    both branches of the entry's choice are the in-place formulation."""
+    monkeypatch.setattr(
+        la, "latent_decode_attention_kernel",
+        partial(la.latent_decode_attention_kernel, interpret=True))
+    formulations = la._formulations.__wrapped__
+
+    def in_place_where_it_selects(rank, scale, topk, window):
+        in_place, gathered = formulations(rank, scale, topk, window)
+        return in_place, gathered if topk is None else in_place
+
+    monkeypatch.setattr(la, "_formulations", in_place_where_it_selects)
+    monkeypatch.setattr(la, "latent_kernel_engages",
+                        lambda page, table_pages, topk: topk is not None)
+
+
+def test_the_model_decodes_through_the_kernel(through_the_kernel):
+    """The tiny model (float32; the leading dense full layer, a full
+    layer, three sliding ones; a selection of 12 keys, a window of 9) one
+    query at a time with the full layers' attention in the kernel,
+    against the expanded form over the same rows: 40 positions, so the
+    selection drops keys from the thirteenth on, in one page of the whole
+    prompt."""
+    cfg = dots3_note.dots3_note_tiny()
+    params = dots3_note.init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 40), 0, 128)
+    np.testing.assert_allclose(
+        dots3_note.forward(cfg, params, tokens, absorbed=True),
+        dots3_note.forward(cfg, params, tokens), atol=1e-4)

@@ -815,3 +815,89 @@ def test_state_kernel_compiles_alone(v5e_2x2, heads, width, size, groups):
     assert mem.alias_size_in_bytes == 2 * slots * heads * width * size * 4
     assert mem.temp_size_in_bytes < 1 << 20
 
+
+
+# dots3-note-prev cut to its first five layers (``serve-note-gen``): 64
+# slots, 2,816 pages of latent rows in whole lanes, tables of 64 pages
+_NOTE_SLOTS, _NOTE_PAGES, _NOTE_TABLE = 64, 2816, 64
+# the latent kernel's instruction, under its name: the rows' pool among
+# its operands, the weighted rows [slots, heads, rank] its result
+_LATENT_KERNEL = re.compile(
+    r"%latent_decode_attn[.\d]* = bf16\[64,128,512\]\S* custom-call\("
+    r".*tpu_custom_call.*bf16\[2,2816,128,640\]")
+
+
+@pytest.mark.parametrize("heads,rank,lanes,table", [
+    (128, 512, 640, 64), (128, 512, 640, 32), (64, 1024, 1152, 64),
+    (16, 128, 256, 6)],
+    ids=["full-layer", "half-table", "sliding-row", "groups-of-two"])
+def test_latent_kernel_compiles_alone(v5e_2x2, heads, rank, lanes, table):
+    """The kernel by itself at the full layers' shape in the cell's two
+    tables, at the sliding layers' row (which no program gives it) and at
+    a table it walks two pages at a time: the chip's compiler takes the
+    tiles, the page buffers' slices and the flags' blocks."""
+    from ray_tpu.ops.latent_attention import latent_decode_attention_kernel
+
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(partial(latent_decode_attention_kernel, rank=rank,
+                               scale=0.07)).lower(
+        shape((_NOTE_SLOTS, heads, lanes), jnp.bfloat16),
+        shape((2, 600, 128, lanes), jnp.bfloat16), shape((), jnp.int32),
+        shape((_NOTE_SLOTS, table), jnp.int32),
+        shape((_NOTE_SLOTS,), jnp.int32),
+        shape((_NOTE_SLOTS, table * 128), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
+    """The cell's decode program (chunk 16, the 64-page table): each of
+    its two runs of full layers holds the latent kernel, no operation
+    gathers the slots' 2,048 chosen rows (``bf16[131072,640]``), the
+    sliding layers gather their five pages as they did, and the program
+    needs less beside its arguments than the gathered one did (0.67
+    GB)."""
+    from ray_tpu.models import dots3_note
+    from ray_tpu.ops.paged_attention import row_pool
+    from ray_tpu.serve.paged_llm import _pool_slices
+
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cfg = dataclasses.replace(
+        dots3_note.dots3_note_prev(), vocab_size=19008, n_experts_held=32,
+        layer_types=("full_attention", "full_attention")
+        + ("sliding_attention",) * 3)
+    plan = dots3_note.layer_plan(cfg)
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(partial(dots3_note.init_params, cfg),
+                       jax.random.key(0)))
+    pools = [
+        shape(pool.shape, pool.dtype)
+        for rows in _pool_slices(plan)[0] for pool in (
+            jax.eval_shape(partial(
+                row_pool, sum(run.layers for run in plan
+                              if run.rows == rows), _NOTE_PAGES, 128, row))
+            for row in rows)]
+    assert [p.shape[-1] for p in pools] == [640, 128, 1152]
+    slots = _NOTE_SLOTS
+    compiled = jax.jit(
+        partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=16,
+                page_size=128, quantized=False),
+        donate_argnums=(1, 2, 3)).lower(
+        params, *pools, shape((slots, _NOTE_TABLE), jnp.int32),
+        shape((slots,), jnp.int32), shape((slots,), jnp.int32),
+        shape((slots,), jnp.bool_), shape((slots,), jnp.float32),
+        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    text = compiled.as_text()
+    assert len(_LATENT_KERNEL.findall(text)) == 2
+    assert "bf16[131072,640]" not in text
+    assert "bf16[320,128,1152]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.62e9
