@@ -58,7 +58,7 @@ from ray_tpu.ops.latent_attention import (IndexInputs, LatentInputs,
                                           latent_decode_attention,
                                           latent_prefill_attention,
                                           write_latent)
-from ray_tpu.ops.moe import moe_ffn_dropless
+from ray_tpu.ops.moe import moe_ffn_dropless, share_statistics
 from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.paged_attention import PageRow, row_pool
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
@@ -417,16 +417,7 @@ def feed_forward(cfg: Dots3NoteConfig, p, x, valid=None):
         valid=None if valid is None else valid.reshape(b * s),
         scoring="sigmoid", choice_bias=p["router_bias"])
     shared = (jax.nn.silu(h @ p["ws_gate"]) * (h @ p["ws_up"])) @ p["ws_down"]
-    load = load.astype(jnp.float32)
-    tokens = (jnp.float32(b * s) if valid is None
-              else jnp.sum(valid, dtype=jnp.float32))
-    stats = {
-        "experts_touched": jnp.sum(load > 0, dtype=jnp.float32),
-        "expert_load_max_over_mean":
-            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
-        "routed_here_share":
-            jnp.sum(load) / jnp.maximum(tokens * cfg.top_k, 1.0),
-    }
+    stats = share_statistics(load, valid, b * s, cfg.top_k)
     return x + routed.reshape(b, s, d) + shared, stats
 
 

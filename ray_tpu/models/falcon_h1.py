@@ -343,25 +343,34 @@ def _mixer_split(cfg, p, conv, dt):
     return xs, b, c, step, -jnp.exp(p["A_log"].astype(jnp.float32))
 
 
-def _mixer_out(cfg, p, y, xs, z):
-    """The mixer's end: the skip ``D x`` onto ``y`` [..., H, P] float32,
-    the gate ``silu(z)``, the RMS norm in ``ssm_groups`` groups (gate
-    first, norm second), ``out_proj``, ``ssm_out_multiplier``. Returns
-    the term the block adds to its stream, in the model's dtype."""
+def gated_norm(cfg, p, y, xs, z):
+    """The skip ``D x`` onto ``y`` [..., H, P] float32, the gate
+    ``silu(z)`` and the RMS norm in ``ssm_groups`` groups (gate first,
+    norm second): what ``out_proj`` takes, in its dtype."""
     lead = y.shape[:-2]
     y = y + p["D"].astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
     y = y.reshape(*lead, cfg.d_ssm) * jax.nn.silu(z)
     g = y.reshape(*lead, cfg.ssm_groups, cfg.d_ssm // cfg.ssm_groups)
     g = g * lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
                       + cfg.rms_eps)
-    y = (g.reshape(*lead, cfg.d_ssm) * p["ssm_norm"].astype(jnp.float32)
-         ).astype(p["out_proj"].dtype)
+    return (g.reshape(*lead, cfg.d_ssm) * p["ssm_norm"].astype(jnp.float32)
+            ).astype(p["out_proj"].dtype)
+
+
+def _mixer_out(cfg, p, y, xs, z):
+    """The mixer's end: ``gated_norm``, ``out_proj``,
+    ``ssm_out_multiplier``. Returns the term the block adds to its
+    stream, in the model's dtype."""
+    y = gated_norm(cfg, p, y, xs, z)
     out = jnp.einsum("...k,kd->...d", y, p["out_proj"],
                      preferred_element_type=jnp.float32)
     return (out * cfg.ssm_out_multiplier).astype(p["out_proj"].dtype)
 
 
-def recurrent_mixer(cfg: FalconH1Config, p, x, state, valid):
+_ENDS = (_mixer_in, _mixer_out)
+
+
+def recurrent_mixer(cfg, p, x, state, valid, *, ends=_ENDS):
     """The mixer over a padded block: the stream ``x`` [n, t, d] (the
     block's input; the mixer norms it as the attention does), each row's
     ``state`` (S [n, H, P, N] float32, tail [n, K-1, C]) before its
@@ -369,18 +378,23 @@ def recurrent_mixer(cfg: FalconH1Config, p, x, state, valid):
     token (a prefix of each row). Returns (the term to add to the
     stream [n, t, d], the state after each row's LAST VALID token).
     Padding moves nothing: the step is zeroed there (``ops/ssm.py``) and
-    the tail is read at the row's length."""
+    the tail is read at the row's length. ``ends``: the mixer's two ends
+    (its norm and input projection, as ``_mixer_in``; its output
+    projection after ``gated_norm``, as ``_mixer_out``), for another
+    family's mixer that is this one between them
+    (``models/nemotron_h.py``: no multipliers)."""
+    mixer_in, mixer_out = ends
     s0, tail = state
-    z, xbc, dt = _mixer_in(cfg, p, x)
+    z, xbc, dt = mixer_in(cfg, p, x)
     conv = causal_conv(xbc, tail, p["conv_w"], p["conv_b"])
     xs, b, c, step, a = _mixer_split(cfg, p, conv, dt)
     step = jnp.where(valid[..., None], step, 0.0)
     y, s1 = ssm_scan(xs, step, a, b, c, s0, chunk=cfg.ssm_chunk)
     lengths = jnp.sum(valid, axis=1, dtype=jnp.int32)
-    return _mixer_out(cfg, p, y, xs, z), (s1, last_rows(xbc, tail, lengths))
+    return mixer_out(cfg, p, y, xs, z), (s1, last_rows(xbc, tail, lengths))
 
 
-def recurrent_step(cfg: FalconH1Config, p, x, state, layer, active):
+def recurrent_step(cfg, p, x, state, layer, active, *, ends=_ENDS):
     """The mixer for one token a slot, over the slots' states where they
     lie: ``x`` [n, 1, d]; ``state`` the STACKED arrays (S [L, n, H, P,
     N] float32, tail [L, n, K-1, C]) of which this block's are at
@@ -390,17 +404,18 @@ def recurrent_step(cfg: FalconH1Config, p, x, state, layer, active):
     as they were. The float32 state goes through ``ssm_state_step`` (on
     a TPU one kernel that reads it once and writes it once, in place);
     the tail, 7 MB a layer at the published widths, is sliced and
-    written back here."""
+    written back here. ``ends``: as ``recurrent_mixer``'s."""
+    mixer_in, mixer_out = ends
     states, tails = state
     tail = tails[layer]
-    z, xbc, dt = _mixer_in(cfg, p, x)
+    z, xbc, dt = mixer_in(cfg, p, x)
     conv = causal_conv(xbc, tail, p["conv_w"], p["conv_b"])
     xs, b, c, step, a = _mixer_split(cfg, p, conv[:, 0], dt[:, 0])
     y, states = ssm_state_step(xs, step, a, b, c, states, layer, active)
     new_tail = jnp.concatenate([tail[:, 1:], xbc.astype(tail.dtype)], axis=1)
     tails = tails.at[layer].set(
         jnp.where(active[:, None, None], new_tail, tail))
-    return _mixer_out(cfg, p, y, xs, z[:, 0])[:, None], (states, tails)
+    return mixer_out(cfg, p, y, xs, z[:, 0])[:, None], (states, tails)
 
 
 def zero_state(cfg: FalconH1Config, rows: int) -> tuple:

@@ -52,7 +52,7 @@ from ray_tpu.models.llama import (  # noqa: F401 - embed, head_logits:
     LayerStack, embed, fanin_init,  # pieces of the block's module that
     head_logits, lm_head_weights)   # are Llama's
 from ray_tpu.ops.attention import cached_attention
-from ray_tpu.ops.moe import moe_ffn_dropless
+from ray_tpu.ops.moe import moe_ffn_dropless, share_statistics
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
 
@@ -340,16 +340,7 @@ def feed_forward(cfg: LagunaConfig, p, x, valid=None):
         routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
         valid=None if valid is None else valid.reshape(b * s))
     shared = (jax.nn.silu(h @ p["ws_gate"]) * (h @ p["ws_up"])) @ p["ws_down"]
-    load = load.astype(jnp.float32)
-    tokens = (jnp.float32(b * s) if valid is None
-              else jnp.sum(valid, dtype=jnp.float32))
-    stats = {
-        "experts_touched": jnp.sum(load > 0, dtype=jnp.float32),
-        "expert_load_max_over_mean":
-            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
-        "routed_here_share":
-            jnp.sum(load) / jnp.maximum(tokens * cfg.top_k, 1.0),
-    }
+    stats = share_statistics(load, valid, b * s, cfg.top_k)
     return x + routed.reshape(b, s, d) + shared, stats
 
 

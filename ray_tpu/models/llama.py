@@ -198,16 +198,23 @@ def attention_output(cfg, p, x, attn):
 
 class LayerStack(NamedTuple):
     """A run of identical layers, as the serving engine's layer loop
-    takes it (``layer_plan``)."""
+    takes it (``layer_plan``): where its weights are, and what one of its
+    layers HOLDS per sequence (pages, in the format ``rows`` names, or
+    none; recurrent state or none) and DOES (attention over those pages,
+    a recurrent mixer, a feed-forward: each or not). The defaults are a
+    transformer block's: K/V pages and attention, then a feed-forward.
+    The engine's stores have as many layers as the plan has layers that
+    keep them, and no more."""
     key: str | None      # ``params["blocks"][key]`` holds the run's weights
     #                      stacked on a leading axis; None: the blocks do
     kind: str            # which of ``rotary_tables`` its attention takes
     window: int | None   # keys a query sees (a sliding layer); None: all
     layers: int
-    # what a sequence keeps per layer of the run BESIDE its KV pages, where
-    # the block holds a recurrent mixer (``models/falcon_h1.py:
-    # RecurrentState``: the arrays' shapes and dtypes, the scan's chunk);
-    # None: pages and nothing else
+    # what a sequence keeps per layer of the run BESIDE its pages, where
+    # the layers hold a recurrent mixer (``models/falcon_h1.py:
+    # RecurrentState``: the arrays' shapes and dtypes, the scan's chunk):
+    # beside the attention on the same input where ``attends``, else the
+    # layer's one sublayer; None: no mixer and no state
     state: tuple | None = None
     # what a token keeps in a page of a layer of the run, where that is
     # not a K row and a V row a KV head: the rows, one pool each
@@ -219,6 +226,12 @@ class LayerStack(NamedTuple):
     # learned selection: the rows then hold an index key); None: all it
     # may see
     selects: int | None = None
+    # whether the layers attend: project, write a page, read the pages.
+    # False: they keep NO pages (``kind``, ``window``, ``rows`` and
+    # ``selects`` then say nothing)
+    attends: bool = True
+    # whether the layers end in a feed-forward
+    feeds: bool = True
 
 
 def layer_plan(cfg) -> tuple:

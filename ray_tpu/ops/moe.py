@@ -1,4 +1,4 @@
-"""Mixture-of-Experts ops: two routed SwiGLU feed-forwards.
+"""Mixture-of-Experts ops: two routed feed-forwards.
 
 ``moe_ffn`` is the GShard/Switch formulation for TRAINING under expert
 parallelism: dispatch and combine are einsums against one-hot capacity
@@ -17,8 +17,10 @@ picks its formulation from the token count it is traced with
 expert parallelism: told which experts it holds (expert stacks narrower
 than the router, and the first held expert's index), it routes over all
 of them and computes the held experts' part of the result, without the
-exchange that would add the other chips' parts. (Reference has NO MoE
-implementation — SURVEY.md §2c row EP.)
+exchange that would add the other chips' parts. Its experts are of the
+form it is told (``EXPERT_FORMS``): a SwiGLU of three matrices, or two
+matrices with a squared ReLU between them and no gate. (Reference has NO
+MoE implementation — SURVEY.md §2c row EP.)
 """
 
 from __future__ import annotations
@@ -136,12 +138,17 @@ def moe_ffn(
 # about 2048 (8.4 ms), and its temporaries do not grow with E x F a token.
 DENSE_MAX_TOKENS = 1024
 
+# What one expert computes of a token ``x``, by the matrices it has:
+# ``swiglu``: ``(silu(x @ gate) * (x @ up)) @ down``; ``relu2``:
+# ``relu(x @ up) ** 2 @ down``, which has no gate (``wi_gate`` is None).
+EXPERT_FORMS = ("swiglu", "relu2")
+
 
 def moe_ffn_dropless(
     x,                  # [T, D] tokens (flattened batch*seq)
     router_w,           # [D, E]
-    wi_gate,            # [H, D, F]: the H <= E experts held here
-    wi_up,              # [H, D, F]
+    wi_gate,            # [H, D, F]: the H <= E experts held here (None
+    wi_up,              # [H, D, F]   where the experts' form has no gate)
     wo,                 # [H, F, D]
     *,
     top_k: int,
@@ -151,8 +158,9 @@ def moe_ffn_dropless(
     valid=None,         # [T] bool: rows that are tokens (None: all)
     scoring: str = "softmax",
     choice_bias=None,   # [E] float32, added to the scores for the choice
+    form: str = "swiglu",   # one expert's form: ``EXPERT_FORMS``
 ):
-    """Exact routed SwiGLU feed-forward. Returns (out [T, D], load [H]).
+    """Exact routed feed-forward. Returns (out [T, D], load [H]).
 
     ``p = softmax(float32(x) @ router_w)`` over all E experts (with
     ``scoring="sigmoid"`` each expert's sigmoid of its own logit); the
@@ -162,7 +170,8 @@ def moe_ffn_dropless(
     the weights are those
     probabilities as they are, or divided by their sum with
     ``norm_topk_prob``, times ``routed_scale``;
-    ``out = sum_k p_k * (silu(x @ gate_k) * (x @ up_k)) @ down_k`` over
+    ``out = sum_k p_k * (silu(x @ gate_k) * (x @ up_k)) @ down_k`` (with
+    ``form="relu2"``: ``sum_k p_k * relu(x @ up_k) ** 2 @ down_k``) over
     those of a token's chosen experts that are HELD here: experts
     ``first_expert`` to ``first_expert + H`` of the router's E, H the
     length of the expert stacks (all of them where H = E). A choice that
@@ -174,8 +183,12 @@ def moe_ffn_dropless(
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
                          f"got {scoring!r}")
+    if form not in EXPERT_FORMS or (wi_gate is None) != (form == "relu2"):
+        raise ValueError(f"form must be one of {EXPERT_FORMS}, with a gate "
+                         f"for 'swiglu' alone; got {form!r} and wi_gate "
+                         f"{'None' if wi_gate is None else 'given'}")
     t, d = x.shape
-    e = wi_gate.shape[0]
+    e = wi_up.shape[0]
     dtype = x.dtype
     with jax.named_scope("moe_router"):
         # true float32: the chip's default would round the products to
@@ -217,18 +230,44 @@ def moe_ffn_dropless(
     return out.astype(dtype), load
 
 
+def share_statistics(load, valid, rows: int, top_k: int) -> dict:
+    """What a feed-forward that holds a SHARE of its router's experts
+    reports of one call, scalars over the held experts, from their
+    ``load`` [H] (``moe_ffn_dropless``): how many got a token, the
+    busiest one's load over the mean load, and the share of the tokens'
+    ``top_k`` choices that fell on a held expert (``valid``: the rows
+    that are tokens, of ``rows``; None: all)."""
+    load = load.astype(jnp.float32)
+    tokens = (jnp.float32(rows) if valid is None
+              else jnp.sum(valid, dtype=jnp.float32))
+    return {
+        "experts_touched": jnp.sum(load > 0, dtype=jnp.float32),
+        "expert_load_max_over_mean":
+            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+        "routed_here_share":
+            jnp.sum(load) / jnp.maximum(tokens * top_k, 1.0),
+    }
+
+
+def _hidden(into, wi_gate, wi_up):
+    """An expert's hidden activations in float32, by its form: ``into(w)``
+    is the tokens' product with the stack ``w``. With a gate ``silu(x @
+    gate) * (x @ up)``; without one ``relu(x @ up) ** 2``."""
+    if wi_gate is None:
+        return jnp.square(jax.nn.relu(into(wi_up)))
+    return jax.nn.silu(into(wi_gate)) * into(wi_up)
+
+
 def _experts_all(x, weights, wi_gate, wi_up, wo):
     """Every expert over every token; ``weights`` [T, E] is zero where an
     expert was not chosen. The tokens are broadcast along the expert axis
     so that the up projections are plain batched matmuls, and the down
     projection contracts expert and width together, so nothing of shape
     [E, T, D] comes out of it."""
-    xe = jnp.broadcast_to(x, (wi_gate.shape[0],) + x.shape)     # [E, T, D]
-    h = jax.nn.silu(
-        jnp.einsum("etd,edf->etf", xe, wi_gate,
-                   preferred_element_type=jnp.float32)
-    ) * jnp.einsum("etd,edf->etf", xe, wi_up,
-                   preferred_element_type=jnp.float32)
+    xe = jnp.broadcast_to(x, (wi_up.shape[0],) + x.shape)       # [E, T, D]
+    h = _hidden(lambda w: jnp.einsum("etd,edf->etf", xe, w,
+                                     preferred_element_type=jnp.float32),
+                wi_gate, wi_up)
     h = (h * weights.T[:, :, None]).astype(x.dtype)
     return jnp.einsum("etf,efd->td", h, wo,
                       preferred_element_type=jnp.float32)
@@ -241,17 +280,14 @@ def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
     (``jax.lax.ragged_dot``) over the sorted rows, and each token's K
     weighted results summed where they came from."""
     t, k = gate_idx.shape
-    e = wi_gate.shape[0]
+    e = wi_up.shape[0]
     expert = gate_idx.reshape(t * k)
     if valid is not None:
         expert = jnp.where(jnp.repeat(valid, k), expert, e)
     order = jnp.argsort(expert)                    # stable: pair -> row
     xs = x[order // k]                             # [T*K, D]
-    h = jax.nn.silu(
-        jax.lax.ragged_dot(xs, wi_gate, load,
-                           preferred_element_type=jnp.float32)
-    ) * jax.lax.ragged_dot(xs, wi_up, load,
-                           preferred_element_type=jnp.float32)
+    h = _hidden(lambda w: jax.lax.ragged_dot(
+        xs, w, load, preferred_element_type=jnp.float32), wi_gate, wi_up)
     ys = jax.lax.ragged_dot(h.astype(x.dtype), wo, load,
                             preferred_element_type=jnp.float32)
     # rows past the last group belong to no expert: whatever they hold

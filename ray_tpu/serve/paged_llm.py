@@ -13,11 +13,19 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   streaming for everyone else. Tokens stream back through per-request
   queues (``serve/llm.py``: ``Request``).
 
-- The KV cache is a POOL of fixed-size pages [L, P, page, nkv, hd],
-  uniform over the layers whatever their kind (a model's full and
-  sliding layers share its KV heads and head size; a sliding layer keeps
-  every page too, and reads only its window's: releasing what has fallen
-  out of every window is ROADMAP Queue 2 B.1). Where a run of the
+- The KV cache is a POOL of fixed-size pages [L, P, page, nkv, hd] over
+  the L layers that ATTEND, uniform over them whatever their kind (a
+  model's full and sliding layers share its KV heads and head size; a
+  sliding layer keeps every page too, and reads only its window's:
+  releasing what has fallen out of every window is ROADMAP Queue 2 B.1).
+  What a layer holds and does is the layer plan's to say, run by run
+  (``LayerStack``: pages or none, recurrent state or none, attention, a
+  mixer, a feed-forward, each or not), and every store has as many
+  layers as the plan has layers that keep it: a layer that is a mixer
+  alone or a feed-forward alone (Nemotron-H's ``M`` and ``E``) has no
+  layer in any pool, a layer that is attention alone none in the state
+  arrays, and a run is handed its layers' places in each store it uses,
+  each store by its own count (``_plan_runs``, ``_places``). Where a run of the
   model's layer plan states that its layers keep ROWS and no K/V twins
   (``LayerStack.rows``: a latent-attention layer's one compressed row a
   token, and its indexer's key), the pools are what the plan states: one
@@ -110,17 +118,20 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   (``tests/test_tpu_compile.py:_stack_moves_in_loops``).
 
 - A sequence's state is its pages and, where the model's layer plan
-  has a RECURRENT run (a state-space mixer beside the attention:
-  ``LayerStack.state``), one more thing: per layer, the arrays the run
-  states (Falcon-H1: a float32 state [heads, width, state size] and the
-  last rows its convolution saw), which do not grow with the context.
-  They live in the SLOT: one array a kind [L, max_batch, ...], allocated
-  once, donated to both programs and got back in place, as the pools
-  are. A prefill runs the mixer over the padded prompt from the zero
+  has a RECURRENT run (a state-space mixer, beside the attention on the
+  same input as Falcon-H1's or a layer's one sublayer as Nemotron-H's:
+  ``LayerStack.state``), one more thing: per layer of such a run, the
+  arrays the run states (a float32 state [heads, width, state size] and
+  the last rows its convolution saw), which do not grow with the
+  context. They live in the SLOT: one array a kind [L', max_batch, ...]
+  over the L' layers that keep state, allocated once, donated to both
+  programs and got back in place, as the pools are. A prefill runs the
+  mixer over the padded prompt from the zero
   state (``recurrent_mixer``: padding moves nothing) and INSTALLS each
-  row's state after its last token at [layer, slot], whole; a decode
-  step hands the model's ``recurrent_step`` the STACKED arrays, the
-  layer and the slots that are active, and gets the arrays back with
+  row's state after its last token at [layer, slot], whole (the layer's
+  place among the layers that keep state); a decode
+  step hands the model's ``recurrent_step`` the STACKED arrays, that
+  place and the slots that are active, and gets the arrays back with
   the active slots' states at [layer] advanced one token and the others'
   as they were, bit for bit. The engine slices nothing out: the float32
   state is updated where it lies (``ops/ssm.py:ssm_state_step``: on a
@@ -140,10 +151,13 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
 
 - What is the MODEL's comes from the model's module, the one its
   config's class is defined in (``_model_module``, which checks it for
-  the pieces): the layer plan (``layer_plan``: the runs of identical
-  layers, each with its kind, its window or none, and what it keeps per
-  sequence beside pages, or nothing), the stream's start (``embed``),
-  the rotary tables of each kind (``rotary_tables``, once a step), the
+  the pieces its plan USES and no others): the layer plan
+  (``layer_plan``: the runs of identical layers, each with what its
+  layers hold and do: whether they attend, of what kind and under what
+  window, what they keep per sequence beside pages, whether they end in
+  a feed-forward), the stream's start (``embed``),
+  the rotary tables of each kind (``rotary_tables``, once a step; a
+  kind's may be empty: a model with no rotary embedding), the
   attention projections (``attention_projections``: norm, q/k/v,
   whatever the block does to them, rotary; for a run that keeps rows
   ``latent_projections``: the queries, the row a token keeps, the
@@ -159,8 +173,12 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   ``cached_attention``, whole or over blocks of queries), the scans over
   the plan's runs, sampling, the chunk loop. A
   feed-forward may hand back statistics of its call (scalars; a dense
-  one has none): the decode program averages them over the chunk's
-  layer-steps, and they go on the chunk's ``engine.emit`` span.
+  one has none, a run with no feed-forward likewise): the decode program
+  averages them over the chunk's steps and the layers that report them
+  (``_over_layers``), and they go on the chunk's ``engine.emit`` span.
+  What the plan's layers hold (the layers that keep pages, by format,
+  and state, with the bytes of a page and of a slot's state) is in
+  ``stats()`` and, while spans are recorded, on ``engine.construct``.
 
 - What the two programs compute and what reaches a client differ, and
   the loop keeps the account (always on, integers in ``stats()``; on the
@@ -237,29 +255,35 @@ def _bucket(n: int, minimum: int = 16) -> int:
     return b
 
 
-_PIECES = ("layer_plan", "rotary_tables", "embed", "attention_output",
-           "feed_forward", "head_logits")
+_PIECES = ("layer_plan", "embed", "head_logits")
+_ATTENTION_PIECES = ("rotary_tables", "attention_output")
 _KV_PIECES = ("attention_projections",)
 _LATENT_PIECES = ("latent_projections",)
 _RECURRENT_PIECES = ("recurrent_mixer", "recurrent_step")
+_FEED_PIECES = ("feed_forward",)
 
 
 def _model_module(cfg):
     """The module that states ``cfg``'s block: the one its config class
-    is defined in, which must hold the block's pieces (module
-    docstring): what attention takes in, as q, k and v where a run of
-    its plan keeps K/V twins and as a latent's inputs where it keeps
-    rows, and the mixer's two forms where its plan has a recurrent
-    run."""
+    is defined in, which must hold the pieces its layer plan USES and no
+    others (module docstring): the plan itself, the stream's start and
+    the head; where a run attends, what attention takes in (as q, k and v
+    where the run keeps K/V twins, as a latent's inputs where it keeps
+    rows), its rotary tables and its end; the mixer's two forms where a
+    run holds a recurrent mixer; the feed-forward where a run ends in
+    one."""
     model = sys.modules.get(type(cfg).__module__)
     missing = [name for name in _PIECES if not hasattr(model, name)]
-    if not missing:
+    if "layer_plan" not in missing:
         plan = model.layer_plan(cfg)
+        attends = [run for run in plan if run.attends]
         asked = (
-            _KV_PIECES * any(run.rows is None for run in plan)
-            + _LATENT_PIECES * any(run.rows is not None for run in plan)
-            + _RECURRENT_PIECES * (_recurrent(plan) is not None))
-        missing = [name for name in asked if not hasattr(model, name)]
+            _ATTENTION_PIECES * bool(attends)
+            + _KV_PIECES * any(run.rows is None for run in attends)
+            + _LATENT_PIECES * any(run.rows is not None for run in attends)
+            + _RECURRENT_PIECES * (_recurrent(plan) is not None)
+            + _FEED_PIECES * any(run.feeds for run in plan))
+        missing += [name for name in asked if not hasattr(model, name)]
     if missing:
         raise TypeError(
             f"the paged engine cannot serve {type(cfg).__name__}: its "
@@ -268,10 +292,10 @@ def _model_module(cfg):
 
 
 def _recurrent(plan):
-    """What the plan's recurrent runs keep per sequence and layer beside
-    the KV pages (``LayerStack.state``), or None where no run holds a
-    recurrent mixer. One statement a plan: the slots' arrays span every
-    layer, as the pools do."""
+    """What the plan's recurrent runs keep per sequence and layer
+    (``LayerStack.state``), or None where no run holds a recurrent mixer.
+    One statement a plan: the slots' arrays span the layers of every run
+    that states it, and no other layer."""
     states = {run.state for run in plan if run.state is not None}
     if len(states) > 1:
         raise ValueError("a layer plan's recurrent runs must keep the "
@@ -279,37 +303,85 @@ def _recurrent(plan):
     return next(iter(states), None)
 
 
+def _state_layers(plan) -> int:
+    """How many of the plan's layers keep recurrent state: the leading
+    axis of the slots' state arrays."""
+    return sum(run.layers for run in plan if run.state is not None)
+
+
 def _pool_slices(plan) -> tuple:
     """Where each page format of a layer plan lies among the pools the
     two programs carry: ({format: slice}, how many pools). A format is
-    what a run's layers keep a token (``LayerStack.rows``): None, the
-    K/V twins, which are four pools (K, V and their scale pools); else
-    the rows it names, a pool each. Runs of one format share its pools,
-    which span their layers in the plan's order."""
+    what the layers of a run that attends keep a token
+    (``LayerStack.rows``): None, the K/V twins, which are four pools (K,
+    V and their scale pools); else the rows it names, a pool each. Runs
+    of one format share its pools, which span THEIR layers in the plan's
+    order: a run that does not attend keeps no page and has no layer in
+    any pool."""
     slices, at = {}, 0
     for run in plan:
-        if run.rows not in slices:
+        if run.attends and run.rows not in slices:
             n = 4 if run.rows is None else len(run.rows)
             slices[run.rows] = slice(at, at + n)
             at += n
+    if not slices:
+        raise ValueError("no run of the layer plan attends: the engine "
+                         "admits, reserves and retires by pages")
     return slices, at
 
 
+def _pool_layers(plan, rows) -> int:
+    """How many of the plan's layers keep pages of the format ``rows``."""
+    return sum(run.layers for run in plan
+               if run.attends and run.rows == rows)
+
+
 def _plan_runs(plan, blocks, fuse=None) -> list:
-    """What each run of a layer plan scans over: (its stacked weights, its
-    layers' indices in its pools). Layers take the layers of their
-    format's pools in the plan's order. ``fuse``: what a module does to
-    its blocks once at a program's entry
+    """What each run of a layer plan scans over: (its stacked weights,
+    its layers' places). A run's layers take the layers of every store
+    they keep in the plan's order, each store by its own count: the
+    pools of the run's page format over the runs that attend, the slots'
+    state arrays over the runs that hold a mixer. The places are those in
+    the run's pools, or in the state arrays for a run that keeps no
+    page; ``_state_place`` gives the others. ``fuse``: what a module does
+    to its blocks once at a program's entry
     (``fuse_attention_projections``)."""
-    layers, first = [], {}
-    for run in plan:
-        at = first.get(run.rows, 0)
+    layers = []
+    for run, (pool_at, state_at) in zip(plan, _places(plan)):
+        at = pool_at if run.attends else state_at or 0
         layers.append(jnp.arange(at, at + run.layers))
-        first[run.rows] = at + run.layers
     if fuse is not None:
         blocks = fuse(blocks)
     return [(blocks if run.key is None else blocks[run.key], idx)
             for run, idx in zip(plan, layers)]
+
+
+def _places(plan) -> list:
+    """For each run, (its first layer's place in its format's pools, that
+    in the slots' state arrays), None for a store the run does not
+    keep."""
+    places, first, states = [], {}, 0
+    for run in plan:
+        pool_at = state_at = None
+        if run.attends:
+            pool_at = first.get(run.rows, 0)
+            first[run.rows] = pool_at + run.layers
+        if run.state is not None:
+            state_at, states = states, states + run.layers
+        places.append((pool_at, state_at))
+    return places
+
+
+def _state_place(place: tuple, layer):
+    """A layer's place in the state arrays from its place ``layer`` among
+    its run's scanned indices (``_plan_runs``): the same number where the
+    run keeps no page or its two places coincide (a plan whose every
+    layer keeps both), else moved by the difference of the run's two
+    first places."""
+    pool_at, state_at = place
+    if pool_at is None or pool_at == state_at:
+        return layer
+    return layer + (state_at - pool_at)
 
 
 def _over_layers(stats: list) -> dict:
@@ -477,12 +549,17 @@ class PagedLLMEngine:
 
         # -- device state: the pools, their host-side bookkeeping and the
         # programs compiled so far
+        built = time.time()
         # the pools, as the plan's runs state them (``_pool_slices``):
         # one list, in the order both programs take and return them
         self._pools = []
         self._bf16_row_bytes = 0    # a token's rows over the layers, bf16
+        self._page_layers = {}      # layers that keep pages, by format
         for rows in _pool_slices(plan)[0]:
-            layers = sum(run.layers for run in plan if run.rows == rows)
+            layers = _pool_layers(plan, rows)
+            self._page_layers[
+                "k+v" if rows is None else
+                ",".join(f"{row.name}:{row.width}" for row in rows)] = layers
             if rows is None:
                 self._pools += self._kv_twins(layers)
                 self._bf16_row_bytes += (
@@ -497,18 +574,23 @@ class PagedLLMEngine:
                                      self.page_size, row) for row in rows]
             self._bf16_row_bytes += layers * 2 * sum(
                 pool.shape[-1] for pool in self._pools[-len(rows):])
-        # the slots' recurrent state, one array a kind [L, max_batch,
-        # ...], where the plan has a recurrent run (else none, and the
-        # programs take no such argument): donated to both programs and
-        # got back, in place as the pools. A prefill INSTALLS each row's
-        # final state in its slot whole, so a slot's new tenant never
-        # reads its last one's; decode advances the live slots' states
+        # the slots' recurrent state, one array a kind [L', max_batch,
+        # ...] over the L' layers that keep it, where the plan has a
+        # recurrent run (else none, and the programs take no such
+        # argument): donated to both programs and got back, in place as
+        # the pools. A prefill INSTALLS each row's final state in its
+        # slot whole, so a slot's new tenant never reads its last one's;
+        # decode advances the live slots' states
         self._state = tuple(
-            jnp.zeros((cfg.n_layers, max_batch, *shape), dtype)
+            jnp.zeros((_state_layers(plan), max_batch, *shape), dtype)
             for _, shape, dtype in (self._recurrent.arrays
                                     if self._recurrent else ()))
         self._state_slot_bytes = sum(
             a.size * a.dtype.itemsize for a in self._state) // max_batch
+        if _tracing.recording():
+            _tracing.emit("engine.construct", start=built,
+                          duration=time.time() - built, kind="serve",
+                          attrs=self._holds())
         self.state_installs = 0
         # decode dispatches, and those whose program advances the state
         # in the state kernel: the rule on the arrays' own shapes, on a
@@ -544,7 +626,8 @@ class PagedLLMEngine:
         # attention kernel: a model with full-attention layers, lowered
         # for a TPU (``_dispatch_prefill``)
         self._kernel_backend = jax.default_backend() == "tpu" and any(
-            run.window is None and run.rows is None for run in plan)
+            run.attends and run.window is None and run.rows is None
+            for run in plan)
         # the keys a layer that picks them attends over at most, if the
         # plan has such layers (for the decode dispatch's count of the
         # rows a step reads after its selection)
@@ -559,10 +642,7 @@ class PagedLLMEngine:
         # the rows a token keeps in a page, by format, for the prefill
         # dispatch's span: "k+v" for K/V twins, else the rows' names and
         # widths
-        self._page_rows = ";".join(
-            "k+v" if rows is None else
-            ",".join(f"{row.name}:{row.width}" for row in rows)
-            for rows in _pool_slices(plan)[0])
+        self._page_rows = ";".join(self._page_layers)
         self.prefill_dispatches = 0
         self.prefill_kernel_dispatches = 0
         # the token-rows the prefill programs computed (group x bucket a
@@ -590,6 +670,24 @@ class PagedLLMEngine:
                                 and _cfg.serve_prefix_routing_enabled)
         self._digest_interval = float(_cfg.serve_digest_publish_interval_s)
         self._digest_t = 0.0
+
+    def _holds(self) -> dict:
+        """What the plan's layers hold, as the stores were sized: the
+        layers that keep pages, by format, with the bytes of one page
+        over them, and the layers that keep recurrent state, with the
+        bytes of one slot's over them."""
+        return {"page_layers": ";".join(
+                    f"{rows}={n}" for rows, n in self._page_layers.items()),
+                "page_bytes": self._pages_bytes() // self.num_pages,
+                "state_layers": self._state[0].shape[0] if self._state else 0,
+                "state_slot_bytes": self._state_slot_bytes}
+
+    def _pages_bytes(self) -> int:
+        """The pools' own bytes: every pool that holds a row a token (K
+        and V pages, with their dequant scales in int8 mode; a latent
+        plan's rows), not the bf16 mode's one-element scale dummies."""
+        return sum(a.size * a.dtype.itemsize for a in self._pools
+                   if a.shape[1] == self.num_pages)
 
     def _kv_twins(self, layers: int) -> list:
         """The four pools of ``layers`` layers that keep K/V twins: K
@@ -698,6 +796,7 @@ class PagedLLMEngine:
         # how (module docstring)
         runs = _plan_runs(plan, params["blocks"], getattr(
             model, "fuse_attention_projections", None))
+        places = _places(plan)
 
         def one_step(carry, _):
             *pools, toks, lens, key = carry[:n_pools + 3]
@@ -713,16 +812,26 @@ class PagedLLMEngine:
             pidx = jnp.where((pidx >= 0) & active, pidx, num_pages)
             ip = pos % page_size
 
-            def block(run, carry, xs):
+            def block(run, place, carry, xs):
                 x, *rest = carry
-                mine = where[run.rows]
-                held, state = rest[mine], rest[n_pools:]
+                state = rest[n_pools:]
                 p, layer = xs
-                if run.rows is not None:
+
+                def mixer_step():
+                    # the mixer on the layer's input, over the slots'
+                    # states at the layer's place among those that keep one
+                    return model.recurrent_step(
+                        cfg, p, x, state, _state_place(place, layer), active)
+
+                if not run.attends:
+                    if run.state is not None:
+                        mixed, state = mixer_step()   # the one sublayer
+                elif run.rows is not None:
                     # a layer that keeps rows: the step's own written,
                     # then the slot's rows read where they lie (a
                     # sliding layer: its window's; a layer with an
                     # indexer: the ones it picks)
+                    held = rest[where[run.rows]]
                     inputs = model.latent_projections(
                         cfg, p, x, *rotary[run.kind])
                     held = write_latent(inputs, held, layer, pidx, ip)
@@ -730,12 +839,12 @@ class PagedLLMEngine:
                         inputs, held, layer, table, pos, window=run.window,
                         active=active)
                 else:
+                    held = rest[where[run.rows]]
                     q, k, v = model.attention_projections(
                         cfg, p, x, *rotary[run.kind])
                     if run.state is not None:
                         # the mixer beside the attention, on the same input
-                        mixed, state = model.recurrent_step(
-                            cfg, p, x, state, layer, active)
+                        mixed, state = mixer_step()
                     held = write_kv(*held, layer, k[:, 0], v[:, 0], pidx,
                                     ip, quantized)
                     # each live slot's pages up to its length (a sliding
@@ -744,19 +853,22 @@ class PagedLLMEngine:
                     attn = paged_decode_attention(
                         q[:, 0], *held, layer, table, pos, active,
                         window=run.window)
-                x = model.attention_output(cfg, p, x, attn)
+                if run.attends:
+                    x = model.attention_output(cfg, p, x, attn)
+                    rest[where[run.rows]] = held
                 if run.state is not None:
                     x = x + mixed
-                x, stats = model.feed_forward(cfg, p, x,
-                                              valid=active[:, None])
-                return (x, *rest[:mine.start], *held,
-                        *rest[mine.stop:n_pools], *state), stats
+                stats = {}
+                if run.feeds:
+                    x, stats = model.feed_forward(cfg, p, x,
+                                                  valid=active[:, None])
+                return (x, *rest[:n_pools], *state), stats
 
             carry = (x, *pools, *state)
             stats = []
-            for run, xs in zip(plan, runs):
-                carry, run_stats = jax.lax.scan(partial(block, run), carry,
-                                                xs)
+            for run, place, xs in zip(plan, places, runs):
+                carry, run_stats = jax.lax.scan(
+                    partial(block, run, place), carry, xs)
                 stats.append(run_stats)
             x, *rest = carry
             x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)[:, 0]
@@ -825,12 +937,27 @@ class PagedLLMEngine:
                              num_pages)
         ip_all = positions % page_size
 
-        def block(run, carry, xs):
+        def block(run, place, carry, xs):
             x, *rest = carry
-            mine = where[run.rows]
-            held, state = rest[mine], rest[n_pools:]
+            state = rest[n_pools:]
             p, layer = xs
-            if run.rows is not None:
+
+            def mixer_pass():
+                """The mixer over the rows from the zero state, and each
+                row's state after its last token INSTALLED whole in its
+                slot, at the layer's place among those that keep one."""
+                fresh = tuple(jnp.zeros((n, *a.shape[2:]), a.dtype)
+                              for a in state)
+                mixed, final = model.recurrent_mixer(cfg, p, x, fresh, valid)
+                at = _state_place(place, layer)
+                return mixed, [a.at[at, slots].set(new, mode="drop")
+                               for a, new in zip(state, final)]
+
+            if not run.attends:
+                if run.state is not None:
+                    mixed, state = mixer_pass()
+            elif run.rows is not None:
+                held = rest[where[run.rows]]
                 inputs = model.latent_projections(cfg, p, x,
                                                   *rotary[run.kind])
                 held = write_latent(inputs, held, layer, pidx_all, ip_all)
@@ -838,30 +965,29 @@ class PagedLLMEngine:
                     inputs, held, layer, table_rows, starts,
                     window=run.window)
             else:
+                held = rest[where[run.rows]]
                 q, k, v = model.attention_projections(cfg, p, x,
                                                       *rotary[run.kind])
                 if run.state is not None:
-                    fresh = tuple(jnp.zeros((n, *a.shape[2:]), a.dtype)
-                                  for a in state)
-                    mixed, final = model.recurrent_mixer(cfg, p, x, fresh,
-                                                         valid)
-                    state = [a.at[layer, slots].set(new, mode="drop")
-                             for a, new in zip(state, final)]
+                    mixed, state = mixer_pass()
                 held = write_kv(*held, layer, k, v, pidx_all, ip_all,
                                 quantized)
                 attn = paged_prefill_attention(
                     q, *held, layer, table_rows, starts, slens,
                     window=run.window)
-            x = model.attention_output(cfg, p, x, attn)
+            if run.attends:
+                x = model.attention_output(cfg, p, x, attn)
+                rest[where[run.rows]] = held
             if run.state is not None:
                 x = x + mixed
-            x, _ = model.feed_forward(cfg, p, x, valid=valid)
-            return (x, *rest[:mine.start], *held,
-                    *rest[mine.stop:n_pools], *state), None
+            if run.feeds:
+                x, _ = model.feed_forward(cfg, p, x, valid=valid)
+            return (x, *rest[:n_pools], *state), None
 
         carry = (x, *pools, *state)
-        for run, xs in zip(plan, _plan_runs(plan, params["blocks"])):
-            carry, _ = jax.lax.scan(partial(block, run), carry, xs)
+        for run, place, xs in zip(plan, _places(plan),
+                                  _plan_runs(plan, params["blocks"])):
+            carry, _ = jax.lax.scan(partial(block, run, place), carry, xs)
         x, *rest = carry
         x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
         x = jnp.take_along_axis(
@@ -1871,6 +1997,9 @@ class PagedLLMEngine:
             # slot, and the bytes the slots' state arrays hold
             "state_installs": self.state_installs,
             "state_bytes_held": self._state_slot_bytes * self.max_batch,
+            # the layers that keep pages (by format) and state, with the
+            # bytes of a page and of a slot's state over them
+            **self._holds(),
             "mean_ttft_s": float(np.mean(self.ttfts)) if self.ttfts else None,
             "kv_pages_total": self.num_pages,
             "kv_pages_free": len(self._alloc.free),
@@ -1893,12 +2022,7 @@ class PagedLLMEngine:
             "cached_idle_pages": self._prefix.evictable(),
         }
         out["kv_dtype"] = self.kv_dtype
-        # the pools' own bytes: every pool that holds a row a token (K
-        # and V pages, with their dequant scales in int8 mode; a latent
-        # plan's rows), not the bf16 mode's one-element scale dummies
-        out["kv_pages_bytes"] = sum(
-            a.size * a.dtype.itemsize for a in self._pools
-            if a.shape[1] == self.num_pages)
+        out["kv_pages_bytes"] = self._pages_bytes()
         out["cache_bytes_per_token"] = (
             out["kv_pages_bytes"] // (self.num_pages * self.page_size))
         # what max_batch contiguous bf16 rows of max_len would take

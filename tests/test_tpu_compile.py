@@ -901,3 +901,126 @@ def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
     assert "bf16[131072,640]" not in text
     assert "bf16[320,128,1152]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.62e9
+
+
+# NVIDIA-Nemotron-3-Nano-30B-A3B cut to its first nine layers, MEMEM*EME,
+# 64 of 128 experts held (``serve-reason-gen``): 128 slots, 2,304 KV pages
+# of the ONE attention layer, recurrent state of the FOUR mixers
+_NANO_SLOTS, _NANO_PAGES = 128, 2304
+_NANO_PROGRAMS = [("decode", (16, 16)), ("decode", (8, 16)),
+                  ("prefill", (2, 512, 4)), ("prefill", (1, 512, 4))]
+_NANO_STATE_KERNEL = re.compile(
+    r"%ssm_state_step[.\d]* = \(.*f32\[4,128,64,64,128\]\S*\) "
+    r"custom-call\(.*tpu_custom_call")
+# a weight stack of the nine runs fetched into the core's memory (``S(1)``
+# in the result's layout and not in the operand's), start and done
+_NANO_FETCH = re.compile(
+    r"= (?:\()?bf16\[1,(\d+),(\d+)\]\{[^}]*S\(1\)\}(?:, bf16\[1,\1,\2\]"
+    r"\{[^}]*\)\}, u32\[\]\S*\))? (copy-start|copy-done)\(")
+
+
+def _lower_nano_program(device, program, dims):
+    """One of the engine's two programs for the d9 plan, its stores sized
+    as the engine sizes them: K/V pools of one layer, state arrays of
+    four."""
+    from ray_tpu.models import nemotron_h
+    from ray_tpu.serve.paged_llm import _pool_layers, _state_layers
+
+    one = SingleDeviceSharding(device)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    cfg = dataclasses.replace(
+        nemotron_h.nemotron_3_nano_30b_a3b(), vocab_size=65536,
+        n_experts_held=64, pattern="MEMEM*EME")
+    plan = nemotron_h.layer_plan(cfg)
+    assert (_pool_layers(plan, None), _state_layers(plan)) == (1, 4)
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(partial(nemotron_h.init_params, cfg),
+                       jax.random.key(0)))
+    pool = shape((1, _NANO_PAGES, 128, cfg.n_kv_heads, cfg.head_dim),
+                 jnp.bfloat16)
+    scale = shape((1, 1, 1, 1), jnp.float32)
+    state = tuple(shape((4, _NANO_SLOTS, *dims_), dtype) for _, dims_, dtype
+                  in nemotron_h.recurrent_state(cfg).arrays)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    slots = _NANO_SLOTS
+    if program == "decode":
+        chunk, pages = dims
+        fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
+                     page_size=128, quantized=False)
+        args = (shape((slots, pages), jnp.int32), shape((slots,), jnp.int32),
+                shape((slots,), jnp.int32), shape((slots,), jnp.bool_),
+                shape((slots,), jnp.float32), key, *state)
+    else:
+        n, tokens, pages = dims
+        fn = partial(PagedLLMEngine._paged_prefill_impl, cfg, page_size=128,
+                     quantized=False)
+        args = (shape((n, pages), jnp.int32), shape((n, tokens), jnp.int32),
+                shape((n,), jnp.int32), shape((n,), jnp.int32),
+                shape((n,), jnp.float32), key, *state,
+                shape((n,), jnp.int32))
+    return cfg, jax.jit(fn, donate_argnums=(1, 2, 3, 4, 11, 12)).lower(
+        params, pool, pool, scale, scale, *args)
+
+
+@pytest.mark.parametrize(
+    "program,dims", _NANO_PROGRAMS,
+    ids=[f"{p}-{'x'.join(map(str, d))}" for p, d in _NANO_PROGRAMS])
+def test_nemotron_d9_engine_programs_hold_what_their_layers_keep(
+        v5e_2x2, program, dims):
+    """The engine's programs for a plan whose every layer is one thing,
+    at the published widths: arguments of 7.7 GB (6.33 GB of weights, a
+    0.30 GB pool of ONE layer's pages, 1.09 GB of state over FOUR layers)
+    fit a v5e with the temporaries beside them (a prefill of 2 x 512 rows
+    computes every held expert for every row: under 0.5 GB); pools and
+    state are donated and come back in place. A decode program attends
+    in the decode kernel at 16 query heads a KV head and advances the
+    state in the state kernel, once a mixer, over the four-layer array
+    (blocks of 32 heads of [64, 128], four of the eight groups a block).
+
+    Every run is ONE layer, so a run's weight stacks are the layer's own
+    weights: the compiler prefetches some of them into the core's memory
+    inside the step loop (``copy-start`` / ``copy-done`` into ``S(1)``),
+    each ONCE a step and never back out, which moves the bytes the layer
+    reads anyway and no others. What ``_stack_moves_in_loops`` fences in
+    the older programs, a stack of SEVERAL layers parked on the core and
+    moved whole round a kernel to read one layer of it, cannot happen to
+    a stack of one; the test holds the moves to those fetches."""
+    cfg, lowered = _lower_nano_program(v5e_2x2[0], program, dims)
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pool_bytes = _NANO_PAGES * 128 * 2 * 128 * 2
+    state_bytes = 4 * _NANO_SLOTS * (4 * 64 * 64 * 128 + 2 * 3 * 6144)
+    assert 7.6e9 < mem.argument_size_in_bytes < 7.8e9
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes + state_bytes
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert not _pool_copy(1, _NANO_PAGES, 2).findall(text)
+    assert bool(_DECODE_KERNEL.search(text)) == (program == "decode")
+    assert len(_NANO_STATE_KERNEL.findall(text)) == (
+        4 if program == "decode" else 0)
+    widths = {(cfg.d_ssm, cfg.d_model): (cfg.d_ssm, (cfg.d_model,)),
+              "in": (cfg.d_model, (10304, 4608, cfg.d_shared)),
+              "down": (cfg.d_shared, (cfg.d_model,))}
+    moves = [m for d_in, w in widths.values()
+             for m in _stack_moves_in_loops(text, 1, d_in, w)]
+    if program == "prefill":
+        assert not moves
+        assert "ragged-dot" not in text     # 1,024 rows: every held expert
+        return
+    assert _in_loops(text, _NANO_STATE_KERNEL) == 4
+    # the four kernel calls, and nothing else, pass over the state
+    passes = _state_passes(text, axes="64,64,128")
+    assert len(passes) == 4 and all(
+        p.startswith("%ssm_state_step") for p in passes), passes
+    # each move is a fetch of a one-layer stack into the core's memory,
+    # start and done, and no stack is fetched twice a step
+    assert moves and all(_NANO_FETCH.search(m) for m in moves), moves
+    starts = [m.split(" = ")[0] for m in moves if "copy-start(" in m]
+    sources = [re.search(r"copy-start\((%[\w.\-]+)\)", m).group(1)
+               for m in moves if "copy-start(" in m]
+    assert len(starts) == len(set(sources)) == len(moves) // 2
+    # no expert stack (1.28 GB a layer) is among them
+    assert not any("1856" in m for m in moves)
