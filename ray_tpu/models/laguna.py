@@ -319,24 +319,30 @@ def attention_output(cfg: LagunaConfig, p, x, attn):
     return x + attn.astype(x.dtype).reshape(b, s, -1) @ p["wo"]
 
 
-def feed_forward(cfg: LagunaConfig, p, x, valid=None):
+def feed_forward(cfg: LagunaConfig, p, x, valid=None, stacked=None):
     """Pre-norm feed-forward over ``x`` [b, s, d]; returns (the
     residual-added stream, its statistics). A dense layer (its weights say
     which) is a SwiGLU with no statistics. Any other: the held routed
-    experts' part for the tokens routed to them, plus the shared expert
-    on every token. ``valid`` [b, s] marks the rows that are tokens:
-    padding is sent to no expert and counts in no statistic. The
-    statistics are scalars of this call, over the HELD experts: how many
-    got a token, the busiest one's load over the mean load, and the share
-    of the tokens' ``top_k`` choices that fell on a held expert."""
+    experts' part for the tokens routed to them, plus the shared expert on
+    every token. ``valid`` [b, s] marks the rows that are tokens: padding
+    is sent to no expert and counts in no statistic. The statistics are
+    scalars of this call, over the HELD experts: how many got a token, the
+    busiest one's load over the mean load, and the share of the tokens'
+    ``top_k`` choices that fell on a held expert. ``stacked``: (the run's
+    weights stacked on their layer axis, this layer's index in them), from
+    a program that scans the run: the expert stacks are then read from
+    there in place (``moe_ffn_dropless``'s ``layer``), not from ``p``'s
+    slices."""
     b, s, d = x.shape
     h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
     if "w_gate" in p:
         gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
         return x + gated @ p["w_down"], {}
+    held, layer = (p, None) if stacked is None else stacked
     routed, load = moe_ffn_dropless(
-        h.reshape(b * s, d), p["router"], p["wi_gate"], p["wi_up"],
-        p["wo_e"], top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+        h.reshape(b * s, d), p["router"], held["wi_gate"], held["wi_up"],
+        held["wo_e"], layer=layer, top_k=cfg.top_k,
+        norm_topk_prob=cfg.norm_topk_prob,
         routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
         valid=None if valid is None else valid.reshape(b * s))
     shared = (jax.nn.silu(h @ p["ws_gate"]) * (h @ p["ws_up"])) @ p["ws_down"]

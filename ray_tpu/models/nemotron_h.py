@@ -363,17 +363,22 @@ def attention_output(cfg: NemotronHConfig, p, x, attn):
         return x + attn.reshape(b, s, -1) @ p["wo"]
 
 
-def feed_forward(cfg: NemotronHConfig, p, x, valid=None):
+def feed_forward(cfg: NemotronHConfig, p, x, valid=None, stacked=None):
     """An ``E`` layer over ``x`` [b, s, d]: the held routed experts' part
     for the tokens routed to them plus the shared expert on every token,
     squared-ReLU experts of two matrices; returns (the residual-added
     stream, statistics over the HELD experts, as
     ``models/laguna.py:feed_forward``'s). ``valid`` [b, s] marks the rows
-    that are tokens."""
+    that are tokens. ``stacked``: (the run's weights stacked on their layer
+    axis, this layer's index in them), from a program that scans the run:
+    the expert stacks are then read from there in place
+    (``moe_ffn_dropless``'s ``layer``), not from ``p``'s slices."""
     b, s, d = x.shape
     h = rms_norm(x, p["norm"], eps=cfg.rms_eps)
+    held, layer = (p, None) if stacked is None else stacked
     routed, load = moe_ffn_dropless(
-        h.reshape(b * s, d), p["router"], None, p["wi_up"], p["wo_e"],
+        h.reshape(b * s, d), p["router"], None, held["wi_up"], held["wo_e"],
+        layer=layer,
         top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
         routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
         valid=None if valid is None else valid.reshape(b * s),

@@ -169,17 +169,23 @@ def attention_projections(cfg: OlmoeConfig, p, x, sin, cos):
     return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
 
 
-def feed_forward(cfg: OlmoeConfig, p, x, valid=None):
+def feed_forward(cfg: OlmoeConfig, p, x, valid=None, stacked=None):
     """Pre-norm routed feed-forward over ``x`` [b, s, d]; returns (the
-    residual-added stream, its statistics). ``valid`` [b, s] marks the
-    rows that are tokens: padding is sent to no expert and counts in no
+    residual-added stream, its statistics). ``valid`` [b, s] marks the rows
+    that are tokens: padding is sent to no expert and counts in no
     statistic. The statistics are scalars of this call: how many experts
-    got a token, and the busiest expert's load over the mean load."""
+    got a token, and the busiest expert's load over the mean load.
+    ``stacked``: (the run's weights stacked on their layer axis, this
+    layer's index in them), from a program that scans the run: the expert
+    stacks are then read from there in place (``moe_ffn_dropless``'s
+    ``layer``), not from ``p``'s slices."""
     b, s, d = x.shape
     h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
+    held, layer = (p, None) if stacked is None else stacked
     out, load = moe_ffn_dropless(
-        h.reshape(b * s, d), p["router"], p["wi_gate"], p["wi_up"],
-        p["wo_e"], top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+        h.reshape(b * s, d), p["router"], held["wi_gate"], held["wi_up"],
+        held["wo_e"], layer=layer, top_k=cfg.top_k,
+        norm_topk_prob=cfg.norm_topk_prob,
         valid=None if valid is None else valid.reshape(b * s))
     load = load.astype(jnp.float32)
     stats = {

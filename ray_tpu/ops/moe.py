@@ -12,8 +12,15 @@ those tokens unchanged), it renormalises the chosen gates, and its
 ``moe_ffn_dropless`` is EXACT, for serving and wherever a dropped token is
 a wrong answer: float32 softmax over all experts, top-k, every chosen
 expert applied to its token, nothing dropped, no ``[T, E, C]`` tensor. It
-picks its formulation from the token count it is traced with
-(``DENSE_MAX_TOKENS``). It is also the expert layer of ONE CHIP under
+picks its formulation from the token count it is traced with, one number
+(``DENSE_MAX_TOKENS``, ``expert_kernel_engages``): up to it every held
+expert over every row, bound by one read of the experts' weights (a
+decode step); past it the (token, choice) pairs sorted by held expert and
+each expert over its own rows, one grouped matmul kernel
+(``ops/grouped_expert_ffn.py``: the Pallas kernel where the program is
+lowered for a TPU, a plain loop over the experts elsewhere), which reads
+the weights of the experts that got a row once and computes the tiles
+that hold one. It is also the expert layer of ONE CHIP under
 expert parallelism: told which experts it holds (expert stacks narrower
 than the router, and the first held expert's index), it routes over all
 of them and computes the held experts' part of the result, without the
@@ -27,6 +34,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.grouped_expert_ffn import (grouped_expert_ffn_kernel,
+                                            grouped_expert_ffn_reference,
+                                            hidden_activation)
 
 
 def router_topk(
@@ -126,17 +137,37 @@ def moe_ffn(
     return out.astype(dtype), aux
 
 
-# Up to this many tokens ``moe_ffn_dropless`` runs every expert over every
-# token and weights the result (zero for an expert not chosen); past it, it
-# sorts the (token, choice) pairs by expert and runs grouped matmuls over
-# the chosen experts alone. On a v5e at OLMoE's widths (64 experts of 2048 x
-# 1024, 8 a token; PERF.md, PR 28) the first reads the experts' weights at
-# nine tenths of the chip's bandwidth up to 128 tokens (1.1 ms a layer) and
-# is bound by its 64 / 8 times the operations from about 256 (4.4 ms at
-# 1024, at the chip's peak); the second, whose grouped matmul XLA pads to
-# its tile group by group, takes 4-6 ms up to 1024 tokens and wins from
-# about 2048 (8.4 ms), and its temporaries do not grow with E x F a token.
-DENSE_MAX_TOKENS = 1024
+# Up to this many tokens ``moe_ffn_dropless`` runs every held expert over
+# every token and weights the result (zero for an expert not chosen); past
+# it, it sorts the (token, choice) pairs by held expert and runs the grouped
+# kernel over the rows each expert got (``ops/grouped_expert_ffn.py``). One
+# layer's call on a v5e, ms, at the four expert widths the benchmark runs
+# with about each cell's share of choices on a held expert (PERF.md, PR 44;
+# ``scripts/sweep_expert_formulations.py``), every-expert / sorted:
+#
+#   rows  64x2688x1856     64x3072x1024     32x5120x1536     64x2048x1024
+#         relu2, 6/128     swiglu, 10/256   swiglu, 8/256    swiglu, 8/64
+#    128   1.77 /  1.89     1.76 /  1.81     2.11 /  2.21     1.16 /  1.28
+#    256   2.01 /  1.95     1.87 /  1.92     2.29 /  2.42     1.48 /  1.38
+#    512   3.62 /  2.22     3.59 /  2.49     4.28 /  3.08     2.26 /  1.61
+#   1024   7.24 /  3.03     6.88 /  3.70     8.42 /  3.91     4.46 /  2.33
+#   2048  14.55 /  4.13    13.60 /  5.50    16.87 /  5.54     8.87 /  3.46
+#   4096  28.96 /  6.46    27.09 /  9.35    33.82 /  9.03    17.60 /  5.58
+#
+# Every-expert reads the held experts' weights once (0.8-1.5 GB: 1.0-1.9 ms
+# at the chip's bandwidth) and is bound by that read up to 256 rows, by its
+# operations (held experts / chosen experts times the needed ones, at the
+# chip's peak) from 512. The kernel reads the touched experts' weights once
+# too (its two calls alone: 1.8 / 1.7 / 2.1 / 1.2 ms up to 512 rows, 85% of
+# the bandwidth) and what grows with the rows is the sort, the gather of
+# the pairs' rows and their weighted sum back (0.3-0.8 ms at 512 rows,
+# 1.5-4.9 at 4,096). The two cross between 256 and 512 rows at every width.
+# The line stays above 128, the most slots a cell decodes with: every decode
+# program is every-expert's. (XLA's own grouped matmul over ragged groups,
+# which the op ran past 1,024 rows until PR 44, pads every group to its
+# tile: 2.6-4.8 ms at 128 rows and 8.4-12.6 at 4,096 at the three widths of
+# whole lanes, 14-28 ms at the first, whose up stack it copies.)
+DENSE_MAX_TOKENS = 256
 
 # What one expert computes of a token ``x``, by the matrices it has:
 # ``swiglu``: ``(silu(x @ gate) * (x @ up)) @ down``; ``relu2``:
@@ -159,6 +190,7 @@ def moe_ffn_dropless(
     scoring: str = "softmax",
     choice_bias=None,   # [E] float32, added to the scores for the choice
     form: str = "swiglu",   # one expert's form: ``EXPERT_FORMS``
+    layer=None,         # the stacks are [L, H, ..]: this call is layer's
 ):
     """Exact routed feed-forward. Returns (out [T, D], load [H]).
 
@@ -178,7 +210,11 @@ def moe_ffn_dropless(
     falls on an absent expert adds nothing: its part is another chip's.
     Rows that ``valid`` marks as padding go to no expert and come out
     zero. ``load`` counts the (token, choice) pairs each held expert got
-    (int32).
+    (int32). With ``layer`` (a traced scalar) the expert stacks are those
+    of a run of layers, [L, H, ..], and the call is that layer's: what a
+    program that scans the run hands over, so that the grouped kernel
+    reads the layer's weights where they lie (a layer sliced out of the
+    scan's stacks to feed a kernel is a copy of it).
     """
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
@@ -188,7 +224,7 @@ def moe_ffn_dropless(
                          f"for 'swiglu' alone; got {form!r} and wi_gate "
                          f"{'None' if wi_gate is None else 'given'}")
     t, d = x.shape
-    e = wi_up.shape[0]
+    e = wi_up.shape[-3]
     dtype = x.dtype
     with jax.named_scope("moe_router"):
         # true float32: the chip's default would round the products to
@@ -220,14 +256,28 @@ def moe_ffn_dropless(
             chosen = chosen & valid[:, None, None]
         load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)  # [E]
     with jax.named_scope("moe_experts"):
-        if t <= DENSE_MAX_TOKENS:
+        if not expert_kernel_engages(t):
+            if layer is not None:
+                wi_gate, wi_up, wo = (w if w is None else w[layer]
+                                      for w in (wi_gate, wi_up, wo))
             weights = jnp.sum(jnp.where(chosen, gate_vals[:, :, None], 0.0),
                               axis=1)                          # [T, E]
             out = _experts_all(x, weights, wi_gate, wi_up, wo)
         else:
+            if layer is None:       # one layer's stacks: a run of one
+                layer = 0
+                wi_gate, wi_up, wo = (w if w is None else w[None]
+                                      for w in (wi_gate, wi_up, wo))
             out = _experts_grouped(x, gate_vals, gate_idx, valid, load,
-                                   wi_gate, wi_up, wo)
+                                   wi_gate, wi_up, wo, layer)
     return out.astype(dtype), load
+
+
+def expert_kernel_engages(rows: int) -> bool:
+    """Whether a call traced with ``rows`` tokens computes the held
+    experts over the rows routed to them (``_experts_grouped``) and not
+    every held expert over every row: the traced token count alone."""
+    return rows > DENSE_MAX_TOKENS
 
 
 def share_statistics(load, valid, rows: int, top_k: int) -> dict:
@@ -249,15 +299,6 @@ def share_statistics(load, valid, rows: int, top_k: int) -> dict:
     }
 
 
-def _hidden(into, wi_gate, wi_up):
-    """An expert's hidden activations in float32, by its form: ``into(w)``
-    is the tokens' product with the stack ``w``. With a gate ``silu(x @
-    gate) * (x @ up)``; without one ``relu(x @ up) ** 2``."""
-    if wi_gate is None:
-        return jnp.square(jax.nn.relu(into(wi_up)))
-    return jax.nn.silu(into(wi_gate)) * into(wi_up)
-
-
 def _experts_all(x, weights, wi_gate, wi_up, wo):
     """Every expert over every token; ``weights`` [T, E] is zero where an
     expert was not chosen. The tokens are broadcast along the expert axis
@@ -265,34 +306,36 @@ def _experts_all(x, weights, wi_gate, wi_up, wo):
     projection contracts expert and width together, so nothing of shape
     [E, T, D] comes out of it."""
     xe = jnp.broadcast_to(x, (wi_up.shape[0],) + x.shape)       # [E, T, D]
-    h = _hidden(lambda w: jnp.einsum("etd,edf->etf", xe, w,
-                                     preferred_element_type=jnp.float32),
-                wi_gate, wi_up)
+    h = hidden_activation(
+        lambda w: jnp.einsum("etd,edf->etf", xe, w,
+                             preferred_element_type=jnp.float32),
+        wi_gate, wi_up)
     h = (h * weights.T[:, :, None]).astype(x.dtype)
     return jnp.einsum("etf,efd->td", h, wo,
                       preferred_element_type=jnp.float32)
 
 
 def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
-                     wo):
+                     wo, layer):
     """The chosen experts alone: the T x K (token, choice) pairs sorted by
-    expert (padding rows last, in no group), three grouped matmuls
-    (``jax.lax.ragged_dot``) over the sorted rows, and each token's K
-    weighted results summed where they came from."""
+    held expert (a choice on an absent expert and a padding row last, in
+    no group), the experts over their groups (``ops.grouped_expert_ffn``:
+    the kernel in a program lowered for a TPU, the plain loop elsewhere),
+    and each token's K weighted results summed where they came from."""
     t, k = gate_idx.shape
-    e = wi_up.shape[0]
+    e = wi_up.shape[1]
     expert = gate_idx.reshape(t * k)
     if valid is not None:
         expert = jnp.where(jnp.repeat(valid, k), expert, e)
     order = jnp.argsort(expert)                    # stable: pair -> row
     xs = x[order // k]                             # [T*K, D]
-    h = _hidden(lambda w: jax.lax.ragged_dot(
-        xs, w, load, preferred_element_type=jnp.float32), wi_gate, wi_up)
-    ys = jax.lax.ragged_dot(h.astype(x.dtype), wo, load,
-                            preferred_element_type=jnp.float32)
-    # rows past the last group belong to no expert: whatever they hold
-    # must not reach a sum
-    weight = gate_vals.reshape(t * k)[order]
-    ys = jnp.where((expert[order] < e)[:, None], ys * weight[:, None], 0.0)
-    back = jnp.argsort(order)                      # row -> pair
-    return jnp.sum(ys[back].reshape(t, k, -1), axis=1)
+    ys = jax.lax.platform_dependent(
+        xs, load, wi_gate, wi_up, wo, layer,
+        tpu=grouped_expert_ffn_kernel, default=grouped_expert_ffn_reference)
+    # each pair's row back beside its token; a pair in no group (its row
+    # holds anything) adds nothing. Weighing and masking after the gather
+    # fuse into the sum: no pass of their own over the [T*K, D] rows
+    back = jnp.argsort(order)                      # pair -> row
+    held = (expert < e).reshape(t, k, 1)
+    ys = ys[back].reshape(t, k, -1) * gate_vals[:, :, None]
+    return jnp.sum(jnp.where(held, ys, 0.0), axis=1)

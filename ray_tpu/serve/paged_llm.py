@@ -221,6 +221,7 @@ from ray_tpu.ops.latent_attention import (latent_decode_attention,
                                           latent_kernel_engages,
                                           latent_prefill_attention,
                                           write_latent)
+from ray_tpu.ops.moe import expert_kernel_engages
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import (PageAllocator, PrefixCache,
                                          page_hashes, row_pool, write_kv)
@@ -354,6 +355,12 @@ def _plan_runs(plan, blocks, fuse=None) -> list:
         blocks = fuse(blocks)
     return [(blocks if run.key is None else blocks[run.key], idx)
             for run, idx in zip(plan, layers)]
+
+
+def _routes(run, weights) -> bool:
+    """Whether a run's layers end in a ROUTED feed-forward
+    (``ops/moe.py:moe_ffn_dropless``): the run's weights hold a router."""
+    return run.feeds and "router" in weights
 
 
 def _places(plan) -> list:
@@ -639,6 +646,15 @@ class PagedLLMEngine:
         self._latent_backend = (jax.default_backend() == "tpu"
                                 and self._selects is not None)
         self.latent_kernel_dispatches = 0
+        # prefill dispatches whose program computes the routed experts in
+        # the grouped kernel: a plan with a run whose weights hold a
+        # router, lowered for a TPU, by the rule on the dispatch's rows
+        # (``ops/moe.py:expert_kernel_engages``)
+        blocks = params["blocks"]
+        self._expert_backend = jax.default_backend() == "tpu" and any(
+            _routes(run, blocks if run.key is None else blocks[run.key])
+            for run in plan)
+        self.expert_kernel_dispatches = 0
         # the rows a token keeps in a page, by format, for the prefill
         # dispatch's span: "k+v" for K/V twins, else the rows' names and
         # widths
@@ -937,10 +953,10 @@ class PagedLLMEngine:
                              num_pages)
         ip_all = positions % page_size
 
-        def block(run, place, carry, xs):
+        def block(run, place, stacked, carry, xs):
             x, *rest = carry
             state = rest[n_pools:]
-            p, layer = xs
+            p, layer, *at = xs
 
             def mixer_pass():
                 """The mixer over the rows from the zero state, and each
@@ -981,13 +997,24 @@ class PagedLLMEngine:
             if run.state is not None:
                 x = x + mixed
             if run.feeds:
-                x, _ = model.feed_forward(cfg, p, x, valid=valid)
+                x, _ = model.feed_forward(
+                    cfg, p, x, valid=valid,
+                    **({"stacked": (stacked, at[0])} if at else {}))
             return (x, *rest[:n_pools], *state), None
 
         carry = (x, *pools, *state)
-        for run, place, xs in zip(plan, _places(plan),
-                                  _plan_runs(plan, params["blocks"])):
-            carry, _ = jax.lax.scan(partial(block, run, place), carry, xs)
+        # a program whose routed experts run in the grouped kernel (the
+        # rule on its rows) hands a run that routes the run's OWN stacks
+        # and each layer's index in them: the kernel reads a layer's
+        # experts where they lie, where a layer sliced out of the scan's
+        # stacks to feed it would be a copy of them, a GB a layer
+        grouped = expert_kernel_engages(n * t)
+        for run, place, (stacks, places) in zip(
+                plan, _places(plan), _plan_runs(plan, params["blocks"])):
+            at = ((jnp.arange(run.layers),)
+                  if grouped and _routes(run, stacks) else ())
+            carry, _ = jax.lax.scan(partial(block, run, place, stacks),
+                                    carry, (stacks, places, *at))
         x, *rest = carry
         x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
         x = jnp.take_along_axis(
@@ -1266,8 +1293,12 @@ class PagedLLMEngine:
             (len(part), bucket, self.cfg.n_heads, self.cfg.head_dim),
             self._k_pages, wp, None)
         token_rows, new_tokens = len(part) * bucket, int(slens_np.sum())
+        # and whether its routed experts run in the grouped kernel
+        expert_kernel = (self._expert_backend
+                         and expert_kernel_engages(token_rows))
         self.prefill_dispatches += 1
         self.prefill_kernel_dispatches += int(kernel)
+        self.expert_kernel_dispatches += int(expert_kernel)
         self.prefill_token_rows += token_rows
         self.prefill_new_tokens += new_tokens
         if ph:
@@ -1280,7 +1311,8 @@ class PagedLLMEngine:
             ph.set(token_rows=token_rows, new_tokens=new_tokens,
                    cached_tokens=cached,
                    missed_pages=lookups - cached // page,
-                   attn_kernel=int(kernel), page_rows=self._page_rows)
+                   attn_kernel=int(kernel), expert_kernel=int(expert_kernel),
+                   page_rows=self._page_rows)
         slots = None
         if self._state:
             # every row's final state goes into its slot; the scan cuts
@@ -1979,6 +2011,7 @@ class PagedLLMEngine:
             "total_finished": self.total_finished,
             "prefill_dispatches": self.prefill_dispatches,
             "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
+            "expert_kernel_dispatches": self.expert_kernel_dispatches,
             "decode_dispatches": self.decode_dispatches,
             "state_kernel_dispatches": self.state_kernel_dispatches,
             "latent_kernel_dispatches": self.latent_kernel_dispatches,

@@ -56,3 +56,35 @@ def cpu_mesh_devices():
     devices = jax.devices()
     assert len(devices) >= 8, f"expected >=8 virtual devices, got {len(devices)}"
     return devices
+
+
+# the formulations ``ops.moe.moe_ffn_dropless`` has, as a test forces them
+EXPERT_FORMULATIONS = ("as-chosen", "every-held-expert", "sorted-loop",
+                       "sorted-kernel")
+
+
+@pytest.fixture
+def expert_formulation(request, monkeypatch):
+    """``moe_ffn_dropless`` held to one formulation whatever its token
+    count (``request.param``, one of ``EXPERT_FORMULATIONS``; use with
+    ``indirect=True``): every held expert over every row; the rows sorted
+    by expert and the plain loop over the experts (what a program lowered
+    off the TPU runs past the line); the sorted rows through the Pallas
+    kernel, interpreted at 16 rows a tile (what a TPU runs there: off the
+    TPU ``lax.platform_dependent`` takes the default lowering, so the
+    kernel is put in its place). ``as-chosen`` leaves the rule alone."""
+    import functools
+
+    from ray_tpu.ops import moe
+
+    which = request.param
+    assert which in EXPERT_FORMULATIONS, which
+    if which == "every-held-expert":
+        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 1 << 30)
+    elif which != "as-chosen":
+        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0)
+    if which == "sorted-kernel":
+        monkeypatch.setattr(
+            moe, "grouped_expert_ffn_reference", functools.partial(
+                moe.grouped_expert_ffn_kernel, tile=16, interpret=True))
+    return which

@@ -161,10 +161,14 @@ def test_the_shares_parts_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, want, atol=1e-5)
 
 
-def test_sigmoid_scoring_and_the_choice_bias_in_the_dropless_op():
-    """The op's new arguments against the arithmetic written out: the
-    choice by score + bias, the weights the scores alone, renormalised
-    over the chosen with 1e-20 under the line."""
+@pytest.mark.parametrize(
+    "expert_formulation", ["every-held-expert", "sorted-loop",
+                           "sorted-kernel"], indirect=True)
+def test_sigmoid_scoring_and_the_choice_bias_in_the_dropless_op(
+        expert_formulation):
+    """The op's new arguments against the arithmetic written out, in
+    every formulation: the choice by score + bias, the weights the scores
+    alone, renormalised over the chosen with 1e-20 under the line."""
     k = jax.random.split(jax.random.key(4), 6)
     t, d, e, f, top = 24, 16, 8, 12, 3
     x = jax.random.normal(k[0], (t, d))

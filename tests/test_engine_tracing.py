@@ -166,9 +166,10 @@ def test_every_dispatch_has_counts_and_one_device_run(profiled):
     assert sum(s["attrs"]["group"] for s in prefills) == len(reqs)
     for s in prefills:
         assert {"seq", "group", "bucket", "token_rows", "new_tokens",
-                "cached_tokens", "missed_pages",
-                "attn_kernel"} <= set(s["attrs"])
+                "cached_tokens", "missed_pages", "attn_kernel",
+                "expert_kernel"} <= set(s["attrs"])
         assert s["attrs"]["attn_kernel"] == 0       # lowered for the CPU
+        assert s["attrs"]["expert_kernel"] == 0
     for s in decodes:
         a = s["attrs"]
         assert {"seq", "chunk", "live", "slots", "drain"} <= set(a)
@@ -683,6 +684,57 @@ def test_the_older_plans_carry_no_latent_kernel_count(monkeypatch, family):
     assert decodes and stats["decode_dispatches"] == len(decodes)
     assert not any("latent_kernel" in s["attrs"] for s in decodes)
     assert stats["latent_kernel_dispatches"] == 0
+
+
+@pytest.mark.parametrize("family,backend,routes", [
+    ("olmoe", "tpu", True), ("nemotron_h", "tpu", True),
+    ("olmoe", "cpu", False), ("llama", "tpu", False)])
+def test_prefill_dispatches_say_whether_their_experts_run_in_the_kernel(
+        monkeypatch, family, backend, routes):
+    """``expert_kernel`` on ``engine.dispatch_prefill`` is the rule the
+    program's routed experts were traced by (``ops/moe.py``:
+    ``expert_kernel_engages``, the dispatch's rows against the line)
+    applied to the host's own count, ``group x bucket``, on a TPU backend
+    alone and for a plan that routes; ``stats()`` counts the dispatches
+    that took it beside ``prefill_dispatches``. The line is brought down
+    to where toy prompts cross it: 16 rows stay under, 64 and 128 pass.
+    An engine on the CPU, and a plan with no router on an engine that
+    finds a TPU (it is told so as it is built; its programs still lower
+    for the CPU), read 0 on every dispatch."""
+    from ray_tpu.models import nemotron_h, olmoe
+    from ray_tpu.ops import moe
+    from ray_tpu.serve import paged_llm
+
+    model, cfg = {"llama": (llama, llama.llama_tiny),
+                  "olmoe": (olmoe, olmoe.olmoe_tiny),
+                  "nemotron_h": (nemotron_h, nemotron_h.nemotron_h_tiny),
+                  }[family]
+    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: backend)
+    eng, _ = _pool_stats(model, cfg(), prefix_cache=False)
+    monkeypatch.undo()
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 32)
+    assert [moe.expert_kernel_engages(r) for r in (16, 32, 64)] == [
+        False, False, True]
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        eng.start()
+        rng = np.random.default_rng(4)
+        for n in (9, 40, 70):
+            assert len(list(eng.submit(rng.integers(1, 100, n),
+                                       max_new_tokens=3).tokens())) == 3
+        eng.stop()
+        spans = tracing.recorded_spans("engine.dispatch_prefill")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    assert eng.error is None
+    got = [(s["attrs"]["token_rows"], s["attrs"]["expert_kernel"])
+           for s in spans]
+    assert got == [(16, 0), (64, int(routes)), (128, int(routes))]
+    stats = eng.stats()
+    assert stats["prefill_dispatches"] == 3
+    assert stats["expert_kernel_dispatches"] == 2 * routes
 
 
 def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):

@@ -175,12 +175,17 @@ def test_the_llama_decode_program_is_the_one_that_changed():
 # widest prefill the serving cells warm (two cold prompts of 2048 tokens
 # over 16 pages), which is where ``SCORES_MAX_BYTES`` (the plain prefill
 # attention's, ``ops/paged_prefill_attention.py`` since PR 34) draws its
-# line: at 32 heads it still goes whole.
+# line: at 32 heads it still goes whole. (PR 44 re-pinned the last: at
+# 4,096 rows OLMoE's experts run over the rows sorted by expert, which
+# were XLA's ``ragged_dot`` (afa0c5c62c6296a9) and are the grouped kernel
+# or, lowered for the CPU as here, the plain loop over the experts; the
+# narrow OLMoE programs above, 8 and 32 rows, are under the line and
+# stand.)
 _BEFORE_THE_LAYER_PLAN = {
     "llama-decode-bf16": "fdd46263ed8b0999",
     "llama-decode-int8": "1cf7fdecece96f9a",
     "llama-prefill-wide": "7e741f0e9ff873fa",
-    "olmoe-prefill-wide": "afa0c5c62c6296a9",
+    "olmoe-prefill-wide": "3fbcf5a045086096",
 }
 _ONE_RUN = {
     "llama-decode-bf16": lambda: _engine_text(

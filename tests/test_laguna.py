@@ -183,12 +183,18 @@ def test_the_four_shares_and_the_shared_expert_once_are_the_uncut_layer(tiny):
     assert float(stats["experts_touched"]) == float(jnp.sum(load > 0))
 
 
-@pytest.mark.parametrize("tokens", [40, moe.DENSE_MAX_TOKENS + 8],
-                         ids=["every-held-expert", "grouped"])
-def test_a_share_is_its_experts_part_in_both_formulations(tokens):
-    """Both formulations of ``moe_ffn_dropless`` take the share: 3 of 8
-    experts held from the third, padding rows sent nowhere, against the
-    whole layer's op with the other experts' output weights zeroed."""
+@pytest.mark.parametrize("tokens,expert_formulation", [
+    (40, "as-chosen"), (moe.DENSE_MAX_TOKENS + 8, "as-chosen"),
+    (40, "sorted-loop"), (40, "sorted-kernel"),
+    (moe.DENSE_MAX_TOKENS + 8, "sorted-kernel")],
+    indirect=["expert_formulation"])
+def test_a_share_is_its_experts_part_in_both_formulations(
+        tokens, expert_formulation):
+    """Every formulation of ``moe_ffn_dropless`` takes the share: 3 of 8
+    experts held from the third, padding rows sent nowhere (sorted: the
+    choices on absent experts and the padding rows lie behind the last
+    group), against the whole layer's op with the other experts' output
+    weights zeroed."""
     ks = jax.random.split(jax.random.key(2), 5)
     d, f, e = 32, 16, 8
     x = jax.random.normal(ks[0], (tokens, d), jnp.float32)
@@ -367,6 +373,10 @@ def test_engine_serves_laguna_past_the_window_and_over_reused_pages(tiny):
     first = rng.integers(1, 128, 50, dtype=np.int32)
     second = np.concatenate([first[:32], rng.integers(1, 128, 19,
                                                       dtype=np.int32)])
+    # the ring is the process's: what another file's engine left in it
+    # (another family's counts) is not this engine's
+    tracing.drain_spans(1 << 20)
+    tracing._rings()[1].clear()
     tracing.enable_tracing()
     try:
         eng = PagedLLMEngine(cfg=cfg, params=params, max_batch=2,

@@ -182,13 +182,13 @@ def test_the_two_chips_parts_add_up_to_the_uncut_layer(tiny):
 
 # -- experts of two matrices --------------------------------------------------
 
-@pytest.mark.parametrize("formulation", ["dense", "grouped"])
+@pytest.mark.parametrize(
+    "expert_formulation", ["every-held-expert", "sorted-loop",
+                           "sorted-kernel"], indirect=True)
 @pytest.mark.parametrize("held,first", [(8, 0), (4, 4)],
                          ids=["whole", "share"])
-def test_relu2_experts_against_a_loop_over_tokens(monkeypatch, formulation,
-                                                  held, first):
-    if formulation == "grouped":
-        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0)
+def test_relu2_experts_against_a_loop_over_tokens(expert_formulation, held,
+                                                  first):
     t, d, f, e, k = 13, 32, 20, 8, 3
     ks = jax.random.split(jax.random.key(7), 5)
     x = jax.random.normal(ks[0], (t, d))

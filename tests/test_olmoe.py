@@ -149,17 +149,21 @@ def per_token_loop(x, router, gate, up, down, top_k, norm, valid=None):
 
 
 @pytest.mark.parametrize("norm", [False, True])
-@pytest.mark.parametrize("t,dense_max", [
-    (1, None), (7, None), (300, None), (1100, None),   # as the op chooses
-    (7, 0), (300, 0)])                                  # grouped, forced
-def test_dropless_op_against_a_per_token_loop(monkeypatch, t, dense_max,
-                                              norm):
+@pytest.mark.parametrize("t,expert_formulation", [
+    (1, "as-chosen"), (7, "as-chosen"), (100, "as-chosen"),
+    (300, "as-chosen"),                   # on both sides of the line
+    (300, "every-held-expert"),           # forced past the line
+    (7, "sorted-loop"), (300, "sorted-loop"),
+    (7, "sorted-kernel"), (300, "sorted-kernel")], indirect=[
+        "expert_formulation"])
+def test_dropless_op_against_a_per_token_loop(t, expert_formulation, norm):
     """Every formulation the op can choose, at token counts on both sides
-    of its threshold (and the grouped one forced at small counts, where
-    groups are empty)."""
-    if dense_max is not None:
-        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", dense_max)
-    assert (t > moe.DENSE_MAX_TOKENS) == (t == 1100 or dense_max == 0)
+    of its line, and each of them forced: every held expert past the
+    line, and the sorted rows (the plain loop, and the kernel interpreted)
+    at small counts too, where groups are empty."""
+    assert moe.expert_kernel_engages(t) == (
+        t == 300 and expert_formulation == "as-chosen"
+        or expert_formulation.startswith("sorted"))
     args = op_inputs(t, seed=t)
     out, load = jax.jit(lambda *a: moe.moe_ffn_dropless(
         *a, top_k=3, norm_topk_prob=norm))(*args)
@@ -169,10 +173,13 @@ def test_dropless_op_against_a_per_token_loop(monkeypatch, t, dense_max,
     assert int(load.sum()) == 3 * t                     # nothing dropped
 
 
-@pytest.mark.parametrize("dense_max", [None, 0])
-def test_padding_rows_go_to_no_expert(monkeypatch, dense_max):
-    if dense_max is not None:
-        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", dense_max)
+_FORCED = pytest.mark.parametrize(
+    "expert_formulation", ["as-chosen", "sorted-loop", "sorted-kernel"],
+    indirect=True)
+
+
+@_FORCED
+def test_padding_rows_go_to_no_expert(expert_formulation):
     args = op_inputs(20, seed=5)
     valid = np.arange(20) % 3 != 1
     out, load = jax.jit(lambda *a: moe.moe_ffn_dropless(
@@ -184,14 +191,12 @@ def test_padding_rows_go_to_no_expert(monkeypatch, dense_max):
     assert int(load.sum()) == 3 * int(valid.sum())
 
 
-@pytest.mark.parametrize("dense_max", [None, 0])
-def test_nothing_is_dropped_where_capacity_routing_drops(monkeypatch,
-                                                         dense_max):
+@_FORCED
+def test_nothing_is_dropped_where_capacity_routing_drops(expert_formulation):
     """Every token's first choice is expert 0: capacity routing keeps
     ``1.25 * T * k / E`` of them and drops the rest; the dropless op
-    serves all."""
-    if dense_max is not None:
-        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", dense_max)
+    serves all (sorted, expert 0's group is several tiles of rows and
+    most experts' are empty)."""
     t, k, e = 32, 2, 8
     x, router, gate, up, down = op_inputs(t, seed=9)
     x = jnp.abs(x)

@@ -258,6 +258,40 @@ _PREFILL_KERNEL = re.compile(
     r"%paged_prefill_attn[.\d]* = \S+ custom-call\(.*tpu_custom_call")
 
 
+# the grouped expert kernel's instruction (PR 44): two a layer of routed
+# experts, rows x the up stacks and x the down stack
+_EXPERT_KERNEL = re.compile(
+    r"%grouped_expert_ffn[.\d]* = \S+ custom-call\(.*tpu_custom_call")
+
+
+def _expert_stack_moves(text, experts, d, f):
+    """The instructions ANYWHERE in a compiled program (a run of one
+    layer is no loop) that move the held experts' weights, whole or a
+    layer of them: a copy, an asynchronous copy or slice whose result is
+    ``bf16[..., experts, d, f]`` or ``[..., experts, f, d]``, or a fusion
+    that writes ONE layer's stack (the slice of a scanned run of layers,
+    which a kernel cannot read through: the engine hands the kernel the
+    run's stacks and the layer's index instead). The grouped kernel takes
+    a stack in the layout it lies in; asked for in another it would be
+    copied whole, a GB a layer, under no name a trace shows."""
+    stack = re.compile(rf"bf16\[(?:\d+,)?{experts},(?:{d},{f}|{f},{d})\]")
+    sliced = re.compile(
+        rf"^\s*(?:ROOT )?%\S+ = bf16\[(?:1,)?{experts},(?:{d},{f}|{f},{d})\]"
+        r"\S* fusion\(")
+    moves, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            # inside a fusion's own computation a value of the stack's
+            # shape is no array in memory (every-expert's matmul reads its
+            # layer through such a one)
+            fused = line.lstrip("%").startswith("fused_computation")
+        elif not fused and (
+                sliced.match(line) or (m := _MOVE.match(line))
+                and m.group(2) != "custom-call" and stack.search(m.group(1))):
+            moves.append(line.strip()[:160])
+    return moves
+
+
 def _window(slots, pages, nkv):
     """An operation whose result is a page window of every slot (the
     gather decode attended over before PR 30, or a float32 copy of it)."""
@@ -489,8 +523,8 @@ def test_decode_programs_compile_over_int8_pages(v5e_2x2, model, pages,
 _MOE_PAGES, _MOE_LAYERS = 352, 10
 _MOE_PROGRAMS = [
     ("decode", (16, 8), False), ("decode", (8, 8), False),
-    ("prefill", (2, 512, 4), False), ("prefill", (1, 1024, 8), False),
-    ("prefill", (2, 1024, 8), True)]
+    ("prefill", (1, 128, 4), False), ("prefill", (2, 512, 4), True),
+    ("prefill", (1, 1024, 8), True), ("prefill", (2, 1024, 8), True)]
 
 
 @pytest.mark.parametrize(
@@ -501,14 +535,23 @@ def test_olmoe_d10_engine_programs_compile_and_fit(v5e_2x2, program, dims,
     """The same two engine programs around OLMoE's block: they compile
     for the chip beside 8.8 GB of weights and a 3.7 GB pool, keep the
     pool in place, and take the dropless op's formulation from their
-    token count: every expert over every token up to 1,024 tokens (no
-    grouped matmul in the program), the chosen experts alone past it
-    (XLA's ragged-dot kernel, three times a layer)."""
+    token count (``ops/moe.py:expert_kernel_engages``): every expert over
+    every token up to 128 rows, which is every decode program (no grouped
+    matmul in the program), the rows sorted by expert through the grouped
+    kernel past it (two instructions in the layers' loop, no
+    ``ragged-dot``), with no expert stack moved to feed it."""
     cfg = dataclasses.replace(olmoe.olmoe_1b_7b(), n_layers=_MOE_LAYERS)
     compiled = _compile_engine_program(v5e_2x2[0], olmoe, cfg, _MOE_PAGES,
                                        program, dims)
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert ("ragged-dot" in text) == grouped
+    from ray_tpu.ops.moe import expert_kernel_engages
+
+    rows = 32 if program == "decode" else dims[0] * dims[1]
+    assert expert_kernel_engages(rows) == grouped
+    assert "ragged-dot" not in text
+    assert len(_EXPERT_KERNEL.findall(text)) == 2 * grouped
+    assert _in_loops(text, _EXPERT_KERNEL) == 2 * grouped
+    assert not _expert_stack_moves(text, 64, 2048, 1024)
     assert not _pool_copy(_MOE_LAYERS, _MOE_PAGES, 16).findall(text)
     # decode: the same kernel at one query head a KV head (MHA), and
     # neither the window nor a float32 copy of it
@@ -643,6 +686,16 @@ def test_prefill_programs_hold_the_kernel_by_the_rule(v5e_2x2, model, pages,
     text = compiled.as_text()
     assert len(_PREFILL_KERNEL.findall(text)) == kernels
     assert not _DECODE_KERNEL.search(text)
+    # the routed experts' kernel follows its own rule, the rows alone:
+    # two instructions a run of expert layers (Laguna's two runs: four)
+    from ray_tpu.ops.moe import expert_kernel_engages
+
+    expert_runs = {"d12": 0, "olmoe-d10": 1, "laguna-ep4-d5": 2}[model]
+    assert len(_EXPERT_KERNEL.findall(text)) == (
+        2 * expert_runs * expert_kernel_engages(dims[0] * dims[1]))
+    assert "ragged-dot" not in text
+    if model == "laguna-ep4-d5":
+        assert not _expert_stack_moves(text, 64, 3072, 1024)
     if kernels:
         assert not _score_arrays(text, dims[2] * 128)
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
@@ -854,18 +907,16 @@ def test_latent_kernel_compiles_alone(v5e_2x2, heads, rank, lanes, table):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
-    """The cell's decode program (chunk 16, the 64-page table): each of
-    its two runs of full layers holds the latent kernel, no operation
-    gathers the slots' 2,048 chosen rows (``bf16[131072,640]``), the
-    sliding layers gather their five pages as they did, and the program
-    needs less beside its arguments than the gathered one did (0.67
-    GB)."""
+def _lower_note_program(device, program, dims):
+    """One of the engine's two programs for ``serve-note-gen``'s plan
+    (two full layers with an indexer, three sliding ones; 32 of 256
+    experts held), over the row pools the plan states. ``dims``: decode
+    (chunk, table pages); prefill (prompts, tokens, table pages)."""
     from ray_tpu.models import dots3_note
     from ray_tpu.ops.paged_attention import row_pool
     from ray_tpu.serve.paged_llm import _pool_slices
 
-    one_chip = SingleDeviceSharding(v5e_2x2[0])
+    one_chip = SingleDeviceSharding(device)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -887,20 +938,109 @@ def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
                               if run.rows == rows), _NOTE_PAGES, 128, row))
             for row in rows)]
     assert [p.shape[-1] for p in pools] == [640, 128, 1152]
-    slots = _NOTE_SLOTS
-    compiled = jax.jit(
-        partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=16,
-                page_size=128, quantized=False),
-        donate_argnums=(1, 2, 3)).lower(
-        params, *pools, shape((slots, _NOTE_TABLE), jnp.int32),
-        shape((slots,), jnp.int32), shape((slots,), jnp.int32),
-        shape((slots,), jnp.bool_), shape((slots,), jnp.float32),
-        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    if program == "decode":
+        chunk, pages = dims
+        slots = _NOTE_SLOTS
+        fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
+                     page_size=128, quantized=False)
+        args = (shape((slots, pages), jnp.int32), shape((slots,), jnp.int32),
+                shape((slots,), jnp.int32), shape((slots,), jnp.bool_),
+                shape((slots,), jnp.float32), key)
+    else:
+        n, tokens, pages = dims
+        fn = partial(PagedLLMEngine._paged_prefill_impl, cfg, page_size=128,
+                     quantized=False)
+        args = (shape((n, pages), jnp.int32), shape((n, tokens), jnp.int32),
+                shape((n,), jnp.int32), shape((n,), jnp.int32),
+                shape((n,), jnp.float32), key)
+    return cfg, jax.jit(fn, donate_argnums=(1, 2, 3)).lower(
+        params, *pools, *args)
+
+
+def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
+    """The cell's decode program (chunk 16, the 64-page table): each of
+    its two runs of full layers holds the latent kernel, no operation
+    gathers the slots' 2,048 chosen rows (``bf16[131072,640]``), the
+    sliding layers gather their five pages as they did, and the program
+    needs less beside its arguments than the gathered one did (0.67
+    GB). Its 64 rows a step are under the routed experts' line: no
+    grouped expert kernel."""
+    _, lowered = _lower_note_program(v5e_2x2[0], "decode",
+                                     (16, _NOTE_TABLE))
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert len(_LATENT_KERNEL.findall(text)) == 2
     assert "bf16[131072,640]" not in text
     assert "bf16[320,128,1152]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.62e9
+    assert not _EXPERT_KERNEL.search(text)
+
+
+def test_note_d5_cold_prefill_runs_its_experts_in_the_grouped_kernel(
+        v5e_2x2):
+    """The cell's cold prompt (one of 4,096 tokens, the 32-page window):
+    32,768 (token, choice) pairs of which an eighth fall on the 32 held
+    experts; each of the plan's runs of sparse layers holds the grouped
+    kernel twice and no ``ragged-dot``, no stack of the held experts
+    (1.5 GB a layer) is moved to feed it, and the program fits beside
+    its arguments."""
+    from ray_tpu.ops.moe import expert_kernel_engages
+
+    cfg, lowered = _lower_note_program(v5e_2x2[0], "prefill", (1, 4096, 32))
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert expert_kernel_engages(4096)
+    kernels = len(_EXPERT_KERNEL.findall(text))
+    assert kernels and kernels % 2 == 0
+    assert "ragged-dot" not in text
+    assert not _expert_stack_moves(text, 32, cfg.d_model, 1536)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            - mem.alias_size_in_bytes) < 15.75e9
+
+
+_EXPERT_WIDTHS = [
+    # held experts, model width, expert width, gated, (token, choice) pairs
+    (64, 2688, 1856, False, 6144),       # serve-reason-gen: 2 x 512 x 6
+    (64, 3072, 1024, True, 20480),       # serve-code-gen: 2,048 x 10
+    (32, 5120, 1536, True, 32768),       # serve-note-gen: 4,096 x 8
+    (64, 2048, 1024, True, 8192)]        # serve-moe-gen: 2 x 512 x 8
+
+
+@pytest.mark.parametrize(
+    "experts,d,f,gated,pairs", _EXPERT_WIDTHS,
+    ids=["nano-relu2", "code-swiglu", "note-swiglu", "moe-swiglu"])
+def test_expert_kernel_compiles_alone(v5e_2x2, experts, d, f, gated, pairs):
+    """The grouped expert kernel at the four widths the benchmark runs:
+    Mosaic takes both calls (the whole contraction a block, the widest
+    column block that fits, 64 MiB of the core's memory at most) and the
+    stacks of a run of three layers go in whole, as they lie, with the
+    layer's index. Nemotron's up stack is the one whose last
+    axis is no whole number of lanes: the compiler keeps such a parameter
+    as [.., experts, f, d] (``{2,3,1,0}``), and the kernel, which contracts its
+    blocks over their last axis then, is handed it without a copy."""
+    from ray_tpu.ops.grouped_expert_ffn import grouped_expert_ffn_kernel
+
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(grouped_expert_ffn_kernel).lower(
+        shape((pairs, d)), shape((experts,), jnp.int32),
+        shape((3, experts, d, f)) if gated else None,
+        shape((3, experts, d, f)), shape((3, experts, f, d)),
+        shape((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(_EXPERT_KERNEL.findall(text)) == 2
+    assert not _expert_stack_moves(text, experts, d, f)
+    layout = re.search(rf"bf16\[3,{experts},{d},{f}\]\{{([\d,]+):",
+                       text.split("\n", 1)[0]).group(1)
+    assert layout == ("3,2,1,0" if f % 128 == 0 else "2,3,1,0")
+    # the sorted rows in, the hidden activations between the calls and
+    # the float32 rows out, and no more
+    assert compiled.memory_analysis().temp_size_in_bytes < pairs * (
+        2 * f + 64) + (1 << 20)
 
 
 # NVIDIA-Nemotron-3-Nano-30B-A3B cut to its first nine layers, MEMEM*EME,
@@ -975,8 +1115,10 @@ def test_nemotron_d9_engine_programs_hold_what_their_layers_keep(
     at the published widths: arguments of 7.7 GB (6.33 GB of weights, a
     0.30 GB pool of ONE layer's pages, 1.09 GB of state over FOUR layers)
     fit a v5e with the temporaries beside them (a prefill of 2 x 512 rows
-    computes every held expert for every row: under 0.5 GB); pools and
-    state are donated and come back in place. A decode program attends
+    sorts its 6,144 (token, choice) pairs by held expert and holds the
+    grouped kernel twice an ``E`` layer: under 0.65 GB, the pairs' rows in
+    and out in float32); pools and state are donated and come back in
+    place. A decode program attends
     in the decode kernel at 16 query heads a KV head and advances the
     state in the state kernel, once a mixer, over the four-layer array
     (blocks of 32 heads of [64, 128], four of the eight groups a block).
@@ -996,9 +1138,18 @@ def test_nemotron_d9_engine_programs_hold_what_their_layers_keep(
     state_bytes = 4 * _NANO_SLOTS * (4 * 64 * 64 * 128 + 2 * 3 * 6144)
     assert 7.6e9 < mem.argument_size_in_bytes < 7.8e9
     assert mem.alias_size_in_bytes >= 2 * pool_bytes + state_bytes
-    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.temp_size_in_bytes < (0.65e9 if program == "prefill"
+                                     else 0.5e9)
     assert not _pool_copy(1, _NANO_PAGES, 2).findall(text)
     assert bool(_DECODE_KERNEL.search(text)) == (program == "decode")
+    # the grouped expert kernel by its rule: both prefill programs are
+    # over the line (512 and 1,024 rows), a decode step's 128 rows under
+    # it; and the stacks are read where they lie: the up stack [64, 2688,
+    # 1856] lies on the chip as [64, 1856, 2688] and is handed over so
+    assert len(_EXPERT_KERNEL.findall(text)) == (
+        8 if program == "prefill" else 0)
+    assert "ragged-dot" not in text
+    assert not _expert_stack_moves(text, 64, cfg.d_model, 1856)
     assert len(_NANO_STATE_KERNEL.findall(text)) == (
         4 if program == "decode" else 0)
     widths = {(cfg.d_ssm, cfg.d_model): (cfg.d_ssm, (cfg.d_model,)),
@@ -1008,7 +1159,6 @@ def test_nemotron_d9_engine_programs_hold_what_their_layers_keep(
              for m in _stack_moves_in_loops(text, 1, d_in, w)]
     if program == "prefill":
         assert not moves
-        assert "ragged-dot" not in text     # 1,024 rows: every held expert
         return
     assert _in_loops(text, _NANO_STATE_KERNEL) == 4
     # the four kernel calls, and nothing else, pass over the state
