@@ -82,6 +82,7 @@ class Request:
     drain_t: float | None = None
     generated: int = 0
     slot: int = -1
+    done: bool = False     # the engine has put its end-of-stream
     # set before the None sentinel when the request itself failed
     # (e.g. prompt longer than the cache) — distinguishes rejection from
     # a legitimate empty/EOS completion

@@ -188,10 +188,18 @@ the vLLM-style paged format of ``ray_tpu/ops/paged_attention.py``.
   TAIL (a live slot's steps after its answer ended inside the chunk),
   OVERRUN AHEAD (all of a live slot's steps where its answer had ended
   in the chunk read before: the double buffer dispatches chunk N+1
-  before it reads chunk N), VACANT (slots not live at dispatch); the
-  four sum to the chunk's slot-steps, exactly. A prefill dispatch is
-  ``group x bucket`` token-rows, of which the rows' suffixes are prompt
-  tokens and the rest padding to the bucket.
+  before it reads chunk N, so an end the host could not foresee, an
+  ``eos_id``'s, costs the chunk in flight; and a request whose first
+  token is its last is live in the one chunk dispatched behind its
+  prefill, since ends are foreseen where decode is dispatched), VACANT (slots not live at
+  dispatch); the four sum to the chunk's slot-steps, exactly. An end
+  the host CAN foresee costs no chunk in flight: every request carries
+  its ``max_new_tokens``, the loop knows how many tokens it has asked
+  the device for, and the slot whose answer ends inside the chunk just
+  dispatched is released there (``_release_foreseen``), to be taken by
+  the next admission while its last tokens are still to be read. A
+  prefill dispatch is ``group x bucket`` token-rows, of which the rows'
+  suffixes are prompt tokens and the rest padding to the bucket.
 
 Threading: one engine thread owns the device loop (admission, prefill
 and decode dispatches, emission); a watcher thread blocks on each
@@ -238,7 +246,7 @@ class _Chunk(NamedTuple):
     """A dispatched decode chunk until its tokens are read back."""
     toks: object          # [chunk, max_batch] tokens, on the device
     active_idx: list      # the slots live at dispatch
-    gens: list            # their admission generations then
+    reqs: list            # the request each of them decoded for
     seq: int              # its place among the chunks (``_dispatch_seq``)
     stream_seq: int       # among all dispatches (the spans' ``seq``)
     drain: bool           # the short chunk (``_use_drain_chunk``)
@@ -496,12 +504,15 @@ class PagedLLMEngine:
         self._chunk_period: float | None = None  # EMA between syncs
         # host-side slot state (the trusted copy of the device lengths)
         self._lengths = np.zeros((max_batch,), np.int32)
+        # what the device's last-token vector starts from (_last_dev)
         self._last_tok = np.zeros((max_batch,), np.int32)
-        # bumped per admission into a slot: lets the pipelined loop tell
-        # "same slot, same request" from "same slot, NEW request" when
-        # deciding whether an in-flight chunk's tokens are still valid
-        self._slot_gen = np.zeros((max_batch,), np.int64)
+        # the request a slot will next be asked to decode for. One whose
+        # end lies inside what is already dispatched has given its slot
+        # up (_release_foreseen) and waits in _leaving, by request id,
+        # for its last tokens: the chunks in flight know whom they
+        # decoded for (_Chunk.reqs), whoever holds the slot by then
         self._active: list[Request | None] = [None] * max_batch
+        self._leaving: dict[int, Request] = {}
         self._waiting: "queue.Queue[Request]" = queue.Queue()
         self._req_ids = itertools.count()
         self._stop = threading.Event()
@@ -615,6 +626,11 @@ class PagedLLMEngine:
         self.decode_overrun_tail = 0    # after the answer's end, same chunk
         self.decode_overrun_ahead = 0   # a chunk in flight at the answer's end
         self.decode_vacant = 0          # slots not live at dispatch
+        # answers whose end the loop foresaw from its own dispatches,
+        # and of their slots those a waiting request took before the
+        # end had been read back (_release_foreseen, _admit_round)
+        self.retirements_foreseen = 0
+        self.slots_handed_over = 0
         self._table = np.full((self.max_batch, self.max_pages_per_seq),
                               -1, np.int32)
         self._alloc = PageAllocator(self.num_pages)
@@ -1115,7 +1131,6 @@ class PagedLLMEngine:
                     self._next_key(), *self._state), 4)[0]
                 np.asarray(toks)
         self._lengths[:] = 0
-        self._last_tok[:] = 0
 
     # -- threads and submission --------------------------------------------
 
@@ -1200,7 +1215,13 @@ class PagedLLMEngine:
     # -- admission and prefill ---------------------------------------------
 
     def _free_slots(self) -> list[int]:
-        return [i for i, r in enumerate(self._active) if r is None]
+        """The slots no request holds; last among them those whose
+        occupant still waits for a chunk in flight, so that
+        ``slots_handed_over`` counts the admissions no other slot could
+        have taken."""
+        leaving = {r.slot for r in self._leaving.values()}
+        return sorted((i for i, r in enumerate(self._active) if r is None),
+                      key=leaving.__contains__)
 
     def _reserve_pages(self, req: Request, slot: int) -> bool:
         """Reserve-on-admit: pages for prompt + token budget + one page
@@ -1365,18 +1386,22 @@ class PagedLLMEngine:
 
     def _admit(self, first: "Request | None" = None):
         with _tracing.phase("engine.admit", kind="serve") as ph:
+            handed_over = self.slots_handed_over
             admitted = self._admit_round(first)
             if ph:
-                ph.set(admitted=admitted)
+                ph.set(admitted=admitted,
+                       handed_over=self.slots_handed_over - handed_over)
 
     def _admit_round(self, first: "Request | None") -> int:
-        """Prefill waiting requests into free slots. All prefills of the
-        round are DISPATCHED first and their first tokens extracted in
-        one host pass — each sync has a fixed cost, so a burst of
-        admissions pays ~one, not one per request. ``first``: a request
-        already pulled off the queue (the admission window's timed get)
-        — admitted ahead of the queue, requeued on backpressure like any
-        other. Returns the number of requests admitted."""
+        """Prefill waiting requests into free slots, those given up
+        ahead of their occupant's last read-back (``_release_foreseen``)
+        among them. All prefills of the round are DISPATCHED first and
+        their first tokens extracted in one host pass — each sync has a
+        fixed cost, so a burst of admissions pays ~one, not one per
+        request. ``first``: a request already pulled off the queue (the
+        admission window's timed get) — admitted ahead of the queue,
+        requeued on backpressure like any other. Returns the number of
+        requests admitted."""
         admits = []   # (req, slot, plen, padded)
         self._admission_blocked = False
         pulled = first
@@ -1459,13 +1484,14 @@ class PagedLLMEngine:
             except Exception:  # noqa: BLE001 - backend without async copy
                 pass
             for (req, slot, plen, _) in part:
+                # a HAND-OVER where the slot's last occupant still waits
+                # for tokens of a chunk in flight: that chunk knows whom
+                # it decoded for, and lies before this prefill on the
+                # device stream
+                self.slots_handed_over += any(
+                    r.slot == slot for r in self._leaving.values())
                 req.slot = slot
                 self._active[slot] = req
-                # admission GENERATION: an in-flight decode chunk
-                # dispatched for this slot's PREVIOUS occupant must
-                # neither have its tokens emitted to the new request
-                # nor be chained from
-                self._slot_gen[slot] += 1
                 self._lengths[slot] = plen
             # any chunk dispatched from here on (seq >= _dispatch_seq)
             # executes after this prefill on the device stream
@@ -1599,7 +1625,6 @@ class PagedLLMEngine:
     def _emit(self, req: Request, tok: int):
         req.generated += 1
         self.total_generated += 1
-        self._last_tok[req.slot] = tok
         # the cache-capacity cutoff counts prompt + emitted tokens — the
         # _lengths mirror is chunk-granular (pre-advanced at dispatch)
         # and would trip this up to two chunks early
@@ -1608,22 +1633,50 @@ class PagedLLMEngine:
             len(req.prompt) + req.generated >= self.max_len
         req.out.put(tok)
         if done:
+            req.done = True
             req.out.put(None)
-            self._active[req.slot] = None
             self.total_finished += 1
-            self._retire_slot(req.slot)
+            if self._leaving.pop(req.request_id, None) is None:
+                # an end the loop could not foresee (an ``eos_id``): the
+                # slot is released here, where the end is read, and the
+                # chunk in flight behind it was dispatched for nobody
+                self._active[req.slot] = None
+                self._retire_slot(req.slot)
+
+    def _release_foreseen(self, slots):
+        """Of ``slots``, live in the chunk just dispatched, release
+        those whose answer ends inside it, whatever tokens come back:
+        the first token and every chunk asked of the device
+        (``_lengths`` counts them at dispatch and does not lag as
+        ``generated`` does) cover the request's ``max_new_tokens`` or
+        reach ``max_len``. Such a slot has no use for the next chunk and
+        a waiting request does: it is not live at the next dispatch and
+        the next admission may take it (a hand-over), a chunk sooner
+        than where its end is read back. The request waits in
+        ``_leaving`` for its last tokens."""
+        for slot in slots:
+            req = self._active[slot]
+            asked = int(self._lengths[slot]) - len(req.prompt) + 1
+            if (asked >= req.max_new_tokens
+                    or len(req.prompt) + asked >= self.max_len):
+                self._active[slot] = None
+                self._leaving[req.request_id] = req
+                self.retirements_foreseen += 1
+                self._retire_slot(slot)
 
     def _retire_slot(self, slot: int):
-        """A request finished and its slot was released: its pages go
+        """A slot was released, where its request's end was read or,
+        foreseen, where its last chunk was dispatched: its pages go
         back, the shared ones now and its own after two chunk syncs."""
         self._dev_dirty = True
-        # a chunk dispatched before this retirement was observed may
+        # a chunk dispatched before the release (a foreseen end's last
+        # chunk; the chunk in flight behind an end that was read) may
         # still write into the slot's own (reserved) pages: defer the
         # free by two chunk syncs. Shared prefix pages are released
         # immediately — nothing ever WRITES them (suffix and decode
-        # positions lie past the prefix), and a stale in-flight read of
-        # a page later evicted + rewritten only feeds tokens the
-        # retired slot already discards.
+        # positions lie past the prefix), and whatever evicts and
+        # rewrites one is a prefill dispatched later, so it runs after
+        # every chunk in flight that still reads it.
         pages = self._alloc.owned.pop(slot, [])
         shared = self._shared.pop(slot, [])
         self._pending_hashes.pop(slot, None)
@@ -1683,6 +1736,7 @@ class PagedLLMEngine:
             with self._submit_lock:
                 self._stop.set()
                 live = {id(r): r for r in self._active if r is not None}
+                live.update((id(r), r) for r in self._leaving.values())
                 live.update((id(r), r) for r in self._admitting)
                 for req in live.values():
                     req.out.put(None)
@@ -1704,7 +1758,10 @@ class PagedLLMEngine:
         this is the common case — admission then never waits for a
         retirement), or a retirement is imminent. The horizon is 3
         chunks because the double-buffered loop's ``generated`` counts
-        lag the device by up to two in-flight chunks."""
+        lag the device by up to two in-flight chunks. What the short
+        chunk buys since ends are foreseen (``_release_foreseen``: no
+        chunk in flight is lost behind one) is the shorter TAIL of the
+        chunk an answer ends in, and an earlier admission."""
         if self._waiting.empty():
             return False
         if any(r is None for r in self._active) \
@@ -1825,29 +1882,25 @@ class PagedLLMEngine:
             # host mirror advances deterministically (+chunk per active
             # slot) — retired slots are reconciled at admission
             self._lengths[active_idx] += chunk
-            gens = [int(self._slot_gen[i]) for i in active_idx]
+            reqs = [self._active[i] for i in active_idx]
+            self._release_foreseen(active_idx)
             seq = self._dispatch_seq
             self._dispatch_seq += 1
         self._ready_q.put(("decode", stream_seq, toks, now, (), ph or None))
-        return _Chunk(toks, active_idx, gens, seq, stream_seq, drain)
+        return _Chunk(toks, active_idx, reqs, seq, stream_seq, drain)
 
-    def _emit_chunk(self, toks_np, active_idx, gens) -> tuple:
-        """A chunk's tokens to the streams of the requests that still
-        wait for them. Returns the steps it computed for nobody, in the
-        slots that were live at its dispatch: (those after an answer's
-        end inside this chunk, those of slots whose answer had ended
-        before this chunk was read: the double buffer's price)."""
+    def _emit_chunk(self, toks_np, active_idx, reqs) -> tuple:
+        """A chunk's tokens to the streams of the requests it decoded
+        for, whoever holds their slots by now. Returns the steps it
+        computed for nobody, in the slots that were live at its
+        dispatch: (those after an answer's end inside this chunk, those
+        of slots whose answer had ended before this chunk was read: the
+        double buffer's price for an end the loop could not foresee)."""
         steps = toks_np.shape[0]
         tail = ahead = 0
-        for i, gen in zip(active_idx, gens):
-            if self._slot_gen[i] != gen:
-                # slot re-admitted since dispatch: the chunk's tokens
-                # belong to the RETIRED occupant, not this request
-                ahead += steps
-                continue
+        for i, req in zip(active_idx, reqs):
             for t in range(steps):
-                req = self._active[i]
-                if req is None:
+                if req.done:
                     # finished mid-chunk (drop the surplus tokens), or
                     # in a chunk read before this one (drop them all)
                     if t:
@@ -1876,7 +1929,7 @@ class PagedLLMEngine:
         with _tracing.phase("engine.emit", kind="serve") as ph:
             generated, finished = self.total_generated, self.total_finished
             tail, ahead = self._emit_chunk(toks_np, chunk.active_idx,
-                                           chunk.gens)
+                                           chunk.reqs)
             steps = toks_np.shape[0]
             slot_steps = steps * self.max_batch
             delivered = self.total_generated - generated
@@ -1930,7 +1983,12 @@ class PagedLLMEngine:
         emitted asynchronously when their copy lands (_drain_firsts).
         Emission order per request is preserved: firsts dispatched
         before chunk N are force-drained right after chunk N's sync,
-        before the chunk's tokens are emitted.
+        before the chunk's tokens are emitted. Chunk N+1 is dispatched
+        before chunk N is read, so an end seen only in chunk N's tokens
+        (an ``eos_id``) leaves chunk N+1 computing for nobody in that
+        slot; an end on the request's own bound is known at chunk N's
+        dispatch, and chunk N+1 already decodes for the slot's next
+        request (``_release_foreseen``).
 
         Each pass is one ``engine.iteration`` span while spans are
         recorded (``tracing.phase``), its phases its children: what the
@@ -2023,6 +2081,10 @@ class PagedLLMEngine:
             "decode_overrun_tail": self.decode_overrun_tail,
             "decode_overrun_ahead": self.decode_overrun_ahead,
             "decode_vacant": self.decode_vacant,
+            # ends the loop foresaw, and the slots among them that a
+            # waiting request took before the end was read back
+            "retirements_foreseen": self.retirements_foreseen,
+            "slots_handed_over": self.slots_handed_over,
             "prefill_token_rows": self.prefill_token_rows,
             "prefill_new_tokens": self.prefill_new_tokens,
             # recurrent state beside the pages (0 where the plan has no

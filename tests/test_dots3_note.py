@@ -420,6 +420,38 @@ def test_a_slots_new_tenant_never_reads_its_predecessors_rows(tiny):
     assert serve([old, new])[1] == serve([new])[0]
 
 
+def test_a_handed_over_slots_rows_are_its_new_tenants(tiny):
+    """One slot, two prompts waiting before the loop starts: the first's
+    end is foreseen, and the second's prefill is dispatched behind the
+    first's last chunk, into the slot and (the pool being two requests
+    wide) pages the first has just given up, before the first's last
+    tokens are read. Both streams are what an engine gives that serves
+    the two in a slot each: latent rows, index keys and the selection
+    over them are each tenant's own."""
+    cfg, params = tiny
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, 128, n, dtype=np.int32) for n in (60, 45)]
+    budgets = [7, 6]
+
+    def serve(slots):
+        eng = PagedLLMEngine(cfg=cfg, params=params, max_batch=slots,
+                             max_len=128, page_size=PAGE, num_pages=12,
+                             prefix_cache=False, decode_chunk=4)
+        reqs = [eng.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, budgets)]
+        eng.start()
+        out = [list(r.tokens()) for r in reqs]
+        stats = eng.stats()
+        eng.stop()
+        assert eng.error is None
+        return out, stats["slots_handed_over"]
+
+    one_slot, handed = serve(1)
+    assert handed == 1 and [len(o) for o in one_slot] == budgets
+    # a slot each: nothing is handed over, nothing shared
+    assert (one_slot, 0) == serve(2)
+
+
 def test_int8_pages_are_refused_over_a_plan_of_rows(tiny):
     cfg, params = tiny
     with pytest.raises(ValueError, match="int8"):

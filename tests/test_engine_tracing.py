@@ -29,6 +29,8 @@ DECODE_ACCOUNT = ("decode_slot_steps", "decode_delivered",
                   "decode_overrun_tail", "decode_overrun_ahead",
                   "decode_vacant")
 PREFILL_ACCOUNT = ("prefill_token_rows", "prefill_new_tokens")
+# and of the ends it foresaw and the slots handed on ahead of a read-back
+HANDOVER_ACCOUNT = ("retirements_foreseen", "slots_handed_over")
 
 
 @pytest.fixture(scope="module")
@@ -66,7 +68,13 @@ def wait_idle(eng, timeout=60.0):
 
 def account(eng) -> dict:
     stats = eng.stats()
-    return {k: stats[k] for k in DECODE_ACCOUNT + PREFILL_ACCOUNT}
+    return {k: stats[k] for k in (DECODE_ACCOUNT + PREFILL_ACCOUNT
+                                  + HANDOVER_ACCOUNT)}
+
+
+def handed_over(spans) -> int:
+    return sum(s["attrs"]["handed_over"] for s in spans
+               if s["name"] == "engine.admit")
 
 
 def chunk_emits(spans) -> list:
@@ -117,7 +125,8 @@ def profiled(tiny, tmp_path_factory):
     spans = tracing.recorded_spans()
     clear_ring()
     rose = {k: stats1[k] - stats0[k] for k in (
-        "hit_pages", "miss_pages") + DECODE_ACCOUNT + PREFILL_ACCOUNT}
+        "hit_pages", "miss_pages") + DECODE_ACCOUNT + PREFILL_ACCOUNT
+        + HANDOVER_ACCOUNT}
     return spans, reqs, rose
 
 
@@ -247,9 +256,10 @@ def test_every_chunks_slot_steps_are_accounted_for(profiled):
         d = decodes[a["seq"]]
         assert (a["chunk"], a["drain"]) == (d["chunk"], d["drain"])
         assert a["vacant"] == (d["slots"] - d["live"]) * d["chunk"]
-    # both kinds of overrun occur in two waves of 20-token answers
+    # two waves of 20-token answers end inside their chunks, and the
+    # loop foresaw every end: no chunk was dispatched behind one
     assert sum(a["overrun_tail"] for a in emits) > 0
-    assert sum(a["overrun_ahead"] for a in emits) > 0
+    assert sum(a["overrun_ahead"] for a in emits) == 0
     assert (rose["decode_delivered"] + rose["decode_overrun_tail"]
             + rose["decode_overrun_ahead"] + rose["decode_vacant"]
             ) == rose["decode_slot_steps"] > 0
@@ -274,15 +284,21 @@ def test_dispatch_accounts_on_the_spans_equal_stats(profiled):
         rose["prefill_new_tokens"]
     # every token but a request's first comes out of a decode chunk
     assert rose["decode_delivered"] == sum(r.generated - 1 for r in reqs)
+    # every answer ended on its budget, so every end was foreseen; and no
+    # slot was handed over: never more than three of the four were held
+    assert rose["retirements_foreseen"] == len(reqs)
+    assert rose["slots_handed_over"] == handed_over(spans) == 0
 
 
-def _served(tiny, submit, **engine):
+def _served(tiny, submit, trace=True, **engine):
     """Requests handed over BEFORE the loop starts (so what it admits
-    when does not hang on the clock), served to their ends with tracing
-    on: (the chunks' emit spans in order, the engine's account)."""
+    when does not hang on the clock), served to their ends, with tracing
+    on unless ``trace`` is false: (the chunks' emit spans in order, the
+    engine's account, the hand-overs its ``engine.admit`` spans show)."""
     eng = make_engine(tiny, **engine)
     clear_ring()
-    tracing.enable_tracing()
+    if trace:
+        tracing.enable_tracing()
     try:
         reqs = submit(eng)
         eng.start()
@@ -290,11 +306,11 @@ def _served(tiny, submit, **engine):
             assert len(list(r.tokens())) == n
         wait_idle(eng)
         eng.stop()
-        emits = chunk_emits(tracing.recorded_spans("engine.emit"))
+        spans = tracing.recorded_spans("engine.")
     finally:
         tracing.disable_tracing()
         clear_ring()
-    return emits, account(eng)
+    return chunk_emits(spans), account(eng), handed_over(spans)
 
 
 @pytest.mark.parametrize("chunk,tokens,tail", [
@@ -302,48 +318,132 @@ def _served(tiny, submit, **engine):
     (4, 9, 0),      # ends with the last step of its second chunk
     (16, 20, 13),   # the default chunk: 16, then 3 of 16
     (1, 3, 0)], ids=["mid-chunk", "chunk-end", "default-chunk", "chunk-1"])
-def test_an_answers_end_costs_its_chunks_tail_and_the_chunk_in_flight(
+def test_a_foreseen_end_costs_its_chunks_tail_and_no_chunk_behind_it(
         tiny, chunk, tokens, tail):
     """One request alone in an engine of two slots: its answer's tokens
     after the first come out of whole chunks; the steps after its end in
-    its last chunk are ``overrun_tail``; the chunk dispatched before
-    that one was read is ``overrun_ahead``, all of it; the other slot is
+    its last chunk are ``overrun_tail``; the loop knows at that chunk's
+    dispatch that the answer ends inside it, so NO chunk is dispatched
+    behind it and nothing is ``overrun_ahead``; the other slot is
     ``vacant`` throughout."""
     def submit(eng):
         return [(eng.submit(np.arange(1, 20), max_new_tokens=tokens),
                  tokens)]
 
-    emits, total = _served(tiny, submit, decode_chunk=chunk, max_batch=2)
+    emits, total, handed = _served(tiny, submit, decode_chunk=chunk,
+                                   max_batch=2)
     live = -(-(tokens - 1) // chunk)         # chunks that deliver
-    want = [(chunk, 0, 0)] * (live - 1) + [(chunk - tail, tail, 0),
-                                           (0, 0, chunk)]
+    want = [(chunk, 0, 0)] * (live - 1) + [(chunk - tail, tail, 0)]
     assert [(a["tokens"], a["overrun_tail"], a["overrun_ahead"])
             for a in emits] == want
     assert all(a["vacant"] == chunk and a["slot_steps"] == 2 * chunk
                and a["chunk"] == chunk for a in emits)
     assert [total[k] for k in DECODE_ACCOUNT] == [
-        2 * chunk * (live + 1), tokens - 1, tail, chunk,
-        chunk * (live + 1)]
+        2 * chunk * live, tokens - 1, tail, 0, chunk * live]
+    # foreseen, and nobody waited for the slot
+    assert [total[k] for k in HANDOVER_ACCOUNT] == [1, 0] and handed == 0
 
 
-def test_a_refilled_slots_chunk_in_flight_is_overrun_ahead(tiny):
+def test_a_one_token_request_is_live_in_the_chunk_behind_its_prefill(tiny):
+    """Ends are foreseen where decode is dispatched: a request whose first
+    token is its last is live in the one chunk that follows its prefill
+    (all of it ``overrun_ahead``, as before) and released at that
+    dispatch. The benchmark's set-up leans on it: its one-token requests
+    are how the decode programs of every kind are met before a window."""
+    def submit(eng):
+        return [(eng.submit(np.arange(1, 20), max_new_tokens=1), 1)]
+
+    emits, total, handed = _served(tiny, submit, decode_chunk=4, max_batch=2)
+    assert [(a["tokens"], a["overrun_tail"], a["overrun_ahead"], a["vacant"])
+            for a in emits] == [(0, 0, 4, 4)]
+    assert [total[k] for k in HANDOVER_ACCOUNT] == [1, 0] and handed == 0
+
+
+def test_a_handed_over_slot_loses_no_chunk_in_flight(tiny):
     """One slot, two requests: A (7 tokens) ends two steps into its
-    second chunk; the chunk in flight then belongs to A, is read after B
-    took the slot (its generation changed) and is ``overrun_ahead``
-    whole; B (5 tokens) ends with its chunk's last step, and the chunk
-    in flight behind it finds the slot empty at step 0."""
+    second chunk, and the loop knows so where it dispatches that chunk:
+    B takes the slot at the top of the next pass, its prefill behind A's
+    last chunk on the device stream, and the chunk after that one is
+    B's; A's last two tokens still reach A when its chunk is read, after
+    B holds the slot. B (5 tokens) ends with its chunk's last step and no
+    chunk follows it."""
     def submit(eng):
         return [(eng.submit(np.arange(1, 20), max_new_tokens=7), 7),
                 (eng.submit(np.arange(30, 45), max_new_tokens=5), 5)]
 
-    emits, total = _served(tiny, submit, decode_chunk=4, max_batch=1)
+    emits, total, handed = _served(tiny, submit, decode_chunk=4,
+                                   max_batch=1)
     assert [(a["tokens"], a["overrun_tail"], a["overrun_ahead"], a["vacant"])
-            for a in emits] == [(4, 0, 0, 0), (2, 2, 0, 0), (0, 0, 4, 0),
-                                (4, 0, 0, 0), (0, 0, 4, 0)]
-    assert [total[k] for k in DECODE_ACCOUNT] == [20, 10, 2, 8, 0]
-    # the seqs are the stream's: two prefills lie among the five chunks
-    seqs = [a["seq"] for a in emits]
-    assert seqs == sorted(seqs) and seqs[-1] == 6
+            for a in emits] == [(4, 0, 0, 0), (2, 2, 0, 0), (4, 0, 0, 0)]
+    assert [total[k] for k in DECODE_ACCOUNT] == [12, 10, 2, 0, 0]
+    # the seqs are the stream's: B's prefill lies between A's last chunk
+    # and its own first
+    assert [a["seq"] for a in emits] == [1, 2, 4]
+    # both ends foreseen; B took A's slot ahead of A's read-back
+    assert [total[k] for k in HANDOVER_ACCOUNT] == [2, 1] and handed == 1
+
+
+@pytest.fixture(scope="module")
+def greedy7(tiny):
+    """The tiny model's first seven greedy tokens after ``arange(1, 20)``."""
+    eng = make_engine(tiny, max_batch=2)
+    eng.start()
+    try:
+        return list(eng.submit(np.arange(1, 20), max_new_tokens=7).tokens())
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("early", [True, False], ids=["early", "at-bound"])
+def test_an_end_on_eos_pays_the_chunk_in_flight_and_one_on_the_bound_none(
+        tiny, greedy7, early):
+    """An ``eos_id`` may end an answer sooner than its budget, which the
+    host cannot foresee: the end is seen where its chunk is read, and the
+    chunk dispatched before that is ``overrun_ahead``, all of it. The
+    same request run to its budget (its ``eos_id`` never sampled) is
+    foreseen like any other."""
+    assert greedy7[6] not in greedy7[:6]
+    # seven tokens either way: the first, a chunk of 4, 2 of the next 4
+    eos, budget = (greedy7[6], 40) if early else (max(greedy7) + 1, 7)
+
+    def submit(eng):
+        return [(eng.submit(np.arange(1, 20), max_new_tokens=budget,
+                            eos_id=eos), 7)]
+
+    emits, total, handed = _served(tiny, submit, decode_chunk=4, max_batch=2)
+    ahead = 4 if early else 0
+    want = [(4, 0, 0), (2, 2, 0)] + [(0, 0, 4)] * early
+    assert [(a["tokens"], a["overrun_tail"], a["overrun_ahead"])
+            for a in emits] == want
+    assert total["decode_overrun_ahead"] == ahead
+    assert [total[k] for k in HANDOVER_ACCOUNT] == [int(not early), 0]
+    assert handed == 0
+
+
+def test_the_hand_over_counts_equal_the_spans_and_the_identity_holds(tiny):
+    """Two slots and six waiting requests of different lengths: each
+    freed slot has a taker, so every hand-over the ``engine.admit`` spans
+    show is one ``stats()`` counted, every chunk's slot-steps sum, and
+    with tracing off the integers come out the same."""
+    budgets = (7, 5, 12, 3, 9, 6)
+
+    def submit(eng):
+        return [(eng.submit(np.arange(1 + i, 20 + 2 * i), max_new_tokens=n),
+                 n) for i, n in enumerate(budgets)]
+
+    emits, total, handed = _served(tiny, submit, decode_chunk=4, max_batch=2)
+    for a in emits:
+        assert (a["tokens"] + a["overrun_tail"] + a["overrun_ahead"]
+                + a["vacant"]) == a["slot_steps"] == 8
+    assert total["retirements_foreseen"] == len(budgets)
+    assert total["slots_handed_over"] == handed >= 3
+    assert total["decode_overrun_ahead"] == 0
+    assert total["decode_delivered"] == sum(budgets) - len(budgets)
+    assert (total["decode_delivered"] + total["decode_overrun_tail"]
+            + total["decode_vacant"]) == total["decode_slot_steps"]
+    none, untraced, _ = _served(tiny, submit, trace=False, decode_chunk=4,
+                                max_batch=2)
+    assert none == [] and untraced == total
 
 
 def test_prefill_rows_are_group_times_bucket_and_new_tokens_the_suffixes(
@@ -372,7 +472,7 @@ def test_prefill_rows_are_group_times_bucket_and_new_tokens_the_suffixes(
         return [(eng.submit(rng.integers(1, 500, n), max_new_tokens=2), 2)
                 for n in (20, 30)]
 
-    _, total = _served(tiny, submit)
+    _, total, _ = _served(tiny, submit)
     assert (total["prefill_token_rows"], total["prefill_new_tokens"]) == (
         64, 50)
 
@@ -753,7 +853,7 @@ def test_ring_stays_empty_with_no_session_and_tracing_off(tiny):
 
 
 def test_the_accounts_count_with_no_session_and_tracing_off(tiny):
-    """The seven integers are the operator's: they count whether or not
+    """The account's integers are the operator's: they count whether or not
     a span is recorded, and the ring stays empty."""
     clear_ring()
     assert not tracing.recording()
@@ -765,9 +865,10 @@ def test_the_accounts_count_with_no_session_and_tracing_off(tiny):
     eng.stop()
     assert tracing.recorded_spans() == []
     assert account(eng) == dict(
-        decode_slot_steps=48, decode_delivered=6, decode_overrun_tail=2,
-        decode_overrun_ahead=4, decode_vacant=36,
-        prefill_token_rows=32, prefill_new_tokens=29)
+        decode_slot_steps=32, decode_delivered=6, decode_overrun_tail=2,
+        decode_overrun_ahead=0, decode_vacant=24,
+        prefill_token_rows=32, prefill_new_tokens=29,
+        retirements_foreseen=1, slots_handed_over=0)
 
 
 def test_phase_follows_enable_tracing_and_costs_nothing_off():

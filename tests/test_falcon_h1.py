@@ -343,7 +343,15 @@ def prompts(tiny):
     return [rng.integers(1, cfg.vocab_size, n) for n in (9, 30, 1, 17)]
 
 
-def test_a_refilled_slot_starts_from_its_new_tenants_state(tiny, prompts):
+@pytest.fixture(scope="module")
+def alone(tiny, prompts):
+    """Each prompt's first 16 greedy tokens by the model's plain forward
+    pass, once for the tests below (a shorter budget is a prefix)."""
+    return [_alone(*tiny, prompt, 16) for prompt in prompts]
+
+
+def test_a_refilled_slot_starts_from_its_new_tenants_state(tiny, prompts,
+                                                           alone):
     """One slot, four requests one after another, and between two of them
     the slot's state POISONED (every entry NaN, as the worst a tenant
     that decoded on past its end could leave): each tenant gets the
@@ -356,7 +364,7 @@ def test_a_refilled_slot_starts_from_its_new_tenants_state(tiny, prompts):
     try:
         for i, prompt in enumerate(prompts):
             got = serving.collect(eng, eng.submit(prompt, max_new_tokens=10))
-            assert got == _alone(cfg, params, prompt, 10), i
+            assert got == alone[i][:10], i
             if i == 1:
                 deadline = 200
                 while eng.stats()["active_slots"] and deadline:
@@ -372,7 +380,8 @@ def test_a_refilled_slot_starts_from_its_new_tenants_state(tiny, prompts):
     assert stats["state_bytes_held"] == cfg.n_layers * per_slot
 
 
-def test_interleaved_requests_each_get_their_own_tokens(tiny, prompts):
+def test_interleaved_requests_each_get_their_own_tokens(tiny, prompts,
+                                                        alone):
     """Two slots, four requests of different lengths and budgets handed
     over at once: slots retire and refill while their neighbours decode,
     and every request gets the tokens it gets alone."""
@@ -387,9 +396,33 @@ def test_interleaved_requests_each_get_their_own_tokens(tiny, prompts):
         got = [serving.collect(eng, r) for r in reqs]
     finally:
         eng.stop()
-    for prompt, n, tokens in zip(prompts, budgets, got):
-        assert tokens == _alone(cfg, params, prompt, n)
+    for want, n, tokens in zip(alone, budgets, got):
+        assert tokens == want[:n]
     assert eng.stats()["state_installs"] == 4
+
+
+def test_a_handed_over_slots_state_is_its_new_tenants(tiny, prompts, alone):
+    """One slot, two requests waiting before the loop starts: A's end is
+    foreseen where its last chunk is dispatched, and B's prefill goes out
+    behind that chunk, before A's last tokens are read. On the device
+    stream B's state is installed after A's last step wrote the slot's,
+    so each gets the tokens it gets alone, A its last ones too."""
+    cfg, params = tiny
+    eng = PagedLLMEngine(cfg, params, max_batch=1, max_len=64, page_size=8,
+                         num_pages=16, decode_chunk=4)
+    budgets = [7, 5]
+    reqs = [eng.submit(p, max_new_tokens=n)
+            for p, n in zip(prompts, budgets)]
+    eng.start()
+    try:
+        got = [serving.collect(eng, r) for r in reqs]
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    for want, n, tokens in zip(alone, budgets, got):
+        assert tokens == want[:n]
+    assert (stats["slots_handed_over"], stats["state_installs"]) == (1, 2)
+    assert stats["decode_overrun_ahead"] == 0
 
 
 def test_a_prefix_hit_is_impossible_by_rule(tiny):

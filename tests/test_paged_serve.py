@@ -367,3 +367,66 @@ def test_paged_matches_dense_through_slot_refill(tiny, monkeypatch,
     assert [len(o) for o in got] == budgets
     assert got == want
     assert (st["prefix_cache"]["hit_pages"] >= 2) == prefix_cache
+
+
+def _pages_all_back(eng, timeout=30.0) -> bool:
+    """Once the loop idles every page is free or idle in the prefix cache
+    (``_wait_idle`` drains the deferred frees)."""
+    import time
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if (len(eng._alloc.free) + eng._prefix.evictable()
+                == eng.num_pages):
+            return True
+        time.sleep(0.01)
+    return False
+
+
+def test_a_successor_the_pool_cannot_place_waits_and_pages_come_back(tiny):
+    """Two slots and a pool of five pages: A (2 pages) and B (3) fill it.
+    A's end is foreseen and its slot released with A's last chunk in
+    flight, but C needs 3 pages and A's 2 are all there are: C is put
+    back (no deadlock, nothing corrupted), placed once B's pages return,
+    and every stream is its budget of the plain decode's tokens."""
+    cfg, params = tiny
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, n) for n in (20, 20, 40)]
+    budgets = [8, 40, 20]      # ceil((p + n) / 32) + 1 = 2, 3, 3 pages
+    eng = PagedLLMEngine(cfg=cfg, params=params, max_batch=2, max_len=128,
+                         page_size=32, num_pages=5, decode_chunk=4)
+    refused, reserve = [], eng._reserve_pages
+    eng._reserve_pages = lambda req, slot: (
+        reserve(req, slot) or bool(refused.append(req.request_id)))
+    reqs, got = _run(eng, prompts, budgets)
+    assert _pages_all_back(eng)
+    st = eng.stats()
+    eng.stop()
+    assert refused and set(refused) == {reqs[2].request_id}
+    assert [len(o) for o in got] == budgets
+    assert got == _plain_greedy(cfg, params, prompts, budgets)
+    assert st["retirements_foreseen"] == 3
+
+
+def test_a_handed_over_slots_successor_shares_its_predecessors_prefix(tiny):
+    """One slot: A's end is foreseen, its shared prefix pages are
+    released with its last chunk still to run, and B, whose prompt
+    starts with the same two pages, takes slot and pages at the next
+    pass: B's suffix prefill lies behind A's last chunk on the device
+    stream, both read the shared pages, and both streams are the plain
+    decode's."""
+    cfg, params = tiny
+    rng = np.random.default_rng(13)
+    base = rng.integers(1, cfg.vocab_size, 64)     # 2 full pages @ ps=32
+    prompts = [np.concatenate([base, rng.integers(1, cfg.vocab_size, n)])
+               for n in (9, 30)]
+    budgets = [7, 5]
+    eng = PagedLLMEngine(cfg=cfg, params=params, max_batch=1, max_len=128,
+                         page_size=32, num_pages=8, decode_chunk=4)
+    _, got = _run(eng, prompts, budgets)
+    assert _pages_all_back(eng)
+    st = eng.stats()
+    eng.stop()
+    assert got == _plain_greedy(cfg, params, prompts, budgets)
+    assert st["prefix_cache"]["hit_pages"] == 2
+    assert (st["retirements_foreseen"], st["slots_handed_over"]) == (2, 1)
+    assert st["decode_overrun_ahead"] == 0
