@@ -38,13 +38,11 @@ if [ "${1:-}" = "--nightly" ]; then
   # seeded process-kill + partition schedule over a mixed workload
   # (tasks, actors, serve) with conservation invariants: every
   # submitted call resolves or raises typed, nothing wedges, planes
-  # stay intact. The gate fences violations==0, the per-class MTTR
-  # means, and the <1% health-probe overhead guard (ISSUE-16).
-  JAX_PLATFORMS=cpu CHAOS_SOAK_SEEDS=0,1,2 CHAOS_SOAK_DURATION=300 \
-    CHAOS_SOAK_OUT=/tmp/chaos_nightly.json \
-    BENCH_MODE=chaos_soak python bench.py > /tmp/bench_chaos_ci.json
-  python ci/perf_gate.py /tmp/bench_chaos_ci.json \
-    "$(ls BENCH_r*.json 2>/dev/null | sort -V | tail -1 || echo /tmp/bench_chaos_ci.json)"
+  # stay intact. The script exits non-zero on any violation; the
+  # report carries the per-class MTTR means and the health-probe
+  # overhead.
+  JAX_PLATFORMS=cpu python scripts/run_chaos_soak.py --seeds 0,1,2 \
+    --duration 300 --out /tmp/chaos_nightly.json
   stage "nightly log plane (rotation holds disk bounded under worker churn at scale)"
   # a flood of printing workers must keep the node's log dir under the
   # rotation budget (max_bytes * (rotate_count+1) per proc) while every
@@ -59,20 +57,18 @@ if [ "${1:-}" = "--nightly" ]; then
   # exactly the one deliberately-held ref with its creation call site
   JAX_PLATFORMS=cpu python -m pytest tests/test_memory_leak_nightly.py \
     -m nightly -q -s
-  stage "nightly train telemetry leg (step decomposition + goodput + overhead fence)"
-  # telemetry-ON train leg: asserts decomposition sums to step wall and
-  # stamping overhead < 1% of steady step wall; the gate re-checks the
-  # ceiling against the emitted doc
-  JAX_PLATFORMS=cpu BENCH_MODE=train_telemetry python bench.py \
-    > /tmp/bench_train_telemetry_ci.json
-  python ci/perf_gate.py /tmp/bench_train_telemetry_ci.json \
-    "$(ls BENCH_r*.json 2>/dev/null | sort -V | tail -1 || echo /tmp/bench_train_telemetry_ci.json)"
+  stage "nightly train telemetry overhead (stamping < 1% of a steady step)"
+  # the decomposition summing to the step wall and goodput through a
+  # real fit are in the default tier (tests/test_observability_train.py);
+  # the overhead fence is a timing, so it runs here
+  JAX_PLATFORMS=cpu python -m pytest tests/test_observability_train.py \
+    -m nightly -q -s
   echo "nightly tiers: green"
   exit 0
 fi
 
 stage "lint (syntax + bytecode compile of every source)"
-python -m compileall -q ray_tpu tests bench.py __graft_entry__.py
+python -m compileall -q ray_tpu tests __graft_entry__.py chip_smoke.py
 
 stage "native build (shm store, collectives, scheduler, capi, crc)"
 make -C src -j"$(nproc)" all
@@ -95,17 +91,6 @@ SKIP_1B=1 JAX_PLATFORMS=cpu \
 if [ "${SKIP_1B:-0}" != "1" ]; then
   stage "flagship-size dryrun (1.0B params, fsdp over 8 virtual devices; minutes)"
   python -c "import __graft_entry__ as g; g.dryrun_multichip_1b(8)"
-fi
-
-if [ "${SKIP_PERF_GATE:-0}" != "1" ]; then
-  stage "perf gate (current tree's core bench vs last round, ±10% fence)"
-  LAST_BENCH=$(ls BENCH_r*.json 2>/dev/null | sort -V | tail -1 || true)
-  if [ -n "$LAST_BENCH" ]; then
-    BENCH_MODE=core BENCH_CORE_OPS=2000 python bench.py > /tmp/bench_core_ci.json
-    python ci/perf_gate.py /tmp/bench_core_ci.json "$LAST_BENCH"
-  else
-    echo "no recorded BENCH_r*.json; skipping gate"
-  fi
 fi
 
 stage "single-chip compile check of the flagship entry"

@@ -533,7 +533,7 @@ def run_soak(duration_s: float = 300.0, seed: int = 0,
             "violations": violations,
             "chaos_soak_invariant_violations": len(violations),
         })
-        # flat gate metrics (ci/perf_gate.py ceilings)
+        # the per-class recovery means, flat in the report
         rep = per_class.get("replica", {})
         ray_cls = per_class.get("raylet", {})
         if rep.get("replace_mttr_mean_s") is not None:
@@ -571,9 +571,8 @@ def measure_probe_overhead(pings: int = 200) -> dict:
     replica-side cost per probe is bounded above by the full ping RTT
     (handling is a subset of the round trip). Ratio = probe rate x
     min-of-k RTT = worst-case fraction of a replica's wall-clock spent
-    answering probes — ci/perf_gate.py fences it under 1%
-    (serve_probe_overhead_ratio), the ISSUE-16 guard that proactive
-    failover does not tax serving throughput."""
+    answering probes, to stay under 1%: proactive failover must not tax
+    serving throughput."""
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.cluster_utils import Cluster

@@ -43,8 +43,7 @@ _PKG_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__))) + os.sep
 # so the steady-state capture is two dict probes, no string building
 _internal_files: dict[str, bool] = {}
 _callsite_strings: dict[tuple, str] = {}
-# bound once: note_owned sits on the put/submit hot path, fenced by
-# memory_accounting_overhead_ratio in ci/perf_gate.py
+# bound once: note_owned sits on the put/submit hot path
 _time_time = time.time
 
 
@@ -53,8 +52,8 @@ def capture_callsite() -> str | None:
 
     Raw ``sys._getframe`` walk — no traceback/inspect object allocation
     — with memoized per-file classification and interned result
-    strings, because this sits on the owner-side put/submit path and is
-    fenced by ``memory_accounting_overhead_ratio`` in ci/perf_gate.py."""
+    strings, because this sits on the owner-side put/submit path (the
+    accounting is to stay under 3% of a put)."""
     try:
         f = sys._getframe(2)
     except ValueError:  # pragma: no cover - interpreter without frames

@@ -58,8 +58,8 @@ ANNEX_PREFIX = "train/progress/"
 
 # Peak dense bf16 TFLOP/s of one chip, keyed by jax's ``device_kind``
 # (source: Google Cloud TPU documentation, the page of each generation).
-# The one table: MFU here and in bench.py divides by it, and a TPU that
-# is not in it is an error, never a default.
+# MFU here divides by it, and a TPU that is not in it is an error, never
+# a default.
 PEAK_TFLOPS = {"TPU v4": 275.0, "TPU v5 lite": 197.0, "TPU v5e": 197.0,
                "TPU v5": 459.0, "TPU v5p": 459.0, "TPU v6 lite": 918.0,
                "TPU v6e": 918.0}

@@ -318,11 +318,9 @@ class Config:
     # window between decode-chunk dispatches, so a request arriving
     # mid-chunk prefills behind ONE in-flight chunk instead of waiting
     # out the whole double-buffered pipeline (~2.5 chunks of
-    # queue_wait measured in BENCH_r07).
-    serve_continuous_admission: bool = True
-    # Fraction of the EMA chunk period the admission window may wait
-    # before dispatching the next chunk (the remainder covers dispatch
-    # overhead so the device never idles between chunks).
+    # queue_wait). This is the fraction of the EMA chunk period the
+    # window may wait before dispatching the next chunk (the remainder
+    # covers dispatch overhead so the device never idles between chunks).
     serve_admission_window_frac: float = 0.75
     # Prefix-affinity routing: handles score replicas by the longest
     # cached prefix advertised in their pushed page-hash digests and
@@ -357,9 +355,6 @@ class Config:
     # Nightly fork-pool actor axis (tests/test_envelope_nightly.py):
     # actors created through the zygote fork path in one cluster.
     envelope_nightly_fork_actors: int = 10_000
-    # bench.py envelope probe sizes (bounded, driver-visible leg).
-    bench_envelope_tasks: int = 100_000
-    bench_envelope_actors: int = 500
 
     # --- observability ---
     metrics_report_interval_s: float = 2.0
@@ -386,8 +381,8 @@ class Config:
     # plane is strictly best-effort — a slow/partitioned GCS must never
     # block or backpressure a hot path).
     metrics_push_buffer: int = 8
-    # Sampling profiler riding BENCH_MODE=envelope's steady-call phase
-    # (satellite of ROADMAP #2): writes a collapsed-stack artifact.
+    # No reader: the envelope leg it sampled went with the pre-chip
+    # benchmark (ROADMAP Queue 3 item 6 takes it next).
     bench_profile_enabled: bool = False
 
     # --- distributed tracing plane (util/tracing.py; reference analog:

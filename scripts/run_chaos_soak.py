@@ -3,7 +3,7 @@
 workload, conservation invariants checked at the end.
 
     python scripts/run_chaos_soak.py --duration 300 --seeds 0,1,2 \
-        --out CHAOS_r10.json
+        --out /tmp/chaos.json
 
 Exit code 0 iff zero invariant violations across all seeds. See
 docs/crash_chaos.md for the crash-point catalog and the per-class MTTR
@@ -27,8 +27,8 @@ def main(argv=None) -> int:
                     help="skip metrics-plane partition faults")
     ap.add_argument("--inject-period", type=float, default=8.0,
                     help="mean seconds between injections")
-    ap.add_argument("--out", default="CHAOS_r10.json",
-                    help="report path ('' to skip writing)")
+    ap.add_argument("--out", default="",
+                    help="report path ('', the default: none is written)")
     args = ap.parse_args(argv)
 
     from ray_tpu.chaos_soak import run_soak_matrix

@@ -357,7 +357,7 @@ def test_engine_serves_past_the_selection_and_the_window_and_reuses_pages(
                              max_len=128, page_size=PAGE, num_pages=40)
         # a full run's rows and index keys over 2 layers, a sliding run's
         # rows over 3, each in whole lanes; no V twin
-        assert [a.shape for a in eng._pools] == [
+        assert [a.shape for a in eng._programs.pools] == [
             (2, 40, PAGE, 128), (2, 40, PAGE, 128), (3, 40, PAGE, 128)]
         eng.start()
         served = []

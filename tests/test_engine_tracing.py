@@ -486,18 +486,18 @@ def test_prefill_dispatches_say_whether_their_program_holds_the_kernel(
     Off a TPU every dispatch reads 0 (above). Here the engine is told it
     is on one, and a rule that the tiny shapes reach stands in for the
     256 MiB of scores: buckets of 64 tokens engage, shorter ones do not."""
-    from ray_tpu.serve import paged_llm
+    from ray_tpu.serve import engine_programs
 
     eng = make_engine(tiny)
-    assert not eng._kernel_backend                  # the CPU's
-    eng._kernel_backend = True
+    assert not eng._programs._kernel_backend        # the CPU's
+    eng._programs._kernel_backend = True
     seen = []
 
     def rule(q_shape, pools, table_width, window):
         seen.append((q_shape, pools.shape, table_width, window))
         return q_shape[1] >= 64
 
-    monkeypatch.setattr(paged_llm, "kernel_engages", rule)
+    monkeypatch.setattr(engine_programs, "kernel_engages", rule)
     clear_ring()
     tracing.enable_tracing()
     try:
@@ -520,7 +520,7 @@ def test_prefill_dispatches_say_whether_their_program_holds_the_kernel(
     # full-layer heads and head size; the pool; the window's pages
     cfg = tiny[0]
     assert seen[0] == ((1, 64, cfg.n_heads, cfg.head_dim),
-                       eng._k_pages.shape, 4, None)
+                       eng._programs.pools[0].shape, 4, None)
 
 
 _STATE_KERNEL_CASES = [
@@ -543,10 +543,11 @@ def test_decode_dispatches_say_whether_their_program_holds_the_state_kernel(
     lanes of float32 and 0 where it is not. A plan of pages alone
     carries no such count (the test below)."""
     from ray_tpu.models import falcon_h1
-    from ray_tpu.serve import paged_llm
+    from ray_tpu.serve import engine_programs
 
     cfg = falcon_h1.falcon_h1_tiny(ssm_state=state_size)
-    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(engine_programs.jax, "default_backend",
+                        lambda: backend)
     eng = PagedLLMEngine(cfg, falcon_h1.init_params(cfg, jax.random.key(0)),
                          max_batch=2, max_len=64, page_size=PAGE,
                          num_pages=12)
@@ -679,7 +680,7 @@ def test_stats_count_the_pools_own_bytes_and_what_a_token_keeps(family):
         rows = "latent:24,index_key:16;latent:40"
     assert stats["cache_bytes_per_token"] == want
     assert stats["kv_pages_bytes"] == want * tokens == sum(
-        a.size * a.dtype.itemsize for a in eng._pools
+        a.size * a.dtype.itemsize for a in eng._programs.pools
         if a.shape[1] == eng.num_pages)
     assert stats["kv_dense_equiv_bytes"] == dense * slots_len
     clear_ring()
@@ -748,10 +749,11 @@ def test_decode_dispatches_say_whether_their_program_holds_the_latent_kernel(
     times as many, 0 where it holds twenty times as many and 0 where
     nothing is selected."""
     from ray_tpu.models import dots3_note
-    from ray_tpu.serve import paged_llm
+    from ray_tpu.serve import engine_programs
 
     cfg = dots3_note.dots3_note_tiny(index_topk=topk)
-    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(engine_programs.jax, "default_backend",
+                        lambda: backend)
     eng = PagedLLMEngine(cfg, dots3_note.init_params(cfg, jax.random.key(0)),
                          max_batch=2, max_len=256, page_size=128,
                          num_pages=8)
@@ -771,13 +773,13 @@ def test_the_older_plans_carry_no_latent_kernel_count(monkeypatch, family):
     latent kernel on its decode dispatches and counts none, on an engine
     that finds a TPU backend too."""
     from ray_tpu.models import falcon_h1, laguna, olmoe
-    from ray_tpu.serve import paged_llm
+    from ray_tpu.serve import engine_programs
 
     model, cfg = {"llama": (llama, llama.llama_tiny),
                   "olmoe": (olmoe, olmoe.olmoe_tiny),
                   "laguna": (laguna, laguna.laguna_tiny),
                   "falcon_h1": (falcon_h1, falcon_h1.falcon_h1_tiny)}[family]
-    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(engine_programs.jax, "default_backend", lambda: "tpu")
     eng, _ = _pool_stats(model, cfg(), prefix_cache=False)
     monkeypatch.undo()
     decodes, stats = _decode_spans_of(eng, [np.arange(1, 40)], new_tokens=5)
@@ -803,13 +805,14 @@ def test_prefill_dispatches_say_whether_their_experts_run_in_the_kernel(
     for the CPU), read 0 on every dispatch."""
     from ray_tpu.models import nemotron_h, olmoe
     from ray_tpu.ops import moe
-    from ray_tpu.serve import paged_llm
+    from ray_tpu.serve import engine_programs
 
     model, cfg = {"llama": (llama, llama.llama_tiny),
                   "olmoe": (olmoe, olmoe.olmoe_tiny),
                   "nemotron_h": (nemotron_h, nemotron_h.nemotron_h_tiny),
                   }[family]
-    monkeypatch.setattr(paged_llm.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(engine_programs.jax, "default_backend",
+                        lambda: backend)
     eng, _ = _pool_stats(model, cfg(), prefix_cache=False)
     monkeypatch.undo()
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 32)
@@ -940,9 +943,10 @@ def test_failed_prefill_ends_the_requests_the_loop_had_taken(tiny):
 def test_engine_programs_carry_their_static_facts_in_their_names(tiny):
     cfg, params = tiny
     eng = make_engine(tiny)
-    decode = eng._decode_paged(8, 4)
-    prefill = eng._prefill_paged(2)
-    pools = (eng._k_pages, eng._v_pages, eng._k_scale, eng._v_scale)
+    programs = eng._programs
+    decode = programs._decode_paged(8, 4)
+    prefill = programs._prefill_paged(2)
+    pools = programs.pools
     i32 = partial(jnp.zeros, dtype=jnp.int32)
     text = decode.lower(
         params, *pools, i32((4, 4)), i32((4,)), i32((4,)),
@@ -955,7 +959,7 @@ def test_engine_programs_carry_their_static_facts_in_their_names(tiny):
         jnp.zeros((2,), jnp.float32), jax.random.key(0)).as_text()
     assert re.search(r"module @jit_paged_prefill_w\d+\b", text)
     assert "@jit_paged_prefill_w2" in text
-    assert "@jit_scatter_firsts" in eng._scatter_fn.lower(
+    assert "@jit_scatter_firsts" in programs.scatter_firsts.lower(
         i32((4,)), i32((2,)), i32((2,))).as_text()
 
 

@@ -3,9 +3,10 @@
 Reference analog: ``release/benchmarks/`` (the scalability envelope —
 many actors, deep task queues, many args/returns, large objects,
 broadcast) and ``release/benchmarks/README.md``'s single-node
-dimensions. Real envelope numbers live in ``bench.py`` / BENCH_r*.json;
-these tests pin down the same AXES at sizes that run in seconds, so a
-regression that breaks an axis (not just slows it) fails the suite.
+dimensions. The envelope at scale is ``scripts/run_envelope.py`` and the
+nightly tier; these tests pin down the same AXES at sizes that run in
+seconds, so a regression that breaks an axis (not just slows it) fails
+the suite.
 """
 
 import numpy as np

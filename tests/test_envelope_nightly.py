@@ -157,8 +157,8 @@ def test_trace_context_survives_steady_actor_phase(big_cluster):
     ENABLED and (a) a traced slice of the steady calls lands in the GCS
     TraceStore as ONE trace whose worker-side ``run:`` spans prove the
     context crossed real process boundaries at this scale, (b) the warm
-    actor-location resolve rate — the ``envelope_actor_resolves_per_sec``
-    axis ``ci/perf_gate.py`` fences — stays within 30% of the
+    actor-location resolve rate (``envelope_actor_resolves_per_sec``)
+    stays within 30% of the
     tracing-off rate measured seconds earlier in the same session. The
     bound is deliberately generous (nightly hosts are noisy); the tight
     <3% hot-path fence lives in tests/test_tracing_plane.py.

@@ -20,8 +20,9 @@ from benchmark.families import falcon_h1 as family
 from ray_tpu.models import falcon_h1, laguna
 from ray_tpu.models.llama import LayerStack
 from ray_tpu.ops import ssm
-from ray_tpu.serve import paged_llm
-from ray_tpu.serve.paged_llm import PagedLLMEngine, _model_module
+from ray_tpu.serve import engine_programs, paged_llm
+from ray_tpu.serve.engine_programs import _model_module
+from ray_tpu.serve.paged_llm import PagedLLMEngine
 from test_tpu_compile import _lower_engine_program
 
 # the tiny model under the published key names: query groups of 5, two
@@ -217,7 +218,7 @@ def _programs_logits(monkeypatch, cfg, params, prompt, new, *, page, slots=3,
                            ordered=True)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    monkeypatch.setattr(paged_llm, "select_tokens", spy)
+    monkeypatch.setattr(engine_programs, "select_tokens", spy)
     plen = len(prompt)
     max_pages = -(-(plen + new + chunk) // page) + 1
     pool = jnp.zeros((cfg.n_layers, slots * max_pages, page, cfg.n_kv_heads,
@@ -370,8 +371,8 @@ def test_a_refilled_slot_starts_from_its_new_tenants_state(tiny, prompts,
                 while eng.stats()["active_slots"] and deadline:
                     deadline -= 1
                 # the loop is idle: nothing is in flight to donate these
-                eng._state = tuple(jnp.full_like(a, jnp.nan)
-                                   for a in eng._state)
+                eng._programs.state = tuple(
+                    jnp.full_like(a, jnp.nan) for a in eng._programs.state)
         stats = eng.stats()
     finally:
         eng.stop()
@@ -433,7 +434,8 @@ def test_a_prefix_hit_is_impossible_by_rule(tiny):
     # left unset it follows the flag only for a plan of pages alone
     eng = PagedLLMEngine(cfg, params, max_batch=1, max_len=64, page_size=8)
     assert eng.stats()["prefix_cache"]["enabled"] is False
-    assert [a.shape for a in eng._state] == [(2, 1, 6, 8, 16), (2, 1, 3, 112)]
+    assert [a.shape for a in eng._programs.state] == [
+        (2, 1, 6, 8, 16), (2, 1, 3, 112)]
 
 
 def test_a_plan_without_a_recurrent_run_allocates_and_passes_nothing():
@@ -442,8 +444,14 @@ def test_a_plan_without_a_recurrent_run_allocates_and_passes_nothing():
     cfg = llama.llama_tiny()
     eng = PagedLLMEngine(cfg, llama.init_params(cfg, jax.random.key(0)),
                          max_batch=2, max_len=64, page_size=16)
-    assert eng._state == () and eng._donated() == (1, 2, 3, 4)
-    assert eng._state_args(None) == ()
+    programs = eng._programs
+    assert programs.state == ()
+    for order in (engine_programs._DECODE, engine_programs._PREFILL):
+        # the four pools follow the weights, and nothing follows the key
+        assert order.donated(len(programs.pools), 0) == (1, 2, 3, 4)
+        inputs = dict.fromkeys(order.inputs + order.beside_state)
+        assert len(order.arguments(None, programs.pools, inputs, ())) == (
+            1 + 4 + len(order.inputs))
     stats = eng.stats()
     assert stats["state_installs"] == 0 and stats["state_bytes_held"] == 0
     assert stats["prefix_cache"]["enabled"] is True     # the flag's default
@@ -458,7 +466,7 @@ def test_the_module_is_found_by_the_configs_class_and_checked(tiny):
         _model_module(types.SimpleNamespace())
     # a recurrent plan needs the mixer's two forms
     half = types.ModuleType("half_a_model")
-    for name in paged_llm._PIECES:
+    for name in engine_programs._PIECES:
         setattr(half, name, getattr(falcon_h1, name))
     half.Config = type("Config", (falcon_h1.FalconH1Config,),
                        {"__module__": "half_a_model"})

@@ -381,7 +381,7 @@ def test_engine_serves_laguna_past_the_window_and_over_reused_pages(tiny):
     try:
         eng = PagedLLMEngine(cfg=cfg, params=params, max_batch=2,
                              max_len=128, page_size=PAGE, num_pages=40)
-        assert eng._window == WINDOW
+        assert eng._programs.window == WINDOW
         eng.start()
         served = []
         for prompt in (first, second):

@@ -25,8 +25,9 @@ from benchmark.families import nemotron_h as family
 from ray_tpu.models import (dots3_note, falcon_h1, laguna, llama,
                             nemotron_h, olmoe)
 from ray_tpu.ops import moe, ssm
-from ray_tpu.serve import paged_llm
-from ray_tpu.serve.paged_llm import PagedLLMEngine, _model_module
+from ray_tpu.serve import engine_programs, paged_llm
+from ray_tpu.serve.engine_programs import _model_module
+from ray_tpu.serve.paged_llm import PagedLLMEngine
 from ray_tpu.util import tracing
 
 # the published keys at a tiny size: the pattern's first nine letters,
@@ -285,14 +286,15 @@ def test_pools_and_state_have_the_layers_that_keep_them(tiny):
     eng = PagedLLMEngine(cfg, params, max_batch=3, max_len=64, page_size=8,
                          num_pages=20)
     # one attention layer of nine keeps pages, four mixers keep state
-    assert [p.shape for p in eng._pools[:2]] == [(1, 20, 8, 2, 16)] * 2
-    assert [a.shape for a in eng._state] == [(4, 3, 8, 8, 16),
+    assert [p.shape for p in eng._programs.pools[:2]] == [
+        (1, 20, 8, 2, 16)] * 2
+    assert [a.shape for a in eng._programs.state] == [(4, 3, 8, 8, 16),
                                              (4, 3, 3, 192)]
     plan = nemotron_h.layer_plan(cfg)
-    assert paged_llm._places(plan) == [
+    assert engine_programs._places(plan) == [
         (None, 0), (None, None), (None, 1), (None, None), (None, 2),
         (0, None), (None, None), (None, 3), (None, None)]
-    runs = paged_llm._plan_runs(plan, params["blocks"])
+    runs = engine_programs._plan_runs(plan, params["blocks"])
     assert [int(idx[0]) for _, idx in runs] == [0, 0, 1, 0, 2, 0, 0, 3, 0]
     stats = eng.stats()
     assert stats["page_layers"] == "k+v=1" and stats["state_layers"] == 4
@@ -322,16 +324,16 @@ def test_the_older_plans_stores_are_what_they_were(model, make):
     plan = model.layer_plan(cfg)
     assert all(run.attends and run.feeds for run in plan)
     eng = _engine(model, cfg)
-    formats = paged_llm._pool_slices(plan)[0]
+    formats = engine_programs._pool_slices(plan)[0]
     for rows, where in formats.items():
         layers = sum(run.layers for run in plan if run.rows == rows)
-        assert all(p.shape[0] == layers for p in eng._pools[where])
-    assert sum(paged_llm._pool_layers(plan, rows)
+        assert all(p.shape[0] == layers for p in eng._programs.pools[where])
+    assert sum(engine_programs._pool_layers(plan, rows)
                for rows in formats) == cfg.n_layers
     recurrent = any(run.state is not None for run in plan)
-    assert [a.shape[0] for a in eng._state] == (
+    assert [a.shape[0] for a in eng._programs.state] == (
         [cfg.n_layers] * 2 if recurrent else [])
-    for run, (pool_at, state_at) in zip(plan, paged_llm._places(plan)):
+    for run, (pool_at, state_at) in zip(plan, engine_programs._places(plan)):
         assert pool_at is not None
         assert state_at == (pool_at if run.state is not None else None)
     assert eng.stats()["state_layers"] == (cfg.n_layers if recurrent else 0)
@@ -343,9 +345,9 @@ def test_a_module_is_asked_only_for_the_pieces_its_plan_uses(tiny):
 
     def module(name, pattern, leave_out):
         mod = types.ModuleType(name)
-        for piece in (paged_llm._PIECES + paged_llm._ATTENTION_PIECES
-                      + paged_llm._KV_PIECES + paged_llm._RECURRENT_PIECES
-                      + paged_llm._FEED_PIECES):
+        ep = engine_programs
+        for piece in (ep._PIECES + ep._ATTENTION_PIECES + ep._KV_PIECES
+                      + ep._RECURRENT_PIECES + ep._FEED_PIECES):
             if piece not in leave_out:
                 setattr(mod, piece, getattr(nemotron_h, piece))
         mod.Config = type("Config", (nemotron_h.NemotronHConfig,),
@@ -386,16 +388,17 @@ def _programs_logits(monkeypatch, cfg, params, prompt, new, *, page, slots=3,
                            ordered=True)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    monkeypatch.setattr(paged_llm, "select_tokens", spy)
+    monkeypatch.setattr(engine_programs, "select_tokens", spy)
     plan = nemotron_h.layer_plan(cfg)
     plen = len(prompt)
     max_pages = -(-(plen + new + chunk) // page) + 1
-    pool = jnp.zeros((paged_llm._pool_layers(plan, None), slots * max_pages,
-                      page, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    pool = jnp.zeros((engine_programs._pool_layers(plan, None),
+                      slots * max_pages, page, cfg.n_kv_heads, cfg.head_dim),
+                     jnp.bfloat16)
     scale = jnp.ones((pool.shape[0], 1, 1, 1), jnp.float32)
     # a predecessor's garbage in every slot: the prefill must overwrite it
-    state = [jnp.full((paged_llm._state_layers(plan), slots, *shape), 7.0,
-                      dtype)
+    state = [jnp.full((engine_programs._state_layers(plan), slots, *shape),
+                      7.0, dtype)
              for _, shape, dtype in nemotron_h.recurrent_state(cfg).arrays]
     table = np.full((slots, max_pages), -1, np.int32)
     table[slot] = np.arange(max_pages) + slot * max_pages

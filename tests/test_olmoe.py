@@ -325,14 +325,14 @@ def test_decode_chunks_carry_the_routing_counts(tiny, tmp_path):
 
 def test_the_engine_resolves_the_block_from_the_configs_class(tiny):
     from ray_tpu.models import llama, mixtral
-    from ray_tpu.serve import paged_llm
+    from ray_tpu.serve import engine_programs
 
-    assert paged_llm._model_module(tiny[0]) is olmoe
-    assert paged_llm._model_module(llama.llama_tiny()) is llama
+    assert engine_programs._model_module(tiny[0]) is olmoe
+    assert engine_programs._model_module(llama.llama_tiny()) is llama
     with pytest.raises(TypeError, match="MixtralConfig"):
-        paged_llm._model_module(mixtral.mixtral_tiny())
+        engine_programs._model_module(mixtral.mixtral_tiny())
     # the programs keep their names, whatever the block
     eng = PagedLLMEngine(tiny[0], tiny[1], max_batch=1, max_len=64,
                          page_size=PAGE, num_pages=8)
-    assert eng._decode_paged(4, 2).__name__ == "paged_decode_c4_w2"
-    assert eng._prefill_paged(2).__name__ == "paged_prefill_w2"
+    assert eng._programs._decode_paged(4, 2).__name__ == "paged_decode_c4_w2"
+    assert eng._programs._prefill_paged(2).__name__ == "paged_prefill_w2"

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from ray_tpu.models import llama, olmoe
 from ray_tpu.ops import paged_decode_attention as pda
 from ray_tpu.ops.paged_attention import quantize_kv
-from ray_tpu.serve import paged_llm
+from ray_tpu.serve import engine_programs
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 
 PAGE, BUCKET, HEAD_DIM, LAYERS, POOL = 16, 4, 32, 3, 24
@@ -127,7 +127,8 @@ def test_engine_decodes_the_same_tokens_through_the_kernel(monkeypatch, model,
     prompts = [rng.integers(1, cfg.vocab_size, n) for n in (5, 30, 41)]
 
     def served(attention):
-        monkeypatch.setattr(paged_llm, "paged_decode_attention", attention)
+        monkeypatch.setattr(engine_programs, "paged_decode_attention",
+                            attention)
         eng = PagedLLMEngine(cfg, params, max_batch=4, max_len=128,
                              page_size=PAGE, num_pages=40,
                              kv_dtype=kv_dtype)

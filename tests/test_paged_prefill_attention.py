@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from ray_tpu.models import llama
 from ray_tpu.ops import paged_prefill_attention as ppa
 from ray_tpu.ops.paged_attention import quantize_kv
-from ray_tpu.serve import paged_llm
+from ray_tpu.serve import engine_programs
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 
 PAGE, WP, T, HEAD_DIM, LAYERS, POOL = 16, 8, 64, 32, 3, 40
@@ -167,7 +167,8 @@ def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch):
         return ppa.paged_prefill_attention_kernel(*args, interpret=True)
 
     def served(attention):
-        monkeypatch.setattr(paged_llm, "paged_prefill_attention", attention)
+        monkeypatch.setattr(engine_programs, "paged_prefill_attention",
+                            attention)
         eng = PagedLLMEngine(cfg, params, max_batch=4, max_len=128,
                              page_size=PAGE, num_pages=40)
         eng.start()

@@ -234,25 +234,6 @@ def test_prefix_cache_exact_prompt_repeat(tiny):
     assert st["prefix_cache"]["hit_pages"] >= 1
 
 
-def test_warmup_prefix_compiles_suffix_variants(tiny):
-    """warmup_prefix pre-compiles the suffix-bucket programs so a
-    shared-prefix hit reuses a cached jit entry instead of compiling
-    inside its TTFT."""
-    cfg, params = tiny
-    eng = PagedLLMEngine(cfg=cfg, params=params, max_batch=2, max_len=256,
-                         page_size=32, num_pages=16)
-    eng.warmup_prefix(prefix_len=64, tail_len=20, max_n=2)
-    wp = eng._window_pages(64 + 32)    # tail bucket = 32
-    assert wp in eng._prefill_cache
-    rng = np.random.default_rng(6)
-    base = rng.integers(1, cfg.vocab_size, 64)
-    prompts = [base,
-               np.concatenate([base, rng.integers(1, cfg.vocab_size, 20)])]
-    _, outs = _run(eng, prompts, max_new=6)
-    eng.stop()
-    assert all(len(o) == 6 for o in outs)
-
-
 def test_kv_quantization_roundtrip_error():
     from ray_tpu.ops.paged_attention import dequantize_kv, quantize_kv
     x = jax.random.normal(jax.random.key(0), (4, 16, 2, 64),

@@ -82,7 +82,7 @@ def _compile_step(trainer, batch, seq):
 
 
 def test_one_chip_1b_step_compiles(v5e_2x2):
-    """bench.py's 1.0B config at its batch: fits one chip's HBM (the
+    """A 1.0B Llama-shaped config at 4 x 2048 tokens: fits one chip's HBM (the
     compiler raises when a program does not) and keeps its three kernel
     calls (the remat policy saves the flash residuals, so the backward
     does not run the forward kernel again)."""
@@ -914,7 +914,7 @@ def _lower_note_program(device, program, dims):
     (chunk, table pages); prefill (prompts, tokens, table pages)."""
     from ray_tpu.models import dots3_note
     from ray_tpu.ops.paged_attention import row_pool
-    from ray_tpu.serve.paged_llm import _pool_slices
+    from ray_tpu.serve.engine_programs import _pool_slices
 
     one_chip = SingleDeviceSharding(device)
 
@@ -1064,7 +1064,7 @@ def _lower_nano_program(device, program, dims):
     as the engine sizes them: K/V pools of one layer, state arrays of
     four."""
     from ray_tpu.models import nemotron_h
-    from ray_tpu.serve.paged_llm import _pool_layers, _state_layers
+    from ray_tpu.serve.engine_programs import _pool_layers, _state_layers
 
     one = SingleDeviceSharding(device)
 
