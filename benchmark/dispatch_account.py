@@ -50,15 +50,31 @@ def decode_overrun_share(spans):
                        for a in chunks) / live
 
 
+def _prefill_dispatches(spans) -> list:
+    return [s["attrs"] for s in spans or ()
+            if s["name"] == "engine.dispatch_prefill"
+            and {"group", "bucket", "new_tokens"} <= set(
+                s.get("attrs", {}))]
+
+
+def prefill_fill_by_rows(spans) -> dict:
+    """{token-rows of a dispatch: the share of them that is a prompt
+    token, over the slice's dispatches of that many rows}: what a reader
+    needs that knows a program's run by its rows alone."""
+    new, rows = {}, {}
+    for a in _prefill_dispatches(spans):
+        n = a.get("token_rows", a["group"] * a["bucket"])
+        new[n] = new.get(n, 0) + a["new_tokens"]
+        rows[n] = rows.get(n, 0) + n
+    return {n: new[n] / rows[n] for n in rows if rows[n] > 0}
+
+
 def prefill_fill_share(spans):
     """Of the token-rows the prefill programs computed (``group x
     bucket`` a dispatch: the span's ``token_rows``, which a program from
     before PR 38 leaves to be multiplied out), the share that is a prompt
     token."""
-    dispatches = [s["attrs"] for s in spans or ()
-                  if s["name"] == "engine.dispatch_prefill"
-                  and {"group", "bucket", "new_tokens"} <= set(
-                      s.get("attrs", {}))]
+    dispatches = _prefill_dispatches(spans)
     rows = sum(a.get("token_rows", a["group"] * a["bucket"])
                for a in dispatches)
     if len(dispatches) < inside.MIN_SAMPLES or rows <= 0:

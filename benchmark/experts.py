@@ -19,28 +19,35 @@ from benchmark import inside
 from benchmark.trace import CONTAINERS, opcode
 
 
-def expert_ffn_share(trace, is_expert_op):
-    """Device time of the operations ``is_expert_op`` accepts that ran
-    inside a run of a decode program, over those runs' time. A loop or a
-    branch is left out: its event spans its body's operations."""
-    if trace is None or not trace.devices:
-        return None
+def ops_inside(trace, programs, accept) -> tuple:
+    """([(operation's name, seconds)], runs, the runs' seconds): the
+    operations ``accept`` takes that began inside a run of a program whose
+    name ``programs`` (a compiled pattern) matches, on the first chip,
+    each cut at its run's end. A loop or a branch is left out: its event
+    spans its body's operations."""
     dev = trace.devices[0]
-    runs = sorted((s, e) for n, s, e in dev["modules"]
-                  if inside.DECODE.match(n))
-    total = sum(e - s for s, e in runs)
-    if len(runs) < inside.MIN_SAMPLES or total <= 0:
-        return None
-    seconds, i = 0.0, 0
+    runs = sorted((s, e) for n, s, e in dev["modules"] if programs.match(n))
+    found, i = [], 0
     for name, s, e in sorted(dev["ops"], key=lambda x: x[1]):
         while i < len(runs) and runs[i][1] <= s:
             i += 1
         if i == len(runs):
             break
         if (s >= runs[i][0] and opcode(name) not in CONTAINERS
-                and is_expert_op(name)):
-            seconds += min(e, runs[i][1]) - s
-    return 100.0 * seconds / total
+                and accept(name)):
+            found.append((name, min(e, runs[i][1]) - s))
+    return found, len(runs), sum(e - s for s, e in runs)
+
+
+def expert_ffn_share(trace, is_expert_op):
+    """Device time of the operations ``is_expert_op`` accepts that ran
+    inside a run of a decode program, over those runs' time."""
+    if trace is None or not trace.devices:
+        return None
+    found, runs, total = ops_inside(trace, inside.DECODE, is_expert_op)
+    if runs < inside.MIN_SAMPLES or total <= 0:
+        return None
+    return 100.0 * sum(s for _, s in found) / total
 
 
 def chunk_stat_mean(spans, stat: str):

@@ -412,6 +412,19 @@ def flash_train_cost(m: dict, batch: int, seq: int):
     return None
 
 
+def grouped_expert_cost(m: dict, n_out: int, pairs: float,
+                        here_share=None):
+    """What one call of the grouped expert kernel must do, at this
+    family's widths (gate and up): ``families.grouped_expert_call_cost``.
+    For ``grouped_expert_ffn_roofline``."""
+    from benchmark.families import grouped_expert_call_cost
+
+    return grouped_expert_call_cost(
+        hidden=m["hidden_size"], width=m["moe_intermediate_size"], held=m["num_experts"],
+        total=_share(m)[0], up_stacks=2, n_out=n_out, pairs=pairs,
+        here_share=here_share)
+
+
 def expert_ffn_op(m: dict):
     """A predicate on a device operation's HLO text: true for the ROUTED
     feed-forward's operations (router and held experts; the shared

@@ -164,12 +164,21 @@ def flash_train_cost(m: dict, batch: int, seq: int) -> dict:
     return {"flops": 3.5 * fwd, "bytes": fwd_bytes + bwd_bytes}
 
 
+def kv_bytes_per_token_layer(m: dict) -> int:
+    """Keys and values of one token in one layer, bf16."""
+    return 2 * 2 * m["num_key_value_heads"] * m["head_dim"]
+
+
+def attention_kv_bytes(m: dict, counters: dict) -> float:
+    """Bytes of keys and values one decode step must read: every live
+    token's (the counter ``live_kv_tokens_mean``) in every layer
+    (``paged_attn_roofline``)."""
+    return (kv_bytes_per_token_layer(m) * m["num_hidden_layers"]
+            * counters.get("live_kv_tokens_mean", 0.0))
+
+
 def decode_step_bytes(m: dict, counters: dict) -> float:
     """HBM bytes one decode step must move: every weight once (bf16; the
     embedding rows read are negligible) and the live keys and values
     (the counter ``live_kv_tokens_mean``) once."""
-    kv = m["num_key_value_heads"] * m["head_dim"]
-    weights = 2.0 * matmul_params(m)
-    cache = (2.0 * 2 * m["num_hidden_layers"] * kv
-             * counters.get("live_kv_tokens_mean", 0.0))
-    return weights + cache
+    return 2.0 * matmul_params(m) + attention_kv_bytes(m, counters)

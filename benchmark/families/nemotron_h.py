@@ -516,6 +516,19 @@ def ssm_op(m: dict):
     return {"state": is_state, "mixer": is_mixer}
 
 
+def grouped_expert_cost(m: dict, n_out: int, pairs: float,
+                        here_share=None):
+    """What one call of the grouped expert kernel must do, at this
+    family's widths (up alone: relu2 has no gate): ``families.grouped_expert_call_cost``.
+    For ``grouped_expert_ffn_roofline``."""
+    from benchmark.families import grouped_expert_call_cost
+
+    return grouped_expert_call_cost(
+        hidden=m["hidden_size"], width=m["moe_intermediate_size"], held=m["n_routed_experts"],
+        total=_share(m)[0], up_stacks=1, n_out=n_out, pairs=pairs,
+        here_share=here_share)
+
+
 def expert_ffn_op(m: dict):
     """A predicate on a device operation's HLO text: true for the ROUTED
     feed-forward's operations (router and held experts), told from the

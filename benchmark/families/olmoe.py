@@ -253,6 +253,14 @@ def flash_train_cost(m: dict, batch: int, seq: int) -> dict:
             "bytes": tok * (2 * q + 2 * kv) + tok * (4 * q + 4 * kv)}
 
 
+def attention_kv_bytes(m: dict, counters: dict) -> float:
+    """Bytes of keys and values one decode step must read: every live
+    token's (the counter ``live_kv_tokens_mean``) in every layer
+    (``paged_attn_roofline``)."""
+    return (kv_bytes_per_token_layer(m) * m["num_hidden_layers"]
+            * counters.get("live_kv_tokens_mean", 0.0))
+
+
 def experts_touched_share(m: dict, live_tokens: float) -> float:
     """The share of a layer's experts that ``live_tokens`` tokens reach
     when each picks ``num_experts_per_tok`` of ``num_experts`` uniformly:
@@ -276,9 +284,20 @@ def decode_step_bytes(m: dict, counters: dict) -> float:
                      + m["hidden_size"] * m["vocab_size"])
               + 4.0 * layers * router_params(m))
     experts = 2.0 * layers * m["num_experts"] * expert_params(m) * share
-    cache = (layers * kv_bytes_per_token_layer(m)
-             * counters.get("live_kv_tokens_mean", 0.0))
-    return always + experts + cache
+    return always + experts + attention_kv_bytes(m, counters)
+
+
+def grouped_expert_cost(m: dict, n_out: int, pairs: float,
+                        here_share=None):
+    """What one call of the grouped expert kernel must do, at this
+    family's widths (gate and up): ``families.grouped_expert_call_cost``.
+    For ``grouped_expert_ffn_roofline``."""
+    from benchmark.families import grouped_expert_call_cost
+
+    return grouped_expert_call_cost(
+        hidden=m["hidden_size"], width=m["intermediate_size"], held=m["num_experts"],
+        total=m["num_experts"], up_stacks=2, n_out=n_out, pairs=pairs,
+        here_share=here_share)
 
 
 def expert_ffn_op(m: dict):

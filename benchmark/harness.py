@@ -235,6 +235,7 @@ class Context:
         """Called by the runner at the instant the measured window starts."""
         self.setup_s = time.perf_counter() - self.t_start
         self.compiles.open()
+        say("window", opens_after_s=self.setup_s)
 
 
 def device_info(chips: int, rehearse: bool) -> dict:
@@ -321,7 +322,8 @@ def main(argv=None, t_start: float | None = None) -> int:
         if value is not None and math.isfinite(value):
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     say("counts", attempted=run.attempted, failed=run.failed,
-        correct=run.correct, **{k: v for k, v in run.notes.items()})
+        correct=run.correct, compiles_in_window=ctx.compiles.count,
+        **run.notes)
     if args.rehearse:
         # counts only: a CPU run never carries a time, a rate or a share
         counted = {k: v for k, v in metrics.items()

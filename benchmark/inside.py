@@ -144,15 +144,6 @@ def stamp_check(spans, trace) -> dict:
     return out
 
 
-def prefill_group_mean(spans):
-    """Requests per prefill dispatch."""
-    groups = [s["attrs"]["group"]
-              for s in _named(spans, "engine.dispatch_prefill")]
-    if len(groups) < MIN_SAMPLES:
-        return None
-    return sum(groups) / len(groups)
-
-
 def decode_active_share(spans):
     """Live slots per decode dispatch, over the slots there are."""
     shares = [s["attrs"]["live"] / s["attrs"]["slots"]

@@ -6,20 +6,20 @@ is the bytes of keys and values the step must read, which is the family's
 count (``attention_kv_bytes``: a full layer every live token's, a sliding
 layer its window's), over the chip's bandwidth. The time is that of the
 instructions named ``paged_decode_attn*`` inside the decode programs' runs
-(``paged_attn_kernel_share``'s own finding of them), a step: their share of
+(found as PR 30's ``paged_attn_kernel_share`` found them), a step: their share of
 those runs' time times the runs' time a step. The kernel fetches whole
 pages, and a window that straddles a page's edge one page more, so the
 share reads under 100% by that much at the least; a family that counts no
 such bytes gives None."""
 
 from benchmark import experts, flops, inside, systems
-from benchmark.trace import KERNEL_TARGET
+from benchmark.trace import is_kernel_call
 
 KERNEL = "paged_decode_attn"
 
 
 def is_kernel(op: str) -> bool:
-    return KERNEL_TARGET in op and op.lstrip("%").startswith(KERNEL)
+    return is_kernel_call(op, KERNEL)
 
 
 def read(run):

@@ -11,8 +11,7 @@ left out, its event spans its body's operations. None where the family has
 no such layer, the program no such operation, or the slice fewer than
 ``inside.MIN_SAMPLES`` prefill runs."""
 
-from benchmark import inside, systems
-from benchmark.trace import CONTAINERS, opcode
+from benchmark import experts, inside, systems
 
 
 def read(run):
@@ -20,20 +19,9 @@ def read(run):
     trace = run.trace
     if expert_op is None or trace is None or not trace.devices:
         return None
-    is_expert = expert_op(run.config)
-    dev = trace.devices[0]
-    runs = sorted((s, e) for n, s, e in dev["modules"]
-                  if inside.PREFILL.match(n))
-    total = sum(e - s for s, e in runs)
-    if len(runs) < inside.MIN_SAMPLES or total <= 0:
+    found, runs, total = experts.ops_inside(trace, inside.PREFILL,
+                                            expert_op(run.config))
+    if runs < inside.MIN_SAMPLES or total <= 0:
         return None
-    seconds, i = 0.0, 0
-    for name, s, e in sorted(dev["ops"], key=lambda x: x[1]):
-        while i < len(runs) and runs[i][1] <= s:
-            i += 1
-        if i == len(runs):
-            break
-        if (s >= runs[i][0] and opcode(name) not in CONTAINERS
-                and is_expert(name)):
-            seconds += min(e, runs[i][1]) - s
+    seconds = sum(s for _, s in found)
     return 100.0 * seconds / total if seconds else None

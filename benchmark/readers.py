@@ -40,13 +40,6 @@ def prefix_hit_share(run):
     return 100.0 * hit / lookups
 
 
-def batch_occupancy(run):
-    samples = run.counters.get("occupancy_samples")
-    if not samples:
-        return None
-    return 100.0 * sum(samples) / len(samples) / run.counters["max_batch"]
-
-
 def decode_roofline(run):
     """Bytes one step must move (the family's count: for a dense model
     the weights once and the live keys and values once) over the chip's

@@ -34,6 +34,9 @@ def run(ctx) -> RunRecord:
                               traffic["prefill_limits"]["max_group"])
     ctx.phases.mark("prefix cache fill")
 
+    serving.settle_collector()
+    ctx.phases.mark("collector settled")
+
     client = serving.Client(eng, config["vocab_size"])
     ramp = sched["ramp_s"]
     t_sched = time.perf_counter()
@@ -44,6 +47,7 @@ def run(ctx) -> RunRecord:
     measured = stats.measured(reqs, t0, t1)
     ctx.tracer.start_in(ramp + 0.5)
     nxt, opened, closed, c0, c1, late = 0, False, False, None, None, []
+    watch = serving.StallWatch()
     while True:
         now = time.perf_counter()
         if not opened and now >= t0:
@@ -66,9 +70,12 @@ def run(ctx) -> RunRecord:
         wake = serving.POLL_S
         if nxt < len(reqs):
             wake = min(wake, max(0.0, reqs[nxt].due - time.perf_counter()))
+        slept = watch.took(now, 0.0, "work")
         with span("sleep"):
             time.sleep(wake)
+        watch.took(slept, wake, "sleep")
     drain_s = time.perf_counter() - t1
+    watch.report(t0, t1)
     error = serving.stop_engine(eng)
     for r in measured:
         r.failed = r.failed or not r.done    # unfinished at the cap
@@ -105,6 +112,8 @@ def run(ctx) -> RunRecord:
         tokens_per_s=rec.counters["tokens_per_s"],
         ttft_p90_ms=rec.end_to_end.get("ttft_p90_ms"),
         tpot_p90_ms=rec.end_to_end.get("tpot_p90_ms"),
+        queue_wait_p50_ms=(stats.percentile(queue_wait, 50) * 1e3
+                           if queue_wait else None),
         queue_wait_p99_ms=(stats.percentile(queue_wait, 99) * 1e3
                            if queue_wait else None),
         queue_wait_max_ms=max(queue_wait) * 1e3 if queue_wait else None,

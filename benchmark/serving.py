@@ -7,7 +7,9 @@ from __future__ import annotations
 
 import gc
 import hashlib
+import json
 import queue
+import threading
 import time
 import weakref
 
@@ -305,6 +307,64 @@ class Client:
 
     def valid_tokens(self, reqs) -> bool:
         return all(0 <= t < self.vocab for r in reqs for t in r.tokens)
+
+
+def settle_collector():
+    """What a serving front end does once it has warmed up: collect what
+    the set-up left behind, then move everything that is still alive out
+    of the collector's sight (``gc.freeze``), so that no full collection
+    inside the window walks the set-up's objects (the traced programs, the
+    schedule, the engine's tables) while every other thread waits for the
+    lock: one took 0.11 s on the engine's loop (PERF.md, PR 49). Only
+    once the engine the window uses is the only one: a discarded engine's
+    cycles have to be collected for its page pool to go."""
+    gc.collect()
+    gc.freeze()
+
+
+class StallWatch:
+    """What the run's own process can say of a stall in its window, on
+    the clock ``benchmark/tools/stall_sampler.py`` samples on: where the
+    runner's loop took ``LEAST_S`` longer over its work or its sleep than
+    it meant to, and every collection of the cyclic collector that took
+    ``GC_LEAST_S`` or more, with its generation and the thread it ran on.
+    Printed as one ``bench stalls`` line; no metric reads it."""
+
+    LEAST_S, GC_LEAST_S = 0.1, 0.01
+
+    def __init__(self):
+        self.holdups: list = []         # [time, seconds, "work" | "sleep"]
+        self.collections: list = []     # [time, seconds, generation, tid]
+        self.gc0 = [g["collections"] for g in gc.get_stats()]
+        self._t = None
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase, info):
+        now = time.perf_counter()
+        if phase == "start":
+            self._t = now
+        elif self._t is not None and now - self._t >= self.GC_LEAST_S:
+            self.collections.append(
+                [self._t, now - self._t, info["generation"],
+                 threading.get_native_id()])
+
+    def took(self, start: float, meant: float, what: str) -> float:
+        """Note a step of the loop that began at ``start`` and was meant
+        to take ``meant`` seconds; returns the time now."""
+        now = time.perf_counter()
+        if now - start - meant >= self.LEAST_S:
+            self.holdups.append([start, now - start - meant, what])
+        return now
+
+    def report(self, t0: float, t1: float):
+        gc.callbacks.remove(self._on_gc)
+        counts = [g["collections"] - a
+                  for g, a in zip(gc.get_stats(), self.gc0)]
+        print("bench stalls: " + json.dumps({
+            "window": [t0, t1], "holdups": self.holdups[:50],
+            "collections": self.collections[:50], "gc_counts": counts,
+            "threads": {t.native_id: t.name
+                        for t in threading.enumerate()}}), flush=True)
 
 
 def collect(eng, handle, timeout_s: float = 120.0) -> list:

@@ -47,6 +47,20 @@ def opcode(op_name: str) -> str:
     return m.group(1) if m else ""
 
 
+def is_kernel_call(op_name: str, kernel: str) -> bool:
+    """Whether an ``XLA Ops`` event is a call of the Pallas kernel whose
+    ``pallas_call`` is named ``kernel`` (the instruction is
+    ``%<kernel>[.N] = ... custom_call_target="tpu_custom_call"``)."""
+    return KERNEL_TARGET in op_name and op_name.lstrip("%").startswith(kernel)
+
+
+def result_dims(op_name: str) -> list:
+    """The dimensions of an operation's result, from its HLO text; [] for
+    a scalar or where the text shows none."""
+    shape = _SHAPE.search(op_name.partition(" = ")[2])
+    return [int(x) for x in shape.group(2).split(",") if x] if shape else []
+
+
 def is_collective(op_name: str) -> bool:
     oc = opcode(op_name)
     if oc.startswith(COLLECTIVE_OPCODES):

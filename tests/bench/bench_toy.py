@@ -55,7 +55,7 @@ TOY_CHAT = {
     "trace_s": 1, "prefill_limits": LIMITS}
 TOY_DOC = {
     "generator": "doc_backlog", "runner": "serve_backlog",
-    "doc_tokens": {"dist": "uniform", "min": 64, "max": 128},
+    "doc_tokens": {"dist": "uniform", "min": 100, "max": 128},
     "question_tokens": {"dist": "uniform", "min": 4, "max": 12},
     "answer_tokens": {"dist": "uniform", "min": 4, "max": 12},
     "askings": 3, "docs_per_cycle": 4, "wave_docs": 2, "max_waiting": 2,

@@ -1,7 +1,7 @@
 """The readers of ``decode_delivered_share.*``, ``decode_overrun_share.*``
 and ``prefill_fill_share.*`` (PR 38; ``benchmark/dispatch_account.py``) on
-synthetic span lists, their ten entries found by name, and a toy serve cell
-whose spans all three can read."""
+synthetic span lists, their entries found by name with every serving cell
+under ``workloads``, and a toy serve cell whose spans all three can read."""
 
 import json
 import os
@@ -12,22 +12,24 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_pins  # noqa: E402
 import bench_toy  # noqa: E402
 
 from benchmark import dispatch_account, harness, inside  # noqa: E402
 from benchmark import program_spans  # noqa: E402
 
-DECODE_CELLS = {"doc": "serve-doc", "moe": "serve-moe-gen",
-                "code": "serve-code-gen"}
-PREFILL_CELLS = dict(DECODE_CELLS, chat="serve-chat")
+BACKLOG_CELLS = ("serve-doc", "serve-moe-gen", "serve-code-gen",
+                 "serve-instruct-gen", "serve-note-gen", "serve-reason-gen")
+# (stem, cell): (better, the end-to-end metric it moves there)
 ENTRIES = {
-    **{f"decode_delivered_share.{s}": (c, "higher", "serve_tokens_per_s")
-       for s, c in DECODE_CELLS.items()},
-    **{f"decode_overrun_share.{s}": (c, "lower", "serve_tokens_per_s")
-       for s, c in DECODE_CELLS.items()},
-    **{f"prefill_fill_share.{s}": (
-        c, "higher", "ttft_p90_ms" if s == "chat" else "serve_tokens_per_s")
-       for s, c in PREFILL_CELLS.items()}}
+    **{("decode_delivered_share", c): ("higher", "serve_tokens_per_s")
+       for c in BACKLOG_CELLS},
+    **{("decode_overrun_share", c): ("lower", "serve_tokens_per_s")
+       for c in BACKLOG_CELLS},
+    **{("prefill_fill_share", c): ("higher", "serve_tokens_per_s")
+       for c in BACKLOG_CELLS},
+    ("prefill_fill_share", "serve-chat"): ("higher", "ttft_p90_ms")}
+IDS = [f"{s}-{c}" for s, c in sorted(ENTRIES)]
 
 
 def emit(i, **attrs):
@@ -123,57 +125,44 @@ def test_prefill_fill_share_is_new_tokens_over_group_times_bucket(
         [prefill(i, seq=i) for i in range(6)]) is None
 
 
-@pytest.mark.parametrize("name", sorted(ENTRIES))
-def test_each_reader_is_found_by_its_metrics_name(monkeypatch, name):
+@pytest.mark.parametrize("stem,cell", sorted(ENTRIES), ids=IDS)
+def test_each_reader_is_found_by_its_metrics_name(monkeypatch, stem, cell):
+    name = bench_pins.reports(bench_pins.committed(), cell,
+                              [stem])[stem]["name"]
     read = harness.load_reader(name)
     run = type("Run", (), {"trace": None})
     spans = chunk_spans() + prefill_spans(token_rows=False)
     monkeypatch.setattr(program_spans, "engine_spans", lambda: spans)
-    want = getattr(dispatch_account, name.rsplit(".", 1)[0])(spans)
+    want = getattr(dispatch_account, stem)(spans)
     assert read(run) == pytest.approx(want) and 0.0 < want < 100.0
     # a program that records no spans at all (before PR 24): None, quietly
     monkeypatch.setattr(program_spans, "engine_spans", lambda: None)
     assert read(run) is None
 
 
-def benchmark_json() -> dict:
-    with open(os.path.join(bench_toy.REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
+@pytest.mark.parametrize("stem,cell", sorted(ENTRIES), ids=IDS)
+def test_the_entry_keeps_the_contract(bench, stem, cell):
+    """Found by NAME with the cell under ``workloads``, wherever later
+    PRs' entries put it and whatever cells they add to it."""
+    better, moves = ENTRIES[stem, cell]
+    m = bench_pins.reports(bench, cell, [stem], moves=moves)[stem]
+    assert {k: m[k] for k in ("unit", "better", "source", "layer")} == {
+        "unit": "%", "better": better, "source": "program_span",
+        "layer": "engine scheduler"}
+    # the cell reports the end-to-end metric the share moves
+    assert cell in bench_pins.entry(bench["end_to_end"], moves)["workloads"]
 
 
-@pytest.mark.parametrize("name", sorted(ENTRIES))
-def test_the_entry_keeps_the_contract(name):
-    """Found by NAME, wherever later PRs' entries put it."""
-    bench = benchmark_json()
-    cell, better, moves = ENTRIES[name]
-    (m,) = [m for m in bench["per_layer"] if m["name"] == name]
-    assert m == {"name": name, "unit": "%", "better": better,
-                 "source": "program_span", "layer": "engine scheduler",
-                 "moves": moves, "workloads": [cell]}
-    # the cell reports the end-to-end metric the share moves, and lists
-    # the share among its per-layer metrics
-    moved = next(e for e in bench["end_to_end"] if e["name"] == moves)
-    assert cell in moved["workloads"]
-    assert name in [x["name"] for x in harness.cell_metrics(
-        bench, cell, "per_layer")]
-    assert any(x["layer"] == "engine scheduler" for x in bench["per_layer"]
-               if x["name"] not in ENTRIES)       # a layer that is there
-
-
-def test_no_other_cell_reports_them():
-    """``serve-chat``'s program is 32 wide for 2-3 live slots and its
-    overrun costs no client anything: no decode entry there; the cell
-    whose entries a test of PR 36 pins by their ``workloads`` gets none
-    in this PR; the train cells run no engine."""
-    bench = benchmark_json()
-    stems = {n.rsplit(".", 1)[0] for n in ENTRIES}
-    mine = [m for m in bench["per_layer"]
-            if m["name"].rsplit(".", 1)[0] in stems]
-    assert sorted(m["name"] for m in mine) == sorted(ENTRIES)
-    for cell in ("serve-instruct-gen", "train-2k", "train-2k-fsdp4"):
-        assert not any(cell in m["workloads"] for m in mine)
-    assert [m["name"] for m in mine if "serve-chat" in m["workloads"]] == [
-        "prefill_fill_share.chat"]
+def test_no_cell_without_an_engine_reports_them(bench):
+    """``serve-chat``'s program is 32 wide for the slots its rate fills
+    and its overrun costs no client anything: no decode entry there; the
+    train cells run no engine."""
+    stems = {s for s, _ in ENTRIES}
+    for cell in ("train-2k", "train-2k-fsdp4"):
+        assert not stems & {bench_pins.stem(n)
+                            for n in bench_pins.reported(bench, cell)}
+    assert stems & {bench_pins.stem(n) for n in bench_pins.reported(
+        bench, "serve-chat")} == {"prefill_fill_share"}
 
 
 DRIVER = '''
@@ -210,8 +199,8 @@ def test_a_rehearsed_serve_cell_reports_the_three(tmp_path):
                PYTHONPATH=bench_toy.REPO + os.pathsep
                + os.environ.get("PYTHONPATH", ""),
                JAX_COMPILATION_CACHE_DIR=os.path.join(root, ".jax_cache"))
-    names = ["decode_delivered_share.doc", "decode_overrun_share.doc",
-             "prefill_fill_share.doc"]
+    names = ["decode_delivered_share", "decode_overrun_share",
+             "prefill_fill_share"]
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         listed = [m["name"] for m in harness.cell_metrics(
             json.load(f), "toy-doc", "per_layer")]
@@ -228,15 +217,15 @@ def test_a_rehearsed_serve_cell_reports_the_three(tmp_path):
     sums, values = got["sums"], got["values"]
     assert (sums["tokens"] + sums["overrun_tail"] + sums["overrun_ahead"]
             + sums["vacant"]) == sums["slot_steps"] > 0
-    assert values["decode_delivered_share.doc"] == pytest.approx(
+    assert values["decode_delivered_share"] == pytest.approx(
         100.0 * sums["tokens"] / sums["slot_steps"])
-    assert values["decode_overrun_share.doc"] == pytest.approx(
+    assert values["decode_overrun_share"] == pytest.approx(
         100.0 * (sums["overrun_tail"] + sums["overrun_ahead"])
         / (sums["slot_steps"] - sums["vacant"]))
-    assert values["prefill_fill_share.doc"] == pytest.approx(
+    assert values["prefill_fill_share"] == pytest.approx(
         100.0 * sums["new_tokens"] / sums["token_rows"])
     # answers of 4-12 tokens in chunks of 8 and 16: every answer ends
     # inside a chunk; a suffix is padded to 16 tokens at the least
-    assert 0.0 < values["decode_delivered_share.doc"] < 100.0
-    assert 0.0 < values["decode_overrun_share.doc"] < 100.0
-    assert 0.0 < values["prefill_fill_share.doc"] <= 100.0
+    assert 0.0 < values["decode_delivered_share"] < 100.0
+    assert 0.0 < values["decode_overrun_share"] < 100.0
+    assert 0.0 < values["prefill_fill_share"] <= 100.0

@@ -19,6 +19,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_pins  # noqa: E402
 import bench_toy  # noqa: E402
 
 from benchmark import harness, inside, program_spans, systems  # noqa: E402
@@ -26,19 +27,16 @@ from benchmark.families import dots3_note as family  # noqa: E402
 from benchmark.trace import Trace  # noqa: E402
 
 ROOT = bench_toy.REPO
-CELL, SUFFIX = "serve-note-gen", ".note"
+CELL, SUFFIX = "serve-note-gen", ""
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 TWINS = ("decode_program_step_ms", "decode_roofline",
-         "prefill_program_share", "batch_occupancy", "prefix_hit_share",
+         "prefill_program_share", "prefix_hit_share",
          "device_idle_share", "peak_hbm_gb", "engine_host_share",
-         "prefill_group_mean", "decode_active_share", "expert_ffn_share",
+         "decode_active_share", "decode_delivered_share",
+         "decode_overrun_share", "prefill_fill_share", "expert_ffn_share",
          "experts_touched_mean", "expert_load_max_over_mean",
-         "routed_here_share", "kv_window_read_share")
-# (no ``.note`` twin of ``decode_delivered_share``, ``decode_overrun_share``
-# or ``prefill_fill_share``: ``test_bench_dispatch_account.py`` pins those
-# stems to the ten entries PR 38 added, and a PR of this kind edits no
-# test that is there; as for ``serve-instruct-gen``, they wait for the
-# ``benchmark`` PR that repairs the positional pins)
+         "routed_here_share",
+         "kv_window_read_share")
 OWN = {"latent_attn_share": ("device_trace", "lower"),
        "index_select_share": ("device_trace", "lower"),
        "latent_attn_roofline": ("device_trace", "higher"),
@@ -324,62 +322,42 @@ def test_kv_selected_share_reads_the_dispatch_spans_own_counts(monkeypatch):
                            kv_rows_selected=64 * 2048) for i in range(6)]
     monkeypatch.setattr(program_spans, "engine_spans", lambda: spans)
     whole = sum(s["attrs"]["kv_rows_full"] for s in spans)
-    assert harness.load_reader("kv_selected_share.note")(run) == \
+    assert harness.load_reader("kv_selected_share")(run) == \
         pytest.approx(100.0 * 6 * 64 * 2048 / whole)
     # the parent's spans count no selection; too few; none
     for other in ([dispatch_span(i, kv_rows_full=9) for i in range(6)],
                   spans[:inside.MIN_SAMPLES - 1], None):
         monkeypatch.setattr(program_spans, "engine_spans", lambda o=other: o)
-        assert harness.load_reader("kv_selected_share.note")(run) is None
+        assert harness.load_reader("kv_selected_share")(run) is None
 
 
 # -- the entries --------------------------------------------------------------
 
-def test_the_cells_entries_keep_the_contract():
+def test_the_cells_entries_keep_the_contract(bench):
     """Every clause of ``test_benchmark_json_keeps_the_contract`` for the
-    entries this PR adds: one configuration, one cell, nineteen metrics
-    of its own, each found by name, and nothing before them moved."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    cell = next(c for c in bench["workloads"] if c["name"] == CELL)
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    entries of this cell: its configuration, the cell, and its metrics,
+    each found by name with the cell under ``workloads``, wherever later
+    PRs' entries stand."""
+    cell = bench_pins.cell_entry(bench, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "dots3-note-prev-ep8-d5", "note-backlog-transcript", 1)
-    assert len(cell["why"]) <= 200 and NAME.match(cell["traffic"])
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert len(entry["why"]) <= 200 and len(entry["source"]) <= 200
+    entry = bench_pins.config_entry(bench, cell["config"])
     config = cell_config()
-    assert config["reduced"] == entry["reduced"] == REDUCED and \
-        config["source"] == entry["source"] and config["name"] == entry["name"]
-    assert all(NAME.match(k) for k in entry["reduced"])
-    e2e = {m["name"] for m in harness.cell_metrics(bench, CELL, "end_to_end")}
-    assert e2e == {"serve_tokens_per_s", "setup_s"}
-    by_name = {m["name"]: m for m in bench["end_to_end"]}
-    assert CELL in by_name["serve_tokens_per_s"]["workloads"]
-    assert by_name["serve_tokens_per_s"]["bound"] == 0.045
-    mine = {m["name"]: m for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]}
-    assert set(mine) == {n + SUFFIX for n in TWINS + tuple(OWN)}
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[names.index(TWINS[0] + SUFFIX):][:len(mine)] == list(mine)
-    layers = {m["layer"] for m in bench["per_layer"]
-              if m.get("workloads") != [CELL]}
-    for name, m in mine.items():
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert NAME.match(name) and m["moves"] == "serve_tokens_per_s"
-        assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", m["unit"])
-        assert m["layer"] in layers and harness.load_reader(name) is not None
-        base = name[:-len(SUFFIX)]
-        if base in OWN:
+    bench_pins.check_reduced(entry, config, PUBLISHED)
+    assert sorted(entry["reduced"]) == sorted(REDUCED)
+    assert config["name"] == entry["name"]
+    assert set(bench_pins.reported(bench, CELL, "end_to_end")) == {
+        "serve_tokens_per_s", "setup_s"}
+    moved = bench_pins.entry(bench["end_to_end"], "serve_tokens_per_s")
+    assert CELL in moved["workloads"] and moved["bound"] == 0.045
+    mine = bench_pins.reports(bench, CELL, TWINS + tuple(OWN),
+                              moves="serve_tokens_per_s")
+    for stem, m in mine.items():
+        if stem in OWN:
             assert (m["source"], m["better"], m["unit"], m["layer"]) == (
-                *OWN[base], "%", "kernels")
-        else:
-            twin = next(x for x in bench["per_layer"]
-                        if x["name"] == base + ".code")
-            assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
-                == {k: twin[k] for k in ("unit", "better", "source", "layer")}
+                *OWN[stem], "%", "kernels")
+        else:                   # one entry, shared with the cell before
+            assert "serve-code-gen" in m["workloads"]
     with open(os.path.join(ROOT, "benchmark", "traffic",
                            cell["traffic"] + ".json")) as f:
         traffic = json.load(f)
@@ -500,28 +478,28 @@ def test_toy_note_rehearses_the_cells_runner(toy_root):
     new reader and the twins. (Seed 4: a 64-wide stream in bf16 is a
     lottery the cell's 5,120-wide one is not; of seeds 3-6 three read a
     token gap under 0.01 and seed 3 read 0.26, CPU runs, PR 40.)"""
-    names = ["kv_selected_share.note", "kv_window_read_share.note",
-             "routed_here_share.note", "experts_touched_mean.note",
-             "decode_active_share.note", "prefill_group_mean.note",
-             "latent_attn_share.note", "index_select_share.note",
-             "latent_attn_roofline.note", "expert_ffn_share.note"]
+    names = ["kv_selected_share", "kv_window_read_share",
+             "routed_here_share", "experts_touched_mean",
+             "decode_active_share",
+             "latent_attn_share", "index_select_share",
+             "latent_attn_roofline", "expert_ffn_share"]
     rehearsal, got = rehearse(toy_root, "toy-note-gen", names)
     assert got["rc"] == 0
     assert rehearsal["correct"] is True and rehearsal["failed"] == 0
     assert rehearsal["attempted"] > 0
     # a rehearsal prints counters only
-    assert set(rehearsal["metrics"]) == {
-        "batch_occupancy.note", "prefix_hit_share.note", "compiles_in_window"}
+    assert set(rehearsal["metrics"]) == {"prefix_hit_share",
+                                         "compiles_in_window"}
     assert rehearsal["metrics"]["compiles_in_window"]["value"] <= 1.0
-    assert rehearsal["metrics"]["prefix_hit_share.note"]["value"] > 30.0
+    assert rehearsal["metrics"]["prefix_hit_share"]["value"] > 30.0
     values = got["values"]
     assert [values[n] for n in names[-4:]] == [None] * 4   # no device trace
     for name in names[:-4]:
         assert values[name] is not None, (name, values)
     # contexts of 70-220 tokens: 32 selected of them, a window of 17
-    assert 15.0 < values["kv_selected_share.note"] < 50.0
-    assert values["kv_window_read_share.note"] < 70.0
-    assert values["experts_touched_mean.note"] <= 2.0
+    assert 15.0 < values["kv_selected_share"] < 50.0
+    assert values["kv_window_read_share"] < 70.0
+    assert values["experts_touched_mean"] <= 2.0
 
 
 def test_a_reference_without_the_indexer_reads_not_correct(toy_root):

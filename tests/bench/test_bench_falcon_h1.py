@@ -21,6 +21,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_pins  # noqa: E402
 import bench_toy  # noqa: E402
 
 from benchmark import harness, inside, reference, serving, systems  # noqa: E402
@@ -33,10 +34,10 @@ CELL, CONFIG_NAME, TRAFFIC = ("serve-instruct-gen",
                               "falcon-h1-34b-instruct-d4",
                               "instruct-backlog-fewshot")
 TWINS = ("decode_program_step_ms", "decode_roofline",
-         "prefill_program_share", "batch_occupancy", "device_idle_share",
-         "peak_hbm_gb", "engine_host_share", "prefill_group_mean",
-         "decode_active_share", "paged_attn_kernel_share",
-         "paged_attn_roofline")
+         "prefill_program_share", "device_idle_share",
+         "peak_hbm_gb", "engine_host_share", "decode_active_share",
+         "decode_delivered_share", "decode_overrun_share",
+         "prefill_fill_share", "paged_attn_roofline")
 OWN = {"ssm_mixer_share": "lower", "ssm_state_roofline": "higher",
        "prefill_scan_share": "lower"}
 # https://huggingface.co/tiiuae/Falcon-H1-34B-Instruct/blob/main/config.json
@@ -120,6 +121,8 @@ def test_the_configuration_is_the_catalog_row_but_for_depth():
                                 "Falcon-H1-34B-Instruct/blob/main/config.json")
     differs = [k for k, v in PUBLISHED.items() if config.get(k, "absent") != v]
     assert differs == ["num_hidden_layers"] == config["reduced"]
+    bench_pins.check_reduced(bench_pins.config_entry(
+        benchmark_json(), CONFIG_NAME), config)
     assert config["num_hidden_layers"] == 4       # the guide's floor
     assert config["reduced_from"] == {"num_hidden_layers": 72}
     assert {"state_dtype", "torch_dtype", "init"} <= set(config["assumed"])
@@ -270,17 +273,17 @@ def test_the_three_readers_on_a_synthetic_trace():
                            "device": {"kind": "TPU v5 lite"}})
     assert inside.decode_program_step_ms(run.trace) == pytest.approx(20.0)
     # of a run's 160 ms: 32 x (2 + 1 + 0.5) ms in the mixer's operations
-    assert harness.load_reader("ssm_mixer_share.ssm")(run) == \
+    assert harness.load_reader("ssm_mixer_share")(run) == \
         pytest.approx(100.0 * 32 * 3.5 / 160.0)
     # a step's 4 layers take 4 x 3 ms on the state; 4.326 GB at 819 GB/s
     # are 5.28 ms: an update that reads the state twice sits at 44%
-    got = harness.load_reader("ssm_state_roofline.ssm")(run)
+    got = harness.load_reader("ssm_state_roofline")(run)
     assert got == pytest.approx(100.0 * 4.326e9 / 819e9 / 12e-3, rel=1e-3)
     assert 40.0 < got < 50.0
     # of a prefill run's 40 ms, 10 in the scan's operations
-    assert harness.load_reader("prefill_scan_share.ssm")(run) == \
+    assert harness.load_reader("prefill_scan_share")(run) == \
         pytest.approx(25.0)
-    names = [n + ".ssm" for n in OWN]
+    names = list(OWN)
     # too few runs, no trace, another family, and a program with no such
     # operation (the parent's, were it to run the cell): nothing, no error
     run.trace = synthetic_trace(inside.MIN_SAMPLES - 1)
@@ -293,69 +296,50 @@ def test_the_three_readers_on_a_synthetic_trace():
     bare.devices[0]["ops"] = [op for op in bare.devices[0]["ops"]
                               if op[0] in OTHER_OPS]
     run.trace, run.config = bare, m
-    assert harness.load_reader("ssm_state_roofline.ssm")(run) is None
-    assert harness.load_reader("prefill_scan_share.ssm")(run) is None
-    assert not harness.load_reader("ssm_mixer_share.ssm")(run)
+    assert harness.load_reader("ssm_state_roofline")(run) is None
+    assert harness.load_reader("prefill_scan_share")(run) is None
+    assert not harness.load_reader("ssm_mixer_share")(run)
 
 
 # -- the entries, by name -----------------------------------------------------
 
-def test_the_cells_entries_keep_the_contract():
+def test_the_cells_entries_keep_the_contract(bench):
     """Every clause of ``test_benchmark_json_keeps_the_contract`` for the
-    entries this PR adds, each found by its NAME: a later PR's entries,
-    appended behind these, turn nothing here."""
-    bench = benchmark_json()
-    cell = next(c for c in bench["workloads"] if c["name"] == CELL)
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    entries of this cell, each found by its NAME with the cell under its
+    ``workloads``: a later PR's entries, appended behind these or added to
+    the cell, turn nothing here."""
+    cell = bench_pins.cell_entry(bench, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG_NAME, TRAFFIC, 1)
-    assert len(cell["why"]) <= 200 and NAME.match(cell["traffic"])
     assert "depth cut" in cell["why"]       # the head's share is its doing
-    entry = next(c for c in bench["configs"] if c["name"] == CONFIG_NAME)
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert len(entry["why"]) <= 200
+    entry = bench_pins.config_entry(bench, CONFIG_NAME)
     assert entry["file"] == f"benchmark/configs/{CONFIG_NAME}.json"
-    config = cell_config()
-    assert config["reduced"] == entry["reduced"] == ["num_hidden_layers"]
-    assert config["source"] == entry["source"]
+    bench_pins.check_reduced(entry, cell_config(), PUBLISHED)
+    assert entry["reduced"] == ["num_hidden_layers"]
     # one four-chip cell as before
     assert [c["name"] for c in bench["workloads"] if c["chips"] == 4] == [
         "train-2k-fsdp4"]
     assert len(bench["workloads"]) >= 7 and len(bench["configs"]) >= 6
-    e2e = {m["name"] for m in harness.cell_metrics(bench, CELL, "end_to_end")}
-    assert e2e == {"serve_tokens_per_s", "setup_s"}
-    by_name = {m["name"]: m for m in bench["end_to_end"]}
-    assert CELL in by_name["serve_tokens_per_s"]["workloads"]
-    assert by_name["serve_tokens_per_s"]["bound"] == 0.045
+    assert set(bench_pins.reported(bench, CELL, "end_to_end")) == {
+        "serve_tokens_per_s", "setup_s"}
+    moved = bench_pins.entry(bench["end_to_end"], "serve_tokens_per_s")
+    assert CELL in moved["workloads"] and moved["bound"] == 0.045
     assert bench["run_seconds"] == 51
-    mine = {m["name"]: m for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]}
-    assert set(mine) == {n + ".ssm" for n in TWINS + tuple(OWN)}
-    assert "prefix_hit_share.ssm" not in mine      # there is nothing to hit
-    per_layer = {m["name"]: m for m in bench["per_layer"]}
-    for name, m in mine.items():
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert NAME.match(name) and m["moves"] == "serve_tokens_per_s"
-        assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", m["unit"])
-        assert harness.load_reader(name) is not None
-        base = name[:-len(".ssm")]
-        if base in OWN:
+    mine = bench_pins.reports(bench, CELL, TWINS + tuple(OWN),
+                              moves="serve_tokens_per_s")
+    # there is nothing to hit: a recurrent state has no prefix to share
+    assert "prefix_hit_share" not in bench_pins.reported(bench, CELL)
+    for stem, m in mine.items():
+        if stem in OWN:
             assert (m["source"], m["layer"], m["unit"], m["better"]) == (
-                "device_trace", "recurrent state", "%", OWN[base])
-        else:                   # a twin reads as its .code sibling does
-            twin = per_layer[base + ".code"]
-            assert {k: v for k, v in m.items()
-                    if k not in ("name", "workloads")} == {
-                k: v for k, v in twin.items()
-                if k not in ("name", "workloads")}
-    names = [m["name"] for m in harness.cell_metrics(bench, CELL,
-                                                     "per_layer")]
-    assert sorted(names) == sorted(list(mine) + ["compiles_in_window"])
+                "device_trace", "recurrent state", "%", OWN[stem])
+        else:                   # one entry, shared with the cell before
+            assert "serve-code-gen" in m["workloads"]
+    assert "compiles_in_window" in bench_pins.reported(bench, CELL)
     # the roofline and mfu shares of the accepted benchmark that move the
     # cell's end-to-end metric are reported in it
-    assert {"decode_roofline.ssm", "paged_attn_roofline.ssm",
-            "ssm_state_roofline.ssm"} <= set(mine)
+    assert {"decode_roofline", "paged_attn_roofline",
+            "ssm_state_roofline"} <= set(mine)
 
 
 def test_the_traffic_fills_every_slot_at_the_eight_page_table():
@@ -528,9 +512,9 @@ def test_toy_falcon_rehearses_the_cells_runner(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
                JAX_COMPILATION_CACHE_DIR=os.path.join(root, ".jax_cache"))
-    names = ["decode_active_share.ssm", "prefill_group_mean.ssm",
-             "ssm_mixer_share.ssm", "ssm_state_roofline.ssm",
-             "prefill_scan_share.ssm", "paged_attn_roofline.ssm"]
+    names = ["decode_active_share",
+             "ssm_mixer_share", "ssm_state_roofline",
+             "prefill_scan_share", "paged_attn_roofline"]
     r = subprocess.run(
         [sys.executable, "-c", DRIVER, str(2 ** 31 + 5), json.dumps(names)],
         cwd=root, env=env, capture_output=True, text=True, timeout=900)
@@ -542,14 +526,11 @@ def test_toy_falcon_rehearses_the_cells_runner(tmp_path):
     assert rehearsal["correct"] is True and rehearsal["failed"] == 0
     assert rehearsal["attempted"] > 0
     # a rehearsal prints counters only, and this cell has no prefix to hit
-    assert set(rehearsal["metrics"]) == {"batch_occupancy.ssm",
-                                         "compiles_in_window"}
+    assert set(rehearsal["metrics"]) == {"compiles_in_window"}
     assert rehearsal["metrics"]["compiles_in_window"]["value"] <= 1.0
-    assert rehearsal["metrics"]["batch_occupancy.ssm"]["value"] > 50.0
     values = got["values"]
-    assert all(values[n] is None for n in names[2:])    # no device trace
-    assert values["decode_active_share.ssm"] > 50.0
-    assert values["prefill_group_mean.ssm"] >= 1.0
+    assert all(values[n] is None for n in names[1:])    # no device trace
+    assert values["decode_active_share"] > 50.0
     attrs = got["attrs"]
     # every prefilled row's state is installed, and a decode chunk's
     # state bytes are its live slots' (2 layers of 3,072 + 672 B of

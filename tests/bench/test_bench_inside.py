@@ -12,6 +12,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_pins  # noqa: E402
 import bench_toy  # noqa: E402
 from test_bench_trace import engine_like_trace  # noqa: E402
 
@@ -19,8 +20,7 @@ from benchmark import harness, inside, readers, systems  # noqa: E402
 from benchmark.trace import Trace  # noqa: E402
 
 SPAN_METRICS = ("engine_host_share", "prefill_device_wait_p50_ms",
-                "prefill_run_p50_ms", "prefill_group_mean",
-                "decode_active_share")
+                "prefill_run_p50_ms", "decode_active_share")
 
 
 def repeated(trace, times: int):
@@ -165,7 +165,6 @@ def test_span_readers_on_a_synthetic_loop():
     assert inside.engine_host_share(spans) == pytest.approx(25.0)
     assert inside.prefill_device_wait_p50_ms(spans) == pytest.approx(300.0)
     assert inside.prefill_run_p50_ms(spans) == pytest.approx(200.0)
-    assert inside.prefill_group_mean(spans) == pytest.approx(2.0)
     assert inside.decode_active_share(spans) == pytest.approx(75.0)
     shares = inside.phase_shares(spans)
     assert shares == pytest.approx({
@@ -188,7 +187,7 @@ def test_span_readers_on_a_synthetic_loop():
     # fewer than five samples is no median; no spans (the parent) is None
     for read in (inside.engine_host_share, inside.prefill_run_p50_ms,
                  inside.prefill_device_wait_p50_ms,
-                 inside.prefill_group_mean, inside.decode_active_share):
+                 inside.decode_active_share):
         assert read(loop_spans(4)) is None
         assert read(None) is None and read([]) is None
 
@@ -231,16 +230,18 @@ def test_new_readers_return_none_without_a_trace_or_spans(monkeypatch):
         program_spans.engine_spans.cache_clear()
 
 
-def test_new_entries_name_their_cells_and_are_not_counters():
-    with open(os.path.join(bench_toy.REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    mine = [m for m in bench["per_layer"]
-            if m["name"].split(".")[0] in SPAN_METRICS + (
-                "decode_program_step_ms", "prefill_program_share",
-                "flash_fwd_share", "flash_bwd_share")]
-    assert len(mine) == 14
-    assert bench["per_layer"][-14:] == mine          # appended, at the end
-    for m in mine:
+@pytest.mark.parametrize("cell,stems", [
+    ("serve-chat", SPAN_METRICS + ("decode_program_step_ms",
+                                   "prefill_program_share")),
+    ("serve-doc", ("engine_host_share", "decode_active_share",
+                   "decode_program_step_ms", "prefill_program_share")),
+    ("train-2k", ("flash_fwd_share", "flash_bwd_share")),
+    ("train-2k-fsdp4", ("flash_fwd_share", "flash_bwd_share"))])
+def test_new_entries_name_their_cells_and_are_not_counters(bench, cell,
+                                                           stems):
+    """PR 24's entries, each found by its name with the cell under its
+    ``workloads``, wherever in the list they stand."""
+    for m in bench_pins.reports(bench, cell, stems).values():
         assert m["workloads"] and m["source"] in ("device_trace",
                                                   "program_span")
 
@@ -261,7 +262,7 @@ print("inside " + json.dumps({"rc": rc, "values": values,
 
 
 @pytest.mark.parametrize("cell,suffix", [("toy-chat", ".chat"),
-                                         ("toy-doc", ".doc")])
+                                         ("toy-doc", "")])
 def test_rehearsed_serve_cell_leaves_spans_every_reader_can_read(
         tmp_path, cell, suffix):
     """A toy serve cell with a profiler session over most of its window,
@@ -293,4 +294,5 @@ def test_rehearsed_serve_cell_leaves_spans_every_reader_can_read(
         assert 0.0 <= value < 1e6
     assert 0.0 < got["values"]["engine_host_share"] <= 100.0
     assert 0.0 < got["values"]["decode_active_share"] <= 100.0
-    assert got["values"]["prefill_group_mean"] >= 1.0
+    assert got["values"]["engine_host_share"] == got["values"][
+        "engine_host_share"]

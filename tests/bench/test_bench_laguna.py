@@ -19,6 +19,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_pins  # noqa: E402
 import bench_toy  # noqa: E402
 
 from benchmark import harness, inside, reference, serving, systems  # noqa: E402
@@ -27,12 +28,14 @@ from benchmark.trace import Trace  # noqa: E402
 
 ROOT = bench_toy.REPO
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+# what every backlog cell reports, and what every cell of routed experts
 CODE_TWINS = ("decode_program_step_ms", "decode_roofline",
-              "prefill_program_share", "batch_occupancy", "prefix_hit_share",
+              "prefill_program_share", "prefix_hit_share",
               "device_idle_share", "peak_hbm_gb", "engine_host_share",
-              "prefill_group_mean", "decode_active_share",
-              "paged_attn_kernel_share", "expert_ffn_share",
-              "experts_touched_mean", "expert_load_max_over_mean")
+              "decode_active_share", "decode_delivered_share",
+              "decode_overrun_share", "prefill_fill_share",
+              "expert_ffn_share", "experts_touched_mean",
+              "expert_load_max_over_mean")
 CODE_OWN = {"kv_window_read_share": ("program_span", "kernels"),
             "routed_here_share": ("program_span", "routed experts"),
             "paged_attn_roofline": ("device_trace", "kernels")}
@@ -105,9 +108,10 @@ def test_the_configuration_is_the_published_one_but_for_its_three_cuts():
         ["num_hidden_layers", "num_experts", "vocab_size", *PER_LAYER_LISTS])
     # every key that differs is listed: the three cuts, and the four
     # per-layer lists that the depth cuts with it
-    assert config["reduced"] == ["num_hidden_layers", "num_experts",
-                                 "vocab_size", *PER_LAYER_LISTS]
-    assert {k: config["reduced_from"][k] for k in config["reduced"][:3]} == {
+    assert sorted(config["reduced"]) == sorted(
+        ["num_hidden_layers", "num_experts", "vocab_size", *PER_LAYER_LISTS])
+    assert {k: config["reduced_from"][k] for k in (
+        "num_hidden_layers", "num_experts", "vocab_size")} == {
         "num_hidden_layers": 48, "num_experts": 256, "vocab_size": 100352}
     assert set(config["reduced_from"]) == set(config["reduced"])
     # the per-layer lists are the published ones' first five entries: the
@@ -235,17 +239,17 @@ def test_paged_attn_roofline_is_the_familys_bytes_over_the_kernels_time():
                            "counters": counters,
                            "device": {"kind": "TPU v5 lite"}})
     # a step's five calls take 2.0 ms; 1.713 GB at 819 GB/s take 2.09 ms
-    got = harness.load_reader("paged_attn_roofline.code")(run)
+    got = harness.load_reader("paged_attn_roofline")(run)
     assert inside.decode_program_step_ms(run.trace) == pytest.approx(10.0)
     assert got == pytest.approx(100.0 * 1.713e9 / 819e9 / 2.0e-3, rel=0.01)
     # fewer runs than a median wants, no trace, a family that counts no
     # such bytes, the parent's family file: nothing, and no error
     run.trace = synthetic_trace(inside.MIN_SAMPLES - 1)
-    assert harness.load_reader("paged_attn_roofline.code")(run) is None
+    assert harness.load_reader("paged_attn_roofline")(run) is None
     run.trace = None
-    assert harness.load_reader("paged_attn_roofline.code")(run) is None
-    run.trace, run.config = synthetic_trace(), {"family": "olmoe"}
-    assert harness.load_reader("paged_attn_roofline.code")(run) is None
+    assert harness.load_reader("paged_attn_roofline")(run) is None
+    run.trace, run.config = synthetic_trace(), {"family": "dots3_note"}
+    assert harness.load_reader("paged_attn_roofline")(run) is None
 
 
 def dispatch_span(i, **attrs):
@@ -273,74 +277,53 @@ def test_the_span_readers_read_the_programs_own_counts(monkeypatch):
     run = type("Run", (), {"trace": None, "config": cell_config()})
     full = sum(64 * 2000 + 1000 * i for i in range(6))
     want = 100.0 * (2 * full + 3 * 6 * 64 * 512) / (5 * full)
-    assert harness.load_reader("kv_window_read_share.code")(run) == \
+    assert harness.load_reader("kv_window_read_share")(run) == \
         pytest.approx(want)
     assert 54.0 < want < 56.0
-    assert harness.load_reader("routed_here_share.code")(run) == \
+    assert harness.load_reader("routed_here_share")(run) == \
         pytest.approx(25.0)
     # too few chunks, or a program that counts neither (the parent's)
     monkeypatch.setattr(program_spans, "engine_spans", lambda: spans[:3])
-    assert harness.load_reader("kv_window_read_share.code")(run) is None
+    assert harness.load_reader("kv_window_read_share")(run) is None
     monkeypatch.setattr(program_spans, "engine_spans", lambda: spans[-2:])
-    assert harness.load_reader("routed_here_share.code")(run) is None
+    assert harness.load_reader("routed_here_share")(run) is None
     monkeypatch.setattr(program_spans, "engine_spans", lambda: None)
-    assert harness.load_reader("kv_window_read_share.code")(run) is None
-    assert harness.load_reader("routed_here_share.code")(run) is None
+    assert harness.load_reader("kv_window_read_share")(run) is None
+    assert harness.load_reader("routed_here_share")(run) is None
     run.config = {"family": "llama"}
     monkeypatch.setattr(program_spans, "engine_spans", lambda: spans)
-    assert harness.load_reader("kv_window_read_share.code")(run) is None
+    assert harness.load_reader("kv_window_read_share")(run) is None
 
 
 # -- the entries --------------------------------------------------------------
 
-def test_the_cells_entries_keep_the_contract():
+def test_the_cells_entries_keep_the_contract(bench):
     """Every clause of ``test_benchmark_json_keeps_the_contract`` for the
-    entries this PR adds, but the one it is known to turn (``reduced ==
-    ["num_hidden_layers"]``: this configuration's usual cut lists the
-    experts held and the vocabulary too)."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    cell = bench["workloads"][-1]
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        "serve-code-gen", "laguna-s-2.1-ep4-d5", "code-backlog-longgen", 1)
-    assert len(cell["why"]) <= 200 and NAME.match(cell["traffic"])
-    entry = bench["configs"][-1]
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert entry["name"] == cell["config"] and len(entry["why"]) <= 200
+    entries of this cell, each found by its NAME with the cell under its
+    ``workloads``: what a later PR appends behind them, or adds to the
+    cell, turns nothing here."""
+    cell = bench_pins.cell_entry(bench, "serve-code-gen")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "laguna-s-2.1-ep4-d5", "code-backlog-longgen", 1)
+    entry = bench_pins.config_entry(bench, cell["config"])
     config = cell_config()
-    assert config["reduced"] == entry["reduced"] and \
-        config["source"] == entry["source"]
-    assert all(NAME.match(k) for k in entry["reduced"])
-    e2e = {m["name"] for m in harness.cell_metrics(
-        bench, "serve-code-gen", "end_to_end")}
-    assert e2e == {"serve_tokens_per_s", "setup_s"}
-    by_name = {m["name"]: m for m in bench["end_to_end"]}
-    assert by_name["serve_tokens_per_s"]["workloads"][-1] == "serve-code-gen"
-    assert by_name["serve_tokens_per_s"]["bound"] == 0.045
-    mine = {m["name"]: m for m in bench["per_layer"]
-            if m.get("workloads") == ["serve-code-gen"]}
-    assert list(mine) == [m["name"] for m in bench["per_layer"]][-len(mine):]
-    assert set(mine) == {n + ".code" for n in CODE_TWINS + tuple(CODE_OWN)}
-    for name, m in mine.items():
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert NAME.match(name) and m["moves"] == "serve_tokens_per_s"
-        assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", m["unit"])
-        assert harness.load_reader(name) is not None
-        twin = next((t for t in bench["per_layer"]
-                     if t["name"] == name[:-5] + ".moe"), None)
-        if twin is not None:        # a twin reads as its .moe sibling does
-            assert {k: v for k, v in m.items()
-                    if k not in ("name", "workloads")} == {
-                k: v for k, v in twin.items()
-                if k not in ("name", "workloads")}
-        else:
-            assert (m["source"], m["layer"]) == CODE_OWN[name[:-5]]
+    bench_pins.check_reduced(entry, config, PUBLISHED)
+    assert set(bench_pins.reported(bench, "serve-code-gen",
+                                   "end_to_end")) == {
+        "serve_tokens_per_s", "setup_s"}
+    moved = bench_pins.entry(bench["end_to_end"], "serve_tokens_per_s")
+    assert "serve-code-gen" in moved["workloads"] and moved["bound"] == 0.045
+    mine = bench_pins.reports(bench, "serve-code-gen",
+                              CODE_TWINS + tuple(CODE_OWN),
+                              moves="serve_tokens_per_s")
+    for stem, m in mine.items():
+        if stem in CODE_OWN:
+            assert (m["source"], m["layer"]) == CODE_OWN[stem]
             assert m["unit"] == "%"
-    names = [m["name"] for m in harness.cell_metrics(
-        bench, "serve-code-gen", "per_layer")]
-    assert sorted(names) == sorted(list(mine) + ["compiles_in_window"])
+        if stem in CODE_TWINS:      # one entry, shared with the cell before
+            assert "serve-moe-gen" in m["workloads"]
+    assert "compiles_in_window" in bench_pins.reported(bench,
+                                                       "serve-code-gen")
     with open(os.path.join(ROOT, "benchmark", "traffic",
                            "code-backlog-longgen.json")) as f:
         traffic = json.load(f)
@@ -482,10 +465,10 @@ def test_toy_laguna_rehearses_the_cells_runner(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
                JAX_COMPILATION_CACHE_DIR=os.path.join(root, ".jax_cache"))
-    names = ["kv_window_read_share.code", "routed_here_share.code",
-             "experts_touched_mean.code", "expert_load_max_over_mean.code",
-             "decode_active_share.code", "prefill_group_mean.code",
-             "paged_attn_roofline.code", "expert_ffn_share.code"]
+    names = ["kv_window_read_share", "routed_here_share",
+             "experts_touched_mean", "expert_load_max_over_mean",
+             "decode_active_share",
+             "paged_attn_roofline", "expert_ffn_share"]
     r = subprocess.run(
         [sys.executable, "-c", DRIVER, "3", json.dumps(names)],
         cwd=root, env=env, capture_output=True, text=True, timeout=900)
@@ -497,16 +480,16 @@ def test_toy_laguna_rehearses_the_cells_runner(tmp_path):
     assert rehearsal["correct"] is True and rehearsal["failed"] == 0
     assert rehearsal["attempted"] > 0
     # a rehearsal prints counters only
-    assert set(rehearsal["metrics"]) == {
-        "batch_occupancy.code", "prefix_hit_share.code", "compiles_in_window"}
+    assert set(rehearsal["metrics"]) == {"prefix_hit_share",
+                                         "compiles_in_window"}
     assert rehearsal["metrics"]["compiles_in_window"]["value"] <= 1.0
-    assert rehearsal["metrics"]["prefix_hit_share.code"]["value"] > 30.0
+    assert rehearsal["metrics"]["prefix_hit_share"]["value"] > 30.0
     values = got["values"]
-    assert values["paged_attn_roofline.code"] is None    # no device trace
-    assert values["expert_ffn_share.code"] is None
+    assert values["paged_attn_roofline"] is None    # no device trace
+    assert values["expert_ffn_share"] is None
     for name in names[:-2]:
         assert values[name] is not None, (name, values)
     # contexts of 70-220 tokens against a window of 16 in 3 of 5 layers
-    assert 40.0 < values["kv_window_read_share.code"] < 60.0
-    assert 10.0 < values["routed_here_share.code"] < 45.0
-    assert values["experts_touched_mean.code"] <= 2.0
+    assert 40.0 < values["kv_window_read_share"] < 60.0
+    assert 10.0 < values["routed_here_share"] < 45.0
+    assert values["experts_touched_mean"] <= 2.0

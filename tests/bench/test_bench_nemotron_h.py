@@ -22,6 +22,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_pins  # noqa: E402
 import bench_toy  # noqa: E402
 
 from benchmark import harness, inside, reference, serving, systems  # noqa: E402
@@ -29,23 +30,28 @@ from benchmark.families import nemotron_h as family  # noqa: E402
 from benchmark.trace import Trace  # noqa: E402
 
 ROOT = bench_toy.REPO
-CELL, SUFFIX = "serve-reason-gen", ".nano"
+CELL, SUFFIX = "serve-reason-gen", ""
 CONFIG_NAME = "nemotron-3-nano-30b-a3b-ep2-d9"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-# the older readers' entries, each with the twin it copies
-TWINS = {"decode_program_step_ms": ".ssm", "decode_roofline": ".ssm",
-         "prefill_program_share": ".ssm", "batch_occupancy": ".ssm",
-         "device_idle_share": ".ssm", "peak_hbm_gb": ".ssm",
-         "engine_host_share": ".ssm", "paged_attn_roofline": ".ssm",
-         "expert_ffn_share": ".note", "experts_touched_mean": ".note",
-         "expert_load_max_over_mean": ".note", "routed_here_share": ".note",
-         "ssm_mixer_share": ".ssm", "ssm_state_roofline": ".ssm",
-         "prefill_scan_share": ".ssm"}
-# (no ``.nano`` twin of ``decode_delivered_share``, ``decode_overrun_share``
-# or ``prefill_fill_share``: ``test_bench_dispatch_account.py`` pins those
-# stems to the cells they have; none of ``prefill_group_mean``,
-# ``decode_active_share`` or ``paged_attn_kernel_share`` either: the
-# benchmark may hold 128 per-layer metrics and had 112)
+# the older readers' entries, each with a cell it shares the entry with
+TWINS = {"decode_program_step_ms": "serve-instruct-gen",
+         "decode_roofline": "serve-instruct-gen",
+         "prefill_program_share": "serve-instruct-gen",
+         "decode_active_share": "serve-instruct-gen",
+         "decode_delivered_share": "serve-instruct-gen",
+         "decode_overrun_share": "serve-instruct-gen",
+         "prefill_fill_share": "serve-instruct-gen",
+         "device_idle_share": "serve-instruct-gen",
+         "peak_hbm_gb": "serve-instruct-gen",
+         "engine_host_share": "serve-instruct-gen",
+         "paged_attn_roofline": "serve-instruct-gen",
+         "expert_ffn_share": "serve-note-gen",
+         "experts_touched_mean": "serve-note-gen",
+         "expert_load_max_over_mean": "serve-note-gen",
+         "routed_here_share": "serve-note-gen",
+         "ssm_mixer_share": "serve-instruct-gen",
+         "ssm_state_roofline": "serve-instruct-gen",
+         "prefill_scan_share": "serve-instruct-gen"}
 OWN = "prefill_expert_share"
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 # https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16/blob/
@@ -283,27 +289,27 @@ def test_the_readers_on_a_synthetic_trace():
     assert inside.decode_program_step_ms(run.trace) == pytest.approx(13.0)
     # of a step's 13 ms: 4 x (0.8 + 0.2) in the mixer's operations, 4 x
     # 1.8 in the experts'; the out_proj and wo matmuls in neither
-    assert harness.load_reader("ssm_mixer_share.nano")(run) == \
+    assert harness.load_reader("ssm_mixer_share")(run) == \
         pytest.approx(100.0 * 4.0 / 13.0)
-    assert harness.load_reader("expert_ffn_share.nano")(run) == \
+    assert harness.load_reader("expert_ffn_share")(run) == \
         pytest.approx(100.0 * 7.2 / 13.0)
     # 2.185 GB of state at 819 GB/s are 2.67 ms against the 3.2 measured
-    got = harness.load_reader("ssm_state_roofline.nano")(run)
+    got = harness.load_reader("ssm_state_roofline")(run)
     assert got == pytest.approx(
         100.0 * family.ssm_state_bytes(m, counters) / 819e9 / 3.2e-3,
         rel=1e-3)
     assert 80.0 < got < 86.0
     # 0.168 GB of keys and values are 0.205 ms against the kernel's 0.2:
     # the synthetic kernel runs at its roofline, and a real one under it
-    assert harness.load_reader("paged_attn_roofline.nano")(run) == \
+    assert harness.load_reader("paged_attn_roofline")(run) == \
         pytest.approx(100.0 * 128 * 1280 * 1024 / 819e9 / 0.2e-3, rel=1e-3)
     # of a prefill run's 50 ms, 30 in the experts' and 8 in the scan's
-    assert harness.load_reader("prefill_expert_share.nano")(run) == \
+    assert harness.load_reader("prefill_expert_share")(run) == \
         pytest.approx(60.0)
-    assert harness.load_reader("prefill_scan_share.nano")(run) == \
+    assert harness.load_reader("prefill_scan_share")(run) == \
         pytest.approx(16.0)
-    names = ["prefill_expert_share.nano", "prefill_scan_share.nano",
-             "ssm_state_roofline.nano", "expert_ffn_share.nano"]
+    names = ["prefill_expert_share", "prefill_scan_share",
+             "ssm_state_roofline", "expert_ffn_share"]
     # too few runs, no trace, another family, and a program with no such
     # operation (the parent's, were it to run the cell): nothing, no error
     run.trace = synthetic_trace(inside.MIN_SAMPLES - 1)
@@ -316,65 +322,43 @@ def test_the_readers_on_a_synthetic_trace():
     bare.devices[0]["ops"] = [op for op in bare.devices[0]["ops"]
                               if op[0] in OTHER_OPS + SHARED_SHAPES]
     run.trace, run.config = bare, m
-    assert harness.load_reader("prefill_expert_share.nano")(run) is None
-    assert harness.load_reader("prefill_scan_share.nano")(run) is None
-    assert harness.load_reader("ssm_state_roofline.nano")(run) is None
-    assert not harness.load_reader("expert_ffn_share.nano")(run)
+    assert harness.load_reader("prefill_expert_share")(run) is None
+    assert harness.load_reader("prefill_scan_share")(run) is None
+    assert harness.load_reader("ssm_state_roofline")(run) is None
+    assert not harness.load_reader("expert_ffn_share")(run)
 
 
 # -- the entries, by name -----------------------------------------------------
 
-def test_the_cells_entries_keep_the_contract():
+def test_the_cells_entries_keep_the_contract(bench):
     """Every clause of ``test_benchmark_json_keeps_the_contract`` for the
-    entries this PR adds: one configuration, one cell, sixteen metrics of
-    its own, each found by name, and the lists within their limits."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    cell = next(c for c in bench["workloads"] if c["name"] == CELL)
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    entries of this cell: one configuration, one cell and its metrics,
+    each found by name with the cell under ``workloads``, and the lists
+    within their limits with room to spare."""
+    cell = bench_pins.cell_entry(bench, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG_NAME, "reason-backlog-trace", 1)
-    assert len(cell["why"]) <= 200 and NAME.match(cell["traffic"])
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert len(entry["why"]) <= 200 and len(entry["source"]) <= 200
-    assert bench["configs"][-1] is entry and bench["workloads"][-1] is cell
+    entry = bench_pins.config_entry(bench, cell["config"])
     config = cell_config()
-    assert config["reduced"] == entry["reduced"] == REDUCED and \
-        config["source"] == entry["source"] and config["name"] == entry["name"]
-    assert all(NAME.match(k) for k in entry["reduced"])
-    e2e = {m["name"] for m in harness.cell_metrics(bench, CELL, "end_to_end")}
-    assert e2e == {"serve_tokens_per_s", "setup_s"}
-    by_name = {m["name"]: m for m in bench["end_to_end"]}
-    assert by_name["serve_tokens_per_s"]["workloads"][-1] == CELL
-    assert by_name["serve_tokens_per_s"]["bound"] == 0.045
-    mine = {m["name"]: m for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]}
-    assert set(mine) == {n + SUFFIX for n in (*TWINS, OWN)}
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(mine):] == list(mine)         # appended, in one block
-    assert len(bench["per_layer"]) <= 128 and len(bench["workloads"]) <= 24
-    assert not any(n.startswith(("decode_delivered_share", "prefill_fill",
-                                 "decode_overrun_share", "prefix_hit_share"))
-                   for n in mine)
-    layers = {m["layer"] for m in bench["per_layer"]
-              if m.get("workloads") != [CELL]}
-    for name, m in mine.items():
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert NAME.match(name) and m["moves"] == "serve_tokens_per_s"
-        assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", m["unit"])
-        assert m["layer"] in layers and harness.load_reader(name) is not None
-        base = name[:-len(SUFFIX)]
-        if base == OWN:
+    bench_pins.check_reduced(entry, config, PUBLISHED)
+    assert sorted(entry["reduced"]) == sorted(REDUCED)
+    assert config["name"] == entry["name"]
+    assert set(bench_pins.reported(bench, CELL, "end_to_end")) == {
+        "serve_tokens_per_s", "setup_s"}
+    moved = bench_pins.entry(bench["end_to_end"], "serve_tokens_per_s")
+    assert CELL in moved["workloads"] and moved["bound"] == 0.045
+    mine = bench_pins.reports(bench, CELL, (*TWINS, OWN),
+                              moves="serve_tokens_per_s")
+    assert len(bench["per_layer"]) < 64 and len(bench["workloads"]) <= 24
+    # a recurrent state has no prefix to share
+    assert "prefix_hit_share" not in bench_pins.reported(bench, CELL)
+    for stem, m in mine.items():
+        if stem == OWN:
             assert (m["source"], m["better"], m["unit"], m["layer"]) == (
                 "device_trace", "lower", "%", "routed experts")
-        else:
-            twin = next(x for x in bench["per_layer"]
-                        if x["name"] == base + TWINS[base])
-            assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
-                == {k: twin[k] for k in ("unit", "better", "source", "layer")}
+        else:                   # one entry, shared with a cell before
+            assert TWINS[stem] in m["workloads"]
     with open(os.path.join(ROOT, "BENCHMARK.json"), "rb") as f:
         assert len(f.read()) <= 64 * 1024
 
@@ -562,11 +546,12 @@ def test_toy_nano_rehearses_the_cells_runner(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
                JAX_COMPILATION_CACHE_DIR=os.path.join(root, ".jax_cache"))
-    names = ["experts_touched_mean.nano", "routed_here_share.nano",
-             "expert_load_max_over_mean.nano", "engine_host_share.nano",
-             "ssm_mixer_share.nano", "ssm_state_roofline.nano",
-             "prefill_scan_share.nano", "prefill_expert_share.nano",
-             "paged_attn_roofline.nano", "expert_ffn_share.nano"]
+    names = ["experts_touched_mean", "routed_here_share",
+             "expert_load_max_over_mean", "engine_host_share",
+             "decode_active_share",
+             "ssm_mixer_share", "ssm_state_roofline",
+             "prefill_scan_share", "prefill_expert_share",
+             "paged_attn_roofline", "expert_ffn_share"]
     r = subprocess.run(
         [sys.executable, "-c", DRIVER, str(2 ** 31 + 5), json.dumps(names)],
         cwd=root, env=env, capture_output=True, text=True, timeout=900)
@@ -578,17 +563,16 @@ def test_toy_nano_rehearses_the_cells_runner(tmp_path):
     assert rehearsal["correct"] is True and rehearsal["failed"] == 0
     assert rehearsal["attempted"] > 0
     # a rehearsal prints counters only, and this cell has no prefix to hit
-    assert set(rehearsal["metrics"]) == {"batch_occupancy.nano",
-                                         "compiles_in_window"}
+    assert set(rehearsal["metrics"]) == {"compiles_in_window"}
     assert rehearsal["metrics"]["compiles_in_window"]["value"] <= 1.0
-    assert rehearsal["metrics"]["batch_occupancy.nano"]["value"] > 50.0
     values = got["values"]
-    assert all(values[n] is None for n in names[4:])    # no device trace
+    assert values["decode_active_share"] > 50.0
+    assert all(values[n] is None for n in names[5:])    # no device trace
     # the chunks' means over the FOUR layers that report them: up to 4
     # live tokens of 3 choices over 8 experts, 4 of them held
-    assert 0.0 < values["experts_touched_mean.nano"] <= 4.0
-    assert 0.0 < values["routed_here_share.nano"] < 100.0
-    assert values["expert_load_max_over_mean.nano"] >= 1.0
+    assert 0.0 < values["experts_touched_mean"] <= 4.0
+    assert 0.0 < values["routed_here_share"] < 100.0
+    assert values["expert_load_max_over_mean"] >= 1.0
     attrs = got["attrs"]
     # float32 S [8, 8, 16] and a bf16 tail [3, 192] in each of 4 layers
     slot_bytes = 4 * (4 * 8 * 8 * 16 + 2 * 3 * 192)

@@ -1,6 +1,8 @@
-"""The reader of ``paged_attn_kernel_share.*`` (PR 30) on synthetic traces:
-it finds the paged decode-attention kernel by its instruction's name inside
-the decode programs' runs, and reports nothing for a program without it."""
+"""The reader of ``paged_attn_roofline`` (PR 33; in every cell whose decode
+program holds the kernel since PR 49, where ``paged_attn_kernel_share``, PR
+30's reading of the same kernel's time, went) on synthetic traces: it finds
+the paged decode-attention kernel by its instruction's name inside the
+decode programs' runs, and reports nothing for a program without it."""
 
 import json
 import os
@@ -10,9 +12,10 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_pins  # noqa: E402
 import bench_toy  # noqa: E402
 
-from benchmark import harness, inside  # noqa: E402
+from benchmark import flops, harness, inside, systems  # noqa: E402
 from benchmark.trace import Trace  # noqa: E402
 
 DECODE = "jit_paged_decode_c8_w16(1234)"
@@ -52,13 +55,32 @@ def synthetic_trace(runs: int = 6, kernel: bool = True) -> Trace:
                  extent_s=t + 0.010)
 
 
-@pytest.mark.parametrize("metric", ["paged_attn_kernel_share.doc",
-                                    "paged_attn_kernel_share.chat",
-                                    "paged_attn_kernel_share.moe"])
-def test_kernel_share_is_the_named_kernel_inside_decode_runs(metric):
-    read = harness.load_reader(metric)
-    run = type("Run", (), {"trace": synthetic_trace()})
-    assert read(run) == pytest.approx(20.0)
+KERNEL_CELLS = {"serve-doc": "serve_tokens_per_s",
+                "serve-chat": "tpot_p90_ms",
+                "serve-moe-gen": "serve_tokens_per_s",
+                "serve-code-gen": "serve_tokens_per_s",
+                "serve-instruct-gen": "serve_tokens_per_s",
+                "serve-reason-gen": "serve_tokens_per_s"}
+
+
+@pytest.mark.parametrize("cell", sorted(KERNEL_CELLS))
+def test_the_roofline_is_the_named_kernel_inside_decode_runs(cell):
+    """Two calls of 1 ms in each 10 ms run of a chunk of 8: the kernel
+    takes 20% of a 1.25 ms step, and the share is the family's bytes of
+    keys and values over the bandwidth over that."""
+    bench, _, config, _ = harness.load_cell(cell)
+    name = bench_pins.reports(bench, cell, ["paged_attn_roofline"])[
+        "paged_attn_roofline"]["name"]
+    read = harness.load_reader(name)
+    counters = {"live_kv_tokens_mean": 20000.0,
+                "occupancy_samples": [24, 24]}
+    run = type("Run", (), {
+        "trace": synthetic_trace(), "config": config, "counters": counters,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite"}})
+    nbytes = systems.family(config).attention_kv_bytes(config, counters)
+    want = (100.0 * nbytes / flops.peaks("TPU v5 lite")["hbm_bytes_per_s"]
+            / (0.2 * 1.25e-3))
+    assert nbytes > 0 and read(run) == pytest.approx(want)
     # a program without the kernel (the parent's), too few calls to tell,
     # no trace (a rehearsal), no device: nothing, and no exception
     run.trace = synthetic_trace(kernel=False)
@@ -71,20 +93,13 @@ def test_kernel_share_is_the_named_kernel_inside_decode_runs(metric):
     assert read(run) is None
 
 
-def test_the_entries_name_one_cell_each():
-    """Found by name, wherever later PRs' entries put them."""
-    with open(os.path.join(bench_toy.REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    last = [m for m in bench["per_layer"]
-            if m["name"].startswith("paged_attn_kernel_share.")]
-    assert [(m["name"], m["workloads"], m["moves"]) for m in last] == [
-        ("paged_attn_kernel_share.doc", ["serve-doc"], "serve_tokens_per_s"),
-        ("paged_attn_kernel_share.chat", ["serve-chat"], "tpot_p90_ms"),
-        ("paged_attn_kernel_share.moe", ["serve-moe-gen"],
-         "serve_tokens_per_s")]
-    for m in last:
-        assert (m["layer"], m["source"], m["unit"]) == (
-            "kernels", "device_trace", "%")
-        # the cell reports the end-to-end metric the share moves
-        moved = next(e for e in bench["end_to_end"] if e["name"] == m["moves"])
-        assert m["workloads"][0] in moved["workloads"]
+@pytest.mark.parametrize("cell", sorted(KERNEL_CELLS))
+def test_the_entries_name_their_cells(bench, cell):
+    """Found by name with the cell under ``workloads``, wherever later
+    PRs' entries stand; ``paged_attn_kernel_share`` is in no cell."""
+    m = bench_pins.reports(bench, cell, ["paged_attn_roofline"],
+                           moves=KERNEL_CELLS[cell])["paged_attn_roofline"]
+    assert (m["layer"], m["source"], m["unit"], m["better"]) == (
+        "kernels", "device_trace", "%", "higher")
+    assert not any(n.startswith("paged_attn_kernel_share")
+                   for n in bench_pins.reported(bench, cell))
