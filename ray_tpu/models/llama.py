@@ -232,6 +232,13 @@ class LayerStack(NamedTuple):
     attends: bool = True
     # whether the layers end in a feed-forward
     feeds: bool = True
+    # whether that feed-forward takes something of the layer's INPUT, the
+    # stream BEFORE the attention (a router that reads the rows the
+    # attention's projections read): the module's ``feed_ahead(cfg, p,
+    # x)`` is then called where the layer begins and what it gives is
+    # handed to ``feed_forward`` as ``ahead=`` where the layer ends.
+    # False: the feed-forward sees the stream behind the attention alone
+    ahead: bool = False
 
 
 def layer_plan(cfg) -> tuple:

@@ -73,14 +73,14 @@ _VMEM_LIMIT_BYTES = 64 << 20
 _LANES = 128
 
 
-def hidden_activation(into, wi_gate, wi_up):
+def hidden_activation(into, wi_gate, wi_up, gate_act=jax.nn.silu):
     """An expert's hidden activations in float32, by its form: ``into(w)``
     is the rows' float32 product with ``w`` (a stack, a matrix, a block of
-    one). With a gate ``silu(x @ gate) * (x @ up)``; without one (None)
-    ``relu(x @ up) ** 2``."""
+    one). With a gate ``gate_act(x @ gate) * (x @ up)`` (SiLU: a SwiGLU;
+    ``jax.nn.relu``: a ReGLU); without one (None) ``relu(x @ up) ** 2``."""
     if wi_gate is None:
         return jnp.square(jax.nn.relu(into(wi_up)))
-    return jax.nn.silu(into(wi_gate)) * into(wi_up)
+    return gate_act(into(wi_gate)) * into(wi_up)
 
 
 def group_visits(load, rows: int, tile: int = ROW_TILE):
@@ -190,9 +190,11 @@ def grouped_matmul(x, stacks, layer, visits, *, epilogue, out_dtype,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), *visits, x, *stacks)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("gate_act", "tile", "interpret"))
 def grouped_expert_ffn_kernel(xs, load, wi_gate, wi_up, wo, layer, *,
-                              tile=ROW_TILE, interpret=False):
+                              gate_act=jax.nn.silu, tile=ROW_TILE,
+                              interpret=False):
     """The kernel's launch; arguments and result as
     ``grouped_expert_ffn_reference``, but for the rows of no group, which
     hold anything. The stacks go in whole and the layer's index through
@@ -203,7 +205,8 @@ def grouped_expert_ffn_kernel(xs, load, wi_gate, wi_up, wo, layer, *,
         ups, hidden = (wi_up,), lambda into, up: hidden_activation(
             into, None, up)
     else:
-        ups, hidden = (wi_gate, wi_up), hidden_activation
+        ups, hidden = (wi_gate, wi_up), functools.partial(
+            hidden_activation, gate_act=gate_act)
     h = grouped_matmul(xs, ups, layer, visits, epilogue=hidden,
                        out_dtype=xs.dtype, tile=tile, interpret=interpret)
     return grouped_matmul(h, (wo,), layer, visits,
@@ -212,11 +215,13 @@ def grouped_expert_ffn_kernel(xs, load, wi_gate, wi_up, wo, layer, *,
                           interpret=interpret)
 
 
-def grouped_expert_ffn_reference(xs, load, wi_gate, wi_up, wo, layer):
+def grouped_expert_ffn_reference(xs, load, wi_gate, wi_up, wo, layer, *,
+                                 gate_act=jax.nn.silu):
     """``xs`` [M, D]: rows sorted by held expert, ``load`` [H] of them a
     group; the stacks of a run of layers, of which this call is layer
     ``layer``'s: ``wi_gate`` ([L, H, D, F], or None for a form without a
-    gate), ``wi_up`` [L, H, D, F], ``wo`` [L, H, F, D]. Returns float32
+    gate; ``gate_act``: the gate's activation), ``wi_up`` [L, H, D, F],
+    ``wo`` [L, H, F, D]. Returns float32
     [M, D]: row i of group e is ``act(xs[i] @ up[e]) @ down[e]``, the
     hidden activations rounded to ``xs``'s type between the two; rows in
     no group are zero. A loop over the experts, each over all rows and
@@ -229,7 +234,7 @@ def grouped_expert_ffn_reference(xs, load, wi_gate, wi_up, wo, layer):
     def one(ys, e):
         h = hidden_activation(
             lambda w: jnp.dot(xs, w[e], preferred_element_type=jnp.float32),
-            wi_gate, wi_up)
+            wi_gate, wi_up, gate_act)
         y = jnp.dot(h.astype(xs.dtype), wo[e],
                     preferred_element_type=jnp.float32)
         mine = (row >= ends[e] - load[e]) & (row < ends[e])

@@ -25,12 +25,18 @@ expert parallelism: told which experts it holds (expert stacks narrower
 than the router, and the first held expert's index), it routes over all
 of them and computes the held experts' part of the result, without the
 exchange that would add the other chips' parts. Its experts are of the
-form it is told (``EXPERT_FORMS``): a SwiGLU of three matrices, or two
-matrices with a squared ReLU between them and no gate. (Reference has NO
-MoE implementation — SURVEY.md §2c row EP.)
+form it is told (``EXPERT_FORMS``): a SwiGLU of three matrices, the same
+three with a ReLU gate (a ReGLU), or two matrices with a squared ReLU
+between them and no gate. Its two halves stand alone too: ``moe_route``
+scores rows and chooses, ``moe_experts`` applies a choice, so that a block
+whose router reads the layer's INPUT makes the choice before its attention
+and uses it after (``moe_ffn_dropless`` is the two in one call, the router
+scoring ``x``). (Reference has NO MoE implementation — SURVEY.md §2c row EP.)
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -170,9 +176,104 @@ def moe_ffn(
 DENSE_MAX_TOKENS = 256
 
 # What one expert computes of a token ``x``, by the matrices it has:
-# ``swiglu``: ``(silu(x @ gate) * (x @ up)) @ down``; ``relu2``:
-# ``relu(x @ up) ** 2 @ down``, which has no gate (``wi_gate`` is None).
-EXPERT_FORMS = ("swiglu", "relu2")
+# ``swiglu``: ``(silu(x @ gate) * (x @ up)) @ down``; ``reglu``: ``(relu(x
+# @ gate) * (x @ up)) @ down``; ``relu2``: ``relu(x @ up) ** 2 @ down``,
+# which has no gate (``wi_gate`` is None). A gated form's gate activation:
+EXPERT_FORMS = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu, "relu2": None}
+
+
+def moe_route(
+    rows,               # [T, D]: what the router scores
+    router_w,           # [D, E]
+    *,
+    top_k: int,
+    norm_topk_prob: bool = False,
+    routed_scale: float = 1.0,
+    scoring: str = "softmax",
+    choice_bias=None,   # [E] float32, added to the scores for the choice
+):
+    """The router's choice for each of ``rows``: (weights [T, K] float32,
+    experts [T, K] int32 among the router's E), as ``moe_ffn_dropless``
+    says. What ``moe_experts`` takes, here or further down the block."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
+                         f"got {scoring!r}")
+    with jax.named_scope("moe_router"):
+        # true float32: the chip's default would round the products to
+        # bf16 and now and then pick another k-th expert than float32 does
+        logits = jnp.dot(rows.astype(jnp.float32),
+                         router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = (jax.nn.softmax(logits, axis=-1) if scoring == "softmax"
+                 else jax.nn.sigmoid(logits))
+        if choice_bias is None:
+            gate_vals, gate_idx = jax.lax.top_k(probs, top_k)  # [T, K]
+        else:
+            _, gate_idx = jax.lax.top_k(
+                probs + choice_bias.astype(jnp.float32), top_k)
+            gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+        if norm_topk_prob:
+            total = jnp.sum(gate_vals, axis=-1, keepdims=True)
+            if scoring == "sigmoid":
+                total = total + 1e-20    # sigmoids can all be 0; a
+            gate_vals = gate_vals / total   # softmax's top-k cannot
+        if routed_scale != 1.0:
+            gate_vals = gate_vals * routed_scale
+    return gate_vals, gate_idx
+
+
+def moe_experts(
+    x,                  # [T, D] tokens (flattened batch*seq)
+    choice,             # ``moe_route``'s (weights, experts) of the T rows
+    wi_gate,            # [H, D, F]: the H <= E experts held here (None
+    wi_up,              # [H, D, F]   where the experts' form has no gate)
+    wo,                 # [H, F, D]
+    *,
+    n_experts: int,     # E, the router's width
+    first_expert: int = 0,
+    valid=None,         # [T] bool: rows that are tokens (None: all)
+    form: str = "swiglu",   # one expert's form: ``EXPERT_FORMS``
+    layer=None,         # the stacks are [L, H, ..]: this call is layer's
+):
+    """The held experts' weighted sum for a choice made of the same T
+    rows (of ``x`` itself, or of other rows of theirs: the layer's input
+    where the router reads that). Returns (out [T, D], load [H]); every
+    argument as ``moe_ffn_dropless`` says."""
+    if form not in EXPERT_FORMS or (wi_gate is None) != (form == "relu2"):
+        raise ValueError(f"form must be one of {tuple(EXPERT_FORMS)}, with "
+                         f"no gate for 'relu2' alone; got {form!r} and "
+                         f"wi_gate {'None' if wi_gate is None else 'given'}")
+    gate_vals, gate_idx = choice
+    t = x.shape[0]
+    e = wi_up.shape[-3]
+    dtype = x.dtype
+    gate_act = EXPERT_FORMS[form]       # None: the form has no gate
+    with jax.named_scope("moe_router"):
+        if e < n_experts:
+            # a share: experts by their place in the stacks held here, and
+            # every absent one as ``e``, which is no expert's place
+            gate_idx = gate_idx - first_expert
+            gate_idx = jnp.where((gate_idx >= 0) & (gate_idx < e), gate_idx, e)
+        chosen = gate_idx[:, :, None] == jnp.arange(e)        # [T, K, H]
+        if valid is not None:
+            chosen = chosen & valid[:, None, None]
+        load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)  # [E]
+    with jax.named_scope("moe_experts"):
+        if not expert_kernel_engages(t):
+            if layer is not None:
+                wi_gate, wi_up, wo = (w if w is None else w[layer]
+                                      for w in (wi_gate, wi_up, wo))
+            weights = jnp.sum(jnp.where(chosen, gate_vals[:, :, None], 0.0),
+                              axis=1)                          # [T, E]
+            out = _experts_all(x, weights, wi_gate, wi_up, wo, gate_act)
+        else:
+            if layer is None:       # one layer's stacks: a run of one
+                layer = 0
+                wi_gate, wi_up, wo = (w if w is None else w[None]
+                                      for w in (wi_gate, wi_up, wo))
+            out = _experts_grouped(x, gate_vals, gate_idx, valid, load,
+                                   wi_gate, wi_up, wo, layer, gate_act)
+    return out.astype(dtype), load
 
 
 def moe_ffn_dropless(
@@ -203,6 +304,7 @@ def moe_ffn_dropless(
     probabilities as they are, or divided by their sum with
     ``norm_topk_prob``, times ``routed_scale``;
     ``out = sum_k p_k * (silu(x @ gate_k) * (x @ up_k)) @ down_k`` (with
+    ``form="reglu"`` the gate's activation is a ReLU; with
     ``form="relu2"``: ``sum_k p_k * relu(x @ up_k) ** 2 @ down_k``) over
     those of a token's chosen experts that are HELD here: experts
     ``first_expert`` to ``first_expert + H`` of the router's E, H the
@@ -214,63 +316,18 @@ def moe_ffn_dropless(
     of a run of layers, [L, H, ..], and the call is that layer's: what a
     program that scans the run hands over, so that the grouped kernel
     reads the layer's weights where they lie (a layer sliced out of the
-    scan's stacks to feed a kernel is a copy of it).
+    scan's stacks to feed a kernel is a copy of it). A block whose router
+    reads other rows than its experts (the layer's input, with the
+    experts behind the attention) calls ``moe_route`` there and
+    ``moe_experts`` here.
     """
-    if scoring not in ("softmax", "sigmoid"):
-        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
-                         f"got {scoring!r}")
-    if form not in EXPERT_FORMS or (wi_gate is None) != (form == "relu2"):
-        raise ValueError(f"form must be one of {EXPERT_FORMS}, with a gate "
-                         f"for 'swiglu' alone; got {form!r} and wi_gate "
-                         f"{'None' if wi_gate is None else 'given'}")
-    t, d = x.shape
-    e = wi_up.shape[-3]
-    dtype = x.dtype
-    with jax.named_scope("moe_router"):
-        # true float32: the chip's default would round the products to
-        # bf16 and now and then pick another k-th expert than float32 does
-        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        probs = (jax.nn.softmax(logits, axis=-1) if scoring == "softmax"
-                 else jax.nn.sigmoid(logits))
-        if choice_bias is None:
-            gate_vals, gate_idx = jax.lax.top_k(probs, top_k)  # [T, K]
-        else:
-            _, gate_idx = jax.lax.top_k(
-                probs + choice_bias.astype(jnp.float32), top_k)
-            gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
-        if norm_topk_prob:
-            total = jnp.sum(gate_vals, axis=-1, keepdims=True)
-            if scoring == "sigmoid":
-                total = total + 1e-20    # sigmoids can all be 0; a
-            gate_vals = gate_vals / total   # softmax's top-k cannot
-        if routed_scale != 1.0:
-            gate_vals = gate_vals * routed_scale
-        if e < router_w.shape[1]:
-            # a share: experts by their place in the stacks held here, and
-            # every absent one as ``e``, which is no expert's place
-            gate_idx = gate_idx - first_expert
-            gate_idx = jnp.where((gate_idx >= 0) & (gate_idx < e), gate_idx, e)
-        chosen = gate_idx[:, :, None] == jnp.arange(e)        # [T, K, H]
-        if valid is not None:
-            chosen = chosen & valid[:, None, None]
-        load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)  # [E]
-    with jax.named_scope("moe_experts"):
-        if not expert_kernel_engages(t):
-            if layer is not None:
-                wi_gate, wi_up, wo = (w if w is None else w[layer]
-                                      for w in (wi_gate, wi_up, wo))
-            weights = jnp.sum(jnp.where(chosen, gate_vals[:, :, None], 0.0),
-                              axis=1)                          # [T, E]
-            out = _experts_all(x, weights, wi_gate, wi_up, wo)
-        else:
-            if layer is None:       # one layer's stacks: a run of one
-                layer = 0
-                wi_gate, wi_up, wo = (w if w is None else w[None]
-                                      for w in (wi_gate, wi_up, wo))
-            out = _experts_grouped(x, gate_vals, gate_idx, valid, load,
-                                   wi_gate, wi_up, wo, layer)
-    return out.astype(dtype), load
+    choice = moe_route(
+        x, router_w, top_k=top_k, norm_topk_prob=norm_topk_prob,
+        routed_scale=routed_scale, scoring=scoring, choice_bias=choice_bias)
+    return moe_experts(x, choice, wi_gate, wi_up, wo,
+                       n_experts=router_w.shape[1],
+                       first_expert=first_expert, valid=valid, form=form,
+                       layer=layer)
 
 
 def expert_kernel_engages(rows: int) -> bool:
@@ -299,7 +356,7 @@ def share_statistics(load, valid, rows: int, top_k: int) -> dict:
     }
 
 
-def _experts_all(x, weights, wi_gate, wi_up, wo):
+def _experts_all(x, weights, wi_gate, wi_up, wo, gate_act):
     """Every expert over every token; ``weights`` [T, E] is zero where an
     expert was not chosen. The tokens are broadcast along the expert axis
     so that the up projections are plain batched matmuls, and the down
@@ -309,14 +366,14 @@ def _experts_all(x, weights, wi_gate, wi_up, wo):
     h = hidden_activation(
         lambda w: jnp.einsum("etd,edf->etf", xe, w,
                              preferred_element_type=jnp.float32),
-        wi_gate, wi_up)
+        wi_gate, wi_up, gate_act)
     h = (h * weights.T[:, :, None]).astype(x.dtype)
     return jnp.einsum("etf,efd->td", h, wo,
                       preferred_element_type=jnp.float32)
 
 
 def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
-                     wo, layer):
+                     wo, layer, gate_act):
     """The chosen experts alone: the T x K (token, choice) pairs sorted by
     held expert (a choice on an absent expert and a padding row last, in
     no group), the experts over their groups (``ops.grouped_expert_ffn``:
@@ -331,7 +388,9 @@ def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
     xs = x[order // k]                             # [T*K, D]
     ys = jax.lax.platform_dependent(
         xs, load, wi_gate, wi_up, wo, layer,
-        tpu=grouped_expert_ffn_kernel, default=grouped_expert_ffn_reference)
+        tpu=functools.partial(grouped_expert_ffn_kernel, gate_act=gate_act),
+        default=functools.partial(grouped_expert_ffn_reference,
+                                  gate_act=gate_act))
     # each pair's row back beside its token; a pair in no group (its row
     # holds anything) adds nothing. Weighing and masking after the gather
     # fuse into the sum: no pass of their own over the [T*K, D] rows

@@ -110,20 +110,24 @@ def _pow2_floor(n: int) -> int:
 
 def query_block(n: int, t: int, heads: int, keys: int, window) -> int:
     """How many of a prefill's ``t`` query positions a row attends with at
-    once in the plain formulation (``t``: all of them). A sliding layer
-    goes window by window: a block of ``window`` queries sees two windows
-    of keys, whatever ``t``. A full layer goes whole while its scores fit
-    ``SCORES_MAX_BYTES``, and past that in blocks whose scores are a
-    quarter of it."""
+    once in the plain formulation (``t``: all of them). A full layer goes
+    whole while its scores fit ``SCORES_MAX_BYTES``, and past that in
+    blocks whose scores are a quarter of it. A sliding layer goes window
+    by window, a block of ``window`` queries over the two windows of keys
+    it can see, whatever ``t``, under the same bound: where those scores
+    (of the ``t`` queries, where they are fewer than a window) pass
+    ``SCORES_MAX_BYTES``, in blocks of fewer queries, each over its
+    ``block + window`` keys, whose scores are a quarter of it. That is a
+    window of thousands of keys, or a wide group under a small one: at a
+    window of 512 and 72 heads, groups of 8 rows and more
+    (``4 * n * 72 * 512 * 1024``), which go in blocks of 128; up to 7
+    rows a window of 512 goes as it did before the bound."""
     def scores(block):
-        return 4 * n * heads * block * keys
+        seen = keys if window is None else block + window
+        return 4 * n * heads * block * seen
 
-    if window is not None:
-        block = 1 << (window - 1).bit_length()
-    elif scores(t) <= SCORES_MAX_BYTES:
-        return t
-    else:
-        block = t
+    block = t if window is None else 1 << (window - 1).bit_length()
+    if scores(min(block, t)) > SCORES_MAX_BYTES:
         while block > 16 and scores(block) > SCORES_MAX_BYTES // 4:
             block //= 2
     return block if block < t and t % block == 0 else t

@@ -71,7 +71,12 @@ runs (``EnginePrograms.prefill_kernels``, ``decode_kernels``).
 - What is the MODEL's comes from the model's module (``_model_module``).
   What is the ENGINE's is here, once for every model: the page write,
   the attention over the pages, the scans over the plan's runs,
-  sampling, the chunk loop.
+  sampling, the chunk loop. A block is projections, attention, its
+  output, the feed-forward on the stream behind it; where a run's
+  feed-forward needs something of the layer's INPUT too (a router that
+  reads the rows the projections read), the plan says so
+  (``LayerStack.ahead``) and the block asks the module for it before the
+  attention and hands it on after (``_ahead``).
 """
 
 from __future__ import annotations
@@ -174,6 +179,7 @@ _KV_PIECES = ("attention_projections",)
 _LATENT_PIECES = ("latent_projections",)
 _RECURRENT_PIECES = ("recurrent_mixer", "recurrent_step")
 _FEED_PIECES = ("feed_forward",)
+_AHEAD_PIECES = ("feed_ahead",)
 
 
 def _model_module(cfg):
@@ -183,7 +189,9 @@ def _model_module(cfg):
     run attends, what attention takes in (as q, k and v where the run
     keeps K/V twins, as a latent's inputs where it keeps rows), its
     rotary tables and its end; the mixer's two forms where a run holds a
-    recurrent mixer; the feed-forward where a run ends in one."""
+    recurrent mixer; the feed-forward where a run ends in one, and what
+    it takes of the layer's input where a run states that
+    (``LayerStack.ahead``)."""
     model = sys.modules.get(type(cfg).__module__)
     missing = [name for name in _PIECES if not hasattr(model, name)]
     if "layer_plan" not in missing:
@@ -194,7 +202,8 @@ def _model_module(cfg):
             + _KV_PIECES * any(run.rows is None for run in attends)
             + _LATENT_PIECES * any(run.rows is not None for run in attends)
             + _RECURRENT_PIECES * (_recurrent(plan) is not None)
-            + _FEED_PIECES * any(run.feeds for run in plan))
+            + _FEED_PIECES * any(run.feeds for run in plan)
+            + _AHEAD_PIECES * any(run.ahead for run in plan))
         missing += [name for name in asked if not hasattr(model, name)]
     if missing:
         raise TypeError(
@@ -263,6 +272,15 @@ def _plan_runs(plan, blocks, fuse=None) -> list:
         blocks = fuse(blocks)
     return [(blocks if run.key is None else blocks[run.key], idx)
             for run, idx in zip(plan, layers)]
+
+
+def _ahead(model, cfg, run, p, x) -> dict:
+    """What a layer's feed-forward takes of the layer's input ``x``, as
+    the keyword it is handed over under: made HERE, where the layer
+    begins (a router's choice then stands before the attention in the
+    program), for a run that states it (``LayerStack.ahead``); nothing
+    for any other."""
+    return {"ahead": model.feed_ahead(cfg, p, x)} if run.ahead else {}
 
 
 def _routes(run, weights) -> bool:
@@ -558,6 +576,7 @@ def _paged_decode_impl(cfg, params, *args, chunk, page_size,
             x, *rest = carry
             state = rest[n_pools:]
             p, layer = xs
+            ahead = _ahead(model, cfg, run, p, x)
 
             def mixer_step():
                 # the mixer on the layer's input, over the slots'
@@ -603,7 +622,7 @@ def _paged_decode_impl(cfg, params, *args, chunk, page_size,
             stats = {}
             if run.feeds:
                 x, stats = model.feed_forward(cfg, p, x,
-                                              valid=active[:, None])
+                                              valid=active[:, None], **ahead)
             return (x, *rest[:n_pools], *state), stats
 
         carry = (x, *pools, *state)
@@ -676,6 +695,7 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
         x, *rest = carry
         state = rest[n_pools:]
         p, layer, *at = xs
+        ahead = _ahead(model, cfg, run, p, x)
 
         def mixer_pass():
             """The mixer over the rows from the zero state, and each
@@ -717,7 +737,7 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
             x = x + mixed
         if run.feeds:
             x, _ = model.feed_forward(
-                cfg, p, x, valid=valid,
+                cfg, p, x, valid=valid, **ahead,
                 **({"stacked": (stacked, at[0])} if at else {}))
         return (x, *rest[:n_pools], *state), None
 

@@ -1031,8 +1031,11 @@ class PagedLLMEngine:
                        kv_rows_full=int(rows.sum()))
                 programs = self._programs
                 if programs.window is not None:
+                    # and the live slots whose context has passed it
                     ph.set(kv_rows_window=int(
-                        np.minimum(rows, programs.window).sum()))
+                               np.minimum(rows, programs.window).sum()),
+                           slots_past_window=int(
+                               (rows > programs.window).sum()))
                 if programs.selects is not None:
                     # a layer with an indexer scores every row's index
                     # key and attends over the rows it picks: gathered,

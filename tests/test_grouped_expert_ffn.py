@@ -1,6 +1,6 @@
 """The grouped expert kernel (``ops/grouped_expert_ffn.py``) on the CPU
-(``interpret=True``) against a loop over the experts in float32: both
-forms, one layer of a run's stacks, groups that are empty, several tiles
+(``interpret=True``) against a loop over the experts in float32: every
+form, one layer of a run's stacks, groups that are empty, several tiles
 long or everything, rows behind the last group, a last tile that is
 partial; the walk's index arithmetic; the column blocks at the
 benchmark's widths; the rule."""
@@ -16,7 +16,7 @@ from ray_tpu.ops import moe
 TILE = 16
 
 
-def _loop_over_experts(xs, load, gate, up, down):
+def _loop_over_experts(xs, load, gate, up, down, form="swiglu"):
     """Row i of group e through expert e, in float64; rows in no group
     zero."""
     xs, up, down = (np.asarray(a, np.float64) for a in (xs, up, down))
@@ -28,7 +28,9 @@ def _loop_over_experts(xs, load, gate, up, down):
             h = np.maximum(rows @ up[e], 0.0) ** 2
         else:
             g = rows @ np.asarray(gate, np.float64)[e]
-            h = g / (1.0 + np.exp(-g)) * (rows @ up[e])
+            act = (np.maximum(g, 0.0) if form == "reglu"
+                   else g / (1.0 + np.exp(-g)))
+            h = act * (rows @ up[e])
         out[start:start + n] = h @ down[e]
         start += n
     return out
@@ -42,7 +44,7 @@ def _stacks(form, experts, d, f, seed=0, dtype=jnp.float32):
     below are layer ``LAYER``'s, which ``_layer`` picks out."""
     ks = jax.random.split(jax.random.key(seed), 3)
     gate = (jax.random.normal(ks[0], (LAYERS, experts, d, f)) * d ** -0.5
-            ).astype(dtype) if form == "swiglu" else None
+            ).astype(dtype) if form != "relu2" else None
     up = (jax.random.normal(ks[1], (LAYERS, experts, d, f)) * d ** -0.5
           ).astype(dtype)
     down = (jax.random.normal(ks[2], (LAYERS, experts, f, d)) * f ** -0.5
@@ -80,15 +82,17 @@ def test_the_kernel_against_a_loop_over_experts(form, case):
     # whatever lies behind the last group must reach no held row
     xs = xs.at[held:].set(jnp.nan)
     load = jnp.asarray(load, jnp.int32)
+    act = {"gate_act": moe.EXPERT_FORMS[form]}
     got = gef.grouped_expert_ffn_kernel(xs, load, gate, up, down, LAYER,
-                                        tile=TILE, interpret=True)
+                                        tile=TILE, interpret=True, **act)
     assert got.shape == (rows, d) and got.dtype == jnp.float32
-    want = _loop_over_experts(xs, load, *_layer(gate, up, down))[:held]
+    want = _loop_over_experts(xs, load, *_layer(gate, up, down),
+                              form=form)[:held]
     np.testing.assert_allclose(np.asarray(got)[:held], want, rtol=2e-5,
                                atol=2e-5)
     # and the plain sorted formulation is the same function of its groups
     plain = gef.grouped_expert_ffn_reference(
-        jnp.nan_to_num(xs), load, gate, up, down, LAYER)
+        jnp.nan_to_num(xs), load, gate, up, down, LAYER, **act)
     np.testing.assert_allclose(np.asarray(plain)[:held], want, rtol=2e-5,
                                atol=2e-5)
     assert not np.asarray(plain)[held:].any()
@@ -103,9 +107,11 @@ def test_the_kernel_in_bf16_accumulates_in_float32(form):
     gate, up, down = _stacks(form, len(load), 64, 128, dtype=jnp.bfloat16)
     xs = jax.random.normal(jax.random.key(2), (rows, 64), jnp.bfloat16)
     load = jnp.asarray(load, jnp.int32)
+    act = {"gate_act": moe.EXPERT_FORMS[form]}
     got = gef.grouped_expert_ffn_kernel(xs, load, gate, up, down, LAYER,
-                                        tile=TILE, interpret=True)
-    want = gef.grouped_expert_ffn_reference(xs, load, gate, up, down, LAYER)
+                                        tile=TILE, interpret=True, **act)
+    want = gef.grouped_expert_ffn_reference(xs, load, gate, up, down, LAYER,
+                                            **act)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
 
