@@ -13,11 +13,33 @@ holds it, and stops at each slot's length:
 - one invocation walks the live slots in order and, per slot, the pages
   up to ``ceil(keys / page)``. A page [page, nkv, hd] is contiguous in
   the pool: ONE async copy brings every KV head of it into VMEM, double
-  buffered, and the copy of the next page (of this slot or of the next
-  live slot) is in flight while this one is computed. A slot with no
+  buffered, and the copies of the next step (of this slot or of the next
+  live slot) are in flight while this one is computed. A slot with no
   keys (inactive) costs nothing; pages past a slot's length are neither
   fetched nor computed;
-- the page is read as the rows [page * nkv, hd]: ONE matmul scores every
+- a STEP of the walk takes 1,024 rows ``page * nkv`` (``step_pages``, a
+  rule on the pool's shape and type alone): at a page of 128 tokens one
+  page at 8 and 16 KV heads, two at 4, four at 2. A step's serial chain
+  (wait K, scores, row maximum, exponentials, row sum, wait V, weighted
+  sum, the update of the softmax state) with one step in flight behind
+  it does not hide behind the step's copies when the step is small: a
+  one-page step at 4 KV heads took 0.80 us where its copies alone take
+  0.40 and its arithmetic alone 0.58, 40% of its 262 KB at 819 GB/s, and
+  0.84 us at 2 KV heads, 19% of 131 KB; with 1,024 rows behind every
+  wait a step takes 0.78 us beside copies of 0.71, 82% (chip, PR 52:
+  ``PERF.md``, Findings). Where a step takes more
+  than a page the pool is handed over as rows, [L, P, page * nkv, hd],
+  which is how it lies (a bitcast where ``nkv`` fills a tile's sublanes:
+  the rule takes more pages nowhere else), each page's copy lands in its
+  rows of the step's buffer, and the matmuls read the buffer as it is:
+  read as [page, nkv, hd] and reshaped, a page costs a sublane shuffle a
+  token, 0.23 us of that arithmetic at 4 KV heads. A walk's last
+  step may hold fewer pages than the buffer: the pages it lacks are not
+  fetched, their rows are masked, and the V buffers are zeroed once a
+  call so that what a masked probability multiplies is a fetched page's
+  rows or zero, never what VMEM held. At one page a step the kernel is
+  the one it was, instruction for instruction;
+- the step is read as the rows [rows, hd]: ONE matmul scores every
   query head against every (token, kv head) row, and a mask keeps, for
   query head r, the rows of ITS kv head (r // group) at key positions
   under the slot's count. The online softmax then runs over exactly the
@@ -31,14 +53,15 @@ holds it, and stops at each slot's length:
 - int8 pages: the per-(token, head) scales multiply the score COLUMNS
   (K) and the probability columns (V), which is the dequantisation done
   in VMEM after the matmul instead of on a window copy before it. The
-  window's scales (1/32 of its bytes) are gathered by XLA into rows;
+  window's scales (1/32 of its bytes) are gathered by XLA into rows, a
+  step's side by side;
 - a SLIDING layer (``window``: a query sees the ``window`` newest keys,
   itself among them) starts each slot's walk at the page that holds key
   ``count - window`` and masks the rows before it: pages that lie wholly
   before the window are neither fetched nor computed, so a slot costs
   ``window / page`` pages, one more where the window straddles a page's
-  edge, however long its context. Without a window the kernel is the
-  one it was, instruction for instruction.
+  edge, however long its context: the walk's first step begins at that
+  page, not at a multiple of a step.
 
 ``paged_decode_attention`` is the entry: on a program LOWERED for a TPU
 it is the kernel, on any other platform the plain gather formulation
@@ -62,6 +85,7 @@ from ray_tpu.ops.paged_attention import gather_kv_window, visible_pages
 KERNEL_NAME = "paged_decode_attn"
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 _BUFFERS = 2
+_STEP_ROWS = 1024
 
 
 def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
@@ -90,35 +114,70 @@ def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
     return out[:, 0]
 
 
+def step_pages(pool) -> int:
+    """The pages one step of the kernel's walk takes of a pool [L, P,
+    page, nkv, hd] (an array or its shape and type): as many as make
+    ``_STEP_ROWS`` rows ``page * nkv``, where a page's rows lie dense in
+    the pool (module docstring)."""
+    _, _, page, nkv, _ = pool.shape
+    # dense: the KV heads fill a tile's sublanes with no padding (a power
+    # of two of them, a 32-bit sublane of two bf16 or four int8 at least)
+    dense = nkv & (nkv - 1) == 0 and nkv * jnp.dtype(pool.dtype).itemsize >= 4
+    return max(1, _STEP_ROWS // (page * nkv)) if dense else 1
+
+
 def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
-            q_ref, k_hbm, v_hbm, *rest, pages_per_slot, quantized, window):
+            q_ref, k_hbm, v_hbm, *rest, pages_per_slot, page, nkv,
+            per_step, quantized, window):
     """See the module docstring. ``rest``: the window's scale rows (int8
-    only), the output, the page buffers and their DMA semaphores."""
+    only), the output, the step buffers and their DMA semaphores."""
     if quantized:
         ks_ref, vs_ref, o_ref, k_buf, v_buf, sem = rest
     else:
         o_ref, k_buf, v_buf, sem = rest
     slots, nh, hd = q_ref.shape
-    page, nkv = k_hbm.shape[2], k_hbm.shape[3]
-    rows = page * nkv
+    page_rows = page * nkv
+    rows = per_step * page_rows
     scale = hd ** -0.5
     layer = layer_ref[0]
     # what the gather formulation attends over: the pages' own type, or
     # the dequantised bf16 window
     kv_dtype = jnp.bfloat16 if quantized else k_buf.dtype
 
+    def blocks(count):
+        # never past the table's row (the gather formulation's window
+        # ends there too; the engine's reservations keep counts inside)
+        return jnp.minimum((count + page - 1) // page, pages_per_slot)
+
     def copies(slot, block, buf):
-        p = table_ref[slot * pages_per_slot + block]
-        return (pltpu.make_async_copy(k_hbm.at[layer, p], k_buf.at[buf],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[layer, p], v_buf.at[buf],
-                                      sem.at[1, buf]))
+        """The copies of the step that begins at page ``block`` of
+        ``slot``'s walk, for each of its pages: whether the walk holds
+        the page (its first it always does), its K copy and its V copy."""
+        out = []
+        n_blocks = blocks(count_ref[slot]) if per_step > 1 else None
+        for j in range(per_step):
+            held, at = True, block
+            if j:
+                held = block + j < n_blocks
+                at = jnp.minimum(block + j, pages_per_slot - 1)
+            p = table_ref[slot * pages_per_slot + at]
+            # a buffer is a page, or the step's rows with the page's own
+            # among them
+            to = (buf if per_step == 1
+                  else (buf, pl.ds(j * page_rows, page_rows)))
+            out.append((held,
+                        pltpu.make_async_copy(k_hbm.at[layer, p],
+                                              k_buf.at[to], sem.at[0, buf]),
+                        pltpu.make_async_copy(v_hbm.at[layer, p],
+                                              v_buf.at[to], sem.at[1, buf])))
+        return out
 
     def start(slot, block, buf):
-        for c in copies(slot, block, buf):
-            c.start()
+        for held, *pair in copies(slot, block, buf):
+            for c in pair:
+                pl.when(held)(c.start)
 
-    # which query head may see which row of a page: row = token * nkv + kv
+    # which query head may see which row of a step: row = token * nkv + kv
     # head; head r reads kv head r // group
     col = lax.broadcasted_iota(jnp.int32, (nh, rows), 1)
     row = lax.broadcasted_iota(jnp.int32, (nh, rows), 0)
@@ -134,6 +193,13 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
         count = count_ref[jnp.minimum(slot, slots - 1)]
         return jnp.maximum(count - window, 0) // page
 
+    if per_step > 1:
+        # a walk's last step may hold fewer pages than its buffer: the
+        # rows it does not fetch are masked, and a masked probability
+        # times whatever VMEM held is a NaN if that was one. Zeros, then
+        # a fetched page's rows, are all the V buffers ever hold
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
     first = next_ref[0]
 
     @pl.when(first < slots)
@@ -142,48 +208,62 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
 
     def slot_body(slot, step):
         count = count_ref[slot]
-        # never past the table's row (the gather formulation's window
-        # ends there too; the engine's reservations keep counts inside)
-        n_blocks = jnp.minimum((count + page - 1) // page, pages_per_slot)
+        n_blocks = blocks(count)
+        begin = first_block(slot)
+        # step i of the walk begins at page ``at(i)``; the walk ends
+        # before step ``end``, at key ``keys_end`` (one page a step: the
+        # steps are the pages)
+        def at(i):
+            return i if per_step == 1 else begin + (i - begin) * per_step
+
+        end, keys_end = n_blocks, count
+        if per_step > 1:
+            end = begin + (n_blocks - begin + per_step - 1) // per_step
+            keys_end = jnp.minimum(count, n_blocks * page)
 
         def block_body(i, carry):
             m, l, acc, step = carry
             buf = step % _BUFFERS
-            more = i + 1 < n_blocks
+            more = i + 1 < end
             nslot = jnp.where(more, slot, next_ref[slot + 1])
-            nblock = jnp.where(more, i + 1, first_block(nslot))
+            nblock = jnp.where(more, at(i + 1), first_block(nslot))
 
             @pl.when(nslot < slots)
             def _():
                 start(nslot, nblock, (step + 1) % _BUFFERS)
 
-            k_copy, v_copy = copies(slot, i, buf)
-            k_copy.wait()
+            page0 = at(i)
+            fetched = copies(slot, page0, buf)
+            for held, k_copy, _ in fetched:
+                pl.when(held)(k_copy.wait)
             q = q_ref[slot]
             k = k_buf[buf].reshape(rows, hd).astype(kv_dtype)
             s = lax.dot_general(q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             if quantized:
-                s = s * ks_ref[slot, pl.ds(i, 1), :]
-            seen = own_head & (token < count - i * page)
+                s = s * _scale_row(ks_ref, slot, page0, per_step)
+            # the step's first row is key ``page0 * page``; rows past the
+            # walk's last key are unseen, an unfetched page's among them
+            seen = own_head & (token < keys_end - page0 * page)
             if window is not None:
-                seen = seen & (token >= count - window - i * page)
+                seen = seen & (token >= count - window - page0 * page)
             s = jnp.where(seen, s, _MASKED)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
             l = alpha * l + p.sum(axis=-1, keepdims=True)
-            v_copy.wait()
+            for held, _, v_copy in fetched:
+                pl.when(held)(v_copy.wait)
             v = v_buf[buf].reshape(rows, hd).astype(kv_dtype)
             if quantized:
-                p = p * vs_ref[slot, pl.ds(i, 1), :]
+                p = p * _scale_row(vs_ref, slot, page0, per_step)
             acc = alpha * acc + lax.dot_general(
                 p.astype(kv_dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             return m_new, l, acc, step + 1
 
         m, l, acc, step = lax.fori_loop(
-            first_block(slot), n_blocks, block_body,
+            begin, end, block_body,
             (jnp.full((nh, 1), -jnp.inf, jnp.float32),
              jnp.zeros((nh, 1), jnp.float32),
              jnp.zeros((nh, hd), jnp.float32), step))
@@ -194,13 +274,23 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
     lax.fori_loop(0, slots, slot_body, 0)
 
 
+def _scale_row(scales_ref, slot, block, per_step):
+    """The scales of a step's rows [1, rows], laid out as its score
+    columns are: those of its pages, from ``block`` on, side by side."""
+    # (``block + 0`` would be one more instruction of a one-page step)
+    pages = [scales_ref[slot, pl.ds(block + j if j else block, 1), :]
+             for j in range(per_step)]
+    return pages[0] if per_step == 1 else jnp.concatenate(pages, axis=1)
+
+
 def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
                                   layer, table, pos, active, *,
                                   window=None, interpret=False):
     """The kernel's launch; arguments as ``paged_decode_attention``."""
     slots, nh, hd = q.shape
-    _, _, page, nkv, _ = k_pages.shape
+    layers, num_pages, page, nkv, _ = k_pages.shape
     pb = table.shape[1]
+    per_step = step_pages(k_pages)
     quantized = k_pages.dtype == jnp.int8
     count = jnp.where(active, pos + 1, 0).astype(jnp.int32)
     # next_live[0]: the first slot with keys; next_live[s + 1]: the first
@@ -210,25 +300,37 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
     after = lax.cummin(live_at, reverse=True)
     next_live = jnp.concatenate([after, jnp.full((1,), slots, jnp.int32)])
     table_c = jnp.maximum(table, 0).astype(jnp.int32)
+    buffer = (_BUFFERS, page, nkv, hd)
+    if per_step > 1:
+        # a page as its rows [page * nkv, hd], which is how it lies in
+        # the pool (a bitcast): they arrive dense in a step's buffer
+        k_pages, v_pages = (pool.reshape(layers, num_pages, page * nkv, hd)
+                            for pool in (k_pages, v_pages))
+        buffer = (_BUFFERS, per_step * page * nkv, hd)
     operands = [q, k_pages, v_pages]
     in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY)]
     if quantized:
         # the window's scales as rows [B, PB, page * nkv]: 1/32 of the
-        # window's bytes, laid out as the score columns are
-        operands += [k_scale[layer, table_c].reshape(slots, pb, page * nkv),
-                     v_scale[layer, table_c].reshape(slots, pb, page * nkv)]
+        # window's bytes, laid out as the score columns are (and as many
+        # pages more as a step that begins at the table's last may read)
+        scaled = table_c
+        if per_step > 1:
+            scaled = jnp.pad(table_c, ((0, 0), (0, per_step - 1)))
+        operands += [k_scale[layer, scaled].reshape(slots, -1, page * nkv),
+                     v_scale[layer, scaled].reshape(slots, -1, page * nkv)]
         in_specs += [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2
     return pl.pallas_call(
-        functools.partial(_kernel, pages_per_slot=pb, quantized=quantized,
+        functools.partial(_kernel, pages_per_slot=pb, page=page, nkv=nkv,
+                          per_step=per_step, quantized=quantized,
                           window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(1,), in_specs=in_specs,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[
-                pltpu.VMEM((_BUFFERS, page, nkv, hd), k_pages.dtype),
-                pltpu.VMEM((_BUFFERS, page, nkv, hd), v_pages.dtype),
+                pltpu.VMEM(buffer, k_pages.dtype),
+                pltpu.VMEM(buffer, v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, _BUFFERS))]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret, name=KERNEL_NAME,

@@ -97,7 +97,8 @@ from ray_tpu.ops.latent_attention import (latent_decode_attention,
 from ray_tpu.ops.moe import expert_kernel_engages
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import row_pool, write_kv
-from ray_tpu.ops.paged_decode_attention import paged_decode_attention
+from ray_tpu.ops.paged_decode_attention import (paged_decode_attention,
+                                                step_pages)
 from ray_tpu.ops.paged_prefill_attention import (kernel_engages,
                                                  paged_prefill_attention)
 from ray_tpu.ops.ssm import state_kernel_engages
@@ -350,6 +351,7 @@ class EnginePrograms:
         self.pools = []
         self.bf16_row_bytes = 0     # a token's rows over the layers, bf16
         self._page_layers = {}      # layers that keep pages, by format
+        twins = None                # the K pool of the layers with twins
         for rows in _pool_slices(plan)[0]:
             layers = _pool_layers(plan, rows)
             self._page_layers[
@@ -357,8 +359,9 @@ class EnginePrograms:
                 ",".join(f"{row.name}:{row.width}" for row in rows)] = layers
             if rows is None:
                 self.pools += self._kv_twins(layers)
+                twins = self.pools[-4]
                 self.bf16_row_bytes += (
-                    layers * 2 * 2 * math.prod(self.pools[-4].shape[3:]))
+                    layers * 2 * 2 * math.prod(twins.shape[3:]))
                 continue
             if kv_dtype == "int8":
                 raise ValueError(
@@ -399,6 +402,10 @@ class EnginePrograms:
         self._state_kernel = on_tpu and any(
             state_kernel_engages(a) for a in self.state)
         self._latent_backend = on_tpu and self.selects is not None
+        # the pages a step of the decode kernel's walk takes over the K/V
+        # twins (``ops/paged_decode_attention.py``'s rule on their shape)
+        self._attn_step_pages = (
+            step_pages(twins) if on_tpu and twins is not None else 0)
         self._expert_backend = on_tpu and any(
             _routes(run, blocks if run.key is None else blocks[run.key])
             for run in plan)
@@ -520,8 +527,11 @@ class EnginePrograms:
         """Whether a decode program over a table ``pages`` wide advances
         the slots' state in the state kernel and reads the rows its
         layers pick in the latent kernel (``latent_kernel_engages``, on
-        the program's own table)."""
+        the program's own table), and the pages a step of its attention
+        kernel's walk takes (0: no layer attends over K/V twins, or no
+        kernel does)."""
         return {
+            "attn_step_pages": self._attn_step_pages,
             "state_kernel": int(self._state_kernel),
             "latent_kernel": int(
                 self._latent_backend and latent_kernel_engages(

@@ -1028,7 +1028,8 @@ class PagedLLMEngine:
                 rows = self._lengths[active_idx].astype(np.int64) + 1
                 ph.set(seq=stream_seq, chunk=chunk, drain=drain,
                        live=len(active_idx), slots=self.max_batch,
-                       kv_rows_full=int(rows.sum()))
+                       kv_rows_full=int(rows.sum()),
+                       attn_step_pages=kernels["attn_step_pages"])
                 programs = self._programs
                 if programs.window is not None:
                     # and the live slots whose context has passed it

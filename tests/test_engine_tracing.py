@@ -4,6 +4,7 @@ programs and the flash kernels, the loop's phases as spans while a
 timeline from the watcher thread, and the five TTFT stages. All on the
 CPU with the tiny llama config."""
 
+import dataclasses
 import re
 import sys
 import threading
@@ -765,6 +766,53 @@ def test_decode_dispatches_say_whether_their_program_holds_the_latent_kernel(
     assert [s["attrs"]["latent_kernel"] for s in decodes] == \
         [engaged] * len(decodes)
     assert stats["latent_kernel_dispatches"] == engaged * len(decodes)
+
+
+_ATTN_STEP_CASES = [
+    # the backend the engine finds, the family, its KV heads (query heads
+    # a KV head as the tiny configuration has them), attn_step_pages
+    ("tpu", "smallthinker", 4, 2), ("tpu", "nemotron_h", 2, 4),
+    ("tpu", "llama", 8, 1), ("tpu", "dots3_note", None, 0),
+    ("cpu", "smallthinker", 4, 0)]
+
+
+@pytest.mark.parametrize(
+    "backend,family,kv_heads,step", _ATTN_STEP_CASES,
+    ids=[f"{b}-{f}-kv{n}" for b, f, n, _ in _ATTN_STEP_CASES])
+def test_decode_dispatches_say_how_many_pages_a_step_of_the_kernel_takes(
+        monkeypatch, backend, family, kv_heads, step):
+    """``attn_step_pages`` on ``engine.dispatch_decode`` is the decode
+    kernel's own rule (``ops/paged_decode_attention.py``: ``step_pages``)
+    on the K/V pools the engine holds, pages of 128 tokens here: two
+    pages a step at 4 KV heads, four at 2, one at 8; 0 for a plan none of
+    whose layers attends over K/V twins (the latent family's rows) and 0
+    on any backend but a TPU, where no kernel runs.
+    ``EnginePrograms.decode_kernels`` is where the loop has it from."""
+    from ray_tpu.models import dots3_note, nemotron_h, smallthinker
+    from ray_tpu.serve import engine_programs
+
+    model, cfg = {
+        "smallthinker": lambda: (smallthinker, smallthinker.smallthinker_tiny(
+            n_heads=7 * kv_heads, n_kv_heads=kv_heads)),
+        "nemotron_h": lambda: (nemotron_h, nemotron_h.nemotron_h_tiny(
+            n_kv_heads=kv_heads)),
+        "llama": lambda: (llama, dataclasses.replace(
+            llama.llama_tiny(), n_heads=kv_heads, n_kv_heads=kv_heads)),
+        "dots3_note": lambda: (dots3_note, dots3_note.dots3_note_tiny()),
+    }[family]()
+    monkeypatch.setattr(engine_programs.jax, "default_backend",
+                        lambda: backend)
+    eng = PagedLLMEngine(cfg, model.init_params(cfg, jax.random.key(0)),
+                         max_batch=2, max_len=256, page_size=128,
+                         num_pages=8)
+    monkeypatch.undo()
+    assert eng._programs.decode_kernels(2)["attn_step_pages"] == step
+    rng = np.random.default_rng(3)
+    decodes, stats = _decode_spans_of(
+        eng, [rng.integers(1, 100, n) for n in (70, 7)], new_tokens=5)
+    assert decodes and stats["decode_dispatches"] == len(decodes)
+    assert [s["attrs"]["attn_step_pages"] for s in decodes] == \
+        [step] * len(decodes)
 
 
 @pytest.mark.parametrize("family", ["llama", "olmoe", "laguna", "falcon_h1"])

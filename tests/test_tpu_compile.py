@@ -870,6 +870,175 @@ def test_state_kernel_compiles_alone(v5e_2x2, heads, width, size, groups):
 
 
 
+def _dense_rows(text, layers, pages, rows):
+    """The opcodes of the instructions whose result is a pool seen as
+    dense rows ``[layers, pages, page * nkv, 128]`` (the decode kernel's
+    view of it at fewer than 8 KV heads: a bitcast, or it is a copy)."""
+    return re.findall(
+        rf"= (?:bf16|s8)\[{layers},{pages},{rows},128\]\S* ([\w\-]+)\(", text)
+
+
+# (slots, query heads, KV heads, table pages, window, pages' type): the
+# three cells whose step of the walk takes more than a page
+# (``serve-brief-gen``'s full and sliding layers, ``serve-instruct-gen``,
+# ``serve-reason-gen``), and int8 pages at 4 KV heads (at 2 an int8 pool
+# is tiled by four sublanes with two of them padding, a step is a page,
+# and Mosaic refuses that kernel as it refused the parent's: no case)
+_DECODE_KERNEL_SHAPES = {
+    "28x4-full": (32, 28, 4, 64, None, jnp.bfloat16),
+    "28x4-window-4096": (32, 28, 4, 64, 4096, jnp.bfloat16),
+    "20x4": (128, 20, 4, 4, None, jnp.bfloat16),
+    "32x2": (128, 32, 2, 16, None, jnp.bfloat16),
+    "28x4-int8": (32, 28, 4, 64, None, jnp.int8),
+    "20x4-int8-window": (128, 20, 4, 16, 512, jnp.int8),
+}
+
+
+def _lower_decode_kernel(device, slots, heads, kv_heads, pages, window,
+                         dtype, pool_pages=600):
+    from ray_tpu.ops.paged_decode_attention import (
+        paged_decode_attention_kernel)
+
+    one_chip = SingleDeviceSharding(device)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = shape((3, pool_pages, 128, kv_heads, 128), dtype)
+    scale = shape((3, pool_pages, 128, kv_heads) if dtype == jnp.int8
+                  else (3, 1, 1, 1), jnp.float32)
+    return jax.jit(partial(paged_decode_attention_kernel,
+                           window=window)).lower(
+        shape((slots, heads, 128), jnp.bfloat16), pool, pool, scale, scale,
+        shape((), jnp.int32), shape((slots, pages), jnp.int32),
+        shape((slots,), jnp.int32), shape((slots,), jnp.bool_))
+
+
+@pytest.mark.parametrize("case", _DECODE_KERNEL_SHAPES,
+                         ids=list(_DECODE_KERNEL_SHAPES))
+def test_decode_kernel_compiles_alone(v5e_2x2, case):
+    """The decode kernel by itself where a step of its walk is 2 and 4
+    pages (4 and 2 KV heads): the chip's compiler takes the copies into a
+    step's rows, the dense operands and the step's scale row, and the
+    pools reach it as they lie: the view ``[L, P, page * nkv, hd]`` is a
+    bitcast, and nothing of a pool's size is made."""
+    slots, heads, kv_heads, pages, window, dtype = _DECODE_KERNEL_SHAPES[case]
+    compiled = _lower_decode_kernel(v5e_2x2[0], slots, heads, kv_heads,
+                                    pages, window, dtype).compile()
+    text = compiled.as_text()
+    assert len(_DECODE_KERNEL.findall(text)) == 1
+    assert text.count("tpu_custom_call") == 1
+    assert _dense_rows(text, 3, 600, 128 * kv_heads) == ["bitcast"] * 2
+    assert not _pool_copy(3, 600, kv_heads).findall(text)
+    # nothing but the int8 window's scale rows (1/32 of its bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        128 << 20 if dtype == jnp.int8 else 1 << 20)
+
+
+def _located_nowhere(lowered_text):
+    """A lowered program's text with each Mosaic kernel's body, which is
+    bytecode that carries the source's line numbers, replaced by its
+    assembly without them: what a digest of the kernel can be taken of
+    across an edit that moves its lines."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body(found):
+        config = json.loads(re.sub(
+            r"\\([0-9A-Fa-f]{2})", lambda m: chr(int(m.group(1), 16)),
+            found.group(1)))
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(
+                base64.b64decode(config["custom_call_config"]["body"]))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'backend_config = "(\{\\22custom_call_config.*?)"(?=[,}] )',
+                  body, lowered_text)
+
+
+# sha256 (first 16 hex digits) of the kernel's launch as the commit before
+# the many-page step (b6abfad: PR 51) lowered it for a v5e, by this file's
+# own helpers laid over that tree, under the jax named below: (slots,
+# query heads, KV heads, table pages, window, pages' type)
+_PARENT_DECODE_KERNEL = {
+    "32x8": ((32, 32, 8, 16, None, jnp.bfloat16), "25b816855241afc9"),
+    "16x16": ((32, 16, 16, 8, None, jnp.bfloat16), "9801f4a8da5b3c2e"),
+    "48x8-window-512": ((32, 48, 8, 64, 512, jnp.bfloat16),
+                        "aafa3fd0301fdb62"),
+    "32x8-int8": ((32, 32, 8, 16, None, jnp.int8), "e21d62d6bab2ef5a"),
+    "16x16-int8": ((32, 16, 16, 8, None, jnp.int8), "2c68b00781ed4c22"),
+}
+_PINNED_JAX = "0.9.0"
+
+
+@pytest.mark.parametrize("case", _PARENT_DECODE_KERNEL,
+                         ids=list(_PARENT_DECODE_KERNEL))
+def test_decode_kernel_at_8_and_16_kv_heads_is_the_one_it_was(v5e_2x2, case):
+    """Where a page holds 1,024 rows or more a step of the walk is the
+    page, and the kernel is the parent's instruction for instruction: its
+    launch and its Mosaic body lower to the same text (source locations
+    left out), with and without a window and over int8 pages. The decode
+    programs of ``serve-doc``, ``serve-chat``, ``serve-moe-gen`` and
+    ``serve-code-gen`` hold this kernel and no other. A change MEANT to
+    alter it pins its new digest here, computed on its own tree."""
+    import hashlib
+
+    if jax.__version__ != _PINNED_JAX:
+        pytest.skip(f"digests pinned under jax {_PINNED_JAX}")
+    dims, digest = _PARENT_DECODE_KERNEL[case]
+    text = _located_nowhere(_lower_decode_kernel(v5e_2x2[0], *dims).as_text())
+    assert ".py" not in text and "loc(" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_decode_kernel_at_4_kv_heads_is_another(v5e_2x2):
+    """The fence above is not blind: where a step takes two pages the
+    same launch at the same shapes lowers to another text than a
+    one-page step's would (dense operands, a step's buffer)."""
+    text = _located_nowhere(_lower_decode_kernel(
+        v5e_2x2[0], 32, 28, 4, 64, None, jnp.bfloat16).as_text())
+    assert "memref<3x600x512x128xbf16" in text
+    assert "memref<2x1024x128xbf16" in text
+    assert "memref<3x600x128x4x128xbf16" not in text.split("custom_call")[1]
+
+
+# SmallThinker-21BA3B-Instruct cut to its first eight layers
+# (``serve-brief-gen``): 32 slots, 2,304 pages, tables of 64 pages
+_BRIEF_PAGES = 2304
+
+
+def test_brief_d8_decode_program_reads_its_pages_in_place(v5e_2x2):
+    """The decode program of the cell whose kernel walks two pages a
+    step, at the published widths (28 query heads on 4 KV heads): one
+    kernel instruction a run of the plan (full, three sliding, full,
+    three sliding), each handed the stacked pools as dense rows ``[8,
+    2304, 512, 128]`` by a bitcast of the pool ``write_kv`` scattered
+    into, and no operation that copies, slices or rewrites a pool of
+    either shape; the pools are donated and come back in place."""
+    from ray_tpu.models import smallthinker
+
+    period = (0, 1, 1, 1)
+    cfg = dataclasses.replace(
+        smallthinker.smallthinker_21b_a3b(),
+        sliding_window_layout=period * 2, rope_layout=period * 2)
+    compiled = _compile_engine_program(
+        v5e_2x2[0], smallthinker, cfg, _BRIEF_PAGES, "decode", (16, 64))
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert len(_DECODE_KERNEL.findall(text)) == 4
+    assert _in_loops(text, _DECODE_KERNEL) == 4
+    assert _dense_rows(text, 8, _BRIEF_PAGES, 512) == ["bitcast"] * 8
+    assert not _pool_copy(8, _BRIEF_PAGES, 4).findall(text)
+    assert not _window(32, 64, 4).findall(text)
+    pool_bytes = 8 * _BRIEF_PAGES * 128 * 4 * 128 * 2
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < 0.1e9
+
+
 # dots3-note-prev cut to its first five layers (``serve-note-gen``): 64
 # slots, 2,816 pages of latent rows in whole lanes, tables of 64 pages
 _NOTE_SLOTS, _NOTE_PAGES, _NOTE_TABLE = 64, 2816, 64
