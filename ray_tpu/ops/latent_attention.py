@@ -48,14 +48,14 @@ is lowered for (``jax.lax.platform_dependent``) and by static shapes
   prefetch; the grid walks the slots and, per live slot, the pages up to
   ``ceil(keys / page)``, ``_GROUP`` at a step, ONE async copy a page,
   double buffered, the next pages' copies (of this slot or of the next
-  live one) in flight while these are computed. The pages are contracted
-  as they lie: ``q_row [H, lanes] x rows^T -> [H, keys]`` float32, online
-  softmax in float32, the probabilities cast to the rows' type, ``p x
-  rows[:, :r] -> [H, r]`` accumulated in float32. The SELECTION reaches
-  it as flags a key ([slots, keys], a page's 128 riding with the page):
-  ``kept``'s set among the keys the slot sees, so every page a slot holds
-  is read whole and the keys outside the set are masked. A dead slot and
-  the pages past a slot's count cost nothing;
+  live one) in flight while these are computed (``_walk``). The pages are
+  contracted as they lie: ``q_row [H, lanes] x rows^T -> [H, keys]``
+  float32, online softmax in float32, the probabilities cast to the rows'
+  type, ``p x rows[:, :r] -> [H, r]`` accumulated in float32. The
+  SELECTION reaches it as flags a key ([slots, keys], a page's 128 riding
+  with the page): ``kept``'s set among the keys the slot sees, so every
+  page a slot holds is read whole and the keys outside the set are
+  masked. A dead slot and the pages past a slot's count cost nothing;
 - GATHERED, plain ``jax.numpy`` (``_gathered``: what the kernel is held
   to, and what every other platform, every shape outside the rule and
   every windowed layer runs): the chosen rows, or a window's pages
@@ -67,7 +67,27 @@ is lowered for (``jax.lax.platform_dependent``) and by static shapes
 
 The rule between them for a layer with an indexer is a ratio the program
 sees as static shapes, the table's keys over ``topk``: see
-``GATHER_PAST``. The serving engine (``serve/paged_llm.py``) calls the
+``GATHER_PAST``.
+
+The decode form's INDEX SCORES, which make the selection, have the same
+two formulations, chosen the same way (``index_kernel_engages``: wherever
+the layer selects, pages and index keys in whole lanes):
+
+- IN PLACE, a second Pallas kernel (``index_decode_scores``): the index
+  keys' pool stays in HBM and each live slot's pages are walked as the
+  latent kernel walks the rows' (one ``_walk`` serves both), a group of
+  keys ``[g x page, dI]`` against the slot's index queries ``[HI, dI]``
+  on the MXU in float32, ``relu``, the heads' weighted sum:
+  ``index_scores``' arithmetic (exact products, float32 sums), written
+  as ``[slots, keys]`` float32 with the mask's value from the slot's
+  count on, in the pages it never fetched too;
+- GATHERED (``_scored_gathered``: what the kernel is held to): every
+  page of the table copied out of the pool, for every slot, and
+  ``index_scores`` over the copy (at the serving cell's shapes 134 MB
+  written and read again a layer where the slots hold 65 MB of keys:
+  0.644 ms against the kernel's 0.165 on a v5e, PR 53).
+
+The serving engine (``serve/paged_llm.py``) calls the
 three functions at the bottom from its two programs; what they take of a
 block is ``LatentInputs``, which the model's module builds."""
 
@@ -180,6 +200,15 @@ _GROUP = 8
 # The serving cell's programs (64-page tables of 8,192 keys over a
 # ``topk`` of 2,048) stand at 4 x.
 GATHER_PAST = 8
+INDEX_KERNEL_NAME = "index_decode_scores"
+# pages a step of the index kernel's walk: 32 KB each at the serving
+# cell's 128 keys of 128 bf16 numbers, a fifth of a latent page (a full
+# layer at 64 slots of 3.0-5.1k keys, 64 index heads, on a v5e, the
+# kernel alone, ``scripts/sweep_index_kernel.py``, PR 53: 0.251 ms by
+# fours, 0.187 by eights, 0.165 by sixteens, 0.160 by thirty-twos, 0.166
+# the whole 64-page table at once; the keys' bytes over the bandwidth are
+# 0.081 and the gathered formulation takes 0.644)
+_INDEX_GROUP = 16
 
 
 def latent_kernel_engages(page: int, table_pages: int, topk) -> bool:
@@ -194,17 +223,34 @@ def latent_kernel_engages(page: int, table_pages: int, topk) -> bool:
             and topk < page * table_pages <= GATHER_PAST * topk)
 
 
-def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
-            q_ref, pool_hbm, flags_ref, o_ref, buf, sem, step_ref, *,
-            pages_per_slot, group, width, scale):
-    """One grid step a slot (module docstring): its queries ``q_ref`` [1,
-    H, lanes], its flags [1, PB / group, group x page], its output [1, H,
-    width]. ``buf`` [2, group x page, lanes], ``sem`` [2, group]: the
-    page buffers, a GROUP of pages each, and their DMA semaphores;
-    ``step_ref``: the groups walked so far (which buffer is next), kept
-    across the grid's steps as the buffers are."""
+def index_kernel_engages(page: int, table_pages: int, topk,
+                         width: int) -> bool:
+    """The rule, from static shapes alone: whether a decode step's index
+    scores of a layer that keeps ``topk`` keys (None: it has no indexer),
+    over a table of ``table_pages`` pages of ``page`` index keys of
+    ``width`` numbers, are the index kernel's on a program lowered for a
+    TPU: wherever the layer selects (the table holds more than ``topk``
+    keys), pages and keys in whole lanes (a key is then its pool's whole
+    row)."""
+    return (topk is not None and page % ROW_LANES == 0
+            and width % ROW_LANES == 0 and topk < page * table_pages)
+
+
+def _walk(layer_ref, table_ref, count_ref, next_ref,          # SMEM
+          pool_hbm, buf, sem, step_ref, *, pages_per_slot, group, body,
+          carry=()):
+    """This grid step's slot's live pages of ``pool_hbm``'s layer, through
+    the page table, ``group`` at a step of the walk: ONE async copy a
+    page into ``buf`` [2, group x page, lanes] under ``sem`` [2, group],
+    double buffered, the next group's copies (of this slot or, behind
+    its last, of the next live one's first) in flight while ``body(g,
+    rows, *carry)`` computes on group ``g``'s rows [group x page, lanes]
+    (of its last group, the pages past the slot's last are not fetched:
+    what the buffer holds there is the caller's to ignore) and returns
+    the next ``carry``. ``step_ref``: the groups walked so far (which
+    buffer is next), kept across the grid's steps as the buffers are.
+    Returns the last carry."""
     slot, slots = pl.program_id(0), pl.num_programs(0)
-    heads = q_ref.shape[1]
     page = pool_hbm.shape[2]
     layer = layer_ref[0]
 
@@ -233,9 +279,6 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
     @pl.when(slot == 0)
     def _():
         step_ref[0] = 0
-        # a group's pages past the slot's last are not fetched: what the
-        # buffer holds there weighs nothing, and must be a number
-        buf[...] = jnp.zeros_like(buf)
         first = next_ref[0]
 
         @pl.when(first < slots)
@@ -243,10 +286,9 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
             copies(first, 0, 0, lambda c: c.start())
 
     n_groups = (pages_of(slot) + group - 1) // group
-    q = q_ref[0]                                            # [H, lanes]
 
     def group_body(g, carry):
-        m, l, acc, step = carry
+        *carry, step = carry
         b = step % _BUFFERS
         more = g + 1 < n_groups
         nslot = jnp.where(more, slot, next_ref[slot + 1])
@@ -257,7 +299,51 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
                    lambda c: c.start())
 
         copies(slot, g, b, lambda c: c.wait())
-        rows = buf[b]                               # [group x page, lanes]
+        return (*body(g, buf[b], *carry), step + 1)
+
+    *carry, step = lax.fori_loop(0, n_groups, group_body,
+                                 (*carry, step_ref[0]))
+    step_ref[0] = step
+    return carry
+
+
+def _of_slot(*block):
+    """A grid step's block of an array whose first axis is the slots."""
+    return pl.BlockSpec((1, *block), lambda s, *_: (s, 0, 0))
+
+
+def _walked(table, count):
+    """What ``_walk`` takes through scalar prefetch beside the layer: the
+    page table [B, PB] in one row, holes as page 0 (what ``gather_rows``
+    reads there); the slots' key counts [B]; and the next-live-slot chain
+    [B + 1]: its first entry the first slot with keys, entry s + 1 the
+    first after s (``B`` when there is none)."""
+    slots = count.shape[0]
+    live_at = jnp.where(count > 0, jnp.arange(slots, dtype=jnp.int32),
+                        slots)
+    next_live = jnp.concatenate([lax.cummin(live_at, reverse=True),
+                                 jnp.full((1,), slots, jnp.int32)])
+    return (jnp.maximum(table, 0).astype(jnp.int32).reshape(-1),
+            count.astype(jnp.int32), next_live)
+
+
+def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
+            q_ref, pool_hbm, flags_ref, o_ref, buf, sem, step_ref, *,
+            pages_per_slot, group, width, scale):
+    """One grid step a slot (module docstring): its queries ``q_ref`` [1,
+    H, lanes], its flags [1, PB / group, group x page], its output [1, H,
+    width]; the page buffers, their semaphores and ``step_ref``:
+    ``_walk``'s."""
+    heads = q_ref.shape[1]
+    q = q_ref[0]                                            # [H, lanes]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        # a group's pages past the slot's last are not fetched: what the
+        # buffer holds there weighs nothing, and must be a number
+        buf[...] = jnp.zeros_like(buf)
+
+    def group_body(g, rows, m, l, acc):
         s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         # the flags hold the slot's count and its selection both
@@ -272,14 +358,15 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
         acc = alpha * acc + lax.dot_general(
             p.astype(rows.dtype), rows[:, :width], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return m_new, l, acc, step + 1
+        return m_new, l, acc
 
-    m, l, acc, step = lax.fori_loop(
-        0, n_groups, group_body,
-        (jnp.full((heads, 1), -jnp.inf, jnp.float32),
-         jnp.zeros((heads, 1), jnp.float32),
-         jnp.zeros((heads, width), jnp.float32), step_ref[0]))
-    step_ref[0] = step
+    m, l, acc = _walk(
+        layer_ref, table_ref, count_ref, next_ref, pool_hbm, buf, sem,
+        step_ref, pages_per_slot=pages_per_slot, group=group,
+        body=group_body,
+        carry=(jnp.full((heads, 1), -jnp.inf, jnp.float32),
+               jnp.zeros((heads, 1), jnp.float32),
+               jnp.zeros((heads, width), jnp.float32)))
     # a slot with no keys: zeros (l == 0), never a NaN
     o_ref[0] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
@@ -299,25 +386,16 @@ def latent_decode_attention_kernel(q_row, pool, layer, table, count, flags,
     group = math.gcd(pb, _GROUP)
     # what a page's rows are sliced to: whole lanes (``rank``'s, or all)
     width = min(lanes, -(-rank // ROW_LANES) * ROW_LANES)
-    # next_live[0]: the first slot with keys; next_live[s + 1]: the first
-    # after s (``slots`` when there is none)
-    live_at = jnp.where(count > 0, jnp.arange(slots, dtype=jnp.int32),
-                        slots)
-    next_live = jnp.concatenate([lax.cummin(live_at, reverse=True),
-                                 jnp.full((1,), slots, jnp.int32)])
-
-    def of_slot(*block):
-        return pl.BlockSpec((1, *block), lambda s, *_: (s, 0, 0))
 
     out = pl.pallas_call(
         functools.partial(_kernel, pages_per_slot=pb, group=group,
                           width=width, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(slots,),
-            in_specs=[of_slot(heads, lanes),
+            in_specs=[_of_slot(heads, lanes),
                       pl.BlockSpec(memory_space=pl.ANY),
-                      of_slot(pb // group, group * page)],
-            out_specs=of_slot(heads, width),
+                      _of_slot(pb // group, group * page)],
+            out_specs=_of_slot(heads, width),
             scratch_shapes=[
                 pltpu.VMEM((_BUFFERS, group * page, lanes), pool.dtype),
                 pltpu.SemaphoreType.DMA((_BUFFERS, group)),
@@ -329,10 +407,74 @@ def latent_decode_attention_kernel(q_row, pool, layer, table, count, flags,
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name=KERNEL_NAME,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      jnp.maximum(table, 0).astype(jnp.int32).reshape(-1), count, next_live,
-      q_row, pool,
+      *_walked(table, count), q_row, pool,
       flags.astype(jnp.int32).reshape(slots, pb // group, group * page))
     return out[..., :rank]
+
+
+def _index_kernel(layer_ref, table_ref, count_ref, next_ref,      # SMEM
+                  q_ref, w_ref, pool_hbm, o_ref, buf, sem, step_ref, *,
+                  pages_per_slot, group):
+    """One grid step a slot: its index queries ``q_ref`` [1, HI, dI],
+    their weights ``w_ref`` [1, HI, 1] float32, its scores ``o_ref`` [1,
+    PB / group, group x page] float32, a row a group of the walk; the
+    page buffers, their semaphores and ``step_ref``: ``_walk``'s."""
+    q, w = q_ref[0], w_ref[0]
+    count = count_ref[pl.program_id(0)]
+    keys = o_ref.shape[2]
+    # the groups the walk never reaches (and a dead slot's all)
+    o_ref[...] = jnp.full_like(o_ref, _MASKED)
+
+    def group_body(g, rows):
+        # ``index_scores``' arithmetic: exact products, float32 sums
+        dots = lax.dot_general(q, rows[:, :q.shape[1]],
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+        scores = jnp.sum(w * jnp.maximum(dots, 0.0), axis=0, keepdims=True)
+        at = g * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        # (past the count: pages not fetched, whatever the buffer held)
+        o_ref[0, pl.ds(g, 1), :] = jnp.where(at < count, scores, _MASKED)
+        return ()
+
+    _walk(layer_ref, table_ref, count_ref, next_ref, pool_hbm, buf, sem,
+          step_ref, pages_per_slot=pages_per_slot, group=group,
+          body=group_body)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def index_decode_scores_kernel(q, weights, pool, layer, table, count, *,
+                               interpret=False):
+    """The index kernel's launch: ``q`` [B, HI, dI], a step's index
+    queries; ``weights`` [B, HI] float32; ``pool`` [L, P, page, dI or
+    its whole lanes]: the run's index keys; ``table`` [B, PB] page ids
+    (-1 = hole); ``count`` [B]: the keys a slot's query sees, 0 for a
+    dead slot. Returns ``I`` [B, PB x page] float32, ``_MASKED`` from the
+    slot's count on."""
+    slots, heads, width = q.shape
+    page, pb = pool.shape[2], table.shape[1]
+    group = math.gcd(pb, _INDEX_GROUP)
+
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, pages_per_slot=pb, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(slots,),
+            in_specs=[_of_slot(heads, width), _of_slot(heads, 1),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=_of_slot(pb // group, group * page),
+            scratch_shapes=[
+                pltpu.VMEM((_BUFFERS, group * page, pool.shape[3]),
+                           pool.dtype),
+                pltpu.SemaphoreType.DMA((_BUFFERS, group)),
+                pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, pb // group, group * page),
+                                       jnp.float32),
+        # the slots in order on one core, as the latent kernel's
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name=INDEX_KERNEL_NAME,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *_walked(table, count),
+      q, weights.astype(jnp.float32)[..., None], pool)
+    return out.reshape(slots, pb * page)
 
 
 def _query_rows(inputs: LatentInputs, lanes: int):
@@ -350,6 +492,23 @@ def _query_rows(inputs: LatentInputs, lanes: int):
         [q_latent.astype(q.dtype), q[..., dn:],
          jnp.zeros((*q.shape[:2], lanes - inputs.row.shape[-1]), q.dtype)],
         -1)
+
+
+def _scored_in_place(q, weights, pool, layer, table, count):
+    """The index kernel's formulation; arguments as ``_scored_gathered``'s."""
+    return index_decode_scores_kernel(q[:, 0], weights[:, 0], pool, layer,
+                                      table, count)
+
+
+def _scored_gathered(q, weights, pool, layer, table, count):
+    """The plain formulation of a step's index scores (what the index
+    kernel is held to): ``q`` [B, 1, HI, dI] and ``weights`` [B, 1, HI]
+    against every key of the table's pages, copied out of ``pool``; [B,
+    PB x page] float32, ``_MASKED`` from the slot's ``count`` on."""
+    keys = gather_rows(pool, layer, table)                    # [B, S, dI]
+    scores = index_scores(q, weights, keys)[:, 0]
+    seen = jnp.arange(keys.shape[1], dtype=jnp.int32) < count[:, None]
+    return jnp.where(seen, scores, _MASKED)
 
 
 def _in_place(q_row, pool, layer, table, count, chosen, *, rank, scale,
@@ -428,11 +587,13 @@ def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
     slots' index keys and attends over the ``topk`` best, a windowed
     layer over its window. Returns [B, H, dv].
 
-    On a program lowered for a TPU, where ``latent_kernel_engages``, the
-    rows are read in place by the kernel; everywhere else they are
-    gathered (module docstring). A windowed layer's are gathered on every
-    platform: its gather is whole pages already, and through the kernel
-    it took as long (module docstring)."""
+    On a program lowered for a TPU, where ``index_kernel_engages``, the
+    index keys are scored in place by the index kernel and, where
+    ``latent_kernel_engages``, the rows are read in place by the latent
+    kernel; everywhere else they are gathered (module docstring). A
+    windowed layer's are gathered on every platform: its gather is whole
+    pages already, and through the kernel it took as long (module
+    docstring)."""
     pool = pools[0]
     page, r = pool.shape[2], inputs.wkv_b.shape[0]
     index = inputs.index
@@ -442,10 +603,13 @@ def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
     if index is not None and table.shape[1] * page > index.topk:
         # (a table of no more than ``topk`` keys: nothing is dropped)
         topk = index.topk
-        keys = gather_rows(pools[1], layer, table)            # [B, S, dI]
-        scores = index_scores(index.q, index.weights, keys)[:, 0]
-        seen = jnp.arange(keys.shape[1], dtype=jnp.int32) < count[:, None]
-        args += (jnp.where(seen, scores, _MASKED),)
+        scored = (index.q, index.weights, pools[1], layer, table, count)
+        if index_kernel_engages(page, table.shape[1], topk,
+                                index.q.shape[-1]):
+            args += (lax.platform_dependent(
+                *scored, tpu=_scored_in_place, default=_scored_gathered),)
+        else:
+            args += (_scored_gathered(*scored),)
     in_place, gathered = _formulations(r, inputs.scale, topk, window)
     if latent_kernel_engages(page, table.shape[1], topk):
         o_latent = lax.platform_dependent(*args, tpu=in_place,

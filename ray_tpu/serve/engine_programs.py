@@ -90,7 +90,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.decoding import select_tokens
-from ray_tpu.ops.latent_attention import (latent_decode_attention,
+from ray_tpu.ops.latent_attention import (index_kernel_engages,
+                                          latent_decode_attention,
                                           latent_kernel_engages,
                                           latent_prefill_attention,
                                           write_latent)
@@ -402,6 +403,10 @@ class EnginePrograms:
         self._state_kernel = on_tpu and any(
             state_kernel_engages(a) for a in self.state)
         self._latent_backend = on_tpu and self.selects is not None
+        # an index key's width (pools: latent rows, then index keys)
+        self._index_width = next(
+            (run.rows[1].width for run in plan if run.selects is not None),
+            None)
         # the pages a step of the decode kernel's walk takes over the K/V
         # twins (``ops/paged_decode_attention.py``'s rule on their shape)
         self._attn_step_pages = (
@@ -526,8 +531,9 @@ class EnginePrograms:
     def decode_kernels(self, pages: int) -> dict:
         """Whether a decode program over a table ``pages`` wide advances
         the slots' state in the state kernel and reads the rows its
-        layers pick in the latent kernel (``latent_kernel_engages``, on
-        the program's own table), and the pages a step of its attention
+        layers pick in the latent kernel, scored in the index kernel
+        (``latent_kernel_engages``, ``index_kernel_engages``, on the
+        program's own table), and the pages a step of its attention
         kernel's walk takes (0: no layer attends over K/V twins, or no
         kernel does)."""
         return {
@@ -535,7 +541,11 @@ class EnginePrograms:
             "state_kernel": int(self._state_kernel),
             "latent_kernel": int(
                 self._latent_backend and latent_kernel_engages(
-                    self.page_size, pages, self.selects))}
+                    self.page_size, pages, self.selects)),
+            "index_kernel": int(
+                self._latent_backend and index_kernel_engages(
+                    self.page_size, pages, self.selects,
+                    self._index_width))}
 
 
 def _paged_decode_impl(cfg, params, *args, chunk, page_size,

@@ -252,11 +252,13 @@ class PagedLLMEngine:
         # rows whose recurrent state a prefill wrote into a slot
         self.state_installs = 0
         # decode dispatches, and those whose program advances the state
-        # in the state kernel and reads the rows its layers pick in the
-        # latent kernel (``EnginePrograms.decode_kernels``)
+        # in the state kernel, reads the rows its layers pick in the
+        # latent kernel and scores their index keys in the index kernel
+        # (``EnginePrograms.decode_kernels``)
         self.decode_dispatches = 0
         self.state_kernel_dispatches = 0
         self.latent_kernel_dispatches = 0
+        self.index_kernel_dispatches = 0
         # what became of every slot-step the decode programs computed
         # (chunk x max_batch a dispatch), counted where a chunk is read
         # back (_sync_chunk): delivered + overrun_tail + overrun_ahead +
@@ -1019,6 +1021,7 @@ class PagedLLMEngine:
             self.decode_dispatches += 1
             self.state_kernel_dispatches += kernels["state_kernel"]
             self.latent_kernel_dispatches += kernels["latent_kernel"]
+            self.index_kernel_dispatches += kernels["index_kernel"]
             now = time.monotonic()
             stream_seq = next(self._stream_seq)
             if ph:
@@ -1039,12 +1042,14 @@ class PagedLLMEngine:
                                (rows > programs.window).sum()))
                 if programs.selects is not None:
                     # a layer with an indexer scores every row's index
-                    # key and attends over the rows it picks: gathered,
-                    # or read in place among the slot's (the kernel)
+                    # key (gathered, or where it lies: the index kernel)
+                    # and attends over the rows it picks: gathered, or
+                    # read in place among the slot's (the latent kernel)
                     ph.set(index_rows=int(rows.sum()),
                            kv_rows_selected=int(
                                np.minimum(rows, programs.selects).sum()),
-                           latent_kernel=kernels["latent_kernel"])
+                           latent_kernel=kernels["latent_kernel"],
+                           index_kernel=kernels["index_kernel"])
                 if programs.recurrent is not None:
                     # the live slots' recurrent state, which one step
                     # reads once and writes once in every layer, and
@@ -1246,7 +1251,8 @@ class PagedLLMEngine:
         "total_generated", "total_finished", "prefill_dispatches",
         "prefill_kernel_dispatches", "expert_kernel_dispatches",
         "decode_dispatches", "state_kernel_dispatches",
-        "latent_kernel_dispatches", "decode_slot_steps", "decode_delivered",
+        "latent_kernel_dispatches", "index_kernel_dispatches",
+        "decode_slot_steps", "decode_delivered",
         "decode_overrun_tail", "decode_overrun_ahead", "decode_vacant",
         "retirements_foreseen", "slots_handed_over", "prefill_token_rows",
         "prefill_new_tokens", "state_installs")

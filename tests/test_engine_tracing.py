@@ -728,6 +728,23 @@ def _decode_spans_of(eng, prompts, new_tokens=9):
     return decodes, eng.stats()
 
 
+def _note_engine_on(monkeypatch, backend, **changes):
+    """An engine over the tiny latent plan (``changes`` to its
+    configuration; pages of 128, a table of two) that FINDS ``backend``
+    as it is built; its programs still lower for the CPU."""
+    from ray_tpu.models import dots3_note
+    from ray_tpu.serve import engine_programs
+
+    cfg = dots3_note.dots3_note_tiny(**changes)
+    monkeypatch.setattr(engine_programs.jax, "default_backend",
+                        lambda: backend)
+    eng = PagedLLMEngine(cfg, dots3_note.init_params(cfg, jax.random.key(0)),
+                         max_batch=2, max_len=256, page_size=128,
+                         num_pages=8)
+    monkeypatch.undo()
+    return eng
+
+
 _LATENT_KERNEL_CASES = [
     # the backend the engine finds, the keys its full layers keep (of a
     # table of 256), latent_kernel
@@ -749,16 +766,7 @@ def test_decode_dispatches_say_whether_their_program_holds_the_latent_kernel(
     where the table holds more than ``topk`` keys and no more than eight
     times as many, 0 where it holds twenty times as many and 0 where
     nothing is selected."""
-    from ray_tpu.models import dots3_note
-    from ray_tpu.serve import engine_programs
-
-    cfg = dots3_note.dots3_note_tiny(index_topk=topk)
-    monkeypatch.setattr(engine_programs.jax, "default_backend",
-                        lambda: backend)
-    eng = PagedLLMEngine(cfg, dots3_note.init_params(cfg, jax.random.key(0)),
-                         max_batch=2, max_len=256, page_size=128,
-                         num_pages=8)
-    monkeypatch.undo()
+    eng = _note_engine_on(monkeypatch, backend, index_topk=topk)
     rng = np.random.default_rng(3)
     decodes, stats = _decode_spans_of(
         eng, [rng.integers(1, 100, n) for n in (70, 7, 90)])
@@ -766,6 +774,42 @@ def test_decode_dispatches_say_whether_their_program_holds_the_latent_kernel(
     assert [s["attrs"]["latent_kernel"] for s in decodes] == \
         [engaged] * len(decodes)
     assert stats["latent_kernel_dispatches"] == engaged * len(decodes)
+
+
+_INDEX_KERNEL_CASES = [
+    # the backend the engine finds, the keys its full layers keep (of a
+    # table of 256), an index key's width, latent_kernel, index_kernel
+    ("cpu", 64, 128, 0, 0), ("tpu", 64, 128, 1, 1), ("tpu", 64, 16, 1, 0),
+    ("tpu", 12, 128, 0, 1), ("tpu", 256, 128, 0, 0)]
+
+
+@pytest.mark.parametrize(
+    "backend,topk,width,latent,index", _INDEX_KERNEL_CASES,
+    ids=[f"{b}-top{n}-key{w}" for b, n, w, _, _ in _INDEX_KERNEL_CASES])
+def test_decode_dispatches_say_whether_their_program_holds_the_index_kernel(
+        monkeypatch, backend, topk, width, latent, index):
+    """``index_kernel`` on ``engine.dispatch_decode`` is what
+    ``EnginePrograms.decode_kernels`` says of the dispatched program's
+    own table (two pages of 128 here): the rule its full layers' scores
+    were traced by (``ops/latent_attention.py``:
+    ``index_kernel_engages``), on a TPU backend alone; ``stats()`` counts
+    the decode dispatches that took it. 0 on the CPU whatever the
+    shapes; on an engine that finds a TPU backend 1 wherever the table
+    holds more than ``topk`` keys of whole lanes (past eight times
+    ``topk`` too, where the latent kernel does not engage), 0 for keys of
+    16 numbers and 0 where nothing is selected."""
+    eng = _note_engine_on(monkeypatch, backend, index_topk=topk,
+                          index_dim=width)
+    said = eng._programs.decode_kernels(2)
+    assert (said["latent_kernel"], said["index_kernel"]) == (latent, index)
+    rng = np.random.default_rng(3)
+    decodes, stats = _decode_spans_of(
+        eng, [rng.integers(1, 100, n) for n in (70, 7, 90)])
+    assert decodes and stats["decode_dispatches"] == len(decodes)
+    assert [s["attrs"]["index_kernel"] for s in decodes] == \
+        [index] * len(decodes)
+    assert stats["index_kernel_dispatches"] == index * len(decodes)
+    assert stats["latent_kernel_dispatches"] == latent * len(decodes)
 
 
 _ATTN_STEP_CASES = [
@@ -834,6 +878,10 @@ def test_the_older_plans_carry_no_latent_kernel_count(monkeypatch, family):
     assert decodes and stats["decode_dispatches"] == len(decodes)
     assert not any("latent_kernel" in s["attrs"] for s in decodes)
     assert stats["latent_kernel_dispatches"] == 0
+    # nor of the index kernel: a plan with no indexer
+    assert not any("index_kernel" in s["attrs"] for s in decodes)
+    assert stats["index_kernel_dispatches"] == 0
+    assert eng._programs.decode_kernels(2)["index_kernel"] == 0
 
 
 @pytest.mark.parametrize("family,backend,routes", [

@@ -5,7 +5,11 @@ shapes of the serving cell (640 and 1,152 lanes; few heads, small pages)
 and at tables that walk in groups of one, two, four and eight pages; the
 selection's set against ``lax.top_k``'s, key for key; the shape rule and
 what the lowered text holds on each side of it; and the tiny model
-through the kernel. (Its Mosaic compile at the real shapes:
+through the kernel. The index kernel (``index_decode_scores``) the same
+way: against ``gather_rows`` + ``index_scores`` + the count's mask over
+ragged slots and tables of 32 and 64 pages, the selection made from
+either, the entry through either, its own shape rule, and the tiny model
+through both kernels. (Their Mosaic compiles at the real shapes:
 tests/test_tpu_compile.py.)"""
 
 import math
@@ -175,7 +179,8 @@ def test_the_shape_rule_says_which_formulation_a_tpu_program_holds(
     assert la.latent_kernel_engages(128, table_pages, 256) is engages
     assert la.GATHER_PAST == 8
     text = _lowered(table_pages, "tpu")
-    assert ("tpu_custom_call" in text) is engages
+    # (past 8 x topk the index kernel still scores the keys in place)
+    assert ("tpu_custom_call" in text) is (table_pages * 128 > 256)
     assert (la.KERNEL_NAME in text) is engages
     # the rows copied out of the pool: the 256 chosen ones (or, at 1x,
     # the table's 256)
@@ -215,6 +220,190 @@ def test_the_model_decodes_through_the_kernel(through_the_kernel):
     against the expanded form over the same rows: 40 positions, so the
     selection drops keys from the thirteenth on, in one page of the whole
     prompt."""
+    cfg = dots3_note.dots3_note_tiny()
+    params = dots3_note.init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 40), 0, 128)
+    np.testing.assert_allclose(
+        dots3_note.forward(cfg, params, tokens, absorbed=True),
+        dots3_note.forward(cfg, params, tokens), atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# The index kernel
+# ---------------------------------------------------------------------------
+
+INDEX_HEADS, INDEX_DIM = 4, 128
+# slots, by what their count is: a dead slot; fewer keys than a page;
+# a count that ends mid-page; one on a page's boundary; one mid-table;
+# the last, set by the table: its every page
+INDEX_SLOTS = ("dead", "one-page", "mid-page", "boundary", "ragged", "full")
+
+
+def _index_case(table_pages, seed=0):
+    """Six slots over scattered pages of a stacked index pool: slot i's
+    pages end before the table does (holes, -1, from one page past its
+    last) but for the last slot, which fills the table; slot 2 has a
+    hole INSIDE its count too (page 0 is read there, as ``gather_rows``
+    reads it)."""
+    rng = np.random.default_rng(seed)
+    keys = table_pages * PAGE
+    counts = np.array([0, 9, 2 * PAGE + 5, 4 * PAGE, keys // 2 + 3, keys],
+                      np.int32)
+    pool_pages = len(counts) * table_pages + 3
+    pool = rng.standard_normal((LAYERS, pool_pages, PAGE, INDEX_DIM))
+    q = rng.standard_normal((len(counts), 1, INDEX_HEADS, INDEX_DIM))
+    weights = rng.standard_normal((len(counts), 1, INDEX_HEADS))
+    table = rng.permutation(pool_pages)[:counts.size * table_pages].reshape(
+        -1, table_pages)
+    for slot, n in enumerate(counts):
+        table[slot, -(-n // PAGE) + 1:] = -1
+    table[2, 1] = -1
+    return dict(
+        q=jnp.asarray(q, jnp.bfloat16),
+        weights=jnp.asarray(weights, jnp.float32),
+        pool=jnp.asarray(pool, jnp.bfloat16), layer=jnp.int32(1),
+        table=jnp.asarray(table, jnp.int32), count=jnp.asarray(counts))
+
+
+def _both_scores(case):
+    """(the index kernel's, the plain formulation's) [B, PB x page]."""
+    args = (case["pool"], case["layer"], case["table"], case["count"])
+    got = la.index_decode_scores_kernel(
+        case["q"][:, 0], case["weights"][:, 0], *args, interpret=True)
+    want = la._scored_gathered(case["q"], case["weights"], *args)
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("slot", range(len(INDEX_SLOTS)), ids=INDEX_SLOTS)
+@pytest.mark.parametrize("table_pages", [6, 12, 32, 64])
+def test_index_kernel_is_plain_index_scores(table_pages, slot):
+    """The kernel's scores of one slot against ``gather_rows`` +
+    ``index_scores`` + the count's mask: equal within the order of a
+    float32 sum at every position under the slot's count (a hole's too)
+    and the mask's own value, exactly, from the count on, in the pages
+    the walk fetched and in those it never reached; layer 1 of a stacked
+    pool; tables walked 2, 4 and 16 pages at a step (one group a slot at
+    6 x 16 keys... several at 64)."""
+    case = _index_case(table_pages)
+    assert math.gcd(table_pages, la._INDEX_GROUP) == {
+        6: 2, 12: 4, 32: 16, 64: 16}[table_pages]
+    got, want = _both_scores(case)
+    assert got.shape == want.shape == (len(INDEX_SLOTS), table_pages * PAGE)
+    assert got.dtype == np.float32
+    count = int(case["count"][slot])
+    np.testing.assert_allclose(got[slot, :count], want[slot, :count],
+                               rtol=1e-5, atol=1e-5)
+    assert (got[slot, count:] == la._MASKED).all()
+    assert (want[slot, count:] == la._MASKED).all()
+    assert (got[slot, :count] > la._MASKED).all()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("table_pages", [32, 64])
+def test_the_selection_is_the_same_from_either_scores(table_pages, seed):
+    """``kept`` over the kernel's scores and over the plain ones is one
+    mask, key for key, on seeded inputs (a selection of 24 of up to 1,024
+    keys a slot)."""
+    got, want = _both_scores(_index_case(table_pages, seed))
+    np.testing.assert_array_equal(
+        np.asarray(la.kept(jnp.asarray(got), TOPK)),
+        np.asarray(la.kept(jnp.asarray(want), TOPK)))
+
+
+def _score_in_place(patch):
+    """Every decode step's index scores of a layer that selects through
+    the index kernel in interpret mode, whatever the rule says of its
+    shapes: both branches of the entry's choice are the kernel's
+    formulation."""
+    patch.setattr(
+        la, "index_decode_scores_kernel",
+        partial(la.index_decode_scores_kernel, interpret=True))
+    patch.setattr(la, "_scored_gathered", la._scored_in_place)
+    patch.setattr(la, "index_kernel_engages",
+                  lambda page, table_pages, topk, width: True)
+
+
+@pytest.fixture
+def scored_in_place(monkeypatch):
+    _score_in_place(monkeypatch)
+
+
+def _entry(case, heads=4, rank=128, rope=64, nope=32, v=16):
+    """``latent_decode_attention`` of ``_index_case``'s slots, their
+    latent rows in a pool beside the index keys'."""
+    rng = np.random.default_rng(7)
+    slots = case["count"].shape[0]
+
+    def bf16(*dims):
+        return jnp.asarray(rng.standard_normal(dims) * 0.4, jnp.bfloat16)
+
+    inputs = la.LatentInputs(
+        bf16(slots, 1, heads, nope + rope), bf16(slots, 1, rank + rope),
+        bf16(rank, heads, nope + v), SCALE,
+        la.IndexInputs(case["q"], case["weights"],
+                       bf16(slots, 1, INDEX_DIM), TOPK))
+    pools = (bf16(*case["pool"].shape[:3], 256), case["pool"])
+    return jax.jit(partial(la.latent_decode_attention, inputs))(
+        pools, case["layer"], case["table"], case["count"] - 1,
+        active=case["count"] > 0)
+
+
+@pytest.mark.parametrize("table_pages", [6, 32, 64])
+def test_the_entry_gives_the_same_through_either_formulation(
+        table_pages, monkeypatch):
+    """``latent_decode_attention`` [B, H, dv] with its index scores from
+    the kernel against the same call with them gathered: the selection
+    is the same set, so the live slots' results are the same numbers."""
+    case = _index_case(table_pages)
+    plain = np.asarray(_entry(case), np.float32)
+    with monkeypatch.context() as patch:
+        _score_in_place(patch)
+        got = np.asarray(_entry(case), np.float32)
+    assert got.shape == (len(INDEX_SLOTS), 4, 16)
+    live = np.asarray(case["count"]) > 0
+    np.testing.assert_array_equal(got[live], plain[live])
+
+
+@pytest.mark.parametrize("page,table_pages,topk,width,engages", [
+    (128, 2, 256, 128, False),      # no more than topk keys: none dropped
+    (128, 4, 256, 128, True),
+    (128, 32, 256, 128, True),      # past GATHER_PAST too: it still scores
+    (128, 64, 2048, 128, True),     # the serving cell's
+    (16, 64, 256, 128, False),      # a page that is not whole lanes
+    (128, 64, 256, 64, False),      # a key that is not
+    (128, 64, 256, 256, True),
+    (128, 64, None, 128, False),    # no indexer
+], ids=["1x", "2x", "16x", "cell", "page-16", "width-64", "width-256",
+        "no-indexer"])
+def test_the_index_kernels_shape_rule(page, table_pages, topk, width,
+                                      engages):
+    assert la.index_kernel_engages(page, table_pages, topk, width) is engages
+
+
+@pytest.mark.parametrize("table_pages", [2, 4, 16, 32])
+def test_a_tpu_program_holds_the_index_kernel_where_its_layer_selects(
+        table_pages):
+    """Lowered for a TPU, a layer with an indexer scores its keys in the
+    index kernel wherever its table holds more than ``topk`` keys, on
+    both sides of ``GATHER_PAST``, and copies no table's worth of index
+    keys out of the pool; lowered for the CPU it holds the gather and no
+    kernel, as it did."""
+    engages = table_pages * 128 > 256
+    assert la.index_kernel_engages(128, table_pages, 256, 128) is engages
+    text = _lowered(table_pages, "tpu")
+    assert (la.INDEX_KERNEL_NAME in text) is engages
+    gathered_keys = f"tensor<2x{table_pages}x128x128xbf16>"
+    cpu = _lowered(table_pages, "cpu")
+    assert la.INDEX_KERNEL_NAME not in cpu and "tpu_custom_call" not in cpu
+    if engages:
+        assert gathered_keys not in text and gathered_keys in cpu
+
+
+def test_the_model_decodes_through_both_kernels(through_the_kernel,
+                                                scored_in_place):
+    """The tiny model one query at a time with the full layers' index
+    scores from the index kernel and their attention in the latent
+    kernel, against the expanded form over the same rows."""
     cfg = dots3_note.dots3_note_tiny()
     params = dots3_note.init_params(cfg, jax.random.key(0))
     tokens = jax.random.randint(jax.random.key(1), (2, 40), 0, 128)

@@ -490,6 +490,19 @@ def _serving_model(name):
         return laguna, dataclasses.replace(
             laguna.laguna_s_2_1(), layer_types=(laguna._PERIOD * 2)[:5],
             n_experts_held=64, vocab_size=25088)
+    if name == "falcon-h1-d4":
+        from ray_tpu.models import falcon_h1
+
+        return falcon_h1, dataclasses.replace(
+            falcon_h1.falcon_h1_34b_instruct(), n_layers=_H1_LAYERS)
+    if name == "smallthinker-d8":
+        from ray_tpu.models import smallthinker
+
+        # serve-brief-gen's: full, three sliding, twice
+        period = (0, 1, 1, 1)
+        return smallthinker, dataclasses.replace(
+            smallthinker.smallthinker_21b_a3b(),
+            sliding_window_layout=period * 2, rope_layout=period * 2)
     return olmoe, dataclasses.replace(olmoe.olmoe_1b_7b(), n_layers=10)
 
 
@@ -778,10 +791,7 @@ def test_falcon_h1_d4_engine_programs_fit_beside_their_state(v5e_2x2,
     ``c``, one that read it again and wrote it through a fused update:
     both are gone from the text), and the program's temporaries, under
     1 GB, hold no second state of 2.1 GB."""
-    from ray_tpu.models import falcon_h1
-
-    cfg = dataclasses.replace(falcon_h1.falcon_h1_34b_instruct(),
-                              n_layers=_H1_LAYERS)
+    falcon_h1, cfg = _serving_model("falcon-h1-d4")
     compiled = _compile_engine_program(
         v5e_2x2[0], falcon_h1, cfg, _H1_PAGES, program, dims,
         slots=_H1_SLOTS)
@@ -1020,12 +1030,7 @@ def test_brief_d8_decode_program_reads_its_pages_in_place(v5e_2x2):
     2304, 512, 128]`` by a bitcast of the pool ``write_kv`` scattered
     into, and no operation that copies, slices or rewrites a pool of
     either shape; the pools are donated and come back in place."""
-    from ray_tpu.models import smallthinker
-
-    period = (0, 1, 1, 1)
-    cfg = dataclasses.replace(
-        smallthinker.smallthinker_21b_a3b(),
-        sliding_window_layout=period * 2, rope_layout=period * 2)
+    smallthinker, cfg = _serving_model("smallthinker-d8")
     compiled = _compile_engine_program(
         v5e_2x2[0], smallthinker, cfg, _BRIEF_PAGES, "decode", (16, 64))
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -1074,6 +1079,40 @@ def test_latent_kernel_compiles_alone(v5e_2x2, heads, rank, lanes, table):
         shape((_NOTE_SLOTS, table * 128), jnp.bool_)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+# the index kernel's instruction, under its name: the index keys' pool
+# among its operands, the scores (a row a step of its walk: 16 pages of
+# 128 keys) its result
+_INDEX_KERNEL = re.compile(
+    r"%index_decode_scores[.\d]* = f32\[64,4,2048\]\S* custom-call\("
+    r".*tpu_custom_call.*bf16\[2,2816,128,128\]")
+
+
+@pytest.mark.parametrize("heads,table,pool_pages", [
+    (64, 64, _NOTE_PAGES), (64, 32, _NOTE_PAGES), (4, 6, 600)],
+    ids=["full-layer", "half-table", "groups-of-two"])
+def test_index_kernel_compiles_alone(v5e_2x2, heads, table, pool_pages):
+    """The index kernel by itself at the cell's shape (64 slots, 64 index
+    heads of 128 numbers, the stacked pool of two full layers) in the
+    cell's two tables, and at a table it walks two pages at a time: the
+    chip's compiler takes the page buffers' slices, the weights' column
+    and the scores' rows."""
+    from ray_tpu.ops.latent_attention import index_decode_scores_kernel
+
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(index_decode_scores_kernel).lower(
+        shape((_NOTE_SLOTS, heads, 128), jnp.bfloat16),
+        shape((_NOTE_SLOTS, heads), jnp.float32),
+        shape((2, pool_pages, 128, 128), jnp.bfloat16), shape((), jnp.int32),
+        shape((_NOTE_SLOTS, table), jnp.int32),
+        shape((_NOTE_SLOTS,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def _lower_note_program(device, program, dims):
@@ -1134,16 +1173,46 @@ def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
     sliding layers gather their five pages as they did, and the program
     needs less beside its arguments than the gathered one did (0.67
     GB). Its 64 rows a step are under the routed experts' line: no
-    grouped expert kernel."""
+    grouped expert kernel. Since PR 53 each run of full layers holds the
+    index kernel too: no operation copies the table's 4,096 pages of
+    index keys out of the pool (``bf16[4096,128,128]``, 134 MB), and the
+    temporaries fall from the parent's 0.570 GB to 0.475 GB: what the
+    program of the 32-page table needed, whose copy was half as large
+    (the peak is no longer the indexer's)."""
     _, lowered = _lower_note_program(v5e_2x2[0], "decode",
                                      (16, _NOTE_TABLE))
     compiled = lowered.compile()
     text = compiled.as_text()
     assert len(_LATENT_KERNEL.findall(text)) == 2
+    assert len(_INDEX_KERNEL.findall(text)) == 2
     assert "bf16[131072,640]" not in text
+    assert "bf16[4096,128,128]" not in text
     assert "bf16[320,128,1152]" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.62e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.48e9
     assert not _EXPERT_KERNEL.search(text)
+
+
+# a serving cell's decode program: pool pages, table pages, the engine's
+# slots where they are not 32 (as the tests above compile them)
+_OTHER_DECODE_PROGRAMS = {
+    "d12": (_D12_PAGES, 16, {}), "olmoe-d10": (_MOE_PAGES, 8, {}),
+    "laguna-ep4-d5": (2304, 32, {"slots": 64}),
+    "falcon-h1-d4": (_H1_PAGES, 8, {"slots": _H1_SLOTS}),
+    "smallthinker-d8": (_BRIEF_PAGES, 64, {})}
+
+
+@pytest.mark.parametrize("family", _OTHER_DECODE_PROGRAMS)
+def test_the_other_families_decode_programs_hold_no_index_kernel(v5e_2x2,
+                                                                 family):
+    """No other configuration states a layer with an indexer: the decode
+    program of each holds neither the index kernel nor the latent one."""
+    module, cfg = _serving_model(family)
+    pages, table, slots = _OTHER_DECODE_PROGRAMS[family]
+    text = _compile_engine_program(
+        v5e_2x2[0], module, cfg, pages, "decode", (16, table),
+        **slots).as_text()
+    assert "index_decode_scores" not in text
+    assert "latent_decode_attn" not in text
 
 
 def test_note_d5_cold_prefill_runs_its_experts_in_the_grouped_kernel(
