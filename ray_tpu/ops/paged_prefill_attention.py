@@ -44,15 +44,31 @@ VMEM:
   chunk is masked by position (v5e: 1.06 ms a call at two cold 2048-token
   prompts; masking only the chunks that straddle a block's diagonal, as a
   second loop, 1.18, and as a branch in one loop, 1.68);
+- under a ``window`` (static: a sliding layer's) the walk has its other
+  end too. It STARTS at the chunk that holds the oldest key the block's
+  first query sees, ``starts + i * block_q - window + 1`` (clamped at 0):
+  the chunks wholly before every query's window are neither fetched nor
+  computed, and whoever starts a block's first copies (the grid's first
+  block, the block before it, a block of padding) starts THAT chunk's.
+  The mask gains its second side, key ``> qpos - window``. A chunk can
+  be wholly masked for some rows of a block (the first one walked, for
+  the block's last rows): ``_MASKED`` is finite, so such a row's ``m``
+  rises at its first visible key and ``alpha`` zeroes what it summed
+  before. Without a window none of this is traced (the branches are
+  Python's, on the static argument): a full layer's kernel has no
+  first-chunk arithmetic and a one-sided mask, and its digests hold
+  (``tests/test_tpu_compile.py``);
 - online softmax in float32, probabilities cast to the pages' dtype
   before the weighted sum and the sum divided by the float32 denominator
   at the end, as the decode kernel does (``cached_attention`` normalises
   first: one unit in the last place of a bf16 output apart).
 
 ``paged_prefill_attention`` is the entry. The kernel ENGAGES by one rule
-on the traced shapes (``kernel_engages``): a full-attention layer, bf16
-pools, and float32 scores of the plain path over ``KERNEL_SCORES_BYTES``
-(256 MiB).
+on the traced shapes (``kernel_engages``): bf16 pools, and float32
+scores of the plain path for the layer over ``KERNEL_SCORES_BYTES`` (256
+MiB): a full layer's [n, heads, T, the table's keys]; a sliding layer's
+what its plain path writes block by block, [n, heads, T, ``query_block``
++ window] (or the table's keys where that is narrower).
 Every program that holds the kernel pays its trace and Mosaic lowering in
 every run, warm too, so the small programs (a prefix hit's suffix, a
 short prompt) stay the plain path's, with the lowered text they had. Over
@@ -121,7 +137,12 @@ def query_block(n: int, t: int, heads: int, keys: int, window) -> int:
     window of thousands of keys, or a wide group under a small one: at a
     window of 512 and 72 heads, groups of 8 rows and more
     (``4 * n * 72 * 512 * 1024``), which go in blocks of 128; up to 7
-    rows a window of 512 goes as it did before the bound."""
+    rows a window of 512 goes as it did before the bound.
+
+    Since the kernel takes a window the sliding branch serves the
+    programs UNDER the rule (a cached document's question, a short
+    suffix) and every platform but the TPU; ``kernel_engages`` reads the
+    block it gives to say what the plain path would write."""
     def scores(block):
         seen = keys if window is None else block + window
         return 4 * n * heads * block * seen
@@ -137,13 +158,18 @@ def kernel_engages(q_shape, pools, table_width: int, window) -> bool:
     """The rule (module docstring), from shapes, the layer's kind and the
     pools' dtype: whether the attention of a prefill's queries ``q_shape``
     [n, T, heads, hd] over ``table_width`` pages a row of ``pools`` (a
-    stacked pool, or its shape and dtype) is the kernel's on a TPU. The
-    kernel reads KV heads in pairs and head sizes in whole lanes."""
+    stacked pool, or its shape and dtype), under ``window`` keys where the
+    layer slides, is the kernel's on a TPU. The kernel reads KV heads in
+    pairs and head sizes in whole lanes."""
     n, t, heads, hd = q_shape
     page, nkv = pools.shape[2:4]
-    return (window is None and pools.dtype == jnp.bfloat16
-            and nkv % 2 == 0 and hd % 128 == 0
-            and 4 * n * heads * t * table_width * page > KERNEL_SCORES_BYTES)
+    keys = table_width * page
+    if window is not None:
+        # what the plain path writes for the layer: every block of its
+        # queries against the block's and a window's keys
+        keys = min(keys, query_block(n, t, heads, keys, window) + window)
+    return (pools.dtype == jnp.bfloat16 and nkv % 2 == 0 and hd % 128 == 0
+            and 4 * n * heads * t * keys > KERNEL_SCORES_BYTES)
 
 
 def paged_prefill_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
@@ -194,11 +220,13 @@ def paged_prefill_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
 
 def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
             q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, steps_ref, *,
-            grid, pages_per_row, chunk_pages):
+            grid, pages_per_row, chunk_pages, window):
     """See the module docstring. ``q_ref`` / ``o_ref``: the query heads of
     the pair's two KV heads at ``block_q`` positions, [1, block_q, 2 *
     group * hd]; ``steps_ref``: the chunks walked so far, which
-    names the buffer the next one lands in, carried from block to block."""
+    names the buffer the next one lands in, carried from block to block.
+    ``window`` is static: under None a walk starts at chunk 0 and no
+    statement of a first chunk is traced."""
     n, pairs, nq = grid
     b, pair, qi = (pl.program_id(i) for i in range(3))
     bq, hd = q_ref.shape[1], k_hbm.shape[4]
@@ -245,11 +273,21 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
     def live(row, block):
         return block * bq < slens_ref[row]
 
+    def first_chunk(row, block):
+        """The chunk that holds the oldest key a query of ``block`` sees:
+        its first query's, ``window - 1`` keys back (a full layer: 0)."""
+        if window is None:
+            return 0
+        oldest = (starts_ref[jnp.minimum(row, n - 1)] + block * bq
+                  - (window - 1))
+        return jnp.clip(oldest, 0, pages_per_row * page - 1) // ck
+
     # this block and the one after it, in the grid's order
     this = (b * pairs + pair) * nq + qi
     nxt = this + 1
     nb, nqi = nxt // (pairs * nq), nxt % nq
     next_live = (nxt < n * pairs * nq) & live(jnp.minimum(nb, n - 1), nqi)
+    next_chunk0 = first_chunk(nb, nqi)
 
     @pl.when(this == 0)
     def _():
@@ -257,7 +295,7 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
 
         @pl.when(live(0, 0))
         def _():
-            start(0, 0, 0)
+            start(0, first_chunk(0, 0), 0)
 
     first = qi * bq
     row_start, slen = starts_ref[b], slens_ref[b]
@@ -269,7 +307,13 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
         visible = jnp.minimum(row_start + jnp.minimum(first + bq, slen),
                               pages_per_row * page)
         n_chunks = (visible + ck - 1) // ck
+        chunk0 = first_chunk(b, qi)
         step0 = steps_ref[0]
+
+        def walked(j):
+            """The chunks of this block's walk before chunk ``j``: they,
+            not its place in the row, name a chunk's buffer."""
+            return j if window is None else j - chunk0
         # a KV head's rows: query head r of its group at position i ->
         # r * bq + i
         qs = [jnp.concatenate(
@@ -280,22 +324,26 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
         kcol = lax.broadcasted_iota(jnp.int32, (1, ck), 1)
 
         def chunk_body(j, carry):
-            buf = (step0 + j) % _BUFFERS
+            buf = (step0 + walked(j)) % _BUFFERS
             # the copies after this chunk's: the block's next chunk, or
             # the first of the next live block
             more = j + 1 < n_chunks
 
             @pl.when(more | next_live)
             def _():
-                start(jnp.where(more, b, nb), jnp.where(more, j + 1, 0),
-                      (step0 + j + 1) % _BUFFERS)
+                start(jnp.where(more, b, nb),
+                      jnp.where(more, j + 1, next_chunk0),
+                      (step0 + walked(j) + 1) % _BUFFERS)
 
             wait(0, buf)
             probs = []
             for (m, l, _), q, k in zip(carry, qs, heads_of_pair(k_buf, buf)):
                 s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
-                s = jnp.where(j * ck + kcol <= qpos, s, _MASKED)
+                seen = j * ck + kcol <= qpos
+                if window is not None:
+                    seen &= j * ck + kcol > qpos - window
+                s = jnp.where(seen, s, _MASKED)
                 m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
                 p = jnp.exp(s - m_new)
@@ -315,10 +363,12 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
                        jnp.zeros((rows, 1), jnp.float32),
                        jnp.zeros((rows, hd), jnp.float32))
                       for _ in range(2))
-        carry = lax.fori_loop(0, n_chunks, chunk_body, carry)
-        steps_ref[0] = step0 + n_chunks
+        carry = lax.fori_loop(chunk0, n_chunks, chunk_body, carry)
+        steps_ref[0] = step0 + walked(n_chunks)
         for h, (_, l, acc) in enumerate(carry):
-            # every row sees key 0, so l > 0
+            # every valid row sees its own key, so l > 0 (a row whose
+            # walk held nothing it sees, which is padding: l = the keys
+            # walked, every one at ``_MASKED``)
             out = (acc / l).astype(o_ref.dtype)
             for r in range(group):
                 at = (h * group + r) * hd
@@ -331,14 +381,14 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
 
         @pl.when(next_live)
         def _():
-            start(nb, 0, steps_ref[0] % _BUFFERS)
+            start(nb, next_chunk0, steps_ref[0] % _BUFFERS)
 
 
 def paged_prefill_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
                                    layer, table_rows, starts, slens, *,
-                                   interpret=False):
+                                   window=None, interpret=False):
     """The kernel's launch; arguments as ``paged_prefill_attention`` (bf16
-    pools, a full layer)."""
+    pools; ``window`` static, a sliding layer's or None)."""
     del k_scale, v_scale
     n, t, heads, hd = q.shape
     _, _, page, nkv, _ = k_pages.shape
@@ -353,7 +403,7 @@ def paged_prefill_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
     buffers = (_BUFFERS, chunk_pages * page, nkv, hd)
     out = pl.pallas_call(
         functools.partial(_kernel, grid=grid, pages_per_row=wp,
-                          chunk_pages=chunk_pages),
+                          chunk_pages=chunk_pages, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=grid,
             in_specs=[block, pl.BlockSpec(memory_space=pl.ANY),
@@ -392,13 +442,23 @@ def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
 
     Under the rule (``kernel_engages``) this IS the plain formulation,
     called directly: the program's lowered text is what it was. Over it
-    the two lowerings are the module's own functions, not closures made a
-    call, so the programs of an engine trace them once."""
+    the two lowerings are one pair of partials a window (``_lowerings``),
+    not closures made a call, so the programs of an engine trace them
+    once."""
     if not kernel_engages(q.shape, k_pages, table_rows.shape[1], window):
         return paged_prefill_attention_reference(
             q, k_pages, v_pages, k_scale, v_scale, layer, table_rows, starts,
             window=window)
+    kernel, plain = _lowerings(window)
     return lax.platform_dependent(
         q, k_pages, v_pages, k_scale, v_scale, layer, table_rows, starts,
-        slens, tpu=paged_prefill_attention_kernel,
-        default=paged_prefill_attention_reference)
+        slens, tpu=kernel, default=plain)
+
+
+@functools.lru_cache(maxsize=None)
+def _lowerings(window):
+    """The kernel and the plain formulation under ``window``: one pair of
+    partials a window, the same objects at every call."""
+    return (functools.partial(paged_prefill_attention_kernel, window=window),
+            functools.partial(paged_prefill_attention_reference,
+                              window=window))

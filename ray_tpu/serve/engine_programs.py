@@ -393,13 +393,16 @@ class EnginePrograms:
             (run.selects for run in plan if run.selects is not None), None)
         # which kernels a program lowered HERE can hold: each on a TPU
         # alone, for a plan with the layers it serves (full attention
-        # over K/V twins; a state whose arrays pass ``ops/ssm.py``'s rule;
-        # layers that pick their keys; a run whose weights hold a router)
+        # over K/V twins, and a window of them; a state whose arrays pass
+        # ``ops/ssm.py``'s rule; layers that pick their keys; a run whose
+        # weights hold a router)
         on_tpu = jax.default_backend() == "tpu"
         blocks = params["blocks"]
+        twin_runs = [run for run in plan if run.attends and run.rows is None]
         self._kernel_backend = on_tpu and any(
-            run.attends and run.window is None and run.rows is None
-            for run in plan)
+            run.window is None for run in twin_runs)
+        self._window_kernel_backend = on_tpu and any(
+            run.window is not None for run in twin_runs)
         self._state_kernel = on_tpu and any(
             state_kernel_engages(a) for a in self.state)
         self._latent_backend = on_tpu and self.selects is not None
@@ -516,15 +519,22 @@ class EnginePrograms:
 
     def prefill_kernels(self, group: int, bucket: int, pages: int) -> dict:
         """Whether the prefill program of ``group x bucket`` token-rows
-        over a window of ``pages`` attends in the prefill kernel and
-        computes its routed experts in the grouped one: the rules the
-        program itself is traced by (``kernel_engages``,
+        over a window of ``pages`` attends in the prefill kernel (its
+        full layers, ``attn_kernel``; its sliding layers, at their own
+        head count where the family states one, ``window_attn_kernel``)
+        and computes its routed experts in the grouped one: the rules
+        the program itself is traced by (``kernel_engages``,
         ``expert_kernel_engages``), on the host's own shapes."""
         cfg = self.cfg
         return {
             "attn_kernel": int(self._kernel_backend and kernel_engages(
                 (group, bucket, cfg.n_heads, cfg.head_dim), self.pools[0],
                 pages, None)),
+            "window_attn_kernel": int(
+                self._window_kernel_backend and kernel_engages(
+                    (group, bucket,
+                     getattr(cfg, "n_heads_sliding", cfg.n_heads),
+                     cfg.head_dim), self.pools[0], pages, self.window)),
             "expert_kernel": int(self._expert_backend
                                  and expert_kernel_engages(group * bucket))}
 

@@ -280,10 +280,12 @@ class PagedLLMEngine:
         # (``_retire_slot``)
         self._deferred_free: list[list] = []
         # prefill dispatches, and those whose program holds the prefill
-        # attention kernel and computes its routed experts in the grouped
-        # kernel (``EnginePrograms.prefill_kernels``)
+        # attention kernel (in its full layers; in its sliding layers)
+        # and computes its routed experts in the grouped kernel
+        # (``EnginePrograms.prefill_kernels``)
         self.prefill_dispatches = 0
         self.prefill_kernel_dispatches = 0
+        self.window_kernel_dispatches = 0
         self.expert_kernel_dispatches = 0
         # the token-rows the prefill programs computed (group x bucket a
         # dispatch) and the prompt tokens among them (the suffixes past
@@ -494,12 +496,14 @@ class PagedLLMEngine:
                              np.int32)
         slens_np = np.array([it[2] for it in part], np.int32) - starts_np
         wp = self._window_pages(int((starts_np + slens_np).max()))
-        # whether this program's full layers attend in the prefill
-        # kernel, and its routed experts run in the grouped one
+        # whether this program's full layers, and its sliding ones,
+        # attend in the prefill kernel, and its routed experts run in the
+        # grouped one
         kernels = self._programs.prefill_kernels(len(part), bucket, wp)
         token_rows, new_tokens = len(part) * bucket, int(slens_np.sum())
         self.prefill_dispatches += 1
         self.prefill_kernel_dispatches += kernels["attn_kernel"]
+        self.window_kernel_dispatches += kernels["window_attn_kernel"]
         self.expert_kernel_dispatches += kernels["expert_kernel"]
         self.prefill_token_rows += token_rows
         self.prefill_new_tokens += new_tokens
@@ -1249,7 +1253,8 @@ class PagedLLMEngine:
     # counted where ``__init__`` says what it is
     _COUNTS = (
         "total_generated", "total_finished", "prefill_dispatches",
-        "prefill_kernel_dispatches", "expert_kernel_dispatches",
+        "prefill_kernel_dispatches", "window_kernel_dispatches",
+        "expert_kernel_dispatches",
         "decode_dispatches", "state_kernel_dispatches",
         "latent_kernel_dispatches", "index_kernel_dispatches",
         "decode_slot_steps", "decode_delivered",

@@ -177,8 +177,9 @@ def test_every_dispatch_has_counts_and_one_device_run(profiled):
     for s in prefills:
         assert {"seq", "group", "bucket", "token_rows", "new_tokens",
                 "cached_tokens", "missed_pages", "attn_kernel",
-                "expert_kernel"} <= set(s["attrs"])
+                "window_attn_kernel", "expert_kernel"} <= set(s["attrs"])
         assert s["attrs"]["attn_kernel"] == 0       # lowered for the CPU
+        assert s["attrs"]["window_attn_kernel"] == 0
         assert s["attrs"]["expert_kernel"] == 0
     for s in decodes:
         a = s["attrs"]
@@ -522,6 +523,63 @@ def test_prefill_dispatches_say_whether_their_program_holds_the_kernel(
     cfg = tiny[0]
     assert seen[0] == ((1, 64, cfg.n_heads, cfg.head_dim),
                        eng._programs.pools[0].shape, 4, None)
+    # a plan without a sliding run asks the rule nothing about a window
+    assert not eng._programs._window_kernel_backend
+    assert {call[3] for call in seen} == {None}
+    assert [s["attrs"]["window_attn_kernel"] for s in spans] == [0] * 4
+    assert stats["window_kernel_dispatches"] == 0
+
+
+@pytest.mark.parametrize("sliding_heads", [None, 18],
+                         ids=["smallthinker", "laguna-sliding-heads"])
+def test_prefill_dispatches_say_whether_their_sliding_layers_hold_the_kernel(
+        monkeypatch, sliding_heads):
+    """``window_attn_kernel`` beside ``attn_kernel``: the same rule asked
+    once more, with the plan's window and the sliding layers' own head
+    count where the family states one (Laguna's ``n_heads_sliding``), on
+    a TPU backend alone; ``stats()`` counts the dispatches with it as
+    ``window_kernel_dispatches``. The stand-in rule engages a full layer
+    from 64 tokens and a sliding one from 128: the two counters part."""
+    from ray_tpu.models import laguna, smallthinker
+    from ray_tpu.serve import engine_programs
+
+    model, cfg = ((smallthinker, smallthinker.smallthinker_tiny())
+                  if sliding_heads is None
+                  else (laguna, laguna.laguna_tiny()))
+    eng = make_engine((cfg, model.init_params(cfg, jax.random.key(0))))
+    programs = eng._programs
+    assert not programs._kernel_backend             # the CPU's
+    assert not programs._window_kernel_backend
+    assert eng.stats()["window_kernel_dispatches"] == 0
+    programs._kernel_backend = programs._window_kernel_backend = True
+    seen = []
+
+    def rule(q_shape, pools, table_width, window):
+        seen.append((q_shape[2], window))
+        return q_shape[1] >= (64 if window is None else 128)
+
+    monkeypatch.setattr(engine_programs, "kernel_engages", rule)
+    clear_ring()
+    tracing.enable_tracing()
+    try:
+        eng.start()
+        rng = np.random.default_rng(2)
+        for n in (50, 9, 70):
+            assert len(list(eng.submit(rng.integers(1, cfg.vocab_size, n),
+                                       max_new_tokens=4).tokens())) == 4
+        eng.stop()
+        spans = tracing.recorded_spans("engine.dispatch_prefill")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    got = [(s["attrs"]["bucket"], s["attrs"]["attn_kernel"],
+            s["attrs"]["window_attn_kernel"]) for s in spans]
+    assert got == [(64, 1, 0), (16, 0, 0), (128, 1, 1)]
+    stats = eng.stats()
+    assert stats["prefill_kernel_dispatches"] == 2
+    assert stats["window_kernel_dispatches"] == 1
+    assert seen[:2] == [(cfg.n_heads, None),
+                        (sliding_heads or cfg.n_heads, cfg.window)]
 
 
 _STATE_KERNEL_CASES = [

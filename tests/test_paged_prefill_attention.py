@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, smallthinker
 from ray_tpu.ops import paged_prefill_attention as ppa
 from ray_tpu.ops.paged_attention import quantize_kv
 from ray_tpu.serve import engine_programs
@@ -33,9 +33,14 @@ CASES = {
     "one-query-over-a-table-of-holes": ([48], [1], [0]),     # the warm-up's
 }
 # KV heads, query heads a KV head, rows a query block (so that every
-# layout walks several blocks of 16 positions)
+# layout walks several blocks of 16 positions): the last two are
+# SmallThinker's (a group of 7) and Laguna's sliding layers' (of 9)
 LAYOUTS = {"gqa-32x8": (8, 4, 64), "mha-16x16": (16, 1, 16),
-           "gqa-48x8": (8, 6, 96)}
+           "gqa-48x8": (8, 6, 96), "gqa-28x4": (4, 7, 112),
+           "gqa-72x8": (8, 9, 144)}
+# a full layer, and a sliding layer's keys under chunks of 32: a window
+# inside one chunk, and one that straddles two
+WINDOWS = {"full": None, "window-24": 24, "window-40": 40}
 
 
 def _inputs(nkv, group, starts, slens, reserved, seed=0):
@@ -54,23 +59,32 @@ def _inputs(nkv, group, starts, slens, reserved, seed=0):
             jnp.asarray(starts, jnp.int32), jnp.asarray(slens, jnp.int32))
 
 
+@pytest.mark.parametrize("window", WINDOWS, ids=list(WINDOWS))
 @pytest.mark.parametrize("case", CASES, ids=list(CASES))
 @pytest.mark.parametrize("layout", LAYOUTS, ids=list(LAYOUTS))
-def test_kernel_is_the_gather_formulation(monkeypatch, layout, case):
+def test_kernel_is_the_gather_formulation(monkeypatch, layout, case, window):
     """Layer 1 of a stacked pool whose other layers hold other numbers,
     pages in shuffled order, blocks of 16 queries and chunks of two pages
     (so a block's walk has whole chunks, a masked one and chunks it never
     fetches; a block of padding follows a live one and a live row a
-    padded one). The valid rows within a bf16 rounding of the gather
-    formulation's, the rows of padding finite."""
+    padded one). Under a window the walk has a first chunk too: the
+    blocks from position 48 on start past chunk 0 (``start-mid-page``:
+    its second block already), each next block's first copies are its
+    own first chunk's, the last rows of a block see nothing of the first
+    chunk it fetches (the first rows nothing of the last), and a row of
+    padding may see nothing of what was walked at all. The valid rows
+    within a bf16 rounding of the gather formulation's under the same
+    window, the rows of padding finite."""
     nkv, group, rows = LAYOUTS[layout]
     starts, slens, reserved = CASES[case]
+    window = WINDOWS[window]
     monkeypatch.setattr(ppa, "_BLOCK_ROWS", rows)
     monkeypatch.setattr(ppa, "_CHUNK_KEYS", 2 * PAGE)
     args = _inputs(nkv, group, starts, slens, reserved,
                    seed=nkv + len(case))
-    got = ppa.paged_prefill_attention_kernel(*args, interpret=True)
-    want = ppa.paged_prefill_attention_reference(*args)
+    got = ppa.paged_prefill_attention_kernel(*args, window=window,
+                                             interpret=True)
+    want = ppa.paged_prefill_attention_reference(*args, window=window)
     assert got.shape == args[0].shape and got.dtype == args[0].dtype
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert np.isfinite(got).all()
@@ -101,10 +115,25 @@ def _pool(dtype, nkv=8, hd=128, pages=544):
     ((2, 512, 16, 128), _pool(jnp.bfloat16, nkv=16), 8, None, False),
     ((2, 1024, 16, 128), _pool(jnp.bfloat16, nkv=16), 8, None, False),
     # Laguna: a full layer's 48 heads (two 512-token suffixes over 2048
-    # keys are 384 MiB); never a sliding layer
+    # keys are 384 MiB); a sliding layer's 72 by what the plain path
+    # writes for it, every block of 512 queries against 1,024 keys (the
+    # warm-up's 4,095 tokens 1.2 GB, a cold file 604 MB, two suffixes of
+    # 256 rows 113 MB)
     ((2, 512, 48, 128), _pool(jnp.bfloat16), 16, None, True),
     ((1, 512, 48, 128), _pool(jnp.bfloat16), 16, None, False),
-    ((1, 4096, 72, 128), _pool(jnp.bfloat16), 32, 512, False),
+    ((1, 4096, 72, 128), _pool(jnp.bfloat16), 32, 512, True),
+    ((1, 2048, 72, 128), _pool(jnp.bfloat16), 16, 512, True),
+    ((2, 256, 72, 128), _pool(jnp.bfloat16), 16, 512, False),
+    # serve-brief-gen's sliding layers, 28 heads on 4 under 4,096 keys:
+    # the cold document in blocks of 512 queries over 4,608 keys (4.2
+    # GB), a cached document's question over its window (60 MB)
+    ((1, 8192, 28, 128), _pool(jnp.bfloat16, nkv=4), 64, 4096, True),
+    ((1, 128, 28, 128), _pool(jnp.bfloat16, nkv=4), 64, 4096, False),
+    # a window wider than the table is no window: the table's keys
+    ((1, 2048, 28, 128), _pool(jnp.bfloat16, nkv=4), 16, 4096, True),
+    ((1, 1024, 28, 128), _pool(jnp.bfloat16, nkv=4), 16, 4096, False),
+    # int8 pages and odd shapes keep the plain path under a window too
+    ((1, 8192, 28, 128), _pool(jnp.int8, nkv=4), 64, 4096, False),
     # int8 pages keep the plain path; so do shapes the kernel cannot read
     ((2, 2048, 32, 128), _pool(jnp.int8), 16, None, False),
     ((2, 2048, 32, 128), _pool(jnp.bfloat16, nkv=1), 16, None, False),
@@ -143,18 +172,25 @@ def test_the_entry_off_the_tpu_is_the_plain_path(monkeypatch, pages):
         np.asarray(ppa.paged_prefill_attention_reference(*args), np.float32))
 
 
-def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch):
+@pytest.mark.parametrize("model,tiny", [
+    (llama, llama.llama_tiny), (smallthinker, smallthinker.smallthinker_tiny)],
+    ids=["llama", "smallthinker-window-8"])
+def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch,
+                                                            model, tiny):
     """The paged engine's greedy tokens with the kernel in its prefill
-    program (interpret mode, every full layer) are those of the gather
-    formulation: a cold prompt of three pages and a part, a second that
-    reuses its first two pages (a suffix behind ``starts`` 32), a short
-    one; the comparison is tests/test_paged_decode_attention.py's (the
-    same tokens, or a near tie by the model's own logits where the two
-    roundings part)."""
+    program (interpret mode, every layer that attends over K/V twins) are
+    those of the gather formulation: a cold prompt of three pages and a
+    part, a second that reuses its first two pages (a suffix behind
+    ``starts`` 32), a short one; the comparison is
+    tests/test_paged_decode_attention.py's (the same tokens, or a near
+    tie by the model's own logits where the two roundings part). The
+    second plan has two runs of three sliding layers under a window of 8
+    keys, which every prompt is past: the kernel is handed the run's
+    window and walks from the window's first chunk."""
     from test_paged_decode_attention import _same_greedy_choice
 
-    cfg = llama.llama_tiny()
-    params = llama.init_params(cfg, jax.random.key(0))
+    cfg = tiny()
+    params = model.init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(5)
     first = rng.integers(1, cfg.vocab_size, 53)
     prompts = [first, np.concatenate([first[:40],
@@ -163,8 +199,9 @@ def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch):
     kernel_calls = []
 
     def through_kernel(*args, window=None):
-        kernel_calls.append(args[0].shape)
-        return ppa.paged_prefill_attention_kernel(*args, interpret=True)
+        kernel_calls.append((args[0].shape, window))
+        return ppa.paged_prefill_attention_kernel(*args, window=window,
+                                                 interpret=True)
 
     def served(attention):
         monkeypatch.setattr(engine_programs, "paged_prefill_attention",
@@ -183,7 +220,9 @@ def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch):
     kernel, stats = served(through_kernel)
     gather, _ = served(ppa.paged_prefill_attention)
     assert kernel_calls and stats["prefix_cache"]["hit_pages"] == 2
+    assert {w for _, w in kernel_calls} == {
+        run.window for run in model.layer_plan(cfg)}
     assert [len(t) for t in kernel] == [12, 12, 12]
     for prompt, got, want in zip(prompts, kernel, gather):
-        assert _same_greedy_choice(llama, cfg, params, prompt, got, want)
+        assert _same_greedy_choice(model, cfg, params, prompt, got, want)
     assert sum(g == w for g, w in zip(kernel, gather)) >= 1

@@ -653,6 +653,7 @@ def _moved_shapes(text, cfg):
 
 # model, KV pages, prefill (prompts, tokens, window pages), the kernel's
 # instructions in the program, GB of temporaries it may need
+_BRIEF_PAGES = 2304
 _PREFILL_RULE = [
     # serve-doc's two widest programs
     ("d12", _D12_PAGES, (2, 2048, 16), 1, 0.5),
@@ -664,11 +665,19 @@ _PREFILL_RULE = [
     # everything serve-moe-gen warms stays plain (128 MiB at most)
     ("olmoe-d10", _MOE_PAGES, (2, 512, 8), 0, 0.8),
     ("olmoe-d10", _MOE_PAGES, (2, 1024, 8), 0, 0.8),
-    # Laguna's two runs of full layers (48 heads; an instruction each):
-    # a cold file, the warm-up's 4,095 tokens, and a short suffix
-    ("laguna-ep4-d5", 2304, (1, 2048, 16), 2, 1.4),
-    ("laguna-ep4-d5", 2304, (1, 4096, 32), 2, 1.4),
+    # Laguna's two runs of full layers (48 heads) and its run of three
+    # sliding ones between them (72 heads under 512 keys; PR 56), an
+    # instruction each: a cold file, two of them, the warm-up's 4,095
+    # tokens, and a short suffix
+    ("laguna-ep4-d5", 2304, (1, 2048, 16), 3, 1.4),
+    ("laguna-ep4-d5", 2304, (2, 2048, 16), 3, 1.4),
+    ("laguna-ep4-d5", 2304, (1, 4096, 32), 3, 1.4),
     ("laguna-ep4-d5", 2304, (1, 64, 32), 0, 1.4),
+    # serve-brief-gen's cold document (full, three sliding, twice: four
+    # runs; the parent's program needed 1.22848 GB of temporaries) and a
+    # cached document's question, under the rule in both kinds of layer
+    ("smallthinker-d8", _BRIEF_PAGES, (1, 8192, 64), 4, 1.2284),
+    ("smallthinker-d8", _BRIEF_PAGES, (1, 128, 64), 0, 0.1),
 ]
 
 
@@ -686,9 +695,14 @@ def test_prefill_programs_hold_the_kernel_by_the_rule(v5e_2x2, model, pages,
     held ``f32[2,8,4,2048,2048]``, 1 GiB, and about 2 GiB of temporaries
     with it; Laguna's went over its queries in blocks of a quarter of a
     GiB. A program under the rule holds no such instruction and is the
-    text it was (digests: tests/test_fused_projections.py). Laguna's
-    sliding layers keep their window-by-window path either way (their
-    blocks' scores end in 1,152 keys, not the window's pages). All fit,
+    text it was (digests: tests/test_fused_projections.py). Since PR 56
+    a run of SLIDING layers is held to the same rule by the scores the
+    plain path writes for it (every block of its queries against the
+    block's and a window's keys) and holds one instruction more: Laguna's
+    cold programs three (they went window by window, blocks of 512
+    queries over 1,152 gathered keys, ``f32[2,8,9,512,1152]``), and the
+    cold document of ``serve-brief-gen`` four, where its six sliding
+    layers wrote ``f32[1,4,7,512,4736]`` sixteen times a layer. All fit,
     and the kernel's need of the core's memory moves no weight stack: the
     loops of a program with the kernel copy what they copied without it,
     ONE layer of ``wq`` / ``wk`` / ``wv`` each (the per-layer transposes,
@@ -700,17 +714,29 @@ def test_prefill_programs_hold_the_kernel_by_the_rule(v5e_2x2, model, pages,
     assert len(_PREFILL_KERNEL.findall(text)) == kernels
     assert not _DECODE_KERNEL.search(text)
     # the routed experts' kernel follows its own rule, the rows alone:
-    # two instructions a run of expert layers (Laguna's two runs: four)
+    # two instructions a run of expert layers (Laguna's two runs: four;
+    # SmallThinker's four: eight)
     from ray_tpu.ops.moe import expert_kernel_engages
+    from ray_tpu.ops.paged_prefill_attention import query_block
 
-    expert_runs = {"d12": 0, "olmoe-d10": 1, "laguna-ep4-d5": 2}[model]
+    expert_runs = {"d12": 0, "olmoe-d10": 1, "laguna-ep4-d5": 2,
+                   "smallthinker-d8": 4}[model]
     assert len(_EXPERT_KERNEL.findall(text)) == (
         2 * expert_runs * expert_kernel_engages(dims[0] * dims[1]))
     assert "ragged-dot" not in text
     if model == "laguna-ep4-d5":
         assert not _expert_stack_moves(text, 64, 3072, 1024)
     if kernels:
-        assert not _score_arrays(text, dims[2] * 128)
+        # neither over the table's keys nor, where a run slides, over a
+        # block's and a window's (as counted, and in the whole pages the
+        # plain path gathers for them)
+        widths = {dims[2] * 128}
+        for window in {run.window for run in module.layer_plan(cfg)} - {None}:
+            seen = window + query_block(dims[0], dims[1], cfg.n_heads,
+                                        dims[2] * 128, window)
+            widths |= {seen, (-(-(seen - 2) // 128) + 1) * 128}
+        for keys in widths:
+            assert not _score_arrays(text, keys), keys
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
     assert not _pool_copy(cfg.n_layers, pages, cfg.n_kv_heads).findall(text)
     moved = _moved_shapes(text, cfg)
@@ -721,29 +747,80 @@ def test_prefill_programs_hold_the_kernel_by_the_rule(v5e_2x2, model, pages,
         assert moved == _moved_shapes(plain, cfg)
 
 
-@pytest.mark.parametrize("heads,kv_heads,rows,tokens,pages", [
-    (16, 16, 2, 2048, 16), (48, 8, 1, 4096, 32), (32, 8, 1, 2048, 16)],
-    ids=["mha-16x16", "gqa-48x8", "gqa-32x8"])
-def test_prefill_kernel_compiles_alone(v5e_2x2, heads, kv_heads, rows,
-                                       tokens, pages):
-    """The kernel by itself at the three head layouts the engine serves
-    (no whole program holds it at OLMoE's 16/16: that cell's contexts stop
-    at 1,024 tokens, under the rule)."""
+# the prefill kernel's launches: query heads, KV heads, rows, tokens a
+# row, table pages, window
+_PREFILL_KERNEL_SHAPES = {
+    "mha-16x16": (16, 16, 2, 2048, 16, None),
+    "gqa-48x8": (48, 8, 1, 4096, 32, None),
+    "gqa-32x8": (32, 8, 1, 2048, 16, None),
+    "gqa-28x4": (28, 4, 1, 8192, 64, None),
+    "gqa-28x4-window-4096": (28, 4, 1, 8192, 64, 4096),
+    "gqa-72x8-window-512": (72, 8, 2, 2048, 16, 512)}
+# sha256 (first 16 hex digits) of a full layer's launch as the commit
+# before the walk took a window (9951e21: PR 55's anchor) lowered it for a
+# v5e, by this file's own helpers laid over that tree, under
+# ``_PINNED_JAX``
+_PARENT_PREFILL_KERNEL = {
+    "mha-16x16": "15b0daf144d5fb75", "gqa-48x8": "83f850d6f27e9078",
+    "gqa-32x8": "9d9ee6b13224f1ad", "gqa-28x4": "3b9a8e7799b05dc6"}
+
+
+def _lower_prefill_kernel(device, heads, kv_heads, rows, tokens, pages,
+                          window):
     from ray_tpu.ops.paged_prefill_attention import (
         paged_prefill_attention_kernel)
 
-    one_chip = SingleDeviceSharding(v5e_2x2[0])
+    one_chip = SingleDeviceSharding(device)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     pool = shape((5, 600, 128, kv_heads, 128), jnp.bfloat16)
     scale = shape((5, 1, 1, 1), jnp.float32)
-    compiled = jax.jit(paged_prefill_attention_kernel).lower(
+    kernel = (paged_prefill_attention_kernel if window is None else partial(
+        paged_prefill_attention_kernel, window=window))
+    return jax.jit(kernel).lower(
         shape((rows, tokens, heads, 128), jnp.bfloat16), pool, pool, scale,
         scale, shape((), jnp.int32), shape((rows, pages), jnp.int32),
-        shape((rows,), jnp.int32), shape((rows,), jnp.int32)).compile()
+        shape((rows,), jnp.int32), shape((rows,), jnp.int32))
+
+
+@pytest.mark.parametrize("case", _PREFILL_KERNEL_SHAPES,
+                         ids=list(_PREFILL_KERNEL_SHAPES))
+def test_prefill_kernel_compiles_alone(v5e_2x2, case):
+    """The kernel by itself at the head layouts the engine serves in full
+    layers (no whole program holds it at OLMoE's 16/16: that cell's
+    contexts stop at 1,024 tokens, under the rule) and at the two it
+    serves under a window: SmallThinker's groups of 7 under 4,096 keys at
+    the cold document's 8,192 rows, Laguna's sliding groups of 9 under
+    512."""
+    compiled = _lower_prefill_kernel(
+        v5e_2x2[0], *_PREFILL_KERNEL_SHAPES[case]).compile()
     assert len(_PREFILL_KERNEL.findall(compiled.as_text())) == 1
+
+
+@pytest.mark.parametrize("case", _PARENT_PREFILL_KERNEL,
+                         ids=list(_PARENT_PREFILL_KERNEL))
+def test_a_full_layers_prefill_kernel_is_the_one_it_was(v5e_2x2, case):
+    """Without a window the kernel is the parent's instruction for
+    instruction (the window is a Python branch on a static argument, not
+    a traced select): its launch and its Mosaic body lower to the same
+    text, source locations left out. The prefill programs of
+    ``serve-doc`` and ``serve-chat``, Laguna's full layers and
+    SmallThinker's hold this kernel. A change MEANT to alter it pins its
+    new digest here, computed on its own tree."""
+    import hashlib
+
+    if jax.__version__ != _PINNED_JAX:
+        pytest.skip(f"digests pinned under jax {_PINNED_JAX}")
+    text = _located_nowhere(_lower_prefill_kernel(
+        v5e_2x2[0], *_PREFILL_KERNEL_SHAPES[case]).as_text())
+    assert ".py" not in text and "loc(" not in text
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == _PARENT_PREFILL_KERNEL[case])
+    windowed = _located_nowhere(_lower_prefill_kernel(
+        v5e_2x2[0], *_PREFILL_KERNEL_SHAPES[case][:-1], 512).as_text())
+    assert windowed != text         # the fence is not blind
 
 
 def test_the_plain_prefill_path_does_hold_score_arrays(v5e_2x2):
@@ -1018,8 +1095,7 @@ def test_decode_kernel_at_4_kv_heads_is_another(v5e_2x2):
 
 
 # SmallThinker-21BA3B-Instruct cut to its first eight layers
-# (``serve-brief-gen``): 32 slots, 2,304 pages, tables of 64 pages
-_BRIEF_PAGES = 2304
+# (``serve-brief-gen``): 32 slots, ``_BRIEF_PAGES`` pages, tables of 64
 
 
 def test_brief_d8_decode_program_reads_its_pages_in_place(v5e_2x2):
