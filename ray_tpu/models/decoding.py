@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import llama
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
@@ -157,11 +158,12 @@ def select_tokens(logits, temps, key):
     """The serving engine's per-slot token choice: greedy at temp 0,
     temperature-scaled categorical otherwise. ONE implementation: the
     engine's decode and prefill programs both call it."""
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(
-        jnp.int32)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    with jax.named_scope(scopes.SAMPLE):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.random.categorical(key, scaled, axis=-1).astype(
+            jnp.int32)
+        return jnp.where(temps > 0.0, sampled, greedy)
 
 
 @dataclass(frozen=True)

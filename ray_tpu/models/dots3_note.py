@@ -54,6 +54,7 @@ from jax import lax
 from ray_tpu.models.llama import (  # noqa: F401 - embed, head_logits:
     LayerStack, embed, fanin_init,  # pieces of the block's module that
     head_logits, lm_head_weights)   # are Llama's
+from ray_tpu.ops import scopes
 from ray_tpu.ops.latent_attention import (IndexInputs, LatentInputs,
                                           latent_decode_attention,
                                           latent_prefill_attention,
@@ -329,8 +330,9 @@ def _rotate_leading(x, sin, cos):
     """Rotary on the leading ``2 x sin.shape[-1]`` numbers of each head of
     ``x`` [b, s, heads, width]; the rest pass through."""
     rot = 2 * sin.shape[-1]
-    return jnp.concatenate(
-        [apply_rope(x[..., :rot], sin, cos), x[..., rot:]], axis=-1)
+    with jax.named_scope(scopes.ATTN_QKV):
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], sin, cos), x[..., rot:]], axis=-1)
 
 
 def _projected(cfg: Dots3NoteConfig, p, x):
@@ -351,35 +353,37 @@ def latent_projections(cfg: Dots3NoteConfig, p, x, sin, cos) -> LatentInputs:
     queries (from the query latent), head weights and the token's index
     key (layer-normed), both rotated over their first ``rope_dim``."""
     b, s, d = x.shape
-    y, sliding = _projected(cfg, p, x)
-    heads, rank, nope = cfg.shape(sliding)
-    rq, dr, dt = cfg.q_rank, cfg.rope_dim, x.dtype
-    cq = rms_norm(y[..., :rq], p["q_norm"], eps=cfg.rms_eps)
-    ckv = rms_norm(y[..., rq:rq + rank], p["kv_norm"], eps=cfg.rms_eps)
-    if cfg.rescale:
-        cq = cq * (d / rq) ** 0.5
-        ckv = ckv * (d / rank) ** 0.5
-    cq, ckv = cq.astype(dt), ckv.astype(dt)
-    q = (cq @ p["wq_b"]).reshape(b, s, heads, nope + dr)
-    q = jnp.concatenate(
-        [q[..., :nope], apply_rope(q[..., nope:], sin, cos)], axis=-1)
-    at = rq + rank
-    kr = apply_rope(y[..., None, at:at + dr], sin, cos)[:, :, 0]
-    index = None
-    if not sliding:
-        at += dr + heads
-        qi = (cq @ p["wi_q"]).reshape(b, s, cfg.index_heads, cfg.index_dim)
-        ki = layer_norm(y[..., at:at + cfg.index_dim], p["index_norm"],
-                        p["index_norm_bias"], eps=_INDEX_NORM_EPS)
-        index = IndexInputs(
-            _rotate_leading(qi, sin, cos),
-            y[..., at + cfg.index_dim:],
-            _rotate_leading(ki[..., None, :], sin, cos)[:, :, 0].astype(dt),
-            cfg.index_topk)
-    return LatentInputs(
-        q, jnp.concatenate([ckv, kr.astype(dt)], axis=-1),
-        p["wkv_b"].reshape(rank, heads, nope + cfg.v_dim),
-        (nope + dr) ** -0.5, index)
+    with jax.named_scope(scopes.ATTN_QKV):
+        y, sliding = _projected(cfg, p, x)
+        heads, rank, nope = cfg.shape(sliding)
+        rq, dr, dt = cfg.q_rank, cfg.rope_dim, x.dtype
+        cq = rms_norm(y[..., :rq], p["q_norm"], eps=cfg.rms_eps)
+        ckv = rms_norm(y[..., rq:rq + rank], p["kv_norm"], eps=cfg.rms_eps)
+        if cfg.rescale:
+            cq = cq * (d / rq) ** 0.5
+            ckv = ckv * (d / rank) ** 0.5
+        cq, ckv = cq.astype(dt), ckv.astype(dt)
+        q = (cq @ p["wq_b"]).reshape(b, s, heads, nope + dr)
+        q = jnp.concatenate(
+            [q[..., :nope], apply_rope(q[..., nope:], sin, cos)], axis=-1)
+        at = rq + rank
+        kr = apply_rope(y[..., None, at:at + dr], sin, cos)[:, :, 0]
+        index = None
+        if not sliding:
+            at += dr + heads
+            qi = (cq @ p["wi_q"]).reshape(b, s, cfg.index_heads, cfg.index_dim)
+            ki = layer_norm(y[..., at:at + cfg.index_dim], p["index_norm"],
+                            p["index_norm_bias"], eps=_INDEX_NORM_EPS)
+            index = IndexInputs(
+                _rotate_leading(qi, sin, cos),
+                y[..., at + cfg.index_dim:],
+                _rotate_leading(ki[..., None, :], sin, cos)[:, :, 0].astype(
+                    dt),
+                cfg.index_topk)
+        return LatentInputs(
+            q, jnp.concatenate([ckv, kr.astype(dt)], axis=-1),
+            p["wkv_b"].reshape(rank, heads, nope + cfg.v_dim),
+            (nope + dr) ** -0.5, index)
 
 
 def attention_output(cfg: Dots3NoteConfig, p, x, attn):
@@ -389,12 +393,13 @@ def attention_output(cfg: Dots3NoteConfig, p, x, attn):
     added to ``x`` [b, s, d]. (The projection through ``w_in`` is the one
     ``latent_projections`` took: the compiler computes it once.)"""
     b, s, _ = x.shape
-    y, sliding = _projected(cfg, p, x)
-    heads, rank, _ = cfg.shape(sliding)
-    at = cfg.q_rank + rank + cfg.rope_dim
-    gate = jax.nn.sigmoid(y[..., at:at + heads])             # [b, s, heads]
-    attn = attn.reshape(b, s, heads, cfg.v_dim) * gate[..., None]
-    return x + attn.astype(x.dtype).reshape(b, s, -1) @ p["wo"]
+    with jax.named_scope(scopes.ATTN_OUT):
+        y, sliding = _projected(cfg, p, x)
+        heads, rank, _ = cfg.shape(sliding)
+        at = cfg.q_rank + rank + cfg.rope_dim
+        gate = jax.nn.sigmoid(y[..., at:at + heads])         # [b, s, heads]
+        attn = attn.reshape(b, s, heads, cfg.v_dim) * gate[..., None]
+        return x + attn.astype(x.dtype).reshape(b, s, -1) @ p["wo"]
 
 
 def feed_forward(cfg: Dots3NoteConfig, p, x, valid=None, stacked=None):
@@ -411,8 +416,9 @@ def feed_forward(cfg: Dots3NoteConfig, p, x, valid=None, stacked=None):
     b, s, d = x.shape
     h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
     if "w_gate" in p:
-        gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
-        return x + gated @ p["w_down"], {}
+        with jax.named_scope(scopes.FFN):
+            gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
+            return x + gated @ p["w_down"], {}
     held, layer = (p, None) if stacked is None else stacked
     routed, load = moe_ffn_dropless(
         h.reshape(b * s, d), p["router"], held["wi_gate"], held["wi_up"],
@@ -421,9 +427,12 @@ def feed_forward(cfg: Dots3NoteConfig, p, x, valid=None, stacked=None):
         routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
         valid=None if valid is None else valid.reshape(b * s),
         scoring="sigmoid", choice_bias=p["router_bias"])
-    shared = (jax.nn.silu(h @ p["ws_gate"]) * (h @ p["ws_up"])) @ p["ws_down"]
+    with jax.named_scope(scopes.SHARED_EXPERT):
+        shared = (jax.nn.silu(h @ p["ws_gate"])
+                  * (h @ p["ws_up"])) @ p["ws_down"]
     stats = share_statistics(load, valid, b * s, cfg.top_k)
-    return x + routed.reshape(b, s, d) + shared, stats
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return x + routed.reshape(b, s, d) + shared, stats
 
 
 # ---------------------------------------------------------------------------

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.llama import LayerStack, fanin_init, lm_head_weights
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
@@ -264,16 +265,19 @@ def init_params(cfg: FalconH1Config, key) -> dict:
 def embed(cfg: FalconH1Config, params, tokens):
     """Token ids -> the stream's start, ``embedding_multiplier`` applied
     (the engine's programs ask the module that has this piece for it)."""
-    x = params["embedding"][tokens]
-    return (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(x.dtype)
+    with jax.named_scope(scopes.EMBED):
+        x = params["embedding"][tokens]
+        return (x.astype(jnp.float32)
+                * cfg.embedding_multiplier).astype(x.dtype)
 
 
 def head_logits(cfg: FalconH1Config, params, x):
     """Normed last hidden states [b, d] -> float32 logits [b, vocab],
     ``lm_head_multiplier`` applied."""
-    return jnp.einsum("bd,dv->bv", x, lm_head_weights(cfg, params),
-                      preferred_element_type=jnp.float32
-                      ) * cfg.lm_head_multiplier
+    with jax.named_scope(scopes.LM_HEAD):
+        return jnp.einsum("bd,dv->bv", x, lm_head_weights(cfg, params),
+                          preferred_element_type=jnp.float32
+                          ) * cfg.lm_head_multiplier
 
 
 def attention_projections(cfg: FalconH1Config, p, x, sin, cos):
@@ -284,21 +288,24 @@ def attention_projections(cfg: FalconH1Config, p, x, sin, cos):
     [b, s, kv heads, hd])."""
     b, s, _ = x.shape
     qdim, kvdim = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    u = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    u = (u.astype(jnp.float32) * cfg.attention_in_multiplier).astype(u.dtype)
-    q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
-        u @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
-    k = (k.astype(jnp.float32) * cfg.key_multiplier).astype(k.dtype)
-    return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+    with jax.named_scope(scopes.ATTN_QKV):
+        u = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
+        u = (u.astype(jnp.float32)
+             * cfg.attention_in_multiplier).astype(u.dtype)
+        q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
+            u @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
+        k = (k.astype(jnp.float32) * cfg.key_multiplier).astype(k.dtype)
+        return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
 
 
 def attention_output(cfg: FalconH1Config, p, x, attn):
     """The attention branch's end: the heads' outputs through ``wo``,
     times ``attention_out_multiplier``, added to ``x`` [b, s, d]."""
     b, s, _ = x.shape
-    out = jnp.einsum("bsq,qd->bsd", attn.reshape(b, s, -1), p["wo"],
-                     preferred_element_type=jnp.float32)
-    return x + (out * cfg.attention_out_multiplier).astype(x.dtype)
+    with jax.named_scope(scopes.ATTN_OUT):
+        out = jnp.einsum("bsq,qd->bsd", attn.reshape(b, s, -1), p["wo"],
+                         preferred_element_type=jnp.float32)
+        return x + (out * cfg.attention_out_multiplier).astype(x.dtype)
 
 
 def feed_forward(cfg: FalconH1Config, p, x, valid=None):
@@ -306,12 +313,13 @@ def feed_forward(cfg: FalconH1Config, p, x, valid=None):
     ``mlp_multipliers[0]`` and the output times ``[1]``; returns (the
     residual-added stream, no statistics)."""
     gate_m, down_m = cfg.mlp_multipliers
-    h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
-    gate = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32) * gate_m)
-    gated = (gate * (h @ p["w_up"]).astype(jnp.float32)).astype(x.dtype)
-    out = jnp.einsum("bsf,fd->bsd", gated, p["w_down"],
-                     preferred_element_type=jnp.float32)
-    return x + (out * down_m).astype(x.dtype), {}
+    with jax.named_scope(scopes.FFN):
+        h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
+        gate = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32) * gate_m)
+        gated = (gate * (h @ p["w_up"]).astype(jnp.float32)).astype(x.dtype)
+        out = jnp.einsum("bsf,fd->bsd", gated, p["w_down"],
+                         preferred_element_type=jnp.float32)
+        return x + (out * down_m).astype(x.dtype), {}
 
 
 def _mixer_in(cfg, p, x):
@@ -385,13 +393,15 @@ def recurrent_mixer(cfg, p, x, state, valid, *, ends=_ENDS):
     (``models/nemotron_h.py``: no multipliers)."""
     mixer_in, mixer_out = ends
     s0, tail = state
-    z, xbc, dt = mixer_in(cfg, p, x)
-    conv = causal_conv(xbc, tail, p["conv_w"], p["conv_b"])
-    xs, b, c, step, a = _mixer_split(cfg, p, conv, dt)
-    step = jnp.where(valid[..., None], step, 0.0)
-    y, s1 = ssm_scan(xs, step, a, b, c, s0, chunk=cfg.ssm_chunk)
-    lengths = jnp.sum(valid, axis=1, dtype=jnp.int32)
-    return mixer_out(cfg, p, y, xs, z), (s1, last_rows(xbc, tail, lengths))
+    with jax.named_scope(scopes.SSM_MIXER):
+        z, xbc, dt = mixer_in(cfg, p, x)
+        conv = causal_conv(xbc, tail, p["conv_w"], p["conv_b"])
+        xs, b, c, step, a = _mixer_split(cfg, p, conv, dt)
+        step = jnp.where(valid[..., None], step, 0.0)
+        y, s1 = ssm_scan(xs, step, a, b, c, s0, chunk=cfg.ssm_chunk)
+        lengths = jnp.sum(valid, axis=1, dtype=jnp.int32)
+        return (mixer_out(cfg, p, y, xs, z),
+                (s1, last_rows(xbc, tail, lengths)))
 
 
 def recurrent_step(cfg, p, x, state, layer, active, *, ends=_ENDS):
@@ -407,15 +417,17 @@ def recurrent_step(cfg, p, x, state, layer, active, *, ends=_ENDS):
     written back here. ``ends``: as ``recurrent_mixer``'s."""
     mixer_in, mixer_out = ends
     states, tails = state
-    tail = tails[layer]
-    z, xbc, dt = mixer_in(cfg, p, x)
-    conv = causal_conv(xbc, tail, p["conv_w"], p["conv_b"])
-    xs, b, c, step, a = _mixer_split(cfg, p, conv[:, 0], dt[:, 0])
-    y, states = ssm_state_step(xs, step, a, b, c, states, layer, active)
-    new_tail = jnp.concatenate([tail[:, 1:], xbc.astype(tail.dtype)], axis=1)
-    tails = tails.at[layer].set(
-        jnp.where(active[:, None, None], new_tail, tail))
-    return mixer_out(cfg, p, y, xs, z[:, 0])[:, None], (states, tails)
+    with jax.named_scope(scopes.SSM_MIXER):
+        tail = tails[layer]
+        z, xbc, dt = mixer_in(cfg, p, x)
+        conv = causal_conv(xbc, tail, p["conv_w"], p["conv_b"])
+        xs, b, c, step, a = _mixer_split(cfg, p, conv[:, 0], dt[:, 0])
+        y, states = ssm_state_step(xs, step, a, b, c, states, layer, active)
+        new_tail = jnp.concatenate([tail[:, 1:], xbc.astype(tail.dtype)],
+                                   axis=1)
+        tails = tails.at[layer].set(
+            jnp.where(active[:, None, None], new_tail, tail))
+        return mixer_out(cfg, p, y, xs, z[:, 0])[:, None], (states, tails)
 
 
 def zero_state(cfg: FalconH1Config, rows: int) -> tuple:

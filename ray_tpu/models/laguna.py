@@ -51,6 +51,7 @@ from jax import lax
 from ray_tpu.models.llama import (  # noqa: F401 - embed, head_logits:
     LayerStack, embed, fanin_init,  # pieces of the block's module that
     head_logits, lm_head_weights)   # are Llama's
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.moe import moe_ffn_dropless, share_statistics
 from ray_tpu.ops.norms import rms_norm
@@ -181,12 +182,13 @@ def rotary_tables(cfg: LagunaConfig, positions) -> dict:
     """(sin, cos) of ``positions`` for each kind of layer. A full layer's
     cover the first ``partial_rotary`` of a head only (their last axis is
     that many pairs) and carry YaRN's attention factor."""
-    angles = (positions[..., None].astype(jnp.float32)
-              * _yarn_frequencies(cfg))
-    f = cfg.yarn_attention_factor
-    return {"full": (jnp.sin(angles) * f, jnp.cos(angles) * f),
-            "sliding": rope_sin_cos(positions, cfg.head_dim,
-                                    theta=cfg.rope_theta_sliding)}
+    with jax.named_scope(scopes.ATTN_QKV):
+        angles = (positions[..., None].astype(jnp.float32)
+                  * _yarn_frequencies(cfg))
+        f = cfg.yarn_attention_factor
+        return {"full": (jnp.sin(angles) * f, jnp.cos(angles) * f),
+                "sliding": rope_sin_cos(positions, cfg.head_dim,
+                                        theta=cfg.rope_theta_sliding)}
 
 
 # ---------------------------------------------------------------------------
@@ -287,8 +289,9 @@ def _rotate(x, sin, cos):
     rot = 2 * sin.shape[-1]
     if rot == x.shape[-1]:
         return apply_rope(x, sin, cos)
-    return jnp.concatenate(
-        [apply_rope(x[..., :rot], sin, cos), x[..., rot:]], axis=-1)
+    with jax.named_scope(scopes.ATTN_QKV):
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], sin, cos), x[..., rot:]], axis=-1)
 
 
 def attention_projections(cfg: LagunaConfig, p, x, sin, cos):
@@ -300,10 +303,11 @@ def attention_projections(cfg: LagunaConfig, p, x, sin, cos):
     b, s, _ = x.shape
     kvdim = cfg.n_kv_heads * cfg.head_dim
     qdim = p["wqkv"].shape[-1] - 2 * kvdim
-    h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
-        h @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
-    return _rotate(q, sin, cos), _rotate(k, sin, cos), v
+    with jax.named_scope(scopes.ATTN_QKV):
+        h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
+        q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
+            h @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
+        return _rotate(q, sin, cos), _rotate(k, sin, cos), v
 
 
 def attention_output(cfg: LagunaConfig, p, x, attn):
@@ -313,10 +317,11 @@ def attention_output(cfg: LagunaConfig, p, x, attn):
     ``wo``, added to ``x`` [b, s, d]. (The norm of ``x`` is the one
     ``attention_projections`` took: the compiler computes it once.)"""
     b, s, _ = x.shape
-    h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    gate = jax.nn.sigmoid((h @ p["wg"]).astype(jnp.float32))   # [b, s, heads]
-    attn = attn.reshape(b, s, -1, cfg.head_dim) * gate[..., None]
-    return x + attn.astype(x.dtype).reshape(b, s, -1) @ p["wo"]
+    with jax.named_scope(scopes.ATTN_OUT):
+        h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
+        gate = jax.nn.sigmoid((h @ p["wg"]).astype(jnp.float32))  # [b,s,heads]
+        attn = attn.reshape(b, s, -1, cfg.head_dim) * gate[..., None]
+        return x + attn.astype(x.dtype).reshape(b, s, -1) @ p["wo"]
 
 
 def feed_forward(cfg: LagunaConfig, p, x, valid=None, stacked=None):
@@ -336,8 +341,9 @@ def feed_forward(cfg: LagunaConfig, p, x, valid=None, stacked=None):
     b, s, d = x.shape
     h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
     if "w_gate" in p:
-        gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
-        return x + gated @ p["w_down"], {}
+        with jax.named_scope(scopes.FFN):
+            gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
+            return x + gated @ p["w_down"], {}
     held, layer = (p, None) if stacked is None else stacked
     routed, load = moe_ffn_dropless(
         h.reshape(b * s, d), p["router"], held["wi_gate"], held["wi_up"],
@@ -345,9 +351,12 @@ def feed_forward(cfg: LagunaConfig, p, x, valid=None, stacked=None):
         norm_topk_prob=cfg.norm_topk_prob,
         routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
         valid=None if valid is None else valid.reshape(b * s))
-    shared = (jax.nn.silu(h @ p["ws_gate"]) * (h @ p["ws_up"])) @ p["ws_down"]
+    with jax.named_scope(scopes.SHARED_EXPERT):
+        shared = (jax.nn.silu(h @ p["ws_gate"])
+                  * (h @ p["ws_up"])) @ p["ws_down"]
     stats = share_statistics(load, valid, b * s, cfg.top_k)
-    return x + routed.reshape(b, s, d) + shared, stats
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return x + routed.reshape(b, s, d) + shared, stats
 
 
 # ---------------------------------------------------------------------------

@@ -30,6 +30,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_sin_cos
@@ -173,17 +174,18 @@ def attention_projections(cfg, p, x, sin, cos):
     matmul, split afterwards: each output column is the dot product it
     was."""
     b, s, _ = x.shape
-    h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    if "wqkv" in p:
-        qdim = cfg.n_heads * cfg.head_dim
-        kvdim = cfg.n_kv_heads * cfg.head_dim
-        q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
-            h @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
-    else:
-        q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-        k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+    with jax.named_scope(scopes.ATTN_QKV):
+        h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
+        if "wqkv" in p:
+            qdim = cfg.n_heads * cfg.head_dim
+            kvdim = cfg.n_kv_heads * cfg.head_dim
+            q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
+                h @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
+        else:
+            q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
 
 
 def attention_output(cfg, p, x, attn):
@@ -193,7 +195,8 @@ def attention_output(cfg, p, x, attn):
     pieces that the serving engine calls (a block that gates its heads
     does it here)."""
     b, s, _ = x.shape
-    return x + attn.reshape(b, s, -1) @ p["wo"]
+    with jax.named_scope(scopes.ATTN_OUT):
+        return x + attn.reshape(b, s, -1) @ p["wo"]
 
 
 class LayerStack(NamedTuple):
@@ -266,8 +269,9 @@ def fuse_attention_projections(blocks):
     every layer's attention kernel; the fused stack (604 MB there) cannot
     be, and a layer reads its own slice of it where it lies."""
     blocks = dict(blocks)
-    blocks["wqkv"] = jnp.concatenate(
-        [blocks.pop("wq"), blocks.pop("wk"), blocks.pop("wv")], axis=-1)
+    with jax.named_scope(scopes.ATTN_QKV):
+        blocks["wqkv"] = jnp.concatenate(
+            [blocks.pop("wq"), blocks.pop("wk"), blocks.pop("wv")], axis=-1)
     return blocks
 
 
@@ -276,9 +280,10 @@ def feed_forward(cfg, p, x, valid=None):
     residual-added stream, its statistics: a dense block has none).
     ``valid`` [b, s] marks the rows that are tokens, for a block that
     routes; a dense one computes every row."""
-    h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
-    gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
-    return x + gated @ p["w_down"], {}
+    with jax.named_scope(scopes.FFN):
+        h = rms_norm(x, p["mlp_norm"], eps=cfg.rms_eps)
+        gated = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
+        return x + gated @ p["w_down"], {}
 
 
 def attention_sublayer(cfg, x, p, sin, cos, segment_ids, attn_impl,
@@ -296,8 +301,9 @@ def attention_sublayer(cfg, x, p, sin, cos, segment_ids, attn_impl,
             )
         if segment_ids is not None:
             raise ValueError("ring attention does not support segment_ids yet")
-        attn_out = ring_attention(q, k, v, mesh=mesh, axis=sp_axis,
-                                  causal=True)
+        with jax.named_scope(scopes.ATTN):
+            attn_out = ring_attention(q, k, v, mesh=mesh, axis=sp_axis,
+                                      causal=True)
     else:
         attn_out = attention(q, k, v, causal=True, segment_ids=segment_ids,
                              impl=attn_impl, mesh=mesh)
@@ -306,8 +312,9 @@ def attention_sublayer(cfg, x, p, sin, cos, segment_ids, attn_impl,
     # _flash_vjp_fwd; the reference impl names its output in
     # ops/attention.py) — naming the post-reshape copy here too would
     # double-store ~b*s*d per layer under those policies.
-    attn_out = attn_out.reshape(b, s, cfg.n_heads * cfg.head_dim)
-    return x + attn_out @ p["wo"]
+    with jax.named_scope(scopes.ATTN_OUT):
+        attn_out = attn_out.reshape(b, s, cfg.n_heads * cfg.head_dim)
+        return x + attn_out @ p["wo"]
 
 
 def _block(cfg: LlamaConfig, x, layer_params, sin, cos, segment_ids,
@@ -335,9 +342,9 @@ def forward(
     x = forward_hidden(cfg, params, tokens, positions=positions,
                        segment_ids=segment_ids, attn_impl=attn_impl,
                        mesh=mesh, sp_axis=sp_axis)
-    logits = jnp.einsum("bsd,dv->bsv", x, lm_head_weights(cfg, params),
-                        preferred_element_type=jnp.float32)
-    return logits
+    with jax.named_scope(scopes.LM_HEAD):
+        return jnp.einsum("bsd,dv->bsv", x, lm_head_weights(cfg, params),
+                          preferred_element_type=jnp.float32)
 
 
 def forward_hidden(cfg, params, tokens, *, positions=None,
@@ -346,10 +353,10 @@ def forward_hidden(cfg, params, tokens, *, positions=None,
     """Token ids -> final normalized hidden states [b, s, d] (the input
     to the LM head). Split out so losses can fuse the head projection."""
     b, s = tokens.shape
-    x = params["embedding"][tokens]
+    x = embed(cfg, params, tokens)
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
-    sin, cos = rope_sin_cos(positions, cfg.head_dim, theta=cfg.rope_theta)
+    sin, cos = rotary_tables(cfg, positions)["full"]
 
     body = partial(_block, cfg, sin=sin, cos=cos, segment_ids=segment_ids,
                    attn_impl=attn_impl, mesh=mesh, sp_axis=sp_axis)
@@ -389,13 +396,15 @@ def forward_hidden(cfg, params, tokens, *, positions=None,
 def embed(cfg, params, tokens):
     """Token ids -> the residual stream's start (a model that scales its
     embedding states its own)."""
-    return params["embedding"][tokens]
+    with jax.named_scope(scopes.EMBED):
+        return params["embedding"][tokens]
 
 
 def head_logits(cfg, params, x):
     """Normed last hidden states [b, d] -> float32 logits [b, vocab]."""
-    return jnp.einsum("bd,dv->bv", x, lm_head_weights(cfg, params),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope(scopes.LM_HEAD):
+        return jnp.einsum("bd,dv->bv", x, lm_head_weights(cfg, params),
+                          preferred_element_type=jnp.float32)
 
 
 def lm_head_weights(cfg, params):
@@ -428,50 +437,51 @@ def fused_cross_entropy(cfg, params, hidden, targets, *, mask=None,
     step 15% slower (PERF.md, PR 39). On one chip at a 32k vocabulary the
     recompute costs 3.4% against the dense loss (same place).
     """
-    head = lm_head_weights(cfg, params)
-    if vocab_axes:
-        if mask is None:
-            mask = targets >= 0
-        return _vocab_split_cross_entropy(
-            head, hidden, jnp.maximum(targets, 0), mask.astype(jnp.float32),
-            chunk=chunk, z_loss=z_loss, mesh=mesh, rows=rows,
-            vocab_axes=vocab_axes)
-    b, s, d = hidden.shape
-    n = b * s
-    xm = hidden.reshape(n, d)
-    tg = jnp.maximum(targets.reshape(n), 0)
-    # mask=None derives the mask from the -1 padding convention (same
-    # contract as the trainer's dense path) — silently averaging padding
-    # in as class-0 predictions would be a wrong loss with no error
-    mk = ((targets.reshape(n) >= 0).astype(jnp.float32) if mask is None
-          else mask.reshape(n).astype(jnp.float32))
-    # pad to a whole number of chunks (padding masked out)
-    pad = (-n) % chunk
-    if pad:
-        xm = jnp.concatenate([xm, jnp.zeros((pad, d), xm.dtype)])
-        tg = jnp.concatenate([tg, jnp.zeros((pad,), tg.dtype)])
-        mk = jnp.concatenate([mk, jnp.zeros((pad,), mk.dtype)])
-    n_chunks = (n + pad) // chunk
-    xc = xm.reshape(n_chunks, chunk, d)
-    tc = tg.reshape(n_chunks, chunk)
-    mc = mk.reshape(n_chunks, chunk)
+    with jax.named_scope(scopes.LOSS):
+        head = lm_head_weights(cfg, params)
+        if vocab_axes:
+            if mask is None:
+                mask = targets >= 0
+            return _vocab_split_cross_entropy(
+                head, hidden, jnp.maximum(targets, 0),
+                mask.astype(jnp.float32), chunk=chunk, z_loss=z_loss,
+                mesh=mesh, rows=rows, vocab_axes=vocab_axes)
+        b, s, d = hidden.shape
+        n = b * s
+        xm = hidden.reshape(n, d)
+        tg = jnp.maximum(targets.reshape(n), 0)
+        # mask=None derives the mask from the -1 padding convention (same
+        # contract as the trainer's dense path) — silently averaging padding
+        # in as class-0 predictions would be a wrong loss with no error
+        mk = ((targets.reshape(n) >= 0).astype(jnp.float32) if mask is None
+              else mask.reshape(n).astype(jnp.float32))
+        # pad to a whole number of chunks (padding masked out)
+        pad = (-n) % chunk
+        if pad:
+            xm = jnp.concatenate([xm, jnp.zeros((pad, d), xm.dtype)])
+            tg = jnp.concatenate([tg, jnp.zeros((pad,), tg.dtype)])
+            mk = jnp.concatenate([mk, jnp.zeros((pad,), mk.dtype)])
+        n_chunks = (n + pad) // chunk
+        xc = xm.reshape(n_chunks, chunk, d)
+        tc = tg.reshape(n_chunks, chunk)
+        mc = mk.reshape(n_chunks, chunk)
 
-    def body(carry, inp):
-        x_i, t_i, m_i = inp
-        logits = jnp.einsum("cd,dv->cv", x_i, head,
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tl = jnp.take_along_axis(logits, t_i[:, None], axis=1).squeeze(-1)
-        nll = lse - tl
-        if z_loss > 0.0:
-            nll = nll + z_loss * jnp.square(lse)
-        total, count = carry
-        return (total + jnp.sum(nll * m_i), count + jnp.sum(m_i)), None
+        def body(carry, inp):
+            x_i, t_i, m_i = inp
+            logits = jnp.einsum("cd,dv->cv", x_i, head,
+                                preferred_element_type=jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            tl = jnp.take_along_axis(logits, t_i[:, None], axis=1).squeeze(-1)
+            nll = lse - tl
+            if z_loss > 0.0:
+                nll = nll + z_loss * jnp.square(lse)
+            total, count = carry
+            return (total + jnp.sum(nll * m_i), count + jnp.sum(m_i)), None
 
-    (total, count), _ = lax.scan(
-        jax.checkpoint(body), (jnp.float32(0.0), jnp.float32(0.0)),
-        (xc, tc, mc))
-    return total / jnp.maximum(count, 1.0)
+        (total, count), _ = lax.scan(
+            jax.checkpoint(body), (jnp.float32(0.0), jnp.float32(0.0)),
+            (xc, tc, mc))
+        return total / jnp.maximum(count, 1.0)
 
 
 def _vocab_split_cross_entropy(head, hidden, targets, mask, *, chunk, z_loss,
@@ -568,15 +578,16 @@ def cross_entropy_loss(logits, targets, *, mask=None, z_loss: float = 0.0):
 
     ``mask`` [batch, seq] in {0,1} excludes padding from the mean.
     """
-    logits = logits.astype(jnp.float32)
-    logsumexp = jax.nn.logsumexp(logits, axis=-1)
-    target_logit = jnp.take_along_axis(
-        logits, targets[..., None], axis=-1
-    ).squeeze(-1)
-    nll = logsumexp - target_logit
-    if z_loss > 0.0:
-        nll = nll + z_loss * jnp.square(logsumexp)
-    if mask is not None:
-        mask = mask.astype(jnp.float32)
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return jnp.mean(nll)
+    with jax.named_scope(scopes.LOSS):
+        logits = logits.astype(jnp.float32)
+        logsumexp = jax.nn.logsumexp(logits, axis=-1)
+        target_logit = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1
+        ).squeeze(-1)
+        nll = logsumexp - target_logit
+        if z_loss > 0.0:
+            nll = nll + z_loss * jnp.square(logsumexp)
+        if mask is not None:
+            mask = mask.astype(jnp.float32)
+            return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        return jnp.mean(nll)

@@ -30,8 +30,9 @@ layers. ``params["blocks"]`` maps a run's key to its weights stacked on a
 leading axis; consecutive layers of one letter are one run (the
 published pattern has none).
 
-The pieces carry ``jax.named_scope``s (``ssm_mixer``, ``attention``; the
-routed feed-forward's are ``ops/moe.py``'s): the mixer's ``out_proj`` and
+The pieces carry ``jax.named_scope``s (``ops/scopes.py``: ``attn_qkv``,
+``attn_out``, ``shared_expert``; the mixer's are ``models/falcon_h1.py``'s,
+the routed feed-forward's ``ops/moe.py``'s): the mixer's ``out_proj`` and
 attention's ``wo`` are both [4096, 2688] at the published widths, and a
 lowered program names what shapes cannot. No training path: there are no
 logical axes and no loss here.
@@ -50,6 +51,7 @@ from ray_tpu.models import falcon_h1
 from ray_tpu.models.llama import (  # noqa: F401 - embed, head_logits:
     LayerStack, embed, fanin_init,  # pieces of the block's module that
     head_logits, lm_head_weights)   # are Llama's
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.moe import moe_ffn_dropless, share_statistics
 from ray_tpu.ops.norms import rms_norm
@@ -328,18 +330,15 @@ def recurrent_mixer(cfg: NemotronHConfig, p, x, state, valid):
     (the term to add to the stream, the state after each row's last
     valid token). ``falcon_h1.recurrent_mixer`` between this module's
     ends."""
-    with jax.named_scope("ssm_mixer"):
-        return falcon_h1.recurrent_mixer(cfg, p, x, state, valid,
-                                         ends=_ENDS)
+    return falcon_h1.recurrent_mixer(cfg, p, x, state, valid, ends=_ENDS)
 
 
 def recurrent_step(cfg: NemotronHConfig, p, x, state, layer, active):
     """An ``M`` layer for one token a slot over the slots' STACKED state
     arrays, this layer's at [layer] (its place among the layers that keep
     state): ``falcon_h1.recurrent_step`` between this module's ends."""
-    with jax.named_scope("ssm_mixer"):
-        return falcon_h1.recurrent_step(cfg, p, x, state, layer, active,
-                                        ends=_ENDS)
+    return falcon_h1.recurrent_step(cfg, p, x, state, layer, active,
+                                    ends=_ENDS)
 
 
 def attention_projections(cfg: NemotronHConfig, p, x):
@@ -349,7 +348,7 @@ def attention_projections(cfg: NemotronHConfig, p, x):
     heads, hd])."""
     b, s, _ = x.shape
     qdim, kvdim = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    with jax.named_scope("attention"):
+    with jax.named_scope(scopes.ATTN_QKV):
         u = rms_norm(x, p["norm"], eps=cfg.rms_eps)
         return tuple(y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
             u @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
@@ -359,7 +358,7 @@ def attention_output(cfg: NemotronHConfig, p, x, attn):
     """A ``*`` layer's end: the heads' outputs through ``wo``, added to
     ``x`` [b, s, d]."""
     b, s, _ = x.shape
-    with jax.named_scope("attention"):
+    with jax.named_scope(scopes.ATTN_OUT):
         return x + attn.reshape(b, s, -1) @ p["wo"]
 
 
@@ -383,10 +382,11 @@ def feed_forward(cfg: NemotronHConfig, p, x, valid=None, stacked=None):
         routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
         valid=None if valid is None else valid.reshape(b * s),
         scoring="sigmoid", choice_bias=p["router_bias"], form="relu2")
-    with jax.named_scope("shared_expert"):
+    with jax.named_scope(scopes.SHARED_EXPERT):
         shared = jnp.square(jax.nn.relu(h @ p["ws_up"])) @ p["ws_down"]
     stats = share_statistics(load, valid, b * s, cfg.top_k)
-    return x + routed.reshape(b, s, d) + shared, stats
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return x + routed.reshape(b, s, d) + shared, stats
 
 
 def zero_state(cfg: NemotronHConfig, rows: int) -> tuple:

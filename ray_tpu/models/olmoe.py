@@ -36,6 +36,7 @@ from ray_tpu.models.llama import (  # noqa: F401 - parts of the block's module
     head_logits,
     lm_head_weights,
 )
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.moe import moe_ffn_dropless
 from ray_tpu.ops.norms import rms_norm
@@ -160,13 +161,14 @@ def attention_projections(cfg: OlmoeConfig, p, x, sin, cos):
     the whole projection, the split into heads, rotary on ``q`` and ``k``.
     Returns (q [b, s, heads, hd], k, v [b, s, kv heads, hd])."""
     b, s, _ = x.shape
-    h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    q = rms_norm(h @ p["wq"], p["q_norm"], eps=cfg.rms_eps)
-    k = rms_norm(h @ p["wk"], p["k_norm"], eps=cfg.rms_eps)
-    q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+    with jax.named_scope(scopes.ATTN_QKV):
+        h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
+        q = rms_norm(h @ p["wq"], p["q_norm"], eps=cfg.rms_eps)
+        k = rms_norm(h @ p["wk"], p["k_norm"], eps=cfg.rms_eps)
+        q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+        k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
 
 
 def feed_forward(cfg: OlmoeConfig, p, x, valid=None, stacked=None):
@@ -187,13 +189,15 @@ def feed_forward(cfg: OlmoeConfig, p, x, valid=None, stacked=None):
         held["wo_e"], layer=layer, top_k=cfg.top_k,
         norm_topk_prob=cfg.norm_topk_prob,
         valid=None if valid is None else valid.reshape(b * s))
-    load = load.astype(jnp.float32)
-    stats = {
-        "experts_touched": jnp.sum(load > 0, dtype=jnp.float32),
-        "expert_load_max_over_mean":
-            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
-    }
-    return x + out.reshape(b, s, d), stats
+    with jax.named_scope(scopes.MOE_ROUTER):
+        load = load.astype(jnp.float32)
+        stats = {
+            "experts_touched": jnp.sum(load > 0, dtype=jnp.float32),
+            "expert_load_max_over_mean":
+                jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+        }
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return x + out.reshape(b, s, d), stats
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +215,7 @@ def forward(cfg: OlmoeConfig, params: dict, tokens, *,
     def block(x, p):
         q, k, v = attention_projections(cfg, p, x, sin, cos)
         attn = attention(q, k, v, causal=True, impl=attn_impl)
-        x = x + attn.reshape(b, s, -1) @ p["wo"]
+        x = attention_output(cfg, p, x, attn)
         x, _ = feed_forward(cfg, p, x)
         return x, None
 

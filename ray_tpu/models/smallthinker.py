@@ -49,6 +49,7 @@ from jax import lax
 from ray_tpu.models.llama import (  # noqa: F401 - embed, head_logits:
     LayerStack, embed, fanin_init,  # pieces of the block's module that
     head_logits, lm_head_weights)   # are Llama's
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.moe import moe_experts, moe_route, share_statistics
 from ray_tpu.ops.norms import rms_norm
@@ -233,12 +234,13 @@ def attention_projections(cfg: SmallThinkerConfig, p, x, sin=None, cos=None):
     k, v [b, s, kv heads, hd])."""
     b, s, _ = x.shape
     qdim, kvdim = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
-    q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
-        h @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
-    if sin is None:
-        return q, k, v
-    return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+    with jax.named_scope(scopes.ATTN_QKV):
+        h = rms_norm(x, p["attn_norm"], eps=cfg.rms_eps)
+        q, k, v = (y.reshape(b, s, -1, cfg.head_dim) for y in jnp.split(
+            h @ p["wqkv"], [qdim, qdim + kvdim], axis=-1))
+        if sin is None:
+            return q, k, v
+        return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
 
 
 def attention_output(cfg: SmallThinkerConfig, p, x, attn):
@@ -246,7 +248,8 @@ def attention_output(cfg: SmallThinkerConfig, p, x, attn):
     heads, hd], or [b, heads, hd] of a one-token step) through ``wo``,
     added to ``x`` [b, s, d]."""
     b, s, _ = x.shape
-    return x + attn.astype(x.dtype).reshape(b, s, -1) @ p["wo"]
+    with jax.named_scope(scopes.ATTN_OUT):
+        return x + attn.astype(x.dtype).reshape(b, s, -1) @ p["wo"]
 
 
 def feed_ahead(cfg: SmallThinkerConfig, p, x):
@@ -281,7 +284,8 @@ def feed_forward(cfg: SmallThinkerConfig, p, x, valid=None, stacked=None, *,
         valid=None if valid is None else valid.reshape(b * s))
     stats = share_statistics(load, valid, b * s, cfg.top_k)
     del stats["routed_here_share"]      # every expert is held here
-    return x + out.reshape(b, s, d), stats
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return x + out.reshape(b, s, d), stats
 
 
 # ---------------------------------------------------------------------------

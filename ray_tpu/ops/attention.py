@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from einops import rearrange
 
+from ray_tpu.ops import scopes
+
 
 def _repeat_kv(k, n_rep: int):
     if n_rep == 1:
@@ -97,24 +99,25 @@ def cached_attention(q, k_cache, v_cache, start, *, scale, window=None,
     # query heads as [B, T, nkv, n_rep, hd] and contract against the
     # cache directly — repeating K/V would multiply HBM traffic on the
     # hottest decode-step tensor by n_rep.
-    qg = q.reshape(b, t, nkv, n_rep, hd)
-    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    qpos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
-    kpos = jnp.arange(s, dtype=jnp.int32)                            # [S]
-    if key_start is None:
-        mask = kpos[None, None, :] <= qpos[:, :, None]               # [B,T,S]
-    else:
-        kpos = key_start[:, None] + kpos[None, :]                    # [B,S]
-        mask = kpos[:, None, :] <= qpos[:, :, None]
-    if window is not None:
-        mask = mask & (kpos[..., None, :] > qpos[:, :, None] - window)
-    logits = jnp.where(mask[:, None, None, :, :], logits,
-                       jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v_cache.dtype),
-                     v_cache, preferred_element_type=jnp.float32)
-    return out.reshape(b, t, nh, hd).astype(q.dtype)
+    with jax.named_scope(scopes.ATTN):
+        qg = q.reshape(b, t, nkv, n_rep, hd)
+        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache,
+                            preferred_element_type=jnp.float32) * scale
+        qpos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        kpos = jnp.arange(s, dtype=jnp.int32)                        # [S]
+        if key_start is None:
+            mask = kpos[None, None, :] <= qpos[:, :, None]           # [B,T,S]
+        else:
+            kpos = key_start[:, None] + kpos[None, :]                # [B,S]
+            mask = kpos[:, None, :] <= qpos[:, :, None]
+        if window is not None:
+            mask = mask & (kpos[..., None, :] > qpos[:, :, None] - window)
+        logits = jnp.where(mask[:, None, None, :, :], logits,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v_cache.dtype),
+                         v_cache, preferred_element_type=jnp.float32)
+        return out.reshape(b, t, nh, hd).astype(q.dtype)
 
 
 def attention(q, k, v, *, causal=True, segment_ids=None,
@@ -144,17 +147,19 @@ def attention(q, k, v, *, causal=True, segment_ids=None,
             kernel = jax.shard_map(kernel, mesh=mesh,
                                    in_specs=(spec, spec, spec),
                                    out_specs=spec, check_vma=False)
-        return kernel(q, k, v)
+        with jax.named_scope(scopes.ATTN):
+            return kernel(q, k, v)
     from jax.ad_checkpoint import checkpoint_name
 
     # save point for the "attn"/"dots_attn" remat policies (the flash
     # impl names its kernel residuals instead — _flash_vjp_fwd)
-    return checkpoint_name(
-        reference_attention(
-            q, k, v, causal=causal, segment_ids=segment_ids,
-            logits_soft_cap=logits_soft_cap,
-        ),
-        "attn_out")
+    with jax.named_scope(scopes.ATTN):
+        return checkpoint_name(
+            reference_attention(
+                q, k, v, causal=causal, segment_ids=segment_ids,
+                logits_soft_cap=logits_soft_cap,
+            ),
+            "attn_out")
 
 
 def _flash_supported(q, segment_ids, logits_soft_cap, causal) -> bool:

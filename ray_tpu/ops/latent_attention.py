@@ -103,6 +103,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.paged_attention import (ROW_LANES, gather_rows,
                                          visible_pages, write_rows)
 
@@ -152,10 +153,11 @@ def write_latent(inputs: LatentInputs, pools: tuple, layer, pidx, ip):
 def index_scores(q, weights, keys):
     """``I = sum_j w_j relu(q_j . k)``: q [B, T, HI, dI], weights [B, T,
     HI], keys [B, S, dI or its whole lanes] -> [B, T, S] float32."""
-    dots = jnp.einsum("bthd,bsd->bhts", q, keys[..., :q.shape[-1]],
-                      preferred_element_type=jnp.float32)
-    w = jnp.moveaxis(weights.astype(jnp.float32), -1, 1)[..., None]
-    return jnp.sum(w * jax.nn.relu(dots), axis=1)
+    with jax.named_scope(scopes.INDEX_SELECT):
+        dots = jnp.einsum("bthd,bsd->bhts", q, keys[..., :q.shape[-1]],
+                          preferred_element_type=jnp.float32)
+        w = jnp.moveaxis(weights.astype(jnp.float32), -1, 1)[..., None]
+        return jnp.sum(w * jax.nn.relu(dots), axis=1)
 
 
 def kept(chosen, topk: int):
@@ -168,12 +170,13 @@ def kept(chosen, topk: int):
     position it returns among them.) The caller ANDs it with what the
     query may see: where that is no more than ``topk`` keys the
     ``topk``-th score is the mask's own and none is dropped."""
-    values, positions = lax.top_k(chosen, topk)
-    kth = values[..., -1:]
-    last = jnp.max(jnp.where(values == kth, positions, -1), axis=-1,
-                   keepdims=True)
-    at = jnp.arange(chosen.shape[-1], dtype=positions.dtype)
-    return (chosen > kth) | ((chosen == kth) & (at <= last))
+    with jax.named_scope(scopes.INDEX_SELECT):
+        values, positions = lax.top_k(chosen, topk)
+        kth = values[..., -1:]
+        last = jnp.max(jnp.where(values == kth, positions, -1), axis=-1,
+                       keepdims=True)
+        at = jnp.arange(chosen.shape[-1], dtype=positions.dtype)
+        return (chosen > kth) | ((chosen == kth) & (at <= last))
 
 
 # ---------------------------------------------------------------------------
@@ -496,8 +499,9 @@ def _query_rows(inputs: LatentInputs, lanes: int):
 
 def _scored_in_place(q, weights, pool, layer, table, count):
     """The index kernel's formulation; arguments as ``_scored_gathered``'s."""
-    return index_decode_scores_kernel(q[:, 0], weights[:, 0], pool, layer,
-                                      table, count)
+    with jax.named_scope(scopes.INDEX_SELECT):
+        return index_decode_scores_kernel(q[:, 0], weights[:, 0], pool, layer,
+                                          table, count)
 
 
 def _scored_gathered(q, weights, pool, layer, table, count):
@@ -505,10 +509,11 @@ def _scored_gathered(q, weights, pool, layer, table, count):
     kernel is held to): ``q`` [B, 1, HI, dI] and ``weights`` [B, 1, HI]
     against every key of the table's pages, copied out of ``pool``; [B,
     PB x page] float32, ``_MASKED`` from the slot's ``count`` on."""
-    keys = gather_rows(pool, layer, table)                    # [B, S, dI]
-    scores = index_scores(q, weights, keys)[:, 0]
-    seen = jnp.arange(keys.shape[1], dtype=jnp.int32) < count[:, None]
-    return jnp.where(seen, scores, _MASKED)
+    with jax.named_scope(scopes.INDEX_SELECT):
+        keys = gather_rows(pool, layer, table)                    # [B, S, dI]
+        scores = index_scores(q, weights, keys)[:, 0]
+        seen = jnp.arange(keys.shape[1], dtype=jnp.int32) < count[:, None]
+        return jnp.where(seen, scores, _MASKED)
 
 
 def _in_place(q_row, pool, layer, table, count, chosen, *, rank, scale,
@@ -531,18 +536,19 @@ def _gathered(q_row, pool, layer, table, count, chosen=None, *, rank, scale,
     ``q_row``'s type."""
     page = pool.shape[2]
     if chosen is not None:
-        values, positions = lax.top_k(chosen, topk)
-        # a key past the slot's count comes out with the mask's own value
-        # (a gather of the seen keys at the positions says the same, a
-        # scalar at a time: 1.3 ms a layer on a v5e at 64 x 2,048)
-        mask = values > _MASKED
-        # each position's page id: the table's entry at its page, picked
-        # by comparison (a gather of 2,048 scalars a slot out of the
-        # table takes 1.0 ms a layer on a v5e; this a few microseconds)
-        at = (positions // page)[..., None] == jnp.arange(
-            table.shape[1], dtype=jnp.int32)
-        pages = jnp.sum(jnp.where(at, jnp.maximum(table, 0)[:, None, :], 0),
-                        axis=-1)
+        with jax.named_scope(scopes.INDEX_SELECT):
+            values, positions = lax.top_k(chosen, topk)
+            # a key past the slot's count comes out with the mask's own value
+            # (a gather of the seen keys at the positions says the same, a
+            # scalar at a time: 1.3 ms a layer on a v5e at 64 x 2,048)
+            mask = values > _MASKED
+            # each position's page id: the table's entry at its page, picked
+            # by comparison (a gather of 2,048 scalars a slot out of the
+            # table takes 1.0 ms a layer on a v5e; this a few microseconds)
+            at = (positions // page)[..., None] == jnp.arange(
+                table.shape[1], dtype=jnp.int32)
+            pages = jnp.sum(
+                jnp.where(at, jnp.maximum(table, 0)[:, None, :], 0), axis=-1)
         rows = pool[layer, pages, positions % page]       # [B, topk, w]
     else:
         key_start = jnp.zeros_like(count)
@@ -594,32 +600,33 @@ def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
     windowed layer's are gathered on every platform: its gather is whole
     pages already, and through the kernel it took as long (module
     docstring)."""
-    pool = pools[0]
-    page, r = pool.shape[2], inputs.wkv_b.shape[0]
-    index = inputs.index
-    count = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
-    topk = None
-    args = (_query_rows(inputs, pool.shape[-1]), pool, layer, table, count)
-    if index is not None and table.shape[1] * page > index.topk:
-        # (a table of no more than ``topk`` keys: nothing is dropped)
-        topk = index.topk
-        scored = (index.q, index.weights, pools[1], layer, table, count)
-        if index_kernel_engages(page, table.shape[1], topk,
-                                index.q.shape[-1]):
-            args += (lax.platform_dependent(
-                *scored, tpu=_scored_in_place, default=_scored_gathered),)
+    with jax.named_scope(scopes.LATENT_ATTN):
+        pool = pools[0]
+        page, r = pool.shape[2], inputs.wkv_b.shape[0]
+        index = inputs.index
+        count = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
+        topk = None
+        args = (_query_rows(inputs, pool.shape[-1]), pool, layer, table, count)
+        if index is not None and table.shape[1] * page > index.topk:
+            # (a table of no more than ``topk`` keys: nothing is dropped)
+            topk = index.topk
+            scored = (index.q, index.weights, pools[1], layer, table, count)
+            if index_kernel_engages(page, table.shape[1], topk,
+                                    index.q.shape[-1]):
+                args += (lax.platform_dependent(
+                    *scored, tpu=_scored_in_place, default=_scored_gathered),)
+            else:
+                args += (_scored_gathered(*scored),)
+        in_place, gathered = _formulations(r, inputs.scale, topk, window)
+        if latent_kernel_engages(page, table.shape[1], topk):
+            o_latent = lax.platform_dependent(*args, tpu=in_place,
+                                              default=gathered)
         else:
-            args += (_scored_gathered(*scored),)
-    in_place, gathered = _formulations(r, inputs.scale, topk, window)
-    if latent_kernel_engages(page, table.shape[1], topk):
-        o_latent = lax.platform_dependent(*args, tpu=in_place,
-                                          default=gathered)
-    else:
-        o_latent = gathered(*args)
-    dn = inputs.q.shape[-1] - (inputs.row.shape[-1] - r)
-    return jnp.einsum("bhr,rhv->bhv", o_latent, inputs.wkv_b[..., dn:],
-                      preferred_element_type=jnp.float32
-                      ).astype(inputs.q.dtype)
+            o_latent = gathered(*args)
+        dn = inputs.q.shape[-1] - (inputs.row.shape[-1] - r)
+        return jnp.einsum("bhr,rhv->bhv", o_latent, inputs.wkv_b[..., dn:],
+                          preferred_element_type=jnp.float32
+                          ).astype(inputs.q.dtype)
 
 
 def query_block(n: int, t: int, heads: int, keys: int, window) -> int:
@@ -650,112 +657,116 @@ def latent_prefill_attention(inputs: LatentInputs, pools: tuple, layer,
     an indexer scores a block's queries against the rows' index keys and
     masks every key outside a query's ``topk`` (a block that sees no more
     than ``topk`` keys drops none). Returns [n, T, H, dv]."""
-    pool = pools[0]
-    n, t, heads, _ = inputs.q.shape
-    page, r = pool.shape[2], inputs.wkv_b.shape[0]
-    dr = inputs.row.shape[-1] - r
-    dn = inputs.q.shape[-1] - dr
-    index = inputs.index
-    keys = table_rows.shape[1] * page
-    if index is not None and keys <= index.topk:
-        index = None                    # no query can see more than topk
-    block = query_block(
-        n, t, heads + (index.q.shape[2] if index is not None else 0), keys,
-        window)
-    # the pages that hold the keys of ``block`` queries' windows
-    seen = (table_rows.shape[1] if window is None
-            else -(-(block + window - 2) // page) + 1)
+    with jax.named_scope(scopes.LATENT_ATTN):
+        pool = pools[0]
+        n, t, heads, _ = inputs.q.shape
+        page, r = pool.shape[2], inputs.wkv_b.shape[0]
+        dr = inputs.row.shape[-1] - r
+        dn = inputs.q.shape[-1] - dr
+        index = inputs.index
+        keys = table_rows.shape[1] * page
+        if index is not None and keys <= index.topk:
+            index = None                    # no query can see more than topk
+        block = query_block(
+            n, t, heads + (index.q.shape[2] if index is not None else 0), keys,
+            window)
+        # the pages that hold the keys of ``block`` queries' windows
+        seen = (table_rows.shape[1] if window is None
+                else -(-(block + window - 2) // page) + 1)
 
-    def expand(table):
-        """What ``table``'s pages hold, by key: the rotary keys [n, S, dr],
-        every head's no-position keys [n, H, S, dn] and values [n, H, S,
-        dv] (heads before keys: the layout the two products below
-        contract in) and, where the layer selects, the index keys."""
-        rows = gather_rows(pool, layer, table)
+        def expand(table):
+            """What ``table``'s pages hold, by key: the rotary keys [n, S, dr],
+            every head's no-position keys [n, H, S, dn] and values [n, H, S,
+            dv] (heads before keys: the layout the two products below
+            contract in) and, where the layer selects, the index keys."""
+            rows = gather_rows(pool, layer, table)
 
-        def heads_of(w):
-            return jnp.einsum("nsr,rhe->nhse", rows[..., :r], w,
-                              preferred_element_type=jnp.float32
-                              ).astype(inputs.q.dtype)
+            def heads_of(w):
+                return jnp.einsum("nsr,rhe->nhse", rows[..., :r], w,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(inputs.q.dtype)
 
-        return (rows[..., r:r + dr], heads_of(inputs.wkv_b[..., :dn]),
-                heads_of(inputs.wkv_b[..., dn:]),
-                gather_rows(pools[1], layer, table) if index is not None
-                else None)
+            return (rows[..., r:r + dr], heads_of(inputs.wkv_b[..., :dn]),
+                    heads_of(inputs.wkv_b[..., dn:]),
+                    gather_rows(pools[1], layer, table) if index is not None
+                    else None)
 
-    def attend(q, iq, iw, first, seen_keys=None):
-        """``q`` [n, block, H, dn + dr], the first of them at ``first``
-        [n]; ``iq``, ``iw``: their index queries and weights, or None;
-        ``seen_keys``: what ``expand`` gave for the keys from position 0
-        that they can see, or None for a windowed layer, whose block
-        expands the pages of its own windows."""
-        key_start = jnp.zeros_like(first)
-        if seen_keys is None:
-            table, key_start = visible_pages(
-                table_rows, first - window + 1, seen, page)
-            seen_keys = expand(table)
-        kr, kn, v, index_keys = seen_keys
-        scores = (jnp.einsum("nthd,nhsd->nhts", q[..., :dn], kn,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("nthd,nsd->nhts", q[..., dn:], kr,
-                               preferred_element_type=jnp.float32)
-                  ) * inputs.scale
-        qpos = first[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)
-        kpos = key_start[:, None] + jnp.arange(kr.shape[1], dtype=jnp.int32)
-        mask = kpos[:, None, :] <= qpos[:, :, None]          # [n, block, S]
+        def attend(q, iq, iw, first, seen_keys=None):
+            """``q`` [n, block, H, dn + dr], the first of them at ``first``
+            [n]; ``iq``, ``iw``: their index queries and weights, or None;
+            ``seen_keys``: what ``expand`` gave for the keys from position 0
+            that they can see, or None for a windowed layer, whose block
+            expands the pages of its own windows."""
+            key_start = jnp.zeros_like(first)
+            if seen_keys is None:
+                table, key_start = visible_pages(
+                    table_rows, first - window + 1, seen, page)
+                seen_keys = expand(table)
+            kr, kn, v, index_keys = seen_keys
+            scores = (jnp.einsum("nthd,nhsd->nhts", q[..., :dn], kn,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("nthd,nsd->nhts", q[..., dn:], kr,
+                                   preferred_element_type=jnp.float32)
+                      ) * inputs.scale
+            qpos = first[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)
+            kpos = key_start[:, None] + jnp.arange(kr.shape[1],
+                                                   dtype=jnp.int32)
+            mask = kpos[:, None, :] <= qpos[:, :, None]      # [n, block, S]
+            if window is not None:
+                mask = mask & (kpos[:, None, :] > qpos[:, :, None] - window)
+            if iq is not None and kr.shape[1] > index.topk:
+                chosen = jnp.where(mask, index_scores(iq, iw, index_keys),
+                                   _MASKED)
+                mask = mask & kept(chosen, index.topk)
+            scores = jnp.where(mask[:, None], scores, _MASKED)
+            probs = jax.nn.softmax(scores, axis=-1)
+            return jnp.einsum(
+                "nhts,nhsv->nthv", probs.astype(q.dtype), v,
+                preferred_element_type=jnp.float32).astype(q.dtype)
+
+        iq, iw = ((index.q, index.weights) if index is not None
+                  else (None, None))
+        # a full layer's queries all see the same rows: expanded once, outside
+        # the blocks; a windowed layer's blocks each expand what they can see
+        whole = expand(table_rows) if window is None else None
+        if block == t:
+            return attend(inputs.q, iq, iw, starts, whole)
+        count = t // block
+        firsts = starts[None, :] + block * jnp.arange(
+            count, dtype=jnp.int32)[:, None]                     # [blocks, n]
+
+        def blocks(a):
+            return jnp.moveaxis(a.reshape(n, count, block, *a.shape[2:]), 1, 0)
+
+        xs = (blocks(inputs.q),) + (
+            (blocks(iq), blocks(iw)) if index is not None else ()) + (firsts,)
+
+        def some(lo, hi, seen_keys):
+            """Blocks ``lo`` to ``hi``, one after another."""
+            return jax.lax.map(
+                lambda xs: attend(xs[0], *(xs[1:-1] or (None, None)), xs[-1],
+                                  seen_keys),
+                jax.tree.map(lambda a: a[lo:hi], xs))
+
+        def grouped():
+            # where the table reaches past the last (padded) query, the
+            # queries of block i see no key past ``keys - (count - 1 - i) x
+            # block``: the blocks go in up to ``KEY_GROUPS`` groups, each
+            # over the keys its last block can see (a cold prompt's first
+            # quarter attends over a quarter of the keys, not all of them)
+            groups, out = min(KEY_GROUPS, count), []
+            for g in range(groups):
+                lo, hi = g * count // groups, (g + 1) * count // groups
+                extent = keys - (count - hi) * block
+                out.append(some(lo, hi, jax.tree.map(
+                    lambda a: a[..., :extent, :], whole)))
+            return jnp.concatenate(out)
+
         if window is not None:
-            mask = mask & (kpos[:, None, :] > qpos[:, :, None] - window)
-        if iq is not None and kr.shape[1] > index.topk:
-            chosen = jnp.where(mask, index_scores(iq, iw, index_keys),
-                               _MASKED)
-            mask = mask & kept(chosen, index.topk)
-        scores = jnp.where(mask[:, None], scores, _MASKED)
-        probs = jax.nn.softmax(scores, axis=-1)
-        return jnp.einsum("nhts,nhsv->nthv", probs.astype(q.dtype), v,
-                          preferred_element_type=jnp.float32).astype(q.dtype)
-
-    iq, iw = (index.q, index.weights) if index is not None else (None, None)
-    # a full layer's queries all see the same rows: expanded once, outside
-    # the blocks; a windowed layer's blocks each expand what they can see
-    whole = expand(table_rows) if window is None else None
-    if block == t:
-        return attend(inputs.q, iq, iw, starts, whole)
-    count = t // block
-    firsts = starts[None, :] + block * jnp.arange(
-        count, dtype=jnp.int32)[:, None]                     # [blocks, n]
-
-    def blocks(a):
-        return jnp.moveaxis(a.reshape(n, count, block, *a.shape[2:]), 1, 0)
-
-    xs = (blocks(inputs.q),) + (
-        (blocks(iq), blocks(iw)) if index is not None else ()) + (firsts,)
-
-    def some(lo, hi, seen_keys):
-        """Blocks ``lo`` to ``hi``, one after another."""
-        return jax.lax.map(
-            lambda xs: attend(xs[0], *(xs[1:-1] or (None, None)), xs[-1],
-                              seen_keys),
-            jax.tree.map(lambda a: a[lo:hi], xs))
-
-    def grouped():
-        # where the table reaches past the last (padded) query, the
-        # queries of block i see no key past ``keys - (count - 1 - i) x
-        # block``: the blocks go in up to ``KEY_GROUPS`` groups, each
-        # over the keys its last block can see (a cold prompt's first
-        # quarter attends over a quarter of the keys, not all of them)
-        groups, out = min(KEY_GROUPS, count), []
-        for g in range(groups):
-            lo, hi = g * count // groups, (g + 1) * count // groups
-            extent = keys - (count - hi) * block
-            out.append(some(lo, hi, jax.tree.map(
-                lambda a: a[..., :extent, :], whole)))
-        return jnp.concatenate(out)
-
-    if window is not None:
-        out = some(0, count, None)
-    else:
-        # (a suffix whose padding runs past its table, ``starts + t >
-        # keys``, gives no such bound: every block over every key)
-        out = jax.lax.cond(jnp.all(starts + t <= keys), grouped,
-                           lambda: some(0, count, whole))
-    return jnp.moveaxis(out, 0, 1).reshape(n, t, heads, -1)
+            out = some(0, count, None)
+        else:
+            # (a suffix whose padding runs past its table, ``starts + t >
+            # keys``, gives no such bound: every block over every key)
+            out = jax.lax.cond(jnp.all(starts + t <= keys), grouped,
+                               lambda: some(0, count, whole))
+        return jnp.moveaxis(out, 0, 1).reshape(n, t, heads, -1)

@@ -41,6 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.grouped_expert_ffn import (grouped_expert_ffn_kernel,
                                             grouped_expert_ffn_reference,
                                             hidden_activation)
@@ -122,25 +123,31 @@ def moe_ffn(
     e = router_w.shape[1]
     capacity = max(1, int(capacity_factor * t * top_k / e))
 
-    logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
-    dispatch, combine, aux = router_topk(
-        logits, top_k=top_k, capacity=capacity
-    )
+    with jax.named_scope(scopes.MOE_ROUTER):
+        logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
+        dispatch, combine, aux = router_topk(
+            logits, top_k=top_k, capacity=capacity
+        )
 
     dtype = x.dtype
-    expert_in = jnp.einsum("td,tec->ecd", x, dispatch.astype(dtype),
-                           preferred_element_type=jnp.float32).astype(dtype)
-    h = jax.nn.silu(
-        jnp.einsum("ecd,edf->ecf", expert_in, wi_gate,
-                   preferred_element_type=jnp.float32)
-    ) * jnp.einsum("ecd,edf->ecf", expert_in, wi_up,
-                   preferred_element_type=jnp.float32)
-    h = h.astype(dtype)
-    expert_out = jnp.einsum("ecf,efd->ecd", h, wo,
-                            preferred_element_type=jnp.float32).astype(dtype)
-    out = jnp.einsum("ecd,tec->td", expert_out, combine.astype(dtype),
-                     preferred_element_type=jnp.float32)
-    return out.astype(dtype), aux
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        expert_in = jnp.einsum(
+            "td,tec->ecd", x, dispatch.astype(dtype),
+            preferred_element_type=jnp.float32).astype(dtype)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        h = jax.nn.silu(
+            jnp.einsum("ecd,edf->ecf", expert_in, wi_gate,
+                       preferred_element_type=jnp.float32)
+        ) * jnp.einsum("ecd,edf->ecf", expert_in, wi_up,
+                       preferred_element_type=jnp.float32)
+        h = h.astype(dtype)
+        expert_out = jnp.einsum(
+            "ecf,efd->ecd", h, wo,
+            preferred_element_type=jnp.float32).astype(dtype)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        out = jnp.einsum("ecd,tec->td", expert_out, combine.astype(dtype),
+                         preferred_element_type=jnp.float32)
+        return out.astype(dtype), aux
 
 
 # Up to this many tokens ``moe_ffn_dropless`` runs every held expert over
@@ -198,7 +205,7 @@ def moe_route(
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
                          f"got {scoring!r}")
-    with jax.named_scope("moe_router"):
+    with jax.named_scope(scopes.MOE_ROUTER):
         # true float32: the chip's default would round the products to
         # bf16 and now and then pick another k-th expert than float32 does
         logits = jnp.dot(rows.astype(jnp.float32),
@@ -248,7 +255,7 @@ def moe_experts(
     e = wi_up.shape[-3]
     dtype = x.dtype
     gate_act = EXPERT_FORMS[form]       # None: the form has no gate
-    with jax.named_scope("moe_router"):
+    with jax.named_scope(scopes.MOE_ROUTER):
         if e < n_experts:
             # a share: experts by their place in the stacks held here, and
             # every absent one as ``e``, which is no expert's place
@@ -258,7 +265,7 @@ def moe_experts(
         if valid is not None:
             chosen = chosen & valid[:, None, None]
         load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)  # [E]
-    with jax.named_scope("moe_experts"):
+    with jax.named_scope(scopes.MOE_EXPERTS):
         if not expert_kernel_engages(t):
             if layer is not None:
                 wi_gate, wi_up, wo = (w if w is None else w[layer]
@@ -344,16 +351,17 @@ def share_statistics(load, valid, rows: int, top_k: int) -> dict:
     busiest one's load over the mean load, and the share of the tokens'
     ``top_k`` choices that fell on a held expert (``valid``: the rows
     that are tokens, of ``rows``; None: all)."""
-    load = load.astype(jnp.float32)
-    tokens = (jnp.float32(rows) if valid is None
-              else jnp.sum(valid, dtype=jnp.float32))
-    return {
-        "experts_touched": jnp.sum(load > 0, dtype=jnp.float32),
-        "expert_load_max_over_mean":
-            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
-        "routed_here_share":
-            jnp.sum(load) / jnp.maximum(tokens * top_k, 1.0),
-    }
+    with jax.named_scope(scopes.MOE_ROUTER):
+        load = load.astype(jnp.float32)
+        tokens = (jnp.float32(rows) if valid is None
+                  else jnp.sum(valid, dtype=jnp.float32))
+        return {
+            "experts_touched": jnp.sum(load > 0, dtype=jnp.float32),
+            "expert_load_max_over_mean":
+                jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+            "routed_here_share":
+                jnp.sum(load) / jnp.maximum(tokens * top_k, 1.0),
+        }
 
 
 def _experts_all(x, weights, wi_gate, wi_up, wo, gate_act):
@@ -381,11 +389,12 @@ def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
     and each token's K weighted results summed where they came from."""
     t, k = gate_idx.shape
     e = wi_up.shape[1]
-    expert = gate_idx.reshape(t * k)
-    if valid is not None:
-        expert = jnp.where(jnp.repeat(valid, k), expert, e)
-    order = jnp.argsort(expert)                    # stable: pair -> row
-    xs = x[order // k]                             # [T*K, D]
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        expert = gate_idx.reshape(t * k)
+        if valid is not None:
+            expert = jnp.where(jnp.repeat(valid, k), expert, e)
+        order = jnp.argsort(expert)                # stable: pair -> row
+        xs = x[order // k]                         # [T*K, D]
     ys = jax.lax.platform_dependent(
         xs, load, wi_gate, wi_up, wo, layer,
         tpu=functools.partial(grouped_expert_ffn_kernel, gate_act=gate_act),
@@ -394,7 +403,8 @@ def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
     # each pair's row back beside its token; a pair in no group (its row
     # holds anything) adds nothing. Weighing and masking after the gather
     # fuse into the sum: no pass of their own over the [T*K, D] rows
-    back = jnp.argsort(order)                      # pair -> row
-    held = (expert < e).reshape(t, k, 1)
-    ys = ys[back].reshape(t, k, -1) * gate_vals[:, :, None]
-    return jnp.sum(jnp.where(held, ys, 0.0), axis=1)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        back = jnp.argsort(order)                  # pair -> row
+        held = (expert < e).reshape(t, k, 1)
+        ys = ys[back].reshape(t, k, -1) * gate_vals[:, :, None]
+        return jnp.sum(jnp.where(held, ys, 0.0), axis=1)

@@ -48,8 +48,11 @@ import hashlib
 from collections import OrderedDict
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ray_tpu.ops import scopes
 
 
 class PageRow(NamedTuple):
@@ -111,18 +114,19 @@ def write_kv(kp, vp, ks, vs, layer, k_new, v_new, pidx, ip, quantized):
     the new rows (a scatter, in place on the buffer the layer loop
     carries). Write before any read of the layer's pages, so the reader
     sees the rows just written."""
-    if quantized:
-        kq, ksc = quantize_kv(k_new)
-        vq, vsc = quantize_kv(v_new)
-        kp = kp.at[layer, pidx, ip].set(kq, mode="drop")
-        vp = vp.at[layer, pidx, ip].set(vq, mode="drop")
-        ks = ks.at[layer, pidx, ip].set(ksc, mode="drop")
-        vs = vs.at[layer, pidx, ip].set(vsc, mode="drop")
-    else:
-        kp = kp.at[layer, pidx, ip].set(k_new.astype(kp.dtype),
-                                        mode="drop")
-        vp = vp.at[layer, pidx, ip].set(v_new.astype(vp.dtype),
-                                        mode="drop")
+    with jax.named_scope(scopes.KV_WRITE):
+        if quantized:
+            kq, ksc = quantize_kv(k_new)
+            vq, vsc = quantize_kv(v_new)
+            kp = kp.at[layer, pidx, ip].set(kq, mode="drop")
+            vp = vp.at[layer, pidx, ip].set(vq, mode="drop")
+            ks = ks.at[layer, pidx, ip].set(ksc, mode="drop")
+            vs = vs.at[layer, pidx, ip].set(vsc, mode="drop")
+        else:
+            kp = kp.at[layer, pidx, ip].set(k_new.astype(kp.dtype),
+                                            mode="drop")
+            vp = vp.at[layer, pidx, ip].set(v_new.astype(vp.dtype),
+                                            mode="drop")
     return kp, vp, ks, vs
 
 
@@ -156,10 +160,11 @@ def write_rows(pool, layer, rows, pidx, ip):
     land at (layer, pidx, ip), zeros in the lanes past their width,
     out-of-bounds indices dropping."""
     spare = pool.shape[-1] - rows.shape[-1]
-    if spare:
-        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, spare)])
-    return pool.at[layer, pidx, ip].set(rows.astype(pool.dtype),
-                                        mode="drop")
+    with jax.named_scope(scopes.KV_WRITE):
+        if spare:
+            rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, spare)])
+        return pool.at[layer, pidx, ip].set(rows.astype(pool.dtype),
+                                            mode="drop")
 
 
 def gather_rows(pool, layer, table):

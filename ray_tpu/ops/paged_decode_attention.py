@@ -79,6 +79,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.paged_attention import gather_kv_window, visible_pages
 
@@ -365,6 +366,7 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
     that differ in their chunk alone trace them once (a trace of both is
     0.2-0.3 s of a program's set-up on the chip's host)."""
     kernel, reference = _lowerings(window)
-    return lax.platform_dependent(
-        q, k_pages, v_pages, k_scale, v_scale, layer, table, pos, active,
-        tpu=kernel, default=reference)
+    with jax.named_scope(scopes.ATTN):
+        return lax.platform_dependent(
+            q, k_pages, v_pages, k_scale, v_scale, layer, table, pos, active,
+            tpu=kernel, default=reference)

@@ -86,6 +86,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.paged_attention import gather_kv_window, visible_pages
 
@@ -445,14 +446,15 @@ def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
     the two lowerings are one pair of partials a window (``_lowerings``),
     not closures made a call, so the programs of an engine trace them
     once."""
-    if not kernel_engages(q.shape, k_pages, table_rows.shape[1], window):
-        return paged_prefill_attention_reference(
+    with jax.named_scope(scopes.ATTN):
+        if not kernel_engages(q.shape, k_pages, table_rows.shape[1], window):
+            return paged_prefill_attention_reference(
+                q, k_pages, v_pages, k_scale, v_scale, layer, table_rows,
+                starts, window=window)
+        kernel, plain = _lowerings(window)
+        return lax.platform_dependent(
             q, k_pages, v_pages, k_scale, v_scale, layer, table_rows, starts,
-            window=window)
-    kernel, plain = _lowerings(window)
-    return lax.platform_dependent(
-        q, k_pages, v_pages, k_scale, v_scale, layer, table_rows, starts,
-        slens, tpu=kernel, default=plain)
+            slens, tpu=kernel, default=plain)
 
 
 @functools.lru_cache(maxsize=None)

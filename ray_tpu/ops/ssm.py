@@ -64,6 +64,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import scopes
+
 STATE_KERNEL_NAME = "ssm_state_step"
 _BLOCK_BYTES = 1 << 20      # of state a grid step moves each way
 _BLOCK_MAX_BYTES = 3 << 20  # the most it may: four blocks lie in VMEM
@@ -127,9 +129,10 @@ def ssm_scan(x, dt, a, b, c, state, *, chunk: int = 128):
                               preferred_element_type=jnp.float32))
         return s_out, y
 
-    state, y = lax.scan(one_chunk, state.astype(jnp.float32),
-                        (split(x), split(dt), split(b), split(c)))
-    return jnp.moveaxis(y, 0, 1).reshape(n, t, heads, width), state
+    with jax.named_scope(scopes.SSM_SCAN):
+        state, y = lax.scan(one_chunk, state.astype(jnp.float32),
+                            (split(x), split(dt), split(b), split(c)))
+        return jnp.moveaxis(y, 0, 1).reshape(n, t, heads, width), state
 
 
 def ssm_step(x, dt, a, b, c, state):
@@ -266,12 +269,13 @@ def ssm_state_step(x, dt, a, b, c, states, layer, active):
     formulation, called directly. Within it the two lowerings are the
     module's own functions, not closures made a call, so the programs of
     an engine trace them once."""
-    if not state_kernel_engages(states):
-        return ssm_state_step_reference(x, dt, a, b, c, states, layer,
-                                        active)
-    return lax.platform_dependent(
-        x, dt, a, b, c, states, layer, active,
-        tpu=ssm_state_step_kernel, default=ssm_state_step_reference)
+    with jax.named_scope(scopes.SSM_STEP):
+        if not state_kernel_engages(states):
+            return ssm_state_step_reference(x, dt, a, b, c, states, layer,
+                                            active)
+        return lax.platform_dependent(
+            x, dt, a, b, c, states, layer, active,
+            tpu=ssm_state_step_kernel, default=ssm_state_step_reference)
 
 
 def causal_conv(x, tail, weight, bias):

@@ -90,6 +90,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.decoding import select_tokens
+from ray_tpu.ops import scopes
 from ray_tpu.ops.latent_attention import (index_kernel_engages,
                                           latent_decode_attention,
                                           latent_kernel_engages,
@@ -418,10 +419,7 @@ class EnginePrograms:
             _routes(run, blocks if run.key is None else blocks[run.key])
             for run in plan)
         self._compiled: dict[str, object] = {}     # by program name
-        # a prefill's first tokens into the loop's last-token vector
-        self.scatter_firsts = _named_jit(
-            "scatter_firsts", lambda last, slots, firsts:
-            last.at[slots].set(firsts.astype(last.dtype)))
+        self.scatter_firsts = _named_jit("scatter_firsts", _scatter_firsts)
 
     def _kv_twins(self, layers: int) -> list:
         """The four pools of ``layers`` layers that keep K/V twins: K
@@ -556,6 +554,12 @@ class EnginePrograms:
                 self._latent_backend and index_kernel_engages(
                     self.page_size, pages, self.selects,
                     self._index_width))}
+
+
+def _scatter_firsts(last, slots, firsts):
+    """A prefill's first tokens into the loop's last-token vector."""
+    with jax.named_scope(scopes.SAMPLE):
+        return last.at[slots].set(firsts.astype(last.dtype))
 
 
 def _paged_decode_impl(cfg, params, *args, chunk, page_size,
@@ -735,8 +739,9 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
                           for a in state)
             mixed, final = model.recurrent_mixer(cfg, p, x, fresh, valid)
             at = _state_place(place, layer)
-            return mixed, [a.at[at, slots].set(new, mode="drop")
-                           for a, new in zip(state, final)]
+            with jax.named_scope(scopes.SSM_MIXER):
+                return mixed, [a.at[at, slots].set(new, mode="drop")
+                               for a, new in zip(state, final)]
 
         if not run.attends:
             if run.state is not None:
@@ -786,8 +791,9 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
                                 carry, (stacks, places, *at))
     x, *rest = carry
     x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps)
-    x = jnp.take_along_axis(
-        x, (slens - 1)[:, None, None], axis=1).squeeze(1)
+    with jax.named_scope(scopes.LM_HEAD):
+        x = jnp.take_along_axis(
+            x, (slens - 1)[:, None, None], axis=1).squeeze(1)
     first = select_tokens(model.head_logits(cfg, params, x), temps, key)
     return _PREFILL.returned(rest[:n_pools], dict(firsts=first),
                              rest[n_pools:])

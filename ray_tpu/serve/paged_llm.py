@@ -75,6 +75,7 @@ from ray_tpu.serve.engine_programs import (EnginePrograms, _model_module,
                                            _paged_prefill_impl, _recurrent)
 from ray_tpu.serve.llm import _STAGES, Request, _serve_hist
 from ray_tpu.util import metrics as _metrics
+from ray_tpu.util import program_scopes as _program_scopes
 from ray_tpu.util import tracing as _tracing
 
 
@@ -219,6 +220,10 @@ class PagedLLMEngine:
         self._stream_seq = itertools.count()
         # the engine loop's spans are one trace (util/tracing.phase)
         self._trace_id = uuid.uuid4().hex[:16]
+        # the programs dispatched while spans were recorded, by their
+        # modules' names: ``stop`` records what their instructions are
+        # pieces of (util/program_scopes.py)
+        self._traced_programs: set[str] = set()
         # requests the loop has taken off the queue whose prefill is not
         # dispatched yet (a failing dispatch must still end their
         # streams: see _loop), and requests whose first token went out
@@ -343,6 +348,11 @@ class PagedLLMEngine:
         self._ready_q.put(None)
         if self._watcher is not None:
             self._watcher.join(timeout=30)
+        if self._traced_programs:
+            # a traced slice dispatched these: their instruction-to-scope
+            # maps, while the backend still holds their executables
+            _program_scopes.record_programs(self._traced_programs)
+            self._traced_programs = set()
 
     def _ready_watcher(self):
         """The device's timeline as the host sees it. Every dispatch
@@ -539,6 +549,8 @@ class PagedLLMEngine:
             starts=jnp.asarray(starts_np), temps=temps,
             key=self._next_key(), slots=slots)
         firsts = self._programs.prefilled(program(*arguments))
+        if ph:
+            self._traced_programs.add("jit_" + program.__name__)
         # the dispatch above is what makes each slot's full prompt pages
         # valid on device: REGISTER them in the prefix cache now — any
         # future admission's prefill program runs after this one on the
@@ -1019,6 +1031,8 @@ class PagedLLMEngine:
                 lengths=dev["lens"], active=dev["active"],
                 temps=dev["temps"], key=self._next_key())
             out = self._programs.decoded(program(*arguments))
+            if ph:
+                self._traced_programs.add("jit_" + program.__name__)
             toks = out["toks"]
             self._chunk_stats.append(out["stats"])
             kernels = self._programs.decode_kernels(pb)

@@ -26,6 +26,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import llama
+from ray_tpu.ops import scopes
 from ray_tpu.parallel.mesh import create_mesh
 from ray_tpu.parallel.sharding import (
     PRESETS,
@@ -35,7 +36,7 @@ from ray_tpu.parallel.sharding import (
     tree_shardings,
 )
 from ray_tpu.train.state import TrainState, state_logical_axes
-from ray_tpu.util import tracing
+from ray_tpu.util import program_scopes, tracing
 
 
 @dataclass
@@ -325,11 +326,12 @@ class JaxTrainer:
         else:
             loss, grads = jax.value_and_grad(self._loss_fn)(
                 state.params, batch)
-        updates, new_opt = self.optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, new_opt = self.optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1
         )
@@ -389,8 +391,10 @@ class JaxTrainer:
             log_every: int = 10, callback: Callable | None = None):
         history = []
         t0 = time.perf_counter()
+        traced = False      # whether a step ran while spans were recorded
         for i in range(steps):
             batch = next(data_iter)
+            traced = traced or tracing.recording()
             state, metrics = self.train_step(state, batch)
             if (i + 1) % log_every == 0 or i == steps - 1:
                 m = {k: float(v) for k, v in metrics.items()}
@@ -398,4 +402,8 @@ class JaxTrainer:
                 history.append(m)
                 if callback:
                     callback(m)
+        if traced:
+            # what the step's instructions are pieces of, once the last
+            # step is behind (util/program_scopes.py)
+            program_scopes.record_programs({"jit_" + self._step.__name__})
         return state, history

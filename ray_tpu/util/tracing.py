@@ -588,6 +588,36 @@ def recorded_spans(prefix: str = "") -> list[dict]:
             if "event" not in r and r["name"].startswith(prefix)]
 
 
+# What each instruction of a compiled device program is a piece of
+# (``util/program_scopes.py``), one record an executable. Not in the span
+# rings: the pusher drains one of them to the GCS store, and a map is
+# hundreds of KB. Bounded: a record of the same program and executable
+# replaces the one before it.
+_scope_maps: dict[tuple, dict] = {}
+
+
+def record_scopes(program: str, executable: str, scopes: dict,
+                  inferred: dict) -> None:
+    """Keep ``{instruction: [result shape, scope]}`` of one executable of
+    the device program ``program`` (its module's name), and which of
+    those scopes were not the instruction's own (``{instruction: rule}``,
+    ``util/program_scopes.py:instruction_scopes``)."""
+    with _ring_lock:
+        _scope_maps[program, executable] = {
+            "program": program, "executable": executable, "scopes": scopes,
+            "inferred": inferred}
+
+
+def recorded_scopes() -> list[dict]:
+    """The records ``record_scopes`` kept in this process, oldest first:
+    ``program``, ``executable`` (the backend's fingerprint of it),
+    ``scopes`` and ``inferred``. Read beside ``recorded_spans`` once a run has ended: an
+    ``XLA Ops`` event of a profiler's trace is joined to its instruction
+    here by name and result shape."""
+    with _ring_lock:
+        return list(_scope_maps.values())
+
+
 def dump_flight(path: str | None = None, last_s: float | None = None) -> str:
     """Write the flight snapshot as JSON; returns the path. Defaults to
     ``flight-<pid>-<ts>.json`` in the trace dir (or tempdir)."""

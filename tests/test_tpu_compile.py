@@ -9,6 +9,7 @@ trainers are steered to the flash kernel from here (``attn_impl``).
 """
 
 import dataclasses
+import math
 import os
 import re
 from functools import cache, partial
@@ -1118,6 +1119,63 @@ def test_brief_d8_decode_program_reads_its_pages_in_place(v5e_2x2):
     pool_bytes = 8 * _BRIEF_PAGES * 128 * 4 * 128 * 2
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     assert mem.temp_size_in_bytes < 0.1e9
+
+
+def test_brief_d8_cold_prefill_names_the_pieces_a_trace_shows(v5e_2x2):
+    """The cold document of ``serve-brief-gen`` compiled for the chip,
+    read as a traced engine records it at ``stop()``
+    (``util/program_scopes.py:instruction_scopes``): the float32 combine
+    fusion (result ``f32[rows x k, d_model]``, 8,192 rows x 6 choices) and
+    its rematerialised copies lie under ``moe_combine``, the grouped
+    kernel's eight calls under ``moe_experts``, the prefill kernel's four
+    under ``attn``, the K/V scatter (whose own name the compiler drops)
+    under ``kv_write`` by the one rule for what has no ``op_name`` at all;
+    and the instructions that run (not a parameter, a constant, a tuple or
+    its element, or a bitcast) under no name of the vocabulary hold under
+    a twentieth of the running instructions' result elements. By COUNT
+    they are a sixth: 57 of them the ``pred[8192]`` masks of the write
+    targets' arithmetic in the engine program, which no scope wraps; what
+    weighs is the layer's normed rows ``bf16[8192, d_model]``, whose
+    fusion takes the name of the reshape behind the norm (PR 57)."""
+    from ray_tpu.ops import scopes
+    from ray_tpu.util import program_scopes
+
+    smallthinker, cfg = _serving_model("smallthinker-d8")
+    text = _compile_engine_program(
+        v5e_2x2[0], smallthinker, cfg, _BRIEF_PAGES, "prefill",
+        (1, 8192, 64)).as_text()
+    found, inferred = program_scopes.instruction_scopes(text)
+
+    def under(pattern, shape=None):
+        return {scope for name, (was, scope) in found.items()
+                if re.match(pattern, name) and shape in (None, was)}
+
+    rows = f"f32[{8192 * cfg.top_k},{cfg.d_model}]"
+    assert rows == "f32[49152,2560]"
+    assert under(r"fusion", rows) == {scopes.MOE_COMBINE}
+    assert under(r"reshape.*remat") == {scopes.MOE_COMBINE}
+    kernels = [n for n in found if n.startswith("grouped_expert_ffn")]
+    assert len(kernels) == 8
+    assert under(r"grouped_expert_ffn") == {scopes.MOE_EXPERTS}
+    assert under(r"paged_prefill_attn") == {scopes.ATTN}
+    assert under(r"fusion", "bf16[2359296,4,128]") == {scopes.KV_WRITE}
+    assert {scope for _, scope in found.values()} <= set(
+        scopes.VOCABULARY) | {""}
+    free = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+    runs = [m["name"] for m in map(program_scopes._INSTRUCTION.match,
+                                   text.splitlines())
+            if m and m["name"] in found and m["opcode"] not in free]
+    unscoped = [name for name in runs if not found[name][1]]
+    assert len(runs) > 500 and len(unscoped) < 0.2 * len(runs), unscoped
+
+    def elements(names):
+        return sum(math.prod(int(d) for d in re.findall(
+            r"\d+", found[name][0].partition("[")[2])) for name in names)
+
+    assert elements(unscoped) < 0.05 * elements(runs)
+    assert set(inferred.values()) == {"operand", "user"}
+    masks = [n for n in unscoped if found[n][0] == "pred[8192]"]
+    assert len(unscoped) - len(masks) < 0.1 * len(runs)
 
 
 # dots3-note-prev cut to its first five layers (``serve-note-gen``): 64
