@@ -222,13 +222,24 @@ def rotary_tables(cfg: Dots3NoteConfig, positions) -> dict:
 #   scale: scores of deviation about 1.5 in a full layer, some hundreds of
 #   keys a head. What the selection's tipping moves and what a reference
 #   WITHOUT the indexer moves both go with the full layers' part of the
-#   logits, about 1 to 9 where the selection drops a fifth of the keys
-#   (at 0.4 the program read 0.030 rms and the blind reference 0.24; at
-#   0.25, 0.013 and 0.076) and further apart where it drops half, which
-#   is why the cell's check runs at 4,000 tokens: there the engine's
-#   tokens read 0.008-0.033 short over four seeds and the blind
-#   reference's 0.42 (at 0.4 and 2,600 tokens one seed of four read
-#   0.103 against the check's 0.1);
+#   logits, about 1 to 9 in the mean where the selection drops a fifth of
+#   the keys (at 0.4 the program read 0.030 rms and the blind reference
+#   0.24; at 0.25, 0.013 and 0.076) and further apart where it drops
+#   half, which is why the cell's check runs at 4,000 tokens. The check
+#   reads each side's WORST token of 64, and the two meet at their tails
+#   (sixteen seeds of the check on a v5e, the engine's tokens short of the
+#   reference's best and the same tokens against the reference blind to
+#   the indexer, my chip runs, PR 58, ``scripts/note_check_seeds.py``):
+#   at 0.25 the program read 0.005-0.158, over the check's 0.1 on three
+#   seeds through the plain prefill and on two through the kernel's (4
+#   and 3 of forty), and the blind reference 0.28-0.54 (``topk=1024``
+#   0.25-0.61); at 0.225, 0.000-0.101 (one seed over) and 0.15-0.46; at
+#   0.2, 0.000-0.099 over forty seeds, none over the limit, but the
+#   blind reference 0.09-0.39: one seed of sixteen would pass a program
+#   blind to its indexer. So 0.25 stays: a seed in thirteen refuses a
+#   correct program for a tipping (PERF.md section 7), none passes a
+#   blind one; a smaller scale trades the one for the other, and the way
+#   out is the check's (an rms over positions, not the worst of 64);
 # - a sigmoid router's chosen experts weigh about alike whatever its
 #   scale, so a choice that tips between the eighth and the ninth moves a
 #   whole expert's part, as the correction bias's own changes do: the
@@ -476,7 +487,8 @@ def forward(cfg: Dots3NoteConfig, params: dict, tokens, *,
                 attn = jnp.moveaxis(lax.map(one, jnp.arange(s)), 0, 1)
             else:
                 attn = latent_prefill_attention(
-                    inputs, pools, 0, table, start, window=run.window)
+                    inputs, pools, 0, table, start, jnp.full_like(start, s),
+                    window=run.window)
             x = attention_output(cfg, p, x, attn)
             x, _ = feed_forward(cfg, p, x)
             return x, None

@@ -9,10 +9,11 @@ dn + dv]), and its score on a key is ``(qn . kn + qr . kr) x scale``
 (DeepSeek-V2's multi-head latent attention, arXiv:2405.04434). Two forms
 give the same numbers:
 
-- EXPANDED (``latent_prefill_attention``): the rows a block of queries
-  can see are gathered and expanded into every head's key and value, and
-  plain causal softmax attention runs over them. Right for a prefill's
-  many queries: the expansion is paid once a block of queries;
+- EXPANDED (``latent_prefill_attention``): the rows the queries can see
+  are gathered and expanded into every head's key and value, and causal
+  softmax attention runs over them. Right for a prefill's many queries:
+  the expansion is paid once for all of them (once a block of queries in
+  a windowed layer's plain formulation);
 - ABSORBED (``latent_decode_attention``): the query takes the expansion
   instead, ``q~ = qn @ wkv_b[K]^T`` in R^r, scores run against the rows
   as they lie, ``(q~ . ckv + qr . kr) x scale``, the probabilities weigh
@@ -32,10 +33,45 @@ a rounding), and its softmax runs over the ``topk`` keys of largest
 A WINDOWED layer's queries see the ``window`` newest keys, their own
 among them.
 
-WHAT RUNS WHERE. The prefill form is plain ``jax.numpy`` and ``lax``: it
-gathers the pages a block of queries can see (``visible_pages`` for a
-window), the selection is a mask, and its float32 scores, the layer's
-and its indexer's, go over blocks of queries under ``SCORES_MAX_BYTES``.
+WHAT RUNS WHERE. The prefill form has two formulations of the same
+softmax over the same keys, and ``latent_prefill_attention`` chooses by
+the platform a program is lowered for (``jax.lax.platform_dependent``)
+and by static shapes (``latent_prefill_kernel_engages``), nothing else:
+
+- IN THE KERNEL, one Pallas kernel (``latent_prefill_attn``; full and
+  sliding layers are one kernel at different static arguments): the
+  table's rows are gathered and expanded ONCE a layer in HBM, a head's
+  key as one array (its no-position part beside the rotary key all heads
+  share: 128 + 64 or 192 + 64 wide) and its value, and the grid walks
+  (row, four heads, a block of 512 queries, a chunk of 512 keys): scores
+  on the MXU into VMEM, masked by position (causal from ``starts``; under
+  a static ``window`` the second side too), online softmax in float32,
+  the probabilities cast to the rows' type before the weighted sum, the
+  sum divided by the float32 denominator at a block's last chunk. A
+  block's walk starts at the chunk of its oldest visible key and ends at
+  its last valid query's: the chunks outside it are neither fetched (the
+  index maps hold them at the walk's ends) nor computed, and a block of
+  padding alone is zeros. The SELECTION reaches it as flags a query and
+  key ([n, T, S], one byte a pair): ``index_scores`` and ``kept`` run in
+  ``jax.numpy`` as the plain formulation runs them, in float32, in blocks
+  of queries whose index scores fit ``SCORES_MAX_BYTES`` (they stay in
+  HBM), and the kernel computes no index score, no top-k and no tie.
+  Nothing of size heads x queries x keys is written to HBM (a full
+  layer of one cold 4,096-token prompt, 3,600 of them valid, on a v5e,
+  PR 58: 14.9 ms, of which the kernel 10.4, the flags 3.4 and the
+  expansion 2.1, against the plain formulation's 51.7; a sliding layer
+  4.3 against 14.9);
+- PLAIN ``jax.numpy`` and ``lax`` (``_prefill_plain``: what the kernel
+  is held to, and what every other platform and every shape under the
+  rule runs): it gathers the pages a block of queries can see
+  (``visible_pages`` for a window), the selection is a mask, and its
+  float32 scores, the layer's and its indexer's, go over blocks of
+  queries under ``SCORES_MAX_BYTES``: written, masked, read for the max,
+  for the sum, written as probabilities and read again.
+
+The rule between them is a line in bytes on the plain formulation's
+float32 scores for the layer: see ``PREFILL_KERNEL_SCORES_BYTES``.
+
 The decode form has two formulations of the same softmax over the same
 keys, and ``latent_decode_attention`` chooses by the platform a program
 is lowered for (``jax.lax.platform_dependent``) and by static shapes
@@ -629,6 +665,42 @@ def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
                           ).astype(inputs.q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Prefill: the expanded form, plain or in the kernel
+# ---------------------------------------------------------------------------
+
+PREFILL_KERNEL_NAME = "latent_prefill_attn"
+# The plain formulation's float32 scores for a layer (every block of its
+# queries as ``query_block`` cuts them: [n, H, block, keys], the
+# indexer's [n, HI, block, keys] beside them) past which a layer over
+# bf16 rows takes the kernel on a TPU: the line of
+# ``ops/paged_prefill_attention.py``'s ``KERNEL_SCORES_BYTES``, drawn as
+# that one was, from what a kernel-holding program costs the host to
+# trace and lower in every run against what it saves a dispatch (a v5e,
+# one full layer behind a cached 3,700-token transcript, plain against
+# the kernel's path, PR 58: 256 queries, 805 MB of scores, 5.9 ms against
+# 3.2; 128 queries, 403 MB, 3.9 against 2.8; 64 queries, 201 MB, 2.9
+# against 2.7, where both are the whole table's expansion, 2.1; a sliding
+# layer's suffix, 9-38 MB, 0.3-0.6 ms against 1.8: its plain path expands
+# two windows' pages and the kernel's the table).
+PREFILL_KERNEL_SCORES_BYTES = 256 << 20
+# queries a block and keys a chunk of the kernel's walk (a head's scores
+# [512, 512] float32 are 1 MiB of VMEM), and the heads a grid step takes:
+# they share the step's block of flags (a cold 4,096-token prompt, 3,600
+# queries valid, on a v5e, the kernel alone, a full layer with flags / a
+# sliding one, ``scripts/sweep_latent_prefill.py``, PR 58: 10.29 / 2.15
+# ms at 512, 512, 4; 9.67 / 2.35 with chunks of 1,024; 9.50 / 2.88 at
+# 1,024, 1,024, 2; 11.75 / 3.02 with blocks of 1,024; 10.43 / 2.13 at 8
+# heads; 17.43 / 3.91 with chunks of 256)
+_PREFILL_BLOCK_Q = 512
+_PREFILL_CHUNK = 512
+_PREFILL_HEADS = 4
+# of the core's 128 MiB: a step's queries, keys, values, flags and
+# outputs twice, the heads' accumulators, one head's scores and
+# probabilities (about 14 MiB at 256 | 128 wide)
+_PREFILL_VMEM_BYTES = 48 << 20
+
+
 def query_block(n: int, t: int, heads: int, keys: int, window) -> int:
     """How many of a prefill's ``t`` queries a row attend at once: a
     windowed layer's in blocks of about its window (a block then gathers
@@ -647,126 +719,396 @@ def query_block(n: int, t: int, heads: int, keys: int, window) -> int:
     return block
 
 
+def _kernel_blocks(t: int, keys: int) -> tuple:
+    """(queries a block, keys a chunk) of the kernel's walk over ``t``
+    queries a row and ``keys`` keys: the largest powers of two up to 512
+    that divide them."""
+    return (math.gcd(t, _PREFILL_BLOCK_Q), math.gcd(keys, _PREFILL_CHUNK))
+
+
+def latent_prefill_kernel_engages(q_shape, pool, table_pages: int, window,
+                                  index_heads: int = 0) -> bool:
+    """The rule, from shapes, the layer's kind and the pool's dtype:
+    whether the attention of a prefill's queries ``q_shape`` [n, T, H,
+    dn + dr] over ``table_pages`` pages a row of ``pool`` (the rows'
+    stacked pool, or its shape and dtype), under ``window`` keys where
+    the layer slides, beside ``index_heads`` indexer heads where it
+    selects (0: no indexer, or a table of no more than ``topk`` keys), is
+    the kernel's on a program lowered for a TPU: bf16 rows, and float32
+    scores of the plain formulation for the layer over
+    ``PREFILL_KERNEL_SCORES_BYTES``: a full layer's [n, H + HI, T, the
+    table's keys]; a sliding layer's what its blocks write, [n, H, T,
+    ``query_block`` + window] (or the table's keys where that is
+    narrower). The kernel takes queries in blocks and keys in chunks of
+    whole sublanes and lanes, and heads four at a time."""
+    n, t, heads, _ = q_shape
+    keys = table_pages * pool.shape[2]
+    bq, ck = _kernel_blocks(t, keys)
+    seen = keys
+    if window is not None:
+        seen = min(keys, query_block(n, t, heads, keys, window) + window)
+    return (pool.dtype == jnp.bfloat16 and heads % _PREFILL_HEADS == 0
+            and bq % 32 == 0 and ck % ROW_LANES == 0
+            and 4 * n * (heads + index_heads) * t * seen
+            > PREFILL_KERNEL_SCORES_BYTES)
+
+
+def _walked_chunks(starts_ref, slens_ref, b, qi, *, bq, ck, keys, window):
+    """(first, last): the chunks of keys that block ``qi`` of row ``b``'s
+    queries walks, from the one that holds the oldest key its first query
+    sees (0 without a window) to the one that holds its last valid
+    query's own; ``last < first`` for a block of padding alone."""
+    start, slen = starts_ref[b], slens_ref[b]
+    oldest = start + qi * bq
+    newest = start + jnp.minimum((qi + 1) * bq, slen) - 1
+    first = (0 if window is None
+             else jnp.clip(oldest - (window - 1), 0, keys - 1) // ck)
+    return first, jnp.where(qi * bq < slen,
+                            jnp.minimum(newest, keys - 1) // ck, first - 1)
+
+
+def _prefill_kernel(starts_ref, slens_ref,                       # SMEM
+                    q_ref, k_ref, v_ref, *refs, scale, window, keys):
+    """One grid step (row, block of heads, block of queries, chunk of
+    keys): ``q_ref`` [1, heads, bq, dk], ``k_ref`` [1, heads, ck, dk],
+    ``v_ref`` [1, heads, ck, dv], then the flags of the block's queries
+    on the chunk's keys [1, bq, ck] where the layer selects, the output
+    [1, heads, bq, dv] and, kept from chunk to chunk of a block's walk,
+    the heads' running max, denominator and weighted sum in float32. The
+    chunks outside the block's walk (``_walked_chunks``) are neither
+    fetched (the index maps hold them at the walk's ends) nor computed."""
+    *flags_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    b, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    heads, bq, ck = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
+    first, last = _walked_chunks(starts_ref, slens_ref, b, qi, bq=bq, ck=ck,
+                                 keys=keys, window=window)
+
+    @pl.when(kj == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((kj >= first) & (kj <= last))
+    def _():
+        qpos = starts_ref[b] + qi * bq + lax.broadcasted_iota(
+            jnp.int32, (bq, 1), 0)
+        kpos = kj * ck + lax.broadcasted_iota(jnp.int32, (1, ck), 1)
+        seen = kpos <= qpos
+        if window is not None:
+            seen &= kpos > qpos - window
+        if flags_ref:
+            seen &= flags_ref[0][0] != 0
+        for h in range(heads):
+            s = lax.dot_general(q_ref[0, h], k_ref[0, h],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            # (``_KERNEL_MASKED`` is finite: a row that has met no key of
+            # its set yet sums ones, which the ``alpha`` of its first
+            # such key zeroes)
+            s = jnp.where(seen, s, _KERNEL_MASKED)
+            m = m_ref[h]
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _():
+        # a block of padding alone walked nothing: zeros, never a NaN
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def latent_prefill_attention_kernel(q, k, v, starts, slens, flags=None, *,
+                                    scale, window=None, interpret=False):
+    """The prefill kernel's launch: ``q`` [n, H, T, dk], row i's first
+    query at position ``starts[i]``, its first ``slens[i]`` valid;
+    ``k`` [n, H, S, dk] and ``v`` [n, H, S, dv]: every head's key
+    (no-position part | rotary part) and value at the table's key
+    positions 0 to S; ``flags`` [n, T, S] int8, or None for a layer that
+    selects nothing: nonzero where the query's softmax may run over the
+    key (the causal side, and the ``window``'s, are the kernel's own).
+    Returns [n, H, T, dv] in ``q``'s type; the rows of padding inside a
+    block with valid queries come back finite and otherwise unspecified,
+    a block of padding alone as zeros."""
+    n, heads, t, dk = q.shape
+    keys, dv = k.shape[2], v.shape[3]
+    bq, ck = _kernel_blocks(t, keys)
+    walk = dict(bq=bq, ck=ck, keys=keys, window=window)
+
+    def chunk(b, qi, kj, starts_ref, slens_ref):
+        first, last = _walked_chunks(starts_ref, slens_ref, b, qi, **walk)
+        return jnp.clip(kj, first, jnp.maximum(last, first))
+
+    def of_queries(width):
+        return pl.BlockSpec((1, _PREFILL_HEADS, bq, width),
+                            lambda b, h, qi, kj, *_: (b, h, qi, 0))
+
+    def of_keys(width):
+        return pl.BlockSpec((1, _PREFILL_HEADS, ck, width),
+                            lambda b, h, qi, kj, *refs: (
+                                b, h, chunk(b, qi, kj, *refs), 0))
+
+    in_specs, operands = [of_queries(dk), of_keys(dk), of_keys(dv)], [q, k, v]
+    if flags is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, bq, ck),
+            lambda b, h, qi, kj, *refs: (b, qi, chunk(b, qi, kj, *refs))))
+        operands.append(flags)
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=scale, window=window,
+                          keys=keys),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n, heads // _PREFILL_HEADS, t // bq, keys // ck),
+            in_specs=in_specs, out_specs=of_queries(dv),
+            scratch_shapes=[
+                pltpu.VMEM((_PREFILL_HEADS, bq, 1), jnp.float32),
+                pltpu.VMEM((_PREFILL_HEADS, bq, 1), jnp.float32),
+                pltpu.VMEM((_PREFILL_HEADS, bq, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, heads, t, dv), q.dtype),
+        # a block's chunks in order (the accumulators go from one to the
+        # next); rows, heads and blocks of queries in any
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",),
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=interpret, name=PREFILL_KERNEL_NAME,
+    )(starts.astype(jnp.int32), slens.astype(jnp.int32), *operands)
+
+
+def _over_blocks(fn, xs, starts, by_key, *, block, grouped):
+    """``fn(*x, first, seen)`` for every block of ``block`` queries of
+    ``xs`` (arrays [n, t, ...]; ``first`` [n]: the block's first position,
+    from ``starts``), one block after another, their results [n, block,
+    ...] side by side as [n, t, ...]. ``seen``: ``by_key`` (arrays whose
+    last axis but one is the table's key positions from 0), whole unless
+    ``grouped``: where the table reaches past the last (padded) query,
+    the queries of block i see no key past ``keys - (count - 1 - i) x
+    block``, and the blocks go in up to ``KEY_GROUPS`` groups, each over
+    the keys its last block can see and no further (a cold prompt's first
+    quarter attends over a quarter of the keys, not all of them)."""
+    n, t = xs[0].shape[:2]
+    if block == t:
+        return fn(*xs, starts, by_key)
+    count = t // block
+    firsts = starts[None, :] + block * jnp.arange(
+        count, dtype=jnp.int32)[:, None]                     # [blocks, n]
+    xs = tuple(jnp.moveaxis(a.reshape(n, count, block, *a.shape[2:]), 1, 0)
+               for a in xs) + (firsts,)
+
+    def some(lo, hi, seen):
+        """Blocks ``lo`` to ``hi``, one after another."""
+        return jax.lax.map(lambda xs: fn(*xs, seen),
+                           jax.tree.map(lambda a: a[lo:hi], xs))
+
+    def in_groups():
+        groups, out = min(KEY_GROUPS, count), []
+        for g in range(groups):
+            lo, hi = g * count // groups, (g + 1) * count // groups
+            extent = keys - (count - hi) * block
+            out.append(some(lo, hi, jax.tree.map(
+                lambda a: a[..., :extent, :], by_key)))
+        return jnp.concatenate(out)
+
+    if not grouped:
+        out = some(0, count, by_key)
+    else:
+        # (a suffix whose padding runs past its table, ``starts + t >
+        # keys``, gives no such bound: every block over every key)
+        keys = jax.tree.leaves(by_key)[0].shape[-2]
+        out = jax.lax.cond(jnp.all(starts + t <= keys), in_groups,
+                           lambda: some(0, count, by_key))
+    return jnp.moveaxis(out, 0, 1).reshape(n, t, *out.shape[3:])
+
+
+def _causal(first, block: int, key_start, extent: int, window):
+    """[n, block, extent] bool: which of ``extent`` keys from position
+    ``key_start`` [n] each of a block's queries from ``first`` [n] may
+    see: those up to its own, under a ``window`` the newest alone."""
+    qpos = first[:, None] + jnp.arange(block, dtype=jnp.int32)
+    kpos = key_start[:, None] + jnp.arange(extent, dtype=jnp.int32)
+    mask = kpos[:, None, :] <= qpos[:, :, None]
+    if window is not None:
+        mask = mask & (kpos[:, None, :] > qpos[:, :, None] - window)
+    return mask
+
+
+def _expanded(q, wkv_b, rows, dr: int):
+    """What ``rows`` [n, S, lanes] hold, by key: the rotary keys [n, S,
+    dr], every head's no-position keys [n, H, S, dn] and values [n, H, S,
+    dv] (heads before keys: the layout the products over them contract
+    in), in ``q``'s type."""
+    r, dn = wkv_b.shape[0], q.shape[-1] - dr
+
+    def heads_of(w):
+        return jnp.einsum("nsr,rhe->nhse", rows[..., :r], w,
+                          preferred_element_type=jnp.float32
+                          ).astype(q.dtype)
+
+    return (rows[..., r:r + dr], heads_of(wkv_b[..., :dn]),
+            heads_of(wkv_b[..., dn:]))
+
+
+def _prefill_plain(q, wkv_b, indexed, pools, layer, table_rows, starts, *,
+                   scale, window, topk, dr):
+    """The plain formulation (what the kernel is held to, and what every
+    other platform and every shape under the rule runs): the float32
+    scores of a block of queries (``query_block``), the layer's and its
+    indexer's, written, masked and read again. ``indexed``: the queries'
+    index queries and weights where the layer selects ``topk`` keys, else
+    None. The rows of padding are computed as any other."""
+    pool = pools[0]
+    n, t, heads, _ = q.shape
+    page = pool.shape[2]
+    dn = q.shape[-1] - dr
+    keys = table_rows.shape[1] * page
+    block = query_block(
+        n, t, heads + (indexed[0].shape[2] if indexed else 0), keys, window)
+    # the pages that hold the keys of ``block`` queries' windows
+    seen = (table_rows.shape[1] if window is None
+            else -(-(block + window - 2) // page) + 1)
+
+    def expand(table):
+        """``_expanded`` of ``table``'s pages and, where the layer
+        selects, their index keys."""
+        return (*_expanded(q, wkv_b, gather_rows(pool, layer, table), dr),
+                gather_rows(pools[1], layer, table) if indexed else None)
+
+    # a full layer's queries all see the same rows: expanded once, outside
+    # the blocks; a windowed layer's blocks each expand what they can see
+    whole = expand(table_rows) if window is None else None
+
+    def attend(q, *rest):
+        """``q`` [n, block, H, dn + dr], then their index queries and
+        weights where the layer selects, the first's position [n] and
+        what ``expand`` gave for the keys from position 0 that they can
+        see, or None for a windowed layer, whose block expands the pages
+        of its own windows."""
+        *iq, first, seen_keys = rest
+        key_start = jnp.zeros_like(first)
+        if seen_keys is None:
+            table, key_start = visible_pages(
+                table_rows, first - window + 1, seen, page)
+            seen_keys = expand(table)
+        kr, kn, v, index_keys = seen_keys
+        scores = (jnp.einsum("nthd,nhsd->nhts", q[..., :dn], kn,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("nthd,nsd->nhts", q[..., dn:], kr,
+                               preferred_element_type=jnp.float32)
+                  ) * scale
+        mask = _causal(first, q.shape[1], key_start, kr.shape[1], window)
+        if iq and kr.shape[1] > topk:
+            chosen = jnp.where(mask, index_scores(*iq, index_keys), _MASKED)
+            mask = mask & kept(chosen, topk)
+        scores = jnp.where(mask[:, None], scores, _MASKED)
+        probs = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum(
+            "nhts,nhsv->nthv", probs.astype(q.dtype), v,
+            preferred_element_type=jnp.float32).astype(q.dtype)
+
+    return _over_blocks(attend, (q, *(indexed or ())), starts, whole,
+                        block=block, grouped=window is None)
+
+
+def _selection_flags(indexed, index_keys, starts, *, topk):
+    """[n, T, S] int8: 1 where ``kept`` keeps the key for the query, of
+    the keys up to its own: ``index_scores`` and ``kept`` as the plain
+    formulation runs them, in float32, over blocks of queries whose
+    index scores [n, HI, block, keys] fit ``SCORES_MAX_BYTES``, in the
+    plain formulation's groups of keys. A group of no more than ``topk``
+    keys drops none: ones."""
+    iq, iw = indexed
+    n, t, index_heads, _ = iq.shape
+    keys = index_keys.shape[1]
+
+    def flags(iq, iw, first, seen_keys):
+        extent = seen_keys.shape[1]
+        if extent <= topk:
+            return jnp.ones((n, iq.shape[1], keys), jnp.int8)
+        mask = _causal(first, iq.shape[1], jnp.zeros_like(first), extent,
+                       None)
+        chosen = jnp.where(mask, index_scores(iq, iw, seen_keys), _MASKED)
+        return jnp.pad((mask & kept(chosen, topk)).astype(jnp.int8),
+                       ((0, 0), (0, 0), (0, keys - extent)))
+
+    return _over_blocks(
+        flags, (iq, iw), starts, index_keys, grouped=True,
+        block=query_block(n, t, index_heads, keys, None))
+
+
+def _prefill_in_kernel(q, wkv_b, indexed, pools, layer, table_rows, starts,
+                       slens, *, scale, window, topk, dr):
+    """The kernel's formulation; arguments as ``_prefill_plain``'s, and
+    the rows' valid queries ``slens`` [n]. The table's rows are expanded
+    ONCE a layer in HBM, a head's key as one
+    array (its no-position part beside the rotary key all heads share),
+    and a layer that selects hands the kernel ``kept``'s set as flags."""
+    n, heads = q.shape[0], q.shape[2]
+    kr, kn, v = _expanded(
+        q, wkv_b, gather_rows(pools[0], layer, table_rows), dr)
+    k = jnp.concatenate(
+        [kn, jnp.broadcast_to(kr[:, None], (n, heads, *kr.shape[1:]))], -1)
+    flags = None
+    if indexed:
+        flags = _selection_flags(
+            indexed, gather_rows(pools[1], layer, table_rows), starts,
+            topk=topk)
+    out = latent_prefill_attention_kernel(
+        jnp.moveaxis(q, 1, 2), k, v, starts, slens, flags, scale=scale,
+        window=window)
+    return jnp.moveaxis(out, 1, 2)
+
+
+@functools.cache
+def _prefill_formulations(scale, window, topk, dr):
+    """(in the kernel, plain beside it, plain) for a layer of these
+    statics, made once: the branches ``lax.platform_dependent`` takes
+    (the first two: one list of arguments, of which the plain formulation
+    leaves the last, the valid lengths, unread) are then the same
+    functions from call to call (``_formulations``)."""
+    statics = dict(scale=scale, window=window, topk=topk, dr=dr)
+    plain = functools.partial(_prefill_plain, **statics)
+    return (functools.partial(_prefill_in_kernel, **statics),
+            lambda *args: plain(*args[:-1]), plain)
+
+
 def latent_prefill_attention(inputs: LatentInputs, pools: tuple, layer,
-                             table_rows, starts, *, window=None):
+                             table_rows, starts, slens, *, window=None):
     """A prefill's attention: queries ``inputs.q`` [n, T, H, dn + dr],
     row i's first at position ``starts[i]``, over the rows' pages
     (``table_rows`` [n, PB]) in the expanded form, the suffixes' own
     rows written already, so a suffix's queries see a reused prefix's
     rows exactly as the prompt that wrote them left them. A layer with
     an indexer scores a block's queries against the rows' index keys and
-    masks every key outside a query's ``topk`` (a block that sees no more
-    than ``topk`` keys drops none). Returns [n, T, H, dv]."""
+    attends over each query's ``topk`` alone (a block that sees no more
+    than ``topk`` keys drops none). ``slens`` [n]: the rows' valid
+    queries; the rows of padding past them come back finite and
+    otherwise unspecified. Returns [n, T, H, dv].
+
+    Under the rule (``latent_prefill_kernel_engages``) this IS the plain
+    formulation, called directly: the program's lowered text is what it
+    was. Over it ``jax.lax.platform_dependent`` chooses where the program
+    is LOWERED: the kernel for a TPU, the plain formulation for anything
+    else (module docstring)."""
     with jax.named_scope(scopes.LATENT_ATTN):
-        pool = pools[0]
-        n, t, heads, _ = inputs.q.shape
-        page, r = pool.shape[2], inputs.wkv_b.shape[0]
-        dr = inputs.row.shape[-1] - r
-        dn = inputs.q.shape[-1] - dr
-        index = inputs.index
-        keys = table_rows.shape[1] * page
-        if index is not None and keys <= index.topk:
+        index, pages = inputs.index, table_rows.shape[1]
+        if index is not None and pages * pools[0].shape[2] <= index.topk:
             index = None                    # no query can see more than topk
-        block = query_block(
-            n, t, heads + (index.q.shape[2] if index is not None else 0), keys,
-            window)
-        # the pages that hold the keys of ``block`` queries' windows
-        seen = (table_rows.shape[1] if window is None
-                else -(-(block + window - 2) // page) + 1)
-
-        def expand(table):
-            """What ``table``'s pages hold, by key: the rotary keys [n, S, dr],
-            every head's no-position keys [n, H, S, dn] and values [n, H, S,
-            dv] (heads before keys: the layout the two products below
-            contract in) and, where the layer selects, the index keys."""
-            rows = gather_rows(pool, layer, table)
-
-            def heads_of(w):
-                return jnp.einsum("nsr,rhe->nhse", rows[..., :r], w,
-                                  preferred_element_type=jnp.float32
-                                  ).astype(inputs.q.dtype)
-
-            return (rows[..., r:r + dr], heads_of(inputs.wkv_b[..., :dn]),
-                    heads_of(inputs.wkv_b[..., dn:]),
-                    gather_rows(pools[1], layer, table) if index is not None
-                    else None)
-
-        def attend(q, iq, iw, first, seen_keys=None):
-            """``q`` [n, block, H, dn + dr], the first of them at ``first``
-            [n]; ``iq``, ``iw``: their index queries and weights, or None;
-            ``seen_keys``: what ``expand`` gave for the keys from position 0
-            that they can see, or None for a windowed layer, whose block
-            expands the pages of its own windows."""
-            key_start = jnp.zeros_like(first)
-            if seen_keys is None:
-                table, key_start = visible_pages(
-                    table_rows, first - window + 1, seen, page)
-                seen_keys = expand(table)
-            kr, kn, v, index_keys = seen_keys
-            scores = (jnp.einsum("nthd,nhsd->nhts", q[..., :dn], kn,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("nthd,nsd->nhts", q[..., dn:], kr,
-                                   preferred_element_type=jnp.float32)
-                      ) * inputs.scale
-            qpos = first[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)
-            kpos = key_start[:, None] + jnp.arange(kr.shape[1],
-                                                   dtype=jnp.int32)
-            mask = kpos[:, None, :] <= qpos[:, :, None]      # [n, block, S]
-            if window is not None:
-                mask = mask & (kpos[:, None, :] > qpos[:, :, None] - window)
-            if iq is not None and kr.shape[1] > index.topk:
-                chosen = jnp.where(mask, index_scores(iq, iw, index_keys),
-                                   _MASKED)
-                mask = mask & kept(chosen, index.topk)
-            scores = jnp.where(mask[:, None], scores, _MASKED)
-            probs = jax.nn.softmax(scores, axis=-1)
-            return jnp.einsum(
-                "nhts,nhsv->nthv", probs.astype(q.dtype), v,
-                preferred_element_type=jnp.float32).astype(q.dtype)
-
-        iq, iw = ((index.q, index.weights) if index is not None
-                  else (None, None))
-        # a full layer's queries all see the same rows: expanded once, outside
-        # the blocks; a windowed layer's blocks each expand what they can see
-        whole = expand(table_rows) if window is None else None
-        if block == t:
-            return attend(inputs.q, iq, iw, starts, whole)
-        count = t // block
-        firsts = starts[None, :] + block * jnp.arange(
-            count, dtype=jnp.int32)[:, None]                     # [blocks, n]
-
-        def blocks(a):
-            return jnp.moveaxis(a.reshape(n, count, block, *a.shape[2:]), 1, 0)
-
-        xs = (blocks(inputs.q),) + (
-            (blocks(iq), blocks(iw)) if index is not None else ()) + (firsts,)
-
-        def some(lo, hi, seen_keys):
-            """Blocks ``lo`` to ``hi``, one after another."""
-            return jax.lax.map(
-                lambda xs: attend(xs[0], *(xs[1:-1] or (None, None)), xs[-1],
-                                  seen_keys),
-                jax.tree.map(lambda a: a[lo:hi], xs))
-
-        def grouped():
-            # where the table reaches past the last (padded) query, the
-            # queries of block i see no key past ``keys - (count - 1 - i) x
-            # block``: the blocks go in up to ``KEY_GROUPS`` groups, each
-            # over the keys its last block can see (a cold prompt's first
-            # quarter attends over a quarter of the keys, not all of them)
-            groups, out = min(KEY_GROUPS, count), []
-            for g in range(groups):
-                lo, hi = g * count // groups, (g + 1) * count // groups
-                extent = keys - (count - hi) * block
-                out.append(some(lo, hi, jax.tree.map(
-                    lambda a: a[..., :extent, :], whole)))
-            return jnp.concatenate(out)
-
-        if window is not None:
-            out = some(0, count, None)
-        else:
-            # (a suffix whose padding runs past its table, ``starts + t >
-            # keys``, gives no such bound: every block over every key)
-            out = jax.lax.cond(jnp.all(starts + t <= keys), grouped,
-                               lambda: some(0, count, whole))
-        return jnp.moveaxis(out, 0, 1).reshape(n, t, heads, -1)
+        in_kernel, beside, plain = _prefill_formulations(
+            inputs.scale, window, index.topk if index is not None else None,
+            inputs.row.shape[-1] - inputs.wkv_b.shape[0])
+        args = (inputs.q, inputs.wkv_b,
+                (index.q, index.weights) if index is not None else None,
+                pools, layer, table_rows, starts)
+        if not latent_prefill_kernel_engages(
+                inputs.q.shape, pools[0], pages, window,
+                index.q.shape[2] if index is not None else 0):
+            return plain(*args)
+        return lax.platform_dependent(*args, slens, tpu=in_kernel,
+                                      default=beside)

@@ -95,6 +95,7 @@ from ray_tpu.ops.latent_attention import (index_kernel_engages,
                                           latent_decode_attention,
                                           latent_kernel_engages,
                                           latent_prefill_attention,
+                                          latent_prefill_kernel_engages,
                                           write_latent)
 from ray_tpu.ops.moe import expert_kernel_engages
 from ray_tpu.ops.norms import rms_norm
@@ -407,6 +408,17 @@ class EnginePrograms:
         self._state_kernel = on_tpu and any(
             state_kernel_engages(a) for a in self.state)
         self._latent_backend = on_tpu and self.selects is not None
+        # the runs that attend over latent rows, as the prefill kernel's
+        # rule takes them: (heads, the rows' pool's place, window, the
+        # keys its indexer keeps, the indexer's heads)
+        where = _pool_slices(plan)[0]
+        self._latent_runs = [
+            (cfg.n_heads if run.window is None
+             else getattr(cfg, "n_heads_sliding", cfg.n_heads),
+             where[run.rows].start, run.window, run.selects,
+             getattr(cfg, "index_heads", 0))
+            for run in plan
+            if on_tpu and run.attends and run.rows is not None]
         # an index key's width (pools: latent rows, then index keys)
         self._index_width = next(
             (run.rows[1].width for run in plan if run.selects is not None),
@@ -519,10 +531,12 @@ class EnginePrograms:
         """Whether the prefill program of ``group x bucket`` token-rows
         over a window of ``pages`` attends in the prefill kernel (its
         full layers, ``attn_kernel``; its sliding layers, at their own
-        head count where the family states one, ``window_attn_kernel``)
+        head count where the family states one, ``window_attn_kernel``;
+        a run of its layers over latent rows, ``latent_attn_kernel``)
         and computes its routed experts in the grouped one: the rules
         the program itself is traced by (``kernel_engages``,
-        ``expert_kernel_engages``), on the host's own shapes."""
+        ``latent_prefill_kernel_engages``, ``expert_kernel_engages``),
+        on the host's own shapes."""
         cfg = self.cfg
         return {
             "attn_kernel": int(self._kernel_backend and kernel_engages(
@@ -533,6 +547,13 @@ class EnginePrograms:
                     (group, bucket,
                      getattr(cfg, "n_heads_sliding", cfg.n_heads),
                      cfg.head_dim), self.pools[0], pages, self.window)),
+            "latent_attn_kernel": int(any(
+                latent_prefill_kernel_engages(
+                    (group, bucket, heads, 0), self.pools[at], pages, window,
+                    index_heads if selects is not None
+                    and pages * self.page_size > selects else 0)
+                for heads, at, window, selects, index_heads
+                in self._latent_runs)),
             "expert_kernel": int(self._expert_backend
                                  and expert_kernel_engages(group * bucket))}
 
@@ -752,7 +773,7 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
                                               *rotary[run.kind])
             held = write_latent(inputs, held, layer, pidx_all, ip_all)
             attn = latent_prefill_attention(
-                inputs, held, layer, table_rows, starts,
+                inputs, held, layer, table_rows, starts, slens,
                 window=run.window)
         else:
             held = rest[where[run.rows]]

@@ -285,12 +285,13 @@ class PagedLLMEngine:
         # (``_retire_slot``)
         self._deferred_free: list[list] = []
         # prefill dispatches, and those whose program holds the prefill
-        # attention kernel (in its full layers; in its sliding layers)
-        # and computes its routed experts in the grouped kernel
-        # (``EnginePrograms.prefill_kernels``)
+        # attention kernel (in its full layers; in its sliding layers;
+        # over latent rows, in either) and computes its routed experts in
+        # the grouped kernel (``EnginePrograms.prefill_kernels``)
         self.prefill_dispatches = 0
         self.prefill_kernel_dispatches = 0
         self.window_kernel_dispatches = 0
+        self.latent_prefill_kernel_dispatches = 0
         self.expert_kernel_dispatches = 0
         # the token-rows the prefill programs computed (group x bucket a
         # dispatch) and the prompt tokens among them (the suffixes past
@@ -514,6 +515,7 @@ class PagedLLMEngine:
         self.prefill_dispatches += 1
         self.prefill_kernel_dispatches += kernels["attn_kernel"]
         self.window_kernel_dispatches += kernels["window_attn_kernel"]
+        self.latent_prefill_kernel_dispatches += kernels["latent_attn_kernel"]
         self.expert_kernel_dispatches += kernels["expert_kernel"]
         self.prefill_token_rows += token_rows
         self.prefill_new_tokens += new_tokens
@@ -1268,7 +1270,7 @@ class PagedLLMEngine:
     _COUNTS = (
         "total_generated", "total_finished", "prefill_dispatches",
         "prefill_kernel_dispatches", "window_kernel_dispatches",
-        "expert_kernel_dispatches",
+        "latent_prefill_kernel_dispatches", "expert_kernel_dispatches",
         "decode_dispatches", "state_kernel_dispatches",
         "latent_kernel_dispatches", "index_kernel_dispatches",
         "decode_slot_steps", "decode_delivered",

@@ -272,7 +272,7 @@ def test_prefill_then_decode_then_a_reused_prefix_over_scattered_pages(
     pools = write(pools, 0, 24)
     got = la.latent_prefill_attention(
         part(0, 24), pools, layer, table, jnp.zeros((b,), jnp.int32),
-        window=window)
+        jnp.full((b,), 24, jnp.int32), window=window)
     np.testing.assert_allclose(got, want[:, :24], atol=1e-5)
     for t in range(24, 28):
         pos = jnp.full((b,), t, jnp.int32)
@@ -286,7 +286,7 @@ def test_prefill_then_decode_then_a_reused_prefix_over_scattered_pages(
     pools = write(pools, 28, 40)
     got = la.latent_prefill_attention(
         part(28, 40), pools, layer, table, jnp.full((b,), 28, jnp.int32),
-        window=window)
+        jnp.full((b,), 12, jnp.int32), window=window)
     np.testing.assert_allclose(got, want[:, 28:], atol=1e-5)
     # a suffix whose PADDING runs past its table (24 padded queries from
     # position 28 over 40 keys): no block may lose a key it can see
@@ -297,7 +297,7 @@ def test_prefill_then_decode_then_a_reused_prefix_over_scattered_pages(
         padded)
     got = la.latent_prefill_attention(
         padded, pools, layer, table, jnp.full((b,), 28, jnp.int32),
-        window=window)
+        jnp.full((b,), 12, jnp.int32), window=window)
     np.testing.assert_allclose(got[:, :12], want[:, 28:], atol=1e-5)
     # nothing was written to the other layer of the pools
     assert all(float(jnp.abs(pool[0]).max()) == 0.0 for pool in pools)
@@ -323,7 +323,8 @@ def test_ties_in_the_indexers_scores_go_to_the_lower_position():
                             jnp.arange(32)[None])
     np.testing.assert_allclose(
         la.latent_prefill_attention(inputs, pools, 0, table,
-                                    jnp.zeros((1,), jnp.int32)),
+                                    jnp.zeros((1,), jnp.int32),
+                                    jnp.full((1,), 32, jnp.int32)),
         _plain(inputs), atol=1e-5)
 
 

@@ -177,9 +177,11 @@ def test_every_dispatch_has_counts_and_one_device_run(profiled):
     for s in prefills:
         assert {"seq", "group", "bucket", "token_rows", "new_tokens",
                 "cached_tokens", "missed_pages", "attn_kernel",
-                "window_attn_kernel", "expert_kernel"} <= set(s["attrs"])
+                "window_attn_kernel", "latent_attn_kernel",
+                "expert_kernel"} <= set(s["attrs"])
         assert s["attrs"]["attn_kernel"] == 0       # lowered for the CPU
         assert s["attrs"]["window_attn_kernel"] == 0
+        assert s["attrs"]["latent_attn_kernel"] == 0
         assert s["attrs"]["expert_kernel"] == 0
     for s in decodes:
         a = s["attrs"]
@@ -832,6 +834,88 @@ def test_decode_dispatches_say_whether_their_program_holds_the_latent_kernel(
     assert [s["attrs"]["latent_kernel"] for s in decodes] == \
         [engaged] * len(decodes)
     assert stats["latent_kernel_dispatches"] == engaged * len(decodes)
+
+
+_LATENT_PREFILL_CASES = [
+    # the backend the engine finds, the plan, latent_attn_kernel of the
+    # prefills of 70, 7 and 90 tokens (buckets of 128, 16 and 128)
+    ("cpu", "latent", [0, 0, 0]), ("tpu", "latent", [1, 0, 1]),
+    ("tpu", "twins", [0, 0, 0])]
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["traced", "untraced"])
+@pytest.mark.parametrize(
+    "backend,plan,engaged", _LATENT_PREFILL_CASES,
+    ids=[f"{b}-{p}" for b, p, _ in _LATENT_PREFILL_CASES])
+def test_prefill_dispatches_say_whether_their_latent_layers_hold_the_kernel(
+        tiny, monkeypatch, backend, plan, engaged, traced):
+    """``latent_attn_kernel`` on ``engine.dispatch_prefill`` is the rule a
+    run of latent layers was traced by (``ops/latent_attention.py``:
+    ``latent_prefill_kernel_engages``) applied to the host's own shapes,
+    once a run, on a TPU backend alone; ``stats()`` counts the dispatches
+    with it as ``latent_prefill_kernel_dispatches``, the spans' sum, and
+    gives the same integers with tracing off. 0 on the CPU whatever the
+    shapes, and 0 for a plan of K/V twins, whose runs the rule is never
+    asked about. The stand-in rule engages a full layer from 64 tokens
+    and a sliding one never: a program counts once if any run holds it."""
+    from ray_tpu.serve import engine_programs
+
+    seen = []
+
+    def rule(q_shape, pool, table_pages, window, index_heads):
+        seen.append((q_shape[:3], pool.shape[-1], table_pages, window,
+                     index_heads))
+        return window is None and q_shape[1] >= 64
+
+    if plan == "latent":
+        eng = _note_engine_on(monkeypatch, backend, index_topk=64)
+    else:
+        monkeypatch.setattr(engine_programs.jax, "default_backend",
+                            lambda: backend)
+        eng = make_engine(tiny)
+        monkeypatch.undo()
+    monkeypatch.setattr(engine_programs, "latent_prefill_kernel_engages",
+                        rule)
+    assert eng.stats()["latent_prefill_kernel_dispatches"] == 0
+    clear_ring()
+    if traced:
+        tracing.enable_tracing()
+    try:
+        eng.start()
+        rng = np.random.default_rng(3)
+        for n in (70, 7, 90):
+            assert len(list(eng.submit(rng.integers(1, 100, n),
+                                       max_new_tokens=3).tokens())) == 3
+        eng.stop()
+        spans = tracing.recorded_spans("engine.dispatch_prefill")
+    finally:
+        tracing.disable_tracing()
+        clear_ring()
+    assert eng.error is None
+    stats = eng.stats()
+    assert stats["prefill_dispatches"] == 3
+    assert stats["latent_prefill_kernel_dispatches"] == sum(engaged)
+    if traced:
+        assert [s["attrs"]["latent_attn_kernel"] for s in spans] == engaged
+        assert [s["attrs"]["attn_kernel"] for s in spans] == [0] * 3
+    else:
+        assert not spans
+    if backend == "tpu" and plan == "latent":
+        # the rule saw the dispatch's own shapes: the full layers' heads
+        # and the lanes of their rows' pool beside their indexer's heads
+        # (a table of one page, 128 keys, is more than the 64 kept); a
+        # program counts once, so no run is asked after one that holds
+        # it; the 16-token dispatch asked every run, the sliding layers'
+        # with their window and no indexer
+        cfg = eng._programs.cfg
+        assert seen[0] == ((1, 128, cfg.n_heads), 128, 1, None,
+                           cfg.index_heads)
+        assert [(call[0][1], call[3], call[4]) for call in seen[1:4]] == [
+            (16, None, cfg.index_heads), (16, None, cfg.index_heads),
+            (16, cfg.window, 0)]
+        assert seen[3][0][2] == cfg.n_heads_sliding
+    else:
+        assert not seen
 
 
 _INDEX_KERNEL_CASES = [

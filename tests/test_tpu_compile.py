@@ -1371,6 +1371,100 @@ def test_note_d5_cold_prefill_runs_its_experts_in_the_grouped_kernel(
             - mem.alias_size_in_bytes) < 15.75e9
 
 
+# the latent prefill kernel's instruction, under its name: a layer's
+# heads' outputs [rows, heads, queries, value width] its result
+_LATENT_PREFILL_KERNEL = re.compile(
+    r"%latent_prefill_attn[.\d]* = bf16\[1,(\d+),(\d+),128\]\S* "
+    r"custom-call\(.*tpu_custom_call")
+# the kernel's launches: heads, key width, queries, keys, window, flags
+_LATENT_PREFILL_SHAPES = {
+    "full-cold": (128, 192, 4096, 4096, None, True),
+    "full-suffix-128": (128, 192, 128, 4096, None, True),
+    "full-under-topk": (128, 192, 2048, 2048, None, False),
+    "sliding-cold": (64, 256, 4096, 4096, 513, False)}
+
+
+@pytest.mark.parametrize("case", _LATENT_PREFILL_SHAPES)
+def test_latent_prefill_kernel_compiles_alone(v5e_2x2, case):
+    """The kernel by itself at ``serve-note-gen``'s shapes: a full
+    layer's cold prompt and a suffix behind a cached transcript (keys 192
+    wide, one byte of flags a pair), a full layer over a table of no more
+    than ``topk`` keys (no flags), a sliding layer's cold prompt (keys
+    256 wide, the walk under its window): the chip's compiler takes the
+    blocks, the int8 flags and the accumulators, in a few MiB of
+    temporaries beside the operands."""
+    from ray_tpu.ops.latent_attention import latent_prefill_attention_kernel
+
+    heads, dk, t, keys, window, flags = _LATENT_PREFILL_SHAPES[case]
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(partial(latent_prefill_attention_kernel, scale=0.07,
+                               window=window)).lower(
+        shape((1, heads, t, dk)), shape((1, heads, keys, dk)),
+        shape((1, heads, keys, 128)), shape((1,), jnp.int32),
+        shape((1,), jnp.int32),
+        *([shape((1, t, keys), jnp.int8)] if flags else [])).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(_LATENT_PREFILL_KERNEL.findall(text)) == 1
+    # nothing of size heads x queries x keys beside it
+    assert not re.search(rf"f32\[1,{heads},{t},{keys}\]", text)
+
+
+def test_note_d5_cold_prefill_keeps_its_scores_on_the_core(v5e_2x2):
+    """The cell's cold prompt (one of 4,096 tokens, the 32-page window):
+    each of the plan's three runs of layers (the leading dense full
+    layer, the full layer behind it, the three sliding ones) holds the
+    latent prefill kernel under its name, the full layers' at 128 heads
+    and the sliding layers' at 64, and the program holds no float32 array
+    of heads x block x keys: the plain formulation wrote a full layer's
+    ``f32[1,128,256,4096]`` sixteen times a layer and a sliding layer's
+    ``f32[1,64,1024,1664]`` four times, and no decode kernel."""
+    _, lowered = _lower_note_program(v5e_2x2[0], "prefill", (1, 4096, 32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = _LATENT_PREFILL_KERNEL.findall(text)
+    assert sorted(kernels) == [("128", "4096"), ("128", "4096"),
+                               ("64", "4096")]
+    assert not _LATENT_KERNEL.search(text) and not _INDEX_KERNEL.search(text)
+    # what is left of score shape is the indexer's, which stays in HBM in
+    # float32: a block of 1,024 queries at 64 index heads over the keys
+    # its group of blocks can see, where those are more than ``topk``
+    scores = {dims for keys in (4096, 3072, 2048, 1024, 1664, 1537)
+              for dims in _score_arrays(text, keys)}
+    assert scores == {"1,64,1024,3072", "1,64,1024,4096"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+
+
+# sha256 (first 16 hex digits) of the text the cell's prefill programs
+# UNDER the latent prefill kernel's rule lowered to on the commit before
+# that kernel (5838c9b), under ``_PINNED_JAX``
+_PARENT_NOTE_PREFILL = {(1, 64, 32): "ebaac4e40dc1d182",
+                        (1, 16, 32): "acd16a8baa5cca46"}
+
+
+@pytest.mark.parametrize("dims", _PARENT_NOTE_PREFILL,
+                         ids=lambda d: "x".join(map(str, d)))
+def test_note_d5_prefill_under_the_rule_is_the_parents_text(v5e_2x2, dims):
+    """A question of up to 64 tokens behind a cached transcript (a full
+    layer's float32 scores and its indexer's would be 201 MB, under the
+    256 MiB line): the program holds neither the kernel nor a choice by
+    platform nor any new operation: its lowered text is the parent's,
+    byte for byte."""
+    import hashlib
+
+    if jax.__version__ != _PINNED_JAX:
+        pytest.skip(f"digests pinned under jax {_PINNED_JAX}")
+    _, lowered = _lower_note_program(v5e_2x2[0], "prefill", dims)
+    text = _located_nowhere(lowered.as_text())
+    assert "latent_prefill_attn" not in text
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == _PARENT_NOTE_PREFILL[dims])
+
+
 _EXPERT_WIDTHS = [
     # held experts, model width, expert width, gated, (token, choice) pairs
     (64, 2688, 1856, False, 6144),       # serve-reason-gen: 2 x 512 x 6
