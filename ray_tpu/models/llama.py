@@ -226,8 +226,8 @@ class LayerStack(NamedTuple):
     # None: the K/V twins
     rows: tuple | None = None
     # keys a query attends over at most, where the layers pick them (a
-    # learned selection: the rows then hold an index key); None: all it
-    # may see
+    # learned selection: the rows, or the rows ``beside`` the K/V twins,
+    # then hold an index key); None: all it may see
     selects: int | None = None
     # whether the layers attend: project, write a page, read the pages.
     # False: they keep NO pages (``kind``, ``window``, ``rows`` and
@@ -242,6 +242,13 @@ class LayerStack(NamedTuple):
     # handed to ``feed_forward`` as ``ahead=`` where the layer ends.
     # False: the feed-forward sees the stream behind the attention alone
     ahead: bool = False
+    # further rows a token keeps in a page of a layer of the run BESIDE
+    # its K/V twins (``rows`` is then None), one pool each behind the
+    # twins' four (``PageRow``s, as ``rows``): an indexer's key, where
+    # the layers pick the keys they attend over (``selects``) among K/V
+    # rows; the module's ``index_projections`` then gives what the
+    # indexer takes of a layer's tokens. None: the twins alone
+    beside: tuple | None = None
 
 
 def layer_plan(cfg) -> tuple:

@@ -80,14 +80,16 @@ def reference_attention(
 
 
 def cached_attention(q, k_cache, v_cache, start, *, scale, window=None,
-                     key_start=None):
+                     key_start=None, seen=None):
     """Plain attention of new queries over a cache that already holds
     their keys. q: [B, T, nh, hd]; caches [B, S, nkv, hd]; start [B] =
     offset of the first query token. Causal over the whole cache: query i
     attends to key positions <= start + i, and with ``window`` to those
     > start + i - window alone (a sliding layer). ``key_start`` [B] is the
     position of the cache's first row where that is not 0 (a caller that
-    hands a windowed layer only the rows its queries can see). The
+    hands a windowed layer only the rows its queries can see). ``seen``
+    [B, T, S] bool: of those keys, the ones a query's softmax runs over
+    (a layer that picks its keys: ``ops/index_select.py``). The
     library's plain decode (``models/decoding.py``), the serving engine's
     prefill and the non-TPU lowering of its decode attention all call
     this."""
@@ -112,6 +114,8 @@ def cached_attention(q, k_cache, v_cache, start, *, scale, window=None,
             mask = kpos[:, None, :] <= qpos[:, :, None]
         if window is not None:
             mask = mask & (kpos[..., None, :] > qpos[:, :, None] - window)
+        if seen is not None:
+            mask = mask & seen
         logits = jnp.where(mask[:, None, None, :, :], logits,
                            jnp.finfo(jnp.float32).min)
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)

@@ -21,14 +21,10 @@ give the same numbers:
   ``o = (sum p ckv) @ wkv_b[V]``. Right for a decode step's one query a
   slot: nothing of size keys x heads x width exists.
 
-A layer with an INDEXER (``IndexInputs``; DeepSeek-V3.2's sparse
-attention) keeps a second, narrow row a token, the index key. A query
-scores every key it may see, ``I(t, s) = sum_j w_j relu(qI_j(t) .
-kI(s))`` over the indexer's heads, in float32 (the rows are bf16, so
-their products are exact and the sums float32: a choice must not turn on
-a rounding), and its softmax runs over the ``topk`` keys of largest
-``I`` alone, ties to the lower position; a query that sees no more than
-``topk`` keys attends over them all (``kept``: one rule, both forms).
+A layer with an INDEXER (``ops/index_select.py``: ``IndexInputs``;
+DeepSeek-V3.2's sparse attention) keeps a second, narrow row a token,
+the index key; a query's softmax runs over the ``topk`` keys of largest
+index score alone (``kept``: one rule, both forms).
 
 A WINDOWED layer's queries see the ``window`` newest keys, their own
 among them.
@@ -105,23 +101,12 @@ The rule between them for a layer with an indexer is a ratio the program
 sees as static shapes, the table's keys over ``topk``: see
 ``GATHER_PAST``.
 
-The decode form's INDEX SCORES, which make the selection, have the same
-two formulations, chosen the same way (``index_kernel_engages``: wherever
-the layer selects, pages and index keys in whole lanes):
-
-- IN PLACE, a second Pallas kernel (``index_decode_scores``): the index
-  keys' pool stays in HBM and each live slot's pages are walked as the
-  latent kernel walks the rows' (one ``_walk`` serves both), a group of
-  keys ``[g x page, dI]`` against the slot's index queries ``[HI, dI]``
-  on the MXU in float32, ``relu``, the heads' weighted sum:
-  ``index_scores``' arithmetic (exact products, float32 sums), written
-  as ``[slots, keys]`` float32 with the mask's value from the slot's
-  count on, in the pages it never fetched too;
-- GATHERED (``_scored_gathered``: what the kernel is held to): every
-  page of the table copied out of the pool, for every slot, and
-  ``index_scores`` over the copy (at the serving cell's shapes 134 MB
-  written and read again a layer where the slots hold 65 MB of keys:
-  0.644 ms against the kernel's 0.165 on a v5e, PR 53).
+The INDEXER's own arithmetic (``index_scores``, ``kept``), a decode
+step's index scores in place or gathered (``decode_index_scores``: the
+index kernel, whose walk over a slot's pages the latent kernel shares)
+and a prefill's selection as flags (``selection_flags``) are
+``ops/index_select.py``'s, which the layers that select over K/V twins
+import too.
 
 The serving engine (``serve/paged_llm.py``) calls the
 three functions at the bottom from its two programs; what they take of a
@@ -140,27 +125,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import scopes
+# the indexer's pieces, which the K/V layers that select share
+from ray_tpu.ops.index_select import (  # noqa: F401 - IndexInputs: what a
+    BUFFERS, MASKED, IndexInputs,       # model's ``latent_projections``
+    causal, decode_index_scores,        # builds its ``LatentInputs`` with
+    index_scores, kept, of_slot, over_blocks, query_block, selection_flags,
+    walk, walked)
 from ray_tpu.ops.paged_attention import (ROW_LANES, gather_rows,
                                          visible_pages, write_rows)
 
-# the most a prefill's float32 scores of one block of queries may take,
-# the layer's [n, H, block, keys] and its indexer's [n, HI, block, keys]
-# together (the plain KV prefill's limit: ops/paged_prefill_attention.py)
-SCORES_MAX_BYTES = 1 << 30
-# a full layer's blocks of queries go in this many groups at most, each
-# over the keys its last block can see and no further
-KEY_GROUPS = 4
-_MASKED = float(jnp.finfo(jnp.float32).min)
 # inside the kernel a masked score must stay finite under ``s - m``
 _KERNEL_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-
-class IndexInputs(NamedTuple):
-    """What a layer's indexer takes of its tokens."""
-    q: object         # [b, s, HI, dI]: the tokens' index queries
-    weights: object   # [b, s, HI] float32: the indexer heads' weights
-    key: object       # [b, s, dI]: what each token keeps, the index key
-    topk: int         # keys a query attends over, at most
 
 
 class LatentInputs(NamedTuple):
@@ -186,41 +161,13 @@ def write_latent(inputs: LatentInputs, pools: tuple, layer, pidx, ip):
     return out
 
 
-def index_scores(q, weights, keys):
-    """``I = sum_j w_j relu(q_j . k)``: q [B, T, HI, dI], weights [B, T,
-    HI], keys [B, S, dI or its whole lanes] -> [B, T, S] float32."""
-    with jax.named_scope(scopes.INDEX_SELECT):
-        dots = jnp.einsum("bthd,bsd->bhts", q, keys[..., :q.shape[-1]],
-                          preferred_element_type=jnp.float32)
-        w = jnp.moveaxis(weights.astype(jnp.float32), -1, 1)[..., None]
-        return jnp.sum(w * jax.nn.relu(dots), axis=1)
-
-
-def kept(chosen, topk: int):
-    """Which keys a query's softmax runs over, from its index scores
-    ``chosen`` [..., S] float32 (``_MASKED`` where it may not see the
-    key): every key above the ``topk``-th largest score and, of those AT
-    it, the lowest positions that fill the count: the set ``lax.top_k``
-    returns, as a mask. (``top_k`` takes ties by the lower position, so
-    of the keys at the ``topk``-th score it took all up to the highest
-    position it returns among them.) The caller ANDs it with what the
-    query may see: where that is no more than ``topk`` keys the
-    ``topk``-th score is the mask's own and none is dropped."""
-    with jax.named_scope(scopes.INDEX_SELECT):
-        values, positions = lax.top_k(chosen, topk)
-        kth = values[..., -1:]
-        last = jnp.max(jnp.where(values == kth, positions, -1), axis=-1,
-                       keepdims=True)
-        at = jnp.arange(chosen.shape[-1], dtype=positions.dtype)
-        return (chosen > kth) | ((chosen == kth) & (at <= last))
-
 
 # ---------------------------------------------------------------------------
 # Decode: the absorbed form, in place or gathered
 # ---------------------------------------------------------------------------
 
 KERNEL_NAME = "latent_decode_attn"
-_BUFFERS = 2
+BUFFERS = 2
 # pages a step of the walk: one max, one rescale of the accumulator and one
 # weighted sum for all of them (a full layer at 64 slots of 3.0-5.1k keys
 # on a v5e, the kernel alone, PR 41: 1.36 ms a page at a time, 0.89 by
@@ -239,16 +186,6 @@ _GROUP = 8
 # The serving cell's programs (64-page tables of 8,192 keys over a
 # ``topk`` of 2,048) stand at 4 x.
 GATHER_PAST = 8
-INDEX_KERNEL_NAME = "index_decode_scores"
-# pages a step of the index kernel's walk: 32 KB each at the serving
-# cell's 128 keys of 128 bf16 numbers, a fifth of a latent page (a full
-# layer at 64 slots of 3.0-5.1k keys, 64 index heads, on a v5e, the
-# kernel alone, ``scripts/sweep_index_kernel.py``, PR 53: 0.251 ms by
-# fours, 0.187 by eights, 0.165 by sixteens, 0.160 by thirty-twos, 0.166
-# the whole 64-page table at once; the keys' bytes over the bandwidth are
-# 0.081 and the gathered formulation takes 0.644)
-_INDEX_GROUP = 16
-
 
 def latent_kernel_engages(page: int, table_pages: int, topk) -> bool:
     """The rule, from static shapes alone: whether a decode step's
@@ -262,108 +199,8 @@ def latent_kernel_engages(page: int, table_pages: int, topk) -> bool:
             and topk < page * table_pages <= GATHER_PAST * topk)
 
 
-def index_kernel_engages(page: int, table_pages: int, topk,
-                         width: int) -> bool:
-    """The rule, from static shapes alone: whether a decode step's index
-    scores of a layer that keeps ``topk`` keys (None: it has no indexer),
-    over a table of ``table_pages`` pages of ``page`` index keys of
-    ``width`` numbers, are the index kernel's on a program lowered for a
-    TPU: wherever the layer selects (the table holds more than ``topk``
-    keys), pages and keys in whole lanes (a key is then its pool's whole
-    row)."""
-    return (topk is not None and page % ROW_LANES == 0
-            and width % ROW_LANES == 0 and topk < page * table_pages)
 
 
-def _walk(layer_ref, table_ref, count_ref, next_ref,          # SMEM
-          pool_hbm, buf, sem, step_ref, *, pages_per_slot, group, body,
-          carry=()):
-    """This grid step's slot's live pages of ``pool_hbm``'s layer, through
-    the page table, ``group`` at a step of the walk: ONE async copy a
-    page into ``buf`` [2, group x page, lanes] under ``sem`` [2, group],
-    double buffered, the next group's copies (of this slot or, behind
-    its last, of the next live one's first) in flight while ``body(g,
-    rows, *carry)`` computes on group ``g``'s rows [group x page, lanes]
-    (of its last group, the pages past the slot's last are not fetched:
-    what the buffer holds there is the caller's to ignore) and returns
-    the next ``carry``. ``step_ref``: the groups walked so far (which
-    buffer is next), kept across the grid's steps as the buffers are.
-    Returns the last carry."""
-    slot, slots = pl.program_id(0), pl.num_programs(0)
-    page = pool_hbm.shape[2]
-    layer = layer_ref[0]
-
-    def pages_of(slot):
-        # never past the table's row (the gathered formulation ends there
-        # too; the engine's reservations keep counts inside; the chain
-        # ends at ``slots``, which is no slot: read the last)
-        count = count_ref[jnp.minimum(slot, slots - 1)]
-        return jnp.minimum((count + page - 1) // page, pages_per_slot)
-
-    def copies(slot, g, b, do):
-        """``do`` to the copy of each page of the slot's group ``g`` that
-        the slot holds, into (or in) buffer ``b``."""
-        first = g * group
-
-        def one(j, _):
-            p = table_ref[slot * pages_per_slot + first + j]
-            do(pltpu.make_async_copy(
-                pool_hbm.at[layer, p],
-                buf.at[b, pl.ds(pl.multiple_of(j * page, page), page)],
-                sem.at[b, j]))
-
-        lax.fori_loop(0, jnp.minimum(group, pages_of(slot) - first), one,
-                      None)
-
-    @pl.when(slot == 0)
-    def _():
-        step_ref[0] = 0
-        first = next_ref[0]
-
-        @pl.when(first < slots)
-        def _():
-            copies(first, 0, 0, lambda c: c.start())
-
-    n_groups = (pages_of(slot) + group - 1) // group
-
-    def group_body(g, carry):
-        *carry, step = carry
-        b = step % _BUFFERS
-        more = g + 1 < n_groups
-        nslot = jnp.where(more, slot, next_ref[slot + 1])
-
-        @pl.when(nslot < slots)
-        def _():
-            copies(nslot, jnp.where(more, g + 1, 0), (step + 1) % _BUFFERS,
-                   lambda c: c.start())
-
-        copies(slot, g, b, lambda c: c.wait())
-        return (*body(g, buf[b], *carry), step + 1)
-
-    *carry, step = lax.fori_loop(0, n_groups, group_body,
-                                 (*carry, step_ref[0]))
-    step_ref[0] = step
-    return carry
-
-
-def _of_slot(*block):
-    """A grid step's block of an array whose first axis is the slots."""
-    return pl.BlockSpec((1, *block), lambda s, *_: (s, 0, 0))
-
-
-def _walked(table, count):
-    """What ``_walk`` takes through scalar prefetch beside the layer: the
-    page table [B, PB] in one row, holes as page 0 (what ``gather_rows``
-    reads there); the slots' key counts [B]; and the next-live-slot chain
-    [B + 1]: its first entry the first slot with keys, entry s + 1 the
-    first after s (``B`` when there is none)."""
-    slots = count.shape[0]
-    live_at = jnp.where(count > 0, jnp.arange(slots, dtype=jnp.int32),
-                        slots)
-    next_live = jnp.concatenate([lax.cummin(live_at, reverse=True),
-                                 jnp.full((1,), slots, jnp.int32)])
-    return (jnp.maximum(table, 0).astype(jnp.int32).reshape(-1),
-            count.astype(jnp.int32), next_live)
 
 
 def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
@@ -372,7 +209,7 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
     """One grid step a slot (module docstring): its queries ``q_ref`` [1,
     H, lanes], its flags [1, PB / group, group x page], its output [1, H,
     width]; the page buffers, their semaphores and ``step_ref``:
-    ``_walk``'s."""
+    ``walk``'s."""
     heads = q_ref.shape[1]
     q = q_ref[0]                                            # [H, lanes]
 
@@ -399,7 +236,7 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,       # SMEM
             preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    m, l, acc = _walk(
+    m, l, acc = walk(
         layer_ref, table_ref, count_ref, next_ref, pool_hbm, buf, sem,
         step_ref, pages_per_slot=pages_per_slot, group=group,
         body=group_body,
@@ -431,13 +268,13 @@ def latent_decode_attention_kernel(q_row, pool, layer, table, count, flags,
                           width=width, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(slots,),
-            in_specs=[_of_slot(heads, lanes),
+            in_specs=[of_slot(heads, lanes),
                       pl.BlockSpec(memory_space=pl.ANY),
-                      _of_slot(pb // group, group * page)],
-            out_specs=_of_slot(heads, width),
+                      of_slot(pb // group, group * page)],
+            out_specs=of_slot(heads, width),
             scratch_shapes=[
-                pltpu.VMEM((_BUFFERS, group * page, lanes), pool.dtype),
-                pltpu.SemaphoreType.DMA((_BUFFERS, group)),
+                pltpu.VMEM((BUFFERS, group * page, lanes), pool.dtype),
+                pltpu.SemaphoreType.DMA((BUFFERS, group)),
                 pltpu.SMEM((1,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((slots, heads, width), q_row.dtype),
         # the slots in order on one core: a slot's last group fetches the
@@ -446,74 +283,10 @@ def latent_decode_attention_kernel(q_row, pool, layer, table, count, flags,
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name=KERNEL_NAME,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      *_walked(table, count), q_row, pool,
+      *walked(table, count), q_row, pool,
       flags.astype(jnp.int32).reshape(slots, pb // group, group * page))
     return out[..., :rank]
 
-
-def _index_kernel(layer_ref, table_ref, count_ref, next_ref,      # SMEM
-                  q_ref, w_ref, pool_hbm, o_ref, buf, sem, step_ref, *,
-                  pages_per_slot, group):
-    """One grid step a slot: its index queries ``q_ref`` [1, HI, dI],
-    their weights ``w_ref`` [1, HI, 1] float32, its scores ``o_ref`` [1,
-    PB / group, group x page] float32, a row a group of the walk; the
-    page buffers, their semaphores and ``step_ref``: ``_walk``'s."""
-    q, w = q_ref[0], w_ref[0]
-    count = count_ref[pl.program_id(0)]
-    keys = o_ref.shape[2]
-    # the groups the walk never reaches (and a dead slot's all)
-    o_ref[...] = jnp.full_like(o_ref, _MASKED)
-
-    def group_body(g, rows):
-        # ``index_scores``' arithmetic: exact products, float32 sums
-        dots = lax.dot_general(q, rows[:, :q.shape[1]],
-                               (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-        scores = jnp.sum(w * jnp.maximum(dots, 0.0), axis=0, keepdims=True)
-        at = g * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
-        # (past the count: pages not fetched, whatever the buffer held)
-        o_ref[0, pl.ds(g, 1), :] = jnp.where(at < count, scores, _MASKED)
-        return ()
-
-    _walk(layer_ref, table_ref, count_ref, next_ref, pool_hbm, buf, sem,
-          step_ref, pages_per_slot=pages_per_slot, group=group,
-          body=group_body)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def index_decode_scores_kernel(q, weights, pool, layer, table, count, *,
-                               interpret=False):
-    """The index kernel's launch: ``q`` [B, HI, dI], a step's index
-    queries; ``weights`` [B, HI] float32; ``pool`` [L, P, page, dI or
-    its whole lanes]: the run's index keys; ``table`` [B, PB] page ids
-    (-1 = hole); ``count`` [B]: the keys a slot's query sees, 0 for a
-    dead slot. Returns ``I`` [B, PB x page] float32, ``_MASKED`` from the
-    slot's count on."""
-    slots, heads, width = q.shape
-    page, pb = pool.shape[2], table.shape[1]
-    group = math.gcd(pb, _INDEX_GROUP)
-
-    out = pl.pallas_call(
-        functools.partial(_index_kernel, pages_per_slot=pb, group=group),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(slots,),
-            in_specs=[_of_slot(heads, width), _of_slot(heads, 1),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=_of_slot(pb // group, group * page),
-            scratch_shapes=[
-                pltpu.VMEM((_BUFFERS, group * page, pool.shape[3]),
-                           pool.dtype),
-                pltpu.SemaphoreType.DMA((_BUFFERS, group)),
-                pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((slots, pb // group, group * page),
-                                       jnp.float32),
-        # the slots in order on one core, as the latent kernel's
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret, name=INDEX_KERNEL_NAME,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *_walked(table, count),
-      q, weights.astype(jnp.float32)[..., None], pool)
-    return out.reshape(slots, pb * page)
 
 
 def _query_rows(inputs: LatentInputs, lanes: int):
@@ -533,24 +306,6 @@ def _query_rows(inputs: LatentInputs, lanes: int):
         -1)
 
 
-def _scored_in_place(q, weights, pool, layer, table, count):
-    """The index kernel's formulation; arguments as ``_scored_gathered``'s."""
-    with jax.named_scope(scopes.INDEX_SELECT):
-        return index_decode_scores_kernel(q[:, 0], weights[:, 0], pool, layer,
-                                          table, count)
-
-
-def _scored_gathered(q, weights, pool, layer, table, count):
-    """The plain formulation of a step's index scores (what the index
-    kernel is held to): ``q`` [B, 1, HI, dI] and ``weights`` [B, 1, HI]
-    against every key of the table's pages, copied out of ``pool``; [B,
-    PB x page] float32, ``_MASKED`` from the slot's ``count`` on."""
-    with jax.named_scope(scopes.INDEX_SELECT):
-        keys = gather_rows(pool, layer, table)                    # [B, S, dI]
-        scores = index_scores(q, weights, keys)[:, 0]
-        seen = jnp.arange(keys.shape[1], dtype=jnp.int32) < count[:, None]
-        return jnp.where(seen, scores, _MASKED)
-
 
 def _in_place(q_row, pool, layer, table, count, chosen, *, rank, scale,
               topk):
@@ -558,7 +313,7 @@ def _in_place(q_row, pool, layer, table, count, chosen, *, rank, scale,
     ``_gathered``'s."""
     return latent_decode_attention_kernel(
         q_row, pool, layer, table, count,
-        (chosen > _MASKED) & kept(chosen, topk), rank=rank, scale=scale)
+        (chosen > MASKED) & kept(chosen, topk), rank=rank, scale=scale)
 
 
 def _gathered(q_row, pool, layer, table, count, chosen=None, *, rank, scale,
@@ -566,7 +321,7 @@ def _gathered(q_row, pool, layer, table, count, chosen=None, *, rank, scale,
     """The plain formulation: ``q_row`` [B, H, lanes] over the rows that
     slot b's query attends over, copied out of ``pool`` through ``table``
     [B, PB]: of the ``count`` [B] keys it sees, the ``topk`` of largest
-    ``chosen`` ([B, PB x page] float32 index scores, ``_MASKED`` past the
+    ``chosen`` ([B, PB x page] float32 index scores, ``MASKED`` past the
     count) by position, else its ``window``'s pages, else all. Returns
     the probability-weighted rows' first ``rank`` numbers [B, H, rank] in
     ``q_row``'s type."""
@@ -577,7 +332,7 @@ def _gathered(q_row, pool, layer, table, count, chosen=None, *, rank, scale,
             # a key past the slot's count comes out with the mask's own value
             # (a gather of the seen keys at the positions says the same, a
             # scalar at a time: 1.3 ms a layer on a v5e at 64 x 2,048)
-            mask = values > _MASKED
+            mask = values > MASKED
             # each position's page id: the table's entry at its page, picked
             # by comparison (a gather of 2,048 scalars a slot out of the
             # table takes 1.0 ms a layer on a v5e; this a few microseconds)
@@ -599,7 +354,7 @@ def _gathered(q_row, pool, layer, table, count, chosen=None, *, rank, scale,
             mask = mask & (kpos >= count[:, None] - window)
     scores = jnp.einsum("bhw,bsw->bhs", q_row, rows,
                         preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(mask[:, None, :], scores, _MASKED)
+    scores = jnp.where(mask[:, None, :], scores, MASKED)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhs,bsr->bhr", probs.astype(rows.dtype),
                       rows[..., :rank], preferred_element_type=jnp.float32
@@ -646,13 +401,8 @@ def latent_decode_attention(inputs: LatentInputs, pools: tuple, layer,
         if index is not None and table.shape[1] * page > index.topk:
             # (a table of no more than ``topk`` keys: nothing is dropped)
             topk = index.topk
-            scored = (index.q, index.weights, pools[1], layer, table, count)
-            if index_kernel_engages(page, table.shape[1], topk,
-                                    index.q.shape[-1]):
-                args += (lax.platform_dependent(
-                    *scored, tpu=_scored_in_place, default=_scored_gathered),)
-            else:
-                args += (_scored_gathered(*scored),)
+            args += (decode_index_scores(index, pools[1], layer, table,
+                                         count),)
         in_place, gathered = _formulations(r, inputs.scale, topk, window)
         if latent_kernel_engages(page, table.shape[1], topk):
             o_latent = lax.platform_dependent(*args, tpu=in_place,
@@ -700,23 +450,6 @@ _PREFILL_HEADS = 4
 # probabilities (about 14 MiB at 256 | 128 wide)
 _PREFILL_VMEM_BYTES = 48 << 20
 
-
-def query_block(n: int, t: int, heads: int, keys: int, window) -> int:
-    """How many of a prefill's ``t`` queries a row attend at once: a
-    windowed layer's in blocks of about its window (a block then gathers
-    two windows' pages, not the table), a full layer's all where its
-    float32 scores over ``heads`` (its own and its indexer's) fit
-    ``SCORES_MAX_BYTES``, else in blocks that do: ``t`` halved as often
-    as that takes (a power-of-two bucket halves evenly)."""
-    block = t
-    if window is not None:
-        while block > 16 and block >= 2 * window and block % 2 == 0:
-            block //= 2
-        keys = block + window
-    while (block > 16 and block % 2 == 0
-           and 4 * n * heads * block * keys > SCORES_MAX_BYTES):
-        block //= 2
-    return block
 
 
 def _kernel_blocks(t: int, keys: int) -> tuple:
@@ -882,61 +615,6 @@ def latent_prefill_attention_kernel(q, k, v, starts, slens, flags=None, *,
     )(starts.astype(jnp.int32), slens.astype(jnp.int32), *operands)
 
 
-def _over_blocks(fn, xs, starts, by_key, *, block, grouped):
-    """``fn(*x, first, seen)`` for every block of ``block`` queries of
-    ``xs`` (arrays [n, t, ...]; ``first`` [n]: the block's first position,
-    from ``starts``), one block after another, their results [n, block,
-    ...] side by side as [n, t, ...]. ``seen``: ``by_key`` (arrays whose
-    last axis but one is the table's key positions from 0), whole unless
-    ``grouped``: where the table reaches past the last (padded) query,
-    the queries of block i see no key past ``keys - (count - 1 - i) x
-    block``, and the blocks go in up to ``KEY_GROUPS`` groups, each over
-    the keys its last block can see and no further (a cold prompt's first
-    quarter attends over a quarter of the keys, not all of them)."""
-    n, t = xs[0].shape[:2]
-    if block == t:
-        return fn(*xs, starts, by_key)
-    count = t // block
-    firsts = starts[None, :] + block * jnp.arange(
-        count, dtype=jnp.int32)[:, None]                     # [blocks, n]
-    xs = tuple(jnp.moveaxis(a.reshape(n, count, block, *a.shape[2:]), 1, 0)
-               for a in xs) + (firsts,)
-
-    def some(lo, hi, seen):
-        """Blocks ``lo`` to ``hi``, one after another."""
-        return jax.lax.map(lambda xs: fn(*xs, seen),
-                           jax.tree.map(lambda a: a[lo:hi], xs))
-
-    def in_groups():
-        groups, out = min(KEY_GROUPS, count), []
-        for g in range(groups):
-            lo, hi = g * count // groups, (g + 1) * count // groups
-            extent = keys - (count - hi) * block
-            out.append(some(lo, hi, jax.tree.map(
-                lambda a: a[..., :extent, :], by_key)))
-        return jnp.concatenate(out)
-
-    if not grouped:
-        out = some(0, count, by_key)
-    else:
-        # (a suffix whose padding runs past its table, ``starts + t >
-        # keys``, gives no such bound: every block over every key)
-        keys = jax.tree.leaves(by_key)[0].shape[-2]
-        out = jax.lax.cond(jnp.all(starts + t <= keys), in_groups,
-                           lambda: some(0, count, by_key))
-    return jnp.moveaxis(out, 0, 1).reshape(n, t, *out.shape[3:])
-
-
-def _causal(first, block: int, key_start, extent: int, window):
-    """[n, block, extent] bool: which of ``extent`` keys from position
-    ``key_start`` [n] each of a block's queries from ``first`` [n] may
-    see: those up to its own, under a ``window`` the newest alone."""
-    qpos = first[:, None] + jnp.arange(block, dtype=jnp.int32)
-    kpos = key_start[:, None] + jnp.arange(extent, dtype=jnp.int32)
-    mask = kpos[:, None, :] <= qpos[:, :, None]
-    if window is not None:
-        mask = mask & (kpos[:, None, :] > qpos[:, :, None] - window)
-    return mask
 
 
 def _expanded(q, wkv_b, rows, dr: int):
@@ -1002,44 +680,19 @@ def _prefill_plain(q, wkv_b, indexed, pools, layer, table_rows, starts, *,
                   + jnp.einsum("nthd,nsd->nhts", q[..., dn:], kr,
                                preferred_element_type=jnp.float32)
                   ) * scale
-        mask = _causal(first, q.shape[1], key_start, kr.shape[1], window)
+        mask = causal(first, q.shape[1], key_start, kr.shape[1], window)
         if iq and kr.shape[1] > topk:
-            chosen = jnp.where(mask, index_scores(*iq, index_keys), _MASKED)
+            chosen = jnp.where(mask, index_scores(*iq, index_keys), MASKED)
             mask = mask & kept(chosen, topk)
-        scores = jnp.where(mask[:, None], scores, _MASKED)
+        scores = jnp.where(mask[:, None], scores, MASKED)
         probs = jax.nn.softmax(scores, axis=-1)
         return jnp.einsum(
             "nhts,nhsv->nthv", probs.astype(q.dtype), v,
             preferred_element_type=jnp.float32).astype(q.dtype)
 
-    return _over_blocks(attend, (q, *(indexed or ())), starts, whole,
+    return over_blocks(attend, (q, *(indexed or ())), starts, whole,
                         block=block, grouped=window is None)
 
-
-def _selection_flags(indexed, index_keys, starts, *, topk):
-    """[n, T, S] int8: 1 where ``kept`` keeps the key for the query, of
-    the keys up to its own: ``index_scores`` and ``kept`` as the plain
-    formulation runs them, in float32, over blocks of queries whose
-    index scores [n, HI, block, keys] fit ``SCORES_MAX_BYTES``, in the
-    plain formulation's groups of keys. A group of no more than ``topk``
-    keys drops none: ones."""
-    iq, iw = indexed
-    n, t, index_heads, _ = iq.shape
-    keys = index_keys.shape[1]
-
-    def flags(iq, iw, first, seen_keys):
-        extent = seen_keys.shape[1]
-        if extent <= topk:
-            return jnp.ones((n, iq.shape[1], keys), jnp.int8)
-        mask = _causal(first, iq.shape[1], jnp.zeros_like(first), extent,
-                       None)
-        chosen = jnp.where(mask, index_scores(iq, iw, seen_keys), _MASKED)
-        return jnp.pad((mask & kept(chosen, topk)).astype(jnp.int8),
-                       ((0, 0), (0, 0), (0, keys - extent)))
-
-    return _over_blocks(
-        flags, (iq, iw), starts, index_keys, grouped=True,
-        block=query_block(n, t, index_heads, keys, None))
 
 
 def _prefill_in_kernel(q, wkv_b, indexed, pools, layer, table_rows, starts,
@@ -1056,7 +709,7 @@ def _prefill_in_kernel(q, wkv_b, indexed, pools, layer, table_rows, starts,
         [kn, jnp.broadcast_to(kr[:, None], (n, heads, *kr.shape[1:]))], -1)
     flags = None
     if indexed:
-        flags = _selection_flags(
+        flags = selection_flags(
             indexed, gather_rows(pools[1], layer, table_rows), starts,
             topk=topk)
     out = latent_prefill_attention_kernel(

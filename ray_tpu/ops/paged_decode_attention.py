@@ -63,6 +63,18 @@ holds it, and stops at each slot's length:
   edge, however long its context: the walk's first step begins at that
   page, not at a multiple of a step.
 
+- a layer that PICKS ITS KEYS (``selected``: a learned selection,
+  ``ops/index_select.py``) hands the kernel ``kept``'s set as flags a
+  key, laid out as a step's score columns are (a flag a (token, kv head)
+  row, a page's side by side: [B, PB, page * nkv] int32 in VMEM, 4 MB
+  at 32 slots of 8,192 keys over 4 KV heads, which XLA writes a layer):
+  every page a slot holds is still fetched whole and the rows outside
+  the set are masked, so the selection saves the step no byte (reading
+  the chosen rows alone is ROADMAP Queue 2's). A step none of whose rows
+  is in the set, met before any that has one, sums ones under the
+  finite ``_MASKED``, and the first row of the set zeroes them
+  (``alpha``). Without a selection none of this is traced.
+
 ``paged_decode_attention`` is the entry: on a program LOWERED for a TPU
 it is the kernel, on any other platform the plain gather formulation
 (``paged_decode_attention_reference``), chosen by
@@ -90,13 +102,15 @@ _STEP_ROWS = 1024
 
 
 def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
-                                     layer, table, pos, active, *,
-                                     window=None):
+                                     layer, table, pos, active,
+                                     selected=None, *, window=None):
     """The gather formulation: every slot's window copied out
     (``gather_kv_window``), then ``cached_attention`` over the copy with
     the causal limit ``key position <= pos``; for a sliding layer only
-    the pages that hold keys ``> pos - window`` are copied. What the
-    kernel is held to, and what every platform but the TPU runs."""
+    the pages that hold keys ``> pos - window`` are copied; with
+    ``selected`` ([B, PB x page] bool) the softmax runs over those keys
+    of the table alone. What the kernel is held to, and what every
+    platform but the TPU runs."""
     del active      # a dead slot attends over page 0; its row is discarded
     b, _, hd = q.shape
     page = k_pages.shape[2]
@@ -111,7 +125,9 @@ def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
     out = cached_attention(q[:, None], kg.reshape(b, -1, nkv, hd),
                             vg.reshape(b, -1, nkv, hd), pos,
                             scale=hd ** -0.5, window=window,
-                            key_start=key_start)
+                            key_start=key_start,
+                            seen=None if selected is None
+                            else selected[:, None])
     return out[:, 0]
 
 
@@ -129,13 +145,15 @@ def step_pages(pool) -> int:
 
 def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
             q_ref, k_hbm, v_hbm, *rest, pages_per_slot, page, nkv,
-            per_step, quantized, window):
+            per_step, quantized, window, selects=False):
     """See the module docstring. ``rest``: the window's scale rows (int8
-    only), the output, the step buffers and their DMA semaphores."""
+    only), the selection's flag rows (``selects`` only), the output, the
+    step buffers and their DMA semaphores."""
     if quantized:
-        ks_ref, vs_ref, o_ref, k_buf, v_buf, sem = rest
-    else:
-        o_ref, k_buf, v_buf, sem = rest
+        ks_ref, vs_ref, *rest = rest
+    if selects:
+        flags_ref, *rest = rest
+    o_ref, k_buf, v_buf, sem = rest
     slots, nh, hd = q_ref.shape
     page_rows = page * nkv
     rows = per_step * page_rows
@@ -248,6 +266,9 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
             seen = own_head & (token < keys_end - page0 * page)
             if window is not None:
                 seen = seen & (token >= count - window - page0 * page)
+            if selects:
+                seen = seen & (_scale_row(flags_ref, slot, page0,
+                                          per_step) > 0)
             s = jnp.where(seen, s, _MASKED)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -276,8 +297,9 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
 
 
 def _scale_row(scales_ref, slot, block, per_step):
-    """The scales of a step's rows [1, rows], laid out as its score
-    columns are: those of its pages, from ``block`` on, side by side."""
+    """The scales (or the selection's flags) of a step's rows [1, rows],
+    laid out as its score columns are: those of its pages, from ``block``
+    on, side by side."""
     # (``block + 0`` would be one more instruction of a one-page step)
     pages = [scales_ref[slot, pl.ds(block + j if j else block, 1), :]
              for j in range(per_step)]
@@ -285,8 +307,8 @@ def _scale_row(scales_ref, slot, block, per_step):
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
-                                  layer, table, pos, active, *,
-                                  window=None, interpret=False):
+                                  layer, table, pos, active, selected=None,
+                                  *, window=None, interpret=False):
     """The kernel's launch; arguments as ``paged_decode_attention``."""
     slots, nh, hd = q.shape
     layers, num_pages, page, nkv, _ = k_pages.shape
@@ -322,10 +344,19 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
         operands += [k_scale[layer, scaled].reshape(slots, -1, page * nkv),
                      v_scale[layer, scaled].reshape(slots, -1, page * nkv)]
         in_specs += [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2
+    static = dict(pages_per_slot=pb, page=page, nkv=nkv, per_step=per_step,
+                  quantized=quantized, window=window)
+    if selected is not None:
+        # the selection as rows [B, PB, page * nkv]: a key's flag under
+        # each of its KV heads' score columns (and as many pages of
+        # zeros more as a step that begins at the table's last may read)
+        flags = jnp.repeat(selected.reshape(slots, pb, page), nkv, axis=-1)
+        operands.append(jnp.pad(flags.astype(jnp.int32),
+                                ((0, 0), (0, per_step - 1), (0, 0))))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
+        static["selects"] = True
     return pl.pallas_call(
-        functools.partial(_kernel, pages_per_slot=pb, page=page, nkv=nkv,
-                          per_step=per_step, quantized=quantized,
-                          window=window),
+        functools.partial(_kernel, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(1,), in_specs=in_specs,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -351,22 +382,25 @@ def _lowerings(window):
 
 
 def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
-                           table, pos, active, *, window=None):
+                           table, pos, active, *, window=None,
+                           selected=None):
     """One decode step's attention for every slot, scores scaled by
     head_dim ** -0.5. q [B, nh, hd]; stacked pools [L, P, page, nkv, hd]
     (bf16, or int8 with their scale pools [L, P, page, nkv]); ``layer`` a
     scalar; ``table`` [B, PB] page ids (-1 = hole); slot b attends key
     positions <= pos[b] of its pages, with ``window`` (static: a sliding
     layer's) those > pos[b] - window alone, if ``active[b]`` (a dead
-    slot's row is unspecified, and discarded). Returns [B, nh, hd] in q's
-    dtype.
+    slot's row is unspecified, and discarded); with ``selected`` ([B, PB
+    x page] bool: a layer that picks its keys) over those of them alone.
+    Returns [B, nh, hd] in q's dtype.
 
     The two branches are the module's own functions, not closures made a
     call: JAX then keeps their traces, and an engine's decode programs
     that differ in their chunk alone trace them once (a trace of both is
     0.2-0.3 s of a program's set-up on the chip's host)."""
     kernel, reference = _lowerings(window)
+    args = (q, k_pages, v_pages, k_scale, v_scale, layer, table, pos, active)
+    if selected is not None:
+        args += (selected,)
     with jax.named_scope(scopes.ATTN):
-        return lax.platform_dependent(
-            q, k_pages, v_pages, k_scale, v_scale, layer, table, pos, active,
-            tpu=kernel, default=reference)
+        return lax.platform_dependent(*args, tpu=kernel, default=reference)

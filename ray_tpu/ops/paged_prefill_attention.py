@@ -63,6 +63,18 @@ VMEM:
   at the end, as the decode kernel does (``cached_attention`` normalises
   first: one unit in the last place of a bf16 output apart).
 
+- a full layer that PICKS ITS KEYS (``flags``: a learned selection,
+  ``ops/index_select.py:selection_flags``) hands the kernel ``kept``'s
+  set as one byte a query and key [n, T, S]: a block's flags against
+  every key of the table [1, block_q, S] come in with its queries (the
+  pipeline's own copy, once a pair: 1 MiB at 128 queries of 8,192 keys),
+  a chunk's columns of them are tiled over the group's query heads and
+  ANDed into the mask; the kernel computes no index score, no top-k and
+  no tie, and fetches every page of the walk as before. A chunk none of
+  whose keys a row's set holds, met before any that it has, sums ones
+  under the finite ``_MASKED``, which the ``alpha`` of its first such
+  key zeroes. Without flags none of this is traced;
+
 ``paged_prefill_attention`` is the entry. The kernel ENGAGES by one rule
 on the traced shapes (``kernel_engages``): bf16 pools, and float32
 scores of the plain path for the layer over ``KERNEL_SCORES_BYTES`` (256
@@ -175,16 +187,20 @@ def kernel_engages(q_shape, pools, table_width: int, window) -> bool:
 
 def paged_prefill_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
                                       layer, table_rows, starts, slens=None,
-                                      *, window=None):
+                                      flags=None, *, window=None):
     """The gather formulation: one gather of the rows' whole tables and
     one ``cached_attention`` where that fits; past ``SCORES_MAX_BYTES``
     the same call on blocks of queries, one after another (one program,
     one dispatch: the host sees nothing of it). A sliding layer goes in
     blocks of its window, and for each gathers only the pages that the
-    block's queries can see. What the kernel is held to, and what every
-    platform but the TPU runs; ``slens`` is the kernel's to use (the rows
-    of padding are computed here)."""
+    block's queries can see; with ``flags`` ([n, T, S] int8: a full
+    layer that picks its keys) a query's softmax runs over the keys it
+    flags alone. What the kernel is held to, and what every platform but
+    the TPU runs; ``slens`` is the kernel's to use (the rows of padding
+    are computed here)."""
     del slens
+    if flags is not None and window is not None:
+        raise ValueError("a sliding layer takes no selection")
     n, t, heads, hd = q.shape
     mp = table_rows.shape[1]
     page_size, nkv = k_pages.shape[2], k_pages.shape[3]
@@ -193,9 +209,12 @@ def paged_prefill_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
     seen = (mp if window is None
             else -(-(block + window - 2) // page_size) + 1)
 
-    def attend(q, first):
-        """``q`` [n, block, heads, hd], the first of them at ``first``."""
+    def attend(q, first, chosen=None):
+        """``q`` [n, block, heads, hd], the first of them at ``first``;
+        ``chosen`` [n, block, S]: their flags, where the layer selects."""
         rows, where = table_rows, {}
+        if chosen is not None:
+            where = {"seen": chosen != 0}
         if window is not None:
             rows, key_start = visible_pages(table_rows, first - window + 1,
                                             seen, page_size)
@@ -211,23 +230,30 @@ def paged_prefill_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
                                 scale=hd ** -0.5, **where)
 
     if block == t:
-        return attend(q, starts)
+        return attend(q, starts, flags)
     firsts = starts[None, :] + block * jnp.arange(
         t // block, dtype=jnp.int32)[:, None]                  # [blocks, n]
     qb = jnp.moveaxis(q.reshape(n, t // block, block, heads, hd), 1, 0)
-    out = jax.lax.map(lambda xs: attend(*xs), (qb, firsts))
+    xs = (qb, firsts)
+    if flags is not None:
+        xs += (jnp.moveaxis(flags.reshape(n, t // block, block, -1), 1, 0),)
+    out = jax.lax.map(lambda xs: attend(*xs), xs)
     return jnp.moveaxis(out, 0, 1).reshape(n, t, heads, hd)
 
 
 def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
-            q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, steps_ref, *,
-            grid, pages_per_row, chunk_pages, window):
+            q_ref, k_hbm, v_hbm, *rest, grid, pages_per_row, chunk_pages,
+            window, selects=False):
     """See the module docstring. ``q_ref`` / ``o_ref``: the query heads of
     the pair's two KV heads at ``block_q`` positions, [1, block_q, 2 *
-    group * hd]; ``steps_ref``: the chunks walked so far, which
+    group * hd]; before ``o_ref``, where the layer ``selects``, the
+    block's flags [1, block_q, S]; ``steps_ref``: the chunks walked so far, which
     names the buffer the next one lands in, carried from block to block.
     ``window`` is static: under None a walk starts at chunk 0 and no
     statement of a first chunk is traced."""
+    if selects:
+        flags_ref, *rest = rest
+    o_ref, k_buf, v_buf, sem, steps_ref = rest
     n, pairs, nq = grid
     b, pair, qi = (pl.program_id(i) for i in range(3))
     bq, hd = q_ref.shape[1], k_hbm.shape[4]
@@ -337,6 +363,12 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
                       (step0 + walked(j) + 1) % _BUFFERS)
 
             wait(0, buf)
+            if selects:
+                # the chunk's columns of the block's flags, under every
+                # query head of the group (row r * bq + i is position i)
+                flagged = jnp.concatenate(
+                    [flags_ref[0, :, pl.ds(pl.multiple_of(j * ck, ck), ck)
+                               ].astype(jnp.int32)] * group, axis=0) > 0
             probs = []
             for (m, l, _), q, k in zip(carry, qs, heads_of_pair(k_buf, buf)):
                 s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -344,6 +376,8 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
                 seen = j * ck + kcol <= qpos
                 if window is not None:
                     seen &= j * ck + kcol > qpos - window
+                if selects:
+                    seen &= flagged
                 s = jnp.where(seen, s, _MASKED)
                 m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
@@ -386,10 +420,12 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
 
 
 def paged_prefill_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
-                                   layer, table_rows, starts, slens, *,
-                                   window=None, interpret=False):
+                                   layer, table_rows, starts, slens,
+                                   flags=None, *, window=None,
+                                   interpret=False):
     """The kernel's launch; arguments as ``paged_prefill_attention`` (bf16
-    pools; ``window`` static, a sliding layer's or None)."""
+    pools; ``window`` static, a sliding layer's or None; ``flags`` a
+    full layer's that selects)."""
     del k_scale, v_scale
     n, t, heads, hd = q.shape
     _, _, page, nkv, _ = k_pages.shape
@@ -402,13 +438,21 @@ def paged_prefill_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
     block = pl.BlockSpec((1, bq, 2 * group * hd),
                          lambda b, g, i, *_: (b, i, g))
     buffers = (_BUFFERS, chunk_pages * page, nkv, hd)
+    static = dict(grid=grid, pages_per_row=wp, chunk_pages=chunk_pages,
+                  window=window)
+    in_specs = [block, pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    selection = ()
+    if flags is not None:
+        in_specs.append(pl.BlockSpec((1, bq, wp * page),
+                                     lambda b, g, i, *_: (b, i, 0)))
+        selection = (flags,)
+        static["selects"] = True
     out = pl.pallas_call(
-        functools.partial(_kernel, grid=grid, pages_per_row=wp,
-                          chunk_pages=chunk_pages, window=window),
+        functools.partial(_kernel, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=grid,
-            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=in_specs,
             out_specs=block,
             scratch_shapes=[
                 pltpu.VMEM(buffers, k_pages.dtype),
@@ -424,12 +468,13 @@ def paged_prefill_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       jnp.maximum(table_rows, 0).astype(jnp.int32).reshape(-1),
       starts.astype(jnp.int32), slens.astype(jnp.int32),
-      q.reshape(n, t, heads * hd), k_pages, v_pages)
+      q.reshape(n, t, heads * hd), k_pages, v_pages, *selection)
     return out.reshape(n, t, heads, hd)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
-                            table_rows, starts, slens, *, window=None):
+                            table_rows, starts, slens, *, window=None,
+                            flags=None):
     """A prefill's attention for one layer, scores scaled by head_dim **
     -0.5. q [n, T, heads, hd] at positions ``starts + i``; stacked pools
     [L, P, page, nkv, hd] (bf16, or int8 with their scale pools [L, P,
@@ -437,9 +482,10 @@ def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
     scalar; ``table_rows`` [n, wp] page ids (-1 = hole); query ``i`` of
     row ``b`` attends key positions <= starts[b] + i of its pages, with
     ``window`` (static: a sliding layer's) those > starts[b] + i - window
-    alone. ``slens`` [n]: the rows' valid queries; the rows of padding
-    past them come back finite and otherwise unspecified. Returns [n, T,
-    heads, hd] in q's dtype.
+    alone, with ``flags`` ([n, T, wp x page] int8: a full layer that
+    picks its keys) those it flags alone. ``slens`` [n]: the rows' valid
+    queries; the rows of padding past them come back finite and
+    otherwise unspecified. Returns [n, T, heads, hd] in q's dtype.
 
     Under the rule (``kernel_engages``) this IS the plain formulation,
     called directly: the program's lowered text is what it was. Over it
@@ -450,11 +496,13 @@ def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
         if not kernel_engages(q.shape, k_pages, table_rows.shape[1], window):
             return paged_prefill_attention_reference(
                 q, k_pages, v_pages, k_scale, v_scale, layer, table_rows,
-                starts, window=window)
+                starts, flags=flags, window=window)
         kernel, plain = _lowerings(window)
-        return lax.platform_dependent(
-            q, k_pages, v_pages, k_scale, v_scale, layer, table_rows, starts,
-            slens, tpu=kernel, default=plain)
+        args = (q, k_pages, v_pages, k_scale, v_scale, layer, table_rows,
+                starts, slens)
+        if flags is not None:
+            args += (flags,)
+        return lax.platform_dependent(*args, tpu=kernel, default=plain)
 
 
 @functools.lru_cache(maxsize=None)

@@ -15,7 +15,13 @@ runs (``EnginePrograms.prefill_kernels``, ``decode_kernels``).
   (``LayerStack``), and every store has as many layers as the plan has
   layers that keep it (``_pool_slices``, ``_places``). A page id names
   the same ``page_size`` tokens in every pool, so the loop's one page
-  table, one allocator and one prefix cache serve them all.
+  table, one allocator and one prefix cache serve them all. A run may
+  keep the K/V twins AND further rows beside them (``LayerStack.beside``:
+  the key of an indexer that picks, among K/V rows, the ones a query
+  attends over): its format is the twins' four pools and a pool a row
+  behind them, written in the same step at the same place; a shared page
+  carries the index keys as it carries K and V, each a function of the
+  token's prefix alone.
 - Both programs attend over the pages WHERE THEY LIE: a layer's new
   rows are written, then read through the page table by the entries of
   ``ops/paged_decode_attention.py``, ``ops/paged_prefill_attention.py``
@@ -91,15 +97,17 @@ import jax.numpy as jnp
 
 from ray_tpu.models.decoding import select_tokens
 from ray_tpu.ops import scopes
-from ray_tpu.ops.latent_attention import (index_kernel_engages,
-                                          latent_decode_attention,
+from ray_tpu.ops.index_select import (decode_selection, index_kernel_engages,
+                                      prefill_selection)
+from ray_tpu.ops.latent_attention import (latent_decode_attention,
                                           latent_kernel_engages,
                                           latent_prefill_attention,
                                           latent_prefill_kernel_engages,
                                           write_latent)
 from ray_tpu.ops.moe import expert_kernel_engages
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.paged_attention import row_pool, write_kv
+from ray_tpu.ops.paged_attention import (ROW_LANES, row_pool, write_kv,
+                                         write_rows)
 from ray_tpu.ops.paged_decode_attention import (paged_decode_attention,
                                                 step_pages)
 from ray_tpu.ops.paged_prefill_attention import (kernel_engages,
@@ -180,6 +188,7 @@ _PREFILL = _Order(
 _PIECES = ("layer_plan", "embed", "head_logits")
 _ATTENTION_PIECES = ("rotary_tables", "attention_output")
 _KV_PIECES = ("attention_projections",)
+_INDEX_PIECES = ("index_projections",)
 _LATENT_PIECES = ("latent_projections",)
 _RECURRENT_PIECES = ("recurrent_mixer", "recurrent_step")
 _FEED_PIECES = ("feed_forward",)
@@ -191,8 +200,9 @@ def _model_module(cfg):
     is defined in, which must hold the pieces its layer plan USES and no
     others: the plan itself, the stream's start and the head; where a
     run attends, what attention takes in (as q, k and v where the run
-    keeps K/V twins, as a latent's inputs where it keeps rows), its
-    rotary tables and its end; the mixer's two forms where a run holds a
+    keeps K/V twins, with an indexer's inputs where such a run picks its
+    keys, as a latent's inputs where it keeps rows), its rotary tables
+    and its end; the mixer's two forms where a run holds a
     recurrent mixer; the feed-forward where a run ends in one, and what
     it takes of the layer's input where a run states that
     (``LayerStack.ahead``)."""
@@ -204,6 +214,8 @@ def _model_module(cfg):
         asked = (
             _ATTENTION_PIECES * bool(attends)
             + _KV_PIECES * any(run.rows is None for run in attends)
+            + _INDEX_PIECES * any(run.rows is None and run.selects is not None
+                                  for run in attends)
             + _LATENT_PIECES * any(run.rows is not None for run in attends)
             + _RECURRENT_PIECES * (_recurrent(plan) is not None)
             + _FEED_PIECES * any(run.feeds for run in plan)
@@ -234,19 +246,39 @@ def _state_layers(plan) -> int:
     return sum(run.layers for run in plan if run.state is not None)
 
 
+def _format(run):
+    """What the layers of a run that attends keep a token, as the key its
+    pools go by: ``LayerStack.rows`` (None: the K/V twins; else the rows
+    it names) and, for a run that keeps rows BESIDE its twins
+    (``LayerStack.beside``), (None, those rows): the twins first."""
+    return run.rows if run.beside is None else (None, *run.beside)
+
+
+def _twins(fmt) -> bool:
+    """Whether a format holds the K/V twins (alone, or first)."""
+    return fmt is None or fmt[0] is None
+
+
+def _rows(fmt) -> tuple:
+    """The rows of a format that are a pool each: those beside the twins
+    (none, for the twins alone), or all of a format of rows."""
+    return (fmt or ())[1:] if _twins(fmt) else fmt
+
+
 def _pool_slices(plan) -> tuple:
     """Where each page format of a layer plan lies among the pools the
-    two programs carry: ({format: slice}, how many pools). A format is
-    what the layers of a run that attends keep a token
-    (``LayerStack.rows``): None, the K/V twins, which are four pools (K,
-    V and their scale pools); else the rows it names, a pool each. Runs
-    of one format share its pools, which span THEIR layers in the plan's
-    order."""
+    two programs carry: ({format: slice}, how many pools). A format
+    (``_format``) is what the layers of a run that attends keep a token:
+    None, the K/V twins, which are four pools (K, V and their scale
+    pools); the twins and the rows beside them, a pool each behind the
+    four; else the rows it names, a pool each. Runs of one format share
+    its pools, which span THEIR layers in the plan's order."""
     slices, at = {}, 0
     for run in plan:
-        if run.attends and run.rows not in slices:
-            n = 4 if run.rows is None else len(run.rows)
-            slices[run.rows] = slice(at, at + n)
+        fmt = _format(run)
+        if run.attends and fmt not in slices:
+            n = 4 * _twins(fmt) + len(_rows(fmt))
+            slices[fmt] = slice(at, at + n)
             at += n
     if not slices:
         raise ValueError("no run of the layer plan attends: the engine "
@@ -254,10 +286,11 @@ def _pool_slices(plan) -> tuple:
     return slices, at
 
 
-def _pool_layers(plan, rows) -> int:
-    """How many of the plan's layers keep pages of the format ``rows``."""
+def _pool_layers(plan, fmt) -> int:
+    """How many of the plan's layers keep pages of the format ``fmt``
+    (``_format``)."""
     return sum(run.layers for run in plan
-               if run.attends and run.rows == rows)
+               if run.attends and _format(run) == fmt)
 
 
 def _plan_runs(plan, blocks, fuse=None) -> list:
@@ -301,8 +334,8 @@ def _places(plan) -> list:
     for run in plan:
         pool_at = state_at = None
         if run.attends:
-            pool_at = first.get(run.rows, 0)
-            first[run.rows] = pool_at + run.layers
+            pool_at = first.get(_format(run), 0)
+            first[_format(run)] = pool_at + run.layers
         if run.state is not None:
             state_at, states = states, states + run.layers
         places.append((pool_at, state_at))
@@ -355,28 +388,30 @@ class EnginePrograms:
         self.bf16_row_bytes = 0     # a token's rows over the layers, bf16
         self._page_layers = {}      # layers that keep pages, by format
         twins = None                # the K pool of the layers with twins
-        for rows in _pool_slices(plan)[0]:
-            layers = _pool_layers(plan, rows)
-            self._page_layers[
-                "k+v" if rows is None else
-                ",".join(f"{row.name}:{row.width}" for row in rows)] = layers
-            if rows is None:
+        for fmt in _pool_slices(plan)[0]:
+            layers = _pool_layers(plan, fmt)
+            rows = _rows(fmt)
+            self._page_layers[",".join(
+                ["k+v"] * _twins(fmt)
+                + [f"{row.name}:{row.width}" for row in rows])] = layers
+            if _twins(fmt):
                 self.pools += self._kv_twins(layers)
                 twins = self.pools[-4]
                 self.bf16_row_bytes += (
                     layers * 2 * 2 * math.prod(twins.shape[3:]))
-                continue
-            if kv_dtype == "int8":
+            if rows and kv_dtype == "int8":
                 raise ValueError(
                     "kv_dtype='int8' over a layer plan that keeps rows "
                     f"({', '.join(row.name for row in rows)}): only K/V "
                     "twins are stored quantised")
-            self.pools += [row_pool(layers, num_pages, page_size, row)
-                           for row in rows]
+            row_pools = [row_pool(layers, num_pages, page_size, row)
+                         for row in rows]
+            self.pools += row_pools
             self.bf16_row_bytes += layers * 2 * sum(
-                pool.shape[-1] for pool in self.pools[-len(rows):])
+                pool.shape[-1] for pool in row_pools)
         # the rows a token keeps in a page, by format: "k+v" for K/V
-        # twins, else the rows' names and widths
+        # twins (then the rows beside them), else the rows' names and
+        # widths
         self.page_rows = ";".join(self._page_layers)
         # the slots' recurrent state, one array a kind [L', max_batch,
         # ...] over the L' layers that keep it (none, for a plan of pages
@@ -407,7 +442,11 @@ class EnginePrograms:
             run.window is not None for run in twin_runs)
         self._state_kernel = on_tpu and any(
             state_kernel_engages(a) for a in self.state)
-        self._latent_backend = on_tpu and self.selects is not None
+        # layers that pick their keys: whether any does (their index
+        # scores), and whether any over latent rows
+        self._selects_backend = on_tpu and self.selects is not None
+        self._latent_backend = on_tpu and any(
+            run.selects is not None and run.rows is not None for run in plan)
         # the runs that attend over latent rows, as the prefill kernel's
         # rule takes them: (heads, the rows' pool's place, window, the
         # keys its indexer keeps, the indexer's heads)
@@ -415,14 +454,15 @@ class EnginePrograms:
         self._latent_runs = [
             (cfg.n_heads if run.window is None
              else getattr(cfg, "n_heads_sliding", cfg.n_heads),
-             where[run.rows].start, run.window, run.selects,
+             where[_format(run)].start, run.window, run.selects,
              getattr(cfg, "index_heads", 0))
             for run in plan
             if on_tpu and run.attends and run.rows is not None]
-        # an index key's width (pools: latent rows, then index keys)
+        # an index key's width in its pool, whole lanes (pools: latent
+        # rows or the K/V twins, then index keys)
         self._index_width = next(
-            (run.rows[1].width for run in plan if run.selects is not None),
-            None)
+            (-(-_format(run)[1].width // ROW_LANES) * ROW_LANES
+             for run in plan if run.selects is not None), None)
         # the pages a step of the decode kernel's walk takes over the K/V
         # twins (``ops/paged_decode_attention.py``'s rule on their shape)
         self._attn_step_pages = (
@@ -572,7 +612,7 @@ class EnginePrograms:
                 self._latent_backend and latent_kernel_engages(
                     self.page_size, pages, self.selects)),
             "index_kernel": int(
-                self._latent_backend and index_kernel_engages(
+                self._selects_backend and index_kernel_engages(
                     self.page_size, pages, self.selects,
                     self._index_width))}
 
@@ -647,7 +687,7 @@ def _paged_decode_impl(cfg, params, *args, chunk, page_size,
                 # then the slot's rows read where they lie (a
                 # sliding layer: its window's; a layer with an
                 # indexer: the ones it picks)
-                held = rest[where[run.rows]]
+                held = rest[where[_format(run)]]
                 inputs = model.latent_projections(
                     cfg, p, x, *rotary[run.kind])
                 held = write_latent(inputs, held, layer, pidx, ip)
@@ -655,23 +695,38 @@ def _paged_decode_impl(cfg, params, *args, chunk, page_size,
                     inputs, held, layer, table, pos, window=run.window,
                     active=active)
             else:
-                held = rest[where[run.rows]]
+                held = rest[where[_format(run)]]
+                twins, beside = held[:4], held[4:]
                 q, k, v = model.attention_projections(
                     cfg, p, x, *rotary[run.kind])
                 if run.state is not None:
                     # the mixer beside the attention, on the same input
                     mixed, state = mixer_step()
-                held = write_kv(*held, layer, k[:, 0], v[:, 0], pidx,
-                                ip, quantized)
+                twins = write_kv(*twins, layer, k[:, 0], v[:, 0], pidx,
+                                 ip, quantized)
+                selected = None
+                if run.selects is not None:
+                    # the step's index key beside its K and V, then the
+                    # slot's index keys scored where they lie and the
+                    # keys its query attends over picked
+                    index = model.index_projections(
+                        cfg, p, x, *rotary[run.kind])
+                    beside = [write_rows(beside[0], layer, index.key[:, 0],
+                                         pidx, ip)]
+                    selected = decode_selection(
+                        index, beside[0], layer, table,
+                        jnp.where(active, pos + 1, 0))
                 # each live slot's pages up to its length (a sliding
                 # layer: the pages of its window), read where they
-                # lie; the row just written is among them
+                # lie; the row just written is among them; of a layer
+                # that selects, the rows it does not pick masked
                 attn = paged_decode_attention(
-                    q[:, 0], *held, layer, table, pos, active,
-                    window=run.window)
+                    q[:, 0], *twins, layer, table, pos, active,
+                    window=run.window, selected=selected)
+                held = [*twins, *beside]
             if run.attends:
                 x = model.attention_output(cfg, p, x, attn)
-                rest[where[run.rows]] = held
+                rest[where[_format(run)]] = held
             if run.state is not None:
                 x = x + mixed
             stats = {}
@@ -768,7 +823,7 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
             if run.state is not None:
                 mixed, state = mixer_pass()
         elif run.rows is not None:
-            held = rest[where[run.rows]]
+            held = rest[where[_format(run)]]
             inputs = model.latent_projections(cfg, p, x,
                                               *rotary[run.kind])
             held = write_latent(inputs, held, layer, pidx_all, ip_all)
@@ -776,19 +831,32 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
                 inputs, held, layer, table_rows, starts, slens,
                 window=run.window)
         else:
-            held = rest[where[run.rows]]
+            held = rest[where[_format(run)]]
+            twins, beside = held[:4], held[4:]
             q, k, v = model.attention_projections(cfg, p, x,
                                                   *rotary[run.kind])
             if run.state is not None:
                 mixed, state = mixer_pass()
-            held = write_kv(*held, layer, k, v, pidx_all, ip_all,
-                            quantized)
+            twins = write_kv(*twins, layer, k, v, pidx_all, ip_all,
+                             quantized)
+            flags = None
+            if run.selects is not None:
+                # the suffix's index keys beside its K and V, then each
+                # query's keys picked among the rows' (a reused prefix's
+                # out of its shared pages)
+                index = model.index_projections(cfg, p, x,
+                                                *rotary[run.kind])
+                beside = [write_rows(beside[0], layer, index.key, pidx_all,
+                                     ip_all)]
+                flags = prefill_selection(index, beside[0], layer,
+                                          table_rows, starts)
             attn = paged_prefill_attention(
-                q, *held, layer, table_rows, starts, slens,
-                window=run.window)
+                q, *twins, layer, table_rows, starts, slens,
+                window=run.window, flags=flags)
+            held = [*twins, *beside]
         if run.attends:
             x = model.attention_output(cfg, p, x, attn)
-            rest[where[run.rows]] = held
+            rest[where[_format(run)]] = held
         if run.state is not None:
             x = x + mixed
         if run.feeds:

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.ops import index_select
 from ray_tpu.ops import latent_attention as la
 
 # slots, index heads, key width, page, table pages, pool pages, layers,
@@ -45,7 +46,7 @@ def _chained(scores_of):
     """``q, held -> q`` through a call's scores (a sum that weighs
     nothing)."""
     def step(q, held):
-        live = jnp.where(scores_of(q, held) > la._MASKED, 1.0, 0.0)
+        live = jnp.where(scores_of(q, held) > la.MASKED, 1.0, 0.0)
         return q + (live.sum() * 0.0).astype(q.dtype)
     return step
 
@@ -76,24 +77,24 @@ def sweep(shape, groups, iters, interpret):
     held = (weights, pool, layer, table, count)
 
     def plain(q, held):
-        return la._scored_gathered(q, *held)
+        return index_select._scored_gathered(q, *held)
 
     want = np.asarray(jax.jit(plain)(q, held))
     out["plain_ms"] = _time(_chained(plain), q, held, iters) * 1e3
     for group in groups:
-        la._INDEX_GROUP = group
+        index_select._INDEX_GROUP = group
 
         def kernel(q, held):
             # (the launcher is jitted: its own function here, so that
             # each group traces anew)
-            return la.index_decode_scores_kernel.__wrapped__(
+            return index_select.index_decode_scores_kernel.__wrapped__(
                 q[:, 0], held[0][:, 0], *held[1:], interpret=interpret)
 
         got = np.asarray(jax.jit(kernel)(q, held))
-        seen = want > la._MASKED
+        seen = want > la.MASKED
         out[f"kernel_g{group}"] = {
             "ms": _time(_chained(kernel), q, held, iters) * 1e3,
-            "masked_alike": bool(np.array_equal(seen, got > la._MASKED)),
+            "masked_alike": bool(np.array_equal(seen, got > la.MASKED)),
             "max_rel": float((np.abs(got - want)[seen]
                               / np.maximum(np.abs(want[seen]), 1.0)).max()),
             "kept_alike": bool(np.array_equal(

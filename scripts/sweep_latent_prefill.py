@@ -144,7 +144,7 @@ def sweep(layer, program, iters, toy, exact=False):
     flags = None
     if selects:
         index_keys = la.gather_rows(pools[1], jnp.int32(1), table)
-        select = jax.jit(functools.partial(la._selection_flags, topk=topk))
+        select = jax.jit(functools.partial(la.selection_flags, topk=topk))
         out["flags_ms"] = _time(
             select, ((index.q, index.weights), index_keys, starts), iters)
         flags = select((index.q, index.weights), index_keys, starts)

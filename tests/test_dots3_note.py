@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from benchmark import reference
 from benchmark.families import dots3_note as family
 from ray_tpu.models import dots3_note
+from ray_tpu.ops import index_select
 from ray_tpu.ops import latent_attention as la
 from ray_tpu.ops import moe
 from ray_tpu.ops.paged_attention import PageRow, row_pool
@@ -267,7 +268,7 @@ def test_prefill_then_decode_then_a_reused_prefix_over_scattered_pages(
             part(lo, hi), pools, layer,
             jnp.take_along_axis(table, pos // PAGE, axis=1), pos % PAGE)
 
-    monkeypatch.setattr(la, "SCORES_MAX_BYTES", 4 * b * 4 * 8 * 40)
+    monkeypatch.setattr(index_select, "SCORES_MAX_BYTES", 4 * b * 4 * 8 * 40)
     assert la.query_block(b, 24, 4, 40, window) == 12      # two blocks
     pools = write(pools, 0, 24)
     got = la.latent_prefill_attention(
@@ -330,7 +331,7 @@ def test_ties_in_the_indexers_scores_go_to_the_lower_position():
 
 def test_query_blocks_keep_the_scores_under_the_limit():
     assert la.query_block(1, 4096, 128 + 64, 4096, None) == 256
-    assert 4 * 192 * 256 * 4096 <= la.SCORES_MAX_BYTES
+    assert 4 * 192 * 256 * 4096 <= index_select.SCORES_MAX_BYTES
     assert la.query_block(1, 4096, 64, 4096, 513) == 1024
     assert la.query_block(2, 32, 6, 32, None) == 32
 

@@ -921,7 +921,7 @@ def test_prefill_dispatches_say_whether_their_latent_layers_hold_the_kernel(
 _INDEX_KERNEL_CASES = [
     # the backend the engine finds, the keys its full layers keep (of a
     # table of 256), an index key's width, latent_kernel, index_kernel
-    ("cpu", 64, 128, 0, 0), ("tpu", 64, 128, 1, 1), ("tpu", 64, 16, 1, 0),
+    ("cpu", 64, 128, 0, 0), ("tpu", 64, 128, 1, 1), ("tpu", 64, 16, 1, 1),
     ("tpu", 12, 128, 0, 1), ("tpu", 256, 128, 0, 0)]
 
 
@@ -933,13 +933,14 @@ def test_decode_dispatches_say_whether_their_program_holds_the_index_kernel(
     """``index_kernel`` on ``engine.dispatch_decode`` is what
     ``EnginePrograms.decode_kernels`` says of the dispatched program's
     own table (two pages of 128 here): the rule its full layers' scores
-    were traced by (``ops/latent_attention.py``:
-    ``index_kernel_engages``), on a TPU backend alone; ``stats()`` counts
-    the decode dispatches that took it. 0 on the CPU whatever the
-    shapes; on an engine that finds a TPU backend 1 wherever the table
-    holds more than ``topk`` keys of whole lanes (past eight times
-    ``topk`` too, where the latent kernel does not engage), 0 for keys of
-    16 numbers and 0 where nothing is selected."""
+    were traced by (``ops/index_select.py``: ``index_kernel_engages``, on
+    the index keys' pool, whose rows are whole lanes), on a TPU backend
+    alone; ``stats()`` counts the decode dispatches that took it. 0 on
+    the CPU whatever the shapes; on an engine that finds a TPU backend 1
+    wherever the table holds more than ``topk`` keys (past eight times
+    ``topk`` too, where the latent kernel does not engage; for keys of 16
+    numbers too, since PR 60: the queries meet the row's spare lanes with
+    zeros) and 0 where nothing is selected."""
     eng = _note_engine_on(monkeypatch, backend, index_topk=topk,
                           index_dim=width)
     said = eng._programs.decode_kernels(2)

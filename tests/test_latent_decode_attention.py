@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import dots3_note
+from ray_tpu.ops import index_select
 from ray_tpu.ops import latent_attention as la
 
 PAGE, TABLE, POOL, LAYERS, TOPK = 16, 6, 64, 3, 24
@@ -56,13 +57,13 @@ def _case(shape, table_pages=TABLE, seed=0):
         q=jnp.asarray(q, jnp.bfloat16), pool=jnp.asarray(pool, jnp.bfloat16),
         layer=jnp.int32(2), table=jnp.asarray(table, jnp.int32),
         count=jnp.asarray(COUNTS),
-        chosen=jnp.where(seen, scores, la._MASKED), rank=rank)
+        chosen=jnp.where(seen, scores, la.MASKED), rank=rank)
 
 
 def _chosen_keys(chosen, topk):
     """What ``lax.top_k`` picks of each slot's seen keys, as sets."""
     values, positions = jax.lax.top_k(chosen, topk)
-    return [set(np.asarray(p)[np.asarray(v) > la._MASKED].tolist())
+    return [set(np.asarray(p)[np.asarray(v) > la.MASKED].tolist())
             for v, p in zip(values, positions)]
 
 
@@ -97,7 +98,7 @@ def test_kernel_is_plain_latent_attention(shape, table_pages):
     assert math.gcd(table_pages, la._GROUP) == {5: 1, 6: 2, 8: 8, 12: 4}[table_pages]
     args = (case["q"], case["pool"], case["layer"], case["table"],
             case["count"])
-    flags = (case["chosen"] > la._MASKED) & la.kept(case["chosen"], TOPK)
+    flags = (case["chosen"] > la.MASKED) & la.kept(case["chosen"], TOPK)
     got = la.latent_decode_attention_kernel(
         *args, flags, rank=case["rank"], scale=SCALE, interpret=True)
     assert got.shape == (*case["q"].shape[:2], case["rank"])
@@ -135,8 +136,8 @@ def test_the_mask_is_top_ks_set_key_for_key(ties, count):
     rng = np.random.default_rng(count)
     scores = TIES[ties](rng.standard_normal(TABLE * PAGE).astype(np.float32))
     seen = np.arange(TABLE * PAGE) < count
-    chosen = jnp.where(seen, scores, la._MASKED)[None]
-    flags = np.asarray((chosen > la._MASKED) & la.kept(chosen, TOPK))[0]
+    chosen = jnp.where(seen, scores, la.MASKED)[None]
+    flags = np.asarray((chosen > la.MASKED) & la.kept(chosen, TOPK))[0]
     assert set(np.nonzero(flags)[0].tolist()) == _chosen_keys(chosen, TOPK)[0]
     assert flags.sum() == min(count, TOPK) and not flags[count:].any()
 
@@ -268,9 +269,9 @@ def _index_case(table_pages, seed=0):
 def _both_scores(case):
     """(the index kernel's, the plain formulation's) [B, PB x page]."""
     args = (case["pool"], case["layer"], case["table"], case["count"])
-    got = la.index_decode_scores_kernel(
+    got = index_select.index_decode_scores_kernel(
         case["q"][:, 0], case["weights"][:, 0], *args, interpret=True)
-    want = la._scored_gathered(case["q"], case["weights"], *args)
+    want = index_select._scored_gathered(case["q"], case["weights"], *args)
     return np.asarray(got), np.asarray(want)
 
 
@@ -285,7 +286,7 @@ def test_index_kernel_is_plain_index_scores(table_pages, slot):
     pool; tables walked 2, 4 and 16 pages at a step (one group a slot at
     6 x 16 keys... several at 64)."""
     case = _index_case(table_pages)
-    assert math.gcd(table_pages, la._INDEX_GROUP) == {
+    assert math.gcd(table_pages, index_select._INDEX_GROUP) == {
         6: 2, 12: 4, 32: 16, 64: 16}[table_pages]
     got, want = _both_scores(case)
     assert got.shape == want.shape == (len(INDEX_SLOTS), table_pages * PAGE)
@@ -293,9 +294,9 @@ def test_index_kernel_is_plain_index_scores(table_pages, slot):
     count = int(case["count"][slot])
     np.testing.assert_allclose(got[slot, :count], want[slot, :count],
                                rtol=1e-5, atol=1e-5)
-    assert (got[slot, count:] == la._MASKED).all()
-    assert (want[slot, count:] == la._MASKED).all()
-    assert (got[slot, :count] > la._MASKED).all()
+    assert (got[slot, count:] == la.MASKED).all()
+    assert (want[slot, count:] == la.MASKED).all()
+    assert (got[slot, :count] > la.MASKED).all()
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -316,10 +317,11 @@ def _score_in_place(patch):
     shapes: both branches of the entry's choice are the kernel's
     formulation."""
     patch.setattr(
-        la, "index_decode_scores_kernel",
-        partial(la.index_decode_scores_kernel, interpret=True))
-    patch.setattr(la, "_scored_gathered", la._scored_in_place)
-    patch.setattr(la, "index_kernel_engages",
+        index_select, "index_decode_scores_kernel",
+        partial(index_select.index_decode_scores_kernel, interpret=True))
+    patch.setattr(index_select, "_scored_gathered",
+                  index_select._scored_in_place)
+    patch.setattr(index_select, "index_kernel_engages",
                   lambda page, table_pages, topk, width: True)
 
 
@@ -377,7 +379,7 @@ def test_the_entry_gives_the_same_through_either_formulation(
         "no-indexer"])
 def test_the_index_kernels_shape_rule(page, table_pages, topk, width,
                                       engages):
-    assert la.index_kernel_engages(page, table_pages, topk, width) is engages
+    assert index_select.index_kernel_engages(page, table_pages, topk, width) is engages
 
 
 @pytest.mark.parametrize("table_pages", [2, 4, 16, 32])
@@ -389,12 +391,12 @@ def test_a_tpu_program_holds_the_index_kernel_where_its_layer_selects(
     keys out of the pool; lowered for the CPU it holds the gather and no
     kernel, as it did."""
     engages = table_pages * 128 > 256
-    assert la.index_kernel_engages(128, table_pages, 256, 128) is engages
+    assert index_select.index_kernel_engages(128, table_pages, 256, 128) is engages
     text = _lowered(table_pages, "tpu")
-    assert (la.INDEX_KERNEL_NAME in text) is engages
+    assert (index_select.INDEX_KERNEL_NAME in text) is engages
     gathered_keys = f"tensor<2x{table_pages}x128x128xbf16>"
     cpu = _lowered(table_pages, "cpu")
-    assert la.INDEX_KERNEL_NAME not in cpu and "tpu_custom_call" not in cpu
+    assert index_select.INDEX_KERNEL_NAME not in cpu and "tpu_custom_call" not in cpu
     if engages:
         assert gathered_keys not in text and gathered_keys in cpu
 

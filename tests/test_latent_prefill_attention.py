@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import dots3_note
+from ray_tpu.ops import index_select
 from ray_tpu.ops import latent_attention as la
 from ray_tpu.ops.paged_attention import PageRow, row_pool
 
@@ -175,11 +176,11 @@ def test_the_flags_are_kepts_set_key_for_key(name, blocks, monkeypatch):
     index_keys = la.gather_rows(case["pools"][1], case["layer"],
                                 case["table"])
     t, keys = index.q.shape[1], index_keys.shape[1]
-    monkeypatch.setattr(la, "SCORES_MAX_BYTES",
+    monkeypatch.setattr(index_select, "SCORES_MAX_BYTES",
                         4 * 2 * INDEX_HEADS * (t // blocks) * keys)
     assert la.query_block(2, t, INDEX_HEADS, keys, None) == max(
         t // blocks, 16)
-    flags = la._selection_flags((index.q, index.weights), index_keys,
+    flags = la.selection_flags((index.q, index.weights), index_keys,
                                 starts, topk=TOPK)
     assert flags.shape == (2, t, keys) and flags.dtype == jnp.int8
     qpos = np.asarray(starts)[:, None] + np.arange(t)

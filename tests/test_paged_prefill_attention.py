@@ -198,7 +198,8 @@ def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch,
                rng.integers(1, cfg.vocab_size, 7)]
     kernel_calls = []
 
-    def through_kernel(*args, window=None):
+    def through_kernel(*args, window=None, flags=None):
+        assert flags is None        # neither family picks its keys
         kernel_calls.append((args[0].shape, window))
         return ppa.paged_prefill_attention_kernel(*args, window=window,
                                                  interpret=True)

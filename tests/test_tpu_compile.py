@@ -1232,7 +1232,7 @@ def test_index_kernel_compiles_alone(v5e_2x2, heads, table, pool_pages):
     cell's two tables, and at a table it walks two pages at a time: the
     chip's compiler takes the page buffers' slices, the weights' column
     and the scores' rows."""
-    from ray_tpu.ops.latent_attention import index_decode_scores_kernel
+    from ray_tpu.ops.index_select import index_decode_scores_kernel
 
     one_chip = SingleDeviceSharding(v5e_2x2[0])
 
