@@ -15,6 +15,14 @@ a rounding), and its softmax runs over the ``topk`` keys of largest
 ``I`` alone, ties to the lower position; a query that sees no more than
 ``topk`` keys attends over them all (``kept``: one rule, every form).
 
+``kept`` makes the set ``lax.top_k`` would return and sorts no row for
+it (on a v5e ``lax.top_k`` is a whole ``sort`` of every row, 91 stages
+over 8,192 scores and their positions to learn one value and one
+position): the ``topk``-th largest score is found by an exact THRESHOLD
+SEARCH over the scores' float32 bit patterns, a bit a pass, and the
+ties at it are filled by position (``_searched``): plain ``jax.numpy``
+on every platform, each pass one fused compare and count over the rows.
+
 A DECODE step's index scores (``decode_index_scores``) have two
 formulations, chosen by the platform a program is lowered for
 (``jax.lax.platform_dependent``) and by static shapes
@@ -37,11 +45,11 @@ lanes), nothing else:
   0.644 ms against the kernel's 0.165 on a v5e, PR 53).
 
 A PREFILL's selection (``selection_flags``) is flags a query and key
-([n, T, S], one byte a pair): ``index_scores`` and ``kept`` in
-``jax.numpy``, in float32, over blocks of queries whose index scores fit
-``SCORES_MAX_BYTES`` (``query_block``, ``over_blocks``). A prefill
-kernel takes the flags and computes no index score, no top-k and no
-tie; a plain formulation takes them as a mask."""
+([n, T, S], one byte a pair): ``index_scores`` in ``jax.numpy``, in
+float32, and ``kept`` over them, over blocks of queries whose index
+scores fit ``SCORES_MAX_BYTES`` (``query_block``, ``over_blocks``). A
+prefill kernel takes the flags and computes no index score, no top-k
+and no tie; a plain formulation takes them as a mask."""
 
 from __future__ import annotations
 
@@ -87,23 +95,91 @@ def index_scores(q, weights, keys):
         return jnp.sum(w * jax.nn.relu(dots), axis=1)
 
 
+def _ordered(scores):
+    """float32 -> int32 keys whose signed order is the order ``lax.top_k``
+    sorts by: the floats' total order (``-0.0`` below ``+0.0``), the bit
+    pattern with its low 31 bits flipped where the sign is set."""
+    bits = lax.bitcast_convert_type(scores, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _searched(keys, topk: int):
+    """The search: of ``keys`` [R, S] int32, each row's ``topk``-th
+    largest [R, 1] and the position [R, 1] of the last key AT it that a
+    stable descending sort's first ``topk`` hold. No row is sorted: the
+    threshold is the largest ``t`` with ``topk`` keys at or above it,
+    found a bit a pass from the sign down (32 passes, each a compare and
+    a count along the row), and the position the ``need``-th of the keys
+    equal to ``t`` (``need``: what the keys above ``t`` leave of the
+    count), found the same way over the positions' bits, in the rows
+    that have a tie to spare (where none has, which is what scores that
+    differ give, the passes are not made: every tie is taken, up to the
+    row's end). On a v5e, ``scripts/sweep_kept.py``, PR 61: a prefill's
+    block of 2,048 queries over 8,192 keys 0.87-0.97 ms (XLA keeps the
+    block on the core across the passes) where the sort took 3.7 in the
+    serving cell, a decode step's 32 rows of 8,192 0.063 against 0.19;
+    the same passes as a Pallas kernel over blocks of rows took 0.59 and
+    0.05 alone and, in the cell, served tokens that were not the
+    sort's on the same seed (PERF.md, Findings, PR 61): this form's
+    are."""
+    width = keys.shape[-1]
+
+    def count(which):
+        return jnp.sum(which.astype(jnp.int32), axis=-1, keepdims=True)
+
+    def raised(held, bit, holds):
+        """``held`` with ``bit`` set in the rows where the higher value
+        still ``holds``."""
+        higher = held | bit
+        return jnp.where(holds(higher), higher, held)
+
+    def at_least(t):
+        return count(keys >= t) >= topk
+
+    lowest = jnp.full((keys.shape[0], 1), jnp.iinfo(jnp.int32).min)
+    # the sign first: clearing it is the one step up that sets no bit
+    t = jnp.where(at_least(jnp.zeros_like(lowest)), 0, lowest)
+    t = lax.fori_loop(
+        0, 31, lambda i, t: raised(t, jnp.int32(1) << (30 - i), at_least), t)
+    need = topk - count(keys > t)
+    tied = keys == t
+    spare = count(tied) > need
+
+    def nth():
+        """The largest position with fewer than ``need`` ties below it:
+        the ``need``-th tie's own."""
+        ties = jnp.where(
+            tied, lax.broadcasted_iota(jnp.int32, keys.shape, 1), width)
+        bits = max(width - 1, 1).bit_length()
+        return lax.fori_loop(
+            0, bits, lambda i, last: raised(
+                last, jnp.int32(1) << (bits - 1 - i),
+                lambda p: count(ties < p) < need), jnp.zeros_like(lowest))
+
+    # (scores that differ leave no row a tie to spare, and no pass to make)
+    end = jnp.full_like(lowest, width - 1)
+    last = lax.cond(jnp.max(spare.astype(jnp.int32)) > 0, nth, lambda: end)
+    return t, jnp.where(spare, last, end)
+
+
 def kept(chosen, topk: int):
     """Which keys a query's softmax runs over, from its index scores
     ``chosen`` [..., S] float32 (``MASKED`` where it may not see the
     key): every key above the ``topk``-th largest score and, of those AT
     it, the lowest positions that fill the count: the set ``lax.top_k``
-    returns, as a mask. (``top_k`` takes ties by the lower position, so
-    of the keys at the ``topk``-th score it took all up to the highest
-    position it returns among them.) The caller ANDs it with what the
-    query may see: where that is no more than ``topk`` keys the
-    ``topk``-th score is the mask's own and none is dropped."""
+    returns, key for key, as a mask, and no row sorted for it
+    (``_searched``: a threshold search over the scores' bit patterns in
+    ``lax.top_k``'s own order, the ties at the threshold filled by
+    position). The caller ANDs it with what the query may see: where
+    that is no more than ``topk`` keys the ``topk``-th score is the
+    mask's own and none is dropped."""
     with jax.named_scope(scopes.INDEX_SELECT):
-        values, positions = lax.top_k(chosen, topk)
-        kth = values[..., -1:]
-        last = jnp.max(jnp.where(values == kth, positions, -1), axis=-1,
-                       keepdims=True)
-        at = jnp.arange(chosen.shape[-1], dtype=positions.dtype)
-        return (chosen > kth) | ((chosen == kth) & (at <= last))
+        keys = _ordered(chosen)
+        kth, last = _searched(keys.reshape(-1, keys.shape[-1]), topk)
+        lead = (*chosen.shape[:-1], 1)
+        at = jnp.arange(keys.shape[-1], dtype=jnp.int32)
+        kth, last = kth.reshape(lead), last.reshape(lead)
+        return (keys > kth) | ((keys == kth) & (at <= last))
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,8 @@ give the same numbers:
 A layer with an INDEXER (``ops/index_select.py``: ``IndexInputs``;
 DeepSeek-V3.2's sparse attention) keeps a second, narrow row a token,
 the index key; a query's softmax runs over the ``topk`` keys of largest
-index score alone (``kept``: one rule, both forms).
+index score alone (``kept``: one rule, both forms; the set ``lax.top_k``
+would return, found by a threshold search and not a sort).
 
 A WINDOWED layer's queries see the ``window`` newest keys, their own
 among them.
@@ -48,10 +49,12 @@ and by static shapes (``latent_prefill_kernel_engages``), nothing else:
   its last valid query's: the chunks outside it are neither fetched (the
   index maps hold them at the walk's ends) nor computed, and a block of
   padding alone is zeros. The SELECTION reaches it as flags a query and
-  key ([n, T, S], one byte a pair): ``index_scores`` and ``kept`` run in
-  ``jax.numpy`` as the plain formulation runs them, in float32, in blocks
+  key ([n, T, S], one byte a pair): ``index_scores`` runs in
+  ``jax.numpy`` as the plain formulation runs it, in float32, in blocks
   of queries whose index scores fit ``SCORES_MAX_BYTES`` (they stay in
-  HBM), and the kernel computes no index score, no top-k and no tie.
+  HBM), ``kept`` searches each query's row of them for its ``topk``-th
+  score (``ops/index_select.py``: no row is sorted), and the kernel
+  computes no index score, no top-k and no tie.
   Nothing of size heads x queries x keys is written to HBM (a full
   layer of one cold 4,096-token prompt, 3,600 of them valid, on a v5e,
   PR 58: 14.9 ms, of which the kernel 10.4, the flags 3.4 and the

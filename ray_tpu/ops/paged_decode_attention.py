@@ -64,9 +64,11 @@ holds it, and stops at each slot's length:
   page, not at a multiple of a step.
 
 - a layer that PICKS ITS KEYS (``selected``: a learned selection,
-  ``ops/index_select.py``) hands the kernel ``kept``'s set as flags a
-  key, laid out as a step's score columns are (a flag a (token, kv head)
-  row, a page's side by side: [B, PB, page * nkv] int32 in VMEM, 4 MB
+  ``ops/index_select.py``) hands the kernel ``kept``'s set (the ``topk``
+  keys of largest index score, found by a threshold search over a
+  slot's scores and not a sort of them) as flags a key, laid out as a
+  step's score columns are (a flag a (token, kv head) row, a page's
+  side by side: [B, PB, page * nkv] int32 in VMEM, 4 MB
   at 32 slots of 8,192 keys over 4 KV heads, which XLA writes a layer):
   every page a slot holds is still fetched whole and the rows outside
   the set are masked, so the selection saves the step no byte (reading

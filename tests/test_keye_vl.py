@@ -10,6 +10,9 @@ vocabulary 128. The plain reference is the benchmark's family file, the one
 statement of it (``benchmark/families/keye_vl.py:logits``), which imports
 nothing from the program."""
 
+import re
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -369,6 +372,37 @@ def test_a_plan_that_keeps_twins_and_an_index_row_sizes_its_pools(tiny):
                        page_size=PAGE, num_pages=12, kv_dtype="int8")
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_no_program_sorts_the_index_scores(tiny, program):
+    """Lowered for the TPU, neither program of this plan sorts a row of
+    index scores or takes a ``top_k`` of one (``kept`` searches for the
+    ``topk``-th score: ``ops/index_select.py``); the one ``top_k`` left
+    is the router's, over the eight experts."""
+    cfg, params = tiny
+    programs = PagedLLMEngine(cfg=cfg, params=params, max_batch=2,
+                              max_len=64, page_size=PAGE,
+                              num_pages=20)._programs
+    pages = 4
+    assert pages * PAGE > TOPK          # the layers select
+    i32 = partial(jnp.zeros, dtype=jnp.int32)
+    if program == "decode":
+        fn = programs._decode_paged(8, pages)
+        ins = (i32((2, pages)), i32((2,)), i32((2,)), jnp.zeros((2,), bool))
+    else:
+        fn = programs._prefill_paged(pages)
+        ins = (i32((2, pages)), i32((2, 16)), i32((2,)), i32((2,)))
+    text = fn.trace(params, *programs.pools, *ins,
+                    jnp.zeros((2,), jnp.float32),
+                    jax.random.key(0)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    sorted_widths = [
+        int(width) for line in text.splitlines()
+        if "chlo.top_k" in line or "stablehlo.sort" in line
+        for width in re.findall(r"tensor<(?:\d+x)*(\d+)xf32>", line)[:1]]
+    assert sorted_widths == [cfg.n_experts]
+    assert pages * PAGE not in sorted_widths
+
+
 @pytest.fixture(scope="module")
 def served(tiny):
     """Two prompts through the engine, as ``serving.prepare_engine`` serves
@@ -430,8 +464,10 @@ def test_decode_dispatches_count_the_selection(tiny):
         serving.collect(eng, eng.submit(
             np.arange(1, 31, dtype=np.int32), max_new_tokens=4))
         eng.stop()
-        spans = [s for s in tracing.recorded_spans("engine.")
-                 if s["name"] == "engine.dispatch_decode"]
+        # this engine's own: the flight ring is the process's, and keeps
+        # what an earlier test file's engine recorded on this worker
+        spans = [s for s in tracing.recorded_spans("engine.dispatch_decode")
+                 if s["trace_id"] == eng._trace_id]
     finally:
         tracing.disable_tracing()
         tracing.drain_spans(1 << 20)     # leave no span in the ring

@@ -142,6 +142,90 @@ def test_the_mask_is_top_ks_set_key_for_key(ties, count):
     assert flags.sum() == min(count, TOPK) and not flags[count:].any()
 
 
+# The serving cells' widths: a full table of 64 pages of 128 keys and a
+# prefill's third key group, which is no power of two; 2,048 keys kept.
+WIDE_TOPK = 2048
+
+
+def _wide(kind, width, rng):
+    """Six rows of index scores [2, 3, width], as a prefill hands them,
+    that put ``kind`` across the ``WIDE_TOPK``-th place."""
+    x = rng.standard_normal((6, width)).astype(np.float32)
+    at = np.arange(width)[None]
+    if kind == "negative":
+        # a few hundred values, none above zero: ties at every place
+        x = -np.abs(np.round(x * 64) / 64) - 0.25
+    elif kind == "quantised":
+        x = np.round(x * 8) / 8
+    elif kind == "zeros-of-both-signs":
+        # 1,000-1,500 scores above zero and as many below, zeros of both
+        # signs between them in the rows' own mixes (the first row's
+        # count runs out among the ``+0.0``, the last's among the ``-0.0``)
+        plus = np.linspace(0.9, 0.1, 6)[:, None]
+        zero = np.where(rng.random((6, width)) < plus, 0.0, -0.0)
+        x = np.where(np.abs(x) > 1.1, x, zero).astype(np.float32)
+    elif kind == "masked-alone":
+        x[:] = la.MASKED
+    elif kind == "topk-seen":
+        # exactly ``topk`` seen keys, one fewer, one more, half, none, all
+        seen = np.array([WIDE_TOPK, WIDE_TOPK - 1, WIDE_TOPK + 1,
+                         WIDE_TOPK // 2, 0, width])[:, None]
+        x = np.where(at < seen, x, la.MASKED)
+    else:
+        assert kind == "distinct"
+    return jnp.asarray(x.astype(np.float32).reshape(2, 3, width))
+
+
+def _top_ks_mask(chosen, topk):
+    """The positions ``lax.top_k`` returns, as a mask."""
+    positions = np.asarray(jax.lax.top_k(chosen, topk)[1])
+    mask = np.zeros(chosen.shape, bool)
+    np.put_along_axis(mask, positions, True, axis=-1)
+    return mask
+
+
+@pytest.mark.parametrize("width", [8192, 6144])
+@pytest.mark.parametrize("kind", [
+    "distinct", "negative", "quantised", "zeros-of-both-signs",
+    "masked-alone", "topk-seen"])
+def test_the_search_finds_top_ks_set_at_the_cells_widths(kind, width):
+    """``kept`` over rows [n, T, S] at the serving cells' widths IS the
+    set ``lax.top_k`` returns, position for position (the keys it takes
+    of a row with fewer seen keys than ``topk`` too: the ``MASKED`` ones
+    of lowest position), whatever lies across the ``topk``-th place."""
+    chosen = _wide(kind, width, np.random.default_rng(width + len(kind)))
+    got = np.asarray(la.kept(chosen, WIDE_TOPK))
+    assert got.shape == chosen.shape and got.dtype == bool
+    assert np.array_equal(got, _top_ks_mask(chosen, WIDE_TOPK))
+    assert (got.sum(-1) == WIDE_TOPK).all()
+
+
+@pytest.mark.parametrize("shape,topk", [
+    ((72, 8192), 2048), ((5, 384), 100), ((72, 128), 1), ((16, 256), 255),
+    ((8, 256), 256), ((3, 7, 50), 13), ((1, 33), 32)],
+    ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
+def test_the_search_is_top_ks_set_whatever_the_shape(shape, topk):
+    """Rows that are no whole lanes wide, one key kept, all but one, all;
+    scores with ties and zeros of both signs."""
+    rng = np.random.default_rng(sum(shape))
+    x = np.round(rng.standard_normal(shape) * 4) / 4
+    x = jnp.asarray(np.where(rng.random(shape) < 0.2, -0.0, x), jnp.float32)
+    got = np.asarray(la.kept(x, topk))
+    assert np.array_equal(got, _top_ks_mask(x, topk))
+    assert (got.sum(-1) == topk).all()
+
+
+def test_no_row_is_sorted_for_the_selection():
+    """``kept``'s lowered text holds no ``top_k`` and no ``sort``, for
+    the TPU or the CPU: the passes of a search alone."""
+    scores = jax.ShapeDtypeStruct((32, 8192), jnp.float32)
+    for platform in ("tpu", "cpu"):
+        text = jax.jit(partial(la.kept, topk=2048)).trace(scores).lower(
+            lowering_platforms=(platform,)).as_text()
+        assert "top_k" not in text and "sort" not in text
+        assert "stablehlo.while" in text and "custom_call" not in text
+
+
 def _lowered(table_pages, platform, page=128, topk=256):
     """The text of one decode step's attention of a layer with an
     indexer over a table of ``table_pages`` pages, lowered for

@@ -1249,6 +1249,38 @@ def test_index_kernel_compiles_alone(v5e_2x2, heads, table, pool_pages):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+def _sorted_rows(text, width):
+    """The ``sort`` instructions of a compiled program over float32 rows
+    of ``width``: what ``lax.top_k`` of a row of index scores is on this
+    chip."""
+    return [line for line in text.splitlines()
+            if re.search(rf"\(f32\[[\d,]*\b{width}\]\S*, .* sort\(", line)]
+
+
+@pytest.mark.parametrize("rows,width", [
+    (32, 8192), (64, 8192), (2048, 4096), (2048, 6144), (2048, 8192),
+    (128, 8192)], ids=lambda n: str(n))
+def test_the_selection_compiles_to_no_sort(v5e_2x2, rows, width):
+    """``kept`` at the serving cells' shapes (a decode step's slots, a
+    cold prefill's block of queries over each group of keys, a suffix)
+    compiled for the chip: ``lax.top_k`` of such rows is one ``sort`` of
+    each (the function below says so of the same shapes), the search is
+    loops of fused passes and no sort, no kernel, and needs beside its
+    operand no more than the scores' own keys and ties."""
+    from ray_tpu.ops.index_select import kept
+
+    scores = jax.ShapeDtypeStruct((rows, width), jnp.float32,
+                                  sharding=SingleDeviceSharding(v5e_2x2[0]))
+    compiled = jax.jit(partial(kept, topk=2048)).lower(scores).compile()
+    text = compiled.as_text()
+    assert not _sorted_rows(text, width)
+    assert "tpu_custom_call" not in text and " while(" in text
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= 3 * 4 * rows * width + (1 << 20))
+    sort = jax.jit(lambda x: jax.lax.top_k(x, 2048)).lower(scores).compile()
+    assert len(_sorted_rows(sort.as_text(), width)) == 1
+
+
 def _lower_note_program(device, program, dims):
     """One of the engine's two programs for ``serve-note-gen``'s plan
     (two full layers with an indexer, three sliding ones; 32 of 256
@@ -1319,6 +1351,8 @@ def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
     text = compiled.as_text()
     assert len(_LATENT_KERNEL.findall(text)) == 2
     assert len(_INDEX_KERNEL.findall(text)) == 2
+    # since PR 61 the 64 slots' scores are searched, not sorted
+    assert not _sorted_rows(text, 8192)
     assert "bf16[131072,640]" not in text
     assert "bf16[4096,128,128]" not in text
     assert "bf16[320,128,1152]" in text
@@ -1440,10 +1474,14 @@ def test_note_d5_cold_prefill_keeps_its_scores_on_the_core(v5e_2x2):
 
 
 # sha256 (first 16 hex digits) of the text the cell's prefill programs
-# UNDER the latent prefill kernel's rule lowered to on the commit before
-# that kernel (5838c9b), under ``_PINNED_JAX``
-_PARENT_NOTE_PREFILL = {(1, 64, 32): "ebaac4e40dc1d182",
-                        (1, 16, 32): "acd16a8baa5cca46"}
+# UNDER the latent prefill kernel's rule lower to, under ``_PINNED_JAX``.
+# On the commit before that kernel (5838c9b) and up to PR 60 they read
+# "ebaac4e40dc1d182" and "acd16a8baa5cca46"; PR 61 MEANT to alter them
+# (``kept`` searches a query's index scores for their ``topk``-th and
+# sorts none: the text holds the search's loops where it held
+# ``top_k``) and pinned these on its own tree.
+_PARENT_NOTE_PREFILL = {(1, 64, 32): "7543ac1cf78d8465",
+                        (1, 16, 32): "fb8cc2a8740101af"}
 
 
 @pytest.mark.parametrize("dims", _PARENT_NOTE_PREFILL,
@@ -1451,9 +1489,10 @@ _PARENT_NOTE_PREFILL = {(1, 64, 32): "ebaac4e40dc1d182",
 def test_note_d5_prefill_under_the_rule_is_the_parents_text(v5e_2x2, dims):
     """A question of up to 64 tokens behind a cached transcript (a full
     layer's float32 scores and its indexer's would be 201 MB, under the
-    256 MiB line): the program holds neither the kernel nor a choice by
-    platform nor any new operation: its lowered text is the parent's,
-    byte for byte."""
+    256 MiB line): the program holds no prefill kernel and its lowered
+    text is the one pinned above, byte for byte: what it was before the
+    latent prefill kernel but for ``kept``'s search, and still no kernel
+    and no choice by platform."""
     import hashlib
 
     if jax.__version__ != _PINNED_JAX:
@@ -1461,6 +1500,10 @@ def test_note_d5_prefill_under_the_rule_is_the_parents_text(v5e_2x2, dims):
     _, lowered = _lower_note_program(v5e_2x2[0], "prefill", dims)
     text = _located_nowhere(lowered.as_text())
     assert "latent_prefill_attn" not in text
+    # no kernel at all, and no ``top_k`` but the routers'
+    assert "tpu_custom_call" not in text
+    assert "x4096xf32>" not in "".join(
+        line for line in text.splitlines() if "chlo.top_k" in line)
     assert (hashlib.sha256(text.encode()).hexdigest()[:16]
             == _PARENT_NOTE_PREFILL[dims])
 
