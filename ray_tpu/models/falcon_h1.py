@@ -335,6 +335,21 @@ def _mixer_in(cfg, p, x):
     return z, xbc.astype(x.dtype), dt
 
 
+def plain_mixer_in(cfg, p, x):
+    """``_mixer_in`` for a family whose mixer has no multipliers and
+    whose layer's norm is ``p["norm"]`` (``models/nemotron_h.py``,
+    ``models/granite_moe_hybrid.py``): the input projection of the stream
+    ``x`` [b, s, d] under the layer's norm, (z [b, s, di] float32, xBC
+    [b, s, C] in the model's dtype: what the convolution's tail keeps,
+    dt [b, s, H] float32)."""
+    u = rms_norm(x, p["norm"], eps=cfg.rms_eps)
+    proj = jnp.einsum("bsd,dk->bsk", u, p["in_proj"],
+                      preferred_element_type=jnp.float32)
+    z, xbc, dt = jnp.split(proj, [cfg.d_ssm, cfg.d_ssm + cfg.conv_dim],
+                           axis=-1)
+    return z, xbc.astype(x.dtype), dt
+
+
 def _mixer_split(cfg, p, conv, dt):
     """After the convolution (``conv`` [..., C] float32, bias on): silu,
     the split into x [..., H, P], B and C [..., G, N] in the model's

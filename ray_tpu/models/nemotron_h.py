@@ -10,7 +10,8 @@ residual, ``h <- h + Mix_i(rms(h, norm_i))``, the letter of
   two ends of it, the code: ``recurrent_mixer``, ``recurrent_step``,
   ``ops/ssm.py``). A sequence keeps the float32 state ``S`` [H, P, N] and
   the convolution's tail in such a layer, and no page;
-- ``*``, attention: grouped-query, causal, scale ``head_dim ** -0.5``,
+- ``*``, attention: grouped-query, causal, scale ``head_dim ** -0.5``
+  (the engine's own: ``ops/paged_attention.py:page_attention_scale``),
   q | k | v from one stack ``wqkv``, and NO rotary embedding (the mixers
   carry position). A sequence keeps K/V pages in such a layer and
   nothing else;
@@ -55,6 +56,7 @@ from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
 from ray_tpu.ops.moe import moe_ffn_dropless, share_statistics
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.paged_attention import page_attention_scale
 
 _PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
@@ -302,18 +304,6 @@ def init_params(cfg: NemotronHConfig, key) -> dict:
 # The layers' pieces
 # ---------------------------------------------------------------------------
 
-def _mixer_in(cfg, p, x):
-    """The mixer's input projection of the stream ``x`` [b, s, d] under
-    the layer's norm: (z [b, s, di] float32, xBC [b, s, C] in the model's
-    dtype: what the convolution's tail keeps, dt [b, s, H] float32)."""
-    u = rms_norm(x, p["norm"], eps=cfg.rms_eps)
-    proj = jnp.einsum("bsd,dk->bsk", u, p["in_proj"],
-                      preferred_element_type=jnp.float32)
-    z, xbc, dt = jnp.split(proj, [cfg.d_ssm, cfg.d_ssm + cfg.conv_dim],
-                           axis=-1)
-    return z, xbc.astype(x.dtype), dt
-
-
 def _mixer_out(cfg, p, y, xs, z):
     """The mixer's end: the gated grouped norm, then ``out_proj``."""
     y = falcon_h1.gated_norm(cfg, p, y, xs, z)
@@ -322,7 +312,7 @@ def _mixer_out(cfg, p, y, xs, z):
                       ).astype(p["out_proj"].dtype)
 
 
-_ENDS = (_mixer_in, _mixer_out)
+_ENDS = (falcon_h1.plain_mixer_in, _mixer_out)
 
 
 def recurrent_mixer(cfg: NemotronHConfig, p, x, state, valid):
@@ -418,8 +408,8 @@ def forward(cfg: NemotronHConfig, params: dict, tokens):
         def block(x, p, run=run):
             if run.attends:
                 q_, k, v = attention_projections(cfg, p, x)
-                attn = cached_attention(q_, k, v, start,
-                                        scale=cfg.head_dim ** -0.5)
+                attn = cached_attention(
+                    q_, k, v, start, scale=page_attention_scale(cfg.head_dim))
                 x = attention_output(cfg, p, x, attn)
             elif run.state is not None:
                 x = x + recurrent_mixer(cfg, p, x, state, valid)[0]

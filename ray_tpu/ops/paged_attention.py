@@ -55,6 +55,21 @@ import numpy as np
 from ray_tpu.ops import scopes
 
 
+def page_attention_scale(head_dim: int) -> float:
+    """What BOTH entries of attention over K/V pages multiply a score by
+    (``ops/paged_decode_attention.py``, ``ops/paged_prefill_attention.py``,
+    kernel and plain alike), and so what the serving engine's programs
+    scale by whatever the model: ``head_dim ** -0.5``, the entries' own
+    choice and the one place it is stated. A model's plain ``forward``
+    hands ``cached_attention`` this number where it means the same
+    attention as the engine's. A block whose published scale is another
+    folds the ratio of the two into q, in float32 before q's one rounding
+    (``models/granite_moe_hybrid.py:attention_projections``), as a block
+    with a multiplier on its keys folds it into k
+    (``models/falcon_h1.py``)."""
+    return head_dim ** -0.5
+
+
 class PageRow(NamedTuple):
     """One row a token keeps in a page of a layer that keeps no K/V twins
     (a layer plan's run states its rows: ``LayerStack.rows``)."""

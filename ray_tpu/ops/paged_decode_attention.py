@@ -95,7 +95,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
-from ray_tpu.ops.paged_attention import gather_kv_window, visible_pages
+from ray_tpu.ops.paged_attention import (gather_kv_window,
+                                         page_attention_scale, visible_pages)
 
 KERNEL_NAME = "paged_decode_attn"
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -126,7 +127,7 @@ def paged_decode_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
     nkv = kg.shape[-2]
     out = cached_attention(q[:, None], kg.reshape(b, -1, nkv, hd),
                             vg.reshape(b, -1, nkv, hd), pos,
-                            scale=hd ** -0.5, window=window,
+                            scale=page_attention_scale(hd), window=window,
                             key_start=key_start,
                             seen=None if selected is None
                             else selected[:, None])
@@ -159,7 +160,7 @@ def _kernel(layer_ref, table_ref, count_ref, next_ref,     # SMEM
     slots, nh, hd = q_ref.shape
     page_rows = page * nkv
     rows = per_step * page_rows
-    scale = hd ** -0.5
+    scale = page_attention_scale(hd)
     layer = layer_ref[0]
     # what the gather formulation attends over: the pages' own type, or
     # the dequantised bf16 window
@@ -387,7 +388,9 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
                            table, pos, active, *, window=None,
                            selected=None):
     """One decode step's attention for every slot, scores scaled by
-    head_dim ** -0.5. q [B, nh, hd]; stacked pools [L, P, page, nkv, hd]
+    ``page_attention_scale(head_dim)`` (this entry's choice, stated
+    there: a block with another scale folds the ratio into the q it hands
+    over). q [B, nh, hd]; stacked pools [L, P, page, nkv, hd]
     (bf16, or int8 with their scale pools [L, P, page, nkv]); ``layer`` a
     scalar; ``table`` [B, PB] page ids (-1 = hole); slot b attends key
     positions <= pos[b] of its pages, with ``window`` (static: a sliding

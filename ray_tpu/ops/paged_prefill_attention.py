@@ -100,7 +100,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import cached_attention
-from ray_tpu.ops.paged_attention import gather_kv_window, visible_pages
+from ray_tpu.ops.paged_attention import (gather_kv_window,
+                                         page_attention_scale, visible_pages)
 
 KERNEL_NAME = "paged_prefill_attn"
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -227,7 +228,7 @@ def paged_prefill_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
                                   rows)
         return cached_attention(q, kg.reshape(n, -1, nkv, hd),
                                 vg.reshape(n, -1, nkv, hd), first,
-                                scale=hd ** -0.5, **where)
+                                scale=page_attention_scale(hd), **where)
 
     if block == t:
         return attend(q, starts, flags)
@@ -261,7 +262,7 @@ def _kernel(layer_ref, table_ref, starts_ref, slens_ref,        # SMEM
     group = q_ref.shape[2] // (2 * hd)
     rows = group * bq
     ck = chunk_pages * page
-    scale = hd ** -0.5
+    scale = page_attention_scale(hd)
     layer = layer_ref[0]
 
     def page_copy(which, row, chunk, c, buf):
@@ -475,8 +476,10 @@ def paged_prefill_attention_kernel(q, k_pages, v_pages, k_scale, v_scale,
 def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale, layer,
                             table_rows, starts, slens, *, window=None,
                             flags=None):
-    """A prefill's attention for one layer, scores scaled by head_dim **
-    -0.5. q [n, T, heads, hd] at positions ``starts + i``; stacked pools
+    """A prefill's attention for one layer, scores scaled by
+    ``page_attention_scale(head_dim)`` (this entry's choice, stated
+    there: a block with another scale folds the ratio into the q it hands
+    over). q [n, T, heads, hd] at positions ``starts + i``; stacked pools
     [L, P, page, nkv, hd] (bf16, or int8 with their scale pools [L, P,
     page, nkv]) whose rows of this prefill are written; ``layer`` a
     scalar; ``table_rows`` [n, wp] page ids (-1 = hole); query ``i`` of

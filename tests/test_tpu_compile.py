@@ -928,13 +928,16 @@ def test_the_plain_state_update_does_pass_over_the_state(v5e_2x2,
 
 
 @pytest.mark.parametrize("heads,width,size,groups", [
-    (32, 128, 256, 2), (24, 64, 128, 1), (80, 64, 128, 8), (6, 8, 128, 2)],
-    ids=["falcon-h1-34b", "24x64x128", "80x64x128", "six-tiny-heads"])
+    (32, 128, 256, 2), (24, 64, 128, 1), (80, 64, 128, 8), (6, 8, 128, 2),
+    (128, 64, 128, 1)],
+    ids=["falcon-h1-34b", "24x64x128", "80x64x128", "six-tiny-heads",
+         "granite-4.0-h-small"])
 def test_state_kernel_compiles_alone(v5e_2x2, heads, width, size, groups):
     """The kernel by itself at the published head shapes and at others
     its rule admits (heads in one block of 24, in five of 16, six heads
-    that are no whole block of 8): the chip's compiler takes the tiles,
-    and the stacked state is aliased from operand to result."""
+    that are no whole block of 8, 128 heads of ONE group in four blocks
+    of 32): the chip's compiler takes the tiles, and the stacked state
+    is aliased from operand to result."""
     from ray_tpu.ops import ssm
 
     one_chip = SingleDeviceSharding(v5e_2x2[0])
