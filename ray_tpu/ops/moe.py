@@ -156,16 +156,18 @@ def moe_ffn(
 # kernel over the rows each expert got (``ops/grouped_expert_ffn.py``). One
 # layer's call on a v5e, ms, at the four expert widths the benchmark runs
 # with about each cell's share of choices on a held expert (PERF.md, PR 44;
-# ``scripts/sweep_expert_formulations.py``), every-expert / sorted:
+# ``scripts/sweep_expert_formulations.py``; sorted from 1,024 rows as PR
+# 63 left it, the rows coming back with the choices on the major axis),
+# every-expert / sorted:
 #
 #   rows  64x2688x1856     64x3072x1024     32x5120x1536     64x2048x1024
 #         relu2, 6/128     swiglu, 10/256   swiglu, 8/256    swiglu, 8/64
 #    128   1.77 /  1.89     1.76 /  1.81     2.11 /  2.21     1.16 /  1.28
 #    256   2.01 /  1.95     1.87 /  1.92     2.29 /  2.42     1.48 /  1.38
 #    512   3.62 /  2.22     3.59 /  2.49     4.28 /  3.08     2.26 /  1.61
-#   1024   7.24 /  3.03     6.88 /  3.70     8.42 /  3.91     4.46 /  2.33
-#   2048  14.55 /  4.13    13.60 /  5.50    16.87 /  5.54     8.87 /  3.46
-#   4096  28.96 /  6.46    27.09 /  9.35    33.82 /  9.03    17.60 /  5.58
+#   1024   7.24 /  2.76     6.88 /  3.10     8.42 /  3.88     4.46 /  2.26
+#   2048  14.55 /  3.63    13.60 /  4.31    16.87 /  5.50     8.87 /  3.45
+#   4096  28.96 /  5.39    27.09 /  6.92    33.82 /  8.94    17.60 /  5.56
 #
 # Every-expert reads the held experts' weights once (0.8-1.5 GB: 1.0-1.9 ms
 # at the chip's bandwidth) and is bound by that read up to 256 rows, by its
@@ -174,7 +176,7 @@ def moe_ffn(
 # too (its two calls alone: 1.8 / 1.7 / 2.1 / 1.2 ms up to 512 rows, 85% of
 # the bandwidth) and what grows with the rows is the sort, the gather of
 # the pairs' rows and their weighted sum back (0.3-0.8 ms at 512 rows,
-# 1.5-4.9 at 4,096). The two cross between 256 and 512 rows at every width.
+# 1.5-4.2 at 4,096). The two cross between 256 and 512 rows at every width.
 # The line stays above 128, the most slots a cell decodes with: every decode
 # program is every-expert's. (XLA's own grouped matmul over ragged groups,
 # which the op ran past 1,024 rows until PR 44, pads every group to its
@@ -400,11 +402,15 @@ def _experts_grouped(x, gate_vals, gate_idx, valid, load, wi_gate, wi_up,
         tpu=functools.partial(grouped_expert_ffn_kernel, gate_act=gate_act),
         default=functools.partial(grouped_expert_ffn_reference,
                                   gate_act=gate_act))
-    # each pair's row back beside its token; a pair in no group (its row
+    # each pair's row back to its token, the choices on the MAJOR axis: K
+    # slabs of [T, D], so that splitting the gathered [K*T, D] rows moves
+    # nothing (K beside D would be a tiled axis: a copy of every float32
+    # row into tiles of 8, PERF.md PR 63). A pair in no group (its row
     # holds anything) adds nothing. Weighing and masking after the gather
-    # fuse into the sum: no pass of their own over the [T*K, D] rows
+    # fuse into the sum: no pass of their own over the rows
     with jax.named_scope(scopes.MOE_COMBINE):
-        back = jnp.argsort(order)                  # pair -> row
-        held = (expert < e).reshape(t, k, 1)
-        ys = ys[back].reshape(t, k, -1) * gate_vals[:, :, None]
-        return jnp.sum(jnp.where(held, ys, 0.0), axis=1)
+        back = jnp.argsort(order).reshape(t, k).T  # [K, T]: pair -> row
+        held = (expert < e).reshape(t, k).T[:, :, None]
+        ys = ys[back.reshape(k * t)].reshape(k, t, -1)
+        ys = ys * gate_vals.T[:, :, None]
+        return jnp.sum(jnp.where(held, ys, 0.0), axis=0)

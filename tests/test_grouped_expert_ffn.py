@@ -191,6 +191,49 @@ def test_the_rule_is_the_traced_token_count_alone():
     assert line >= 128
 
 
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("run", [False, True], ids=["one-layer", "a-run"])
+@pytest.mark.parametrize("held", ["all", "share"])
+@pytest.mark.parametrize("top_k", [6, 8, 10])
+def test_the_grouped_rows_come_back_to_their_tokens(monkeypatch, top_k, held,
+                                                    run, dtype, tol):
+    """The sorted formulation (its rows gathered back with the choices on
+    the major axis) against every held expert over every row, one choice
+    for both: all 16 experts held or 5 of them from the fourth (so a part
+    of every token's choices lies on absent experts, and ALL of token 0's
+    do), padding rows among the tokens, the stacks one layer's or a run's.
+    Padding rows and the token with no held choice come out exactly
+    zero."""
+    t, d, f, e = 40, 64, 32, 16
+    first, h = (0, e) if held == "all" else (3, 5)
+    ks = jax.random.split(jax.random.key(top_k), 3)
+    x = jax.random.normal(ks[0], (t, d)).astype(dtype)
+    vals, idx = jax.lax.top_k(jax.random.uniform(ks[1], (t, e)), top_k)
+    if held == "share":     # token 0 chooses among the absent alone
+        idx = idx.at[0].set((first + h + jnp.arange(top_k)) % e)
+    valid = jnp.arange(t) % 7 != 3
+    gate, up, down = (w[:, first:first + h]
+                      for w in _stacks("swiglu", e, d, f, dtype=dtype))
+    layer = LAYER if run else None
+    if not run:
+        gate, up, down = _layer(gate, up, down)
+    out = {}
+    for name, line in (("grouped", 0), ("every", 1 << 30)):
+        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", line)
+        out[name], load = moe.moe_experts(
+            x, (vals, idx), gate, up, down, n_experts=e, first_expert=first,
+            valid=valid, layer=layer)
+        assert out[name].shape == (t, d) and out[name].dtype == dtype
+    got, want = (np.asarray(out[n], np.float32) for n in ("grouped", "every"))
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    assert np.abs(want).max() > 0.1 and int(load.sum()) > 0
+    assert not got[~np.asarray(valid)].any()
+    if held == "share":
+        assert not got[0].any() and got[1:3].any()
+
+
 def test_the_op_takes_the_kernels_branch_for_a_tpu_alone():
     """``moe_ffn_dropless`` past the line holds both lowerings of the
     sorted rows, chosen by the platform the program is lowered for: the

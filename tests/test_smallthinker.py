@@ -230,10 +230,12 @@ def test_the_forms_are_told_apart_and_a_wrong_one_is_refused():
 # sha256 (first 16 hex digits) of the text ``moe_ffn_dropless`` lowered to
 # on the parent's tree (b740d11, before the op came apart), for the two
 # formulations, computed by ``_moe_text`` laid over that tree under the jax
-# named below
+# named below; the sorted one pinned anew in PR 63, whose combine gathers
+# the pairs' rows with the choices on the major axis (cbbe3e83a3e2150a
+# until then), the every-expert one the same since
 _PINNED_JAX = "0.9.0"
 _PARENT_MOE_TEXT = {"every-held-expert": "a7476b9dfcb24d12",
-                    "sorted": "cbbe3e83a3e2150a"}
+                    "sorted": "f1b7bc02f6cc7d58"}
 
 
 def _moe_text(tokens):

@@ -279,18 +279,42 @@ def _expert_stack_moves(text, experts, d, f):
     sliced = re.compile(
         rf"^\s*(?:ROOT )?%\S+ = bf16\[(?:1,)?{experts},(?:{d},{f}|{f},{d})\]"
         r"\S* fusion\(")
-    moves, fused = [], False
+    return [line.strip()[:160] for line in _outside_fusions(text)
+            if sliced.match(line) or (m := _MOVE.match(line))
+            and m.group(2) != "custom-call" and stack.search(m.group(1))]
+
+
+def _outside_fusions(text):
+    """The lines of a compiled program outside any fusion's own
+    computation: inside one a value of an array's shape is no array in
+    memory (every-expert's matmul reads its layer through such a one)."""
+    fused = False
     for line in text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
-            # inside a fusion's own computation a value of the stack's
-            # shape is no array in memory (every-expert's matmul reads its
-            # layer through such a one)
             fused = line.lstrip("%").startswith("fused_computation")
-        elif not fused and (
-                sliced.match(line) or (m := _MOVE.match(line))
-                and m.group(2) != "custom-call" and stack.search(m.group(1))):
-            moves.append(line.strip()[:160])
-    return moves
+        elif not fused:
+            yield line
+
+
+def _combine_relayouts(text, tokens, k, d):
+    """The instructions of a compiled program, outside any fusion's own
+    computation, that lay the (token, choice) pairs' float32 rows out
+    anew on their way back to their tokens: under ``moe_combine`` a copy,
+    a transpose, a reshape or a fusion whose result is ``f32[T, K, D]``
+    (or its padded twin, K rounded up to a float32 tile's 8 sublanes:
+    what ``[T*K, D] -> [T, K, D]`` costs with K beside D, PR 63), and a
+    copy, transpose or reshape to ``[K, T, D]``, which splits the major
+    axis and is a bitcast where nothing moves."""
+    padded = -(-k // 8) * 8
+    minor = re.compile(
+        rf"^\s*(?:ROOT )?%\S+ = f32\[{tokens},(?:{k}|{padded}),{d}\]\S* "
+        r"(?:copy|transpose|reshape|fusion)\(")
+    major = re.compile(
+        rf"^\s*(?:ROOT )?%\S+ = f32\[{k},{tokens},{d}\]\S* "
+        r"(?:copy|transpose|reshape)\(")
+    return [line.strip()[:160] for line in _outside_fusions(text)
+            if "moe_combine" in line
+            and (minor.match(line) or major.match(line))]
 
 
 def _window(slots, pages, nkv):
@@ -1128,8 +1152,9 @@ def test_brief_d8_cold_prefill_names_the_pieces_a_trace_shows(v5e_2x2):
     """The cold document of ``serve-brief-gen`` compiled for the chip,
     read as a traced engine records it at ``stop()``
     (``util/program_scopes.py:instruction_scopes``): the float32 combine
-    fusion (result ``f32[rows x k, d_model]``, 8,192 rows x 6 choices) and
-    its rematerialised copies lie under ``moe_combine``, the grouped
+    fusion (result ``f32[rows x k, d_model]``, 8,192 rows x 6 choices)
+    lies under ``moe_combine`` (the reshape behind it and its
+    rematerialised copies are gone since PR 63), the grouped
     kernel's eight calls under ``moe_experts``, the prefill kernel's four
     under ``attn``, the K/V scatter (whose own name the compiler drops)
     under ``kv_write`` by the one rule for what has no ``op_name`` at all;
@@ -1156,7 +1181,7 @@ def test_brief_d8_cold_prefill_names_the_pieces_a_trace_shows(v5e_2x2):
     rows = f"f32[{8192 * cfg.top_k},{cfg.d_model}]"
     assert rows == "f32[49152,2560]"
     assert under(r"fusion", rows) == {scopes.MOE_COMBINE}
-    assert under(r"reshape.*remat") == {scopes.MOE_COMBINE}
+    assert not under(r"reshape.*remat")     # the [T, K, D] copies (PR 63)
     kernels = [n for n in found if n.startswith("grouped_expert_ffn")]
     assert len(kernels) == 8
     assert under(r"grouped_expert_ffn") == {scopes.MOE_EXPERTS}
@@ -1571,11 +1596,10 @@ _NANO_FETCH = re.compile(
     r"\{[^}]*\)\}, u32\[\]\S*\))? (copy-start|copy-done)\(")
 
 
-def _lower_nano_program(device, program, dims):
-    """One of the engine's two programs for the d9 plan, its stores sized
-    as the engine sizes them: K/V pools of one layer, state arrays of
-    four."""
-    from ray_tpu.models import nemotron_h
+def _lower_planned_program(device, model, cfg, pages, slots, program, dims):
+    """One of the engine's two programs for a plan of mixers and
+    attention layers, its stores sized as the engine sizes them: K/V pools
+    of the attention layers alone, state arrays of the recurrent ones."""
     from ray_tpu.serve.engine_programs import _pool_layers, _state_layers
 
     one = SingleDeviceSharding(device)
@@ -1583,22 +1607,16 @@ def _lower_nano_program(device, program, dims):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
 
-    cfg = dataclasses.replace(
-        nemotron_h.nemotron_3_nano_30b_a3b(), vocab_size=65536,
-        n_experts_held=64, pattern="MEMEM*EME")
-    plan = nemotron_h.layer_plan(cfg)
-    assert (_pool_layers(plan, None), _state_layers(plan)) == (1, 4)
+    plan = model.layer_plan(cfg)
     params = jax.tree.map(
         lambda a: shape(a.shape, a.dtype),
-        jax.eval_shape(partial(nemotron_h.init_params, cfg),
-                       jax.random.key(0)))
-    pool = shape((1, _NANO_PAGES, 128, cfg.n_kv_heads, cfg.head_dim),
-                 jnp.bfloat16)
+        jax.eval_shape(partial(model.init_params, cfg), jax.random.key(0)))
+    pool = shape((_pool_layers(plan, None), pages, 128, cfg.n_kv_heads,
+                  cfg.head_dim), jnp.bfloat16)
     scale = shape((1, 1, 1, 1), jnp.float32)
-    state = tuple(shape((4, _NANO_SLOTS, *dims_), dtype) for _, dims_, dtype
-                  in nemotron_h.recurrent_state(cfg).arrays)
+    state = tuple(shape((_state_layers(plan), slots, *dims_), dtype)
+                  for _, dims_, dtype in model.recurrent_state(cfg).arrays)
     key = jax.eval_shape(lambda: jax.random.key(0))
-    slots = _NANO_SLOTS
     if program == "decode":
         chunk, pages = dims
         fn = partial(PagedLLMEngine._paged_decode_impl, cfg, chunk=chunk,
@@ -1614,8 +1632,23 @@ def _lower_nano_program(device, program, dims):
                 shape((n,), jnp.int32), shape((n,), jnp.int32),
                 shape((n,), jnp.float32), key, *state,
                 shape((n,), jnp.int32))
-    return cfg, jax.jit(fn, donate_argnums=(1, 2, 3, 4, 11, 12)).lower(
+    return jax.jit(fn, donate_argnums=(1, 2, 3, 4, 11, 12)).lower(
         params, pool, pool, scale, scale, *args)
+
+
+def _lower_nano_program(device, program, dims):
+    """One of the engine's two programs for the d9 plan: K/V pools of one
+    layer, state arrays of four."""
+    from ray_tpu.models import nemotron_h
+    from ray_tpu.serve.engine_programs import _pool_layers, _state_layers
+
+    cfg = dataclasses.replace(
+        nemotron_h.nemotron_3_nano_30b_a3b(), vocab_size=65536,
+        n_experts_held=64, pattern="MEMEM*EME")
+    plan = nemotron_h.layer_plan(cfg)
+    assert (_pool_layers(plan, None), _state_layers(plan)) == (1, 4)
+    return cfg, _lower_planned_program(device, nemotron_h, cfg, _NANO_PAGES,
+                                       _NANO_SLOTS, program, dims)
 
 
 @pytest.mark.parametrize(
@@ -1686,3 +1719,43 @@ def test_nemotron_d9_engine_programs_hold_what_their_layers_keep(
     assert len(starts) == len(set(sources)) == len(moves) // 2
     # no expert stack (1.28 GB a layer) is among them
     assert not any("1856" in m for m in moves)
+
+
+# serve-assist-gen's: granite-4.0-h-small's first period, 36 of each
+# layer's 72 experts, half the vocabulary; 64 slots, 1,280 K/V pages
+_ASSIST_SLOTS, _ASSIST_PAGES = 64, 1280
+
+
+@pytest.mark.parametrize("cell", ["serve-brief-gen", "serve-assist-gen"])
+def test_cold_prefills_bring_the_pairs_rows_back_without_a_relayout(v5e_2x2,
+                                                                    cell):
+    """The two cells whose prefill spends most on the rows' way back
+    (``[8192, 6, 2560]`` and ``[1024, 10, 4096]``; K = 6 pads to 8
+    sublanes, K = 10 to 16): the compiled prefill gathers the grouped
+    kernel's float32 rows with the choices on the major axis, so the
+    split of the gathered ``[K*T, D]`` into K slabs is a bitcast and no
+    float32 ``[T, K, D]`` array (a copy of every row into tiles of 8, a
+    quarter to a third of the combine before PR 63) is written anywhere
+    in the program, nor a ``[K, T, D]`` relayout in its place."""
+    if cell == "serve-brief-gen":
+        smallthinker, cfg = _serving_model("smallthinker-d8")
+        tokens, text = 8192, _compile_engine_program(
+            v5e_2x2[0], smallthinker, cfg, _BRIEF_PAGES, "prefill",
+            (1, 8192, 64)).as_text()
+    else:
+        from ray_tpu.models import granite_moe_hybrid as granite
+
+        cfg = dataclasses.replace(
+            granite.granite_4_0_h_small(), layer_types=granite._PERIOD,
+            n_experts_held=36, vocab_size=50176)
+        tokens, text = 1024, _lower_planned_program(
+            v5e_2x2[0], granite, cfg, _ASSIST_PAGES, _ASSIST_SLOTS,
+            "prefill", (1, 1024, 8)).compile().as_text()
+    k, d = cfg.top_k, cfg.d_model
+    assert not _combine_relayouts(text, tokens, k, d)
+    # the fence reads the right program: the gathered rows are there,
+    # under the combine, and split by a bitcast
+    rows = re.compile(rf"= f32\[{tokens * k},{d}\]\S* fusion\(.*moe_combine")
+    slabs = re.compile(rf"= f32\[{k},{tokens},{d}\]\S* bitcast\(")
+    assert rows.search(text) and slabs.search(text)
+
