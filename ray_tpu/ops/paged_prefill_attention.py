@@ -57,7 +57,7 @@ VMEM:
   before. Without a window none of this is traced (the branches are
   Python's, on the static argument): a full layer's kernel has no
   first-chunk arithmetic and a one-sided mask, and its digests hold
-  (``tests/test_tpu_compile.py``);
+  (``tests/test_tpu_compile_kernels.py``);
 - online softmax in float32, probabilities cast to the pages' dtype
   before the weighted sum and the sum divided by the float32 denominator
   at the end, as the decode kernel does (``cached_attention`` normalises

@@ -1,10 +1,13 @@
 """The paged engine's device programs and the stores they carry.
 
 What the host loop (``serve/paged_llm.py``) dispatches, and nothing of
-scheduling: the stores a model's layer plan states, the two programs over
-them with their jit caches and names, the order of their arguments
-(``_Order``, stated once), and which kernels a program of given shapes
-runs (``EnginePrograms.prefill_kernels``, ``decode_kernels``).
+scheduling: the stores a model's layer plan states (``store_shapes``,
+stated once: the engine allocates from it and a test lowers a cell's
+program over it), the two programs over them with their jit caches and
+names, the order of their arguments (``_Order``, stated once) and their
+bodies as they are jitted (``bound_program``), and which kernels a
+program of given shapes runs (``EnginePrograms.prefill_kernels``,
+``decode_kernels``).
 
 - The KV cache is a POOL of fixed-size pages [L, P, page, nkv, hd] over
   the L layers that ATTEND, uniform over them whatever their kind (a
@@ -59,7 +62,7 @@ runs (``EnginePrograms.prefill_kernels``, ``decode_kernels``).
   costliest operations is all that shows); to see one, compile the
   program for a described chip and look for ``S(1)`` in the layout of a
   weight stack inside a loop
-  (``tests/test_tpu_compile.py:_stack_moves_in_loops``).
+  (``tests/compiled_text.py:stack_moves_in_loops``).
 - Where the plan has a RECURRENT run (``LayerStack.state``: a
   state-space mixer, beside the attention on the same input as
   Falcon-H1's or a layer's one sublayer as Nemotron-H's), a sequence's
@@ -366,6 +369,59 @@ def _over_layers(stats: list) -> dict:
     return out
 
 
+def store_shapes(cfg, *, max_batch: int, num_pages: int, page_size: int,
+                 kv_dtype: str) -> tuple:
+    """The stores ``cfg``'s layer plan states, as shapes and types in the
+    order the two programs carry them: (the pools, the state). The pools
+    lie as ``_pool_slices`` says: K/V twins are K and V pages [L, P,
+    page, nkv, hd] and their scale pools (per-token-per-head dequant
+    scales in int8 mode; tiny dummies in bf16 mode so every program
+    shares one signature and donation set); a row is a pool
+    (``row_pool``). The state is one array a kind [L', max_batch, ...]
+    over the L' layers that keep it (none, for a plan of pages alone: the
+    programs then take no such argument). ``EnginePrograms`` allocates
+    from this, and a test that wants a cell's program at the cell's sizes
+    lowers it over this, with no array made."""
+    plan = _model_module(cfg).layer_plan(cfg)
+    quantized = kv_dtype == "int8"
+    pools = []
+    for fmt in _pool_slices(plan)[0]:
+        layers, rows = _pool_layers(plan, fmt), _rows(fmt)
+        if _twins(fmt):
+            nkv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
+            shape = (layers, num_pages, page_size, nkv, cfg.head_dim)
+            pages = jax.ShapeDtypeStruct(
+                shape, jnp.int8 if quantized else jnp.bfloat16)
+            scales = jax.ShapeDtypeStruct(
+                shape[:-1] if quantized else (layers, 1, 1, 1), jnp.float32)
+            pools += [pages, pages, scales, scales]
+        if rows and quantized:
+            raise ValueError(
+                "kv_dtype='int8' over a layer plan that keeps rows "
+                f"({', '.join(row.name for row in rows)}): only K/V "
+                "twins are stored quantised")
+        pools += [jax.eval_shape(partial(row_pool, layers, num_pages,
+                                         page_size, row)) for row in rows]
+    recurrent = _recurrent(plan)
+    state = tuple(
+        jax.ShapeDtypeStruct((_state_layers(plan), max_batch, *shape), dtype)
+        for _, shape, dtype in (recurrent.arrays if recurrent else ()))
+    return pools, state
+
+
+def bound_program(cfg, program: str, *, page_size: int, kv_dtype: str,
+                  **static) -> tuple:
+    """A program's body BOUND as the engine jits it, and the order it is
+    called by: ``program`` is "decode" (``static``: its ``chunk``) or
+    "prefill". A caller builds the call with ``_Order.arguments`` and
+    takes it apart with ``_Order.split``, and donates ``_Order.donated``
+    of as many pools and state arrays as ``store_shapes`` gives."""
+    impl, order = {"decode": (_paged_decode_impl, _DECODE),
+                   "prefill": (_paged_prefill_impl, _PREFILL)}[program]
+    return partial(impl, cfg, page_size=page_size,
+                   quantized=kv_dtype == "int8", **static), order
+
+
 class EnginePrograms:
     """The stores one engine's layer plan states (``pools``, ``state``)
     and the programs over them. A dispatch takes the host's inputs by
@@ -383,43 +439,36 @@ class EnginePrograms:
         self.kv_dtype = kv_dtype
         # what each slot keeps per layer beside its pages (None: nothing)
         self.recurrent = _recurrent(plan)
-        # the pools, as the plan's runs state them (``_pool_slices``)
-        self.pools = []
+        # the pools and the slots' recurrent state, as ``store_shapes``
+        # states them: pages and state empty, scales one
+        where = _pool_slices(plan)[0]
+        pools, state = store_shapes(cfg, max_batch=max_batch,
+                                    num_pages=num_pages, page_size=page_size,
+                                    kv_dtype=kv_dtype)
+        scales = {at.start + i for fmt, at in where.items() if _twins(fmt)
+                  for i in (2, 3)}
+        self.pools = [(jnp.ones if i in scales else jnp.zeros)(a.shape,
+                                                               a.dtype)
+                      for i, a in enumerate(pools)]
+        self.state = tuple(jnp.zeros(a.shape, a.dtype) for a in state)
         self.bf16_row_bytes = 0     # a token's rows over the layers, bf16
         self._page_layers = {}      # layers that keep pages, by format
         twins = None                # the K pool of the layers with twins
-        for fmt in _pool_slices(plan)[0]:
-            layers = _pool_layers(plan, fmt)
-            rows = _rows(fmt)
+        for fmt, at in where.items():
+            layers, rows = _pool_layers(plan, fmt), _rows(fmt)
             self._page_layers[",".join(
                 ["k+v"] * _twins(fmt)
                 + [f"{row.name}:{row.width}" for row in rows])] = layers
             if _twins(fmt):
-                self.pools += self._kv_twins(layers)
-                twins = self.pools[-4]
+                twins = self.pools[at][0]
                 self.bf16_row_bytes += (
                     layers * 2 * 2 * math.prod(twins.shape[3:]))
-            if rows and kv_dtype == "int8":
-                raise ValueError(
-                    "kv_dtype='int8' over a layer plan that keeps rows "
-                    f"({', '.join(row.name for row in rows)}): only K/V "
-                    "twins are stored quantised")
-            row_pools = [row_pool(layers, num_pages, page_size, row)
-                         for row in rows]
-            self.pools += row_pools
             self.bf16_row_bytes += layers * 2 * sum(
-                pool.shape[-1] for pool in row_pools)
+                pool.shape[-1] for pool in self.pools[at][4 * _twins(fmt):])
         # the rows a token keeps in a page, by format: "k+v" for K/V
         # twins (then the rows beside them), else the rows' names and
         # widths
         self.page_rows = ";".join(self._page_layers)
-        # the slots' recurrent state, one array a kind [L', max_batch,
-        # ...] over the L' layers that keep it (none, for a plan of pages
-        # alone: the programs then take no such argument)
-        self.state = tuple(
-            jnp.zeros((_state_layers(plan), max_batch, *shape), dtype)
-            for _, shape, dtype in (self.recurrent.arrays
-                                    if self.recurrent else ()))
         self.state_slot_bytes = sum(
             a.size * a.dtype.itemsize for a in self.state) // max_batch
         # a sliding layer's window, and the keys a layer that picks them
@@ -450,7 +499,6 @@ class EnginePrograms:
         # the runs that attend over latent rows, as the prefill kernel's
         # rule takes them: (heads, the rows' pool's place, window, the
         # keys its indexer keeps, the indexer's heads)
-        where = _pool_slices(plan)[0]
         self._latent_runs = [
             (cfg.n_heads if run.window is None
              else getattr(cfg, "n_heads_sliding", cfg.n_heads),
@@ -473,21 +521,6 @@ class EnginePrograms:
         self._compiled: dict[str, object] = {}     # by program name
         self.scatter_firsts = _named_jit("scatter_firsts", _scatter_firsts)
 
-    def _kv_twins(self, layers: int) -> list:
-        """The four pools of ``layers`` layers that keep K/V twins: K
-        and V pages [L, P, page, nkv, hd] and their scale pools."""
-        cfg = self.cfg
-        nkv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
-        shape = (layers, self.num_pages, self.page_size, nkv, cfg.head_dim)
-        page_dtype = jnp.int8 if self.kv_dtype == "int8" else jnp.bfloat16
-        # per-token-per-head dequant scales (int8 mode; tiny dummies in
-        # bf16 mode so every program shares one signature/donation set)
-        scale_shape = (shape[:-1] if self.kv_dtype == "int8"
-                       else (layers, 1, 1, 1))
-        return [jnp.zeros(shape, page_dtype), jnp.zeros(shape, page_dtype),
-                jnp.ones(scale_shape, jnp.float32),
-                jnp.ones(scale_shape, jnp.float32)]
-
     def holds(self) -> dict:
         """What the plan's layers hold, as the stores were sized: the
         layers that keep pages, by format, and state, with the bytes of
@@ -507,29 +540,30 @@ class EnginePrograms:
 
     # -- the programs, compiled once a shape ---------------------------
 
-    def _program(self, name: str, order: _Order, impl, **static):
-        """The jitted ``impl`` under ``name``, which carries its static
-        facts and so tells the programs apart here too."""
+    def _program(self, name: str, program: str, **static):
+        """The jitted body of ``program`` (``bound_program``) under
+        ``name``, which carries its static facts and so tells the
+        programs apart here too."""
         fn = self._compiled.get(name)
         if fn is None:
+            body, order = bound_program(
+                self.cfg, program, page_size=self.page_size,
+                kv_dtype=self.kv_dtype, **static)
             fn = self._compiled[name] = _named_jit(
-                name, partial(impl, self.cfg, page_size=self.page_size,
-                              quantized=self.kv_dtype == "int8", **static),
-                donate_argnums=order.donated(len(self.pools),
-                                             len(self.state)))
+                name, body, donate_argnums=order.donated(len(self.pools),
+                                                         len(self.state)))
         return fn
 
     def _decode_paged(self, chunk: int, pages: int):
-        return self._program(f"paged_decode_c{chunk}_w{pages}", _DECODE,
-                             _paged_decode_impl, chunk=chunk)
+        return self._program(f"paged_decode_c{chunk}_w{pages}", "decode",
+                             chunk=chunk)
 
     def _prefill_paged(self, pages: int):
         """The prefill program over a ``pages``-page window (it must
         cover every row's start + suffix), bucketed so a short-prompt
         batch reads a fraction of the full window's KV bytes. It
         specializes per (n, bucket) shape besides."""
-        return self._program(f"paged_prefill_w{pages}", _PREFILL,
-                             _paged_prefill_impl)
+        return self._program(f"paged_prefill_w{pages}", "prefill")
 
     # A dispatch is made READY here and CALLED by the loop, from the
     # frame that dispatches it: ``program(*arguments)``; what comes back

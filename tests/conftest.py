@@ -51,6 +51,33 @@ def ray_tpu_start():
     ray_tpu.shutdown()
 
 
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    """The devices of a TPU that is described, not attached: the chip's
+    own compiler refuses for them what it would refuse on the chip (a
+    kernel it cannot tile or partition, a step that does not fit HBM), at
+    no chip time. Nothing runs on them, so nothing read off them is a
+    result or a time. (Code that asks ``jax.default_backend()`` sees the
+    CPU under test.)"""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: the next run would warn
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="session")
 def cpu_mesh_devices():
     devices = jax.devices()

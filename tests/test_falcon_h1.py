@@ -17,13 +17,13 @@ import pytest
 
 from benchmark import reference, serving
 from benchmark.families import falcon_h1 as family
+from engine_lowering import lower, programs_logits
 from ray_tpu.models import falcon_h1, laguna
 from ray_tpu.models.llama import LayerStack
 from ray_tpu.ops import ssm
-from ray_tpu.serve import engine_programs, paged_llm
+from ray_tpu.serve import engine_programs
 from ray_tpu.serve.engine_programs import _model_module
 from ray_tpu.serve.paged_llm import PagedLLMEngine
-from test_tpu_compile import _lower_engine_program
 
 # the tiny model under the published key names: query groups of 5, two
 # mixer groups, every multiplier another number than one
@@ -204,61 +204,15 @@ def test_a_step_leaves_an_inactive_slots_state_and_tail(tiny):
 
 # -- the engine's two programs against the reference's one forward pass ------
 
-def _programs_logits(monkeypatch, cfg, params, prompt, new, *, page, slots=3,
-                     slot=1, chunk=4):
+def _programs_logits(monkeypatch, cfg, params, prompt, new, *, page,
+                     chunk=4):
     """The logits the engine's two programs compute for ``prompt`` and
-    ``new`` greedy tokens behind it: the prefill program (the prompt
-    padded to its bucket, its state installed in ``slot``), then the
-    decode program in chunks, the other slots inactive. Read where the
-    programs hand them to ``select_tokens``."""
-    seen = []
-
-    def spy(logits, temps, key):
-        jax.debug.callback(lambda lg: seen.append(np.asarray(lg)), logits,
-                           ordered=True)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    monkeypatch.setattr(engine_programs, "select_tokens", spy)
-    plen = len(prompt)
-    max_pages = -(-(plen + new + chunk) // page) + 1
-    pool = jnp.zeros((cfg.n_layers, slots * max_pages, page, cfg.n_kv_heads,
-                      cfg.head_dim), jnp.bfloat16)
-    scale = jnp.ones((cfg.n_layers, 1, 1, 1), jnp.float32)
-    # a predecessor's garbage in every slot: the prefill must overwrite it
-    state = [jnp.full((cfg.n_layers, slots, *shape), 7.0, dtype)
-             for _, shape, dtype in falcon_h1.layer_plan(cfg)[0].state.arrays]
-    table = np.full((slots, max_pages), -1, np.int32)
-    table[slot] = np.arange(max_pages) + slot * max_pages
-    bucket = paged_llm._bucket(plen)
-    padded = np.zeros((1, bucket), np.int32)
-    padded[0, :plen] = prompt
-    key = jax.random.key(0)
-    kp, vp, ks, vs, first, *state = PagedLLMEngine._paged_prefill_impl(
-        cfg, params, pool, pool, scale, scale, jnp.asarray(table[slot:slot + 1]),
-        jnp.asarray(padded), jnp.array([plen], jnp.int32),
-        jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.float32), key,
-        *state, jnp.array([slot], jnp.int32), page_size=page,
-        quantized=False)
-    tokens = [int(first[0])]
-    last = jnp.zeros((slots,), jnp.int32).at[slot].set(first[0])
-    lens = jnp.zeros((slots,), jnp.int32).at[slot].set(plen)
-    active = jnp.zeros((slots,), bool).at[slot].set(True)
-    others = [np.asarray(a)[:, [i for i in range(slots) if i != slot]]
-              for a in state]
-    while len(tokens) < new:
-        kp, vp, ks, vs, toks, lens, last, _, *state = \
-            PagedLLMEngine._paged_decode_impl(
-                cfg, params, kp, vp, ks, vs, jnp.asarray(table), last, lens,
-                active, jnp.zeros((slots,), jnp.float32), key, *state,
-                chunk=chunk, page_size=page, quantized=False)
-        tokens += [int(t) for t in np.asarray(toks)[:, slot]]
-    jax.effects_barrier()
-    # an inactive slot's state is left as it was, bit for bit
-    for before, a in zip(others, state):
-        after = np.asarray(a)[:, [i for i in range(slots) if i != slot]]
-        np.testing.assert_array_equal(before, after)
-    rows = [seen[0][0]] + [lg[slot] for lg in seen[1:]]
-    return np.stack(rows[:new]), tokens[:new]
+    ``new`` greedy tokens behind it (``engine_lowering.programs_logits``:
+    its state installed in slot 1 of three, the others inactive and
+    checked to be left as they were)."""
+    rows, tokens, _ = programs_logits(monkeypatch, cfg, params, [prompt], new,
+                                      page=page, slots=(1,), chunk=chunk)
+    return rows[0], tokens[0]
 
 
 @pytest.mark.parametrize("plen,chunk_len,page", [
@@ -482,7 +436,8 @@ def test_the_module_is_found_by_the_configs_class_and_checked(tiny):
 
 # sha256 (first 16 hex digits) of the text each Laguna engine program
 # lowered to on the parent commit (ee80ec6), computed by
-# ``_lower_engine_program`` laid over that tree under the jax named below.
+# the helper that lowered them then laid over that tree under the jax named
+# below (``engine_lowering.lower`` lowers the same text now).
 # ``tests/test_fused_projections.py`` holds the Llama and OLMoE programs'
 # digests, which this PR leaves as they are.
 _LAGUNA_PARENT_TEXT = {
@@ -504,9 +459,9 @@ def test_lagunas_engine_programs_lower_to_the_parents_text(program, dims,
     argument, carry or operation: byte for byte the parent's text."""
     if jax.__version__ != _PINNED_JAX:
         pytest.skip(f"digests pinned under jax {_PINNED_JAX}")
-    text = _lower_engine_program(
-        jax.devices("cpu")[0], laguna, laguna.laguna_tiny(), 16, program,
-        dims, slots=4, page=8, kv_dtype=kv_dtype).as_text()
+    text = lower(jax.devices("cpu")[0], laguna, laguna.laguna_tiny(), program,
+                 dims, num_pages=16, slots=4, page=8,
+                 kv_dtype=kv_dtype).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         _LAGUNA_PARENT_TEXT[(program, dims, kv_dtype)]
 
@@ -517,9 +472,8 @@ def test_a_recurrent_programs_state_is_donated_and_aliased(tiny):
     output."""
     cfg, _ = tiny
     for program, dims in (("decode", (4, 4)), ("prefill", (2, 16, 4))):
-        lowered = _lower_engine_program(
-            jax.devices("cpu")[0], falcon_h1, cfg, 16, program, dims,
-            slots=4, page=8)
+        lowered = lower(jax.devices("cpu")[0], falcon_h1, cfg, program, dims,
+                        num_pages=16, slots=4, page=8)
         main = next(line for line in lowered.as_text().splitlines()
                     if "func.func public @main" in line)
         for shape in ("2x4x6x8x16xf32", "2x4x3x112xf32"):

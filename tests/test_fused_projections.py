@@ -12,13 +12,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from engine_lowering import lower
 from ray_tpu.models import llama, olmoe
 from ray_tpu.ops.paged_prefill_attention import kernel_engages
 from ray_tpu.ops.rope import rope_sin_cos
 from ray_tpu.parallel.mesh import create_mesh
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 from ray_tpu.train.trainer import JaxTrainer, TrainConfig
-from test_tpu_compile import _lower_engine_program
 
 
 @pytest.mark.parametrize("rows,tokens", [(8, 1), (2, 48)],
@@ -64,9 +64,8 @@ def test_decode_program_projects_from_one_stack_and_params_stay():
     ``params`` stay the caller's, in the published layout (the benchmark
     hands them to its plain reference)."""
     cfg = llama.llama_tiny()
-    text = _lower_engine_program(
-        jax.devices("cpu")[0], llama, cfg, 16, "decode", (4, 4), slots=4,
-        page=8).as_text()
+    text = lower(jax.devices("cpu")[0], llama, cfg, "decode", (4, 4),
+                 num_pages=16, slots=4, page=8).as_text()
     width = (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim
     stack = f"tensor<{cfg.n_layers}x{cfg.d_model}x{width}xbf16>"
     built = [line for line in text.splitlines()
@@ -105,9 +104,8 @@ def _train_step_text(strategy, fused_loss):
 
 def _engine_text(model, cfg, program, kv_dtype, dims=None):
     dims = dims or ((4, 4) if program == "decode" else (2, 16, 4))
-    return _lower_engine_program(
-        jax.devices("cpu")[0], model, cfg, 16, program, dims, slots=4,
-        page=8, kv_dtype=kv_dtype).as_text()
+    return lower(jax.devices("cpu")[0], model, cfg, program, dims,
+                 num_pages=16, slots=4, page=8, kv_dtype=kv_dtype).as_text()
 
 
 # sha256 (first 16 hex digits) of the text each program lowered to on the
@@ -220,9 +218,9 @@ def test_a_one_run_layer_plan_lowers_to_the_one_scan_it_was(program):
 # text although the attention moved to ``ops/paged_prefill_attention.py``
 # and takes the rows' valid lengths. The decode programs and the train
 # step import none of it. What a program OVER the rule lowers to is held by
-# ``tests/test_engine_tracing.py`` (the kernel under its name where the
-# program is lowered for the TPU, nothing of it for the CPU) and
-# ``tests/test_tpu_compile.py`` (compiled at the cells' widths).
+# ``tests/test_engine_tracing_kernels.py`` (the kernel under its name where
+# the program is lowered for the TPU, nothing of it for the CPU) and
+# ``tests/test_tpu_compile_dense_moe.py`` (compiled at the cells' widths).
 @pytest.mark.parametrize("model,kv_dtype,dims", [
     (llama, "bf16", (2, 16, 4)), (llama, "int8", (2, 16, 4)),
     (olmoe, "bf16", (2, 16, 4)), (olmoe, "int8", (2, 16, 4)),

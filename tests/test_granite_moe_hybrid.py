@@ -23,9 +23,10 @@ import pytest
 
 from benchmark import reference
 from benchmark.families import granite_moe_hybrid as family
+from engine_lowering import programs_logits
 from ray_tpu.models import granite_moe_hybrid
 from ray_tpu.ops.paged_attention import page_attention_scale
-from ray_tpu.serve import engine_programs, paged_llm
+from ray_tpu.serve import engine_programs
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 from ray_tpu.util import tracing
 
@@ -280,71 +281,6 @@ def test_pools_and_state_have_the_layers_that_keep_them(tiny):
 
 # -- the engine's two programs against the reference's one forward pass ------
 
-def _programs_logits(monkeypatch, cfg, params, prompts, new, *, page,
-                     slots=(2, 0), chunk=4):
-    """The logits the engine's two programs compute for ``prompts`` (ONE
-    prefill group) and ``new`` greedy tokens behind each: the prefill
-    program, each row's state installed in its slot, then the decode
-    program in chunks, the third slot inactive. Returns ([row][step]
-    logits, [row] tokens, the last decode call's statistics)."""
-    seen = []
-
-    def spy(logits, temps, key):
-        jax.debug.callback(lambda lg: seen.append(np.asarray(lg)), logits,
-                           ordered=True)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    monkeypatch.setattr(engine_programs, "select_tokens", spy)
-    plan = granite_moe_hybrid.layer_plan(cfg)
-    n_slots, n = 3, len(prompts)
-    lens = [len(p) for p in prompts]
-    max_pages = -(-(max(lens) + new + chunk) // page) + 1
-    pool = jnp.zeros((engine_programs._pool_layers(plan, None),
-                      n_slots * max_pages, page, cfg.n_kv_heads,
-                      cfg.head_dim), jnp.bfloat16)
-    scale = jnp.ones((pool.shape[0], 1, 1, 1), jnp.float32)
-    # a predecessor's garbage in every slot: the prefill must overwrite it
-    state = [jnp.full((engine_programs._state_layers(plan), n_slots, *shape),
-                      7.0, dtype) for _, shape, dtype
-             in granite_moe_hybrid.recurrent_state(cfg).arrays]
-    table = np.full((n_slots, max_pages), -1, np.int32)
-    for slot in slots:
-        table[slot] = np.arange(max_pages) + slot * max_pages
-    bucket = paged_llm._bucket(max(lens))
-    padded = np.zeros((n, bucket), np.int32)
-    for row, prompt in enumerate(prompts):
-        padded[row, :len(prompt)] = prompt
-    key = jax.random.key(0)
-    at = jnp.array(slots, jnp.int32)
-    kp, vp, ks, vs, first, *state = PagedLLMEngine._paged_prefill_impl(
-        cfg, params, pool, pool, scale, scale, jnp.asarray(table[list(slots)]),
-        jnp.asarray(padded), jnp.array(lens, jnp.int32),
-        jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32), key,
-        *state, at, page_size=page, quantized=False)
-    tokens = [[int(t)] for t in first]
-    last = jnp.zeros((n_slots,), jnp.int32).at[at].set(first)
-    lengths = jnp.zeros((n_slots,), jnp.int32).at[at].set(
-        jnp.array(lens, jnp.int32))
-    active = jnp.zeros((n_slots,), bool).at[at].set(True)
-    idle = [i for i in range(n_slots) if i not in slots]
-    others = [np.asarray(a)[:, idle] for a in state]
-    stats = {}
-    while len(tokens[0]) < new:
-        kp, vp, ks, vs, toks, lengths, last, stats, *state = \
-            PagedLLMEngine._paged_decode_impl(
-                cfg, params, kp, vp, ks, vs, jnp.asarray(table), last,
-                lengths, active, jnp.zeros((n_slots,), jnp.float32), key,
-                *state, chunk=chunk, page_size=page, quantized=False)
-        for row, slot in enumerate(slots):
-            tokens[row] += [int(t) for t in np.asarray(toks)[:, slot]]
-    jax.effects_barrier()
-    for before, a in zip(others, state):
-        np.testing.assert_array_equal(before, np.asarray(a)[:, idle])
-    rows = [np.stack([seen[0][row]] + [lg[slot] for lg in seen[1:]])[:new]
-            for row, slot in enumerate(slots)]
-    return rows, [t[:new] for t in tokens], stats
-
-
 @pytest.fixture(scope="module")
 def programs_run(tiny):
     """Two prompts of 21 and 13 tokens, one prefill group in the 32
@@ -354,8 +290,8 @@ def programs_run(tiny):
     rng = np.random.default_rng(21)
     prompts = [rng.integers(1, cfg.vocab_size, n) for n in (21, 13)]
     with pytest.MonkeyPatch.context() as patch:
-        rows, tokens, stats = _programs_logits(patch, cfg, params, prompts,
-                                               9, page=8)
+        rows, tokens, stats = programs_logits(patch, cfg, params, prompts, 9,
+                                              page=8, slots=(2, 0))
     return prompts, rows, tokens, stats
 
 

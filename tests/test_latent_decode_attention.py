@@ -10,7 +10,7 @@ way: against ``gather_rows`` + ``index_scores`` + the count's mask over
 ragged slots and tables of 32 and 64 pages, the selection made from
 either, the entry through either, its own shape rule, and the tiny model
 through both kernels. (Their Mosaic compiles at the real shapes:
-tests/test_tpu_compile.py.)"""
+tests/test_tpu_compile_kernels.py, test_tpu_compile_note.py.)"""
 
 import math
 from functools import partial

@@ -6,7 +6,8 @@ is ``kept``'s, key for key), a full layer that selects nothing, a
 sliding layer across its window's edge, a suffix behind a cached prefix,
 two rows of unlike lengths and a block of padding alone; in the rows'
 bf16 too; the rule's two sides; and the tiny model through the kernel.
-(Its Mosaic compile at the serving cell's shapes: tests/test_tpu_compile.py.)"""
+(Its Mosaic compile at the serving cell's shapes:
+tests/test_tpu_compile_kernels.py, test_tpu_compile_note.py.)"""
 
 from functools import partial
 

@@ -22,10 +22,11 @@ import pytest
 
 from benchmark import reference
 from benchmark.families import nemotron_h as family
+from engine_lowering import programs_logits
 from ray_tpu.models import (dots3_note, falcon_h1, laguna, llama,
                             nemotron_h, olmoe)
 from ray_tpu.ops import moe, ssm
-from ray_tpu.serve import engine_programs, paged_llm
+from ray_tpu.serve import engine_programs
 from ray_tpu.serve.engine_programs import _model_module
 from ray_tpu.serve.paged_llm import PagedLLMEngine
 from ray_tpu.util import tracing
@@ -374,63 +375,17 @@ def test_a_module_is_asked_only_for_the_pieces_its_plan_uses(tiny):
 
 # -- the engine's two programs against the reference's one forward pass ------
 
-def _programs_logits(monkeypatch, cfg, params, prompt, new, *, page, slots=3,
-                     slot=1, chunk=4):
+def _programs_logits(monkeypatch, cfg, params, prompt, new, *, page,
+                     chunk=4):
     """The logits the engine's two programs compute for ``prompt`` and
-    ``new`` greedy tokens behind it (as ``tests/test_falcon_h1.py``): the
-    prefill program, its state installed in ``slot``, then the decode
-    program in chunks, the other slots inactive; the pools have the one
-    attention layer's pages, the state arrays the four mixers'."""
-    seen = []
-
-    def spy(logits, temps, key):
-        jax.debug.callback(lambda lg: seen.append(np.asarray(lg)), logits,
-                           ordered=True)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    monkeypatch.setattr(engine_programs, "select_tokens", spy)
-    plan = nemotron_h.layer_plan(cfg)
-    plen = len(prompt)
-    max_pages = -(-(plen + new + chunk) // page) + 1
-    pool = jnp.zeros((engine_programs._pool_layers(plan, None),
-                      slots * max_pages, page, cfg.n_kv_heads, cfg.head_dim),
-                     jnp.bfloat16)
-    scale = jnp.ones((pool.shape[0], 1, 1, 1), jnp.float32)
-    # a predecessor's garbage in every slot: the prefill must overwrite it
-    state = [jnp.full((engine_programs._state_layers(plan), slots, *shape),
-                      7.0, dtype)
-             for _, shape, dtype in nemotron_h.recurrent_state(cfg).arrays]
-    table = np.full((slots, max_pages), -1, np.int32)
-    table[slot] = np.arange(max_pages) + slot * max_pages
-    bucket = paged_llm._bucket(plen)
-    padded = np.zeros((1, bucket), np.int32)
-    padded[0, :plen] = prompt
-    key = jax.random.key(0)
-    kp, vp, ks, vs, first, *state = PagedLLMEngine._paged_prefill_impl(
-        cfg, params, pool, pool, scale, scale,
-        jnp.asarray(table[slot:slot + 1]), jnp.asarray(padded),
-        jnp.array([plen], jnp.int32), jnp.zeros((1,), jnp.int32),
-        jnp.zeros((1,), jnp.float32), key, *state,
-        jnp.array([slot], jnp.int32), page_size=page, quantized=False)
-    tokens = [int(first[0])]
-    last = jnp.zeros((slots,), jnp.int32).at[slot].set(first[0])
-    lens = jnp.zeros((slots,), jnp.int32).at[slot].set(plen)
-    active = jnp.zeros((slots,), bool).at[slot].set(True)
-    keep = [i for i in range(slots) if i != slot]
-    others = [np.asarray(a)[:, keep] for a in state]
-    stats = {}
-    while len(tokens) < new:
-        kp, vp, ks, vs, toks, lens, last, stats, *state = \
-            PagedLLMEngine._paged_decode_impl(
-                cfg, params, kp, vp, ks, vs, jnp.asarray(table), last, lens,
-                active, jnp.zeros((slots,), jnp.float32), key, *state,
-                chunk=chunk, page_size=page, quantized=False)
-        tokens += [int(t) for t in np.asarray(toks)[:, slot]]
-    jax.effects_barrier()
-    for before, a in zip(others, state):
-        np.testing.assert_array_equal(before, np.asarray(a)[:, keep])
-    rows = [seen[0][0]] + [lg[slot] for lg in seen[1:]]
-    return np.stack(rows[:new]), tokens[:new], stats
+    ``new`` greedy tokens behind it (``engine_lowering.programs_logits``:
+    its state installed in slot 1 of three, the others inactive): the
+    pools have the one attention layer's pages, the state arrays the four
+    mixers'. With the last decode call's statistics."""
+    rows, tokens, stats = programs_logits(
+        monkeypatch, cfg, params, [prompt], new, page=page, slots=(1,),
+        chunk=chunk)
+    return rows[0], tokens[0], stats
 
 
 @pytest.mark.parametrize("plen,chunk_len,page", [

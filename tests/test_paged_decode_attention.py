@@ -2,7 +2,7 @@
 interpret mode on the CPU: against a plain float32 paged attention at the
 serving cells' head layouts, and through the paged engine against the
 gather formulation. (Its Mosaic compile at the real shapes:
-tests/test_tpu_compile.py.)"""
+tests/test_tpu_compile_kernels.py.)"""
 
 from functools import cache, partial
 
@@ -17,6 +17,7 @@ from ray_tpu.ops import paged_decode_attention as pda
 from ray_tpu.ops.paged_attention import quantize_kv
 from ray_tpu.serve import engine_programs
 from ray_tpu.serve.paged_llm import PagedLLMEngine
+from toy_engine import same_greedy_choice
 
 PAGE, BUCKET, HEAD_DIM, LAYERS, POOL = 16, 4, 32, 3, 24
 # (KV heads, query heads a KV head, page, pages a table row): the two
@@ -251,19 +252,7 @@ def _tiny(model):
 # of logits near 10). So the comparison is the benchmark's ``token_gap``
 # kind: the same tokens up to the first divergence, and there a tie, by
 # the model's own teacher-forced logits. A wrong attention is off by
-# whole logits, not by 0.1.
-NEAR_TIE = 0.1
-
-
-def _same_greedy_choice(model, cfg, params, prompt, got, want):
-    if got == want:
-        return True
-    at = next(i for i, (a, b) in enumerate(zip(got, want)) if a != b)
-    seq = jnp.asarray(list(prompt) + got[:at], jnp.int32)[None]
-    logits = np.asarray(model.forward(cfg, params, seq)[0, -1], np.float32)
-    return abs(logits[got[at]] - logits[want[at]]) < NEAR_TIE
-
-
+# whole logits, not by 0.1 (``toy_engine.same_greedy_choice``).
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("model", [llama, olmoe], ids=["llama", "olmoe"])
 def test_engine_decodes_the_same_tokens_through_the_kernel(monkeypatch, model,
@@ -294,7 +283,7 @@ def test_engine_decodes_the_same_tokens_through_the_kernel(monkeypatch, model,
     gather = served(pda.paged_decode_attention_reference)
     assert [len(t) for t in kernel] == [20, 20, 20]
     for prompt, got, want in zip(prompts, kernel, gather):
-        assert _same_greedy_choice(model, cfg, params, prompt, got, want)
+        assert same_greedy_choice(model, cfg, params, prompt, got, want)
     # and not by diverging everywhere: most answers are the same tokens
     same = sum(g == w for g, w in zip(kernel, gather))
     assert same >= 1

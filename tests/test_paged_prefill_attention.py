@@ -2,7 +2,8 @@
 interpret mode on the CPU: against the gather formulation it replaces on
 the TPU, at the serving cells' head layouts; the rule by which it
 engages; and through the paged engine. (Its Mosaic compile at the real
-shapes, inside the engine's prefill programs: tests/test_tpu_compile.py.)"""
+shapes: tests/test_tpu_compile_kernels.py, and inside the engine's prefill
+programs in each family's tests/test_tpu_compile_<family>.py.)"""
 
 from functools import partial
 
@@ -17,6 +18,7 @@ from ray_tpu.ops import paged_prefill_attention as ppa
 from ray_tpu.ops.paged_attention import quantize_kv
 from ray_tpu.serve import engine_programs
 from ray_tpu.serve.paged_llm import PagedLLMEngine
+from toy_engine import same_greedy_choice
 
 PAGE, WP, T, HEAD_DIM, LAYERS, POOL = 16, 8, 64, 32, 3, 40
 
@@ -187,8 +189,6 @@ def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch,
     second plan has two runs of three sliding layers under a window of 8
     keys, which every prompt is past: the kernel is handed the run's
     window and walks from the window's first chunk."""
-    from test_paged_decode_attention import _same_greedy_choice
-
     cfg = tiny()
     params = model.init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(5)
@@ -225,5 +225,5 @@ def test_engine_prefills_the_same_tokens_through_the_kernel(monkeypatch,
         run.window for run in model.layer_plan(cfg)}
     assert [len(t) for t in kernel] == [12, 12, 12]
     for prompt, got, want in zip(prompts, kernel, gather):
-        assert _same_greedy_choice(model, cfg, params, prompt, got, want)
+        assert same_greedy_choice(model, cfg, params, prompt, got, want)
     assert sum(g == w for g, w in zip(kernel, gather)) >= 1

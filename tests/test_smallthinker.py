@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from benchmark import reference
 from benchmark.families import smallthinker as family
+from engine_lowering import lower
 from ray_tpu.models import laguna, smallthinker
 from ray_tpu.models.llama import LayerStack
 from ray_tpu.ops import moe
@@ -399,12 +400,9 @@ def test_a_plan_without_a_window_counts_none_past_it():
 
 
 def _program_text(cfg, program):
-    from test_tpu_compile import _lower_engine_program
-
     dims = (4, 4) if program == "decode" else (2, 16, 4)
-    return _lower_engine_program(
-        jax.devices("cpu")[0], smallthinker, cfg, 16, program, dims, slots=4,
-        page=8)
+    return lower(jax.devices("cpu")[0], smallthinker, cfg, program, dims,
+                 num_pages=16, slots=4, page=8)
 
 
 def _scoped_events(text):
@@ -523,12 +521,10 @@ def test_the_d8_programs_make_no_array_past_the_bound(program, dims):
     window of queries over two windows of keys is gone. The largest array
     either program makes is the grouped experts' float32 result over the
     (token, choice) pairs, [49152, 2560]."""
-    from test_tpu_compile import _lower_engine_program
-
     cfg = family.model_config(cell_config())
     pages = cell_config()["system"]["num_pages"]
-    text = _lower_engine_program(jax.devices("cpu")[0], smallthinker, cfg,
-                                 pages, program, dims).as_text()
+    text = lower(jax.devices("cpu")[0], smallthinker, cfg, program, dims,
+                 num_pages=pages).as_text()
     at = text.index("func.func public @main")
     entry = text[at:text.index("\n", at)]
     skip = {m.group(0) for m in _TENSOR.finditer(entry)}
