@@ -42,14 +42,25 @@ layer's state twice and write it once; the kernel moves it once each way.
   next block and writes the last one back while this one is computed.
   A block is at most ``_BLOCK_BYTES`` of state; in and out, double
   buffered, four of them lie in VMEM.
-- Per head: ``S' = d S + dtx (x) b`` and ``y = sum_n S' c``, elementwise
-  and one reduction over lanes, all float32: no matmul, nothing of the
-  state is rounded. ``d = exp(dt a)`` is a scalar a head (SMEM, scalar
-  prefetch); a block's ``dtx`` [heads of the block, P] is transposed in
-  VMEM so that a head's is a column, broadcast over lanes, and ``y``, a
-  column a head, goes out the same way back; ``b`` and ``c`` come in
-  their groups, a head reads its group's row. All of these are computed
+- Per head: ``S' = d S + dtx (x) b``, elementwise in float32, and ``y =
+  sum_n S' c`` with no matmul, so nothing of the state is rounded. ``d =
+  exp(dt a)`` is a scalar a head (SMEM, scalar prefetch); a block's
+  ``dtx`` [heads of the block, P] is transposed in VMEM so that a head's
+  is a column, broadcast over lanes; ``b`` and ``c`` come in their
+  groups, a head reads its group's row. All of these are computed
   outside (kilobytes a slot).
+- ``y`` is made without a reduction over lanes: the lane tiles of ``S'
+  c`` are added one on another, the [P, 128] that is left is laid on its
+  side (one transpose) and its rows are added, which leaves the head's
+  ``y`` along the lanes, a row of the block's [heads, P]. The cross-lane
+  unit is what the body has least of: it serves the lane broadcasts of
+  ``dtx`` OR a reduction over lanes a tile under a block's copies, not
+  both. With both (the body until PR 66) a layer-step at 32-tile heads
+  ([128, 256], Falcon-H1) still hid under its copies, and at 8-tile
+  heads ([64, 128], Nemotron-H, granite-4.0-h) took 0.967 ms where the
+  copies alone take 0.85; this body takes 0.857 there and serves every
+  shape of the rule (``scripts/sweep_state_kernel.py``; PERF.md,
+  Findings, PR 66).
 - An inactive slot's block is copied through as it is, bit for bit (the
   pipeline writes every block back), and its ``y`` is zero.
 """
@@ -191,13 +202,12 @@ def ssm_state_step_reference(x, dt, a, b, c, states, layer, active):
 
 
 def _state_kernel(layer_ref, active_ref, decay_ref,            # SMEM
-                  dtx_ref, b_ref, c_ref, s_ref, y_ref, o_ref, y_cols, *,
+                  dtx_ref, b_ref, c_ref, s_ref, y_ref, o_ref, *,
                   heads, per_group):
     """One (slot, block of heads); see the module docstring. dtx_ref,
-    y_ref [hb, P]; b_ref, c_ref [G, 1, N]; s_ref, o_ref [hb, P, N];
-    y_cols [P, hb], the block's ``y`` as columns."""
+    y_ref [hb, P]; b_ref, c_ref [G, 1, N]; s_ref, o_ref [hb, P, N]."""
     del layer_ref                     # the index maps read it
-    hb = s_ref.shape[0]
+    hb, _, size = s_ref.shape
     slot = pl.program_id(0)
     first = pl.program_id(1) * hb
     live = active_ref[slot] != 0
@@ -210,9 +220,13 @@ def _state_kernel(layer_ref, active_ref, decay_ref,            # SMEM
             new = (decay_ref[slot * heads + first + j] * s_ref[j]
                    + dtx[:, j:j + 1] * b_ref[group])
             o_ref[j] = new
-            y_cols[:, j:j + 1] = jnp.sum(new * c_ref[group], axis=-1,
-                                         keepdims=True)
-        y_ref[...] = y_cols[...].T
+            # y = sum_n new c: lane tile on lane tile, then [P, 128] on
+            # its side and its rows added, which leaves y along the lanes
+            weighed = new * c_ref[group]
+            rows = weighed[:, :128]
+            for k in range(128, size, 128):
+                rows = rows + weighed[:, k:k + 128]
+            y_ref[j:j + 1, :] = jnp.sum(rows.T, axis=0, keepdims=True)
 
     @pl.when(jnp.logical_not(live))
     def _():
@@ -220,9 +234,10 @@ def _state_kernel(layer_ref, active_ref, decay_ref,            # SMEM
         y_ref[...] = jnp.zeros_like(y_ref)
 
 
-def ssm_state_step_kernel(x, dt, a, b, c, states, layer, active, *,
-                          interpret=False):
-    """The kernel's launch; arguments as ``ssm_state_step``."""
+def _state_call(body, x, dt, a, b, c, states, layer, active, *,
+                interpret=False):
+    """The launch of ``body`` (``_state_kernel``, or a sweep's stand-in
+    for it) over the stack; arguments as ``ssm_state_step``."""
     n, heads, width = x.shape
     groups, size = b.shape[1:]
     hb = _block_heads(heads, 4 * width * size)
@@ -239,13 +254,11 @@ def ssm_state_step_kernel(x, dt, a, b, c, states, layer, active, *,
     group_block = pl.BlockSpec((None, groups, 1, size),
                                lambda s, h, *_: (s, 0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_state_kernel, heads=heads,
-                          per_group=heads // groups),
+        functools.partial(body, heads=heads, per_group=heads // groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(n, heads // hb),
             in_specs=[head_block, group_block, group_block, state_block],
-            out_specs=[head_block, state_block],
-            scratch_shapes=[pltpu.VMEM((width, hb), jnp.float32)]),
+            out_specs=[head_block, state_block]),
         out_shape=[jax.ShapeDtypeStruct((n, heads, width), jnp.float32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
         # operand 6 (after the three prefetched scalars and dtx, b, c) is
@@ -254,6 +267,13 @@ def ssm_state_step_kernel(x, dt, a, b, c, states, layer, active, *,
         interpret=interpret, name=STATE_KERNEL_NAME,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
       decay, dtx, b, c, states)
+
+
+def ssm_state_step_kernel(x, dt, a, b, c, states, layer, active, *,
+                          interpret=False):
+    """The kernel's launch; arguments as ``ssm_state_step``."""
+    return _state_call(_state_kernel, x, dt, a, b, c, states, layer, active,
+                       interpret=interpret)
 
 
 def ssm_state_step(x, dt, a, b, c, states, layer, active):

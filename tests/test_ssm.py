@@ -171,7 +171,12 @@ def test_causal_conv_and_the_tail_a_row_leaves():
 _TINY = (3, 5, 8, 8, 128, 2)
 _SIX_HEADS = (2, 4, 6, 16, 128, 2)          # no whole block of 8 heads
 _PUBLISHED = (2, 3, 32, 128, 256, 2)        # Falcon-H1-34B's head shapes
-_STACKS = {"tiny": _TINY, "six-heads": _SIX_HEADS, "published": _PUBLISHED}
+_NEMOTRON = (2, 3, 64, 64, 128, 8)          # four groups a block of 32 heads
+_GRANITE = (2, 3, 128, 64, 128, 1)          # one group, four blocks
+_ASKEW = (2, 3, 48, 64, 256, 4)     # blocks of 16 heads, groups of 12
+_STACKS = {"tiny": _TINY, "six-heads": _SIX_HEADS, "published": _PUBLISHED,
+           "nemotron-3-nano": _NEMOTRON, "granite-4.0-h-small": _GRANITE,
+           "groups-askew-of-blocks": _ASKEW}
 
 
 def draw_stack(seed, dims, dtype=jnp.float32, state_dtype=jnp.float32):
@@ -218,8 +223,9 @@ def test_state_kernel_is_the_plain_formulation(dims, operands):
     assert not np.array_equal(after[layer, 0], before[layer, 0])
 
 
-@pytest.mark.parametrize("dims", [_TINY, _PUBLISHED],
-                         ids=["tiny", "published"])
+@pytest.mark.parametrize("dims", [_TINY, _PUBLISHED, _NEMOTRON, _GRANITE],
+                         ids=["tiny", "published", "nemotron-3-nano",
+                              "granite-4.0-h-small"])
 def test_state_kernel_with_every_slot_inactive_moves_nothing(dims):
     x, dt, a, b, c, states = draw_stack(4, dims)
     _, got = ssm.ssm_state_step_kernel(

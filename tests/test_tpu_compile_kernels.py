@@ -93,15 +93,16 @@ def test_a_full_layers_prefill_kernel_is_the_one_it_was(v5e_2x2, case):
 
 @pytest.mark.parametrize("heads,width,size,groups", [
     (32, 128, 256, 2), (24, 64, 128, 1), (80, 64, 128, 8), (6, 8, 128, 2),
-    (128, 64, 128, 1)],
+    (128, 64, 128, 1), (64, 64, 128, 8)],
     ids=["falcon-h1-34b", "24x64x128", "80x64x128", "six-tiny-heads",
-         "granite-4.0-h-small"])
+         "granite-4.0-h-small", "nemotron-3-nano"])
 def test_state_kernel_compiles_alone(v5e_2x2, heads, width, size, groups):
     """The kernel by itself at the published head shapes and at others
     its rule admits (heads in one block of 24, in five of 16, six heads
     that are no whole block of 8, 128 heads of ONE group in four blocks
-    of 32): the chip's compiler takes the tiles, and the stacked state
-    is aliased from operand to result."""
+    of 32, 64 heads whose blocks of 32 hold four groups each): the chip's
+    compiler takes the tiles, and the stacked state is aliased from
+    operand to result."""
     from ray_tpu.ops import ssm
 
     one_chip = SingleDeviceSharding(v5e_2x2[0])
