@@ -142,9 +142,21 @@ class RecurrentState(NamedTuple):
     """What a run of layers with a recurrent mixer keeps per sequence and
     layer beside its KV pages, as ``LayerStack.state``: the arrays
     (name, shape, dtype) in the order the mixer takes and returns them,
-    and the length of the chunks its scan cuts a prompt into."""
+    and the length of the chunks its scan cuts a prompt into.
+    ``pages_keep``: whether the state is worth a page's keeping: every
+    page then keeps, under its own id, the state at its END beside its K
+    and V rows (one more store an array, ``serve/engine_programs.py``),
+    a prefix hit of ``k`` pages hands the prefill the state page ``k -
+    1`` keeps, and the plan's prefix is reusable. For a state of
+    kilobytes (a gated short convolution's tail,
+    ``models/lfm2_moe.py``: an eighth of a page's K and V); a Mamba-2
+    state is as many bytes as sixteen pages of the same layers, and its
+    plans leave this off. A module whose plan says so gives the states
+    at the page ends: its ``recurrent_mixer`` takes ``page_ends=`` (a
+    page's tokens) and returns them third, [n, pages, ...] an array."""
     arrays: tuple
     chunk: int
+    pages_keep: bool = False
 
 
 def layer_plan(cfg: FalconH1Config) -> tuple:

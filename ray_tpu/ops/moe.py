@@ -200,6 +200,7 @@ def moe_route(
     routed_scale: float = 1.0,
     scoring: str = "softmax",
     choice_bias=None,   # [E] float32, added to the scores for the choice
+    norm_eps: float | None = None,  # added to the chosen scores' sum
 ):
     """The router's choice for each of ``rows``: (weights [T, K] float32,
     experts [T, K] int32 among the router's E), as ``moe_ffn_dropless``
@@ -223,7 +224,9 @@ def moe_route(
             gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
         if norm_topk_prob:
             total = jnp.sum(gate_vals, axis=-1, keepdims=True)
-            if scoring == "sigmoid":
+            if norm_eps is not None:
+                total = total + norm_eps    # the block's own, as published
+            elif scoring == "sigmoid":
                 total = total + 1e-20    # sigmoids can all be 0; a
             gate_vals = gate_vals / total   # softmax's top-k cannot
         if routed_scale != 1.0:

@@ -20,6 +20,29 @@ Layout:
                       token and head: ``quantize_kv``)
     page_table:       [B, max_pages_per_seq] int32 (-1 = unused)
 
+KV heads of HALF a lane tile (a head of 64) lie TWO A ROW:
+``heads_per_row(nkv, hd)`` consecutive KV heads side by side in one
+row of ``ROW_LANES``, the pool [L, n_pages, page_size, n_kv / per, 128]
+(``pool_heads`` says the shape, ``rows_of_heads`` lays q, k and v in it,
+``own_parts`` takes a head's result back out). The chip tiles an array's
+last axis in lanes of 128 whatever it holds, so a pool whose rows are 64
+wide keeps 12,288 B a token where the model keeps 6,144, the decode
+kernel copies the padding and the prefill kernel's rule (whole lanes)
+sends every prompt down the gather formulation. Side by side, K's
+write is a reshape of [.., nkv, hd], which is how it lies; a QUERY head
+is laid in the part of the row its KV head has, zeros in the others, so
+the one matmul that scores a head against a row scores it against its
+own part, the kernels' mask of "the rows of its KV head" keeps the rows
+of its KV head's ROW, and of the weighted sum [.., 128] the part that is
+its own is sliced out. No entry below changes by a line: each sees a
+pool of ``n_kv / per`` heads of 128 and queries in groups ``per`` times
+as large (the trick ``ops/index_select.py`` plays for a 64-wide index
+key, "by padding the queries"). The price is ``per`` times the
+attention's multiply-adds, which a decode step bound by its bytes does
+not see and a prefill does. The entries scale a score by
+``page_attention_scale`` of the width THEY see, so ``rows_of_heads``
+folds the ratio of the two scales into the queries it lays out.
+
 A layer that keeps ONE ROW a token and no K/V twins (latent attention:
 the compressed KV with its rotary key; an indexer's key) has pools of
     rows:             [L, n_pages, page_size, lanes], bf16
@@ -55,6 +78,9 @@ import numpy as np
 from ray_tpu.ops import scopes
 
 
+ROW_LANES = 128
+
+
 def page_attention_scale(head_dim: int) -> float:
     """What BOTH entries of attention over K/V pages multiply a score by
     (``ops/paged_decode_attention.py``, ``ops/paged_prefill_attention.py``,
@@ -68,6 +94,72 @@ def page_attention_scale(head_dim: int) -> float:
     with a multiplier on its keys folds it into k
     (``models/falcon_h1.py``)."""
     return head_dim ** -0.5
+
+
+def heads_per_row(n_kv: int, head_dim: int) -> int:
+    """How many consecutive KV heads of ``head_dim`` one row of a K or V
+    pool holds (module docstring): two, where the head is half of
+    ``ROW_LANES`` (the one narrow head a published model has brought: 64)
+    and the heads pair up; else one, the head as it is. (The layout and
+    the two functions below hold for any whole fraction of a row; a head
+    of 32 or 16 is a test's toy, and stays as the parent had it.)"""
+    return 2 if 2 * head_dim == ROW_LANES and n_kv % 2 == 0 else 1
+
+
+def pool_heads(n_kv: int, head_dim: int) -> tuple:
+    """The trailing axes of a K or V pool of ``n_kv`` heads of
+    ``head_dim``: (rows a token, their width)."""
+    per = heads_per_row(n_kv, head_dim)
+    return n_kv // per, head_dim * per
+
+
+def _own_part(per: int):
+    """[KV head of a row, 1, part of the row, 1]: true where the part is
+    the KV head's own."""
+    return jnp.eye(per, dtype=bool)[:, None, :, None]
+
+
+def rows_of_heads(q, k, v):
+    """q [.., heads, hd], k and v [.., n_kv, hd] as a block's module
+    states them -> as the pools and the entries of attention over them
+    take them: themselves, where a head fills its row (nothing is traced:
+    a program of such a model lowers to the text it had). Else k and v
+    reshaped ``per`` heads a row, and each query head laid in its KV
+    head's part of a row, zeros in the others, times the ratio of the
+    model's scale (``head_dim ** -0.5``) to the one the entries apply to
+    a row of that width: one more rounding of q."""
+    heads, hd = q.shape[-2:]
+    n_kv = k.shape[-2]
+    per = heads_per_row(n_kv, hd)
+    if per == 1:
+        return q, k, v
+    lead = q.shape[:-2]
+    fold = page_attention_scale(hd) / page_attention_scale(hd * per)
+    with jax.named_scope(scopes.ATTN_QKV):
+        # [.., row, KV head of the row, query head of the group, part, hd]
+        scaled = (q.astype(jnp.float32) * fold).astype(q.dtype)
+        laid = jnp.where(_own_part(per), scaled.reshape(
+            *lead, n_kv // per, per, heads // n_kv, 1, hd), 0)
+        return (laid.reshape(*lead, heads, per * hd),
+                k.reshape(*k.shape[:-2], n_kv // per, per * hd),
+                v.reshape(*v.shape[:-2], n_kv // per, per * hd))
+
+
+def own_parts(attn, n_kv: int, head_dim: int):
+    """What attention over pools of several heads a row gave for queries
+    ``rows_of_heads`` laid out, [.., heads, per x hd] -> each head's own
+    part of its row's weighted sum [.., heads, hd]; itself where a head
+    fills its row."""
+    per = heads_per_row(n_kv, head_dim)
+    if per == 1:
+        return attn
+    *lead, heads, _ = attn.shape
+    with jax.named_scope(scopes.ATTN_OUT):
+        parts = attn.reshape(*lead, n_kv // per, per, heads // n_kv, per,
+                             head_dim)
+        # one part a head is kept and the others are zeros: the sum is it
+        return jnp.where(_own_part(per), parts, 0).sum(axis=-2).reshape(
+            *lead, heads, head_dim)
 
 
 class PageRow(NamedTuple):
@@ -157,9 +249,6 @@ def gather_kv_window(k_pages, v_pages, k_scale, v_scale, layer, table):
         kg = dequantize_kv(kg, k_scale[layer, table_c])
         vg = dequantize_kv(vg, v_scale[layer, table_c])
     return kg, vg
-
-
-ROW_LANES = 128
 
 
 def row_pool(layers: int, num_pages: int, page_size: int, row: PageRow):

@@ -25,6 +25,8 @@ SHARED_EXPERT = "shared_expert"  # the expert every token takes
 SSM_MIXER = "ssm_mixer"          # a state-space mixer, end to end
 SSM_SCAN = "ssm_scan"            # inside it: the chunked scan of a prompt
 SSM_STEP = "ssm_step"            # inside it: one token's state update
+SHORT_CONV = "short_conv"        # inside it: a gated short convolution whole
+STATE_SNAPSHOT = "state_snapshot"  # a state read from, written to its pages
 LM_HEAD = "lm_head"              # the last rows and the head's logits
 SAMPLE = "sample"                # tokens from logits, and their keeping
 LOSS = "loss"                    # the train step's cross-entropy
@@ -33,4 +35,4 @@ OPTIMIZER = "optimizer"          # the train step's parameter update
 VOCABULARY = (EMBED, NORM, ATTN_QKV, KV_WRITE, ATTN, ATTN_OUT, LATENT_ATTN,
               INDEX_SELECT, FFN, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
               MOE_COMBINE, SHARED_EXPERT, SSM_MIXER, SSM_SCAN, SSM_STEP,
-              LM_HEAD, SAMPLE, LOSS, OPTIMIZER)
+              SHORT_CONV, STATE_SNAPSHOT, LM_HEAD, SAMPLE, LOSS, OPTIMIZER)

@@ -31,6 +31,11 @@ program of given shapes runs (``EnginePrograms.prefill_kernels``,
   and ``ops/latent_attention.py``, each of which says what it reads and
   when it is a Pallas kernel (chosen where the program is lowered, by
   nothing else). The table's width sets no bytes a decode step reads.
+  KV heads of half a lane tile (64) lie two a row of the pools
+  (``ops/paged_attention.py:rows_of_heads``, which says why): a block's
+  q, k and v pass through it behind the module's projections, and the
+  attention's result through ``own_parts``; both are the identity, and
+  nothing traced, where a head fills its row.
 - ``kv_dtype="int8"`` stores K/V pages quantized (per-token-per-head
   symmetric scales in a parallel scale pool): half the KV HBM. The
   decode kernel is compute-bound over int8 pages (conversion on the
@@ -77,6 +82,24 @@ program of given shapes runs (``EnginePrograms.prefill_kernels``,
   mid-chunk decodes on, and its state is garbage afterwards: the next
   tenant's prefill overwrites it before any decode step of that tenant
   runs (the device runs dispatches in order).
+- Where the plan says its state is worth a PAGE's keeping
+  (``RecurrentState.pages_keep``: a state of kilobytes), every page
+  keeps the state at its END: one more store an array of the state,
+  [L', num_pages, ...] under the page's own id, so that the page is
+  allocated, shared, refcounted, evicted and recycled with its state by
+  the one allocator and the one prefix cache, which know nothing of it.
+  The PREFILL program carries these stores beside the pools and the
+  slots' state (``_Order.keeps``): a row whose ``starts`` is ``k`` pages
+  begins from what page ``k - 1`` of its table keeps (0: from zeros),
+  and the state after the last token of every page the suffix completes
+  is written to that page's place in the same program that writes the
+  page's K and V, before the page can be registered. The decode
+  programs neither take nor write them: only freshly PREFILLED full
+  pages are ever registered (``PrefixCache.insert``), so a page that
+  decode fills is never looked up (registering answers, for multi-turn
+  reuse, would have decode write them: ROADMAP Queue 2 B.5). Over such
+  a plan the prefix is reusable; over a plan whose state pages do not
+  keep it is not, and the engine refuses the cache.
 - What is the MODEL's comes from the model's module (``_model_module``).
   What is the ENGINE's is here, once for every model: the page write,
   the attention over the pages, the scans over the plan's runs,
@@ -109,7 +132,8 @@ from ray_tpu.ops.latent_attention import (latent_decode_attention,
                                           write_latent)
 from ray_tpu.ops.moe import expert_kernel_engages
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.paged_attention import (ROW_LANES, row_pool, write_kv,
+from ray_tpu.ops.paged_attention import (ROW_LANES, own_parts, pool_heads,
+                                         row_pool, rows_of_heads, write_kv,
                                          write_rows)
 from ray_tpu.ops.paged_decode_attention import (paged_decode_attention,
                                                 step_pages)
@@ -131,6 +155,15 @@ class _Order(NamedTuple):
     inputs: tuple
     results: tuple
     beside_state: tuple = ()
+    # whether the program carries, behind the slots' state arrays, what
+    # the PAGES keep of it (``store_shapes``'s third): donated and
+    # returned with them, ``carried`` says in which order
+    keeps: bool = False
+
+    def carried(self, state, kept) -> tuple:
+        """The state arrays a call of this program takes and returns: the
+        slots', and behind them the pages' where it ``keeps``."""
+        return (*state, *kept) if self.keeps else tuple(state)
 
     def arguments(self, weights, pools, inputs: dict, state) -> tuple:
         """A call's arguments."""
@@ -182,10 +215,11 @@ _DECODE = _Order(
 # prefill: each row's page table [n, W], its suffix tokens [n, T] padded to
 # the bucket, the suffix's length, where it starts (the cached prefix's
 # length), its temperature and, beside a state, the slot that state is
-# installed in; back come the first tokens
+# installed in; back come the first tokens. Its state arrays are the
+# slots' and then the pages' (a plan whose pages keep none: the slots')
 _PREFILL = _Order(
     inputs=("table_rows", "tokens", "slens", "starts", "temps", "key"),
-    results=("firsts",), beside_state=("slots",))
+    results=("firsts",), beside_state=("slots",), keeps=True)
 
 
 _PIECES = ("layer_plan", "embed", "head_logits")
@@ -372,16 +406,21 @@ def _over_layers(stats: list) -> dict:
 def store_shapes(cfg, *, max_batch: int, num_pages: int, page_size: int,
                  kv_dtype: str) -> tuple:
     """The stores ``cfg``'s layer plan states, as shapes and types in the
-    order the two programs carry them: (the pools, the state). The pools
-    lie as ``_pool_slices`` says: K/V twins are K and V pages [L, P,
-    page, nkv, hd] and their scale pools (per-token-per-head dequant
-    scales in int8 mode; tiny dummies in bf16 mode so every program
-    shares one signature and donation set); a row is a pool
-    (``row_pool``). The state is one array a kind [L', max_batch, ...]
-    over the L' layers that keep it (none, for a plan of pages alone: the
-    programs then take no such argument). ``EnginePrograms`` allocates
-    from this, and a test that wants a cell's program at the cell's sizes
-    lowers it over this, with no array made."""
+    order the two programs carry them: (the pools, the state, what the
+    pages keep of the state). The pools lie as ``_pool_slices`` says: K/V
+    twins are K and V pages [L, P, page, nkv, hd] (heads narrower than a
+    lane tile two a row: ``pool_heads``) and their scale pools
+    (per-token-per-head dequant scales in int8 mode; tiny dummies in bf16
+    mode so every program shares one signature and donation set); a row
+    is a pool (``row_pool``). The state is one array a kind [L',
+    max_batch, ...] over the L' layers that keep it (none, for a plan of
+    pages alone: the programs then take no such argument). Where the plan
+    says its pages keep the state at their end
+    (``RecurrentState.pages_keep``) the third is one array a kind [L',
+    num_pages, ...], which the prefill program alone carries behind the
+    state (``_Order.carried``); else it is empty. ``EnginePrograms``
+    allocates from this, and a test that wants a cell's program at the
+    cell's sizes lowers it over this, with no array made."""
     plan = _model_module(cfg).layer_plan(cfg)
     quantized = kv_dtype == "int8"
     pools = []
@@ -389,7 +428,8 @@ def store_shapes(cfg, *, max_batch: int, num_pages: int, page_size: int,
         layers, rows = _pool_layers(plan, fmt), _rows(fmt)
         if _twins(fmt):
             nkv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
-            shape = (layers, num_pages, page_size, nkv, cfg.head_dim)
+            shape = (layers, num_pages, page_size,
+                     *pool_heads(nkv, cfg.head_dim))
             pages = jax.ShapeDtypeStruct(
                 shape, jnp.int8 if quantized else jnp.bfloat16)
             scales = jax.ShapeDtypeStruct(
@@ -403,10 +443,16 @@ def store_shapes(cfg, *, max_batch: int, num_pages: int, page_size: int,
         pools += [jax.eval_shape(partial(row_pool, layers, num_pages,
                                          page_size, row)) for row in rows]
     recurrent = _recurrent(plan)
-    state = tuple(
-        jax.ShapeDtypeStruct((_state_layers(plan), max_batch, *shape), dtype)
-        for _, shape, dtype in (recurrent.arrays if recurrent else ()))
-    return pools, state
+
+    def state_arrays(rows: int) -> tuple:
+        return tuple(jax.ShapeDtypeStruct(
+            (_state_layers(plan), rows, *shape), dtype)
+            for _, shape, dtype in recurrent.arrays)
+
+    state = state_arrays(max_batch) if recurrent else ()
+    kept = (state_arrays(num_pages)
+            if recurrent and recurrent.pages_keep else ())
+    return pools, state, kept
 
 
 def bound_program(cfg, program: str, *, page_size: int, kv_dtype: str,
@@ -415,7 +461,8 @@ def bound_program(cfg, program: str, *, page_size: int, kv_dtype: str,
     called by: ``program`` is "decode" (``static``: its ``chunk``) or
     "prefill". A caller builds the call with ``_Order.arguments`` and
     takes it apart with ``_Order.split``, and donates ``_Order.donated``
-    of as many pools and state arrays as ``store_shapes`` gives."""
+    of as many pools and state arrays as ``store_shapes`` gives
+    (``_Order.carried`` of its second and third)."""
     impl, order = {"decode": (_paged_decode_impl, _DECODE),
                    "prefill": (_paged_prefill_impl, _PREFILL)}[program]
     return partial(impl, cfg, page_size=page_size,
@@ -442,15 +489,18 @@ class EnginePrograms:
         # the pools and the slots' recurrent state, as ``store_shapes``
         # states them: pages and state empty, scales one
         where = _pool_slices(plan)[0]
-        pools, state = store_shapes(cfg, max_batch=max_batch,
-                                    num_pages=num_pages, page_size=page_size,
-                                    kv_dtype=kv_dtype)
+        pools, state, kept = store_shapes(
+            cfg, max_batch=max_batch, num_pages=num_pages,
+            page_size=page_size, kv_dtype=kv_dtype)
         scales = {at.start + i for fmt, at in where.items() if _twins(fmt)
                   for i in (2, 3)}
         self.pools = [(jnp.ones if i in scales else jnp.zeros)(a.shape,
                                                                a.dtype)
                       for i, a in enumerate(pools)]
         self.state = tuple(jnp.zeros(a.shape, a.dtype) for a in state)
+        # what the pages keep of the state at their end (none, unless the
+        # plan says so): the prefill program's, behind the state
+        self.kept = tuple(jnp.zeros(a.shape, a.dtype) for a in kept)
         self.bf16_row_bytes = 0     # a token's rows over the layers, bf16
         self._page_layers = {}      # layers that keep pages, by format
         twins = None                # the K pool of the layers with twins
@@ -471,6 +521,8 @@ class EnginePrograms:
         self.page_rows = ";".join(self._page_layers)
         self.state_slot_bytes = sum(
             a.size * a.dtype.itemsize for a in self.state) // max_batch
+        self.state_page_bytes = sum(
+            a.size * a.dtype.itemsize for a in self.kept) // num_pages
         # a sliding layer's window, and the keys a layer that picks them
         # attends over at most, if the plan has such layers
         self.window = next(
@@ -524,18 +576,22 @@ class EnginePrograms:
     def holds(self) -> dict:
         """What the plan's layers hold, as the stores were sized: the
         layers that keep pages, by format, and state, with the bytes of
-        one page and of one slot's state over them."""
+        one page (the state it keeps at its end among them) and of one
+        slot's state over them."""
         return {"page_layers": ";".join(
                     f"{rows}={n}" for rows, n in self._page_layers.items()),
                 "page_bytes": self.pages_bytes() // self.num_pages,
                 "state_layers": self.state[0].shape[0] if self.state else 0,
-                "state_slot_bytes": self.state_slot_bytes}
+                "state_slot_bytes": self.state_slot_bytes,
+                "state_page_bytes": self.state_page_bytes}
 
     def pages_bytes(self) -> int:
         """The pools' own bytes: every pool that holds a row a token (K
         and V pages, with their dequant scales in int8 mode; a latent
-        plan's rows), not the bf16 mode's one-element scale dummies."""
-        return sum(a.size * a.dtype.itemsize for a in self.pools
+        plan's rows), not the bf16 mode's one-element scale dummies; and
+        what the pages keep of the recurrent state."""
+        return sum(a.size * a.dtype.itemsize
+                   for a in (*self.pools, *self.kept)
                    if a.shape[1] == self.num_pages)
 
     # -- the programs, compiled once a shape ---------------------------
@@ -550,8 +606,9 @@ class EnginePrograms:
                 self.cfg, program, page_size=self.page_size,
                 kv_dtype=self.kv_dtype, **static)
             fn = self._compiled[name] = _named_jit(
-                name, body, donate_argnums=order.donated(len(self.pools),
-                                                         len(self.state)))
+                name, body, donate_argnums=order.donated(
+                    len(self.pools),
+                    len(order.carried(self.state, self.kept))))
         return fn
 
     def _decode_paged(self, chunk: int, pages: int):
@@ -591,12 +648,16 @@ class EnginePrograms:
         """One prefill over a window of ``pages``, ``inputs`` as
         ``_PREFILL`` names them: (the program, its arguments)."""
         return self._prefill_paged(pages), _PREFILL.arguments(
-            self.params, self.pools, inputs, self.state)
+            self.params, self.pools, inputs,
+            _PREFILL.carried(self.state, self.kept))
 
     def prefilled(self, out):
-        """A prefill call's first tokens, on the device; the pools and
-        the state that came back with them are kept."""
-        self.pools, results, self.state = _PREFILL.split(out, len(self.pools))
+        """A prefill call's first tokens, on the device; the pools, the
+        state and what the pages keep of it, which came back with them,
+        are kept."""
+        self.pools, results, state = _PREFILL.split(out, len(self.pools))
+        self.state, self.kept = (state[:len(self.state)],
+                                 state[len(self.state):])
         return results["firsts"]
 
     # -- which kernels a program runs ----------------------------------
@@ -614,13 +675,14 @@ class EnginePrograms:
         cfg = self.cfg
         return {
             "attn_kernel": int(self._kernel_backend and kernel_engages(
-                (group, bucket, cfg.n_heads, cfg.head_dim), self.pools[0],
-                pages, None)),
+                (group, bucket, cfg.n_heads, self.pools[0].shape[-1]),
+                self.pools[0], pages, None)),
             "window_attn_kernel": int(
                 self._window_kernel_backend and kernel_engages(
                     (group, bucket,
                      getattr(cfg, "n_heads_sliding", cfg.n_heads),
-                     cfg.head_dim), self.pools[0], pages, self.window)),
+                     self.pools[0].shape[-1]), self.pools[0], pages,
+                    self.window)),
             "latent_attn_kernel": int(any(
                 latent_prefill_kernel_engages(
                     (group, bucket, heads, 0), self.pools[at], pages, window,
@@ -733,6 +795,8 @@ def _paged_decode_impl(cfg, params, *args, chunk, page_size,
                 twins, beside = held[:4], held[4:]
                 q, k, v = model.attention_projections(
                     cfg, p, x, *rotary[run.kind])
+                heads = k.shape[-2:]    # as the module states them
+                q, k, v = rows_of_heads(q, k, v)
                 if run.state is not None:
                     # the mixer beside the attention, on the same input
                     mixed, state = mixer_step()
@@ -754,9 +818,9 @@ def _paged_decode_impl(cfg, params, *args, chunk, page_size,
                 # layer: the pages of its window), read where they
                 # lie; the row just written is among them; of a layer
                 # that selects, the rows it does not pick masked
-                attn = paged_decode_attention(
+                attn = own_parts(paged_decode_attention(
                     q[:, 0], *twins, layer, table, pos, active,
-                    window=run.window, selected=selected)
+                    window=run.window, selected=selected), *heads)
                 held = [*twins, *beside]
             if run.attends:
                 x = model.attention_output(cfg, p, x, attn)
@@ -812,13 +876,22 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
     reused prefix KV exactly as the original prompt computed it. The
     layer scans carry the activations and the stacked pools, as
     decode's do: the program holds one pool, the donated one. A row's
-    mixer starts from the zero state (no prefix is reused over a plan
-    with a recurrent run: every prompt starts at 0); a slot past the
-    last one drops."""
+    mixer starts from the zero state where the row starts at 0, which
+    over a plan whose pages do not keep its state is every row (the
+    engine refuses the prefix cache there). Over a plan whose pages do
+    (``RecurrentState.pages_keep``; the state arrays are then the slots'
+    and behind them the pages') a row that starts behind ``k`` reused
+    pages begins from what page ``k - 1`` of its table keeps, and the
+    state at the end of every page the suffix completes is written to
+    that page's place. A slot past the last one drops."""
     model = _model_module(cfg)
     plan = model.layer_plan(cfg)
     where, n_pools = _pool_slices(plan)
     pools, ins, state = _PREFILL.taken(args, n_pools)
+    recurrent = _recurrent(plan)
+    n_state = len(recurrent.arrays) if recurrent else 0
+    # whether the pages' arrays stand behind the slots' among ``state``
+    keeps = recurrent is not None and recurrent.pages_keep
     table_rows, tokens, slens = ins["table_rows"], ins["tokens"], ins["slens"]
     starts, temps, key = ins["starts"], ins["temps"], ins["key"]
     slots = ins.get("slots")    # beside a state alone
@@ -834,6 +907,21 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
     pidx_all = jnp.where((pidx_all >= 0) & valid, pidx_all,
                          num_pages)
     ip_all = positions % page_size
+    if keeps:
+        # the page whose end a row starts from (``starts`` is whole
+        # pages), and the pages the suffix completes: page j of the
+        # block, where all its tokens are the row's (else dropped)
+        first = starts // page_size
+        restored = starts > 0
+        before = jnp.take_along_axis(
+            table_rows, jnp.maximum(first - 1, 0)[:, None], axis=1)[:, 0]
+        ends = jnp.arange(t // page_size, dtype=jnp.int32)
+        done = jnp.take_along_axis(
+            table_rows, jnp.minimum(first[:, None] + ends[None, :],
+                                    table_rows.shape[1] - 1), axis=1)
+        done = jnp.where(
+            (done >= 0) & ((ends[None, :] + 1) * page_size <= slens[:, None]),
+            done, num_pages)                              # [n, T / page]
 
     def block(run, place, stacked, carry, xs):
         x, *rest = carry
@@ -842,16 +930,32 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
         ahead = _ahead(model, cfg, run, p, x)
 
         def mixer_pass():
-            """The mixer over the rows from the zero state, and each
-            row's state after its last token INSTALLED whole in its
-            slot, at the layer's place among those that keep one."""
-            fresh = tuple(jnp.zeros((n, *a.shape[2:]), a.dtype)
-                          for a in state)
-            mixed, final = model.recurrent_mixer(cfg, p, x, fresh, valid)
+            """The mixer over the rows, each from the zero state or from
+            what the page before its start keeps, and each row's state
+            after its last token INSTALLED whole in its slot, at the
+            layer's place among those that keep one; the state at the
+            end of every page the rows complete written to the page's."""
+            slot_state, kept = state[:n_state], state[n_state:]
             at = _state_place(place, layer)
+            fresh = tuple(jnp.zeros((n, *a.shape[2:]), a.dtype)
+                          for a in slot_state)
+            if not keeps:
+                mixed, final = model.recurrent_mixer(cfg, p, x, fresh, valid)
+            else:
+                with jax.named_scope(scopes.STATE_SNAPSHOT):
+                    fresh = tuple(
+                        jnp.where(restored.reshape(-1, *[1] * (a.ndim - 2)),
+                                  a[at, jnp.maximum(before, 0)], zero)
+                        for a, zero in zip(kept, fresh))
+                mixed, final, at_ends = model.recurrent_mixer(
+                    cfg, p, x, fresh, valid, page_ends=page_size)
+                with jax.named_scope(scopes.STATE_SNAPSHOT):
+                    kept = [a.at[at, done].set(new, mode="drop")
+                            for a, new in zip(kept, at_ends)]
             with jax.named_scope(scopes.SSM_MIXER):
-                return mixed, [a.at[at, slots].set(new, mode="drop")
-                               for a, new in zip(state, final)]
+                return mixed, [*(a.at[at, slots].set(new, mode="drop")
+                                 for a, new in zip(slot_state, final)),
+                               *kept]
 
         if not run.attends:
             if run.state is not None:
@@ -869,6 +973,8 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
             twins, beside = held[:4], held[4:]
             q, k, v = model.attention_projections(cfg, p, x,
                                                   *rotary[run.kind])
+            heads = k.shape[-2:]    # as the module states them
+            q, k, v = rows_of_heads(q, k, v)
             if run.state is not None:
                 mixed, state = mixer_pass()
             twins = write_kv(*twins, layer, k, v, pidx_all, ip_all,
@@ -884,9 +990,9 @@ def _paged_prefill_impl(cfg, params, *args, page_size, quantized):
                                      ip_all)]
                 flags = prefill_selection(index, beside[0], layer,
                                           table_rows, starts)
-            attn = paged_prefill_attention(
+            attn = own_parts(paged_prefill_attention(
                 q, *twins, layer, table_rows, starts, slens,
-                window=run.window, flags=flags)
+                window=run.window, flags=flags), *heads)
             held = [*twins, *beside]
         if run.attends:
             x = model.attention_output(cfg, p, x, attn)

@@ -125,17 +125,24 @@ class PagedLLMEngine:
             page_size = _cfg.serve_kv_page_size    # flag
         # what each slot keeps per layer beside its pages (None: nothing)
         recurrent = _recurrent(_model_module(cfg).layer_plan(cfg))
-        if recurrent is not None and prefix_cache:
+        # a prefix is its pages, and over a recurrent run the state at
+        # their end, which the pages keep or do not
+        # (``RecurrentState.pages_keep``)
+        reusable = recurrent is None or recurrent.pages_keep
+        if prefix_cache and not reusable:
             raise ValueError(
-                "prefix_cache=True over a layer plan with a recurrent run: "
+                "prefix_cache=True over a layer plan with a recurrent run "
+                "whose state the pages do not keep (a Mamba-2 state of "
+                "megabytes: Falcon-H1's, Nemotron-H's, Granite 4.0-H's): "
                 "a prefix hit would hand a request its prefix's KV pages "
                 "without the recurrent state at their end, and its tokens "
-                "would be silently wrong (reuse by state snapshot: "
-                "ROADMAP Queue 2 B.5)")
+                "would be silently wrong (a plan whose state is worth a "
+                "page's keeping says so: RecurrentState.pages_keep; what "
+                "a state of megabytes would need: ROADMAP Queue 2 B.5)")
         if prefix_cache is None:
-            # the flag, for a plan whose prefix is its pages alone
+            # the flag, for a plan whose prefix its pages hold whole
             prefix_cache = (_cfg.serve_prefix_cache_enabled   # flag
-                            and recurrent is None)
+                            and reusable)
         if kv_dtype not in ("bf16", "int8"):
             raise ValueError(f"kv_dtype must be 'bf16' or 'int8', "
                              f"got {kv_dtype!r}")
@@ -254,8 +261,13 @@ class PagedLLMEngine:
             _tracing.emit("engine.construct", start=built,
                           duration=time.time() - built, kind="serve",
                           attrs=self._programs.holds())
-        # rows whose recurrent state a prefill wrote into a slot
+        # rows whose recurrent state a prefill wrote into a slot; of
+        # them, those that BEGAN from the state a page keeps (a prefix
+        # hit over a plan whose pages keep it), and the pages whose end
+        # a prefill wrote the state of
         self.state_installs = 0
+        self.state_restores = 0
+        self.state_snapshot_pages = 0
         # decode dispatches, and those whose program advances the state
         # in the state kernel, reads the rows its layers pick in the
         # latent kernel and scores their index keys in the index kernel
@@ -540,6 +552,17 @@ class PagedLLMEngine:
                 ph.set(state_installs=len(part),
                        scan_chunks=len(part) * -(
                            -bucket // self._programs.recurrent.chunk))
+            if self._programs.kept:
+                # the pages keep the state at their end: rows that start
+                # behind reused pages begin from it, and every page a
+                # suffix completes has its own written
+                restores = int((starts_np > 0).sum())
+                written = int((slens_np // self.page_size).sum())
+                self.state_restores += restores
+                self.state_snapshot_pages += written
+                if ph:
+                    ph.set(state_restores=restores,
+                           state_snapshot_pages=written)
         slens = jnp.asarray(slens_np)
         rows = jnp.asarray(np.stack(
             [self._table[it[1]][:wp] for it in part]))
@@ -1276,7 +1299,8 @@ class PagedLLMEngine:
         "decode_slot_steps", "decode_delivered",
         "decode_overrun_tail", "decode_overrun_ahead", "decode_vacant",
         "retirements_foreseen", "slots_handed_over", "prefill_token_rows",
-        "prefill_new_tokens", "state_installs")
+        "prefill_new_tokens", "state_installs", "state_restores",
+        "state_snapshot_pages")
 
     def stats(self) -> dict:
         out = {

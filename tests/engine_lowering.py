@@ -50,12 +50,13 @@ def traced(device, module, cfg, program, dims, *, num_pages, slots=32,
 
     params = placed(jax.eval_shape(partial(module.init_params, cfg),
                                    jax.random.key(0)))
-    pools, state = placed(engine_programs.store_shapes(
+    pools, state, kept = placed(engine_programs.store_shapes(
         cfg, max_batch=slots, num_pages=num_pages, page_size=page,
         kv_dtype=kv_dtype))
     body, order = engine_programs.bound_program(
         cfg, program, page_size=page, kv_dtype=kv_dtype,
         **({"chunk": dims[0]} if program == "decode" else {}))
+    state = order.carried(state, kept)
     inputs = {name: shape(*said) for name, said
               in _input_shapes(program, dims, slots).items()}
     inputs["key"] = jax.eval_shape(lambda: jax.random.key(0))
@@ -104,7 +105,7 @@ def programs_logits(monkeypatch, cfg, params, prompts, new, *, page, slots,
     monkeypatch.setattr(engine_programs, "select_tokens", spy)
     n, lens = len(prompts), [len(p) for p in prompts]
     max_pages = -(-(max(lens) + new + chunk) // page) + 1
-    (k, v, k_scale, v_scale), state = engine_programs.store_shapes(
+    (k, v, k_scale, v_scale), state, _ = engine_programs.store_shapes(
         cfg, max_batch=n_slots, num_pages=n_slots * max_pages, page_size=page,
         kv_dtype="bf16")
     pools = [jnp.zeros(k.shape, k.dtype), jnp.zeros(v.shape, v.dtype),
@@ -184,6 +185,11 @@ NANO_SLOTS, NANO_PAGES = 128, 2304
 # granite-4.0-h-small's first period, 36 of each layer's 72 experts, half
 # the vocabulary (``serve-assist-gen``): 64 slots, 1,280 K/V pages
 ASSIST_SLOTS, ASSIST_PAGES = 64, 1280
+# LFM2-8B-A1B cut to its first fourteen layers, c c | A c c c x 3
+# (``serve-extract-gen``): 64 slots, 3,072 K/V pages of the THREE attention
+# layers (two 64-wide KV heads a row) that each keep, beside their rows, the
+# ELEVEN convolutions' tails at their end; tables of 36 pages
+EXTRACT_SLOTS, EXTRACT_PAGES, EXTRACT_TABLE = 64, 3072, 36
 
 
 def serving_model(name):
@@ -235,5 +241,11 @@ def serving_model(name):
         return granite, dataclasses.replace(
             granite.granite_4_0_h_small(), layer_types=granite._PERIOD,
             n_experts_held=36, vocab_size=50176)
+    if name == "lfm2-d14":
+        from ray_tpu.models import lfm2_moe
+
+        return lfm2_moe, dataclasses.replace(
+            lfm2_moe.lfm2_8b_a1b(),
+            layer_types=lfm2_moe.lfm2_8b_a1b().layer_types[:14])
     return olmoe, dataclasses.replace(olmoe.olmoe_1b_7b(),
                                       n_layers=MOE_LAYERS)

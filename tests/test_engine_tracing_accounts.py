@@ -341,7 +341,8 @@ def test_the_accounts_count_with_no_session_and_tracing_off(tiny):
 # -- the stores and the programs' call, stated once --------------------------
 
 _FAMILIES = ("llama", "olmoe", "laguna", "falcon_h1", "dots3_note",
-             "nemotron_h", "smallthinker", "keye_vl", "granite_moe_hybrid")
+             "nemotron_h", "smallthinker", "keye_vl", "granite_moe_hybrid",
+             "lfm2_moe")
 _SIZES = dict(max_batch=3, num_pages=10, page_size=PAGE)
 
 
@@ -370,8 +371,8 @@ def test_store_shapes_are_the_stores_the_engine_allocates(family, kv_dtype):
             with pytest.raises(ValueError, match="only K/V twins are stored"):
                 build(cfg, kv_dtype=kv_dtype, **_SIZES)
         return
-    pools, state = engine_programs.store_shapes(cfg, kv_dtype=kv_dtype,
-                                                **_SIZES)
+    pools, state, kept = engine_programs.store_shapes(cfg, kv_dtype=kv_dtype,
+                                                      **_SIZES)
     programs = engine_programs.EnginePrograms(cfg, params, kv_dtype=kv_dtype,
                                               **_SIZES)
 
@@ -380,6 +381,7 @@ def test_store_shapes_are_the_stores_the_engine_allocates(family, kv_dtype):
 
     assert said(pools) == said(programs.pools)
     assert said(state) == said(programs.state)
+    assert said(kept) == said(programs.kept)
     assert bool(state) == (programs.recurrent is not None)
     held = programs.pools
     for at, a in enumerate(held):
@@ -392,7 +394,7 @@ def test_store_shapes_are_the_stores_the_engine_allocates(family, kv_dtype):
 @pytest.mark.parametrize("program,static", [
     ("decode", {"chunk": 4}), ("prefill", {})], ids=["decode", "prefill"])
 @pytest.mark.parametrize("family", ["llama", "dots3_note", "falcon_h1",
-                                    "nemotron_h", "keye_vl"])
+                                    "nemotron_h", "keye_vl", "lfm2_moe"])
 def test_a_bound_program_is_the_one_the_engine_jits(monkeypatch, family,
                                                     program, static):
     """``bound_program`` gives the body ``EnginePrograms._program`` jits,
@@ -419,8 +421,9 @@ def test_a_bound_program_is_the_one_the_engine_jits(monkeypatch, family,
         cfg, program, page_size=PAGE, kv_dtype="bf16", **static)
     assert (fn.func, fn.args, fn.keywords) == (body.func, body.args,
                                                body.keywords)
-    pools, state = engine_programs.store_shapes(cfg, kv_dtype="bf16",
-                                                **_SIZES)
+    pools, state, kept = engine_programs.store_shapes(cfg, kv_dtype="bf16",
+                                                      **_SIZES)
+    state = order.carried(state, kept)
     assert donated == order.donated(len(pools), len(state))
     # the stores, and nothing else, among a call's arguments
     inputs = dict.fromkeys(order.inputs + order.beside_state, "input")

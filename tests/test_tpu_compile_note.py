@@ -90,7 +90,7 @@ def test_note_d5_decode_program_reads_its_rows_in_place(v5e_2x2):
     (the peak is no longer the indexer's)."""
     # the pools the plan states: the full layers' latent rows and index
     # keys, the sliding layers' rows, each in whole lanes
-    pools, _ = store_shapes(
+    pools, *_ = store_shapes(
         serving_model("dots3-note-d5")[1], max_batch=NOTE_SLOTS,
         num_pages=NOTE_PAGES, page_size=128, kv_dtype="bf16")
     assert [p.shape[-1] for p in pools] == [640, 128, 1152]

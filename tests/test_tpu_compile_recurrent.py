@@ -1,7 +1,8 @@
 """Compile the paged engine's programs for the plans with a recurrent
 run (Falcon-H1, ``serve-instruct-gen``; Nemotron-H, ``serve-reason-gen``;
-granite-4.0-h, ``serve-assist-gen``) for a TPU that is described, not
-attached (``conftest.py:v5e_2x2``), and read the compiled text."""
+granite-4.0-h, ``serve-assist-gen``; LFM2-MoE, ``serve-extract-gen``) for
+a TPU that is described, not attached (``conftest.py:v5e_2x2``), and read
+the compiled text."""
 
 import re
 
@@ -9,9 +10,10 @@ import pytest
 
 import compiled_checks
 import compiled_text as hlo
-from engine_lowering import (ASSIST_PAGES, ASSIST_SLOTS, H1_LAYERS, H1_PAGES,
-                             H1_SLOTS, NANO_PAGES, NANO_SLOTS, compiled,
-                             lower, serving_model)
+from engine_lowering import (ASSIST_PAGES, ASSIST_SLOTS, EXTRACT_PAGES,
+                             EXTRACT_SLOTS, EXTRACT_TABLE, H1_LAYERS,
+                             H1_PAGES, H1_SLOTS, NANO_PAGES, NANO_SLOTS,
+                             compiled, lower, serving_model)
 
 # Falcon-H1-34B-Instruct cut to 4 blocks: 128 slots, 1280 KV pages, and
 # each slot's recurrent state beside them
@@ -234,3 +236,50 @@ def test_cold_prefills_bring_the_pairs_rows_back_without_a_relayout(v5e_2x2,
                     num_pages=ASSIST_PAGES, slots=ASSIST_SLOTS).as_text()
     compiled_checks.cold_prefill_brings_the_pairs_rows_back_without_a_relayout(
         text, 1024, cfg)
+
+
+# LFM2-8B-A1B cut to its first fourteen layers: the decode program at the
+# cell's full table, the cold prefill of two 4,096-token prompts and a
+# suffix behind cached pages
+_EXTRACT_PROGRAMS = [("decode", (16, EXTRACT_TABLE)),
+                     ("prefill", (2, 4096, 32)), ("prefill", (1, 256, 32))]
+# the K/V pools of the three attention layers, two 64-wide KV heads a row,
+# and what the pages keep of the eleven convolutions' tails
+_EXTRACT_POOL = f"bf16[3,{EXTRACT_PAGES},128,4,128]"
+_EXTRACT_KEPT = f"bf16[11,{EXTRACT_PAGES},2,2048]"
+_MOVES_WHOLE = r"\S* (?:copy|copy-start|dynamic-slice|dynamic-update-slice)\("
+
+
+@pytest.mark.parametrize(
+    "program,dims", _EXTRACT_PROGRAMS,
+    ids=[f"{p}-{'x'.join(map(str, d))}" for p, d in _EXTRACT_PROGRAMS])
+def test_lfm2_d14_engine_programs_hold_both_page_kernels_and_the_tails(
+        v5e_2x2, program, dims):
+    """``serve-extract-gen``'s programs at the published widths: the
+    pools hold a token in 6,144 B (two heads of 64 a row of 128 lanes),
+    the decode program attends in the decode kernel in each of its three
+    attention layers and the cold prefill in the prefill kernel (the rule
+    reads the pool's rows: whole lanes), with its routed experts in the
+    grouped kernel; the prefill programs carry what the pages keep of the
+    tails, written in place, and the decode program neither takes nor
+    writes it; no program moves a pool or that store whole; each fits the
+    chip beside nothing else."""
+    model, cfg = serving_model("lfm2-d14")
+    built = compiled(v5e_2x2[0], model, cfg, program, dims,
+                     num_pages=EXTRACT_PAGES, slots=EXTRACT_SLOTS)
+    text = built.as_text()
+    assert _EXTRACT_POOL in text and "128,8,64]" not in text
+    assert not re.search(re.escape(_EXTRACT_POOL) + _MOVES_WHOLE, text)
+    assert not re.search(re.escape(_EXTRACT_KEPT) + _MOVES_WHOLE, text)
+    assert (_EXTRACT_KEPT in text) == (program == "prefill")
+    cold = dims == (2, 4096, 32)
+    assert len(hlo.DECODE_KERNEL.findall(text)) == (
+        3 if program == "decode" else 0)
+    assert len(hlo.PREFILL_KERNEL.findall(text)) == (3 if cold else 0)
+    assert bool(hlo.EXPERT_KERNEL.search(text)) == cold
+    mem = built.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            - mem.alias_size_in_bytes)
+    # 9.33 GB of weights, 2.42 GB of pools, 0.28 GB of tails in the pages
+    assert 11.7e9 < mem.argument_size_in_bytes < 12.1e9
+    assert held < 12.8e9
